@@ -66,6 +66,9 @@ class Autoscaler:
         self.config = config
         self.provider = provider
         self.runtime = runtime
+        # Tells the runtime's clients (Train's fail-fast on a TPU
+        # request no alive node holds) that capacity can still grow.
+        runtime.autoscaler_attached = True
         self._idle_since: dict[str, float] = {}
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
@@ -81,6 +84,7 @@ class Autoscaler:
 
     def stop(self) -> None:
         self._stop.set()
+        self.runtime.autoscaler_attached = False
 
     def _loop(self) -> None:
         while not self._stop.wait(self.config.update_interval_s):

@@ -175,15 +175,13 @@ class Llama(nn.Module):
 
     def _attn_fn(self) -> Callable:
         cfg = self.config
-        if self.mesh is not None and any(
-                self.mesh.shape.get(a, 1) > 1
-                for a in ("dp", "fsdp", "tp", cfg.sp_axis)):
-            from ray_tpu.ops.attention import (
-                make_sharded_causal_attention,
-            )
-            return make_sharded_causal_attention(
-                self.mesh, seq_axis=cfg.sp_axis, impl=cfg.attn_impl)
-        return causal_attention
+        if self.mesh is None:
+            return causal_attention
+        # Which path (ring, the kernel under shard_map, the kernel
+        # bare) is the dispatch layer's call, made from the mesh.
+        from ray_tpu.ops.attention import make_sharded_causal_attention
+        return make_sharded_causal_attention(
+            self.mesh, seq_axis=cfg.sp_axis, impl=cfg.attn_impl)
 
     def _constrain(self, x):
         if self.mesh is None:

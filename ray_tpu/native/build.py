@@ -51,10 +51,11 @@ def ensure_built() -> str | None:
             os.unlink(tmp)
         except OSError:
             pass
-        stderr = getattr(e, "stderr", b"")
-        if stderr:
-            import sys
-            print(f"[ray_tpu.native] build failed:\n"
-                  f"{stderr.decode(errors='replace')[:2000]}",
-                  file=sys.stderr)
+        # Say so whatever the cause (no g++, a timeout, a compile
+        # error): callers carry on without the library.
+        import sys
+        stderr = getattr(e, "stderr", None) or b""
+        print(f"[ray_tpu.native] build failed: {type(e).__name__}: "
+              f"{e}\n{stderr.decode(errors='replace')[:2000]}",
+              file=sys.stderr)
         return None

@@ -195,6 +195,7 @@ def cluster_status(rt) -> dict:
         "actors": actor_counts,
         "workers": {"total": workers_total, "idle": idle},
         "autoscaler": {
+            "attached": rt.autoscaler_attached,
             "pending_demand": _demand_shapes(demand),
             "demand_count": len(demand),
             "explicit_requests": rt.explicit_resource_requests(),

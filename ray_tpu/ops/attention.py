@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.util.jax_compat import shard_map as _shard_map
-
 _NEG_INF = -1e30
 
 
@@ -57,6 +55,12 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     no SPMD partitioning rule): use make_sharded_causal_attention,
     which shard_maps over the mesh and sets ``force_flash`` for the
     per-device local block. Everything else takes the XLA path.
+
+    Without ``force_flash`` nothing says how many devices the program
+    spans, so the process's device count stands in for it: a caller
+    that knows its mesh — the models, whenever they are given one —
+    goes through make_sharded_causal_attention, which decides from the
+    mesh and not from the process.
 
     ``RAY_TPU_ATTN_KERNEL`` overrides the kernel choice (bench
     sweeps): "ours" | "jaxflash" (jax.experimental pallas flash) |
@@ -246,8 +250,15 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
                       if mesh.shape.get(a, 1) > 1)
         heads = (head_axis if mesh.shape.get(head_axis, 1) > 1
                  else None)
+        if mesh.size == 1:
+            # A one-device mesh is a one-device program whatever else
+            # the process can see (one chip of a four-chip host): the
+            # kernel runs bare, with no device-count guard.
+            return functools.partial(causal_attention, force_flash=True)
         if not batch and heads is None:
-            # Unsharded attention operands: plain local dispatch.
+            # Attention operands replicated over a multi-device mesh
+            # (pp- or ep-only): no axis to shard_map over, and the
+            # bare kernel has no SPMD rule, so this is the XLA path.
             def dense(q, k, v):
                 return causal_attention(q, k, v)
             return dense
@@ -257,9 +268,9 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
         # dense path.
         spec = P(batch if batch else None, None, heads, None)
         local = functools.partial(causal_attention, force_flash=True)
-        sharded = _shard_map(local, mesh=mesh,
-                             in_specs=(spec, spec, spec),
-                             out_specs=spec, check_vma=False)
+        sharded = jax.shard_map(local, mesh=mesh,
+                                in_specs=(spec, spec, spec),
+                                out_specs=spec, check_vma=False)
         n_batch = 1
         for a in batch:
             n_batch *= mesh.shape[a]
@@ -280,5 +291,5 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
     local_impl = (ulysses_attention if impl == "ulysses"
                   else ring_attention)
     fn = functools.partial(local_impl, axis_name=seq_axis)
-    return _shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec, check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)

@@ -199,16 +199,8 @@ def _vmem(shape):
 
 def _compiler_params():
     from jax.experimental.pallas import tpu as pltpu
-    # jax >= 0.6 renamed TPUCompilerParams -> CompilerParams.
-    params_cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    if params_cls is None:
-        return None
-    try:
-        return params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except TypeError:
-        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 # ---------------------------------------------------------------------------

@@ -111,16 +111,9 @@ def make_mesh(spec: MeshSpec | dict[str, int] | None = None,
         devices = devices[:total]
     # Auto axis types: we use classic pjit sharding propagation with
     # with_sharding_constraint (jax 0.9 defaults make_mesh to Explicit).
-    try:
-        auto = (jax.sharding.AxisType.Auto,) * len(names)
-        return jax.make_mesh(shape, names, devices=devices,
-                             axis_types=auto)
-    except (TypeError, AttributeError):
-        # older jax: no AxisType (0.4.x) and/or a make_mesh signature
-        # without devices/axis_types kwargs
-        import numpy as np
-        from jax.sharding import Mesh
-        return Mesh(np.asarray(devices).reshape(shape), names)
+    auto = (jax.sharding.AxisType.Auto,) * len(names)
+    return jax.make_mesh(shape, names, devices=devices,
+                         axis_types=auto)
 
 
 def local_mesh(**axes) -> "jax.sharding.Mesh":  # noqa: F821

@@ -122,6 +122,12 @@ class JaxTrainer:
         drain_restarts = 0
         while True:
             try:
+                if attempt == 0 and drain_restarts == 0:
+                    # Before the first gang only: a restart may find
+                    # its slice drained and the replacement on its way.
+                    why = _unmeetable_tpu_request(self.scaling)
+                    if why:
+                        raise _WorkerGroupError(why, None)
                 return self._mirror(trial_dir, remote_uri,
                                     self._fit_once(trial_dir,
                                                    restored))
@@ -151,7 +157,8 @@ class JaxTrainer:
                 if (exhausted and not drained) or drain_restarts > 100:
                     return self._mirror(trial_dir, remote_uri, Result(
                         metrics={}, checkpoint_dir=latest,
-                        path=trial_dir, error=e.error))
+                        path=trial_dir, metrics_history=e.history,
+                        error=e.error))
                 # Elastic slice restart from the latest checkpoint.
                 restored = latest
 
@@ -178,13 +185,18 @@ class JaxTrainer:
     # -- internals --
 
     def _fit_once(self, trial_dir: str, restored: str | None) -> Result:
-        group = WorkerGroup(
-            num_workers=self.scaling.num_workers,
-            resources_per_worker=self.scaling.worker_resources(),
-            placement_strategy=self.scaling.placement_strategy,
-        )
         latest_ckpt: str | None = restored
         history: list[dict] = []
+        try:
+            # A gang that is not placed in time is a worker-group
+            # failure like any other (FailureConfig).
+            group = WorkerGroup(
+                num_workers=self.scaling.num_workers,
+                resources_per_worker=self.scaling.worker_resources(),
+                placement_strategy=self.scaling.placement_strategy,
+            )
+        except TimeoutError as e:
+            raise _WorkerGroupError(str(e), latest_ckpt) from e
         try:
             group.barrier()
             if self.scaling.num_workers > 1 or self._setup_single_worker:
@@ -224,14 +236,17 @@ class JaxTrainer:
             while not all(done):
                 polls = group.run("poll", timeout=600)
                 for i, p in enumerate(polls):
-                    if p["error"]:
-                        raise _WorkerGroupError(p["error"], latest_ckpt)
+                    # Results first: what a worker reported before it
+                    # failed is kept on the error Result.
                     for r in p["results"]:
                         if r["rank"] == 0:
                             history.append(r["metrics"])
                             final_metrics = r["metrics"]
                         if r["checkpoint_dir"]:
                             latest_ckpt = r["checkpoint_dir"]
+                    if p["error"]:
+                        raise _WorkerGroupError(p["error"], latest_ckpt,
+                                                history)
                     done[i] = p["done"]
                 if not all(done):
                     time.sleep(0.05)
@@ -241,9 +256,31 @@ class JaxTrainer:
         except _WorkerGroupError:
             raise
         except Exception as e:  # noqa: BLE001 — actor/infra failure
-            raise _WorkerGroupError(str(e), latest_ckpt) from e
+            raise _WorkerGroupError(str(e), latest_ckpt, history) from e
         finally:
             group.shutdown()
+
+
+def _unmeetable_tpu_request(scaling: ScalingConfig) -> str | None:
+    """Why the gang's TPU request cannot be met, where that is known
+    without waiting: the alive nodes hold fewer chips than it asks for
+    and no autoscaler is attached that could add a slice. None where
+    it can be met, or may yet be."""
+    per_worker = scaling.worker_resources().get("TPU", 0)
+    want = scaling.num_workers * per_worker
+    if not want:
+        return None
+    import ray_tpu
+    from ray_tpu.util.state import cluster_status
+    total = ray_tpu.cluster_resources()
+    have = total.get("TPU", 0)
+    if want <= have or cluster_status()["autoscaler"]["attached"]:
+        return None
+    return (f"the worker group asks for {want:g} TPU chips "
+            f"({scaling.num_workers} workers x {per_worker:g}) but the "
+            f"cluster's nodes hold {have:g} (cluster_resources() = "
+            f"{total}) and no autoscaler is attached; chips come from "
+            f"core.accelerator.detect_tpu_chips() or init(num_tpus=...)")
 
 
 def _is_drain_interruption(error: str | None) -> bool:
@@ -296,7 +333,9 @@ def _latest_complete_checkpoint(
 
 
 class _WorkerGroupError(Exception):
-    def __init__(self, error: str, latest_ckpt: str | None):
+    def __init__(self, error: str, latest_ckpt: str | None,
+                 history: list[dict] | None = None):
         super().__init__(error)
         self.error = error
         self.latest_ckpt = latest_ckpt
+        self.history = history or []

@@ -10,6 +10,7 @@ polling — the same split as the reference's _TrainSession thread.
 
 from __future__ import annotations
 
+import queue
 import threading
 import traceback
 from typing import Any, Callable
@@ -124,20 +125,26 @@ class TrainWorker:
         return True
 
     def poll(self, max_results: int = 16) -> dict:
-        """Drain queued results; report completion/errors."""
+        """Drain queued results; report completion/errors. The end of
+        the loop is read BEFORE the drain and told only once the queue
+        is empty, so everything the loop reported reaches the trainer
+        ahead of its completion or its error."""
+        done, error = self._done.is_set(), self._error
         out = []
+        drained = True
         if self._session is not None:
             while len(out) < max_results:
                 try:
                     r = self._session.results.get_nowait()
-                except Exception:  # queue.Empty
+                except queue.Empty:
                     break
                 out.append({"metrics": r.metrics,
                             "checkpoint_dir": r.checkpoint_dir,
                             "rank": r.rank, "index": r.index})
+            drained = self._session.results.empty()
         return {"results": out,
-                "done": self._done.is_set(),
-                "error": self._error}
+                "done": done and drained,
+                "error": error if drained else None}
 
     def ping(self) -> str:
         return "ok"
@@ -188,9 +195,17 @@ class WorkerGroup:
                  env_vars: dict | None = None):
         self.num_workers = num_workers
         bundles = [dict(resources_per_worker) for _ in range(num_workers)]
+        # The group is always created, also where no node can place it
+        # yet: an unplaced bundle is the demand an autoscaler reads, and
+        # a slice that was drained may be on its way back.
         self.pg = ray_tpu.placement_group(bundles,
                                           strategy=placement_strategy)
-        self.pg.ready(timeout=120)
+        if not self.pg.ready(timeout=120):
+            ray_tpu.remove_placement_group(self.pg)
+            raise TimeoutError(
+                f"placement group {bundles} ({placement_strategy}) was "
+                f"not placed within 120 s; available_resources() = "
+                f"{ray_tpu.available_resources()}")
         strategy = PlacementGroupSchedulingStrategy(self.pg)
         self.workers = [
             TrainWorker.options(

@@ -3,7 +3,7 @@
 One implementation of: spawn the child in its OWN session, kill the
 whole process group on timeout (wedged jax threads survive a plain
 terminate), and scan stdout bottom-up for the last parseable JSON
-line. bench_watch, bench_sweep, and perf_snapshot all run children
+line. bench_sweep and perf_snapshot both run children
 under this exact contract — drift between hand-rolled copies is how
 kill/parse fixes get silently lost.
 """

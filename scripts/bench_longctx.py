@@ -19,19 +19,13 @@ import time
 
 
 def main() -> None:
-    import os
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          "/tmp/ray_tpu_jax_cache")
     import jax
     import jax.numpy as jnp
     import numpy as np
     import optax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.environ["JAX_COMPILATION_CACHE_DIR"])
-    except Exception:  # noqa: BLE001
-        pass
+    from ray_tpu.util import compile_cache
+    compile_cache.enable()
 
     from ray_tpu.models import GPT2, GPT2Config
     from ray_tpu.models.gpt2 import gpt2_loss_fn

@@ -402,6 +402,75 @@ def test_real_crash_still_consumes_max_failures(tmp_path, monkeypatch):
     assert res.error is not None          # budget consumed, surfaced
 
 
+def _tpu_loop(config):
+    import json
+    import tempfile
+
+    from ray_tpu.train import Checkpoint, get_context, report
+
+    ctx = get_context()
+    start = 0
+    if ctx.restored_checkpoint_dir:
+        with open(os.path.join(ctx.restored_checkpoint_dir,
+                               "state.json")) as f:
+            start = json.load(f)["step"] + 1
+    for i in range(start, config["steps"]):
+        d = tempfile.mkdtemp()
+        with open(os.path.join(d, "state.json"), "w") as f:
+            json.dump({"step": i}, f)
+        report({"step": i, "restored": bool(ctx.restored_checkpoint_dir)},
+               checkpoint=Checkpoint.from_directory(d))
+        if not ctx.restored_checkpoint_dir:
+            time.sleep(0.2)     # the first slice is slow enough to drain
+
+
+def test_tpu_gang_restarts_onto_a_slice_that_arrives_after_the_drain(
+        rt, tmp_path):
+    """The one TPU node is drained mid-run and its replacement comes up
+    only seconds later: while the cluster holds no chip the restarted
+    gang must wait on its placement group — the demand an autoscaler
+    reads — and resume from the checkpoint, with max_failures=0
+    untouched. A check of the chips alive right now would fail it."""
+    import threading
+
+    from ray_tpu import train
+    from ray_tpu.train.config import FailureConfig, RunConfig
+
+    first = rt.add_node({"CPU": 2.0, "TPU": 1.0})
+    trial = tmp_path / "tpu_drain"
+    seen = {}
+
+    def preempt_then_replace():
+        deadline = time.time() + 60
+        while time.time() < deadline and not any(
+                p.name.startswith("checkpoint_")
+                for p in trial.glob("*")):
+            time.sleep(0.05)
+        seen["drained"] = rt.drain_node(
+            first, reason="preemption notice", deadline_s=10,
+            remove=True)
+        seen["tpus_between"] = ray_tpu.cluster_resources().get("TPU", 0)
+        time.sleep(2.0)
+        seen["demand"] = rt.resource_demand()
+        rt.add_node({"CPU": 2.0, "TPU": 1.0})
+
+    chaos = threading.Thread(target=preempt_then_replace, daemon=True)
+    chaos.start()
+    result = train.JaxTrainer(
+        _tpu_loop, train_loop_config={"steps": 40},
+        scaling_config=train.ScalingConfig(
+            num_workers=1, tpu_chips_per_worker=1),
+        run_config=RunConfig(
+            name="tpu_drain", storage_path=str(tmp_path),
+            failure_config=FailureConfig(max_failures=0))).fit()
+    chaos.join(timeout=30)
+    assert seen["drained"] and seen["tpus_between"] == 0, seen
+    # The waiting gang was visible as demand while no node held a chip.
+    assert {"CPU": 1.0, "TPU": 1.0} in seen["demand"], seen
+    assert result.error is None, result.error
+    assert result.metrics == {"step": 39, "restored": True}
+
+
 # ---------------------------------------------------------------------------
 # serve: replicas leave a draining node ahead of the kill
 # ---------------------------------------------------------------------------

@@ -9,7 +9,6 @@ convergence is a release-scale test, not a CI one.
 """
 
 import numpy as np
-import pytest
 
 import ray_tpu
 from ray_tpu.rllib import Dreamer, DreamerConfig
@@ -177,12 +176,6 @@ def test_dreamer_end_to_end_and_checkpoint(tmp_path):
     actors, then a Checkpointable save/restore round-trip resumes at
     iteration+1 with identical params."""
     import jax
-
-    if not hasattr(jax.sharding, "AxisType"):
-        # jax 0.4.x: XLA CPU segfaults (not a clean error) compiling
-        # the grad-of-lifted-scan world-model update at this config
-        # size — a crash here would abort the whole pytest process.
-        pytest.skip("dreamer end-to-end crashes XLA CPU on jax 0.4.x")
 
     ray_tpu.init(num_cpus=4)
     try:
