@@ -1,0 +1,6 @@
+"""input: host milliseconds the loop waited in next(batches) per step, mean
+over the window. Moves step_ms_p90."""
+
+
+def read(run):
+    return run.window_host_ms_per_step("input")
