@@ -211,11 +211,16 @@ class GPT2(nn.Module):
         wpe = nn.Embed(cfg.seq_len, cfg.n_embd, name="wpe",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.01))
-        pos = jnp.arange(T)[None, :]
-        x = wte(tokens) + wpe(pos)
-        x = self._constrain(x)
-        if cfg.dropout > 0:
-            x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
+        # Program scopes (docs/observability.md): every operation of
+        # the step falls under ``embed``, ``blocks``, ``loss`` or
+        # ``optimizer``; flax puts the module names beneath them.
+        with jax.named_scope("embed"):
+            pos = jnp.arange(T)[None, :]
+            x = wte(tokens) + wpe(pos)
+            x = self._constrain(x)
+            if cfg.dropout > 0:
+                x = nn.Dropout(cfg.dropout)(
+                    x, deterministic=deterministic)
 
         attn_fn = self._attn_fn()
         block_cls = Block
@@ -223,11 +228,13 @@ class GPT2(nn.Module):
             block_cls = nn.remat(
                 Block, static_argnums=(2, 3),
                 policy=remat_policy(cfg.remat_policy))
-        for i in range(cfg.n_layer):
-            x = block_cls(cfg, name=f"h_{i}")(x, attn_fn, deterministic)
-            x = self._constrain(x)
-        x = nn.LayerNorm(epsilon=1e-5, name="ln_f", dtype=cfg.dtype,
-                         param_dtype=cfg.param_dtype)(x)
+        with jax.named_scope("blocks"):
+            for i in range(cfg.n_layer):
+                x = block_cls(cfg, name=f"h_{i}")(
+                    x, attn_fn, deterministic)
+                x = self._constrain(x)
+            x = nn.LayerNorm(epsilon=1e-5, name="ln_f", dtype=cfg.dtype,
+                             param_dtype=cfg.param_dtype)(x)
         if return_hidden:
             # Final hidden states for fused/chunked LM-head losses
             # that never materialize the full (B, S, vocab) logits.
@@ -236,10 +243,11 @@ class GPT2(nn.Module):
         # and f32 logits out. Operands are rounded to bf16 (small
         # precision trade, ~2^-8 relative) — accepted for full MXU
         # rate; only the accumulation is fp32.
-        logits = jnp.einsum(
-            "bte,ve->btv", x.astype(self.config.dtype),
-            wte.embedding.astype(self.config.dtype),
-            preferred_element_type=jnp.float32)
+        with jax.named_scope("loss"):
+            logits = jnp.einsum(
+                "bte,ve->btv", x.astype(self.config.dtype),
+                wte.embedding.astype(self.config.dtype),
+                preferred_element_type=jnp.float32)
         return logits
 
     def init_params(self, rng, batch_size: int = 2):
@@ -248,9 +256,9 @@ class GPT2(nn.Module):
         return self.init(rng, tokens)["params"]
 
 
+@jax.named_scope("loss")
 def cross_entropy_loss(logits, targets, ignore_index: int = -1):
     """Mean token cross-entropy; positions == ignore_index are masked."""
-    vocab = logits.shape[-1]
     logp = jax.nn.log_softmax(logits, axis=-1)
     mask = targets != ignore_index
     safe = jnp.where(mask, targets, 0)
@@ -259,7 +267,10 @@ def cross_entropy_loss(logits, targets, ignore_index: int = -1):
     return nll.sum() / jnp.maximum(mask.sum(), 1)
 
 
+# The ``loss`` scope is opened inside each half of the custom_vjp:
+# a scope around the call alone does not reach the backward ``while``.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+@jax.named_scope("loss")
 def _chunked_ce_core(rows_c, emb, tgt_c, ignore_index):
     (tot, cnt), _ = _chunked_ce_fwd_scan(rows_c, emb, tgt_c,
                                          ignore_index)
@@ -299,6 +310,7 @@ def _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index):
         (rows_c, tgt_c), unroll=_ce_unroll())
 
 
+@jax.named_scope("loss")
 def _chunked_ce_core_fwd(rows_c, emb, tgt_c, ignore_index):
     (tot, cnt), lse_c = _chunked_ce_fwd_scan(rows_c, emb, tgt_c,
                                              ignore_index)
@@ -306,6 +318,7 @@ def _chunked_ce_core_fwd(rows_c, emb, tgt_c, ignore_index):
     return loss, (rows_c, emb, tgt_c, lse_c, cnt)
 
 
+@jax.named_scope("loss")
 def _chunked_ce_core_bwd(ignore_index, res, g):
     # Hand-written backward: recompute each chunk's logits but REUSE
     # the saved log-sum-exp (a jax.checkpoint formulation re-runs the
@@ -340,6 +353,7 @@ def _chunked_ce_core_bwd(ignore_index, res, g):
 _chunked_ce_core.defvjp(_chunked_ce_core_fwd, _chunked_ce_core_bwd)
 
 
+@jax.named_scope("loss")
 def chunked_cross_entropy(hidden, embedding, targets,
                           ignore_index: int = -1,
                           chunk_size: int = 2048):

@@ -73,23 +73,31 @@ class ResNet(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = False):
         cfg = self.config
-        x = x.astype(cfg.dtype)
-        x = nn.Conv(cfg.width, (7, 7), strides=(2, 2), use_bias=False,
-                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                    name="conv_init")(x)
-        x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
-                         epsilon=1e-5, dtype=cfg.dtype,
-                         param_dtype=cfg.param_dtype, name="bn_init")(x)
-        x = nn.relu(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
-        for i, n_blocks in enumerate(cfg.stage_sizes):
-            for j in range(n_blocks):
-                strides = 2 if i > 0 and j == 0 else 1
-                x = Bottleneck(cfg.width * 2 ** i, strides, cfg,
-                               name=f"stage{i}_block{j}")(x, train)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(cfg.num_classes, dtype=jnp.float32,
-                     param_dtype=cfg.param_dtype, name="classifier")(x)
+        # Program scopes (docs/observability.md): the stem is this
+        # model's ``embed``, the stages its ``blocks``, pooling and the
+        # classifier belong to ``loss`` with the cross-entropy.
+        with jax.named_scope("embed"):
+            x = x.astype(cfg.dtype)
+            x = nn.Conv(cfg.width, (7, 7), strides=(2, 2),
+                        use_bias=False, dtype=cfg.dtype,
+                        param_dtype=cfg.param_dtype, name="conv_init")(x)
+            x = nn.BatchNorm(use_running_average=not train,
+                             momentum=0.9, epsilon=1e-5, dtype=cfg.dtype,
+                             param_dtype=cfg.param_dtype,
+                             name="bn_init")(x)
+            x = nn.relu(x)
+            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+        with jax.named_scope("blocks"):
+            for i, n_blocks in enumerate(cfg.stage_sizes):
+                for j in range(n_blocks):
+                    strides = 2 if i > 0 and j == 0 else 1
+                    x = Bottleneck(cfg.width * 2 ** i, strides, cfg,
+                                   name=f"stage{i}_block{j}")(x, train)
+        with jax.named_scope("loss"):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(cfg.num_classes, dtype=jnp.float32,
+                         param_dtype=cfg.param_dtype,
+                         name="classifier")(x)
         return x
 
     def init_variables(self, rng, image_size: int = 224,
@@ -106,9 +114,10 @@ def resnet_loss_fn(model: ResNet):
         logits, mutated = model.apply(
             {"params": params, "batch_stats": batch_stats},
             batch["image"], train=True, mutable=["batch_stats"])
-        onehot = jax.nn.one_hot(batch["label"], logits.shape[-1])
-        loss = -jnp.mean(
-            jnp.sum(onehot * jax.nn.log_softmax(logits), axis=-1))
+        with jax.named_scope("loss"):
+            onehot = jax.nn.one_hot(batch["label"], logits.shape[-1])
+            loss = -jnp.mean(
+                jnp.sum(onehot * jax.nn.log_softmax(logits), axis=-1))
         return loss, mutated["batch_stats"]
 
     return loss_fn

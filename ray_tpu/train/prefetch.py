@@ -25,14 +25,41 @@ in ``make_train_step``) can reuse the buffers.
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
 from typing import Any, Callable, Iterable, Iterator
 
-__all__ = ["DevicePrefetcher", "prefetch_to_device"]
+from ray_tpu.util.tracing import annotation
+
+__all__ = ["DevicePrefetcher", "prefetch_to_device", "collect_counters"]
 
 _SENTINEL = object()
+
+# The ``counters`` of every prefetcher made while a collector is open
+# (``fit()``'s worker opens one around the user's loop). The dicts
+# outlive their prefetchers and hold no batch.
+_collected: list[dict] | None = None
+
+
+@contextlib.contextmanager
+def collect_counters():
+    """Yields a function that gives the counters of every prefetcher
+    this process made since, summed: ``{"input.stall_s": ...}``."""
+    global _collected
+    made = _collected = []
+
+    def totals() -> dict[str, float]:
+        out: dict[str, float] = {}
+        for counters in made:
+            for k, v in counters.items():
+                out[f"input.{k}"] = out.get(f"input.{k}", 0) + v
+        return out
+    try:
+        yield totals
+    finally:
+        _collected = None
 
 
 class DevicePrefetcher:
@@ -53,9 +80,14 @@ class DevicePrefetcher:
         buffering; larger depths absorb burstier sources at the cost
         of live-batch memory.
 
-    Stats (for bench/debug): ``batches``, ``stall_s`` (cumulative time
-    the consumer blocked waiting — ~0 means input is fully hidden),
-    ``produce_s`` (cumulative background production+placement time).
+    ``counters``, always on (cumulative; inside ``fit()`` summed onto
+    the ``train.worker.loop`` span): ``batches``; ``stall_s``, the
+    seconds the consumer blocked waiting (~0 means the input is fully
+    hidden; ``bench.py`` reads it); ``source_s`` and ``place_s``, the
+    producer's seconds in ``next(source)`` and in ``place``. Under a
+    device profile the same three are the spans ``train.input.wait``
+    (consumer thread), ``train.input.source`` and ``train.input.place``
+    (this prefetcher's thread).
     """
 
     def __init__(self, source: Iterable | Iterator,
@@ -69,9 +101,10 @@ class DevicePrefetcher:
         self._stop = threading.Event()
         self._err: BaseException | None = None
         self.depth = depth
-        self.batches = 0
-        self.stall_s = 0.0
-        self.produce_s = 0.0
+        self.counters = {"batches": 0, "stall_s": 0.0, "source_s": 0.0,
+                         "place_s": 0.0}
+        if _collected is not None:
+            _collected.append(self.counters)
         self._thread = threading.Thread(
             target=self._run, daemon=True, name="device_prefetch")
         self._thread.start()
@@ -79,16 +112,21 @@ class DevicePrefetcher:
     # -- background producer --
 
     def _run(self) -> None:
+        counters = self.counters
         try:
             while not self._stop.is_set():
                 t0 = time.perf_counter()
                 try:
-                    batch = next(self._source)
+                    with annotation("train.input.source"):
+                        batch = next(self._source)
                 except StopIteration:
                     break
+                t1 = time.perf_counter()
+                counters["source_s"] += t1 - t0
                 if self._place is not None:
-                    batch = self._place(batch)
-                self.produce_s += time.perf_counter() - t0
+                    with annotation("train.input.place"):
+                        batch = self._place(batch)
+                    counters["place_s"] += time.perf_counter() - t1
                 # Bounded put, polling the stop flag so close() never
                 # deadlocks against a full queue.
                 while not self._stop.is_set():
@@ -114,15 +152,19 @@ class DevicePrefetcher:
 
     def __next__(self):
         t0 = time.perf_counter()
-        item = self._q.get()
-        self.stall_s += time.perf_counter() - t0
+        with annotation("train.input.wait"):
+            item = self._q.get()
+        self.counters["stall_s"] += time.perf_counter() - t0
         if item is _SENTINEL:
             if self._err is not None:
                 err, self._err = self._err, None
                 raise err
             raise StopIteration
-        self.batches += 1
+        self.counters["batches"] += 1
         return item
+
+    batches = property(lambda self: self.counters["batches"])
+    stall_s = property(lambda self: self.counters["stall_s"])
 
     def close(self) -> None:
         """Stop the producer and release queued batches."""

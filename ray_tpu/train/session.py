@@ -14,8 +14,11 @@ from __future__ import annotations
 import os
 import queue
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Any
+
+from ray_tpu.util.tracing import annotation
 
 
 @dataclass
@@ -39,15 +42,29 @@ class ReportedResult:
     checkpoint_dir: str | None
     rank: int
     index: int
+    t_report: float = 0.0   # time.monotonic() in the worker at report()
+
+
+def _step_time_buckets() -> list[float]:
+    """26 boundaries from 5 ms to 120 s at one ratio (just under 1.5):
+    two step times a factor 1.5 apart never share a bucket."""
+    ratio = (120.0 / 0.005) ** (1 / 25)
+    return [float(f"{0.005 * ratio ** i:.4g}") for i in range(26)]
 
 
 _session: "_TrainSession | None" = None
 
 
 class _TrainSession:
-    def __init__(self, context: TrainContext):
+    def __init__(self, context: TrainContext,
+                 trace_ctx: tuple[str, str] | None = None):
         self.context = context
         self.results: "queue.Queue[ReportedResult]" = queue.Queue()
+        # The fit's trace (trace id, the driver's ``train.fit`` span):
+        # this worker's train-path spans collect here and ride the poll
+        # reply that tells the loop's end.
+        self.trace_ctx = trace_ctx
+        self.spans: list = []
         # Seed past the restored checkpoint so checkpoint directory
         # names stay monotonic across slice restarts.
         self._index = checkpoint_index(context.restored_checkpoint_dir) + 1
@@ -62,7 +79,7 @@ class _TrainSession:
         self._m_step_time = Histogram(
             "ray_tpu_train_step_time_s",
             "seconds between successive train.report() calls",
-            boundaries=[0.01, 0.05, 0.1, 0.5, 1, 5, 30, 120],
+            boundaries=_step_time_buckets(),
             tag_keys=("rank",),
         ).set_default_tags(tags)
         self._m_steps = Counter(
@@ -73,31 +90,42 @@ class _TrainSession:
 
     def report(self, metrics: dict[str, Any],
                checkpoint: "Checkpoint | None" = None) -> None:
-        import time as _time
-        now = _time.perf_counter()
-        if self._last_report_ts is not None:
-            self._m_step_time.observe(now - self._last_report_ts)
-        self._last_report_ts = now
-        self._m_steps.inc()
-        ckpt_dir = None
-        if checkpoint is not None:
-            ckpt_dir = checkpoint.persist(
-                self.context.trial_dir,
-                index=self._index,
-                rank=self.context.world_rank)
-        with self._lock:
-            r = ReportedResult(metrics=dict(metrics),
-                               checkpoint_dir=ckpt_dir,
-                               rank=self.context.world_rank,
-                               index=self._index)
-            self._index += 1
-        self.results.put(r)
+        with annotation("train.report"):
+            now = time.monotonic()
+            if self._last_report_ts is not None:
+                self._m_step_time.observe(now - self._last_report_ts)
+            self._last_report_ts = now
+            self._m_steps.inc()
+            ckpt_dir = None
+            if checkpoint is not None:
+                ckpt_dir = checkpoint.persist(
+                    self.context.trial_dir,
+                    index=self._index,
+                    rank=self.context.world_rank)
+            with self._lock:
+                r = ReportedResult(metrics=dict(metrics),
+                                   checkpoint_dir=ckpt_dir,
+                                   rank=self.context.world_rank,
+                                   index=self._index, t_report=now)
+                self._index += 1
+            self.results.put(r)
 
 
-def init_session(context: TrainContext) -> _TrainSession:
+def init_session(context: TrainContext,
+                 trace_ctx: tuple[str, str] | None = None
+                 ) -> _TrainSession:
     global _session
-    _session = _TrainSession(context)
+    _session = _TrainSession(context, trace_ctx)
     return _session
+
+
+def trace_target() -> dict:
+    """Where a train-path span recorded in this process belongs
+    (keywords of ``tracing.train_span``): in the live session's list,
+    under the fit's trace — or, outside a fit, in the process ring."""
+    if _session is None:
+        return {}
+    return {"parent": _session.trace_ctx, "sink": _session.spans}
 
 
 def shutdown_session() -> None:

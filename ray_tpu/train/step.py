@@ -10,13 +10,18 @@ Python (SURVEY.md §3.4); here the collective IS part of the program.
 
 from __future__ import annotations
 
+import threading
+import time
 from typing import Any, Callable
 
 import jax
+import jax.monitoring
 import jax.numpy as jnp
 from flax import struct
 
 from ray_tpu.parallel.sharding import place_params
+from ray_tpu.train import session
+from ray_tpu.util import tracing
 
 
 @struct.dataclass
@@ -50,17 +55,97 @@ def _step_body(loss_fn, optimizer, has_extra, grad_norm):
         else:
             loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
             new_extra = state.extra
-        updates, new_opt = optimizer.update(grads, state.opt_state,
-                                            state.params)
         import optax
-        new_params = optax.apply_updates(state.params, updates)
         metrics = {"loss": loss}
-        if grad_norm:
-            metrics["grad_norm"] = optax.global_norm(grads)
-        new_state = TrainState(step=state.step + 1, params=new_params,
-                               opt_state=new_opt, extra=new_extra)
+        # The forward and backward carry the model's scopes; everything
+        # after them is the ``optimizer`` scope (docs/observability.md).
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, state.opt_state,
+                                                state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            if grad_norm:
+                metrics["grad_norm"] = optax.global_norm(grads)
+            new_state = TrainState(step=state.step + 1,
+                                   params=new_params, opt_state=new_opt,
+                                   extra=new_extra)
         return new_state, metrics
     return step
+
+
+# jax.monitoring events that make up a compile, by the ``kind`` of the
+# ``train.compile`` span each becomes. ``backend`` is the XLA compile or,
+# on a persistent-cache hit, the load that ``cache_load`` times alone.
+_COMPILE_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_thread = threading.local()     # depth of open phases; cache outcome
+_listening = False              # and load interval awaiting their backend
+
+
+def _take(name: str):
+    """This thread's pending value of that name, handed over once."""
+    value = getattr(_thread, name, None)
+    setattr(_thread, name, None)
+    return value
+
+
+def _on_compile_begins(event: str, _start: float, **_) -> None:
+    if event in _COMPILE_KINDS:     # jax tells the three phases' starts
+        _thread.depth = getattr(_thread, "depth", 0) + 1
+
+
+def _on_compile_seconds(event: str, seconds: float, **kw) -> None:
+    kind = _COMPILE_KINDS.get(event)
+    if kind is None:
+        return
+    now = time.monotonic()
+    if kind == "cache_load":
+        # jax does not say whose load it is: the backend phase that
+        # holds it does, when it ends.
+        _thread.load = (now - seconds, now)
+        return
+    # Every jitted function called while another is traced or lowered
+    # (the jnp functions) reports a trace of its own: the outermost
+    # span holds them all.
+    _thread.depth = max(0, getattr(_thread, "depth", 1) - 1)
+    if _thread.depth:
+        return
+    attributes = {"kind": kind, "fun_name": kw.get("fun_name", "")}
+    target = session.trace_target()
+    if kind == "backend":
+        outcome, load = _take("cache"), _take("load")
+        if outcome:
+            attributes["cache"] = outcome
+        if load:
+            tracing.record_train_span(
+                "train.compile", *load,
+                {**attributes, "kind": "cache_load"}, **target)
+    tracing.record_train_span("train.compile", now - seconds, now,
+                              attributes, **target)
+
+
+def _on_cache_event(event: str, **_) -> None:
+    if event in _CACHE_EVENTS:      # told on the backend span that follows
+        _thread.cache = _CACHE_EVENTS[event]
+
+
+def _listen_for_compiles() -> None:
+    """Installed once a process, the first time a step is built: from
+    then on every trace, lowering, compile and cache load of the
+    process is a ``train.compile`` span (a dozen a fit; a step that
+    recompiles in the middle of a run shows up by name)."""
+    global _listening
+    if not _listening:
+        _listening = True
+        jax.monitoring.register_scalar_listener(_on_compile_begins)
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_seconds)
+        jax.monitoring.register_event_listener(_on_cache_event)
 
 
 def _donate_argnums(donate: bool, donate_batch: bool) -> tuple:
@@ -90,6 +175,7 @@ def make_train_step(loss_fn: Callable, optimizer,
     batch (the usual LM token case) XLA ignores it with a warning,
     which is why it is off by default.
     """
+    _listen_for_compiles()
     step = _step_body(loss_fn, optimizer, has_extra, grad_norm)
     return jax.jit(step,
                    donate_argnums=_donate_argnums(donate, donate_batch))
@@ -107,6 +193,7 @@ def make_multi_train_step(loss_fn: Callable, optimizer,
     steps, exactly like queueing K async dispatches. Returns
     (state, metrics_of_last_step). ``donate_batch`` donates the batch
     stack buffers too (see :func:`make_train_step`)."""
+    _listen_for_compiles()
     body = _step_body(loss_fn, optimizer, has_extra, grad_norm)
 
     def multi(state: TrainState, batches):

@@ -17,6 +17,7 @@ report()/checkpoint → poll. Differences by design:
 
 from __future__ import annotations
 
+import json
 import os
 import time
 from dataclasses import dataclass, field
@@ -24,6 +25,7 @@ from typing import Any, Callable
 
 from ray_tpu.train.config import RunConfig, ScalingConfig
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.util import tracing
 
 
 @dataclass
@@ -36,6 +38,10 @@ class Result:
     # When RunConfig.storage_path is a URI: the mirrored location of
     # the final checkpoint in remote storage.
     remote_checkpoint_uri: str | None = None
+    # The fit's phases on the driver and in every worker, under one
+    # trace id (``tracing.Span.to_dict``; docs/observability.md). Also
+    # in ``<path>/fit_trace.json`` and in ``tracing.get_spans()``.
+    spans: list[dict] = field(default_factory=list)
 
     @property
     def checkpoint(self):
@@ -110,6 +116,27 @@ class JaxTrainer:
                                      name)
         os.makedirs(trial_dir, exist_ok=True)
 
+        # This fit's spans, the driver's phases and (from the poll
+        # reply that tells each worker's end) the workers';
+        # ``_fit_once`` adds to them through the instance.
+        spans: list[tracing.Span] = []
+        self._spans = spans
+        with tracing.train_span("train.fit", {
+                "trial_dir": trial_dir,
+                "workers": self.scaling.num_workers,
+                "chips": self.scaling.num_workers
+                * self.scaling.worker_resources().get("TPU", 0)},
+                sink=spans):
+            result = self._fit_with_restarts(trial_dir)
+        result.spans = [s.to_dict() for s in spans]
+        # Into the process ring, so that tracing.get_spans() holds the
+        # fit after it returns and after ray_tpu.shutdown().
+        tracing.get_tracer().add_spans(result.spans)
+        with open(os.path.join(trial_dir, "fit_trace.json"), "w") as f:
+            json.dump(tracing.chrome_events(spans), f)
+        return self._mirror(trial_dir, remote_uri, result)
+
+    def _fit_with_restarts(self, trial_dir: str) -> Result:
         max_failures = self.run_config.failure_config.max_failures
         attempt = 0
         restored: str | None = None
@@ -128,9 +155,7 @@ class JaxTrainer:
                     why = _unmeetable_tpu_request(self.scaling)
                     if why:
                         raise _WorkerGroupError(why, None)
-                return self._mirror(trial_dir, remote_uri,
-                                    self._fit_once(trial_dir,
-                                                   restored))
+                return self._fit_once(trial_dir, restored)
             except _WorkerGroupError as e:
                 # A drain-triggered interruption (the gang's node was
                 # preempted/scaled down WITH notice — worker deaths
@@ -155,10 +180,10 @@ class JaxTrainer:
                 exhausted = (max_failures >= 0
                              and attempt > max_failures)
                 if (exhausted and not drained) or drain_restarts > 100:
-                    return self._mirror(trial_dir, remote_uri, Result(
+                    return Result(
                         metrics={}, checkpoint_dir=latest,
                         path=trial_dir, metrics_history=e.history,
-                        error=e.error))
+                        error=e.error)
                 # Elastic slice restart from the latest checkpoint.
                 restored = latest
 
@@ -187,69 +212,101 @@ class JaxTrainer:
     def _fit_once(self, trial_dir: str, restored: str | None) -> Result:
         latest_ckpt: str | None = restored
         history: list[dict] = []
+        spans: list[tracing.Span] = self._spans
+
+        def phase(name: str, attributes: dict | None = None):
+            return tracing.train_span(f"train.fit.{name}", attributes,
+                                      sink=spans)
+
+        group = None
         try:
-            # A gang that is not placed in time is a worker-group
-            # failure like any other (FailureConfig).
-            group = WorkerGroup(
-                num_workers=self.scaling.num_workers,
-                resources_per_worker=self.scaling.worker_resources(),
-                placement_strategy=self.scaling.placement_strategy,
-            )
-        except TimeoutError as e:
-            raise _WorkerGroupError(str(e), latest_ckpt) from e
-        try:
-            group.barrier()
+            # Placement group, actor creation, worker boot and imports.
+            with phase("gang_start"):
+                try:
+                    # A gang that is not placed in time is a
+                    # worker-group failure like any other
+                    # (FailureConfig).
+                    group = WorkerGroup(
+                        num_workers=self.scaling.num_workers,
+                        resources_per_worker=(
+                            self.scaling.worker_resources()),
+                        placement_strategy=(
+                            self.scaling.placement_strategy),
+                    )
+                except TimeoutError as e:
+                    raise _WorkerGroupError(str(e), latest_ckpt) from e
+                group.barrier()
             if self.scaling.num_workers > 1 or self._setup_single_worker:
-                # Rank 0 advertises the rendezvous point from its own
-                # (possibly remote) host — the driver's loopback means
-                # nothing to a gang spanning node daemons.
-                coordinator = group.coordinator()
-                payload = coordinator
-                extra = getattr(self, "_backend_setup_extra", None)
-                if extra:
-                    # backend knobs (e.g. TorchConfig.timeout_s) ride
-                    # the rendezvous payload
-                    payload = (coordinator, extra)
-                group.run(self._backend_setup, payload,
-                          timeout=120)
-            ctx_kwargs = {
-                "experiment_name": os.path.basename(trial_dir),
-                "storage_path": self.run_config.storage_path,
-                "trial_dir": trial_dir,
-                "restored_checkpoint_dir": restored,
-            }
-            if self.datasets:
-                # DataConfig.datasets_to_split: "all" or a list of
-                # names; unsplit datasets replicate — every worker
-                # iterates the full stream (reference: DataConfig).
-                to_split = self.dataset_config.datasets_to_split
-                ctx_kwargs["dataset_shards_all"] = {
-                    name: (ds.streaming_split(group.num_workers)
-                           if (to_split == "all" or name in to_split)
-                           else [ds.iterator()] * group.num_workers)
-                    for name, ds in self.datasets.items()}
-            group.run("start_loop", (self.train_loop, self.loop_config),
-                      ctx_kwargs, timeout=120)
+                with phase("backend_setup"):
+                    # Rank 0 advertises the rendezvous point from its
+                    # own (possibly remote) host — the driver's
+                    # loopback means nothing to a gang spanning node
+                    # daemons.
+                    coordinator = group.coordinator()
+                    payload = coordinator
+                    extra = getattr(self, "_backend_setup_extra", None)
+                    if extra:
+                        # backend knobs (e.g. TorchConfig.timeout_s)
+                        # ride the rendezvous payload
+                        payload = (coordinator, extra)
+                    group.run(self._backend_setup, payload,
+                              timeout=120)
+            with phase("start_loop") as span:
+                ctx_kwargs = {
+                    "experiment_name": os.path.basename(trial_dir),
+                    "storage_path": self.run_config.storage_path,
+                    "trial_dir": trial_dir,
+                    "restored_checkpoint_dir": restored,
+                    # the workers' spans go under the fit's root
+                    "trace_ctx": (span.trace_id, span.parent_id),
+                }
+                if self.datasets:
+                    # DataConfig.datasets_to_split: "all" or a list of
+                    # names; unsplit datasets replicate — every worker
+                    # iterates the full stream (reference: DataConfig).
+                    to_split = self.dataset_config.datasets_to_split
+                    ctx_kwargs["dataset_shards_all"] = {
+                        name: (ds.streaming_split(group.num_workers)
+                               if (to_split == "all" or name in to_split)
+                               else [ds.iterator()] * group.num_workers)
+                        for name, ds in self.datasets.items()}
+                group.run("start_loop",
+                          (self.train_loop, self.loop_config),
+                          ctx_kwargs, timeout=120)
 
             final_metrics: dict = {}
             done = [False] * group.num_workers
-            while not all(done):
-                polls = group.run("poll", timeout=600)
-                for i, p in enumerate(polls):
-                    # Results first: what a worker reported before it
-                    # failed is kept on the error Result.
-                    for r in p["results"]:
-                        if r["rank"] == 0:
-                            history.append(r["metrics"])
-                            final_metrics = r["metrics"]
-                        if r["checkpoint_dir"]:
-                            latest_ckpt = r["checkpoint_dir"]
-                    if p["error"]:
-                        raise _WorkerGroupError(p["error"], latest_ckpt,
-                                                history)
-                    done[i] = p["done"]
-                if not all(done):
-                    time.sleep(0.05)
+            # report_to_poll: from report() in a worker to the poll
+            # that drained it, on that worker's clock.
+            with phase("poll", {"polls": 0, "reports": 0,
+                                "report_to_poll_s_sum": 0.0,
+                                "report_to_poll_s_max": 0.0}) as span:
+                seen = span.attributes
+                while not all(done):
+                    polls = group.run("poll", timeout=600)
+                    seen["polls"] += 1
+                    for i, p in enumerate(polls):
+                        # Results first: what a worker reported before
+                        # it failed is kept on the error Result.
+                        for r in p["results"]:
+                            if r["rank"] == 0:
+                                history.append(r["metrics"])
+                                final_metrics = r["metrics"]
+                            if r["checkpoint_dir"]:
+                                latest_ckpt = r["checkpoint_dir"]
+                            seen["reports"] += 1
+                            seen["report_to_poll_s_sum"] += r["waited_s"]
+                            seen["report_to_poll_s_max"] = max(
+                                seen["report_to_poll_s_max"],
+                                r["waited_s"])
+                        spans.extend(tracing.Span(**d)
+                                     for d in p.get("spans", ()))
+                        if p["error"]:
+                            raise _WorkerGroupError(
+                                p["error"], latest_ckpt, history)
+                        done[i] = p["done"]
+                    if not all(done):
+                        time.sleep(0.05)
             return Result(metrics=final_metrics,
                           checkpoint_dir=latest_ckpt, path=trial_dir,
                           metrics_history=history)
@@ -258,7 +315,9 @@ class JaxTrainer:
         except Exception as e:  # noqa: BLE001 — actor/infra failure
             raise _WorkerGroupError(str(e), latest_ckpt, history) from e
         finally:
-            group.shutdown()
+            if group is not None:
+                with phase("shutdown"):
+                    group.shutdown()
 
 
 def _unmeetable_tpu_request(scaling: ScalingConfig) -> str | None:

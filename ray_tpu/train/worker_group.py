@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 import traceback
 from typing import Any, Callable
 
@@ -19,6 +20,8 @@ import ray_tpu
 from ray_tpu.core.placement_group import (
     PlacementGroupSchedulingStrategy,
 )
+from ray_tpu.train.prefetch import collect_counters
+from ray_tpu.util import tracing
 
 
 @ray_tpu.remote
@@ -30,6 +33,7 @@ class TrainWorker:
         os.environ.update(env_vars)
         self.rank = rank
         self.world_size = world_size
+        self._t_init = time.monotonic()
         self._thread: threading.Thread | None = None
         self._done = threading.Event()
         self._error: str | None = None
@@ -100,20 +104,33 @@ class TrainWorker:
         shards = ({name: lst[self.rank]
                    for name, lst in shards_all.items()}
                   if shards_all else {})
+        # The fit's trace: (trace id, the driver's ``train.fit`` span).
+        trace_ctx = context_kwargs.pop("trace_ctx", None)
         ctx = TrainContext(world_rank=self.rank,
                            world_size=self.world_size,
                            local_rank=self.rank,
                            loop_config=loop_config or {},
                            dataset_shards=shards,
                            **context_kwargs)
-        self._session = init_session(ctx)
+        session = self._session = init_session(ctx, trace_ctx)
+        target = {"parent": trace_ctx, "sink": session.spans}
+        tracing.record_train_span(
+            "train.worker.boot", self._t_init, time.monotonic(),
+            {"rank": self.rank}, **target)
 
         def run():
             try:
-                if _takes_config(fn):
-                    fn(loop_config or {})
-                else:
-                    fn()
+                with collect_counters() as input_totals, \
+                        tracing.train_span("train.worker.loop",
+                                           {"rank": self.rank},
+                                           **target) as span:
+                    try:
+                        if _takes_config(fn):
+                            fn(loop_config or {})
+                        else:
+                            fn()
+                    finally:
+                        span.attributes.update(input_totals())
             except BaseException:  # noqa: BLE001
                 self._error = traceback.format_exc()
             finally:
@@ -132,6 +149,7 @@ class TrainWorker:
         done, error = self._done.is_set(), self._error
         out = []
         drained = True
+        now = time.monotonic()
         if self._session is not None:
             while len(out) < max_results:
                 try:
@@ -140,11 +158,22 @@ class TrainWorker:
                     break
                 out.append({"metrics": r.metrics,
                             "checkpoint_dir": r.checkpoint_dir,
-                            "rank": r.rank, "index": r.index})
+                            "rank": r.rank, "index": r.index,
+                            # report() to this poll, on this worker's
+                            # clock
+                            "waited_s": now - r.t_report})
             drained = self._session.results.empty()
-        return {"results": out,
-                "done": done and drained,
-                "error": error if drained else None}
+        reply = {"results": out,
+                 "done": done and drained,
+                 "error": error if drained else None}
+        if done and drained and self._session is not None:
+            # The first reply that tells the end carries this worker's
+            # train-path spans, handed over once: the trainer polls a
+            # finished worker again until the slowest is done.
+            spans = self._session.spans
+            reply["spans"] = [s.to_dict() for s in spans]
+            del spans[:len(reply["spans"])]
+        return reply
 
     def ping(self) -> str:
         return "ok"

@@ -12,6 +12,15 @@ workers. Export as a span list or Chrome-trace JSON (the same
 
 Device profiling: ``profile_device()`` wraps ``jax.profiler.trace``
 (the nsight-plugin analog for TPU — SURVEY.md §5.1 TPU mapping).
+
+The train path (``train_span``, ``record_train_span``, ``annotation``;
+docs/observability.md) needs no ``enable()``: the dozen phases of one
+``fit()`` are always recorded, on two clocks. ``time.monotonic()`` is
+the clock every process of one host shares, the one a benchmark's own
+stamps are on; a ``jax.profiler.TraceAnnotation`` of the same name puts
+the span on the host plane of a running device profile, on the
+profiler's clock beside the device operations. What happens every step
+is an ``annotation`` alone: nothing is kept unless a profile runs.
 """
 
 from __future__ import annotations
@@ -47,6 +56,10 @@ class Span:
     end: float = 0.0
     attributes: dict = field(default_factory=dict)
     process: str = ""
+    # time.monotonic() at both ends; 0.0 on the control plane's and
+    # Serve's spans, which stamp time.time() alone.
+    mono_start: float = 0.0
+    mono_end: float = 0.0
 
     def to_dict(self) -> dict:
         return {
@@ -55,6 +68,7 @@ class Span:
             "start": self.start, "end": self.end,
             "attributes": dict(self.attributes),
             "process": self.process,
+            "mono_start": self.mono_start, "mono_end": self.mono_end,
         }
 
 
@@ -262,6 +276,125 @@ def get_spans(trace_id: str | None = None):
 
 def chrome_trace() -> list[dict]:
     return _tracer.chrome_trace()
+
+
+# -- the train path ---------------------------------------------------
+
+_TraceAnnotation = None
+
+
+def annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation``: a span that exists only in
+    a running device profile (one atomic read otherwise). For what
+    happens every step — nothing is recorded on the Python side."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:    # jax is not imported at start-up
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
+
+
+# The train-path span open in this thread: what the next one nests
+# under (``_current`` may hold a task's or a request's span instead).
+_train_current: contextvars.ContextVar = contextvars.ContextVar(
+    "ray_tpu_train_span", default=None)
+
+
+def _new_train_span(name: str, attributes: dict | None,
+                    parent: tuple[str, str] | None) -> Span:
+    """A span under the train-path span open in this thread, else
+    under ``parent`` (trace id, span id), else under the thread's
+    current span of the control plane, else the root of a new trace.
+    So a task's span around the call (tracing enabled) never takes a
+    worker's spans out of their fit's trace."""
+    cur = _train_current.get() or (None if parent else _current.get())
+    if cur is not None:
+        parent = (cur.trace_id, cur.span_id)
+    trace_id, parent_id = parent or (uuid.uuid4().hex[:16], None)
+    return Span(name=name, trace_id=trace_id,
+                span_id=uuid.uuid4().hex[:16], parent_id=parent_id,
+                start=0.0, attributes=dict(attributes or {}),
+                process=f"pid:{os.getpid()}")
+
+
+def _keep(span: Span, sink: list | None) -> None:
+    if sink is not None:
+        sink.append(span)
+    else:
+        with _tracer._lock:
+            _tracer._append_locked(span)
+
+
+@contextlib.contextmanager
+def train_span(name: str, attributes: dict | None = None, *,
+               parent: tuple[str, str] | None = None,
+               sink: list | None = None):
+    """Record one phase of the train path, whether or not tracing is
+    enabled: both clocks, and an annotation for a running profile.
+    The finished span goes to ``sink`` (a worker's spans ride its last
+    poll reply) or, without one, into the process tracer's ring."""
+    s = _new_train_span(name, attributes, parent)
+    tokens = _current.set(s), _train_current.set(s)
+    s.start, s.mono_start = time.time(), time.monotonic()
+    try:
+        with annotation(name):
+            yield s
+    except BaseException as e:
+        s.attributes.setdefault("error", type(e).__name__)
+        raise
+    finally:
+        s.end, s.mono_end = time.time(), time.monotonic()
+        _current.reset(tokens[0])
+        _train_current.reset(tokens[1])
+        _keep(s, sink)
+
+
+def record_train_span(name: str, mono_start: float, mono_end: float,
+                      attributes: dict | None = None, *,
+                      parent: tuple[str, str] | None = None,
+                      sink: list | None = None) -> Span:
+    """A train-path span whose ends were taken elsewhere, on
+    ``time.monotonic()`` (a phase that began before its trace was
+    known; a compile reported by its duration)."""
+    s = _new_train_span(name, attributes, parent)
+    to_wall = time.time() - time.monotonic()
+    s.mono_start, s.mono_end = mono_start, mono_end
+    s.start, s.end = mono_start + to_wall, mono_end + to_wall
+    _keep(s, sink)
+    return s
+
+
+def self_seconds(spans: list[Span]) -> dict[str, float]:
+    """span id -> the span's time less what its direct children
+    cover (their union, clipped to the span), on the monotonic
+    clock."""
+    children: dict[str, list[Span]] = {}
+    for s in spans:
+        children.setdefault(s.parent_id, []).append(s)
+    out = {}
+    for s in spans:
+        covered, at = 0.0, s.mono_start
+        for c in sorted(children.get(s.span_id, ()),
+                        key=lambda c: c.mono_start):
+            a, b = max(c.mono_start, at), min(c.mono_end, s.mono_end)
+            if b > a:
+                covered, at = covered + (b - a), b
+        out[s.span_id] = (s.mono_end - s.mono_start) - covered
+    return out
+
+
+def chrome_events(spans: list[Span]) -> list[dict]:
+    """Spans of the train path in chrome-trace form (the surface of
+    ``ray_tpu.timeline()``), timed on the monotonic clock so that the
+    processes of one host line up."""
+    self_s = self_seconds(spans)
+    return [{"name": s.name, "ph": "X", "pid": s.process or "driver",
+             "tid": s.trace_id, "ts": s.mono_start * 1e6,
+             "dur": (s.mono_end - s.mono_start) * 1e6,
+             "args": {**s.attributes, "span_id": s.span_id,
+                      "parent_id": s.parent_id, "unix_start": s.start,
+                      "self_s": self_s[s.span_id]}}
+            for s in spans]
 
 
 @contextlib.contextmanager

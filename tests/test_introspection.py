@@ -512,18 +512,21 @@ def test_cli_status_memory_stack_live_cluster(intro_cluster, capsys):
     cluster, node = intro_cluster
     big = ray_tpu.put(b"C" * 400_000)
     from ray_tpu.scripts.cli import main as cli_main
+    # Without --address the CLI attaches to the NEWEST session socket
+    # on the machine: under xdist another worker's cluster.
+    here = ["--address", ray_tpu.core.api.get_runtime().client_address]
 
-    assert cli_main(["status"]) == 0
+    assert cli_main(["status", *here]) == 0
     out = capsys.readouterr().out
     assert "ray_tpu cluster status" in out
     assert "2 alive / 2 total" in out
 
-    assert cli_main(["memory", "--top", "5"]) == 0
+    assert cli_main(["memory", "--top", "5", *here]) == 0
     out = capsys.readouterr().out
     assert "ray_tpu memory" in out
     assert "shm" in out
 
-    assert cli_main(["stack"]) == 0
+    assert cli_main(["stack", *here]) == 0
     out = capsys.readouterr().out
     assert "==== head" in out
     assert "==== daemon" in out
@@ -536,7 +539,8 @@ def test_cli_profile_writes_speedscope(intro_rt, tmp_path, capsys):
     from ray_tpu.scripts.cli import main as cli_main
     out_path = str(tmp_path / "prof.speedscope.json")
     assert cli_main(["profile", "--duration", "0.4", "--hz", "50",
-                     "-o", out_path]) == 0
+                     "-o", out_path,
+                     "--address", intro_rt.client_address]) == 0
     capsys.readouterr()
     with open(out_path) as f:
         doc = json.load(f)

@@ -72,13 +72,21 @@ def test_worker_counter_reaches_cluster_scrape(obs_rt):
 
     assert sum(ray_tpu.get([bump.remote() for _ in range(3)],
                            timeout=60)) == 3
-    text = _wait_for(
-        lambda: ("pipeline_probe_total{" in
-                 obs_rt.observability.prometheus_text())
-        and obs_rt.observability.prometheus_text())
-    assert text, "worker counter never reached the head aggregator"
-    line = next(ln for ln in text.splitlines()
-                if ln.startswith("pipeline_probe_total{"))
+    def probe_line():
+        return next((ln for ln in
+                     obs_rt.observability.prometheus_text().splitlines()
+                     if ln.startswith("pipeline_probe_total{")), "")
+
+    # The three increments may come from up to three worker processes,
+    # each with its own exporter and flush phase: the series appears
+    # with the first push and reaches 3 with the last, so wait for the
+    # value, not for the series.
+    def probe_value():
+        return float((probe_line() or "x 0").rsplit(" ", 1)[1])
+
+    _wait_for(lambda: probe_value() == 3.0)
+    line = probe_line()
+    assert line, "worker counter never reached the head aggregator"
     # Attribution: the series carries the node that ran the task.
     assert 'node_id="' in line
     # All three increments survived the cumulative merge.

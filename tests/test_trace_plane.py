@@ -586,9 +586,13 @@ def test_serve_http_retry_assembles_one_trace(serve_rt, tmp_path):
         buf = io.StringIO()
         old = sys.stdout
         sys.stdout = buf
+        # Without --address the CLI attaches to the NEWEST session
+        # socket on the machine — under xdist that is whichever
+        # worker's cluster came up last, which never saw this trace.
+        here = ["--address", rt_obj.client_address]
         try:
-            assert cli_main(["trace", t["trace_id"]]) == 0
-            assert cli_main(["traces", "--slowest"]) == 0
+            assert cli_main(["trace", t["trace_id"], *here]) == 0
+            assert cli_main(["traces", "--slowest", *here]) == 0
         finally:
             sys.stdout = old
         out = buf.getvalue()

@@ -1,0 +1,794 @@
+"""The train path's own instrumentation (docs/observability.md):
+
+- program scopes inside the compiled step (``embed``, ``blocks``,
+  ``loss``, ``optimizer``), read from the HLO's ``op_name`` metadata;
+- the phases of one ``fit()`` as spans on two clocks, under one trace;
+- the input pipeline's per-step annotations, live only under a profile,
+  and its always-on counters;
+- the process-wide compile listener;
+- ``observability/xplane.py::summarize_trace`` on a capture whose
+  events are named by whole HLO instructions.
+"""
+
+import json
+import os
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from ray_tpu.observability import xplane
+from ray_tpu.train import session as train_session
+from ray_tpu.train import step as train_step
+from ray_tpu.train.prefetch import DevicePrefetcher, collect_counters
+from ray_tpu.util import tracing
+
+SCOPES = ("embed", "blocks", "loss", "optimizer")
+# Instructions that compute nothing on their own: arguments, constants
+# and their broadcasts, tuple plumbing.
+PLUMBING = {"parameter", "constant", "broadcast", "get-tuple-element",
+            "tuple", "bitcast"}
+INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(.*op_name=\"([^\"]*)\"")
+
+
+# -- (a) program scopes in the compiled step -----------------------------
+
+def _gpt2_step_hlo(fused_ce: bool = True) -> str:
+    from ray_tpu.models.gpt2 import GPT2, GPT2Config, gpt2_loss_fn
+    cfg = GPT2Config.tiny()
+    model = GPT2(cfg)
+    opt = optax.adamw(1e-3)
+    state = train_step.init_train_state(
+        model.init_params(jax.random.key(0)), opt)
+    step = train_step.make_train_step(
+        gpt2_loss_fn(model, fused_ce=fused_ce, ce_chunk=64), opt)
+    tokens = jnp.zeros((4, cfg.seq_len), jnp.int32)
+    return step.lower(state, {"tokens": tokens,
+                              "targets": tokens}).compile().as_text()
+
+
+def _resnet_step_hlo() -> str:
+    from ray_tpu.models.resnet import ResNet, ResNet50Config, resnet_loss_fn
+    model = ResNet(ResNet50Config.tiny())
+    v = model.init_variables(jax.random.key(0), image_size=32)
+    opt = optax.sgd(0.1, momentum=0.9, nesterov=True)
+    state = train_step.init_train_state(v["params"], opt,
+                                        extra=v["batch_stats"])
+    step = train_step.make_multi_train_step(resnet_loss_fn(model), opt,
+                                            has_extra=True)
+    batch = {"image": jnp.zeros((2, 4, 32, 32, 3)),
+             "label": jnp.zeros((2, 4), jnp.int32)}
+    return step.lower(state, batch).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def step_hlo():
+    return {"gpt2": _gpt2_step_hlo(), "gpt2-unfused": _gpt2_step_hlo(False),
+            "resnet": _resnet_step_hlo()}
+
+
+def _computing(hlo: str) -> list[tuple[str, str, str]]:
+    """(name, opcode, op_name) of the instructions of the jitted
+    program that compute something."""
+    out = []
+    for line in hlo.splitlines():
+        m = INSTRUCTION.match(line)
+        if (m and m.group(3).startswith("jit(")
+                and m.group(2) not in PLUMBING):
+            out.append(m.groups())
+    return out
+
+
+@pytest.mark.parametrize("model", ["gpt2", "gpt2-unfused", "resnet"])
+def test_step_instructions_fall_under_the_four_scopes(step_hlo, model):
+    rows = _computing(step_hlo[model])
+    assert len(rows) > 200
+    unscoped = [r for r in rows
+                if xplane.scope_path(r[2]) == "unscoped"]
+    assert len(unscoped) <= 0.02 * len(rows), unscoped[:20]
+    seen = {xplane.scope_path(r[2]).split("/")[0] for r in rows}
+    assert seen >= set(SCOPES)
+
+
+def test_chunked_cross_entropy_whiles_are_under_loss(step_hlo):
+    whiles = [r for r in _computing(step_hlo["gpt2"]) if r[1] == "while"]
+    assert len(whiles) == 2                     # forward and backward
+    assert {xplane.scope_path(r[2]) for r in whiles} == {"loss/loss"}
+    assert any("transpose(" in r[2] for r in whiles)    # the backward's
+
+
+@pytest.mark.parametrize("model", ["gpt2", "resnet"])
+def test_no_bare_primitive_is_left_from_the_optimizer(step_hlo, model):
+    """Before the scope the update, the apply and the gradient norm were
+    ``jit(step)/add``, ``mul``, ``sqrt``...: now the only operations
+    with no scope are the fused steps' own scan (slicing the batch
+    stack, the counter)."""
+    scan = {"dynamic_slice", "dynamic_update_slice", "while", "add", "lt"}
+    rows = _computing(step_hlo[model])
+    bare = [r for r in rows if xplane.scope_path(r[2]) == "unscoped"
+            and not (model == "resnet" and "/while" in r[2] + "/"
+                     and r[2].rsplit("/", 1)[-1] in scan)
+            and not r[2].endswith("/dynamic_slice")]
+    assert bare == []
+    under = [r for r in rows
+             if xplane.scope_path(r[2]).startswith("optimizer")]
+    assert len(under) > 50
+    assert {"mul", "add"} <= {r[2].rsplit("/", 1)[-1] for r in under}
+
+
+def test_gpt2_blocks_keep_their_module_names(step_hlo):
+    paths = {xplane.scope_path(r[2]) for r in _computing(step_hlo["gpt2"])}
+    assert {"blocks/h_*/attn", "blocks/h_*/mlp", "blocks/h_*/ln_*",
+            "blocks/ln_f", "embed/wte", "embed/wpe"} <= paths
+
+
+@pytest.mark.parametrize("op_name, want", [
+    ("jit(step)/transpose(jvp(GPT2))/blocks/h_11/attn/pallas_call",
+     "blocks/h_*/attn"),
+    ("jit(step)/jvp(GPT2)/blocks/h_3/mlp/fc/dot_general", "blocks/h_*/mlp"),
+    ("jit(step)/transpose(jvp(loss))/loss/while/body/dot_general",
+     "loss/loss/while"),
+    ("jit(step)/optimizer/mul", "optimizer"),
+    ("jit(multi)/while/body/closed_call/jvp(ResNet)/blocks/stage1_block0/"
+     "conv2/conv_general_dilated", "blocks/stage*_block*/conv*"),
+    ("jit(step)/jvp()/while/body/dot_general", "unscoped"),
+    ("jit(loss)/mul", "unscoped"),
+    ("", "unscoped"),
+])
+def test_scope_path(op_name, want):
+    assert xplane.scope_path(op_name) == want
+
+
+# -- (b) one fit(): phases under one trace, on two clocks -----------------
+
+def _fit_loop(config):
+    import jax as _jax
+    import numpy as _np
+    import optax as _optax
+
+    from ray_tpu import train
+    from ray_tpu.models.gpt2 import GPT2, GPT2Config, gpt2_loss_fn
+    cfg = GPT2Config.tiny()
+    model = GPT2(cfg)
+    opt = _optax.adamw(1e-3)
+    step = train.make_train_step(gpt2_loss_fn(model, ce_chunk=64), opt)
+    state = train.init_train_state(
+        _jax.jit(model.init_params)(_jax.random.key(0)), opt)
+    tokens = _np.zeros((4, cfg.seq_len), _np.int32)
+    batches = train.prefetch_to_device(
+        {"tokens": tokens, "targets": tokens} for _ in range(config["steps"]))
+    for batch in batches:
+        state, metrics = step(state, batch)
+        train.report({"loss": float(metrics["loss"])})
+    batches.close()
+
+
+@pytest.fixture(scope="module")
+def fit(tmp_path_factory):
+    """One single-worker fit; the cluster is down again when the tests
+    look at what it left behind."""
+    import ray_tpu
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+    storage = tmp_path_factory.mktemp("fit_trace")
+    ray_tpu.init(num_cpus=4, ignore_reinit_error=False)
+    try:
+        result = JaxTrainer(
+            _fit_loop, train_loop_config={"steps": 4},
+            scaling_config=ScalingConfig(num_workers=1),
+            run_config=RunConfig(name="fit", storage_path=str(storage)),
+        ).fit()
+    finally:
+        ray_tpu.shutdown()
+    assert result.error is None
+    return result
+
+
+PHASES = ["train.fit", "train.fit.gang_start", "train.fit.start_loop",
+          "train.fit.poll", "train.fit.shutdown", "train.worker.boot",
+          "train.worker.loop"]
+
+
+def test_fit_result_holds_every_phase_under_one_trace(fit):
+    names = [s["name"] for s in fit.spans]
+    for phase in PHASES:
+        assert names.count(phase) == 1, (phase, names)
+    assert "train.fit.backend_setup" not in names      # one worker: not run
+    assert len({s["trace_id"] for s in fit.spans}) == 1
+    assert len({s["process"] for s in fit.spans}) == 2  # driver and worker
+    root = next(s for s in fit.spans if s["name"] == "train.fit")
+    assert root["parent_id"] is None
+    assert root["attributes"]["workers"] == 1
+    assert root["attributes"]["trial_dir"] == fit.path
+    kinds = {s["attributes"]["kind"] for s in fit.spans
+             if s["name"] == "train.compile"}
+    assert kinds >= {"trace", "lower", "backend"}
+    assert len(fit.spans) < 100         # phases and compiles, not steps
+
+
+def test_fit_spans_nest_on_the_monotonic_clock(fit):
+    by_id = {s["span_id"]: s for s in fit.spans}
+    for s in fit.spans:
+        assert 0 < s["mono_start"] <= s["mono_end"]
+        assert s["start"] <= s["end"]
+        # both clocks tell the same duration
+        assert (s["end"] - s["start"]) == pytest.approx(
+            s["mono_end"] - s["mono_start"], abs=0.05)
+        if s["parent_id"] is not None:
+            parent = by_id[s["parent_id"]]
+            assert parent["mono_start"] <= s["mono_start"]
+            assert s["mono_end"] <= parent["mono_end"]
+    order = [next(s for s in fit.spans if s["name"] == n)
+             for n in ("train.fit.gang_start", "train.fit.start_loop",
+                       "train.fit.poll", "train.fit.shutdown")]
+    for a, b in zip(order, order[1:]):
+        assert a["mono_end"] <= b["mono_start"]
+    loop = next(s for s in fit.spans if s["name"] == "train.worker.loop")
+    compiles = [s for s in fit.spans if s["name"] == "train.compile"]
+    assert all(s["parent_id"] == loop["span_id"] for s in compiles)
+
+
+def test_fit_self_times_sum_to_no_more_than_the_fit(fit):
+    spans = [tracing.Span(**s) for s in fit.spans]
+    self_s = tracing.self_seconds(spans)
+    root = next(s for s in spans if s.name == "train.fit")
+    assert all(v >= -1e-9 for v in self_s.values())
+    # the driver's phases and the worker's run side by side: each
+    # process's self times fit in the fit
+    for process in {s.process for s in spans}:
+        total = sum(self_s[s.span_id] for s in spans
+                    if s.process == process)
+        assert total <= (root.mono_end - root.mono_start) + 1e-6
+    assert self_s[root.span_id] < root.mono_end - root.mono_start
+
+
+def test_fit_poll_and_loop_attributes(fit):
+    poll = next(s for s in fit.spans if s["name"] == "train.fit.poll")
+    a = poll["attributes"]
+    assert a["reports"] == 4 and a["polls"] >= 1
+    assert 0 <= a["report_to_poll_s_max"] <= a["report_to_poll_s_sum"]
+    assert a["report_to_poll_s_max"] < 5.0
+    loop = next(s for s in fit.spans if s["name"] == "train.worker.loop")
+    a = loop["attributes"]
+    assert a["input.batches"] == 4
+    assert a["input.source_s"] >= 0 and a["input.place_s"] > 0
+    assert a["input.stall_s"] >= 0
+
+
+def test_fit_trace_json_is_written_in_chrome_form(fit):
+    with open(os.path.join(fit.path, "fit_trace.json")) as f:
+        events = json.load(f)
+    assert sorted(e["name"] for e in events) == sorted(
+        s["name"] for s in fit.spans)
+    for e in events:
+        assert e["ph"] == "X" and e["dur"] >= 0
+        assert {"span_id", "parent_id", "self_s"} <= set(e["args"])
+
+
+def test_get_spans_holds_the_fit_after_shutdown(fit):
+    held = tracing.get_spans(fit.spans[0]["trace_id"])
+    assert sorted(s.span_id for s in held) == sorted(
+        s["span_id"] for s in fit.spans)
+
+
+def _uneven_loop(config):
+    from ray_tpu import train
+    if train.get_context().world_rank == 1:
+        time.sleep(1.0)         # rank 0 is polled some 20 times more
+    train.report({"rank": train.get_context().world_rank})
+
+
+@pytest.fixture(scope="module")
+def uneven_fit(tmp_path_factory):
+    """A two-worker fit whose ranks end a second apart, with tracing
+    enabled: the actor method that starts the loop then runs inside a
+    task's span."""
+    import ray_tpu
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+    storage = tmp_path_factory.mktemp("uneven_fit")
+    ray_tpu.init(num_cpus=4, ignore_reinit_error=False)
+    tracing.enable()
+    try:
+        result = JaxTrainer(
+            _uneven_loop, scaling_config=ScalingConfig(num_workers=2),
+            run_config=RunConfig(name="fit", storage_path=str(storage)),
+        ).fit()
+    finally:
+        tracing.disable()
+        ray_tpu.shutdown()
+    assert result.error is None, result.error
+    return result
+
+
+def test_a_finished_worker_hands_its_spans_over_once(uneven_fit):
+    ids = [s["span_id"] for s in uneven_fit.spans]
+    assert len(ids) == len(set(ids))
+    names = [s["name"] for s in uneven_fit.spans]
+    for phase in PHASES + ["train.fit.backend_setup"]:
+        assert names.count(phase) == (
+            2 if phase.startswith("train.worker.") else 1), (phase, names)
+    ranks = sorted(s["attributes"]["rank"] for s in uneven_fit.spans
+                   if s["name"] == "train.worker.loop")
+    assert ranks == [0, 1]
+    poll = next(s for s in uneven_fit.spans
+                if s["name"] == "train.fit.poll")
+    assert poll["attributes"]["polls"] >= 10    # rank 0 was asked again
+    with open(os.path.join(uneven_fit.path, "fit_trace.json")) as f:
+        assert len(json.load(f)) == len(ids)
+
+
+def test_worker_spans_stay_in_the_fit_with_tracing_enabled(uneven_fit):
+    root = next(s for s in uneven_fit.spans if s["name"] == "train.fit")
+    assert {s["trace_id"] for s in uneven_fit.spans} == {root["trace_id"]}
+    for s in uneven_fit.spans:
+        if s["name"].startswith("train.worker."):
+            assert s["parent_id"] == root["span_id"]
+
+
+# -- tracing.py's train-path helpers --------------------------------------
+
+def test_train_span_is_recorded_with_tracing_disabled():
+    assert not tracing.get_tracer().enabled
+    sink: list = []
+    with tracing.train_span("train.fit", {"k": 1}, sink=sink) as root:
+        with tracing.train_span("train.fit.poll", sink=sink) as child:
+            time.sleep(0.01)
+        tracing.record_train_span("train.compile", child.mono_start,
+                                  child.mono_end, {"kind": "trace"},
+                                  sink=sink)
+    assert [s.name for s in sink] == ["train.fit.poll", "train.compile",
+                                      "train.fit"]
+    assert {s.trace_id for s in sink} == {root.trace_id}
+    assert sink[0].parent_id == sink[1].parent_id == root.span_id
+    assert root.mono_end - root.mono_start >= 0.01
+    assert root.end - root.start == pytest.approx(
+        root.mono_end - root.mono_start, abs=0.01)
+    self_s = tracing.self_seconds(sink)
+    # the two children overlap entirely: counted once
+    assert self_s[root.span_id] == pytest.approx(
+        (root.mono_end - root.mono_start)
+        - (child.mono_end - child.mono_start))
+
+
+def test_train_span_without_a_sink_goes_to_the_ring_and_tags_errors():
+    before = len(tracing.get_spans())
+    with pytest.raises(ValueError):
+        with tracing.train_span("train.fit.gang_start",
+                                parent=("t" * 16, "p" * 16)):
+            raise ValueError("no gang")
+    added = tracing.get_spans()[before:]
+    assert [s.name for s in added] == ["train.fit.gang_start"]
+    assert added[0].trace_id == "t" * 16 and added[0].parent_id == "p" * 16
+    assert added[0].attributes["error"] == "ValueError"
+
+
+def test_an_explicit_parent_wins_over_a_tasks_span():
+    """With tracing enabled a worker's method runs inside its task's
+    span, of whatever trace: the fit's (trace id, root) still holds,
+    and train-path spans still nest in each other."""
+    tracing.enable()
+    try:
+        sink: list = []
+        fit = ("f" * 16, "r" * 16)
+        with tracing.span("task::start_loop") as task:
+            assert task.trace_id != fit[0]
+            tracing.record_train_span("train.worker.boot", 1.0, 2.0,
+                                      parent=fit, sink=sink)
+            with tracing.train_span("train.worker.loop", parent=fit,
+                                    sink=sink) as loop:
+                tracing.record_train_span("train.compile", 1.0, 2.0,
+                                          parent=fit, sink=sink)
+                with tracing.span("submit::f") as inner:
+                    pass
+            with tracing.train_span("train.fit", sink=sink) as joined:
+                pass
+    finally:
+        tracing.disable()
+    boot, compiled = sink[0], sink[1]
+    assert {boot.trace_id, loop.trace_id, compiled.trace_id} == {fit[0]}
+    assert boot.parent_id == loop.parent_id == fit[1]
+    assert compiled.parent_id == loop.span_id
+    # the control plane's spans nest under an open train-path span, and
+    # a fit with no parent joins the trace around it
+    assert (inner.trace_id, inner.parent_id) == (fit[0], loop.span_id)
+    assert (joined.trace_id, joined.parent_id) == (task.trace_id,
+                                                   task.span_id)
+
+
+def test_step_time_histogram_tells_steps_apart():
+    b = train_session._step_time_buckets()
+    assert b[0] == 0.005 and b[-1] == 120.0
+    assert all(hi / lo <= 1.5 for lo, hi in zip(b, b[1:]))
+    # the GPT-2 step (250 ms) and ten fused ResNet steps (476 ms), and a
+    # step 60% slower than either, each have a bucket of their own
+
+    def bucket(x):
+        return sum(x > edge for edge in b)
+    assert len({bucket(0.250), bucket(0.476), bucket(0.250 * 1.6),
+                bucket(0.476 * 1.6)}) == 4
+
+
+# -- (c) the input pipeline under a profile -------------------------------
+
+@pytest.fixture(scope="module")
+def prefetch_profile(tmp_path_factory):
+    """A profile around a DevicePrefetcher loop whose source and place
+    take known times, and the prefetcher's counters after it."""
+    from jax.profiler import ProfileData
+    logdir = str(tmp_path_factory.mktemp("prefetch_profile"))
+
+    def source():
+        for i in range(12):
+            time.sleep(0.010)
+            yield np.full((4,), i, np.float32)
+
+    def place(x):
+        time.sleep(0.005)
+        return jax.device_put(x)
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=opts)
+    try:
+        pf = DevicePrefetcher(source(), place=place, depth=2)
+        got = [int(x[0]) for x in pf]
+        pf.close()
+    finally:
+        jax.profiler.stop_trace()
+    assert got == list(range(12))
+    path = xplane.trace_files(logdir)[-1]
+    by_name: dict[str, list] = {}       # name -> [(thread, seconds)]
+    for p, plane in enumerate(ProfileData.from_file(path).planes):
+        for i, line in enumerate(plane.lines):      # one line a thread
+            for ev in line.events:
+                if ev.name.startswith("train.input."):
+                    by_name.setdefault(ev.name, []).append(
+                        ((p, i), ev.duration_ns / 1e9))
+    return pf, by_name
+
+
+def test_input_spans_lie_on_their_threads(prefetch_profile):
+    _, by_name = prefetch_profile
+    assert set(by_name) == {"train.input.source", "train.input.place",
+                            "train.input.wait"}
+    source = {line for line, _ in by_name["train.input.source"]}
+    place = {line for line, _ in by_name["train.input.place"]}
+    wait = {line for line, _ in by_name["train.input.wait"]}
+    assert len(source) == len(place) == len(wait) == 1
+    assert source == place and source != wait
+    assert len(by_name["train.input.place"]) == 12
+    assert len(by_name["train.input.wait"]) == 13      # and the sentinel's
+
+
+@pytest.mark.parametrize("counter, span", [
+    ("source_s", "train.input.source"), ("place_s", "train.input.place"),
+    ("stall_s", "train.input.wait")])
+def test_input_counters_agree_with_the_spans(prefetch_profile, counter,
+                                             span):
+    pf, by_name = prefetch_profile
+    spans_s = sum(d for _, d in by_name[span])
+    assert pf.batches == 12 and not hasattr(pf, "produce_s")
+    assert pf.counters[counter] == pytest.approx(spans_s, rel=0.2)
+    assert pf.counters[counter] >= {"source_s": 0.12, "place_s": 0.06,
+                                    "stall_s": 0.1}[counter]
+    assert pf.stall_s == pf.counters["stall_s"]
+
+
+# -- (d) the compile listener ----------------------------------------------
+
+def _compile_spans_since(n: int, fun: str) -> list:
+    return [s for s in tracing.get_spans()[n:]
+            if s.name == "train.compile"
+            and fun in s.attributes.get("fun_name", "")]
+
+
+def test_a_new_step_yields_compile_spans_and_a_repeat_none():
+    def quadratic_loss(params, batch):
+        return jnp.sum((params["w"] * batch["x"] - 1.0) ** 2)
+
+    opt = optax.sgd(0.1)
+    step = train_step.make_train_step(quadratic_loss, opt)
+    state = train_step.init_train_state({"w": jnp.ones((8,))}, opt)
+    batch = {"x": jnp.arange(8.0)}
+    before = len(tracing.get_spans())
+    state, _ = step(state, batch)
+    first = _compile_spans_since(before, "step")
+    assert {s.attributes["kind"] for s in first} >= {"trace", "lower",
+                                                     "backend"}
+    for s in first:
+        assert s.attributes["fun_name"] in ("step", "jit(step)")
+        assert 0 < s.mono_start <= s.mono_end <= time.monotonic()
+    # donated outputs come back in the layouts the compiler chose: at
+    # most one more executable, then none
+    state, _ = step(state, batch)
+    before = len(tracing.get_spans())
+    for _ in range(3):
+        state, _ = step(state, batch)
+    assert _compile_spans_since(before, "step") == []
+
+
+def test_nested_jits_fold_into_the_outermost_trace():
+    inner = jax.jit(lambda x: x * 2.0)
+
+    def outer_fn(x):
+        return inner(inner(x)) + jnp.sum(x)
+
+    train_step._listen_for_compiles()
+    x = jnp.ones((4,))
+    before = len(tracing.get_spans())
+    jax.jit(outer_fn)(x)
+    traces = [s for s in tracing.get_spans()[before:]
+              if s.name == "train.compile"
+              and s.attributes["kind"] == "trace"]
+    assert [s.attributes["fun_name"] for s in traces] == ["outer_fn"]
+
+
+def test_the_listener_is_installed_once_a_process():
+    from jax._src import monitoring
+    for _ in range(3):
+        train_step.make_train_step(lambda p, b: jnp.sum(p["w"]),
+                                   optax.sgd(0.1))
+    listeners = monitoring.get_event_duration_listeners()
+    assert listeners.count(train_step._on_compile_seconds) == 1
+    assert monitoring.get_event_listeners().count(
+        train_step._on_cache_event) == 1
+
+
+def test_compile_spans_inside_a_session_go_to_its_list():
+    sess = train_session.init_session(train_session.TrainContext(),
+                                      trace_ctx=("a" * 16, "b" * 16))
+    try:
+        train_step._listen_for_compiles()
+        jax.jit(lambda x: x - 3.0)(jnp.ones((3,)))
+    finally:
+        train_session.shutdown_session()
+    kinds = [s.attributes["kind"] for s in sess.spans]
+    assert "trace" in kinds and "backend" in kinds
+    assert {s.trace_id for s in sess.spans} == {"a" * 16}
+    assert {s.parent_id for s in sess.spans} == {"b" * 16}
+
+
+# -- (e) nothing is kept per step when no profile runs --------------------
+
+def test_ten_thousand_steps_allocate_no_span(monkeypatch):
+    made = []
+
+    class CountingSpan(tracing.Span):
+        def __init__(self, *a, **kw):
+            made.append(1)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(tracing, "Span", CountingSpan)
+    ring = len(tracing.get_spans())
+    sess = train_session.init_session(train_session.TrainContext())
+    try:
+        with collect_counters() as input_totals:
+            pf = DevicePrefetcher(iter(range(10_000)), depth=4)
+            for i, item in enumerate(pf):
+                train_session.report({"i": item})
+            pf.close()
+    finally:
+        train_session.shutdown_session()
+    assert i == 9_999 and sess.results.qsize() == 10_000
+    assert made == [] and sess.spans == []
+    assert len(tracing.get_spans()) == ring
+    assert input_totals()["input.batches"] == 10_000
+    # outside a collector a prefetcher is kept by nobody
+    assert DevicePrefetcher(iter(()), depth=1).counters["batches"] == 0
+    assert input_totals()["input.batches"] == 10_000
+
+
+# -- summarize_trace on events named by whole HLO instructions -----------
+
+def _varint(n: int) -> bytes:
+    out = bytearray()
+    while True:
+        out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+        n >>= 7
+        if not n:
+            return bytes(out)
+
+
+def _msg(field: int, payload: bytes) -> bytes:
+    return _varint(field << 3 | 2) + _varint(len(payload)) + payload
+
+
+GOLDEN_OPS = {  # instruction -> (the event's name, op_name)
+    "fusion.165": (
+        "%fusion.165 = bf16[32,3,1024,12,64]{2,4,0,3,1:T(8,128)(2,1)} "
+        "fusion(bf16[3,12,64]{2,1,0:T(8,128)(2,1)S(1)} %copy-done.499), "
+        "kind=kOutput, calls=%fused_computation.365",
+        "jit(step)/jvp(GPT2)/blocks/h_0/attn/bte,eshd->bsthd/dot_general"),
+    "attn.33": (
+        "%attn.33 = (bf16[384,1024,64]{2,1,0:T(8,128)(2,1)S(1)}, "
+        "f32[384,1024,1]{2,1,0:T(8,128)}) custom-call(bf16[384,1024,64]"
+        "{2,1,0:T(8,128)(2,1)} %bitcast.2028), "
+        "custom_call_target=\\\"tpu_custom_call\\\"",
+        "jit(step)/jvp(GPT2)/blocks/h_0/attn/pallas_call"),
+    "while.26": (
+        "%while.26 = (s32[]{:T(128)}, f32[50304,768]{1,0:T(8,128)}) "
+        "while((s32[]{:T(128)}) %tuple.7), condition=%c, body=%b",
+        "jit(step)/transpose(jvp(loss))/loss/while"),
+    "convolution_add_fusion.26": (
+        "%convolution_add_fusion.26 = f32[50304,768]{1,0:T(8,128)} "
+        "fusion(bf16[2048,50304]{1,0} %p), kind=kLoop, calls=%fc.26",
+        "jit(step)/transpose(jvp(loss))/loss/while/body/dot_general"),
+    "fusion.9": (
+        "%fusion.9 = f32[768]{0} fusion(f32[768]{0} %p), kind=kLoop, "
+        "calls=%fused_computation.9", "jit(step)/optimizer/mul"),
+    "all-reduce.3": (
+        "%all-reduce.3 = f32[768]{0} all-reduce(f32[768]{0} %g), "
+        "replica_groups={{0,1,2,3}}, to_apply=%add", ""),
+    # added by the compiler, no op_name: read by fusion.9
+    "copy-done.7": (
+        "%copy-done.7 = f32[768]{0} copy-done((f32[768]{0}) %copy-start.7)",
+        ""),
+}
+GOLDEN_OPERANDS = {"fusion.9": ["copy-done.7"]}
+
+
+@pytest.fixture(scope="module")
+def golden_capture(tmp_path_factory):
+    return _capture(tmp_path_factory.mktemp("golden_capture"), GOLDEN_OPS)
+
+
+def _capture(logdir, ops: dict) -> str:
+    from jax.profiler import ProfileData
+    us = 1_000_000
+
+    def event(meta, start, dur):
+        return (f"events {{ metadata_id: {meta} offset_ps: {start * us} "
+                f"duration_ps: {dur * us} }}")
+
+    ids = {name: i + 1 for i, name in enumerate(ops)}
+    metas = "".join(
+        f'event_metadata {{ key: {ids[n]} value {{ id: {ids[n]} '
+        f'name: "{text}" }} }} ' for n, (text, _) in ops.items())
+    metas += ('event_metadata { key: 50 value { id: 50 '
+              'name: "jit_step(7)" } } ')
+    device = (
+        'planes { name: "/device:TPU:0" ' + metas
+        + 'lines { name: "XLA Modules" timestamp_ns: 0 '
+        + event(50, 0, 2000) + ' } '
+        # the line's own origin: 1 us after the plane's
+        + 'lines { name: "XLA Ops" timestamp_ns: 1000 ' + " ".join([
+            event(ids["fusion.165"], 0, 100),
+            event(ids["attn.33"], 100, 50),
+            event(ids["while.26"], 200, 400),       # holds 300 of:
+            event(ids["convolution_add_fusion.26"], 250, 300),
+            event(ids["copy-done.7"], 690, 10),
+            event(ids["fusion.9"], 700, 60),
+            event(ids["all-reduce.3"], 800, 40)])
+        + ' } lines { name: "Async XLA Ops" timestamp_ns: 1000 '
+        + event(ids["fusion.9"], 0, 1000) + ' } }')
+    insts = b"".join(
+        _msg(2, _msg(1, name.encode()) + _msg(2, b"fusion")
+             + (_msg(7, _msg(2, op.encode())) if op else b"")
+             + _varint(35 << 3) + _varint(ids[name])
+             + _msg(36, b"".join(_varint(ids[o]) for o in
+                                 GOLDEN_OPERANDS.get(name, []))))
+        for name, (_, op) in ops.items())
+    hlo = _msg(1, _msg(1, b"jit_step") + _msg(3, _msg(1, b"main") + insts))
+    octal = "".join(f"\\{b:03o}" for b in hlo)
+    metadata = ('planes { name: "/host:metadata" event_metadata { key: 1 '
+                'value { id: 1 name: "jit_step(7)" stats { metadata_id: 1 '
+                f'bytes_value: "{octal}" }} }} }} }}')
+    # the benchmark's reader counts what lies inside this span
+    host = ('planes { name: "/host:CPU" event_metadata { key: 1 value { '
+            'id: 1 name: "bench.window" } } lines { name: "main" '
+            f'timestamp_ns: 0 {event(1, 0, 3000)} }} }} ')
+    (logdir / "host.xplane.pb").write_bytes(
+        ProfileData.text_proto_to_serialized_xspace(
+            host + device + metadata))
+    return str(logdir)
+
+
+def test_summarize_trace_classes_by_opcode_and_fusion_kind(golden_capture):
+    got = xplane.summarize_trace(golden_capture, steps=2)
+    assert got["plane"] == "/device:TPU:0" and got["files"] == 1
+    # self times: the while's body is not counted twice, and the
+    # asynchronous line is not counted at all
+    assert got["total_ms"] == pytest.approx(0.660)
+    assert got["ms_per_step"] == pytest.approx(0.330)
+    assert got["class_ms"] == {
+        # the kOutput fusion, and the one with a convolution in its name
+        "mxu": pytest.approx(0.050 + 0.150),
+        "kernel": pytest.approx(0.025),
+        "collective": pytest.approx(0.020),
+        # the while's own time, fusion.9, the compiler's copy
+        "other": pytest.approx(0.050 + 0.030 + 0.005)}
+    assert got["matmul_share"] == pytest.approx(400 / 660, abs=1e-4)
+    assert [r["name"] for r in got["top_matmul"]] == [
+        "convolution_add_fusion.26", "fusion.165"]
+    assert got["top_non_matmul"][0] == {
+        "name": "while.26", "ms": pytest.approx(0.050),
+        "share": pytest.approx(100 / 660, abs=1e-4)}
+
+
+def test_summarize_trace_groups_by_program_scope(golden_capture):
+    got = xplane.summarize_trace(golden_capture, steps=2)
+    assert got["scope_ms"] == {
+        "loss/loss/while": pytest.approx(0.150),
+        "blocks/h_*/attn": pytest.approx(0.075),
+        "loss/loss": pytest.approx(0.050),
+        # fusion.9 and the copy it reads, which has no op_name of its own
+        "optimizer": pytest.approx(0.030 + 0.005),
+        "unscoped": pytest.approx(0.020)}
+    assert sum(got["scope_ms"].values()) == pytest.approx(
+        got["ms_per_step"])
+
+
+def test_summarize_trace_and_the_benchmark_agree_by_scope(golden_capture):
+    """The operator's table and the benchmark's scope metrics restate
+    the same rules in two places (the program does not import the
+    benchmark, the yardstick does not lean on the program's parser):
+    one capture through both gives the same self time under each scope,
+    the compiler's copy under its user's included."""
+    import sys
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark"))
+    try:
+        from benchlib import program_trace
+    finally:
+        sys.path.pop(0)
+    steps = 2
+    theirs = program_trace.reduce_file(
+        xplane.trace_files(golden_capture)[0], steps)
+    ours = xplane.summarize_trace(golden_capture, steps=steps)
+    by_scope: dict[str, float] = {}
+    for path, ms in ours["scope_ms"].items():
+        top = path.split("/")[0]
+        by_scope[top] = by_scope.get(top, 0.0) + ms
+    assert by_scope == {
+        scope: pytest.approx(seconds / steps * 1e3)
+        for scope, seconds in theirs["scope_s"].items()}
+    assert set(by_scope) == {"blocks", "loss", "optimizer", "unscoped"}
+    assert ours["scope_ms"]["blocks/h_*/attn"] == pytest.approx(
+        theirs["blocks_s"]["attn"] / steps * 1e3)
+    assert theirs["inherited_s"] == pytest.approx(10e-6)
+    assert program_trace.SCOPES == xplane.SCOPES
+    assert "scope_note" not in ours
+
+
+def test_summarize_trace_says_when_the_executable_has_no_scopes(
+        tmp_path):
+    """The step as compiled before the scopes, which is also what a
+    compile-cache hit on such an entry loads."""
+    before = {name: (text, op.replace("/blocks", "")
+                     .replace("(loss))/loss", "())")
+                     .replace("/optimizer", ""))
+              for name, (text, op) in GOLDEN_OPS.items()}
+    assert before["fusion.9"][1] == "jit(step)/mul"
+    got = xplane.summarize_trace(_capture(tmp_path, before), steps=2)
+    assert list(got["scope_ms"]) == ["unscoped"]
+    assert "compile cache" in got["scope_note"]
+    assert got["class_ms"]["mxu"] == pytest.approx(0.200)
+
+
+@pytest.mark.parametrize("text, want", [
+    (GOLDEN_OPS["fusion.165"][0], ("fusion.165", "fusion", "kOutput")),
+    (GOLDEN_OPS["while.26"][0], ("while.26", "while", "")),
+    ("%all-gather.31 = bf16[64,2048,768]{2,1,0} all-gather(bf16[16] %x)",
+     ("all-gather.31", "all-gather", "")),
+    ("dot_general", ("dot_general", "", "")),
+])
+def test_parse_hlo(text, want):
+    assert xplane.parse_hlo(text.replace('\\"', '"')) == want
+
+
+@pytest.mark.parametrize("args, want", [
+    (("fusion.1", "fusion", "kOutput"), "mxu"),
+    (("convolution_add_fusion.26", "fusion", "kLoop"), "mxu"),
+    (("fusion.9", "fusion", "kLoop"), "other"),
+    (("attn.33", "custom-call", ""), "kernel"),
+    (("all-reduce-start.1", "all-reduce-start", ""), "collective"),
+    (("dot.3", "", ""), "mxu"),                 # a CPU event's plain name
+    (("convert_element_type", "", ""), "other"),
+])
+def test_classify(args, want):
+    assert xplane.classify(*args) == want
