@@ -20,6 +20,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 import functools
+import math
 from functools import partial
 from typing import Any, Callable
 
@@ -28,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import causal_attention, ring_attention
+from ray_tpu.util import tracing
 
 
 @dataclass(frozen=True)
@@ -269,12 +271,13 @@ def cross_entropy_loss(logits, targets, ignore_index: int = -1):
 
 # The ``loss`` scope is opened inside each half of the custom_vjp:
 # a scope around the call alone does not reach the backward ``while``.
+# It returns the sums, not their quotient, so that a caller that holds
+# only part of the rows can add its sums to the others' first.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 @jax.named_scope("loss")
 def _chunked_ce_core(rows_c, emb, tgt_c, ignore_index):
-    (tot, cnt), _ = _chunked_ce_fwd_scan(rows_c, emb, tgt_c,
-                                         ignore_index)
-    return tot / jnp.maximum(cnt, 1).astype(jnp.float32)
+    sums, _ = _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index)
+    return sums
 
 
 def _chunk_logits(x_c, emb):
@@ -312,19 +315,18 @@ def _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index):
 
 @jax.named_scope("loss")
 def _chunked_ce_core_fwd(rows_c, emb, tgt_c, ignore_index):
-    (tot, cnt), lse_c = _chunked_ce_fwd_scan(rows_c, emb, tgt_c,
-                                             ignore_index)
-    loss = tot / jnp.maximum(cnt, 1).astype(jnp.float32)
-    return loss, (rows_c, emb, tgt_c, lse_c, cnt)
+    sums, lse_c = _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index)
+    return sums, (rows_c, emb, tgt_c, lse_c)
 
 
 @jax.named_scope("loss")
 def _chunked_ce_core_bwd(ignore_index, res, g):
     # Hand-written backward: recompute each chunk's logits but REUSE
     # the saved log-sum-exp (a jax.checkpoint formulation re-runs the
-    # full logsumexp reduction too). dlogits = (softmax - onehot)/cnt.
-    rows_c, emb, tgt_c, lse_c, cnt = res
-    scale = (g / jnp.maximum(cnt, 1).astype(jnp.float32))
+    # full logsumexp reduction too). dlogits = (softmax - onehot) *
+    # d tot, and d tot is 1/cnt of the caller's mean.
+    rows_c, emb, tgt_c, lse_c = res
+    scale, _ = g
 
     def one(demb, xt):
         x_c, t_c, lse = xt
@@ -353,10 +355,37 @@ def _chunked_ce_core_bwd(ignore_index, res, g):
 _chunked_ce_core.defvjp(_chunked_ce_core_fwd, _chunked_ce_core_bwd)
 
 
+def _token_axes(mesh, batch: int, seq: int):
+    """(batch axes, sequence axis or None): the mesh axes of size > 1
+    that shard a ``[batch, seq, ...]`` array's tokens — dp and fsdp on
+    the batch, sp on the sequence, as ``train.step.batch_spec`` places
+    them and the attention dispatch maps over them. None where the
+    chunk scan has to stay one global scan."""
+    if mesh is None or mesh.size == 1:
+        return None
+    from ray_tpu.parallel.mesh import AXIS_DP, AXIS_FSDP, AXIS_SP
+    from ray_tpu.parallel.sharding import DEFAULT_RULES
+
+    if DEFAULT_RULES.mesh_axis("vocab", mesh) is not None:
+        # The head is sharded on its vocabulary axis (tp): mapping
+        # over the token axes alone would hand every chip the whole
+        # head. A vocabulary-parallel cross-entropy is another path.
+        return None
+    batch_axes = tuple(a for a in (AXIS_DP, AXIS_FSDP)
+                       if mesh.shape.get(a, 1) > 1)
+    seq_axis = AXIS_SP if mesh.shape.get(AXIS_SP, 1) > 1 else None
+    if not batch_axes and seq_axis is None:
+        return None
+    n_batch = math.prod(mesh.shape[a] for a in batch_axes)
+    if batch % n_batch or (seq_axis and seq % mesh.shape[seq_axis]):
+        return None     # e.g. the tiny batch of init-time tracing
+    return batch_axes, seq_axis
+
+
 @jax.named_scope("loss")
 def chunked_cross_entropy(hidden, embedding, targets,
                           ignore_index: int = -1,
-                          chunk_size: int = 2048):
+                          chunk_size: int = 2048, mesh=None):
     """Cross-entropy that never materializes the full (B, S, vocab)
     logits: the tied LM head + loss run per row-chunk with a
     hand-written VJP (bwd recomputes each chunk's logits but reuses
@@ -367,24 +396,59 @@ def chunked_cross_entropy(hidden, embedding, targets,
     traffic. Chunking keeps the live logits block at
     chunk_size*vocab (~400 MB at 2048), trading one extra LM-head
     matmul in bwd for most of that bandwidth.
+
+    On a ``mesh`` that shards tokens (dp, fsdp, sp) each chip chunks
+    and scans the rows it holds, under ``shard_map``; the two sums and
+    the head's gradient are reduced over those axes once, after the
+    scan. ``mesh=None``, a one-device mesh, shapes the axes do not
+    divide and a head sharded on its vocabulary (tp) keep one global
+    scan: the first two have nothing to split, the others would
+    gather what the caller sharded (a scan walks its leading axis in
+    order, so a sharded chunk axis is gathered to every chip).
     """
     B, S, E = hidden.shape
-    compute_dtype = hidden.dtype
-    rows = hidden.reshape(B * S, E)
-    tgt = targets.reshape(B * S)
-    n_rows = B * S
-    chunk = min(chunk_size, n_rows)
-    pad = (-n_rows) % chunk
-    if pad:
-        rows = jnp.pad(rows, ((0, pad), (0, 0)))
-        tgt = jnp.pad(tgt, (0, pad), constant_values=ignore_index)
-    n = rows.shape[0] // chunk
-    rows_c = rows.reshape(n, chunk, E).astype(compute_dtype)
-    tgt_c = tgt.reshape(n, chunk)
     # Cast the tied embedding ONCE outside the scan (fwd and bwd both
     # consume the bf16 copy).
-    emb = embedding.astype(compute_dtype)
-    return _chunked_ce_core(rows_c, emb, tgt_c, ignore_index)
+    emb = embedding.astype(hidden.dtype)
+
+    def sums(hidden, emb, targets, over=()):
+        """(sum of the rows' losses, count of unmasked rows) of the
+        rows in hand, added over the mesh axes ``over``."""
+        rows = hidden.reshape(-1, E)
+        tgt = targets.reshape(-1)
+        chunk = min(chunk_size, rows.shape[0])
+        pad = (-rows.shape[0]) % chunk
+        if pad:
+            rows = jnp.pad(rows, ((0, pad), (0, 0)))
+            tgt = jnp.pad(tgt, (0, pad), constant_values=ignore_index)
+        n = rows.shape[0] // chunk
+        tot_cnt = _chunked_ce_core(
+            rows.reshape(n, chunk, E), emb, tgt.reshape(n, chunk),
+            ignore_index)
+        return jax.lax.psum(tot_cnt, over) if over else tot_cnt
+
+    axes = _token_axes(mesh, B, S)
+    if axes is None:
+        tot, cnt = sums(hidden, emb, targets)
+    else:
+        from jax.sharding import PartitionSpec
+        batch_axes, seq_axis = axes
+        tokens = PartitionSpec(batch_axes or None, seq_axis)
+        over = batch_axes + ((seq_axis,) if seq_axis else ())
+        # The head enters replicated, so the transpose reduces its
+        # gradient over the axes: once, after the backward scan. All
+        # mesh axes are manual, as in ops/attention.py: with only the
+        # token axes manual (``axis_names``) the bf16 sum's reduction
+        # gets a sharding constraint that aborts XLA's CPU compiler.
+        tot, cnt = jax.shard_map(
+            functools.partial(sums, over=over), mesh=mesh,
+            in_specs=(tokens, PartitionSpec(), tokens),
+            out_specs=PartitionSpec(),
+            check_vma=False)(hidden, emb, targets)
+        tracing.note_trace(
+            ce_rows_local=B * S // math.prod(mesh.shape[a] for a in over),
+            ce_axes=list(over))
+    return tot / jnp.maximum(cnt, 1).astype(jnp.float32)
 
 
 def gpt2_loss_fn(model: GPT2, fused_ce: bool = True,
@@ -401,7 +465,7 @@ def gpt2_loss_fn(model: GPT2, fused_ce: bool = True,
                             return_hidden=True)
             return chunked_cross_entropy(
                 h, params["wte"]["embedding"], batch["targets"],
-                chunk_size=ce_chunk)
+                chunk_size=ce_chunk, mesh=model.mesh)
         logits = model.apply({"params": params}, batch["tokens"])
         return cross_entropy_loss(logits, batch["targets"])
 

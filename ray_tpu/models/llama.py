@@ -250,7 +250,8 @@ def llama_loss_fn(model: Llama, fused_ce: bool = True,
                 # transpose into the dot, no materialized copy.
                 head = params["lm_head"]["kernel"].T
             return chunked_cross_entropy(
-                h, head, batch["targets"], chunk_size=ce_chunk)
+                h, head, batch["targets"], chunk_size=ce_chunk,
+                mesh=model.mesh)
         logits = model.apply({"params": params}, batch["tokens"])
         return cross_entropy_loss(logits, batch["targets"])
 

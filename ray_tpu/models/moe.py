@@ -175,7 +175,7 @@ def moe_loss_fn(model: MoETransformer, fused_ce: bool = True,
                 return_hidden=True, mutable=["intermediates"])
             lm = chunked_cross_entropy(
                 h, params["wte"]["embedding"], batch["targets"],
-                chunk_size=ce_chunk)
+                chunk_size=ce_chunk, mesh=model.mesh)
         else:
             logits, state = model.apply(
                 {"params": params}, batch["tokens"],

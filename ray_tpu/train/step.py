@@ -97,6 +97,8 @@ def _take(name: str):
 def _on_compile_begins(event: str, _start: float, **_) -> None:
     if event in _COMPILE_KINDS:     # jax tells the three phases' starts
         _thread.depth = getattr(_thread, "depth", 0) + 1
+        if _thread.depth == 1 and _COMPILE_KINDS[event] == "trace":
+            tracing.take_trace_notes()      # an earlier trace's, unread
 
 
 def _on_compile_seconds(event: str, seconds: float, **kw) -> None:
@@ -116,6 +118,10 @@ def _on_compile_seconds(event: str, seconds: float, **kw) -> None:
     if _thread.depth:
         return
     attributes = {"kind": kind, "fun_name": kw.get("fun_name", "")}
+    if kind == "trace":
+        # What the traced code said of itself (``ce_rows_local``,
+        # ``ce_axes`` of a cross-entropy scanned per chip).
+        attributes.update(tracing.take_trace_notes())
     target = session.trace_target()
     if kind == "backend":
         outcome, load = _take("cache"), _take("load")

@@ -364,6 +364,26 @@ def record_train_span(name: str, mono_start: float, mono_end: float,
     return s
 
 
+# What code that runs while jax traces a program has to say about
+# the program it is building, by thread: ``train/step.py`` clears it
+# when the thread's outermost trace begins and puts it on that trace's
+# ``train.compile`` span when it ends.
+_trace_notes = threading.local()
+
+
+def note_trace(**attributes) -> None:
+    """Called at trace time (a decision taken from shapes and the
+    mesh): attributes for the ``train.compile`` span of the trace in
+    progress in this thread. Costs a dict update; kept by nobody
+    where no train step listens."""
+    vars(_trace_notes).setdefault("notes", {}).update(attributes)
+
+
+def take_trace_notes() -> dict:
+    """This thread's notes, handed over once."""
+    return vars(_trace_notes).pop("notes", {})
+
+
 def self_seconds(spans: list[Span]) -> dict[str, float]:
     """span id -> the span's time less what its direct children
     cover (their union, clipped to the span), on the monotonic

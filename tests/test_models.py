@@ -1,5 +1,7 @@
 """Model + sharded train-step tests on the 8-device CPU mesh."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -156,3 +158,232 @@ def test_chunked_ce_custom_vjp_matches_dense():
                                rtol=1e-3, atol=1e-5)
     np.testing.assert_allclose(np.asarray(ge1), np.asarray(ge2),
                                rtol=1e-3, atol=1e-5)
+
+
+# -- the chunked cross-entropy on a mesh: each chip scans its own rows ----
+
+# 8 x 24 tokens: on four chips 48 rows each, which chunks of 32 do not
+# divide, so every chip pads; masked positions on two chips.
+_CE_B, _CE_S, _CE_E, _CE_V, _CE_CHUNK = 8, 24, 32, 128, 32
+
+
+def _ce_inputs(batch=_CE_B):
+    hidden = jax.random.normal(jax.random.key(0), (batch, _CE_S, _CE_E))
+    emb = jax.random.normal(jax.random.key(1), (_CE_V, _CE_E)) * 0.1
+    tgt = jax.random.randint(jax.random.key(2), (batch, _CE_S), 0, _CE_V)
+    tgt = tgt.at[0, :5].set(-1).at[batch - 1, 3:9].set(-1)
+    return hidden, emb, tgt
+
+
+def _ce_value_and_grad(mesh, chunk=_CE_CHUNK):
+    from ray_tpu.models.gpt2 import chunked_cross_entropy
+
+    def loss(h, e, t):
+        return chunked_cross_entropy(h, e, t, chunk_size=chunk, mesh=mesh)
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))
+
+
+def _ce_on_mesh(mesh, hidden, emb, tgt, emb_spec=None):
+    """The three inputs placed as a train step has them: tokens over
+    dp/fsdp and sp (``batch_spec``), the head as ``emb_spec`` says."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from ray_tpu.train.step import batch_spec
+
+    tokens = NamedSharding(mesh, batch_spec(mesh, seq_sharded=True))
+    head = NamedSharding(mesh, emb_spec or PartitionSpec())
+    return (jax.device_put(hidden, tokens), jax.device_put(emb, head),
+            jax.device_put(tgt, tokens))
+
+
+def _mesh_of(axes):
+    n = int(np.prod(list(axes.values())))
+    return make_mesh(axes, devices=jax.devices()[:n])
+
+
+@pytest.mark.parametrize("axes", [
+    {"dp": 4}, {"dp": 2, "fsdp": 2}, {"dp": 2, "sp": 2}],
+    ids=["dp4", "dp2-fsdp2", "dp2-sp2"])
+def test_chunked_ce_on_a_mesh_equals_the_unsharded(axes):
+    """Loss, d hidden and d embedding of the per-chip scan equal the
+    global scan's: the same mean over the global count of unmasked
+    tokens, no gradient scaled by an axis size or reduced twice."""
+    hidden, emb, tgt = _ce_inputs()
+    want_l, (want_dh, want_de) = _ce_value_and_grad(None)(hidden, emb, tgt)
+    mesh = _mesh_of(axes)
+    placed = _ce_on_mesh(mesh, hidden, emb, tgt)
+    got_l, (got_dh, got_de) = _ce_value_and_grad(mesh)(*placed)
+    assert float(got_l) == float(want_l)
+    np.testing.assert_array_equal(np.asarray(got_dh), np.asarray(want_dh))
+    np.testing.assert_allclose(np.asarray(got_de), np.asarray(want_de),
+                               rtol=0, atol=1e-7)
+    # d hidden stays where its rows are
+    assert got_dh.sharding.is_equivalent_to(placed[0].sharding, 3)
+
+
+_HLO_LINE = re.compile(
+    r"^\s*(?:ROOT )?%?(?P<name>[\w.\-]+) = (?P<type>.*?) "
+    r"(?P<op>[a-z][\w\-]*)\((?P<rest>.*)$")
+_COLLECTIVES = {"all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+                "collective-permute", "collective-broadcast"}
+
+
+def _hlo_computations(text):
+    """computation name -> its instructions as (name, type, opcode,
+    op_name, whole line); the entry computation under ``ENTRY``."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$", line)
+        if head:
+            cur = comps.setdefault(
+                "ENTRY" if head.group(1) else head.group(2), [])
+            continue
+        m = _HLO_LINE.match(line)
+        if m and cur is not None:
+            op = m.group("op").removesuffix("-start").removesuffix("-done")
+            name = re.search(r'op_name="([^"]*)"', line)
+            cur.append((m.group("name"), m.group("type"), op,
+                        name.group(1) if name else "", line))
+    return comps
+
+
+def _crosses_devices(line) -> bool:
+    """False for a collective whose groups hold one device each: the
+    sum over a mesh axis of size 1, which the CPU compiler leaves in
+    the text (the TPU's removes it) and which moves nothing."""
+    groups = re.search(r"replica_groups=(\{\{[\d,{}]*\}\}|\[\d+,(\d+)\])",
+                       line)
+    if groups.group(2):
+        return int(groups.group(2)) > 1
+    return "," in groups.group(1).replace("},{", "")
+
+
+def _under_loss(instruction) -> bool:
+    from ray_tpu.observability import xplane
+    return xplane.scope_path(instruction[3]).split("/")[0] == "loss"
+
+
+def test_chunked_ce_on_dp4_scans_a_quarter_of_the_rows():
+    """The guard that fails where the chunk axis is sharded and the
+    partitioner gathers it (two all-gathers of ``[n, chunk, E]`` and
+    both ``while``s over all ``n`` chunks on every chip): here both
+    loops carry ``[n/4, chunk, E]``, nothing is gathered, and the only
+    collectives are the scalar sums and one all-reduce of the
+    embedding's gradient, outside any loop body."""
+    batch, chunk = 16, 32           # 384 rows: n = 12, three a chip
+    n = batch * _CE_S // chunk
+    hidden, emb, tgt = _ce_inputs(batch)
+    mesh = _mesh_of({"dp": 4})
+    fn = _ce_value_and_grad(mesh)
+    text = fn.lower(*_ce_on_mesh(mesh, hidden, emb, tgt)).compile().as_text()
+    comps = _hlo_computations(text)
+    rows_global = f"f32[{n},{chunk},{_CE_E}]"
+    rows_local = f"f32[{n // 4},{chunk},{_CE_E}]"
+
+    everything = [i for body in comps.values() for i in body]
+    assert not [i for i in everything if i[2] == "all-gather"]
+    assert not [i for i in everything if rows_global in i[1]]
+    whiles = [i for i in everything if i[2] == "while"]
+    assert len(whiles) == 2                     # forward and backward
+    for w in whiles:
+        assert rows_local in w[1], w[4]
+        assert f'"known_trip_count":{{"n":"{n // 4}"}}' in w[4]
+        assert "/loss/" in w[3]
+
+    collectives = [i for i in everything
+                   if i[2] in _COLLECTIVES and _crosses_devices(i[4])]
+    assert collectives and all(c in comps["ENTRY"] for c in collectives)
+    big = [c for c in collectives if "[]" not in c[1].split("{")[0]]
+    assert [(c[2], c[1].split("{")[0]) for c in big] == [
+        ("all-reduce", f"f32[{_CE_V},{_CE_E}]")], big
+    assert "transpose(" in big[0][3]
+    for c in collectives:                       # the rest: scalar sums
+        assert c[2] == "all-reduce" and _under_loss(c), c[4]
+        if c is not big[0]:
+            assert set(re.findall(r"[a-z]\d+\[(\d*)\]", c[1])) == {""}, c[4]
+
+
+@pytest.mark.parametrize("case", ["no-mesh", "one-device", "batch-of-1",
+                                  "vocab-over-tp"])
+def test_chunked_ce_fall_through_keeps_the_global_scan(case):
+    """Where there is nothing to split (no mesh, a one-device mesh),
+    where the batch does not divide the axes (init-time tracing), and
+    where the head is sharded on its vocabulary, the function takes the
+    global scan: still the dense loss, no ``shard_map`` in the lowering,
+    and on one device the very program of ``mesh=None``."""
+    from jax.sharding import PartitionSpec
+
+    from ray_tpu.models.gpt2 import cross_entropy_loss
+
+    hidden, emb, tgt = _ce_inputs(1 if case == "batch-of-1" else _CE_B)
+    mesh = {"no-mesh": lambda: None,
+            "one-device": lambda: _mesh_of({"dp": 1}),
+            "batch-of-1": lambda: _mesh_of({"dp": 4}),
+            "vocab-over-tp": lambda: _mesh_of({"dp": 2, "tp": 2})}[case]()
+    args = (hidden, emb, tgt)
+    if case == "vocab-over-tp":
+        args = _ce_on_mesh(mesh, hidden, emb, tgt,
+                           emb_spec=PartitionSpec("tp", None))
+    fn = _ce_value_and_grad(mesh)
+    loss, (dh, de) = fn(*args)
+
+    def dense(h, e):
+        return cross_entropy_loss(jnp.einsum("bse,ve->bsv", h, e), tgt)
+
+    want, (want_dh, want_de) = jax.value_and_grad(
+        dense, argnums=(0, 1))(hidden, emb)
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(dh), np.asarray(want_dh),
+                               rtol=1e-3, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(de), np.asarray(want_de),
+                               rtol=1e-3, atol=1e-5)
+
+    lowered = fn.lower(*args)
+    assert "shard_map" not in lowered.as_text(debug_info=True)
+    if case == "vocab-over-tp":
+        # the head stays sharded: no chip is handed all of it
+        assert de.sharding.is_equivalent_to(args[1].sharding, 2)
+    if case == "one-device":
+        # the text without locations, which is what jit compiles and
+        # the compile cache keys on
+        plain = _ce_value_and_grad(None).lower(hidden, emb, tgt)
+        assert lowered.as_text() == plain.as_text()
+
+
+def test_gpt2_loss_on_dp4_scans_local_rows_and_says_so():
+    """``gpt2_loss_fn`` hands the model's mesh on: a dp=4 step has no
+    all-gather under ``loss``, and the trace's ``train.compile`` span
+    carries the rows one chip scans and the axes they were mapped over
+    (absent where the function fell through)."""
+    from ray_tpu.util import tracing
+
+    cfg = GPT2Config.tiny()
+    batch = _gpt_batch(cfg, batch=8)
+
+    def traced_step(mesh):
+        model = GPT2(cfg, mesh=mesh)
+        opt = optax.adamw(1e-3)
+        state = init_train_state(
+            model.init_params(jax.random.key(0)), opt, mesh)
+        step = make_train_step(gpt2_loss_fn(model, ce_chunk=64), opt)
+        b = shard_batch(batch, mesh) if mesh is not None else batch
+        before = len(tracing.get_spans())
+        text = step.lower(state, b).compile().as_text()
+        traces = [s.attributes for s in tracing.get_spans()[before:]
+                  if s.name == "train.compile"
+                  and s.attributes["kind"] == "trace"
+                  and s.attributes["fun_name"] == "step"]
+        return text, traces
+
+    text, traces = traced_step(_mesh_of({"dp": 4}))
+    assert [(t["ce_rows_local"], t["ce_axes"]) for t in traces] == [
+        (8 * cfg.seq_len // 4, ["dp"])]
+    under_loss = [i for body in _hlo_computations(text).values()
+                  for i in body if _under_loss(i)]
+    assert under_loss
+    assert not [i for i in under_loss if i[2] == "all-gather"]
+
+    _, traces = traced_step(_mesh_of({"dp": 1}))
+    assert len(traces) == 1
+    assert not {"ce_rows_local", "ce_axes"} & set(traces[0])

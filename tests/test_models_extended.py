@@ -117,6 +117,37 @@ def test_moe_train_step_with_ep_mesh():
     assert float(m["loss"]) < float(m0["loss"])
 
 
+# ---------- the loss on a mesh, all three language models ----------
+
+@pytest.mark.parametrize("family, axes", [
+    ("llama", {"dp": 4}), ("llama", {"dp": 2, "sp": 2}),
+    ("moe", {"dp": 4}), ("moe", {"dp": 2, "ep": 2})],
+    ids=["llama-dp4", "llama-dp2-sp2", "moe-dp4", "moe-dp2-ep2"])
+def test_lm_loss_on_a_mesh_equals_the_loss_without(family, axes):
+    """``llama_loss_fn`` and ``moe_loss_fn`` hand their model's mesh to
+    the chunked cross-entropy, which then scans each chip's rows: the
+    loss and the head's gradient are those of the model with no mesh
+    (up to the attention path, which the mesh changes too)."""
+    model_cls, cfg, loss_of = {
+        "llama": (Llama, LlamaConfig.tiny(), llama_loss_fn),
+        "moe": (MoETransformer, MoEConfig.tiny(), moe_loss_fn)}[family]
+    n = int(np.prod(list(axes.values())))
+    mesh = make_mesh(axes, devices=jax.devices()[:n])
+    plain = model_cls(cfg)
+    params = plain.init_params(jax.random.key(0))
+    batch = _lm_batch(cfg, batch=4)
+    want, want_g = jax.jit(jax.value_and_grad(
+        loss_of(plain, ce_chunk=64)))(params, batch)
+    got, got_g = jax.jit(jax.value_and_grad(
+        loss_of(model_cls(cfg, mesh=mesh), ce_chunk=64)))(
+            params, shard_batch(batch, mesh, seq_sharded=True))
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-3)
+    np.testing.assert_allclose(
+        np.asarray(got_g["wte"]["embedding"], np.float32),
+        np.asarray(want_g["wte"]["embedding"], np.float32),
+        rtol=5e-2, atol=2e-4)
+
+
 # ---------- vit ----------
 
 def test_vit_forward_and_train():

@@ -527,6 +527,31 @@ def test_nested_jits_fold_into_the_outermost_trace():
     assert [s.attributes["fun_name"] for s in traces] == ["outer_fn"]
 
 
+def test_trace_notes_reach_their_trace_span_and_no_other():
+    """What traced code says of itself (``tracing.note_trace``: the
+    chunked cross-entropy's ``ce_rows_local`` / ``ce_axes``) lands on
+    the ``trace`` span of the program being traced: not on its lower
+    or backend span, not on the next program's, and a note left where
+    nobody listened does not reach a later trace."""
+    def noted(x):
+        tracing.note_trace(rows=7, axes=["dp"])
+        return x + 1.0
+
+    train_step._listen_for_compiles()
+    x = jnp.ones((2,))
+    tracing.note_trace(stale=True)
+    before = len(tracing.get_spans())
+    jax.jit(noted)(x)
+    jax.jit(lambda x: x * 3.0)(x)
+    spans = [s.attributes for s in tracing.get_spans()[before:]
+             if s.name == "train.compile"]
+    with_notes = [a for a in spans if set(a) - {"kind", "fun_name", "cache"}]
+    assert with_notes == [{"kind": "trace", "fun_name": "noted",
+                           "rows": 7, "axes": ["dp"]}]
+    assert [a["fun_name"] for a in spans if a["kind"] == "trace"] == [
+        "noted", "<lambda>"]
+
+
 def test_the_listener_is_installed_once_a_process():
     from jax._src import monitoring
     for _ in range(3):
