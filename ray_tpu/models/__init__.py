@@ -1,4 +1,11 @@
-"""Model zoo: flax implementations annotated for mesh sharding."""
+"""Model zoo: flax implementations annotated for mesh sharding.
+
+``Llama`` is the RMSNorm / RoPE / SwiGLU decoder; its config also
+expresses OLMoE-1B-7B (``LlamaConfig.olmoe_1b_7b``), whose routed
+experts are ``ops/moe.py::routed_ffn`` (dropless, top-k).
+``MoETransformer`` is the older top-1, capacity-dropping switch model
+on GPT-2 blocks, which goes when the dropless path runs under ``ep``
+(ROADMAP C5)."""
 
 from ray_tpu.models.gpt2 import GPT2, GPT2Config
 from ray_tpu.models.llama import Llama, LlamaConfig

@@ -122,7 +122,15 @@ DEFAULT_PARAM_PATTERNS: list[tuple[str, tuple[str | None, ...]]] = [
     (r"pos_embed", (None, None, "embed")),       # ViT [1, P, E]
     (r"wpe|pos_emb", (None, "embed")),
     (r"wte|embedding", ("vocab", "embed")),
-    # MoE experts (models/moe.py): expert dim -> ep axis
+    # The routed experts of models/llama.py (OLMoE: ops/moe.py's
+    # dropless routed_ffn): mlp.gate is the router, mlp.experts the
+    # stacked [E, d, f] / [E, f, d] weights. Listed before the dense
+    # mlp rules, whose "gate" would take the router. routed_ffn
+    # replicates the experts inside its shard_map (ep or tp > 1 raises).
+    (r"mlp.*experts.*(gate_proj|up_proj)", ("experts", "embed", "mlp")),
+    (r"mlp.*experts.*down_proj", ("experts", "mlp", "embed")),
+    (r"mlp/gate/kernel", ("embed", None)),
+    # The top-1 switch experts (models/moe.py): expert dim -> ep axis
     (r"moe.*router", ("embed", None)),
     (r"moe.*w_up", ("experts", "embed", "mlp")),
     (r"moe.*w_down", ("experts", "mlp", "embed")),

@@ -48,15 +48,25 @@ def init_train_state(params, optimizer, mesh=None, extra=None,
 
 
 def _step_body(loss_fn, optimizer, has_extra, grad_norm):
+    def loss_and_report(params, batch):
+        """A loss function may return ``(loss, report)``: the report's
+        scalars ride beside the loss in the step's metrics. A scalar
+        loss has the empty report, which adds nothing to the traced
+        program."""
+        out = loss_fn(params, batch)
+        return out if isinstance(out, tuple) else (out, {})
+
     def step(state: TrainState, batch) -> tuple[TrainState, dict]:
         if has_extra:
             (loss, new_extra), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params, state.extra, batch)
+            report = {}
         else:
-            loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
+            (loss, report), grads = jax.value_and_grad(
+                loss_and_report, has_aux=True)(state.params, batch)
             new_extra = state.extra
         import optax
-        metrics = {"loss": loss}
+        metrics = {"loss": loss, **report}
         # The forward and backward carry the model's scopes; everything
         # after them is the ``optimizer`` scope (docs/observability.md).
         with jax.named_scope("optimizer"):
@@ -168,7 +178,9 @@ def make_train_step(loss_fn: Callable, optimizer,
     compiled program with the param/opt-state buffers donated — the
     update happens in place in HBM, no re-materialized param copy.
 
-    loss_fn: (params, batch) -> loss            (has_extra=False)
+    loss_fn: (params, batch) -> loss, or (loss, report) with a dict
+             of scalars that the step adds to its metrics (the
+             routed experts' losses and load)      (has_extra=False)
              (params, extra, batch) -> (loss, new_extra)  (True)
     Returns step(state, batch) -> (state, metrics).
     ``grad_norm=False`` skips the global-norm metric (a full f32 read

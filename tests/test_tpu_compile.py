@@ -20,9 +20,10 @@ from jax.sharding import (  # noqa: E402
 
 from ray_tpu.ops.pallas.flash_attention import flash_attention  # noqa: E402
 
-# [batch, seq, heads, head_dim]: chip_smoke.py's GPT-2 124M shape, and
-# one head-dim-128 shape (multi-block path: 2048 = 2 x 1024 blocks).
-SHAPES = [(32, 1024, 12, 64), (8, 2048, 32, 128)]
+# [batch, seq, heads, head_dim]: chip_smoke.py's GPT-2 124M shape, one
+# head-dim-128 shape (multi-block path: 2048 = 2 x 1024 blocks), and the
+# OLMoE cell's (olmoe-1b-7b.b4-t4096: four 1,024-blocks at D=128).
+SHAPES = [(32, 1024, 12, 64), (8, 2048, 32, 128), (4, 4096, 16, 128)]
 
 
 @pytest.fixture(scope="module")
@@ -96,3 +97,29 @@ def test_every_model_takes_its_attention_from_the_mesh(
                              sharding=NamedSharding(mesh, P()))
     text = jax.jit(attn).lower(x, x, x).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_routed_experts_compile_for_v5e(v5e, monkeypatch):
+    """The OLMoE cell's routed layer at the published widths (16,384
+    tokens, 64 experts x 1,024, top-8), forward and backward: on a TPU
+    the grouped matmuls are the megablox Pallas kernel, which Mosaic has
+    to take at the tile ``ops/moe.py`` chose (nine custom calls: three
+    matrices, each forward, for its input and for its weights)."""
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe.grouped_matmul_path() == "megablox_gmm"
+    one = SingleDeviceSharding(v5e[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(x, router, gate, up, down):
+        y, aux, z, _ = moe.routed_ffn(x, router, gate, up, down, top_k=8)
+        return y.astype(jnp.float32).sum() + aux + z
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg((4, 4096, 2048), jnp.bfloat16), arg((2048, 64), jnp.float32),
+        arg((64, 2048, 1024), jnp.float32), arg((64, 2048, 1024), jnp.float32),
+        arg((64, 1024, 2048), jnp.float32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 9

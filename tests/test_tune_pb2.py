@@ -70,8 +70,11 @@ def _pb2_trainable(config):
         # Pace the steps so the population genuinely overlaps in
         # time — on the sharded 1-core CI host, unpaced trials can
         # serialize and the exploit quantile never sees 2+ live
-        # trials (same pacing as the PBT e2e).
-        time.sleep(0.03)
+        # trials. 0.03 s a step (the PBT e2e's pacing) let a whole
+        # trial (0.36 s) end before the next worker had booted when the
+        # cores were busy: 1 run in 3 failed with all of them loaded,
+        # and the whole run under xdist twice in a row (PR 27).
+        time.sleep(0.15)
         score += 1.0 - (lr - 0.8) ** 2          # best at lr=0.8
         d = tempfile.mkdtemp()
         with open(os.path.join(d, "state.json"), "w") as f:
