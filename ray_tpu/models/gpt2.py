@@ -112,12 +112,20 @@ class CausalSelfAttention(nn.Module):
     def __call__(self, x, attn_fn: Callable, deterministic: bool = True):
         cfg = self.config
         B, T, _ = x.shape
-        # One fused qkv projection as an einsum with a [E, 3, H, D]
-        # kernel: the head split falls out of the parameter layout, so
-        # no post-matmul reshape/transpose copies hit HBM (the
-        # [B,T,H,D] outputs feed the flash kernel's fold directly and
-        # XLA folds the permutation into the matmul epilogue). The
-        # sharding table's qkv pattern still splits heads over tp.
+        # The qkv projection: one kernel kept as [E, 3, H, D] (the
+        # sharding table's qkv pattern splits its heads over tp),
+        # applied as three matmuls that see the heads merged. q, k and
+        # v then each come out [B, T, H*D] as a matmul wrote them —
+        # the layout the flash kernel's blocks index — and the
+        # kernel's output is the output projection's operand as it
+        # stands: nothing is copied on either side. What this avoids
+        # (PR 25's trace: 36.6 ms of a 250 ms step): an array whose
+        # last dimension is D=64 is half padding in the chip's
+        # 128-lane tiles, so XLA lays a [.., H, 64] matmul output out
+        # with T innermost and copies it on the way to the kernel; and
+        # one matmul whose [B, 3, T, H*D] output is sliced three ways
+        # pays for the slices (226.2 against 220.5 ms a step on the
+        # v5e, PERF.md section 6, PR 29).
         kernel_init = nn.initializers.normal(0.02)
         qkv_w = self.param(
             "qkv_kernel", kernel_init,
@@ -126,11 +134,13 @@ class CausalSelfAttention(nn.Module):
         qkv_b = self.param(
             "qkv_bias", nn.initializers.zeros,
             (3, cfg.n_head, cfg.head_dim), cfg.param_dtype)
-        qkv = jnp.einsum(
-            "bte,eshd->bsthd", x.astype(cfg.dtype),
-            qkv_w.astype(cfg.dtype)) \
-            + qkv_b.astype(cfg.dtype)[None, :, None]
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
+        x = x.astype(cfg.dtype)
+        w = qkv_w.astype(cfg.dtype).reshape(cfg.n_embd, 3, -1)
+        bias = qkv_b.astype(cfg.dtype).reshape(3, -1)
+        q, k, v = (
+            (jnp.einsum("bte,ef->btf", x, w[:, i])
+             + bias[i]).reshape(B, T, cfg.n_head, cfg.head_dim)
+            for i in range(3))
         y = attn_fn(q, k, v)
         proj_w = self.param(
             "proj_kernel",
@@ -138,9 +148,10 @@ class CausalSelfAttention(nn.Module):
             (cfg.n_head, cfg.head_dim, cfg.n_embd), cfg.param_dtype)
         proj_b = self.param("proj_bias", nn.initializers.zeros,
                             (cfg.n_embd,), cfg.param_dtype)
-        y = jnp.einsum("bthd,hde->bte", y.astype(cfg.dtype),
-                       proj_w.astype(cfg.dtype)) + proj_b.astype(
-                           cfg.dtype)
+        y = jnp.einsum("btf,fe->bte",
+                       y.astype(cfg.dtype).reshape(B, T, -1),
+                       proj_w.astype(cfg.dtype).reshape(-1, cfg.n_embd)) \
+            + proj_b.astype(cfg.dtype)
         if cfg.dropout > 0:
             y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
         return y

@@ -2,7 +2,10 @@
 
 - ``causal_attention``: dense causal attention. On single-device TPU
   with flash-blockable shapes it dispatches to the Pallas flash kernel
-  (ops/pallas/flash_attention.py); otherwise
+  (ops/pallas/flash_attention.py), which reads q, k, v as the
+  projections wrote them ([B, T, H, D] is [B, T, H*D] for free; its
+  grid runs over the batch and 128-lane blocks of H*D) and writes
+  the output projection's operand: no copy on either side. Otherwise
   ``jax.nn.dot_product_attention`` (XLA fused path).
 - ``ring_attention``: sequence-parallel causal attention over an ICI
   ring. The reference has NO sequence parallelism in-tree (SURVEY.md
@@ -266,11 +269,12 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
         # device runs the local block — this is what lets the Pallas
         # flash kernel (no SPMD rule of its own) serve the multi-chip
         # dense path.
-        spec = P(batch if batch else None, None, heads, None)
-        local = functools.partial(causal_attention, force_flash=True)
-        sharded = jax.shard_map(local, mesh=mesh,
-                                in_specs=(spec, spec, spec),
-                                out_specs=spec, check_vma=False)
+        # The shards cross the boundary with heads merged, [B, T, H*D]
+        # (a block of heads is a block of the merged dimension): a
+        # [.., H, 64] array that has to exist there is half padding in
+        # the chip's 128-lane tiles, so XLA lays it out with T
+        # innermost and copies it into and out of the kernel's layout.
+        spec = P(batch if batch else None, None, heads)
         n_batch = 1
         for a in batch:
             n_batch *= mesh.shape[a]
@@ -281,7 +285,18 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
             # used by init tracing) take the plain XLA path.
             if q.shape[0] % n_batch or q.shape[2] % n_heads:
                 return causal_attention(q, k, v)
-            return sharded(q, k, v)
+            d = q.shape[-1]
+
+            def local(*qkv):
+                q, k, v = (x.reshape(*x.shape[:2], -1, d) for x in qkv)
+                return causal_attention(
+                    q, k, v, force_flash=True).reshape(qkv[0].shape)
+
+            merged = jax.shard_map(local, mesh=mesh,
+                                   in_specs=(spec, spec, spec),
+                                   out_specs=spec, check_vma=False)
+            return merged(*(x.reshape(*x.shape[:2], -1)
+                            for x in (q, k, v))).reshape(q.shape)
         return dispatch
 
     batch = tuple(a for a in batch_axes if mesh.shape.get(a, 1) > 1)

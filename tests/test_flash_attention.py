@@ -5,6 +5,8 @@ program through the Pallas interpreter so block logic, masking, and
 the custom VJP are validated in CI without a chip.
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -14,6 +16,10 @@ from ray_tpu.ops.pallas.flash_attention import (
     flash_attention,
     flash_attention_shapes_ok,
 )
+from ray_tpu.util import tracing
+
+# the package exports the function under the module's name
+fa = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
 
 
 def _rand_qkv(b=2, t=256, h=4, d=64, dtype=jnp.float32, seed=0):
@@ -52,6 +58,77 @@ def test_gradients_match_dense():
     g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         assert float(jnp.abs(a - b).max()) < 5e-4
+
+
+def _folded(q, k, v, *, causal, block):
+    """The same kernels on [B*H, T, D], one head a block: the layout
+    every shape took before the kernels indexed [B, T, H*D], and the
+    one a shape that cannot be blocked on 128 lanes still takes."""
+    b, t, h, d = q.shape
+    static = fa._Static(d ** -0.5, causal, block, block, d, hpb=1,
+                        interpret=True)
+    out = fa._flash_core(*(x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+                           for x in (q, k, v)), static)
+    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+
+def _out_and_grads(attn, q, k, v):
+    def loss(q, k, v):
+        o = attn(q, k, v)
+        return jnp.sum(o * jnp.cos(o)), o
+    (_, out), grads = jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return (out, *grads)
+
+
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["causal", "full"])
+@pytest.mark.parametrize("blocks", [1, 2], ids=["single", "multi"])
+@pytest.mark.parametrize("h,d", [(4, 64), (2, 128)],
+                         ids=["two_heads_a_block", "one_head_a_block"])
+def test_direct_layout_matches_dense_and_folded(h, d, blocks, causal):
+    """The kernels on the projections' [B, T, H*D] (two heads in a
+    128-lane block at D=64, one at D=128; one block a row and the
+    streaming path) against XLA's dense attention and against the
+    same kernels on the folded layout: output, dq, dk, dv."""
+    t = 128 * blocks
+    q, k, v = _rand_qkv(b=2, t=t, h=h, d=d, seed=3)
+    assert fa._heads_per_block(h, d) == 128 // d
+    got = _out_and_grads(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=128, block_k=128,
+            interpret=True), q, k, v)
+    assert tracing.take_trace_notes() == {
+        "flash_layout": "bthd", "flash_lanes_per_block": 128,
+        "flash_path": "single_block" if blocks == 1 else "multi_block"}
+    dense = _out_and_grads(
+        lambda q, k, v: jax.nn.dot_product_attention(
+            q, k, v, is_causal=causal), q, k, v)
+    folded = _out_and_grads(
+        lambda q, k, v: _folded(q, k, v, causal=causal, block=128),
+        q, k, v)
+    for name, a, ref, f in zip(("out", "dq", "dk", "dv"), got, dense,
+                               folded):
+        assert float(jnp.abs(a - ref).max()) < 5e-4, name
+        assert float(jnp.abs(a - f).max()) < 2e-5, name
+
+
+@pytest.mark.parametrize("h,d,lanes", [(3, 64, 64), (4, 32, 32),
+                                       (2, 192, 192)],
+                         ids=["odd_heads", "d32", "d192"])
+def test_shapes_off_the_lane_tiles_take_the_fold_and_say_so(h, d, lanes):
+    """An odd head count at D=64 (H*D is not a multiple of 128) and a
+    head width that is neither 64 nor a multiple of 128: decided from
+    the shapes, noted for the trace span, and still right."""
+    q, k, v = _rand_qkv(b=1, t=128, h=h, d=d, seed=4)
+    assert fa._heads_per_block(h, d) == 0
+    tracing.take_trace_notes()
+    out = flash_attention(q, k, v, causal=True, interpret=True)
+    assert tracing.take_trace_notes() == {
+        "flash_layout": "folded", "flash_lanes_per_block": lanes,
+        "flash_path": "single_block"}
+    ref = jax.nn.dot_product_attention(q, k, v, is_causal=True)
+    assert float(jnp.abs(out - ref).max()) < 2e-5
 
 
 def test_uneven_block_sizes():

@@ -7,7 +7,9 @@ slice off the tiling, too much VMEM, a kernel that cannot be
 partitioned. Nothing runs, so nothing here is a result or a time.
 """
 
+import importlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -59,6 +61,163 @@ def test_flash_kernel_compiles_for_v5e(v5e, shape, grad):
     fn = jax.grad(_loss, argnums=(0, 1, 2)) if grad else _loss
     text = jax.jit(fn).lower(x, x, x).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("shape", SHAPES,
+                         ids=["x".join(map(str, s)) for s in SHAPES])
+def test_flash_kernel_compiles_under_shard_map_on_dp4(
+        v5e, monkeypatch, shape, grad):
+    """The same three shapes as the four-chip cell runs them: batch
+    over dp=4, the kernel under shard_map, its grid over a chip's own
+    rows."""
+    from ray_tpu.ops.attention import make_sharded_causal_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(v5e, ("dp",))
+    attn = make_sharded_causal_attention(mesh)
+
+    def loss(q, k, v):
+        return attn(q, k, v).astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else loss
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        1 if not grad else 2 if shape[1] == 1024 else 3)
+
+
+MOVES = re.compile(r"copy|transpose|pad|slice|concatenate|bitcast")
+ENTRY_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
+SEES_THROUGH = ("get-tuple-element", "bitcast")
+
+
+def _moves_next_to_the_kernels(hlo: str) -> list[str]:
+    """Instructions of the entry computation that only move data (a
+    copy, transpose, pad, slice or concatenate, bare or as a fusion
+    XLA named after one) and that write a kernel's operand or read a
+    kernel's result, tuple plumbing and bitcasts seen through."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    made, calls = {}, {}
+    for line in entry.splitlines():
+        m = ENTRY_INSTRUCTION.match(line)
+        if m:
+            name, result, opcode, rest = m.groups()
+            operands, _, attributes = rest.partition("), ")
+            made[name] = (opcode, result,
+                          re.findall(r"%([\w.\-]+)", operands))
+            if opcode == "fusion":
+                calls[name] = re.search(r"calls=%([\w.\-]+)",
+                                        attributes).group(1)
+
+    def is_move(name):
+        opcode = made[name][0]
+        if opcode != "fusion":
+            return bool(MOVES.fullmatch(opcode))
+        # XLA names a fusion after what it holds: a matmul that also
+        # bitcasts is no move
+        body = hlo[hlo.index(f"\n%{calls[name]} "):]
+        return bool(MOVES.match(name)) and " convolution(" not in body[
+            :body.index("\n}")]
+
+    def source(name):
+        while made[name][0] in SEES_THROUGH:
+            name = made[name][2][0]
+        return name
+
+    kernels = {n for n, (op, _, _) in made.items() if op == "custom-call"}
+    found = set()
+    for kernel in kernels:
+        found |= {source(o) for o in made[kernel][2] if is_move(source(o))}
+    for name, (opcode, _, operands) in made.items():
+        if opcode not in SEES_THROUGH and is_move(name) and any(
+                source(o) in kernels for o in operands):
+            found.add(name)
+    return sorted(f"{n} = {made[n][1]} {made[n][0]}" for n in found)
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "dp4"])
+def test_nothing_is_copied_between_the_projections_and_the_kernel(
+        v5e, monkeypatch, chips):
+    """The GPT-2 cells' attention block (32 x 1,024 tokens a chip, 12
+    heads of 64), forward and backward, compiled for the chip: the qkv
+    projection's matmuls write what the forward kernel reads, the
+    kernel writes what the output projection reads, and the backward
+    kernel's three gradients are the operands of the projections'
+    backward matmuls — bare on one chip, and on dp=4 across the
+    shard_map's boundary. Before the kernel indexed [B, T, H*D] there
+    were a transpose and a copy a tensor a pass (PERF.md section 6,
+    PR 29): 36.6 ms of a 250 ms step."""
+    from ray_tpu.models.gpt2 import CausalSelfAttention, GPT2Config
+    from ray_tpu.ops.attention import make_sharded_causal_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = GPT2Config.small()
+    block = CausalSelfAttention(cfg)
+    mesh = Mesh(v5e[:chips], ("dp",))
+    attn = make_sharded_causal_attention(mesh)
+    x = jax.ShapeDtypeStruct((32 * chips, 1024, cfg.n_embd), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=NamedSharding(mesh, P())),
+        jax.eval_shape(
+            lambda: block.init(jax.random.key(0),
+                               jnp.zeros((1, 128, cfg.n_embd), cfg.dtype),
+                               jax.nn.dot_product_attention))["params"])
+
+    def loss(params, x, g):     # g: the cotangent the next layer sends
+        y = block.apply({"params": params}, x, attn)
+        return (y.astype(jnp.float32) * g.astype(jnp.float32)).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x, x).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert _moves_next_to_the_kernels(hlo) == []
+
+
+def test_twelve_layers_lower_each_kernel_once_and_a_second_trace_none(
+        v5e, monkeypatch):
+    """The price of a kernel in warm set-up is its trace and its
+    lowering to Mosaic (PR 28 paid both 24 times a trace of the step,
+    +5.15 s of ``step.trace_lower_s``, and was refused for it). The
+    functions that hold the pallas_calls are jitted: twelve layers at
+    one shape lower to one function a kernel, called twelve times, and
+    the step's second trace (its donated outputs come back in other
+    layouts) finds the first one's."""
+    fa = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
+    bodies = []
+    scores = fa._masked_scores
+    monkeypatch.setattr(
+        fa, "_masked_scores",
+        lambda *a, **kw: bodies.append(1) or scores(*a, **kw))
+
+    def stack(x, scales):
+        for i in range(12):
+            with jax.named_scope(f"h_{i}"):
+                # a scale no other test of this process has traced
+                x = flash_attention(x * scales[i], x, x, scale=0.1171875)
+        return x.astype(jnp.float32).sum()
+
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct(SHAPES[0], jnp.bfloat16, sharding=one)
+    scales = jax.ShapeDtypeStruct((12,), jnp.bfloat16, sharding=one)
+    text = jax.jit(jax.grad(stack)).lower(x, scales).as_text()
+    assert 2 <= text.count("tpu_custom_call") <= 3
+    assert text.count("call @_flash_fwd") == 12
+    assert text.count("call @_flash_bwd") == 12
+    traced = len(bodies)
+    assert traced > 0
+
+    def again(x, scales):       # a new function: a new trace
+        return stack(x, scales) * 2.0
+
+    text = jax.jit(jax.grad(again)).lower(x, scales).as_text()
+    assert len(bodies) == traced
+    assert 2 <= text.count("tpu_custom_call") <= 3
 
 
 @pytest.mark.parametrize("n_devices", [4, 1], ids=["dp4", "one_of_four"])

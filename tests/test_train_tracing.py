@@ -552,6 +552,59 @@ def test_trace_notes_reach_their_trace_span_and_no_other():
         "noted", "<lambda>"]
 
 
+@pytest.mark.parametrize("n_head, layout, lanes", [
+    (2, "bthd", 128), (3, "folded", 64)],
+    ids=["gpt2_even_heads", "odd_heads"])
+def test_flash_notes_reach_both_traces_of_the_step_and_no_other_span(
+        monkeypatch, n_head, layout, lanes):
+    """Which layout the flash kernel took (``flash_attention``'s note:
+    the projections' own [B, T, H*D], or the fold of a shape that
+    cannot be blocked on 128 lanes) is on the step's ``trace`` span
+    and on no other; and on the span of a second trace too, which
+    finds the jitted kernel functions' own traces cached: the note is
+    made outside them. The kernel is steered into interpret mode and
+    the dispatch onto it here, in the test (no chip in the sandbox)."""
+    import functools
+    import importlib
+
+    from jax.sharding import Mesh
+
+    from ray_tpu.models.gpt2 import GPT2, GPT2Config, gpt2_loss_fn
+    from ray_tpu.ops import attention
+
+    fa = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(attention, "flash_eligible",
+                        fa.flash_attention_shapes_ok)
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))
+    cfg = GPT2Config.tiny(n_head=n_head, n_embd=64 * n_head, seq_len=128,
+                          n_layer=2)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape((1,) * 6),
+                ("pp", "dp", "fsdp", "ep", "sp", "tp"))
+    model = GPT2(cfg, mesh=mesh)
+    opt = optax.sgd(0.1)
+    state = train_step.init_train_state(
+        model.init_params(jax.random.key(0)), opt, mesh)
+    tokens = jnp.zeros((2, cfg.seq_len), jnp.int32)
+    batch = {"tokens": tokens, "targets": tokens}
+    want = {"flash_layout": layout, "flash_lanes_per_block": lanes,
+            "flash_path": "single_block"}
+    for _ in range(2):      # the second: a new jit of the same step
+        before = len(tracing.get_spans())
+        step = train_step.make_train_step(
+            gpt2_loss_fn(model, ce_chunk=64), opt, donate=False)
+        _, metrics = step(state, batch)
+        assert np.isfinite(float(metrics["loss"]))
+        spans = _compile_spans_since(before, "step")
+        kinds = [s.attributes["kind"] for s in spans]
+        assert {"trace", "lower", "backend"} <= set(kinds)
+        for s in spans:
+            noted = {k: v for k, v in s.attributes.items()
+                     if k.startswith("flash_")}
+            assert noted == (want if s.attributes["kind"] == "trace"
+                             else {}), s.attributes
+
+
 def test_the_listener_is_installed_once_a_process():
     from jax._src import monitoring
     for _ in range(3):
