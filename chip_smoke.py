@@ -196,7 +196,8 @@ def train_loop(config: dict) -> None:
         f["ok"] = devs[0].platform == PLATFORM and len(devs) == chips
 
     cfg = model_config()
-    # The bench's optimizer (bench.py gpt2_main): bf16 first moment.
+    # The GPT-2 cells' optimizer (benchmark/configs/gpt2-124m.json):
+    # bf16 first moment.
     opt = optax.adamw(3e-4, weight_decay=0.1, mu_dtype=jnp.bfloat16)
     mesh = make_mesh({"dp": chips})
     batch_size = BATCH_PER_CHIP * chips

@@ -1068,8 +1068,8 @@ class DataIterator:
         ``prefetch`` device-resident batches queued ahead of the
         consumer — host decode + H2D transfer overlap device compute
         (the multi-host device-prefetch path, SURVEY.md §2.4
-        data-pipeline row; same pipeline as ``bench.py``'s hot loop
-        via ``ray_tpu.train.prefetch_to_device``)."""
+        data-pipeline row; the pipeline of
+        ``ray_tpu.train.prefetch_to_device``)."""
         from ray_tpu.train.prefetch import DevicePrefetcher
         if prefetch is None:
             from ray_tpu.data.context import DataContext

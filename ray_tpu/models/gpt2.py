@@ -43,13 +43,6 @@ class GPT2Config:
     dtype: Any = jnp.bfloat16        # compute dtype
     param_dtype: Any = jnp.float32
     remat: bool = False
-    # What remat may KEEP from the fwd pass (jax.checkpoint_policies):
-    # "nothing" recomputes everything (min HBM, max recompute FLOPs);
-    # "dots" / "dots_no_batch" keep matmul outputs so backward only
-    # re-runs the cheap VPU ops; "everything" disables rematting while
-    # keeping the checkpoint structure. Sweepable via
-    # RAY_TPU_BENCH_REMAT in bench.py.
-    remat_policy: str = "nothing"
     attn_impl: str = "auto"          # "auto" | "dense" | "ring"
     sp_axis: str = "sp"
 
@@ -84,25 +77,6 @@ class GPT2Config:
             self.seq_len
         per_block = 12 * e * e + 13 * e  # qkv+proj+mlp + norms/biases
         return v * e + s * e + l * per_block + 2 * e
-
-
-_REMAT_POLICIES = {
-    "nothing": "nothing_saveable",
-    "dots": "checkpoint_dots",
-    "dots_no_batch": "checkpoint_dots_with_no_batch_dims",
-    "everything": "everything_saveable",
-}
-
-
-def remat_policy(name: str):
-    """Resolve a GPT2Config.remat_policy name to a
-    ``jax.checkpoint_policies`` policy callable."""
-    try:
-        return getattr(jax.checkpoint_policies, _REMAT_POLICIES[name])
-    except KeyError:
-        raise ValueError(
-            f"unknown remat policy {name!r}; "
-            f"one of {sorted(_REMAT_POLICIES)}") from None
 
 
 class CausalSelfAttention(nn.Module):
@@ -240,7 +214,7 @@ class GPT2(nn.Module):
         if cfg.remat:
             block_cls = nn.remat(
                 Block, static_argnums=(2, 3),
-                policy=remat_policy(cfg.remat_policy))
+                policy=jax.checkpoint_policies.nothing_saveable)
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):
                 x = block_cls(cfg, name=f"h_{i}")(
@@ -296,17 +270,6 @@ def _chunk_logits(x_c, emb):
                       preferred_element_type=jnp.float32)
 
 
-def _ce_unroll() -> int:
-    """Chunks are independent (the carry is two scalar adds): a small
-    unroll lets XLA overlap chunk matmuls with the previous chunk's
-    VPU softmax work instead of serializing on the scan boundary."""
-    import os
-    try:
-        return max(1, int(os.environ.get("RAY_TPU_CE_UNROLL", 1)))
-    except ValueError:
-        return 1
-
-
 def _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index):
     def one(carry, xt):
         x_c, t_c = xt
@@ -321,7 +284,7 @@ def _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index):
 
     return jax.lax.scan(
         one, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
-        (rows_c, tgt_c), unroll=_ce_unroll())
+        (rows_c, tgt_c))
 
 
 @jax.named_scope("loss")
@@ -358,8 +321,7 @@ def _chunked_ce_core_bwd(ignore_index, res, g):
         return demb, dx
 
     demb0 = jnp.zeros(emb.shape, jnp.float32)
-    demb, dx_c = jax.lax.scan(one, demb0, (rows_c, tgt_c, lse_c),
-                              unroll=_ce_unroll())
+    demb, dx_c = jax.lax.scan(one, demb0, (rows_c, tgt_c, lse_c))
     return dx_c, demb.astype(emb.dtype), None
 
 

@@ -30,10 +30,9 @@ HloModuleProto.computations=3, HloComputationProto.instructions=2,
 HloInstructionProto.name=1 .metadata=7 .id=35 .operand_ids=36,
 OpMetadata.op_name=2).
 
-Consumers: ``bench.py`` (BENCH ``extra.profile_slices``),
-``observability.profiler.device_trace_summary`` (the remote
+Consumers: ``observability.profiler.device_trace_summary`` (the remote
 ``profile_device`` post-processing, ``ray_tpu profile --device``), and
-the tier-1 smoke lane (the CPU backend also emits xplane files, so the
+the tier-1 tests (the CPU backend also emits xplane files, so the
 parser is testable without a chip).
 """
 
@@ -413,8 +412,8 @@ def _module_at(modules: list[dict], t: int) -> str:
 
 def summarize_trace(logdir: str, top_k: int = 5,
                     steps: int = 1) -> dict:
-    """Aggregate a capture into the slice breakdown an operator (and
-    ``bench.py``) reads, by the benchmark's rules: self time of the
+    """Aggregate a capture into the slice breakdown an operator
+    reads, by the rules of ``benchmark/``: self time of the
     ``XLA Ops`` line of the busiest device plane, classed by HLO
     opcode and fusion kind, and grouped by program scope.
 

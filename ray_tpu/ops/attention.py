@@ -36,9 +36,8 @@ def _flash_ok(q, k, v) -> bool:
 
 def flash_eligible(t: int, d: int) -> bool:
     """Would ``causal_attention`` dispatch [*, t, *, d] self-attention
-    to the Pallas flash kernel on this backend (absent an
-    ``RAY_TPU_ATTN_KERNEL`` override)? Benchmarks use this to refuse
-    silently measuring the XLA fallback."""
+    to the Pallas flash kernel on this backend? Benchmarks use this to
+    refuse silently measuring the XLA fallback."""
     from ray_tpu.ops.pallas.flash_attention import (
         flash_attention_shapes_ok,
     )
@@ -52,11 +51,10 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Causal attention [B, T, H, D] -> [B, T, H, D].
 
     Single-device TPU with cleanly-blocking shapes runs the Pallas
-    flash kernel (ops/pallas/flash_attention.py — measured ~25% faster
-    fwd and ~35% faster fwd+bwd than the XLA fused path on v5e).
-    Multi-device programs must NOT hit the bare kernel (pallas_call has
-    no SPMD partitioning rule): use make_sharded_causal_attention,
-    which shard_maps over the mesh and sets ``force_flash`` for the
+    flash kernel (ops/pallas/flash_attention.py). Multi-device
+    programs must NOT hit the bare kernel (pallas_call has no SPMD
+    partitioning rule): use make_sharded_causal_attention, which
+    shard_maps over the mesh and sets ``force_flash`` for the
     per-device local block. Everything else takes the XLA path.
 
     Without ``force_flash`` nothing says how many devices the program
@@ -64,55 +62,12 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     that knows its mesh — the models, whenever they are given one —
     goes through make_sharded_causal_attention, which decides from the
     mesh and not from the process.
-
-    ``RAY_TPU_ATTN_KERNEL`` overrides the kernel choice (bench
-    sweeps): "ours" | "jaxflash" (jax.experimental pallas flash) |
-    "splash" (jax.experimental splash attention) | "xla".
     """
-    import os
-    override = os.environ.get("RAY_TPU_ATTN_KERNEL", "")
-    if override and jax.default_backend() == "tpu":
-        if override == "xla":
-            return jax.nn.dot_product_attention(q, k, v, scale=scale,
-                                                is_causal=True)
-        if override == "jaxflash":
-            return _jax_flash(q, k, v, scale)
-        if override == "splash":
-            return _splash(q, k, v, scale)
     if _flash_ok(q, k, v) and (force_flash or jax.device_count() == 1):
         from ray_tpu.ops.pallas.flash_attention import flash_attention
         return flash_attention(q, k, v, causal=True, scale=scale)
     return jax.nn.dot_product_attention(q, k, v, scale=scale,
                                         is_causal=True)
-
-
-def _jax_flash(q, k, v, scale):
-    """jax.experimental pallas flash kernel ([B,H,T,D] layout)."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention as jfa,
-    )
-    out = jfa(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-              v.transpose(0, 2, 1, 3), causal=True,
-              sm_scale=float(scale if scale is not None
-                             else q.shape[-1] ** -0.5))
-    return out.transpose(0, 2, 1, 3)
-
-
-def _splash(q, k, v, scale):
-    """jax.experimental splash-attention kernel (per-batch vmap)."""
-    from jax.experimental.pallas.ops.tpu import (
-        splash_attention as sa,
-    )
-    b, t, h, d = q.shape
-    if scale is None:
-        scale = d ** -0.5
-    mask = sa.MultiHeadMask([sa.CausalMask((t, t)) for _ in range(h)])
-    kernel = sa.make_splash_mha(
-        mask, head_shards=1, q_seq_shards=1)
-    qs = (q * scale).transpose(0, 2, 1, 3)
-    out = jax.vmap(kernel)(qs, k.transpose(0, 2, 1, 3),
-                           v.transpose(0, 2, 1, 3))
-    return out.transpose(0, 2, 1, 3)
 
 
 def _block_attend(q, k, v, acc, row_max, row_sum, mask_mode, scale):

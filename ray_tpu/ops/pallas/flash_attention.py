@@ -73,23 +73,18 @@ def _pick_block(t: int, target: int = 1024) -> int:
     return best
 
 
-def _masked_scores(q, k, iq, ik, *, scale, bq, bk, causal,
-                   row0=None, col0=None):
+def _masked_scores(q, k, iq, ik, *, scale, bq, bk, causal):
     """Scaled q·kᵀ for one (q-block, k-block) pair with the causal
     mask applied in absolute coordinates — shared by the fwd and both
-    bwd kernels so the mask can never diverge between passes.
-    ``row0``/``col0`` override the block-index arithmetic for
-    rectangular (tq != tk) kernels whose rows sit at an arbitrary
-    offset (the causal-split path)."""
+    bwd kernels so the mask can never diverge between passes."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale        # [bq, bk]
     if causal:
-        r0 = iq * bq if row0 is None else row0
-        c0 = ik * bk if col0 is None else col0
-        rows = r0 + jax.lax.broadcasted_iota(
+        row0, col0 = iq * bq, ik * bk
+        rows = row0 + jax.lax.broadcasted_iota(
             jnp.int32, (bq, bk), 0)
-        cols = c0 + jax.lax.broadcasted_iota(
+        cols = col0 + jax.lax.broadcasted_iota(
             jnp.int32, (bq, bk), 1)
         s = jnp.where(rows >= cols, s, _NEG_INF)
     return s
@@ -457,146 +452,6 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk, d, hpb,
 
 
 # ---------------------------------------------------------------------------
-# rectangular single-pass kernels (causal-split decomposition)
-# ---------------------------------------------------------------------------
-#
-# Causal attention wastes the masked upper triangle: the single-block
-# kernel computes the full T x T score matrix. Splitting the QUERY
-# rows into n bands, band r only needs the K/V prefix of length
-# (r+1)*T/n — a rectangular [T/n, (r+1)*T/n] single-pass kernel with
-# NO streaming-softmax state (the whole row is present). Computed
-# fraction: (n+1)/2n of T^2 (75% at n=2, 62.5% at n=4) vs the
-# multi-block streaming path, whose per-cell correction overhead
-# measured SLOWER than the full T^2 single block on v5e (r5 sweep:
-# bq/bk 512 -> 112k tok/s vs 1024 single block -> 127k at batch 32).
-# Each band is its own custom-VJP primitive; jax autodiff composes
-# the bands (slice/concat transposes become pads+adds).
-
-
-def _fwd_rect_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                     *, scale, tq, tk, causal):
-    q = q_ref[0]                           # [tq, d]
-    k = k_ref[0]                           # [tk, d]
-    v = v_ref[0]
-    s = _masked_scores(q, k, 0, 0, scale=scale, bq=tq, bk=tk,
-                       causal=causal, row0=tk - tq, col0=0)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    lse_ref[0] = (m + jnp.log(jnp.maximum(l, 1e-30))).astype(
-        lse_ref.dtype)
-
-
-def _bwd_rect_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dq_ref, dk_ref, dv_ref, *, scale, tq, tk,
-                     causal):
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0]                       # [tq, 1]
-    delta = delta_ref[0]                   # [tq, 1]
-    s = _masked_scores(q, k, 0, 0, scale=scale, bq=tq, bk=tk,
-                       causal=causal, row0=tk - tq, col0=0)
-    p = jnp.exp(s - lse)                   # [tq, tk]
-    pb = p.astype(do.dtype)
-    dv_ref[0] = jax.lax.dot_general(
-        pb, do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-    dov = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                # [tq, tk]
-    ds = (p * (dov - delta) * scale).astype(q.dtype)
-    dq_ref[0] = jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    dk_ref[0] = jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dk_ref.dtype)
-
-
-def _rect_fwd(q, k, v, scale, causal, interpret):
-    bh, tq, d = q.shape
-    tk = k.shape[1]
-    qs = pl.BlockSpec((1, tq, d), lambda b: (b, 0, 0))
-    ks = pl.BlockSpec((1, tk, d), lambda b: (b, 0, 0))
-    return pl.pallas_call(
-        functools.partial(_fwd_rect_kernel, scale=scale, tq=tq,
-                          tk=tk, causal=causal),
-        grid=(bh,),
-        in_specs=[qs, ks, ks],
-        out_specs=[qs, pl.BlockSpec((1, tq, 1), lambda b: (b, 0, 0))],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _rect_core(q, k, v, scale, causal, interpret):
-    out, _ = _rect_fwd(q, k, v, scale, causal, interpret)
-    return out
-
-
-def _rect_core_fwd(q, k, v, scale, causal, interpret):
-    out, lse = _rect_fwd(q, k, v, scale, causal, interpret)
-    return out, (q, k, v, out, lse)
-
-
-def _rect_core_bwd(scale, causal, interpret, res, g):
-    q, k, v, out, lse = res
-    bh, tq, d = q.shape
-    tk = k.shape[1]
-    do = g.astype(q.dtype)
-    delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    qs = pl.BlockSpec((1, tq, d), lambda b: (b, 0, 0))
-    ks = pl.BlockSpec((1, tk, d), lambda b: (b, 0, 0))
-    one = pl.BlockSpec((1, tq, 1), lambda b: (b, 0, 0))
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_rect_kernel, scale=scale, tq=tq,
-                          tk=tk, causal=causal),
-        grid=(bh,),
-        in_specs=[qs, ks, ks, qs, one, one],
-        out_specs=[qs, ks, ks],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
-        ],
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
-
-
-_rect_core.defvjp(_rect_core_fwd, _rect_core_bwd)
-
-
-def _flash_causal_split(q, k, v, scale, n_split, interpret):
-    """[BH, T, D] causal attention as n_split row bands of
-    rectangular single-pass kernels. Plain jax composition: autodiff
-    of the slices/concat routes each band's dk/dv into the right
-    prefix."""
-    bh, t, d = q.shape
-    s = t // n_split
-    outs = []
-    for r in range(n_split):
-        off = r * s
-        outs.append(_rect_core(
-            jax.lax.slice_in_dim(q, off, off + s, axis=1),
-            jax.lax.slice_in_dim(k, 0, off + s, axis=1),
-            jax.lax.slice_in_dim(v, 0, off + s, axis=1),
-            scale, True, interpret))
-    return jnp.concatenate(outs, axis=1)
-
-
-# ---------------------------------------------------------------------------
 # public API with custom VJP
 # ---------------------------------------------------------------------------
 
@@ -659,39 +514,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     to [B*H, T, D], at a transpose each way; which of the two ran is in
     the trace's notes (``flash_layout``).
     """
-    import os
     b, t, h, d = q.shape
     if scale is None:
         scale = d ** -0.5
-    # Env overrides for block tuning (bench sweeps): RAY_TPU_FLASH_BQ/BK.
-    bq = (block_q or int(os.environ.get("RAY_TPU_FLASH_BQ", 0))
-          or _pick_block(t))
-    bk = (block_k or int(os.environ.get("RAY_TPU_FLASH_BK", 0))
-          or _pick_block(t))
+    bq = block_q or _pick_block(t)
+    bk = block_k or _pick_block(t)
     if bq == 0 or bk == 0 or t % bq or t % bk:
         raise ValueError(
             f"seq len {t} not divisible into flash blocks")
-    # [B, T, H, D] -> [B*H, T, D]
-    def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-    # Causal-split decomposition (see _flash_causal_split): skips the
-    # masked upper-triangle bands entirely. OPT-IN
-    # (RAY_TPU_FLASH_SPLIT=2|4): at GPT-2 bench shapes (seq 1024,
-    # d 64, bf16, v5e) the r5 on-chip A/B measured it SLOWER than the
-    # full-T^2 single block (103.7k vs 111.0k tok/s at split=2,
-    # 103.2k at split=4, same capture window) — the banded bwd's
-    # dk/dv pad+add accumulation and extra kernel launches cost more
-    # than the 25-37.5%% FLOP saving at this arithmetic intensity.
-    # Revisit for long-context shapes where T^2 dominates. It slices
-    # rows and prefixes of [B*H, T, D], so it stays on the fold.
-    n_split = int(os.environ.get("RAY_TPU_FLASH_SPLIT", 0))
-    if (causal and n_split > 1 and bq == t and t % n_split == 0
-            and (t // n_split) % 128 == 0):
-        tracing.note_trace(flash_layout="folded", flash_lanes_per_block=d,
-                           flash_path="causal_split")
-        out = _flash_causal_split(fold(q), fold(k), fold(v),
-                                  float(scale), n_split, interpret)
-        return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
     direct = _heads_per_block(h, d)
     hpb = direct or 1           # folded: one head a block
     # Made here and not in the jitted functions: jax caches their
@@ -705,6 +535,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         out = _flash_core(q.reshape(b, t, h * d), k.reshape(b, t, h * d),
                           v.reshape(b, t, h * d), static)
         return out.reshape(b, t, h, d)
+
+    def fold(x):    # [B, T, H, D] -> [B*H, T, D]
+        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     out = _flash_core(fold(q), fold(k), fold(v), static)
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
@@ -712,20 +545,3 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def flash_attention_shapes_ok(t: int, d: int) -> bool:
     return _pick_block(t) >= 128 and d % 8 == 0
 
-
-def resolved_flash_config(t: int, causal: bool = True) -> dict:
-    """The block tiling ``flash_attention`` resolves at seq len ``t``
-    under the current env overrides (RAY_TPU_FLASH_BQ/BK/SPLIT), with
-    no explicit block args. Benchmarks record this in their artifact
-    so a sweep's winner is reproducible from the JSON alone — the knob
-    *settings* alone don't say what tiling actually ran (0/absent
-    means auto-picked).
-    """
-    import os
-    bq = int(os.environ.get("RAY_TPU_FLASH_BQ", 0)) or _pick_block(t)
-    bk = int(os.environ.get("RAY_TPU_FLASH_BK", 0)) or _pick_block(t)
-    n_split = int(os.environ.get("RAY_TPU_FLASH_SPLIT", 0))
-    split_active = (causal and n_split > 1 and bq == t
-                    and t % n_split == 0 and (t // n_split) % 128 == 0)
-    return {"block_q": bq, "block_k": bk,
-            "split": n_split if split_active else 0}

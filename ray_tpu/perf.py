@@ -338,7 +338,7 @@ def _run_benchmarks(rec, quick: bool) -> None:
     # roundtrip (2 wrapped sends + 2 wrapped recvs) over raw
     # multiprocessing connections. Best-of-2 each side — the row
     # tracks the envelope, not the host's scheduler. The e2e contract
-    # (direct-call and task rows within 2% of PERF_r07) is pinned by
+    # (direct-call and task rows within 2% of round 7's) is pinned by
     # test_perf.py::test_microbench_floors.
     def _echo_rate(wrap: bool, n: int) -> float:
         import threading as _th
@@ -790,9 +790,9 @@ def _run_benchmarks(rec, quick: bool) -> None:
     # -- scale envelope (PR-13 indexed pending paths) ------------------
     # One-shot throughput rows pinning the scheduler's indexed
     # structures at tier-1-sized N; the full envelope (1k actors,
-    # 100k tasks, 500 PGs, chaos overlay) is scripts/scale_driver.py
-    # -> SCALE_r01.json. Each row reports a rate plus elapsed and the
-    # peak head queue depth observed while it ran.
+    # 100k tasks, 500 PGs, chaos overlay) is scripts/scale_driver.py.
+    # Each row reports a rate plus elapsed and the peak head queue
+    # depth observed while it ran.
     import threading as _sthr
 
     def _run_with_depth_sampler(fn):

@@ -14,13 +14,9 @@ input production and transfer (classic double buffering at
 ``depth=2``).
 
 This is the single input-overlap implementation for the framework:
-``bench.py``'s hot loops, ``Dataset.iter_device_batches`` (the
-train.fit() path via ``get_dataset_shard``), and user loops through
-``ray_tpu.train.prefetch_to_device`` all ride it.
-
-Donation-safe: the queue drops its reference when a batch is yielded,
-so a jitted step with donated batch arguments (``donate_batch=True``
-in ``make_train_step``) can reuse the buffers.
+``Dataset.iter_device_batches`` (the train.fit() path via
+``get_dataset_shard``) and user loops through
+``ray_tpu.train.prefetch_to_device`` both ride it.
 """
 
 from __future__ import annotations
@@ -83,7 +79,7 @@ class DevicePrefetcher:
     ``counters``, always on (cumulative; inside ``fit()`` summed onto
     the ``train.worker.loop`` span): ``batches``; ``stall_s``, the
     seconds the consumer blocked waiting (~0 means the input is fully
-    hidden; ``bench.py`` reads it); ``source_s`` and ``place_s``, the
+    hidden); ``source_s`` and ``place_s``, the
     producer's seconds in ``next(source)`` and in ``place``. Under a
     device profile the same three are the spans ``train.input.wait``
     (consumer thread), ``train.input.source`` and ``train.input.place``
@@ -162,9 +158,6 @@ class DevicePrefetcher:
             raise StopIteration
         self.counters["batches"] += 1
         return item
-
-    batches = property(lambda self: self.counters["batches"])
-    stall_s = property(lambda self: self.counters["stall_s"])
 
     def close(self) -> None:
         """Stop the producer and release queued batches."""

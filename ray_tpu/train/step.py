@@ -164,15 +164,10 @@ def _listen_for_compiles() -> None:
         jax.monitoring.register_event_listener(_on_cache_event)
 
 
-def _donate_argnums(donate: bool, donate_batch: bool) -> tuple:
-    return (() if not donate else ((0, 1) if donate_batch else (0,)))
-
-
 def make_train_step(loss_fn: Callable, optimizer,
                     has_extra: bool = False,
                     donate: bool = True,
-                    grad_norm: bool = True,
-                    donate_batch: bool = False) -> Callable:
+                    grad_norm: bool = True) -> Callable:
     """Build the jitted step: forward, backward, gradient psum (via
     sharding propagation) and the optimizer update fused into ONE
     compiled program with the param/opt-state buffers donated — the
@@ -185,32 +180,22 @@ def make_train_step(loss_fn: Callable, optimizer,
     Returns step(state, batch) -> (state, metrics).
     ``grad_norm=False`` skips the global-norm metric (a full f32 read
     of every gradient leaf — measurable on HBM-bound steps).
-    ``donate_batch=True`` additionally marks the batch buffers
-    donatable — safe when each batch is consumed exactly once (the
-    ``train.prefetch`` pipeline drops its reference on yield). Caveat:
-    XLA donation is input->output aliasing, so it only engages when
-    some output matches a batch leaf's shape/dtype; for a pure-input
-    batch (the usual LM token case) XLA ignores it with a warning,
-    which is why it is off by default.
     """
     _listen_for_compiles()
     step = _step_body(loss_fn, optimizer, has_extra, grad_norm)
-    return jax.jit(step,
-                   donate_argnums=_donate_argnums(donate, donate_batch))
+    return jax.jit(step, donate_argnums=(0,) if donate else ())
 
 
 def make_multi_train_step(loss_fn: Callable, optimizer,
                           has_extra: bool = False,
                           donate: bool = True,
-                          grad_norm: bool = True,
-                          donate_batch: bool = False) -> Callable:
+                          grad_norm: bool = True) -> Callable:
     """Scan variant: one compiled program runs K optimizer steps over
     a batch stack whose leaves carry a leading [K, ...] axis. Same
     math as K calls of the single step — the scan just amortizes
     per-dispatch overhead (host round-trip, arg handling) across K
     steps, exactly like queueing K async dispatches. Returns
-    (state, metrics_of_last_step). ``donate_batch`` donates the batch
-    stack buffers too (see :func:`make_train_step`)."""
+    (state, metrics_of_last_step)."""
     _listen_for_compiles()
     body = _step_body(loss_fn, optimizer, has_extra, grad_norm)
 
@@ -219,8 +204,7 @@ def make_multi_train_step(loss_fn: Callable, optimizer,
         last = jax.tree_util.tree_map(lambda x: x[-1], ms)
         return state, last
 
-    return jax.jit(multi,
-                   donate_argnums=_donate_argnums(donate, donate_batch))
+    return jax.jit(multi, donate_argnums=(0,) if donate else ())
 
 
 def compile_count(step_fn: Callable) -> int | None:

@@ -59,7 +59,8 @@ class JaxTrainer:
     ``ray_tpu.train.report(metrics, checkpoint=...)`` to stream results.
 
     For the device hot loop, use the same fused-step/prefetch plumbing
-    the bench measures (docs/training_perf.md): build the step with
+    the benchmark's cells measure (docs/training_perf.md): build the
+    step with
     ``train.make_train_step`` / ``make_multi_train_step`` (optimizer
     update jitted into the step, param/opt-state buffers donated in
     place) and feed it from

@@ -1,11 +1,9 @@
-"""Shared child-run contract for the bench/perf harness scripts.
+"""Child-run contract for the perf harness scripts.
 
 One implementation of: spawn the child in its OWN session, kill the
 whole process group on timeout (wedged jax threads survive a plain
 terminate), and scan stdout bottom-up for the last parseable JSON
-line. bench_sweep and perf_snapshot both run children
-under this exact contract — drift between hand-rolled copies is how
-kill/parse fixes get silently lost.
+line. perf_snapshot runs its children under this contract.
 """
 
 from __future__ import annotations

@@ -97,7 +97,7 @@ SERVE_METRICS = (
 # heartbeat envelope's no-fault tax on a loopback echo pair, in added
 # microseconds per roundtrip. The e2e contract is that
 # actor_calls_direct_1_1 and the tasks rows stay within 2% of the
-# pre-hardening round (PERF_r07) on an idle host; this row tracks the
+# pre-hardening round (round 7) on an idle host; this row tracks the
 # isolated component cost across rounds. Same must-be-present
 # contract.
 WIRE_METRICS = (
@@ -106,7 +106,7 @@ WIRE_METRICS = (
 
 # Scale-envelope metrics (ray_tpu/perf.py): small-N throughput rows
 # over the indexed pending-queue paths — the tier-1-sized shadow of
-# the full scripts/scale_driver.py envelope (SCALE_r01.json). Same
+# the full scripts/scale_driver.py envelope. Same
 # must-be-present contract.
 SCALE_METRICS = (
     "actors_create_call_100",

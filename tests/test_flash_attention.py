@@ -161,76 +161,48 @@ def test_causal_attention_dispatch_cpu_fallback():
     assert float(jnp.abs(out - ref).max()) < 1e-6
 
 
-def test_causal_split_matches_dense():
-    """The causal-split decomposition (rectangular row bands) must
-    match dense causal attention in fwd AND grads — including the
-    dk/dv prefix accumulation autodiff composes across bands."""
-    import numpy as np
-
-    from ray_tpu.ops.pallas.flash_attention import (
-        _flash_causal_split,
-    )
-
-    rng = np.random.default_rng(5)
-    bh, t, d = 3, 256, 16
-    q = jnp.asarray(rng.standard_normal((bh, t, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((bh, t, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((bh, t, d)), jnp.float32)
-    scale = d ** -0.5
-
-    def dense(q, k, v):
-        s = jnp.einsum("btd,bsd->bts", q, k) * scale
-        mask = np.tril(np.ones((t, t), dtype=bool))
-        s = jnp.where(mask[None], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bts,bsd->btd", p, v)
-
-    for n_split in (2, 4):
-        out = _flash_causal_split(q, k, v, scale, n_split,
-                                  interpret=True)
-        ref = dense(q, k, v)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
-
-        def loss_split(q, k, v, n=n_split):
-            o = _flash_causal_split(q, k, v, scale, n,
-                                    interpret=True)
-            return jnp.sum(o * jnp.cos(o))
-
-        def loss_dense(q, k, v):
-            o = dense(q, k, v)
-            return jnp.sum(o * jnp.cos(o))
-
-        g_split = jax.grad(loss_split, argnums=(0, 1, 2))(q, k, v)
-        g_dense = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-        for gs, gd, name in zip(g_split, g_dense, "qkv"):
-            np.testing.assert_allclose(
-                np.asarray(gs), np.asarray(gd), atol=5e-4, rtol=5e-4,
-                err_msg=f"d{name} mismatch at n_split={n_split}")
+@pytest.mark.parametrize("backend, force_flash, t, kernel", [
+    ("tpu", True, 256, True),       # a one-device program, shapes block
+    ("tpu", False, 256, False),     # the tests' process holds 8 devices
+    ("tpu", True, 100, False),      # 100 rows do not block
+    ("cpu", True, 256, False),
+], ids=["one_device_program", "process_of_many_devices",
+        "rows_do_not_block", "no_tpu"])
+def test_causal_attention_decides_from_backend_shapes_and_devices(
+        monkeypatch, backend, force_flash, t, kernel):
+    """The whole decision, traced and not run: the kernel where the
+    backend is a TPU, the shapes block and the program is one
+    device's; XLA's attention otherwise. The kernel says that it was
+    traced in the trace's notes."""
+    assert jax.device_count() > 1
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    x = jax.ShapeDtypeStruct((2, t, 4, 64), jnp.bfloat16)
+    tracing.take_trace_notes()
+    out = jax.eval_shape(
+        lambda q, k, v: causal_attention(q, k, v, force_flash=force_flash),
+        x, x, x)
+    assert (out.shape, out.dtype) == (x.shape, x.dtype)
+    assert ("flash_path" in tracing.take_trace_notes()) == kernel
 
 
-def test_resolved_flash_config_mirrors_env_knobs(monkeypatch):
-    """resolved_flash_config is what benchmarks write into their
-    artifact's extra.attn_blocks — it must track the kernel's own
-    env-override resolution (RAY_TPU_FLASH_BQ/BK/SPLIT)."""
-    from ray_tpu.ops.pallas.flash_attention import resolved_flash_config
-
-    for var in ("RAY_TPU_FLASH_BQ", "RAY_TPU_FLASH_BK",
-                "RAY_TPU_FLASH_SPLIT"):
-        monkeypatch.delenv(var, raising=False)
-    auto = resolved_flash_config(1024)
-    assert auto == {"block_q": 1024, "block_k": 1024, "split": 0}
-
-    monkeypatch.setenv("RAY_TPU_FLASH_BQ", "256")
-    monkeypatch.setenv("RAY_TPU_FLASH_BK", "512")
-    assert resolved_flash_config(1024) == {
-        "block_q": 256, "block_k": 512, "split": 0}
-
-    # Split engages only at full-T block_q with 128-aligned bands —
-    # the same predicate flash_attention itself applies.
-    monkeypatch.setenv("RAY_TPU_FLASH_SPLIT", "2")
-    assert resolved_flash_config(1024)["split"] == 0  # bq=256 != t
-    monkeypatch.delenv("RAY_TPU_FLASH_BQ")
-    monkeypatch.delenv("RAY_TPU_FLASH_BK")
-    assert resolved_flash_config(1024)["split"] == 2
-    assert resolved_flash_config(1024, causal=False)["split"] == 0
+@pytest.mark.parametrize("shape, layout, lanes, path", [
+    ((32, 1024, 12, 64), "bthd", 128, "single_block"),
+    ((4, 4096, 16, 128), "bthd", 128, "multi_block"),
+    ((8, 2048, 32, 128), "bthd", 128, "multi_block"),
+    ((1, 1024, 3, 64), "folded", 64, "single_block"),
+], ids=["gpt2_cells", "olmoe_cell", "d128_t2048", "odd_heads_t1024"])
+def test_the_cells_shapes_choose_what_their_trace_notes_say(
+        shape, layout, lanes, path):
+    """The benchmark's cells at their real shapes (a chip's share of
+    the batch), by nothing but the shapes: the notes that every
+    ``train.compile`` span of a cell's step carries (PERF.md section
+    3), and one folded shape at the cells' length. Traced only: no
+    kernel runs."""
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    tracing.take_trace_notes()
+    out = jax.eval_shape(
+        lambda q, k, v: flash_attention(q, k, v, causal=True), x, x, x)
+    assert (out.shape, out.dtype) == (shape, jnp.bfloat16)
+    assert tracing.take_trace_notes() == {
+        "flash_layout": layout, "flash_lanes_per_block": lanes,
+        "flash_path": path}

@@ -1,7 +1,7 @@
 """Microbenchmark harness sanity (ray_perf analog).
 
 Thresholds are deliberately far below the recorded numbers
-(PERF_r02.jsonl: ~3k sync tasks/s, ~4k sync actor calls/s on a 1-core
+(round 2: ~3k sync tasks/s, ~4k sync actor calls/s on a 1-core
 host vs the reference bar of 952 / 1,950 from SURVEY §6) — this guards
 against order-of-magnitude control-plane regressions, not noise.
 """
@@ -38,7 +38,7 @@ def test_microbench_floors(rt):
         f"{results['actor_calls_head_routed_1_1']} calls/s")
     # Wire-hardening no-fault guardrail: the checksum/seq/heartbeat
     # envelope must not regress the steady-state rows vs the
-    # pre-hardening round (PERF_r07: direct 12.0k/s, sync tasks
+    # pre-hardening round (round 7: direct 12.0k/s, sync tasks
     # 5.75k/s). Floors at 0.85x absorb quick-mode jitter; the strict
     # <2% contract is verified on idle-host medians by
     # scripts/perf_snapshot.py (WIRE_METRICS). heartbeat_overhead is
@@ -401,7 +401,7 @@ def _fused_step_time_ms(build, n_timed=3):
 def test_gpt2_fused_step_time_guardrail():
     """Tiny-GPT-2 fused donated step on the CPU backend: order-of-
     magnitude guardrail (load-gated) + the compile-count pin on the
-    exact step construction bench.py times. Catches an accidentally
+    exact step construction the benchmark's GPT-2 cells time. Catches an accidentally
     unfused/recompiling hot loop, not noise."""
     from conftest import perf_floor_gate
     relax = perf_floor_gate()

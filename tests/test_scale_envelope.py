@@ -3,8 +3,8 @@
 Small-N variants run in tier-1 (100 actors / 5k tasks / 50 PGs /
 8 logical nodes); the full envelope (1,000 actors, 100k tasks,
 500 PGs, 32 nodes over 8 daemons, 1 GiB broadcast, chaos overlay)
-runs behind ``-m scale`` via scripts/run_scale.sh, and the measured
-artifact is SCALE_r01.json (scripts/scale_driver.py).
+runs behind ``-m scale`` via scripts/run_scale.sh
+(scripts/scale_driver.py).
 
 Also here: the admission/backpressure contract (ST_BUSY engages at a
 low watermark, queue depth stays bounded, light clients progress

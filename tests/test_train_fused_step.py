@@ -139,7 +139,7 @@ def test_prefetcher_preserves_order_and_counts():
     src = list(range(20))
     pf = DevicePrefetcher(iter(src), place=lambda x: x * 10, depth=3)
     assert list(pf) == [x * 10 for x in src]
-    assert pf.batches == len(src)
+    assert pf.counters["batches"] == len(src)
     pf.close()
 
 
@@ -225,6 +225,6 @@ def test_prefetcher_feeds_donated_step():
     for b in pf:
         state, m = step(state, b)
     pf.close()
-    assert pf.batches == n
+    assert pf.counters["batches"] == n
     assert int(state.step) == n
     assert np.isfinite(float(m["loss"]))
