@@ -32,6 +32,14 @@ with the innermost grid dimension "arbitrary" (sequential on TPU), so
 VMEM scratch carries state across inner steps of one outer block.
 Folded, "batch" is batch*heads and there is one lane block.
 
+The causal triangle: the multi-block kernels skip the blocks above the
+diagonal through the grid. A single-block body has no grid to skip
+with, so it walks its [T, T] block as static row slabs — slab i is
+rows [i*S, (i+1)*S) against keys [0, (i+1)*S), slices that fall on
+whole tiles — and so computes the triangle too, not the square
+(``_causal_slabs``; how many it walked is in the trace's notes,
+``flash_causal_slabs``, 1 for the square).
+
 Set-up: the two functions that hold the pallas_calls are jitted, so a
 model's layers, which call them at one shape, trace each kernel and
 lower it to Mosaic once a trace of the step, not once a layer.
@@ -60,12 +68,13 @@ def _pick_block(t: int, target: int = 1024) -> int:
 
     The rows of a block: its lanes are chosen from the head width
     (``_heads_per_block``), and a grid cell is one (batch row, lane
-    block, q-block, k-block). Default target 1024: on v5e-class chips
-    the per-grid-cell overhead (pipeline fill, scratch init, mask/exp
-    VPU work) dominates below ~1k blocks — measured 16.5ms vs 21.2ms
-    attention time per GPT-2 step for 1024x1024 vs 512x512 blocks,
-    even though the single-block causal path computes the full (not
-    triangular) score matrix."""
+    block, q-block, k-block). Default target 1024: a grid cell has
+    its price (pipeline fill, scratch init, the streaming softmax's
+    extra VPU work), so a row that fits one block takes one, and the
+    block is cut inside the body instead, by static slices
+    (``_causal_slabs``): 33.1 ms of kernels a GPT-2 step so against
+    46.6 for the square in one piece (one v5e chip, 12 layers of 32 x
+    12 heads at T=1024; PERF.md section 6, PR 31)."""
     best = 0
     for b in range(8, min(t, target) + 1, 8):
         if t % b == 0:
@@ -87,6 +96,55 @@ def _masked_scores(q, k, iq, ik, *, scale, bq, bk, causal):
         cols = col0 + jax.lax.broadcasted_iota(
             jnp.int32, (bq, bk), 1)
         s = jnp.where(rows >= cols, s, _NEG_INF)
+    return s
+
+
+# Rows of a causal slab on the single-block path (``_causal_slabs``).
+_SLAB_ROWS = 256
+
+
+def _causal_slabs(t: int, causal: bool) -> int:
+    """Row slabs a single-block body walks its [t, t] block in; 1 where
+    it computes the square.
+
+    Under ``causal`` the body computes the triangle, not the square:
+    the block's rows are cut into ``n`` static slabs of ``_SLAB_ROWS``
+    and slab ``i`` reads only the keys up to its own diagonal,
+    ``[0, (i + 1) * _SLAB_ROWS)`` — (n + 1) / 2n of the square's matmul
+    passes, exps and selects. Decided from ``causal`` and ``t`` alone:
+    where three or more such slabs tile the row, that many; else — not
+    causal, or a row too short or too odd for them — one: the square.
+
+    Why 256 rows, and three (one v5e chip, 32 x 12 heads of 64, forward
+    + backward of a layer in ms; PERF.md section 6, PR 31). At t=1024:
+    the square 1.18 + 2.76, two slabs of 512 0.94 + 2.17, four of 256
+    0.93 + 1.86, eight of 128 1.19 + 2.44. A matmul latches its right
+    operand into the array 128 x 128 at a time and streams the left
+    one's rows past it, so a slab of S rows latches k's and v's tiles
+    once more and streams only S rows past each: at 128 that costs what
+    the triangle saves. At t=768 three slabs 0.59 + 1.17 against 0.78 +
+    1.57; at t=512 two slabs, three quarters of the square, 0.49 + 0.74
+    against 0.38 + 0.74: no gain, so the square stays."""
+    if causal and t % _SLAB_ROWS == 0 and t >= 3 * _SLAB_ROWS:
+        return t // _SLAB_ROWS
+    return 1
+
+
+def _slab_scores(q, k, *, scale, causal):
+    """Scaled q·kᵀ of one row slab, [S, c]: its S rows are the last S
+    of the c columns it is given, so the causal mask can bite only in
+    the last S columns (the slab's diagonal square, the same lower
+    triangle for every slab) and is applied nowhere else."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    if causal:
+        rows, cols = s.shape
+        below = (jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 0)
+                 >= jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 1))
+        diag = jnp.where(below, s[:, cols - rows:], _NEG_INF)
+        s = diag if cols == rows else jnp.concatenate(
+            [s[:, :cols - rows], diag], axis=1)
     return s
 
 
@@ -132,11 +190,11 @@ def _as_col(row):
     return jnp.broadcast_to(row, (128, row.shape[1])).T[:, :1]
 
 
-def _delta(o_ref, do_ref, sl):
+def _delta(o_ref, do_ref, sl, rows=slice(None)):
     """The row sums of o * do for the head in lanes ``sl``: [rows, 1],
     made in the kernel from blocks it holds, never an array in HBM."""
-    return jnp.sum(o_ref[0, :, sl].astype(jnp.float32)
-                   * do_ref[0, :, sl].astype(jnp.float32),
+    return jnp.sum(o_ref[0, rows, sl].astype(jnp.float32)
+                   * do_ref[0, rows, sl].astype(jnp.float32),
                    axis=-1, keepdims=True)
 
 
@@ -192,27 +250,42 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 jnp.maximum(l, 1e-30))).astype(lse_ref.dtype)
 
 
+def _slab_rows(t, slabs):
+    return [slice(i * (t // slabs), (i + 1) * (t // slabs))
+            for i in range(slabs)]
+
+
 def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       *, scale, t, d, hpb, causal):
+                       *, scale, t, d, hpb, causal, slabs):
     """Single-block forward: the whole row fits one block, so plain
     (one-pass) softmax replaces the streaming max/sum scratch state —
-    fewer VPU ops and no cross-iteration scratch."""
-    for j, sl in enumerate(_head_slices(d, hpb)):
-        q = q_ref[0, :, sl]
-        k = k_ref[0, :, sl]
-        v = v_ref[0, :, sl]
-        s = _masked_scores(q, k, 0, 0, scale=scale, bq=t, bk=t,
-                           causal=causal)
-        m = jnp.max(s, axis=-1, keepdims=True)             # [t, 1]
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        o = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        o_ref[0, :, sl] = (o / jnp.maximum(l, 1e-30)).astype(
-            o_ref.dtype)
-        lse_ref[j:j + 1] = _as_row(
-            m + jnp.log(jnp.maximum(l, 1e-30))).astype(lse_ref.dtype)
+    fewer VPU ops and no cross-iteration scratch.
+
+    The block is walked as ``slabs`` static row slabs (``_causal_slabs``)
+    and slab i meets only the keys and values up to its diagonal: the
+    whole of a row's scores is still in hand, so the softmax stays one
+    pass. One slab is the square. A head's slabs are written out phase
+    by phase, not slab by slab: Mosaic schedules close to the order it
+    is given, and a slab's row maximum, a reduction across lanes,
+    stands between its two matmuls — slab by slab the array waits for
+    it (1.10 ms a GPT-2 layer against 0.93 so)."""
+    rows = _slab_rows(t, slabs)
+    for h, sl in enumerate(_head_slices(d, hpb)):
+        s = [_slab_scores(q_ref[0, r, sl], k_ref[0, :r.stop, sl],
+                          scale=scale, causal=causal) for r in rows]
+        m = [jnp.max(x, axis=-1, keepdims=True) for x in s]  # [S, 1]
+        p = [jnp.exp(x - m_i) for x, m_i in zip(s, m)]
+        l = [jnp.maximum(jnp.sum(x, axis=-1, keepdims=True), 1e-30)
+             for x in p]
+        for r, p_i, l_i in zip(rows, p, l):
+            v = v_ref[0, :r.stop, sl]
+            o = jax.lax.dot_general(
+                p_i.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            o_ref[0, r, sl] = (o / l_i).astype(o_ref.dtype)
+        for r, m_i, l_i in zip(rows, m, l):
+            lse_ref[h:h + 1, r] = _as_row(m_i + jnp.log(l_i)).astype(
+                lse_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -232,7 +305,8 @@ def _flash_fwd(q, k, v, *, scale, causal, bq, bk, d, hpb, interpret):
         seq = _seq_spec(t, lanes, lambda b, c: (b, 0, c))
         return pl.pallas_call(
             functools.partial(_fwd_single_kernel, scale=scale, t=t,
-                              d=d, hpb=hpb, causal=causal),
+                              d=d, hpb=hpb, causal=causal,
+                              slabs=_causal_slabs(t, causal)),
             grid=(n, g),
             in_specs=[seq, seq, seq],
             out_specs=[seq,
@@ -362,36 +436,59 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                      dq_ref, dk_ref, dv_ref, *, scale, t, d, hpb,
-                      causal):
+                      dq_ref, dk_ref, dv_ref, *acc, scale, t, d, hpb,
+                      causal, slabs):
     """Single-block backward (t fits one block): computes the score
     matrix ONCE for dq, dk, AND dv — the two-pass kernels each
     recompute s/p/dov, so this saves a full [t,t] matmul + exp pass.
-    No cross-block accumulation, so no scratch is needed."""
-    for j, sl in enumerate(_head_slices(d, hpb)):
-        q = q_ref[0, :, sl]
-        k = k_ref[0, :, sl]
-        v = v_ref[0, :, sl]
-        do = do_ref[0, :, sl]                  # bf16 operand for the MXU
-        lse = _as_col(lse_ref[j:j + 1])          # [t, 1]
-        delta = _delta(o_ref, do_ref, sl)      # [t, 1]
-        s = _masked_scores(q, k, 0, 0, scale=scale, bq=t, bk=t,
-                           causal=causal)
-        p = jnp.exp(s - lse)                               # [t, t]
-        pb = p.astype(do.dtype)
-        dv_ref[0, :, sl] = jax.lax.dot_general(
-            pb, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-        dov = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [t, t]
-        ds = (p * (dov - delta) * scale).astype(q.dtype)
-        dq_ref[0, :, sl] = jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-        dk_ref[0, :, sl] = jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dk_ref.dtype)
+
+    Walked as the forward is: ``slabs`` static row slabs that stop at
+    the diagonal, a head's slabs phase by phase. A slab's dq is whole;
+    its shares of dk and dv (the keys it read) are summed over the
+    slabs in float32 VMEM scratch ``acc`` = (dk_acc, dv_acc) and cast
+    once — the last slab, which reads every key, goes first and
+    initialises them. One slab (the square) has nothing to sum and
+    takes no scratch."""
+    rows = _slab_rows(t, slabs)[::-1]
+    for h, sl in enumerate(_head_slices(d, hpb)):
+        lse = [_as_col(lse_ref[h:h + 1, r]) for r in rows]  # [S, 1]
+        delta = [_delta(o_ref, do_ref, sl, r) for r in rows]
+        s = [_slab_scores(q_ref[0, r, sl], k_ref[0, :r.stop, sl],
+                          scale=scale, causal=causal) for r in rows]
+        dov = [jax.lax.dot_general(
+                   do_ref[0, r, sl], v_ref[0, :r.stop, sl],
+                   (((1,), (1,)), ((), ())),
+                   preferred_element_type=jnp.float32)     # [S, c]
+               for r in rows]
+        p = [jnp.exp(x - lse_i) for x, lse_i in zip(s, lse)]
+        # bf16 operands into the MXU (f32 operands run it at a
+        # fraction of peak); accumulation stays f32.
+        ds = [(p_i * (dov_i - delta_i) * scale).astype(q_ref.dtype)
+              for p_i, dov_i, delta_i in zip(p, dov, delta)]
+        for r, p_i, ds_i in zip(rows, p, ds):
+            keys = slice(0, r.stop)
+            do = do_ref[0, r, sl]
+            dv = jax.lax.dot_general(
+                p_i.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # [c, d]
+            dq_ref[0, r, sl] = jax.lax.dot_general(
+                ds_i, k_ref[0, keys, sl], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(dq_ref.dtype)
+            dk = jax.lax.dot_general(
+                ds_i, q_ref[0, r, sl], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # [c, d]
+            if slabs == 1:
+                dk_ref[0, :, sl] = dk.astype(dk_ref.dtype)
+                dv_ref[0, :, sl] = dv.astype(dv_ref.dtype)
+            elif r.stop == t:
+                acc[0][:, sl] = dk
+                acc[1][:, sl] = dv
+            else:
+                acc[0][keys, sl] += dk
+                acc[1][keys, sl] += dv
+    if slabs > 1:
+        dk_ref[0] = acc[0][...].astype(dk_ref.dtype)
+        dv_ref[0] = acc[1][...].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -408,14 +505,16 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk, d, hpb,
     grads = [jax.ShapeDtypeStruct((n, t, w), x.dtype) for x in (q, k, v)]
     if nq == 1 and nk == 1:
         seq = _seq_spec(t, lanes, lambda b, c: (b, 0, c))
+        slabs = _causal_slabs(t, causal)
         return pl.pallas_call(
             functools.partial(_bwd_fused_kernel, scale=scale, t=t,
-                              d=d, hpb=hpb, causal=causal),
+                              d=d, hpb=hpb, causal=causal, slabs=slabs),
             grid=(n, ng),
             in_specs=[seq, seq, seq, seq, seq,
                       _stat_spec(hpb, t, lambda b, c: (b, c, 0, 0, 0))],
             out_specs=[seq, seq, seq],
             out_shape=grads,
+            scratch_shapes=[_vmem((t, lanes))] * 2 if slabs > 1 else [],
             interpret=interpret,
         )(q, k, v, out, do, lse)
 
@@ -524,12 +623,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             f"seq len {t} not divisible into flash blocks")
     direct = _heads_per_block(h, d)
     hpb = direct or 1           # folded: one head a block
+    single = bq == t == bk
     # Made here and not in the jitted functions: jax caches their
     # traces, so the step's second trace would find no note.
     tracing.note_trace(
         flash_layout="bthd" if direct else "folded",
         flash_lanes_per_block=d * hpb,
-        flash_path="single_block" if bq == t == bk else "multi_block")
+        flash_path="single_block" if single else "multi_block",
+        flash_causal_slabs=_causal_slabs(t, causal) if single else 1)
     static = _Static(float(scale), causal, bq, bk, d, hpb, interpret)
     if direct:
         out = _flash_core(q.reshape(b, t, h * d), k.reshape(b, t, h * d),

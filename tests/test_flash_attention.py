@@ -81,36 +81,112 @@ def _out_and_grads(attn, q, k, v):
     return (out, *grads)
 
 
+@pytest.fixture
+def the_square(monkeypatch):
+    """Runs what it is given with the single-block bodies held to one
+    slab (the square, whatever ``_causal_slabs`` would say): what a
+    slabbed run is compared with. The rule is read when the jitted
+    kernel functions are traced, so their cached traces go before and
+    after."""
+    def run(fn):
+        with monkeypatch.context() as m:
+            m.setattr(fa, "_causal_slabs", lambda t, causal: 1)
+            jax.clear_caches()
+            try:
+                return fn()
+            finally:
+                jax.clear_caches()
+    return run
+
+
 @pytest.mark.parametrize("causal", [True, False],
                          ids=["causal", "full"])
-@pytest.mark.parametrize("blocks", [1, 2], ids=["single", "multi"])
+@pytest.mark.parametrize("t,block", [(128, 128), (256, 128), (768, 768)],
+                         ids=["single", "multi", "slabs"])
 @pytest.mark.parametrize("h,d", [(4, 64), (2, 128)],
                          ids=["two_heads_a_block", "one_head_a_block"])
-def test_direct_layout_matches_dense_and_folded(h, d, blocks, causal):
+def test_direct_layout_matches_dense_and_folded(h, d, t, block, causal,
+                                                the_square):
     """The kernels on the projections' [B, T, H*D] (two heads in a
-    128-lane block at D=64, one at D=128; one block a row and the
-    streaming path) against XLA's dense attention and against the
-    same kernels on the folded layout: output, dq, dk, dv."""
-    t = 128 * blocks
+    128-lane block at D=64, one at D=128; one block a row, the
+    streaming path, and one block a row long enough to be walked as
+    causal slabs) against XLA's dense attention and against the same
+    kernels on the folded layout: output, dq, dk, dv. Where the body
+    walks slabs, also against the same call computing the square."""
     q, k, v = _rand_qkv(b=2, t=t, h=h, d=d, seed=3)
     assert fa._heads_per_block(h, d) == 128 // d
-    got = _out_and_grads(
-        lambda q, k, v: flash_attention(
-            q, k, v, causal=causal, block_q=128, block_k=128,
-            interpret=True), q, k, v)
+    slabs = 3 if (t == 768 and causal) else 1
+
+    def direct():
+        return _out_and_grads(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=causal, block_q=block, block_k=block,
+                interpret=True), q, k, v)
+    tracing.take_trace_notes()
+    got = direct()
     assert tracing.take_trace_notes() == {
         "flash_layout": "bthd", "flash_lanes_per_block": 128,
-        "flash_path": "single_block" if blocks == 1 else "multi_block"}
+        "flash_path": "single_block" if t == block else "multi_block",
+        "flash_causal_slabs": slabs}
     dense = _out_and_grads(
         lambda q, k, v: jax.nn.dot_product_attention(
             q, k, v, is_causal=causal), q, k, v)
     folded = _out_and_grads(
-        lambda q, k, v: _folded(q, k, v, causal=causal, block=128),
+        lambda q, k, v: _folded(q, k, v, causal=causal, block=block),
         q, k, v)
-    for name, a, ref, f in zip(("out", "dq", "dk", "dv"), got, dense,
-                               folded):
+    square = the_square(direct) if slabs > 1 else got
+    for name, a, ref, f, sq in zip(("out", "dq", "dk", "dv"), got, dense,
+                                   folded, square):
         assert float(jnp.abs(a - ref).max()) < 5e-4, name
         assert float(jnp.abs(a - f).max()) < 2e-5, name
+        assert float(jnp.abs(a - sq).max()) < 2e-5, name
+
+
+@pytest.mark.parametrize("h,d", [(2, 64), (1, 128), (3, 64)],
+                         ids=["two_heads_a_block", "one_head_a_block",
+                              "folded"])
+def test_slabs_leave_the_rows_log_sum_exp_the_squares(h, d, the_square):
+    """``lse``, which the backward reads in place of the softmax's
+    statistics: a slab's rows written into their own lanes of the
+    [hpb, T] rows, equal to the square's and to the dense scores'."""
+    t = 768
+    q, k, v = _rand_qkv(b=1, t=t, h=h, d=d, seed=5)
+    hpb = fa._heads_per_block(h, d) or 1
+    if fa._heads_per_block(h, d):
+        args = [x.reshape(1, t, h * d) for x in (q, k, v)]
+    else:
+        args = [x.transpose(0, 2, 1, 3).reshape(h, t, d) for x in (q, k, v)]
+    assert fa._causal_slabs(t, True) == 3
+
+    def run():
+        return fa._flash_fwd(*args, scale=d ** -0.5, causal=True, bq=t,
+                             bk=t, d=d, hpb=hpb, interpret=True)[1]
+    lse = run()
+    assert float(jnp.abs(lse - the_square(run)).max()) < 2e-6
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    want = jax.nn.logsumexp(s, axis=-1)                    # [1, H, T]
+    assert float(jnp.abs(lse.reshape(h, t) - want[0]).max()) < 2e-5
+
+
+@pytest.mark.parametrize("t,causal,slabs", [
+    (128, True, 1), (512, True, 1), (768, False, 1), (1000, True, 1),
+    (896, True, 1), (768, True, 3), (1024, True, 4), (1024, False, 1),
+], ids=["t128", "two_slabs_do_not_pay", "t768_not_causal",
+        "rows_off_the_tiles", "t896_not_whole_slabs", "t768", "t1024",
+        "t1024_not_causal"])
+def test_slabs_are_chosen_from_causal_and_the_rows_alone(t, causal, slabs):
+    """One rule (``_causal_slabs``), and what it decided in the
+    trace's notes: 1 where the square is computed — not causal, or a
+    row that three or more slabs of whole tiles do not tile."""
+    x = jax.ShapeDtypeStruct((1, t, 2, 64), jnp.bfloat16)
+    tracing.take_trace_notes()
+    jax.eval_shape(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal), x, x, x)
+    notes = tracing.take_trace_notes()
+    assert notes["flash_path"] == "single_block"
+    assert notes["flash_causal_slabs"] == slabs == fa._causal_slabs(
+        t, causal)
 
 
 @pytest.mark.parametrize("h,d,lanes", [(3, 64, 64), (4, 32, 32),
@@ -126,7 +202,7 @@ def test_shapes_off_the_lane_tiles_take_the_fold_and_say_so(h, d, lanes):
     out = flash_attention(q, k, v, causal=True, interpret=True)
     assert tracing.take_trace_notes() == {
         "flash_layout": "folded", "flash_lanes_per_block": lanes,
-        "flash_path": "single_block"}
+        "flash_path": "single_block", "flash_causal_slabs": 1}
     ref = jax.nn.dot_product_attention(q, k, v, is_causal=True)
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
@@ -185,18 +261,20 @@ def test_causal_attention_decides_from_backend_shapes_and_devices(
     assert ("flash_path" in tracing.take_trace_notes()) == kernel
 
 
-@pytest.mark.parametrize("shape, layout, lanes, path", [
-    ((32, 1024, 12, 64), "bthd", 128, "single_block"),
-    ((4, 4096, 16, 128), "bthd", 128, "multi_block"),
-    ((8, 2048, 32, 128), "bthd", 128, "multi_block"),
-    ((1, 1024, 3, 64), "folded", 64, "single_block"),
+@pytest.mark.parametrize("shape, layout, lanes, path, slabs", [
+    ((32, 1024, 12, 64), "bthd", 128, "single_block", 4),
+    ((4, 4096, 16, 128), "bthd", 128, "multi_block", 1),
+    ((8, 2048, 32, 128), "bthd", 128, "multi_block", 1),
+    ((1, 1024, 3, 64), "folded", 64, "single_block", 4),
 ], ids=["gpt2_cells", "olmoe_cell", "d128_t2048", "odd_heads_t1024"])
 def test_the_cells_shapes_choose_what_their_trace_notes_say(
-        shape, layout, lanes, path):
+        shape, layout, lanes, path, slabs):
     """The benchmark's cells at their real shapes (a chip's share of
     the batch), by nothing but the shapes: the notes that every
     ``train.compile`` span of a cell's step carries (PERF.md section
-    3), and one folded shape at the cells' length. Traced only: no
+    3), and one folded shape at the cells' length. The GPT-2 cells'
+    one block a row is walked as causal slabs; the streaming path
+    skips whole blocks through its grid and says 1. Traced only: no
     kernel runs."""
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     tracing.take_trace_notes()
@@ -205,4 +283,4 @@ def test_the_cells_shapes_choose_what_their_trace_notes_say(
     assert (out.shape, out.dtype) == (shape, jnp.bfloat16)
     assert tracing.take_trace_notes() == {
         "flash_layout": layout, "flash_lanes_per_block": lanes,
-        "flash_path": path}
+        "flash_path": path, "flash_causal_slabs": slabs}

@@ -63,6 +63,24 @@ def test_flash_kernel_compiles_for_v5e(v5e, shape, grad):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("shape, slabs", [
+    ((4, 1024, 3, 64), 4), ((8, 1024, 16, 128), 4), ((8, 768, 12, 64), 3),
+], ids=["folded_one_head_a_block", "d128_one_head_a_block", "three_slabs"])
+def test_causal_slabs_compile_for_v5e_off_the_cells_shapes(
+        v5e, shape, slabs):
+    """The single-block bodies walked as causal row slabs where no cell
+    runs them (the GPT-2 cells' shape is above): one head a block,
+    folded and at D=128, and three slabs. The slabs' slices fall on
+    whole tiles, and the float32 dk and dv sums fit VMEM."""
+    fa = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
+    assert fa._causal_slabs(shape[1], True) == slabs
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+    text = jax.jit(jax.grad(_loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("shape", SHAPES,
                          ids=["x".join(map(str, s)) for s in SHAPES])
@@ -190,9 +208,9 @@ def test_twelve_layers_lower_each_kernel_once_and_a_second_trace_none(
     layouts) finds the first one's."""
     fa = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
     bodies = []
-    scores = fa._masked_scores
+    scores = fa._slab_scores    # the single-block bodies', once a slab
     monkeypatch.setattr(
-        fa, "_masked_scores",
+        fa, "_slab_scores",
         lambda *a, **kw: bodies.append(1) or scores(*a, **kw))
 
     def stack(x, scales):

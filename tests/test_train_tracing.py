@@ -587,7 +587,7 @@ def test_flash_notes_reach_both_traces_of_the_step_and_no_other_span(
     tokens = jnp.zeros((2, cfg.seq_len), jnp.int32)
     batch = {"tokens": tokens, "targets": tokens}
     want = {"flash_layout": layout, "flash_lanes_per_block": lanes,
-            "flash_path": "single_block"}
+            "flash_path": "single_block", "flash_causal_slabs": 1}
     for _ in range(2):      # the second: a new jit of the same step
         before = len(tracing.get_spans())
         step = train_step.make_train_step(
