@@ -3,18 +3,23 @@
 ``Llama`` is the RMSNorm / RoPE / SwiGLU decoder; its config also
 expresses OLMoE-1B-7B (``LlamaConfig.olmoe_1b_7b``), whose routed
 experts are ``ops/moe.py::routed_ffn`` (dropless, top-k).
-``MoETransformer`` is the older top-1, capacity-dropping switch model
+``NemotronH`` is the layer-typed stack, one mixer a block by a pattern
+over ``M`` (Mamba-2, ``ops/ssm.py``), ``E`` (sigmoid-routed relu^2
+experts through the same ``routed_ffn``, of which a share may be held,
+plus a shared expert) and ``*`` (grouped-query attention); its config
+expresses NVIDIA-Nemotron-3-Nano-30B-A3B. ``MoETransformer`` is the older top-1, capacity-dropping switch model
 on GPT-2 blocks, which goes when the dropless path runs under ``ep``
 (ROADMAP C5)."""
 
 from ray_tpu.models.gpt2 import GPT2, GPT2Config
 from ray_tpu.models.llama import Llama, LlamaConfig
 from ray_tpu.models.moe import MoEConfig, MoETransformer
+from ray_tpu.models.nemotron_h import NemotronH, NemotronHConfig
 from ray_tpu.models.resnet import ResNet, ResNet50Config
 from ray_tpu.models.vit import ViT, ViTConfig
 
 __all__ = [
     "GPT2", "GPT2Config", "Llama", "LlamaConfig",
-    "MoETransformer", "MoEConfig", "ResNet", "ResNet50Config",
-    "ViT", "ViTConfig",
+    "MoETransformer", "MoEConfig", "NemotronH", "NemotronHConfig",
+    "ResNet", "ResNet50Config", "ViT", "ViTConfig",
 ]

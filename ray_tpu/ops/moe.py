@@ -1,15 +1,27 @@
 """Mixture-of-Experts feed-forward layers. Two paths live here.
 
-``routed_ffn`` is the one public models use (OLMoE-1B-7B through
-``models/llama.py``): top-k, **dropless**. Router logits and softmax in
-float32, ``top_k``, the ``tokens x k`` routes sorted by expert, three
-grouped matmuls (SwiGLU) over the ragged groups in bf16 with float32
+``routed_ffn`` is the one public models use: top-k, **dropless**.
+The float32 router, ``top_k``, the ``tokens x k`` routes sorted by
+expert, grouped matmuls over the ragged groups in bf16 with float32
 accumulation, un-sort, weighted combine. No capacity, no dropped
-route: every one of the ``T*k`` routes is computed whatever the skew.
+route: every route to an expert held here is computed whatever the
+skew. What runs through it, by argument:
+
+- OLMoE-1B-7B (``models/llama.py``): the softmax router over 64
+  experts, top-8 unnormalised, SwiGLU experts (three grouped matmuls),
+  every expert held, with the load-balancing and z losses;
+- Nemotron-3-Nano-30B-A3B (``models/nemotron_h.py``): the sigmoid
+  router over 128 experts with its selection bias, top-6 renormalised
+  and scaled, un-gated relu^2 experts (two grouped matmuls), and
+  ``experts_held``: the layer is told which experts it holds (a chip's
+  share under expert parallelism), routes over all of them and
+  computes the part of the sum that its own give. The shared expert is
+  the model's, a dense MLP beside this layer.
+
 On a mesh that shards tokens (dp, fsdp, sp) each chip routes and sorts
 its own tokens under ``shard_map`` with the experts replicated; a mesh
-that would shard the experts (``ep > 1``, ``tp > 1``) is refused: not
-implemented for this path yet.
+that would shard the experts (``ep > 1``, ``tp > 1``) is refused: the
+exchange of routes between chips is not implemented yet.
 
 ``top1_dispatch`` / ``moe_ffn`` / ``dense_switch_ffn_reference`` are
 the older switch path (``models/moe.py``): top-1, capacity-dropping,
@@ -139,16 +151,37 @@ def grouped_matmul_path() -> str:
 _GMM_TILING = (512, 1024, 1024)
 
 
-def _grouped_matmul(lhs, rhs, group_sizes):
+@functools.lru_cache(maxsize=None)
+def _gmm_tiling(rows_per_expert: int):
+    """The (m, k, n) tile for each grouped matmul of a layer, forward
+    and backward, from that matmul's own shapes: ``_GMM_TILING``, but
+    the row tile no larger than the rows an expert is expected to own
+    (a group's last tile is computed whole, and a tile that straddles
+    two groups twice: at 384 rows an expert a 512-row tile is more
+    padding than work), and a width tile that pads its dimension least
+    (2,688 = 3 x 896; 1,856 into 3 x 640 rather than 2 x 1,024). One
+    function a hint, so that a step's layers share their kernels'
+    jitted functions."""
+    tm = min(_GMM_TILING[0], max(128, 1 << (rows_per_expert.bit_length() - 1)))
+
+    def width(d: int, most: int) -> int:
+        fits = range(most // 2, most + 1, 128)
+        return min(fits, key=lambda t: (-(-d // t) * t, -t))
+
+    def tiles(m: int, k: int, n: int) -> tuple[int, int, int]:
+        return (min(tm, m), min(width(k, _GMM_TILING[1]), k),
+                min(width(n, _GMM_TILING[2]), n))
+    return tiles
+
+
+def _grouped_matmul(lhs, rhs, group_sizes, rows_per_expert: int):
     """``[m, k] x [E, k, n] -> [m, n]``: rows ``lhs`` sorted by group,
     group ``e`` holding ``group_sizes[e]`` of them, each multiplied by
     its group's matrix. bf16 in and out, float32 accumulation."""
     if grouped_matmul_path() == "megablox_gmm":
         from jax.experimental.pallas.ops.tpu.megablox import ops
-        m, k = lhs.shape
-        n = rhs.shape[-1]
-        tiling = tuple(min(t, s) for t, s in zip(_GMM_TILING, (m, k, n)))
-        return ops.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling)
+        return ops.gmm(lhs, rhs, group_sizes, lhs.dtype,
+                       _gmm_tiling(rows_per_expert))
     return lax.ragged_dot(lhs, rhs, group_sizes,
                           preferred_element_type=jnp.float32
                           ).astype(lhs.dtype)
@@ -205,17 +238,170 @@ def _route(x, router_w, top_k: int, norm_topk_prob: bool):
     return weights, experts, probs.sum(axis=0), jnp.sum(lse * lse)
 
 
-def _routed_ffn_local(x, router_w, w_gate, w_up, w_down, *, top_k,
-                      norm_topk_prob, over=()):
+def _route_sigmoid(x, router_w, select_bias, top_k: int,
+                   norm_topk_prob: bool, route_scale: float):
+    """The float32 sigmoid router (DeepSeek-V3's, Nemotron-H's): every
+    expert scored ``s = sigmoid(x . W)`` on its own; the ``top_k`` are
+    chosen by ``s + select_bias`` (the score-correction bias: it moves
+    the choice and carries no gradient), their weights are ``s``
+    **without** it, divided by their sum (+1e-20) under
+    ``norm_topk_prob``, times ``route_scale``. No auxiliary loss
+    belongs to it: the last two returns are zeros."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, experts = lax.top_k(
+        scores + lax.stop_gradient(select_bias.astype(jnp.float32)), top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk_prob:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    return (weights * route_scale, experts,
+            jnp.zeros((router_w.shape[-1],), jnp.float32), jnp.float32(0))
+
+
+def _experts(xs, w_gate, w_up, w_down, counts, rows_per_expert: int):
+    """The experts on rows sorted by expert, ``counts[e]`` of them for
+    expert ``e`` (``rows_per_expert`` at an even load): SwiGLU (three
+    grouped matmuls), or with no ``w_gate`` the un-gated relu^2 expert
+    (two)."""
+    dt = xs.dtype
+    gmm = functools.partial(_grouped_matmul, group_sizes=counts,
+                            rows_per_expert=rows_per_expert)
+    if w_gate is None:
+        hidden = jnp.square(jax.nn.relu(gmm(xs, w_up.astype(dt))))
+    else:
+        gate = gmm(xs, w_gate.astype(dt))
+        up = gmm(xs, w_up.astype(dt))
+        hidden = jax.nn.silu(gate) * up
+    return gmm(hidden, w_down.astype(dt))
+
+
+_HELD_ROOM = 2       # times the even share: the rows gathered for a share
+
+
+def held_rows(routes: int, held: int, experts: int) -> int:
+    """How many sorted rows a layer that holds ``held`` of ``experts``
+    experts gathers and multiplies at a time (a slab; one is enough
+    unless more routes than that land on them): ``_HELD_ROOM`` times
+    the even share of the ``routes``, rounded up to the grouped
+    matmul's row tile, and never more than all of them."""
+    tile = _GMM_TILING[0]
+    even = routes * held / experts
+    return min(routes, tile * math.ceil(_HELD_ROOM * even / tile))
+
+
+def _slab(lo, static, x, order, weights, sizes, w_gate, w_up, w_down):
+    """The sorted routes ``lo .. lo + rows`` (``order`` holds the held
+    experts' routes first, by expert, ``sizes[e]`` of them for held
+    expert ``e``): their tokens' rows gathered, multiplied by the
+    experts that own them, weighted and added to their tokens' rows of
+    a float32 ``[t, d]``. ``lo`` may be traced; ``static`` is (the
+    slab's rows, top_k, an expert's rows at an even load)."""
+    rows, top_k, per_expert = static
+    with jax.named_scope("dispatch"):
+        ends = jnp.cumsum(sizes)
+        own = (jnp.clip(ends, lo, lo + rows)
+               - jnp.clip(ends - sizes, lo, lo + rows))
+        route = lax.dynamic_slice(order, (lo,), (rows,))
+        live = (lo + jnp.arange(rows, dtype=jnp.int32) < ends[-1])[:, None]
+        token = route // top_k
+        xs = jnp.where(live, x[token], jnp.zeros((), x.dtype))
+    with jax.named_scope("experts"):
+        ys = _experts(xs, w_gate, w_up, w_down, own, per_expert)
+    with jax.named_scope("combine"):
+        # rows past the real ones hold whatever the kernel left
+        ys = jnp.where(live, ys, jnp.zeros((), ys.dtype)) \
+            * weights.reshape(-1)[route][:, None].astype(ys.dtype)
+        return jnp.zeros(x.shape, jnp.float32).at[token].add(
+            ys.astype(jnp.float32))
+
+
+def _slabs_needed(sizes, rows):
+    return (sizes.sum() + rows - 1) // rows
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _slabs(static, x, order, weights, sizes, w_gate, w_up, w_down):
+    """Every slab of ``rows`` sorted routes that holds a real one,
+    added up: the first always, the next ones in a loop that runs as
+    often as the routes that landed here need (not at all at twice
+    the even share or less). The backward walks the same slabs,
+    recomputing each (the gathered rows and the experts' hidden
+    activations: 1% of a Nemotron step's operations), so nothing of a
+    slab outlives it."""
+    args = (x, order, weights, sizes, w_gate, w_up, w_down)
+    rows = static[0]
+    return lax.fori_loop(
+        1, _slabs_needed(sizes, rows),
+        lambda j, y: y + _slab(j * rows, static, *args),
+        _slab(0, static, *args))
+
+
+def _slabs_fwd(static, *args):
+    return _slabs(static, *args), args
+
+
+def _slabs_bwd(static, args, dy):
+    x, order, weights, sizes, *ws = args
+    rows = static[0]
+
+    def pull(lo):
+        return jax.vjp(
+            lambda x, weights, *ws: _slab(lo, static, x, order, weights,
+                                          sizes, *ws),
+            x, weights, *ws)[1](dy)
+
+    dx, dweights, *dws = lax.fori_loop(
+        1, _slabs_needed(sizes, rows),
+        lambda j, acc: jax.tree_util.tree_map(jnp.add, acc, pull(j * rows)),
+        pull(0))
+    return (dx, None, dweights, None, *dws)
+
+
+_slabs.defvjp(_slabs_fwd, _slabs_bwd)
+
+
+def _held_part(x, flat, weights, counts, w_gate, w_up, w_down, top_k,
+               experts_held):
+    """The held experts' part of every token's sum, ``[t, d]``. The
+    held experts' routes sort to the front (by expert; every absent
+    expert's route takes one key behind them), and the sorted routes
+    are walked in slabs of ``held_rows`` rows (``_slabs``): no route is
+    ever dropped and no buffer has the worst case's ``t * top_k``
+    rows."""
+    first, held = experts_held
+    routes = x.shape[0] * top_k
+    rows = held_rows(routes, held, counts.shape[0])
+    with jax.named_scope("dispatch"):
+        local = flat - first
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        _, order = lax.sort((key, jnp.arange(routes, dtype=jnp.int32)),
+                            num_keys=1, is_stable=True)
+        # whole slabs: the rows past the routes are never live
+        order = jnp.pad(order, (0, -routes % rows))
+    return _slabs((rows, top_k, max(1, routes // counts.shape[0])), x, order,
+                  weights, counts[first:first + held], w_gate, w_up, w_down
+                  ).astype(x.dtype)
+
+
+def _routed_ffn_local(x, router_w, w_gate, w_up, w_down, select_bias=None,
+                      *, top_k, norm_topk_prob, over=(), router="softmax",
+                      route_scale=1.0, experts_held=None):
     """The layer on the tokens in hand (``[..., d]``); the router's
     sums are added over the mesh axes ``over`` so that the two losses
-    and the load are those of the global batch."""
+    and the load are those of the global batch. ``w_gate`` None: the
+    two-matrix relu^2 expert."""
     shape = x.shape
     x = x.reshape(-1, shape[-1])
     t, e = x.shape[0], router_w.shape[-1]
     with jax.named_scope("router"):
-        weights, experts, prob_sum, z_sum = _route(
-            x, router_w, top_k, norm_topk_prob)
+        if router == "sigmoid":
+            weights, experts, prob_sum, z_sum = _route_sigmoid(
+                x, router_w, select_bias, top_k, norm_topk_prob,
+                route_scale)
+        else:
+            weights, experts, prob_sum, z_sum = _route(
+                x, router_w, top_k, norm_topk_prob)
         flat = experts.reshape(-1)
         counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
         total = (counts.astype(jnp.float32), prob_sum, z_sum,
@@ -227,18 +413,20 @@ def _routed_ffn_local(x, router_w, w_gate, w_up, w_down, *, top_k,
         # sums to k), P_e the mean router probability of e.
         aux = e * jnp.sum(load / n * prob_sum / n)
         z = z_sum / n
+    if experts_held is not None:
+        y = _held_part(x, flat, weights, counts, w_gate, w_up, w_down,
+                       top_k, experts_held)
+        return y.reshape(shape), aux, z, load
     with jax.named_scope("dispatch"):
         iota = jnp.arange(t * top_k, dtype=jnp.int32)
         _, order = lax.sort((flat, iota), num_keys=1, is_stable=True)
         _, inverse = lax.sort((order, iota), num_keys=1)
         xs = _dispatch(x, order, inverse, top_k)
     with jax.named_scope("experts"):
-        dt = x.dtype
-        gate = _grouped_matmul(xs, w_gate.astype(dt), counts)
-        up = _grouped_matmul(xs, w_up.astype(dt), counts)
-        ys = _grouped_matmul(jax.nn.silu(gate) * up, w_down.astype(dt),
-                             counts)
+        ys = _experts(xs, w_gate, w_up, w_down, counts,
+                      t * top_k // e)
     with jax.named_scope("combine"):
+        dt = x.dtype
         ys = _unsort(ys, order, inverse).reshape(t, top_k, -1)
         y = jnp.einsum("tkd,tk->td", ys, weights.astype(dt),
                        preferred_element_type=jnp.float32).astype(dt)
@@ -279,51 +467,105 @@ def _token_axes(mesh, batch: int, seq: int):
 
 
 def routed_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
-               norm_topk_prob: bool = False, mesh=None):
-    """Dropless top-k mixture of SwiGLU experts.
+               norm_topk_prob: bool = False, mesh=None,
+               router: str = "softmax", select_bias=None,
+               route_scale: float = 1.0, expert: str = "swiglu",
+               experts_held: tuple[int, int] | None = None):
+    """Dropless top-k mixture of experts.
 
     x:        [batch, seq, d] (or [tokens, d]) activations
-    router_w: [d, E]          bias-free router
-    w_gate, w_up: [E, d, f];  w_down: [E, f, d]
+    router_w: [d, E]          bias-free router, over **all** E experts
+    w_gate, w_up: [E_held, d, f];  w_down: [E_held, f, d]
+
+    ``router``: ``"softmax"`` (OLMoE: probabilities over all experts,
+    the ``top_k`` largest, renormalised only with ``norm_topk_prob``)
+    or ``"sigmoid"`` (Nemotron-H, DeepSeek-V3: ``_route_sigmoid``, with
+    ``select_bias`` [E] and ``route_scale``). ``expert``: ``"swiglu"``
+    (three matrices, ``down(silu(gate x) * up x)``) or ``"relu2"`` (two,
+    ``down(relu(up x)^2)``; ``w_gate`` is None). ``experts_held =
+    (first, count)``: this caller holds experts ``first .. first +
+    count - 1`` of the ``E`` the router scores (one chip's share under
+    expert parallelism; default: all). The router still sees every
+    token and every expert; routes to absent experts are sorted behind
+    the held ones' and are neither gathered nor multiplied:
+    ``held_rows`` (twice the even share) sorted rows are, with the
+    group sizes saying how many are real, and further slabs of as many
+    only when more than that land here (``_slabs``). ``y`` is then
+    the held experts' part of each token's sum; what the absent experts
+    would add is left to the chips that hold them.
 
     Returns ``(y, aux, z, load)``: the output in ``x``'s shape and
-    dtype (sum over each token's ``top_k`` experts of router
-    probability times the expert's output; the probabilities are
-    renormalised over the ``top_k`` only with ``norm_topk_prob``) and,
-    in float32, the load-balancing loss ``E * sum_e f_e P_e`` (the
-    published code's form: ``f_e`` sums to ``top_k``), the router
-    z-loss ``mean(logsumexp(logits)^2)`` and the routes each expert
-    received, ``[E]``, summing to ``tokens * top_k``.
+    dtype (sum over each token's ``top_k`` experts of router weight
+    times the expert's output) and, in float32, the load-balancing
+    loss ``E * sum_e f_e P_e`` (the published code's form: ``f_e`` sums
+    to ``top_k``), the router z-loss ``mean(logsumexp(logits)^2)``
+    (both zero for the sigmoid router, which has neither) and the
+    routes each of the ``E`` experts received, ``[E]``, summing to
+    ``tokens * top_k`` also when only a share is held
+    (``held_route_share`` reads the share's part of it).
 
     On a ``mesh`` that shards tokens (dp, fsdp on the batch, sp on the
     sequence) each chip routes, sorts and computes the tokens it holds
     under ``shard_map``, experts replicated; the three returned
     statistics are those of the global batch. A sort over a dimension
     sharded over ``dp`` would gather every token to every chip.
-    ``ep > 1`` and ``tp > 1`` raise ``NotImplementedError``.
+    ``ep > 1`` and ``tp > 1`` raise ``NotImplementedError``:
+    ``experts_held`` is what a chip of an ``ep`` mesh will be told,
+    the exchange of routes between them is not written.
     """
-    local = functools.partial(_routed_ffn_local, top_k=top_k,
-                              norm_topk_prob=norm_topk_prob)
+    if router not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown router {router!r}")
+    if (expert == "relu2") != (w_gate is None) or expert not in (
+            "swiglu", "relu2"):
+        raise ValueError(f"expert {expert!r} with w_gate "
+                         f"{'absent' if w_gate is None else 'given'}")
+    e = router_w.shape[-1]
+    first, held = experts_held or (0, e)
+    if not (0 <= first and first + held <= e and w_up.shape[0] == held):
+        raise ValueError(f"experts_held {experts_held} of {e} experts, "
+                         f"weights for {w_up.shape[0]}")
+    local = functools.partial(
+        _routed_ffn_local, top_k=top_k, norm_topk_prob=norm_topk_prob,
+        router=router, route_scale=route_scale, experts_held=experts_held)
+    weights = (router_w, w_gate, w_up, w_down)
+    if router == "sigmoid":
+        weights += (jnp.zeros((e,), jnp.float32) if select_bias is None
+                    else select_bias,)
     batch_axes, seq_axis = _token_axes(
         mesh, x.shape[0], x.shape[1] if x.ndim == 3 else 1)
     axes = batch_axes + ((seq_axis,) if seq_axis else ())
     tokens = math.prod(x.shape[:-1])
     if axes:
         from jax.sharding import PartitionSpec as P
-        held = P(batch_axes or None, seq_axis)
+        held_spec = P(batch_axes or None, seq_axis)
         # All mesh axes manual, as in ops/attention.py and the chunked
         # cross-entropy: the weights enter replicated, so the transpose
         # sums their gradients over the axes once.
         out = jax.shard_map(
             functools.partial(local, over=axes), mesh=mesh,
-            in_specs=(held, P(), P(), P(), P()),
-            out_specs=(held, P(), P(), P()), check_vma=False)(
-                x, router_w, w_gate, w_up, w_down)
+            in_specs=(held_spec,) + tuple(
+                None if w is None else P() for w in weights),
+            out_specs=(held_spec, P(), P(), P()), check_vma=False)(
+                x, *weights)
         tokens //= math.prod(mesh.shape[a] for a in axes)
     else:
-        out = local(x, router_w, w_gate, w_up, w_down)
-    tracing.note_trace(
-        moe_tokens=tokens, moe_experts=router_w.shape[-1],
-        moe_top_k=top_k, moe_routes=tokens * top_k,
-        moe_path=grouped_matmul_path(), moe_axes=list(axes))
+        out = local(x, *weights)
+    notes = dict(
+        moe_tokens=tokens, moe_experts=e, moe_top_k=top_k,
+        moe_routes=tokens * top_k, moe_path=grouped_matmul_path(),
+        moe_axes=list(axes))
+    if (router, expert, experts_held) != ("softmax", "swiglu", None):
+        # beside today's keys, and only where one of them says something
+        notes.update(
+            moe_router=router, moe_expert_kind=expert,
+            moe_experts_held=[first, held],
+            moe_rows_sorted=held_rows(tokens * top_k, held, e))
+    tracing.note_trace(**notes)
     return out
+
+
+def held_route_share(load, experts_held: tuple[int, int]):
+    """Of all the routes in ``load`` ([..., E], as ``routed_ffn``
+    returns it), the share that landed on the experts held."""
+    first, held = experts_held
+    return load[..., first:first + held].sum() / load.sum()

@@ -1,0 +1,164 @@
+"""State-space (Mamba-2) ops: the selective scan in its chunked form,
+the depthwise causal convolution (with its SiLU) in front of it and the
+grouped gated RMSNorm behind it, both recomputed in the backward. Nemotron-H's ``M`` layers (``models/nemotron_h.py``)
+are the caller.
+
+The recurrence, a head (``S`` is ``[P, N]``)::
+
+    S_t = exp(dt_t * A) * S_{t-1} + dt_t * x_t (x) B_t
+    y_t = S_t . C_t + D * x_t
+
+``mamba2_scan`` computes it a chunk of the sequence at a time (the
+"state-space duality" form of Mamba-2, arXiv:2405.21060): inside a
+chunk the quadratic term ``sum_{s<=t} exp(a_{s+1..t}) dt_s (C_t . B_s)
+x_s`` as two matmuls round a ``[chunk, chunk]`` decay-masked score
+square; each chunk's contribution to the state at its end as one
+matmul; the states carried from chunk to chunk by a scan over the
+chunks (64 steps at 8,192 tokens, each an elementwise update of
+``[H, P, N]``); and the carried state's contribution to the chunk's
+outputs as one more matmul. Matmul operands are in ``x``'s dtype
+(bfloat16 in training) with float32 accumulation; ``dt``, the decays
+and their cumulative sums are float32 throughout.
+
+Written plainly, autodiff would keep every chunk's ``[H, chunk, chunk]``
+decay and score squares for the backward: ~210 KB a token a layer in
+float32. So the whole function is a ``jax.checkpoint`` whose policy
+saves one thing, the states at the chunk boundaries (``[T / chunk, H,
+P, N]`` float32: 134 MB a layer at 8,192 tokens): the backward
+recomputes inside each chunk from the inputs and those states.
+
+One path, pure XLA (``scan_path()``): the chunk terms are batched
+matmuls that XLA places on the MXU. A sequence split over chips (``sp``)
+would need the state passed between chips; the model refuses it by
+name.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+_BOUNDARY = "ssm_boundary_states"
+
+
+def scan_path() -> str:
+    """Which scan ``mamba2_scan`` compiles: one, on every backend."""
+    return "chunked_xla"
+
+
+def _ssd(x, dt, A, B, C, chunk: int):
+    """The chunked scan without the ``D`` skip, ``T`` a multiple of
+    ``chunk``. x [b, T, H, P]; dt [b, T, H] float32; A [H] float32;
+    B, C [b, T, G, N]; returns float32 [b, T, H, P]."""
+    b, T, H, P = x.shape
+    G, N = B.shape[-2:]
+    R, nc, L = H // G, T // chunk, chunk
+    dtype = x.dtype
+    xc = x.reshape(b, nc, L, G, R, P)
+    dtc = dt.reshape(b, nc, L, G, R)
+    Bc = B.reshape(b, nc, L, G, N)
+    Cc = C.reshape(b, nc, L, G, N)
+    # log-decay a step, and its running sum inside the chunk (<= 0)
+    cum = jnp.cumsum(dtc * A.reshape(G, R), axis=2)     # [b, nc, L, G, R]
+
+    # inside a chunk: t reads s <= t through exp(cum_t - cum_s)
+    scores = jnp.einsum("bctgn,bcsgn->bcgts", Cc, Bc,
+                        preferred_element_type=jnp.float32)
+    ct = jnp.moveaxis(cum, 2, -1)                       # [b, nc, G, R, L]
+    reach = ct[..., :, None] - ct[..., None, :]         # [.., t, s]
+    causal = jnp.tril(jnp.ones((L, L), bool))
+    decay = jnp.exp(jnp.where(causal, reach, -jnp.inf))
+    weight = (scores[:, :, :, None] * decay
+              * jnp.moveaxis(dtc, 2, -1)[..., None, :])  # [b,nc,G,R,t,s]
+    y = jnp.einsum("bcgrts,bcsgrp->bctgrp", weight.astype(dtype), xc,
+                   preferred_element_type=jnp.float32)
+
+    # each chunk's own contribution to the state at its end
+    to_end = jnp.exp(cum[:, :, -1:] - cum) * dtc        # [b, nc, L, G, R]
+    own = jnp.einsum("bcsgrp,bcsgn->bcgrpn",
+                     (xc * to_end[..., None]).astype(dtype), Bc,
+                     preferred_element_type=jnp.float32)
+    keep = jnp.exp(cum[:, :, -1])                       # [b, nc, G, R]
+
+    # carry: the state entering chunk c (zeros for the first)
+    def step(state, inp):
+        own_c, keep_c = inp
+        return keep_c[..., None, None] * state + own_c, state
+
+    _, entering = lax.scan(
+        step, jnp.zeros_like(own[:, 0]),
+        (jnp.moveaxis(own, 1, 0), jnp.moveaxis(keep, 1, 0)))
+    entering = checkpoint_name(jnp.moveaxis(entering, 0, 1), _BOUNDARY)
+
+    # what the carried state adds inside the chunk
+    y = y + jnp.einsum("bctgn,bcgrpn->bctgrp", Cc, entering.astype(dtype),
+                       preferred_element_type=jnp.float32
+                       ) * jnp.exp(cum)[..., None]
+    return y.reshape(b, T, H, P)
+
+
+def mamba2_scan(x, dt, A, B, C, D, *, chunk: int = 128):
+    """The Mamba-2 selective scan, chunked; backward by recomputation
+    from the chunk-boundary states.
+
+    x:  [batch, T, H, P]  the heads' inputs (compute dtype)
+    dt: [batch, T, H]     step sizes after softplus, float32
+    A:  [H]               negative decay rates, float32
+    B, C: [batch, T, G, N]  input and output projections of the state;
+                          head ``h`` uses group ``h // (H // G)``
+    D:  [H]               the skip's weight
+    Returns ``y`` [batch, T, H, P] in ``x``'s dtype. ``T`` need not be
+    a multiple of ``chunk``: the tail is padded with steps that neither
+    decay nor write the state.
+    """
+    return jax.checkpoint(
+        functools.partial(_padded_scan, chunk=chunk),
+        policy=jax.checkpoint_policies.save_only_these_names(_BOUNDARY))(
+            x, dt, A, B, C, D)
+
+
+def _padded_scan(x, dt, A, B, C, D, *, chunk: int):
+    T = x.shape[1]
+    pad = (-T) % chunk
+    xp, dtp, Bp, Cp = ((jnp.pad(z, ((0, 0), (0, pad))
+                                + ((0, 0),) * (z.ndim - 2)) if pad else z)
+                       for z in (x, dt, B, C))
+    y = _ssd(xp, dtp.astype(jnp.float32), A.astype(jnp.float32), Bp, Cp,
+             chunk)[:, :T]
+    return (y + D.astype(jnp.float32)[:, None] * x).astype(x.dtype)
+
+
+@jax.checkpoint
+def causal_conv1d_silu(x, weight, bias):
+    """``silu`` of the depthwise causal convolution over time: ``y[t,
+    c] = bias[c] + sum_j weight[j, c] * x[t - (K - 1) + j, c]``, zeros
+    before the start. x [batch, T, C]; weight [K, C]; bias [C]. K
+    shifted multiply-adds (K is 4), recomputed in the backward: only
+    ``x`` is kept, not the sum in front of the SiLU."""
+    K = weight.shape[0]
+    T = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    w = weight.astype(x.dtype)
+    y = bias.astype(x.dtype)
+    for j in range(K):
+        y = y + padded[:, j:j + T] * w[j]
+    return jax.nn.silu(y)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4))
+def gated_group_rms_norm(y, z, scale, groups: int, eps: float):
+    """Mamba-2's output norm: ``RMSNorm(y * silu(z))`` with the mean
+    square taken over each of ``groups`` equal slices of the last
+    dimension and one ``scale`` over all of it; float32 inside, ``y``'s
+    dtype out. Recomputed in the backward: ``y`` and ``z`` are kept, in
+    their own dtype, and none of the float32 products between."""
+    dtype = y.dtype
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    shape = g.shape
+    g = g.reshape(*shape[:-1], groups, shape[-1] // groups)
+    g = g * lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return (g.reshape(shape) * scale.astype(jnp.float32)).astype(dtype)
