@@ -221,6 +221,7 @@ class _GateNorm(nn.Module):
 
 class Mamba2Mixer(nn.Module):
     config: NemotronHConfig
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, x):
@@ -242,10 +243,12 @@ class Mamba2Mixer(nn.Module):
                 xs.reshape(b, t, h, p),
                 jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
                 -jnp.exp(a_log), bs.reshape(b, t, g, n),
-                cs.reshape(b, t, g, n), skip, chunk=cfg.chunk)
+                cs.reshape(b, t, g, n), skip, chunk=cfg.chunk,
+                mesh=self.mesh)
         tracing.note_trace(
             ssm_tokens=b * t, ssm_heads=h, ssm_state=n,
-            ssm_chunk=cfg.chunk, ssm_path=ssm.scan_path())
+            ssm_chunk=cfg.chunk, ssm_path=ssm.scan_path(
+                (b, t, h, p), (b, t, g, n), cfg.chunk, self.mesh))
         y = _GateNorm(cfg, name="gate_norm")(y.reshape(b, t, inner), z)
         return _dense(cfg)(cfg.n_embd, name="out_proj")(y)
 
@@ -348,7 +351,7 @@ class Block(nn.Module):
         h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype, name="norm")(x)
         if self.kind == "M":
-            return x + Mamba2Mixer(cfg, name="mamba")(h)
+            return x + Mamba2Mixer(cfg, self.mesh, name="mamba")(h)
         if self.kind == "*":
             return x + Attention(cfg, name="attn")(h, attn_fn)
         return x + MoE(cfg, self.mesh, name="mlp")(h)

@@ -27,10 +27,21 @@ saves one thing, the states at the chunk boundaries (``[T / chunk, H,
 P, N]`` float32: 134 MB a layer at 8,192 tokens): the backward
 recomputes inside each chunk from the inputs and those states.
 
-One path, pure XLA (``scan_path()``): the chunk terms are batched
-matmuls that XLA places on the MXU. A sequence split over chips (``sp``)
-would need the state passed between chips; the model refuses it by
-name.
+Two paths, chosen by ``scan_path()`` from the backend, the shapes and
+the devices the program spans. On a TPU, where the shapes tile (a chunk
+and a state of whole 128-lane tiles, each group's heads in blocks of
+eight that fill whole tiles), the scan is two Pallas kernels, forward
+and backward, under one ``custom_vjp`` (``ops/pallas/ssd_scan.py``,
+``pallas_chunked``): a chunk's squares live and die in VMEM, the state
+is carried across the chunks in scratch, and the boundary states are
+the one thing kept. A program on one device calls them bare; one whose
+mesh shards the batch and nothing else (``dp``, ``fsdp``) calls them
+under a ``shard_map`` over the batch, each device its own sequences.
+Everywhere else it is pure XLA (``_ssd`` below, ``chunked_xla``): the
+chunk terms are batched matmuls that XLA places on the MXU, the
+squares arrays in HBM. Same mathematics, same precisions, same
+residual. A sequence split over chips (``sp``) would need the state
+passed between chips; the model refuses it by name.
 """
 
 from __future__ import annotations
@@ -42,12 +53,43 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops.pallas import ssd_scan
+
 _BOUNDARY = "ssm_boundary_states"
+# what ``parallel/sharding.py`` maps the logical "batch" to
+_BATCH_AXES = ("dp", "fsdp")
 
 
-def scan_path() -> str:
-    """Which scan ``mamba2_scan`` compiles: one, on every backend."""
+def scan_path(x_shape, state_shape, chunk: int, mesh=None) -> str:
+    """Which scan ``mamba2_scan`` compiles for ``x`` [b, T, H, P] and
+    ``B`` / ``C`` [b, T, G, N] at this chunk: ``pallas_chunked`` on a
+    TPU where the kernels tile the shapes and ``_kernel_batch_axes``
+    finds the program one the kernels can serve, else ``chunked_xla``."""
+    (h, p), (g, n) = x_shape[-2:], state_shape[-2:]
+    if (jax.default_backend() == "tpu"
+            and ssd_scan.shapes_ok(h, p, g, n, chunk)
+            and _kernel_batch_axes(mesh, x_shape[0]) is not None):
+        return "pallas_chunked"
     return "chunked_xla"
+
+
+def _kernel_batch_axes(mesh, batch: int):
+    """The mesh axes to ``shard_map`` the kernels over, ``()`` for a
+    one-device program, ``None`` where the kernels cannot run: a
+    ``pallas_call`` has no SPMD partitioning rule, so a program that
+    spans devices reaches it only through a ``shard_map``. The scan is
+    independent a sequence, so the batch's axes are the ones it can be
+    mapped over; a mesh with any other real axis, or a batch its
+    devices do not divide (the tiny one of init tracing), takes the XLA
+    path. Without a mesh nothing says how many devices the program
+    spans and the process's device count stands in for it, as in
+    ``ops/attention.py::causal_attention``."""
+    if mesh is None:
+        return () if jax.device_count() == 1 else None
+    real = tuple(a for a in mesh.axis_names if mesh.shape[a] > 1)
+    if set(real) <= set(_BATCH_AXES) and batch % mesh.size == 0:
+        return real
+    return None
 
 
 def _ssd(x, dt, A, B, C, chunk: int):
@@ -101,7 +143,7 @@ def _ssd(x, dt, A, B, C, chunk: int):
     return y.reshape(b, T, H, P)
 
 
-def mamba2_scan(x, dt, A, B, C, D, *, chunk: int = 128):
+def mamba2_scan(x, dt, A, B, C, D, *, chunk: int = 128, mesh=None):
     """The Mamba-2 selective scan, chunked; backward by recomputation
     from the chunk-boundary states.
 
@@ -113,8 +155,14 @@ def mamba2_scan(x, dt, A, B, C, D, *, chunk: int = 128):
     D:  [H]               the skip's weight
     Returns ``y`` [batch, T, H, P] in ``x``'s dtype. ``T`` need not be
     a multiple of ``chunk``: the tail is padded with steps that neither
-    decay nor write the state.
+    decay nor write the state. ``mesh`` is the mesh the program is
+    sharded over, if the caller knows one: ``scan_path`` decides from
+    it.
     """
+    if scan_path(x.shape, B.shape, chunk, mesh) == "pallas_chunked":
+        return ssd_scan.ssd_scan(
+            x, dt, A, B, C, D, chunk=chunk, mesh=mesh,
+            batch_axes=_kernel_batch_axes(mesh, x.shape[0]))
     return jax.checkpoint(
         functools.partial(_padded_scan, chunk=chunk),
         policy=jax.checkpoint_policies.save_only_these_names(_BOUNDARY))(

@@ -1,8 +1,10 @@
 """``ops/ssm.py``: the chunked Mamba-2 scan against the recurrence as
 written (a ``lax.scan`` over time, here), values and gradients, at
 sequence lengths that are and are not a multiple of the chunk; the
-convolution and the gated norm against their formulas; and what the
-backward keeps."""
+convolution and the gated norm against their formulas; what the
+backward keeps; and the same for the scan's Pallas kernels
+(``ops/pallas/ssd_scan.py``) in ``interpret`` mode, with which of the
+two paths ``scan_path`` picks for a shape, a backend and a mesh."""
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +12,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops import ssm
+from ray_tpu.ops.pallas import ssd_scan
 
 B, H, P, G, N, CHUNK = 2, 4, 8, 2, 16, 16
 
@@ -32,14 +35,15 @@ def _recurrence(x, dt, A, Bm, C, D):
     return jnp.moveaxis(y, 0, 1)
 
 
-def _inputs(T, seed=0):
+def _inputs(T, seed=0, shape=(B, H, P, G, N)):
+    b, h, p, g, n = shape
     ks = jax.random.split(jax.random.key(seed), 6)
-    return (jax.random.normal(ks[0], (B, T, H, P)),
-            jax.nn.softplus(jax.random.normal(ks[1], (B, T, H))),
-            -jnp.exp(jax.random.uniform(ks[2], (H,), maxval=2.7)),
-            jax.random.normal(ks[3], (B, T, G, N)),
-            jax.random.normal(ks[4], (B, T, G, N)),
-            jax.random.normal(ks[5], (H,)))
+    return (jax.random.normal(ks[0], (b, T, h, p)),
+            jax.nn.softplus(jax.random.normal(ks[1], (b, T, h))),
+            -jnp.exp(jax.random.uniform(ks[2], (h,), maxval=2.7)),
+            jax.random.normal(ks[3], (b, T, g, n)),
+            jax.random.normal(ks[4], (b, T, g, n)),
+            jax.random.normal(ks[5], (h,)))
 
 
 @pytest.mark.parametrize("T", [64, 16, 50, 7],
@@ -134,3 +138,230 @@ def test_gated_norm_normalises_each_group():
     grads = jax.grad(lambda *a: ssm.gated_group_rms_norm(
         *a, 3, 1e-5).sum(), (0, 1, 2))(y, z, scale)
     assert all(bool(jnp.isfinite(g).all()) for g in grads)
+
+
+# ---------------------------------------------------------------------------
+# the Pallas kernels, interpreted here: chunks of 128, a state of 128,
+# heads in blocks of eight
+# ---------------------------------------------------------------------------
+
+# (batch, heads, head width, groups, state), T
+KERNEL_CASES = {
+    "whole_chunks_batch_two": ((2, 8, 16, 1, 128), 256),
+    "ragged_tail_two_groups": ((1, 16, 16, 2, 128), 300),
+    "two_head_blocks_a_group": ((1, 16, 16, 1, 128), 256),
+}
+
+
+def _kernel(*args):
+    return ssd_scan.ssd_scan(*args, chunk=128, interpret=True)
+
+
+def _close_gradients(got, want):
+    """Within 1e-4 of each element and of the gradient's largest: the
+    running sums of a chunk's 128 log-decays, and their cotangents',
+    are float32 sums in another order than the recurrence's."""
+    for name, g, w in zip("x dt A B C D".split(), got, want):
+        np.testing.assert_allclose(
+            g, w, rtol=1e-4, atol=1e-4 * float(jnp.abs(w).max()),
+            err_msg=name)
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_kernel_scan_is_the_recurrence(case):
+    shape, T = KERNEL_CASES[case]
+    args = _inputs(T, seed=T, shape=shape)
+    got = _kernel(*args)
+    want = _recurrence(*args)
+    assert got.shape == want.shape == args[0].shape
+    np.testing.assert_allclose(got, want, rtol=1e-4,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_kernel_scan_gradients_are_the_recurrences(case):
+    """All six, through the backward kernel that recomputes a chunk's
+    squares from the inputs and the state entering it."""
+    shape, T = KERNEL_CASES[case]
+    args = _inputs(T, seed=T + 1, shape=shape)
+    got = jax.grad(lambda *a: jnp.sum(_kernel(*a) ** 2), range(6))(*args)
+    want = jax.grad(lambda *a: jnp.sum(_recurrence(*a) ** 2),
+                    range(6))(*args)
+    _close_gradients(got, want)
+
+
+def test_kernel_scan_is_the_xla_scan_to_float32_rounding():
+    """The two paths of ``mamba2_scan`` at one tileable shape: values
+    and gradients."""
+    args = _inputs(256, seed=3, shape=(1, 8, 16, 1, 128))
+
+    def xla(*a):
+        return ssm._padded_scan(*a, chunk=128)
+
+    with jax.default_matmul_precision("highest"):
+        want = xla(*args)
+        np.testing.assert_allclose(
+            _kernel(*args), want, rtol=1e-5,
+            atol=1e-5 * float(jnp.abs(want).max()))
+        got = jax.grad(lambda *a: jnp.sum(_kernel(*a) ** 2),
+                       range(6))(*args)
+        want = jax.grad(lambda *a: jnp.sum(xla(*a) ** 2), range(6))(*args)
+    _close_gradients(got, want)
+
+
+def test_kernel_scan_in_bfloat16_keeps_decays_in_float32():
+    shape = (1, 8, 16, 1, 128)
+    x, dt, A, Bm, C, D = _inputs(256, shape=shape)
+    want = _recurrence(x, dt, A, Bm, C, D)
+    low = (x.astype(jnp.bfloat16), dt, A, Bm.astype(jnp.bfloat16),
+           C.astype(jnp.bfloat16), D)
+    got = _kernel(*low)
+    assert got.dtype == jnp.bfloat16
+    err = jnp.abs(got.astype(jnp.float32) - want).max()
+    assert float(err) < 0.03 * float(jnp.abs(want).max())
+    grads = jax.grad(lambda *a: jnp.sum(
+        _kernel(*a).astype(jnp.float32) ** 2), range(6))(*low)
+    wants = jax.grad(lambda *a: jnp.sum(_recurrence(*a) ** 2),
+                     range(6))(x, dt, A, Bm, C, D)
+    assert [g.dtype for g in grads] == [a.dtype for a in low]
+    for name, g, w in zip("x dt A B C D".split(), grads, wants):
+        err = jnp.linalg.norm(g.astype(jnp.float32) - w)
+        assert float(err) < 0.03 * float(jnp.linalg.norm(w)), name
+
+
+def test_kernel_scan_takes_the_difference_of_the_sums_not_the_product():
+    """``A`` = -16 at ``dt`` = 0.1 runs the sum to -204.8 over a chunk:
+    ``exp`` of its negation alone is past float32 (3e38 at 88.7), the
+    decay between two steps is not."""
+    shape = (1, 8, 16, 1, 128)
+    x, _, _, Bm, C, D = _inputs(256, seed=5, shape=shape)
+    dt = jnp.full((1, 256, 8), 0.1)
+    A = jnp.full((8,), -16.0)
+    args = (x, dt, A, Bm, C, D)
+    got = _kernel(*args)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, _recurrence(*args), rtol=1e-4,
+                               atol=1e-4)
+    grads = jax.grad(lambda *a: jnp.sum(_kernel(*a) ** 2), range(6))(*args)
+    wants = jax.grad(lambda *a: jnp.sum(_recurrence(*a) ** 2),
+                     range(6))(*args)
+    assert all(bool(jnp.isfinite(g).all()) for g in grads)
+    _close_gradients(grads, wants)
+
+
+def test_kernel_scan_at_the_cells_head_width_in_bfloat16():
+    """Heads of 64 (a block is 512 lanes, as in the Nemotron cell) with
+    bfloat16 operands, against the XLA scan given the same operands:
+    both round a chunk's weights and states to bfloat16 at the same
+    places, so they differ by the order of float32 sums alone; and
+    against the recurrence in float32 within bfloat16's rounding."""
+    shape = (1, 8, 64, 1, 128)
+    x, dt, A, Bm, C, D = _inputs(256, seed=7, shape=shape)
+    low = (x.astype(jnp.bfloat16), dt, A, Bm.astype(jnp.bfloat16),
+           C.astype(jnp.bfloat16), D)
+
+    def xla(*a):
+        return ssm._padded_scan(*a, chunk=128)
+
+    def loss(f):
+        return lambda *a: jnp.sum(f(*a).astype(jnp.float32) ** 2)
+
+    got, want = _kernel(*low), xla(*low)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    scale = float(jnp.abs(want.astype(jnp.float32)).max())
+    # a bfloat16 result: one step of its rounding apart at most
+    assert float(jnp.abs(got.astype(jnp.float32)
+                         - want.astype(jnp.float32)).max()) < 2 ** -7 * scale
+    exact = _recurrence(x, dt, A, Bm, C, D)
+    assert float(jnp.abs(got.astype(jnp.float32) - exact).max()) < (
+        0.03 * float(jnp.abs(exact).max()))
+    grads = jax.grad(loss(_kernel), range(6))(*low)
+    wants = jax.grad(loss(xla), range(6))(*low)
+    exacts = jax.grad(loss(_recurrence), range(6))(x, dt, A, Bm, C, D)
+    for name, g, w, e in zip("x dt A B C D".split(), grads, wants, exacts):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert float(jnp.linalg.norm(g - w)) < (
+            0.01 * float(jnp.linalg.norm(w))), name
+        assert float(jnp.linalg.norm(g - e)) < (
+            0.03 * float(jnp.linalg.norm(e))), name
+
+
+CELL = ((1, 8192, 64, 64), (1, 8192, 8, 128), 128)
+
+
+def _mesh(**axes):
+    from ray_tpu.parallel.mesh import make_mesh
+    size = int(np.prod(list(axes.values())))
+    return make_mesh(axes, devices=jax.devices()[:size])
+
+
+@pytest.mark.parametrize("backend, x, state, chunk, path", [
+    ("tpu", *CELL, "pallas_chunked"),
+    ("cpu", *CELL, "chunked_xla"),
+    ("tpu", CELL[0], (1, 8192, 8, 16), 128, "chunked_xla"),
+    ("tpu", *CELL[:2], 16, "chunked_xla"),
+    ("tpu", (1, 8192, 64, 8), CELL[1], 128, "chunked_xla"),
+    ("tpu", (1, 8192, 32, 64), CELL[1], 128, "chunked_xla"),
+], ids=["the_cell_on_a_tpu", "the_cell_on_a_cpu", "a_state_of_16",
+        "a_chunk_of_16", "eight_heads_fill_no_tile",
+        "four_heads_a_group"])
+def test_scan_path_reads_the_backend_and_the_shapes(
+        monkeypatch, backend, x, state, chunk, path):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert ssm.scan_path(x, state, chunk) == path
+
+
+@pytest.mark.parametrize("axes, batch, path", [
+    (None, 1, "chunked_xla"),
+    ({"dp": 1}, 1, "pallas_chunked"),
+    ({"dp": 4}, 4, "pallas_chunked"),
+    ({"dp": 2, "fsdp": 2}, 8, "pallas_chunked"),
+    ({"dp": 4}, 2, "chunked_xla"),
+    ({"dp": 2, "tp": 2}, 4, "chunked_xla"),
+    ({"ep": 2}, 4, "chunked_xla"),
+], ids=["no_mesh_in_a_process_of_eight_devices", "a_mesh_of_one_device",
+        "dp", "dp_and_fsdp", "a_batch_dp_does_not_divide", "dp_and_tp",
+        "ep_alone"])
+def test_scan_path_reads_the_devices_the_program_spans(
+        monkeypatch, axes, batch, path):
+    """A ``pallas_call`` has no SPMD rule: the kernels serve a program
+    of one device, or under a ``shard_map`` one whose mesh shards the
+    batch and nothing else. This process has eight (virtual) devices,
+    so with no mesh given the count says the program may span them."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert jax.device_count() > 1
+    mesh = None if axes is None else _mesh(**axes)
+    x, state = ((batch, *shape[1:]) for shape in CELL[:2])
+    assert ssm.scan_path(x, state, 128, mesh) == path
+
+
+def test_kernel_scan_over_a_batch_sharded_mesh_is_the_one_device_scan():
+    """Under the ``shard_map`` over ``dp`` each device scans its own
+    sequences; ``A`` and ``D`` are whole on both and their gradients
+    are the sum of the two devices'."""
+    mesh = _mesh(dp=2)
+    args = _inputs(256, seed=11, shape=(2, 8, 16, 1, 128))
+
+    def sharded(*a):
+        return ssd_scan.ssd_scan(*a, chunk=128, interpret=True, mesh=mesh,
+                                 batch_axes=("dp",))
+
+    def loss(f):
+        return lambda *a: jnp.sum(f(*a) ** 2)
+
+    want = _kernel(*args)
+    got = jax.jit(sharded)(*args)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    assert got.sharding.spec[0] in ("dp", ("dp",))
+    grads = jax.jit(jax.grad(loss(sharded), range(6)))(*args)
+    wants = jax.grad(loss(_kernel), range(6))(*args)
+    for name, g, w in zip("x dt A B C D".split(), grads, wants):
+        np.testing.assert_allclose(
+            g, w, rtol=1e-5, atol=1e-5 * float(jnp.abs(w).max()),
+            err_msg=name)
+
+
+def test_shapes_the_kernels_do_not_tile_are_refused_by_name():
+    with pytest.raises(ValueError, match="do not tile"):
+        ssd_scan.ssd_scan(*_inputs(64), chunk=16, interpret=True)

@@ -80,3 +80,149 @@ def test_chunked_scan_compiles_for_v5e_at_the_cells_shape(v5e):
     ).compile()
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 4e9
+
+
+def test_kernel_scan_compiles_for_v5e_at_the_cells_shape(v5e, monkeypatch):
+    """The same shape where the backend is a TPU: ``mamba2_scan`` takes
+    the Pallas kernels, one custom call a pass, and no ``[128, 128]``
+    square of any chunk is an array of the program (268 MB each in
+    float32 on the XLA path): the temporaries are the boundary states
+    and the small per-step vectors."""
+    from ray_tpu.ops import ssm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
+    assert ssm.scan_path((1, 8192, 64, 64), (1, 8192, 8, 128),
+                         128) == "pallas_chunked"
+    arg = _arg(v5e[0])
+
+    def loss(x, dt, a, b, c, d):
+        return ssm.mamba2_scan(x, dt, a, b, c, d, chunk=128).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        arg((1, 8192, 64, 64), jnp.bfloat16), arg((1, 8192, 64), jnp.float32),
+        arg((64,), jnp.float32), arg((1, 8192, 8, 128), jnp.bfloat16),
+        arg((1, 8192, 8, 128), jnp.bfloat16), arg((64,), jnp.float32)
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def _dp(v5e):
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    mesh = Mesh(np.array(v5e), ("dp",))
+    rows = NamedSharding(mesh, PartitionSpec("dp"))
+    whole = NamedSharding(mesh, PartitionSpec())
+    return mesh, (lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=rows)), (
+        lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=whole))
+
+
+@pytest.mark.parametrize("given_the_mesh", [True, False],
+                         ids=["given_the_mesh", "given_no_mesh"])
+def test_scan_compiles_for_four_chips_with_the_batch_over_dp(
+        v5e, monkeypatch, given_the_mesh):
+    """Four sequences at the cell's shape, one a chip of the described
+    2x2, forward and backward. A ``pallas_call`` has no SPMD rule
+    ("Mosaic kernels cannot be automatically partitioned"): given the
+    mesh, the kernels run under a ``shard_map`` over ``dp``, a custom
+    call a pass on each chip; given none, in a process that sees more
+    than one device, the scan is XLA's. Either way no chip gathers
+    another's rows: the one collective is the sum of ``A``'s and
+    ``D``'s gradients."""
+    from ray_tpu.ops import ssm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert jax.device_count() > 1
+    mesh, rows, whole = _dp(v5e)
+    given = mesh if given_the_mesh else None
+
+    def loss(x, dt, a, b, c, d):
+        return ssm.mamba2_scan(x, dt, a, b, c, d, chunk=128,
+                               mesh=given).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        rows((4, 8192, 64, 64), jnp.bfloat16),
+        rows((4, 8192, 64), jnp.float32), whole((64,), jnp.float32),
+        rows((4, 8192, 8, 128), jnp.bfloat16),
+        rows((4, 8192, 8, 128), jnp.bfloat16), whole((64,), jnp.float32)
+    ).compile()
+    text = compiled.as_text()
+    assert (text.count("tpu_custom_call") >= 2) == given_the_mesh
+    assert "all-gather" not in text
+    assert "all-reduce" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        1e9 if given_the_mesh else 4e9)
+
+
+@pytest.mark.parametrize("given_the_mesh", [True, False],
+                         ids=["given_the_mesh", "given_no_mesh"])
+def test_a_mamba_layer_compiles_for_four_chips_with_the_batch_over_dp(
+        v5e, monkeypatch, given_the_mesh):
+    """The model hands its mesh to the scan: an ``M`` layer whose
+    shapes the kernels tile, loss and gradients, the batch over ``dp``
+    on the described 2x2. The trace's ``ssm_path`` note says which scan
+    each program got."""
+    from ray_tpu.models.nemotron_h import (
+        NemotronH, NemotronHConfig, nemotron_h_loss_fn,
+    )
+    from ray_tpu.util import tracing
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh, rows, whole = _dp(v5e)
+    cfg = NemotronHConfig.tiny(
+        pattern="M", seq_len=256, mamba_heads=8, mamba_head_dim=16,
+        ssm_state=128, ssm_groups=1, chunk=128)
+    model = NemotronH(cfg, mesh=mesh if given_the_mesh else None)
+    params = jax.tree.map(
+        lambda z: whole(z.shape, z.dtype),
+        jax.eval_shape(NemotronH(cfg).init_params, jax.random.key(0)))
+    batch = {k: rows((4, cfg.seq_len), jnp.int32)
+             for k in ("tokens", "targets")}
+    loss = nemotron_h_loss_fn(model, ce_chunk=64)
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    text = jax.jit(jax.grad(lambda p, b: loss(p, b)[0])).lower(
+        params, batch).compile().as_text()
+    want = "pallas_chunked" if given_the_mesh else "chunked_xla"
+    assert notes["ssm_path"] == want
+    assert (text.count("tpu_custom_call") >= 2) == given_the_mesh
+
+
+def test_layers_at_one_shape_trace_each_scan_kernel_once(monkeypatch):
+    """What holds ``setup_s`` (PERF.md section 6, PR 28: a kernel's
+    price is per ``pallas_call`` equation): the two functions that hold
+    the ``pallas_call``s are jitted, so two layers of one step trace
+    each kernel's body once and lower to one function a kernel, called
+    twice; a second trace of the step finds the first one's.
+    Interpreted, on the CPU: counting traces needs nothing of the
+    chip."""
+    from ray_tpu.ops.pallas import ssd_scan
+    bodies = []
+    sums = ssd_scan._running_sums       # each kernel's body, once
+    monkeypatch.setattr(
+        ssd_scan, "_running_sums",
+        lambda *a: bodies.append(1) or sums(*a))
+    x = jax.ShapeDtypeStruct((1, 128, 8, 32), jnp.float32)  # no other test's
+    dt = jax.ShapeDtypeStruct((1, 128, 8), jnp.float32)
+    bc = jax.ShapeDtypeStruct((1, 128, 1, 128), jnp.float32)
+    head = jax.ShapeDtypeStruct((8,), jnp.float32)
+
+    def two_layers(x, dt, a, b, c, d):
+        for i in range(2):
+            with jax.named_scope(f"h_{i}"):
+                x = ssd_scan.ssd_scan(x, dt, a, b, c, d, chunk=128,
+                                      interpret=True)
+        return x.sum()
+
+    text = jax.jit(jax.grad(two_layers, argnums=(0, 1))).lower(
+        x, dt, head, bc, bc, head).as_text()
+    assert text.count("call @_ssd_fwd") == 2
+    assert text.count("call @_ssd_bwd") == 2
+    assert len(bodies) == 2             # forward's and backward's
+
+    def again(*args):                   # a new function: a new trace
+        return two_layers(*args) * 2.0
+
+    jax.jit(jax.grad(again, argnums=(0, 1))).lower(x, dt, head, bc, bc, head)
+    assert len(bodies) == 2
