@@ -7,11 +7,17 @@ experts are ``ops/moe.py::routed_ffn`` (dropless, top-k).
 over ``M`` (Mamba-2, ``ops/ssm.py``), ``E`` (sigmoid-routed relu^2
 experts through the same ``routed_ffn``, of which a share may be held,
 plus a shared expert) and ``*`` (grouped-query attention); its config
-expresses NVIDIA-Nemotron-3-Nano-30B-A3B. ``MoETransformer`` is the older top-1, capacity-dropping switch model
+expresses NVIDIA-Nemotron-3-Nano-30B-A3B. ``JoyAI`` is the
+DeepSeek-V3-shaped decoder (latent attention through ``ops/mla.py``, a
+leading dense layer, sigmoid-routed SwiGLU experts with a shared one
+through ``routed_ffn``, a multi-token-prediction module and its second
+loss); its config expresses JoyAI-LLM-Flash. ``MoETransformer`` is the
+older top-1, capacity-dropping switch model
 on GPT-2 blocks, which goes when the dropless path runs under ``ep``
 (ROADMAP C5)."""
 
 from ray_tpu.models.gpt2 import GPT2, GPT2Config
+from ray_tpu.models.joyai import JoyAI, JoyAIConfig
 from ray_tpu.models.llama import Llama, LlamaConfig
 from ray_tpu.models.moe import MoEConfig, MoETransformer
 from ray_tpu.models.nemotron_h import NemotronH, NemotronHConfig
@@ -19,7 +25,7 @@ from ray_tpu.models.resnet import ResNet, ResNet50Config
 from ray_tpu.models.vit import ViT, ViTConfig
 
 __all__ = [
-    "GPT2", "GPT2Config", "Llama", "LlamaConfig",
+    "GPT2", "GPT2Config", "JoyAI", "JoyAIConfig", "Llama", "LlamaConfig",
     "MoETransformer", "MoEConfig", "NemotronH", "NemotronHConfig",
     "ResNet", "ResNet50Config", "ViT", "ViTConfig",
 ]

@@ -1,0 +1,416 @@
+"""JoyAI-LLM-Flash: the DeepSeek-V3-shaped decoder in flax, designed for
+mesh sharding.
+
+The public model it expresses is **JoyAI-LLM-Flash** (jdopensource,
+"48B-A2.7B": 40 layers at a hidden size of 2,048), whose ``config.json``
+carries the keys of the DeepSeek-V3 architecture (arXiv:2412.19437):
+
+- every block is ``x = x + MLA(RMSNorm(x))``; ``x = x + MLP_l(RMSNorm(x))``;
+- **MLA**, multi-head latent attention (``ops/mla.py``): queries through
+  a 1,536-wide normed latent, keys and values through a 512-wide one,
+  32 heads of 128 + 64 (the 64 rotary, the key's rotary part one head
+  that all share) against values of 128;
+- the first ``dense_layers`` blocks (1) have a dense SwiGLU MLP
+  (``models/llama.py::SwiGLU``); the others the routed layer
+  (``ops/moe.py::routed_ffn``: the float32 sigmoid router over 256
+  experts with its selection bias, top-8 renormalised and scaled, SwiGLU
+  experts, of which this model may hold a share, ``experts_held``) plus
+  a shared SwiGLU expert on every token;
+- a final RMSNorm and an untied head;
+- ``mtp_depth`` (1) **multi-token-prediction modules** (that paper's
+  section 2.2): with ``x_L[i]`` the last block's output at position
+  ``i`` before the final norm, ``u_i = W_eh [RMSNorm(Emb(t_{i+1})) ;
+  RMSNorm(x_L[i])]``, one more block of the routed kind over ``u``, a
+  norm of its own, and the main model's embedding and head, predicting
+  ``t_{i+2}``; the loss is ``L_main + mtp_weight * L_mtp``.
+
+It is the benchmark's fourth language model
+(``joyai-llm-flash.b1-t8192`` runs the dense layer, four routed layers
+and the MTP module with one chip's share of the experts and of the
+vocabulary). ``RMSNorm``, ``apply_rope``, ``rope_freqs`` and ``SwiGLU``
+are ``models/llama.py``'s, the routed layer ``ops/moe.py``'s, the loss
+``models/gpt2.py::chunked_cross_entropy``, twice a step.
+
+Program scopes (docs/observability.md): ``embed``; ``blocks`` with
+``h_i/attn`` (``q_down``, ``q_up``, ``kv_down``, ``kv_up``, ``rope``,
+``core``, ``out_proj`` beneath) and ``h_i/mlp`` (a routed one:
+``router``, ``dispatch``, ``experts``, ``combine``, ``shared``), and the
+MTP module's ``mtp/proj`` and ``mtp/h`` (its ``attn`` and ``mlp``);
+``loss``, with the MTP module's norm, head and cross-entropy under
+``loss/mtp``.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from functools import partial
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.llama import RMSNorm, SwiGLU, rope_freqs
+from ray_tpu.models.nemotron_h import _Router   # gate: [d, E] and its bias
+from ray_tpu.ops.mla import UpProjections, latent_attention
+from ray_tpu.ops.moe import held_route_share, routed_ffn
+from ray_tpu.util import tracing
+
+
+@dataclass(frozen=True)
+class JoyAIConfig:
+    """The keys of a ``joyai_llm_flash`` (DeepSeek-V3-shaped)
+    ``config.json`` under this repo's names; the defaults are
+    JoyAI-LLM-Flash's."""
+    vocab_size: int = 129280
+    n_layer: int = 40                   # num_hidden_layers
+    n_embd: int = 2048
+    seq_len: int = 8192
+    rms_eps: float = 1e-6
+    # MLA
+    n_head: int = 32
+    q_rank: int = 1536                  # q_lora_rank
+    kv_rank: int = 512                  # kv_lora_rank
+    nope_dim: int = 128                 # qk_nope_head_dim
+    rope_dim: int = 64                  # qk_rope_head_dim
+    v_dim: int = 128                    # v_head_dim
+    rope_theta: float = 32_000_000.0
+    # the MLPs
+    dense_layers: int = 1               # first_k_dense_replace
+    dense_width: int = 7168             # intermediate_size
+    num_experts: int = 256              # the router's width
+    top_k: int = 8
+    expert_width: int = 768             # moe_intermediate_size
+    shared_width: int = 768             # n_shared_experts x that
+    norm_topk_prob: bool = True
+    route_scale: float = 2.5
+    # (first, count) of the experts this model holds, as one chip of an
+    # expert-parallel deployment does; None: all of them
+    experts_held: tuple[int, int] | None = None
+    # multi-token prediction
+    mtp_depth: int = 1                  # num_nextn_predict_layers
+    mtp_weight: float = 0.3
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @staticmethod
+    def joyai_llm_flash(**kw) -> "JoyAIConfig":
+        """jdopensource/JoyAI-LLM-Flash ``config.json``: 2.7B active of
+        48B parameters."""
+        return JoyAIConfig(**kw)
+
+    @staticmethod
+    def tiny(**kw) -> "JoyAIConfig":
+        """The same shape at test size: a dense layer, two routed ones
+        with 16 experts of which 4 are held, top-3, the MTP module."""
+        base = dict(
+            vocab_size=256, n_layer=3, n_embd=64, seq_len=64, n_head=4,
+            q_rank=48, kv_rank=32, nope_dim=16, rope_dim=8, v_dim=16,
+            rope_theta=10000.0, dense_width=160, num_experts=16, top_k=3,
+            expert_width=32, shared_width=32, experts_held=(4, 4))
+        return JoyAIConfig(**{**base, **kw})
+
+    def __post_init__(self):
+        if self.mtp_depth not in (0, 1):
+            raise NotImplementedError(
+                f"mtp_depth={self.mtp_depth}: one multi-token-prediction "
+                "module or none")
+        if not 0 <= self.dense_layers <= self.n_layer:
+            raise ValueError(f"{self.dense_layers} dense layers of "
+                             f"{self.n_layer}")
+
+    @property
+    def experts_span(self) -> tuple[int, int]:
+        """(first, count) of the experts held; all of them by default."""
+        return self.experts_held or (0, self.num_experts)
+
+    @property
+    def held(self) -> int:
+        return self.experts_span[1]
+
+    def layer_params(self) -> dict:
+        """Parameters of each part: ``mla`` (the five projections and
+        the two latent norms), a ``dense`` and a ``routed`` block (MLA,
+        the MLP and the block's two norms; the router's bias counts),
+        the ``mtp`` module (a routed block, ``W_eh`` and three norms)."""
+        d, h = self.n_embd, self.n_head
+        mla = (d * self.q_rank + self.q_rank
+               + self.q_rank * h * (self.nope_dim + self.rope_dim)
+               + d * (self.kv_rank + self.rope_dim) + self.kv_rank
+               + self.kv_rank * h * (self.nope_dim + self.v_dim)
+               + h * self.v_dim * d)
+        routed = (mla + 2 * d + d * self.num_experts + self.num_experts
+                  + 3 * d * self.shared_width
+                  + self.held * 3 * d * self.expert_width)
+        return {"mla": mla, "dense": mla + 2 * d + 3 * d * self.dense_width,
+                "routed": routed, "mtp": routed + 2 * d * d + 3 * d}
+
+    def num_params(self) -> int:
+        per = self.layer_params()
+        return (self.dense_layers * per["dense"]
+                + (self.n_layer - self.dense_layers) * per["routed"]
+                + self.mtp_depth * per["mtp"]
+                + 2 * self.vocab_size * self.n_embd + self.n_embd)
+
+
+@dataclass(frozen=True)
+class _MLPWidths:
+    """What ``models/llama.py::SwiGLU`` reads of a config."""
+    n_embd: int
+    intermediate: int
+    dtype: Any
+    param_dtype: Any
+
+
+def _swiglu(cfg: JoyAIConfig, width: int, name: str):
+    return SwiGLU(_MLPWidths(cfg.n_embd, width, cfg.dtype, cfg.param_dtype),
+                  name=name)
+
+
+def _dense(cfg: JoyAIConfig):
+    return partial(nn.Dense, use_bias=False, dtype=cfg.dtype,
+                   param_dtype=cfg.param_dtype,
+                   kernel_init=nn.initializers.normal(0.02))
+
+
+def _norm(cfg: JoyAIConfig):
+    return partial(RMSNorm, eps=cfg.rms_eps, dtype=cfg.dtype,
+                   param_dtype=cfg.param_dtype)
+
+
+class _Down(nn.Module):
+    """A down projection: ``proj`` to ``rank + extra`` and the RMSNorm
+    of the first ``rank`` (the latent); the ``extra`` columns (the
+    shared rotary key) pass unnormed."""
+    config: JoyAIConfig
+    rank: int
+    extra: int = 0
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.config
+        z = _dense(cfg)(self.rank + self.extra, name="proj")(h)
+        c = _norm(cfg)(name="norm")(z[..., :self.rank])
+        return c, z[..., self.rank:]
+
+
+class _Up(nn.Module):
+    """An up projection's arrays, by part (``ops/mla.py``): ``widths``
+    maps a part's name to its columns."""
+    config: JoyAIConfig
+    rank: int
+    widths: tuple
+
+    @nn.compact
+    def __call__(self):
+        cfg = self.config
+        return tuple(
+            self.param(name, nn.initializers.normal(0.02),
+                       (self.rank, cfg.n_head * width), cfg.param_dtype)
+            for name, width in self.widths)
+
+
+class LatentAttention(nn.Module):
+    """MLA (``ops/mla.py`` has the equations and the choices)."""
+    config: JoyAIConfig
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, h, angles):
+        cfg = self.config
+        c_q, _ = _Down(cfg, cfg.q_rank, name="q_down")(h)
+        c_kv, k_r = _Down(cfg, cfg.kv_rank, cfg.rope_dim, name="kv_down")(h)
+        q_nope, q_rope = _Up(cfg, cfg.q_rank, (("nope", cfg.nope_dim),
+                                               ("rope", cfg.rope_dim)),
+                             name="q_up")()
+        k_nope, v = _Up(cfg, cfg.kv_rank, (("k", cfg.nope_dim),
+                                           ("v", cfg.v_dim)),
+                        name="kv_up")()
+        o = latent_attention(
+            c_q, c_kv, k_r, UpProjections(q_nope, q_rope, k_nope, v), angles,
+            n_head=cfg.n_head, mesh=self.mesh)
+        return _dense(cfg)(cfg.n_embd, name="out_proj")(o)
+
+
+class _Experts(nn.Module):
+    """The stacked weights of the experts held: ``gate_proj`` and
+    ``up_proj`` [held, d, f], ``down_proj`` [held, f, d]."""
+    config: JoyAIConfig
+
+    @nn.compact
+    def __call__(self):
+        cfg = self.config
+        e, d, f = cfg.held, cfg.n_embd, cfg.expert_width
+        init = nn.initializers.normal(0.02)
+        return (self.param("gate_proj", init, (e, d, f), cfg.param_dtype),
+                self.param("up_proj", init, (e, d, f), cfg.param_dtype),
+                self.param("down_proj", init, (e, f, d), cfg.param_dtype))
+
+
+class MoE(nn.Module):
+    """The held experts' part of the routed sum, plus the shared
+    expert. Sows the routes each expert received."""
+    config: JoyAIConfig
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        router_w, select_bias = _Router(cfg, name="gate")()
+        y, _, _, load = routed_ffn(
+            x, router_w, *_Experts(cfg, name="experts")(), top_k=cfg.top_k,
+            norm_topk_prob=cfg.norm_topk_prob, mesh=self.mesh,
+            router="sigmoid", select_bias=select_bias,
+            route_scale=cfg.route_scale, expert="swiglu",
+            experts_held=cfg.experts_held)
+        self.sow("moe", "load", load)
+        return y + _swiglu(cfg, cfg.shared_width, "shared")(x)
+
+
+class Block(nn.Module):
+    """Attention, then the MLP of the block's kind (``routed`` or the
+    dense SwiGLU), each on the normed stream and added to it."""
+    config: JoyAIConfig
+    routed: bool
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, x, angles):
+        cfg = self.config
+        x = x + LatentAttention(cfg, self.mesh, name="attn")(
+            _norm(cfg)(name="attn_norm")(x), angles)
+        mlp = (MoE(cfg, self.mesh, name="mlp") if self.routed
+               else _swiglu(cfg, cfg.dense_width, "mlp"))
+        return x + mlp(_norm(cfg)(name="mlp_norm")(x))
+
+
+class MTP(nn.Module):
+    """One multi-token-prediction module up to its block's output:
+    ``proj`` (the two norms and ``W_eh`` over [embedding ; stream]) and
+    ``h``, a block of the routed kind."""
+    config: JoyAIConfig
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, x, next_emb, angles):
+        cfg = self.config
+        with jax.named_scope("proj"):
+            u = _dense(cfg)(cfg.n_embd, name="eh_proj")(jnp.concatenate(
+                [_norm(cfg)(name="enorm")(next_emb),
+                 _norm(cfg)(name="hnorm")(x)], axis=-1))
+        return Block(cfg, True, self.mesh, name="h")(u, angles)
+
+
+class JoyAI(nn.Module):
+    """``__call__(tokens, next_tokens) -> (logits, logits_mtp)`` (or the
+    two normed hidden states). ``next_tokens[i]`` is ``t_{i+1}``, what
+    the MTP module embeds beside position ``i``'s stream; by default
+    ``tokens`` rolled by one. Without an MTP module the second of each
+    pair is None."""
+
+    config: JoyAIConfig
+    mesh: Any = None
+
+    def _constrain(self, x):
+        if self.mesh is None:
+            return x
+        from ray_tpu.parallel.sharding import constrain
+        return constrain(x, self.mesh, "batch", "seq", None)
+
+    @nn.compact
+    def __call__(self, tokens, next_tokens=None, return_hidden: bool = False):
+        cfg = self.config
+        tracing.note_trace(
+            attn_kind="mla", mla_ranks=[cfg.q_rank, cfg.kv_rank],
+            mla_qk_dims=[cfg.nope_dim, cfg.rope_dim], mla_v_dim=cfg.v_dim,
+            mtp_depth=cfg.mtp_depth, mtp_weight=cfg.mtp_weight,
+            dense_layers=cfg.dense_layers)
+        wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
+                       dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                       embedding_init=nn.initializers.normal(0.02))
+        with jax.named_scope("embed"):
+            x = self._constrain(wte(tokens))
+        angles = rope_freqs(cfg.rope_dim, cfg.seq_len, cfg.rope_theta)
+        h_mtp = None
+        with jax.named_scope("blocks"):
+            for i in range(cfg.n_layer):
+                x = Block(cfg, i >= cfg.dense_layers, self.mesh,
+                          name=f"h_{i}")(x, angles)
+                x = self._constrain(x)
+            h = _norm(cfg)(name="norm_f")(x)
+            if cfg.mtp_depth:
+                if next_tokens is None:
+                    next_tokens = jnp.roll(tokens, -1, axis=1)
+                with jax.named_scope("mtp"), jax.named_scope("proj"):
+                    next_emb = wte(next_tokens)
+                h_mtp = self._constrain(MTP(cfg, self.mesh, name="mtp")(
+                    x, next_emb, angles))
+        head = nn.Dense(cfg.vocab_size, use_bias=False, name="lm_head",
+                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                        kernel_init=nn.initializers.normal(0.02))
+        with jax.named_scope("loss"):
+            if cfg.mtp_depth:
+                with jax.named_scope("mtp"):
+                    h_mtp = _norm(cfg)(name="mtp_norm")(h_mtp)
+            if return_hidden:   # lm_head's parameters come from init's call
+                return h, h_mtp
+            logits = head(h).astype(jnp.float32)
+            with jax.named_scope("mtp"):
+                logits_mtp = (head(h_mtp).astype(jnp.float32)
+                              if cfg.mtp_depth else None)
+        return logits, logits_mtp
+
+    def init_params(self, rng, batch_size: int = 2):
+        tokens = jnp.zeros((batch_size, self.config.seq_len), jnp.int32)
+        return self.init(rng, tokens)["params"]
+
+
+def mtp_targets(targets):
+    """The MTP module's targets from the main ones: position ``i``
+    predicts ``t_{i+2}`` = ``targets[i + 1]``; the last position of a
+    row has none and is masked (-1)."""
+    return jnp.concatenate(
+        [targets[:, 1:], jnp.full_like(targets[:, :1], -1)], axis=1)
+
+
+def joyai_loss_fn(model: JoyAI, ce_chunk: int = 2048):
+    """(params, batch) -> ``(loss, report)``; batch = {tokens, targets}.
+
+    ``loss = lm_loss + mtp_weight * mtp_loss``: the next-token
+    cross-entropy of the main head, and the MTP module's, the mean over
+    the ``T - 1`` positions of a row that have a second-next token, both
+    chunked against the one untied head. The report, which
+    ``train/step.py`` puts beside the loss: ``lm_loss``, ``mtp_loss``;
+    ``moe_held_route_share``, of all the routes of all routed layers
+    (the MTP module's among them) the share that landed on the experts
+    held, ``moe_absent_route_share``, the rest, and
+    ``moe_load_max_over_mean``, the largest expert's routes over the
+    mean in the worst layer."""
+    from ray_tpu.models.gpt2 import chunked_cross_entropy
+    cfg = model.config
+
+    def loss_fn(params, batch):
+        (h, h_mtp), sown = model.apply(
+            {"params": params}, batch["tokens"], batch["targets"],
+            return_hidden=True, mutable=["moe"])
+        head = params["lm_head"]["kernel"].T
+        ce = partial(chunked_cross_entropy, chunk_size=ce_chunk,
+                     mesh=model.mesh)
+        loss = lm = ce(h, head, batch["targets"])
+        report = {"lm_loss": lm}
+        if cfg.mtp_depth:
+            with jax.named_scope("loss"), jax.named_scope("mtp"):
+                mtp = ce(h_mtp, head, mtp_targets(batch["targets"]))
+                loss = lm + cfg.mtp_weight * mtp
+            report["mtp_loss"] = mtp
+        if "moe" in sown:
+            load = jnp.stack(jax.tree_util.tree_leaves(sown["moe"]))
+            share = held_route_share(load, cfg.experts_span)
+            report.update(
+                moe_held_route_share=share,
+                moe_absent_route_share=1.0 - share,
+                moe_load_max_over_mean=jnp.max(
+                    load.max(axis=-1) / load.mean(axis=-1)))
+        return loss, report
+
+    return loss_fn

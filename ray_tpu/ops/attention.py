@@ -6,7 +6,11 @@
   projections wrote them ([B, T, H, D] is [B, T, H*D] for free; its
   grid runs over the batch and 128-lane blocks of H*D) and writes
   the output projection's operand: no copy on either side. Otherwise
-  ``jax.nn.dot_product_attention`` (XLA fused path).
+  ``jax.nn.dot_product_attention`` (XLA fused path). It serves q, k and
+  v of one shape; the kernels' other shape, latent attention's keys of
+  128 + 64 against values of 128 with the rotary key shared by the
+  heads, is ``ops/mla.py::latent_attention``'s, which has its own
+  dispatch (``mla_path``) to the kernels of the same file.
 - ``ring_attention``: sequence-parallel causal attention over an ICI
   ring. The reference has NO sequence parallelism in-tree (SURVEY.md
   §5.7); here it is first-class: K/V blocks rotate around the ``sp``
@@ -30,6 +34,8 @@ _NEG_INF = -1e30
 
 
 def _flash_ok(q, k, v) -> bool:
+    """This function's kernel shape: three equal [B, T, H, D]. (Unequal
+    widths have a kernel too, reached through ``ops/mla.py``.)"""
     return (q.shape == k.shape == v.shape
             and flash_eligible(q.shape[1], q.shape[-1]))
 
@@ -50,8 +56,15 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      force_flash: bool = False) -> jax.Array:
     """Causal attention [B, T, H, D] -> [B, T, H, D].
 
-    Single-device TPU with cleanly-blocking shapes runs the Pallas
-    flash kernel (ops/pallas/flash_attention.py). Multi-device
+    Single-device TPU with cleanly-blocking shapes, q, k and v of one
+    shape, runs the Pallas flash kernel
+    (ops/pallas/flash_attention.py). Operands of unequal shapes (fewer
+    key/value heads, keys wider than values) take the XLA path here and
+    say so in the trace's notes (``flash_path`` = ``"xla"``,
+    ``flash_layout`` = ``"unequal_shapes"``): the kernel for latent
+    attention's 192-wide keys and 128-wide values is reached through
+    ``ops/mla.py::latent_attention``, not through this function.
+    Multi-device
     programs must NOT hit the bare kernel (pallas_call has no SPMD
     partitioning rule): use make_sharded_causal_attention, which
     shard_maps over the mesh and sets ``force_flash`` for the
@@ -66,6 +79,9 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if _flash_ok(q, k, v) and (force_flash or jax.device_count() == 1):
         from ray_tpu.ops.pallas.flash_attention import flash_attention
         return flash_attention(q, k, v, causal=True, scale=scale)
+    if not q.shape == k.shape == v.shape:
+        from ray_tpu.util import tracing
+        tracing.note_trace(flash_path="xla", flash_layout="unequal_shapes")
     return jax.nn.dot_product_attention(q, k, v, scale=scale,
                                         is_causal=True)
 

@@ -1,0 +1,226 @@
+"""Multi-head latent attention (MLA) for training.
+
+DeepSeek-V2/V3's attention, which JoyAI-LLM-Flash carries: queries and
+keys/values are projected **down** to a narrow latent, normed, and
+projected **up** again to the heads; a small rotary part is kept apart
+from the latent ("decoupled RoPE"), and the key's rotary part is one
+head that every query head shares. With ``h`` the normed block input:
+
+    c_q          = RMSNorm(h W_qa)                  [rq]     ``q_down``
+    q_nope|q_rope = c_q W_qb, per head [dn | dr]             ``q_up``
+    c_kv | k_r   = h W_kva, c_kv = RMSNorm(c_kv)    [rkv | dr] ``kv_down``
+    k_nope | v   = c_kv W_kvb, per head [dn | dv]            ``kv_up``
+    q_rope, k_r  take RoPE over interleaved pairs            ``rope``
+    s_i = (q_nope_i k_nope_i^T + q_rope_i k_r^T) / sqrt(dn + dr)
+    o_i = causal_softmax(s_i) v_i                            ``core``
+    y   = concat_i(o_i) W_o                                  ``out_proj``
+
+The down projections and the output projection are the model's own
+dense layers (``models/joyai.py``); this file holds everything between
+the latents and ``concat_i(o_i)``: ``latent_attention``.
+
+**The up-projections' columns.** ``W_qb`` and ``W_kvb`` are held as two
+arrays each, the heads' 128-wide parts side by side in one (``[rq,
+H*dn]``, ``[rkv, H*dn]``, ``[rkv, H*dv]``) and the heads' rotary parts
+in the other (``[rq, H*dr]``): a permutation of the published matrices'
+columns. Each matmul then writes ``[B, T, H*128]`` with one head a
+128-lane block, which is what the kernel indexes
+(``ops/pallas/flash_attention.py``'s last section); nothing is sliced,
+concatenated or transposed between a projection and the kernel.
+
+**What is kept for the backward pass** (``saved``): ``"expanded"``
+keeps what the kernel read, as any attention does: ``q``, ``k_nope``,
+``v`` and the rotated parts, ``H*(dn + dr) + H*(dn + dv) + dr`` values a
+token (14,400 at the published widths) beside the output. ``"latents"``
+keeps ``c_q``, ``c_kv`` and ``k_r`` (``rq + rkv + dr`` = 2,112) and the
+backward pass runs the up-projections and the rotation again before
+the kernels' backward: 2 x 13.6 M more operations a token a layer, a
+sixth of the projections' own, for 24.6 KB a token a layer (201 MB a
+layer at 8,192 tokens; PERF.md section 6, PR 34). The kernel's own
+forward is not run again: its output and log-sum-exp are kept.
+
+**Which attention runs** (``mla_path``; the trace's notes say:
+``flash_path``). On a TPU where the shapes tile, the Pallas kernels:
+bare in a one-device program, under a ``shard_map`` over the batch on a
+mesh whose only real axes are ``dp`` / ``fsdp``. ``sp``, ``tp`` and
+``ep`` are refused by name here, before a routed layer is built (which refuses
+``ep`` and ``tp`` itself). Elsewhere (the CPU; shapes that do not
+tile; a batch the mesh does not divide, as at init) a masked softmax
+over the concatenated keys in XLA, ``flash_path`` = ``"xla"``: at 8,192
+tokens its float32 scores are 8.6 GB a layer, so a benchmark refuses a
+cell whose note says so.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.util import tracing
+
+
+class UpProjections(NamedTuple):
+    """``W_qb`` and ``W_kvb``, columns grouped by part (see above)."""
+    q_nope: jax.Array       # [rq, H*dn]
+    q_rope: jax.Array       # [rq, H*dr]
+    k_nope: jax.Array       # [rkv, H*dn]
+    v: jax.Array            # [rkv, H*dv]
+
+
+def mla_path(batch: int, t: int, n_head: int, dn: int, dr: int, dv: int,
+             mesh=None, interpret: bool = False) -> tuple[str, tuple]:
+    """(``"kernel"`` or ``"xla"``, the mesh axes the batch is mapped
+    over). Decided from the backend, the shapes and the mesh, as
+    ``ops/attention.py`` decides for equal widths; raises where a TPU
+    program with shapes that tile cannot reach the kernel."""
+    from ray_tpu.ops.pallas.flash_attention import mla_flash_shapes_ok
+    from ray_tpu.parallel.mesh import (
+        AXIS_DP, AXIS_EP, AXIS_FSDP, AXIS_SP, AXIS_TP)
+
+    if mesh is not None:
+        for axis, what in ((AXIS_SP, "the sequence split over chips (keys "
+                            "passed round a ring)"),
+                           (AXIS_TP, "the heads split over chips"),
+                           (AXIS_EP, "attention operands replicated over an "
+                            "expert axis")):
+            if mesh.shape.get(axis, 1) > 1:
+                raise NotImplementedError(
+                    f"latent attention on a mesh with {axis}="
+                    f"{mesh.shape[axis]}: {what} is not implemented for "
+                    "it; dp and fsdp shard the batch and need nothing")
+    if not interpret and (
+            jax.default_backend() != "tpu"
+            or not mla_flash_shapes_ok(t, dn, dr, dv, n_head)):
+        return "xla", ()
+    if mesh is None or mesh.size == 1:
+        if mesh is None and not interpret and jax.device_count() != 1:
+            raise NotImplementedError(
+                "latent attention in a process of "
+                f"{jax.device_count()} devices and no mesh: a bare Pallas "
+                "kernel cannot be partitioned; give the model its mesh")
+        return "kernel", ()
+    axes = tuple(a for a in (AXIS_DP, AXIS_FSDP) if mesh.shape.get(a, 1) > 1)
+    if batch % math.prod(mesh.shape[a] for a in axes):
+        return "xla", ()        # e.g. the tiny batch of init-time tracing
+    return "kernel", axes
+
+
+def _xla_core(qn, qr, kn, kr, v, n_head: int):
+    """The same attention as one masked softmax over the concatenated
+    keys, float32 scores: the path of the CPU and of shapes that do not
+    tile."""
+    b, t, _ = qn.shape
+    q = jnp.concatenate([qn.reshape(b, t, n_head, -1),
+                         qr.reshape(b, t, n_head, -1)], -1)
+    k = jnp.concatenate([
+        kn.reshape(b, t, n_head, -1),
+        jnp.broadcast_to(kr[:, :, None], (b, t, n_head, kr.shape[-1]))], -1)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1).astype(v.dtype),
+                   v.reshape(b, t, n_head, -1),
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b, t, -1).astype(v.dtype)
+
+
+def _over_batch(fn, mesh, axes, n_in: int):
+    """``fn`` on each chip's rows of the batch (every operand and
+    result carries the batch first); ``fn`` itself where no axis maps."""
+    if not axes:
+        return fn
+    from jax.sharding import PartitionSpec as P
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(axes),) * n_in,
+                         out_specs=P(axes), check_vma=False)
+
+
+def latent_attention(c_q, c_kv, k_r, up: UpProjections, angles, *,
+                     n_head: int, saved: str = "latents", mesh=None,
+                     interpret: bool = False):
+    """``concat_i(o_i)``, [B, T, H*dv], from the normed latents ``c_q``
+    [B, T, rq] and ``c_kv`` [B, T, rkv], the unrotated shared key
+    ``k_r`` [B, T, dr], the up-projections and the rotation's
+    ``angles`` [T, dr/2] (``models/llama.py::rope_freqs``).
+
+    ``saved``: what the backward pass keeps (module docstring). The
+    model takes the default; ``"expanded"`` is what the tests hold its
+    gradients against, and the note ``mla_saved`` says which ran.
+    ``interpret`` runs the kernels in interpret mode wherever this is
+    (tests). Opens the scopes ``q_up``, ``kv_up``, ``rope`` and ``core``
+    under its caller's, in both passes."""
+    from ray_tpu.models.llama import apply_rope
+    from ray_tpu.ops.pallas.flash_attention import (
+        mla_flash_bwd, mla_flash_core, mla_flash_fwd, mla_flash_static)
+
+    if saved not in ("latents", "expanded"):
+        raise ValueError(f"saved={saved!r}: 'latents' or 'expanded'")
+    tracing.note_trace(mla_saved=saved)
+    b, t, _ = c_q.shape
+    dt = c_q.dtype
+    dr = k_r.shape[-1]
+    dn, dv = up.q_nope.shape[-1] // n_head, up.v.shape[-1] // n_head
+    path, axes = mla_path(b, t, n_head, dn, dr, dv, mesh, interpret)
+
+    def expand(c_q, c_kv, k_r, *up):
+        """The kernel's five operands from the latents."""
+        q_nope_w, q_rope_w, k_nope_w, v_w = up
+        with jax.named_scope("q_up"):
+            qn, qr = c_q @ q_nope_w.astype(dt), c_q @ q_rope_w.astype(dt)
+        with jax.named_scope("kv_up"):
+            kn, v = c_kv @ k_nope_w.astype(dt), c_kv @ v_w.astype(dt)
+        with jax.named_scope("rope"):
+            # in float32: apply_rope rounds the cosines to its operand's
+            # type, and a rotation by bf16 cosines is another function,
+            # not a rounding of this one (the gradient norm's distance
+            # from the float32 reference halves; PERF.md 6, PR 34)
+            f32 = jnp.float32
+            qr = apply_rope(qr.reshape(b, t, n_head, dr).astype(f32),
+                            angles[:t]).reshape(b, t, n_head * dr).astype(dt)
+            kr = apply_rope(k_r[:, :, None].astype(f32),
+                            angles[:t])[:, :, 0].astype(dt)
+        return qn, qr, kn, kr, v
+
+    operands = (c_q, c_kv, k_r, *up)
+    if path == "xla":
+        tracing.note_trace(flash_path="xla", flash_layout="concatenated")
+
+        def attend(*operands):
+            qkv = expand(*operands)
+            with jax.named_scope("core"):
+                return _xla_core(*qkv, n_head)
+        if saved == "latents":
+            attend = jax.checkpoint(attend)
+        return attend(*operands)
+
+    static = mla_flash_static(t, dn, dr, interpret=interpret)
+
+    def kernel(fn, n_in):
+        return jax.named_scope("core")(_over_batch(
+            functools.partial(fn, static=static), mesh, axes, n_in))
+
+    if saved == "expanded":     # the kernels' own custom_vjp keeps them
+        return kernel(mla_flash_core, 5)(*expand(*operands))
+    fwd, bwd = kernel(mla_flash_fwd, 5), kernel(mla_flash_bwd, 8)
+
+    @jax.custom_vjp
+    def attend(*operands):
+        return fwd(*expand(*operands))[0]
+
+    def attend_fwd(*operands):
+        out, lse = fwd(*expand(*operands))
+        return out, (operands, out, lse)
+
+    def attend_bwd(res, g):
+        operands, out, lse = res
+        # as jax.checkpoint does: without the barrier XLA finds the
+        # forward pass's identical matmuls and keeps their results
+        # instead (common subexpressions), and nothing is saved
+        operands, g = jax.lax.optimization_barrier((operands, g))
+        qkv, pull = jax.vjp(expand, *operands)
+        return pull(bwd(*qkv, out, lse, g))
+    attend.defvjp(attend_fwd, attend_bwd)
+    return attend(*operands)

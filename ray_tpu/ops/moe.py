@@ -16,7 +16,12 @@ skew. What runs through it, by argument:
   ``experts_held``: the layer is told which experts it holds (a chip's
   share under expert parallelism), routes over all of them and
   computes the part of the sum that its own give. The shared expert is
-  the model's, a dense MLP beside this layer.
+  the model's, a dense MLP beside this layer;
+- JoyAI-LLM-Flash (``models/joyai.py``, the DeepSeek-V3 architecture):
+  the same sigmoid router over 256 experts, top-8 renormalised and
+  scaled, with **SwiGLU** experts and ``experts_held``: the slabs walk
+  three grouped matmuls an expert (``_slab`` with a ``w_gate``), 16 of
+  256 held in the benchmark's cell, a slab of 8,192 sorted rows.
 
 On a mesh that shards tokens (dp, fsdp, sp) each chip routes and sorts
 its own tokens under ``shard_map`` with the experts replicated; a mesh

@@ -646,3 +646,355 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def flash_attention_shapes_ok(t: int, d: int) -> bool:
     return _pick_block(t) >= 128 and d % 8 == 0
 
+
+
+# ---------------------------------------------------------------------------
+# latent attention: keys of two parts, one of them shared by every head
+# ---------------------------------------------------------------------------
+#
+# Multi-head latent attention (DeepSeek-V2/V3's MLA, JoyAI-LLM-Flash's)
+# as the training pass sees it: head i's query is [q_nope_i | q_rope_i]
+# (dn + dr wide: 128 + 64), its key [k_nope_i | k_r] where the rotary
+# part ``k_r`` is ONE head that all the heads share, its value dn wide.
+# The kernels take the five operands as the projections wrote them:
+#
+#   qn, kn, v   [B, T, H*dn]   one head a 128-lane block, as D=128 above
+#   qr          [B, T, H*dr]   the heads' rotary queries side by side
+#   kr          [B, T, dr]     the shared rotary key, never repeated
+#
+# and add the two score matmuls in the body, before the softmax:
+# ``s = (qn kn^T + qr kr^T) * scale``. No operand is padded to 256 and
+# no [B, T, H*(dn+dr)] key exists anywhere. A grid cell holds ``hpb =
+# 128 // dr`` heads (two), so that its block of ``qr`` is whole 128-lane
+# tiles; ``kr``'s block is the array's full 64 lanes. The grids are the
+# multi-block ones above at bq == bk, with two differences: the block
+# that straddles the diagonal is the only one that pays for a mask, and
+# the index maps stop at the diagonal, so a skipped cell moves nothing.
+# ``dk_r`` is the sum over every head: the dk/dv kernel walks the head
+# pairs as its third, sequential, grid dimension and carries the sum in
+# VMEM scratch across them, so it is written once a key block.
+# Statistics and outputs are laid out as above.
+
+def _mla_scores(qn, qr, kn, kr, scale, on_diagonal: bool):
+    """[bq, bk] scaled scores of one head: the two parts' products
+    summed; on the diagonal block (bq == bk) the causal mask."""
+    dims = (((1,), (1,)), ((), ()))
+    s = (jax.lax.dot_general(qn, kn, dims,
+                             preferred_element_type=jnp.float32)
+         + jax.lax.dot_general(qr, kr, dims,
+                               preferred_element_type=jnp.float32)) * scale
+    if on_diagonal:
+        below = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                 >= jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+        s = jnp.where(below, s, _NEG_INF)
+    return s
+
+
+def _mla_parts(dn, dr, hpb):
+    """(lanes of the nope / value block, lanes of the rope block) for
+    each head of a grid cell."""
+    return [(slice(j * dn, (j + 1) * dn), slice(j * dr, (j + 1) * dr))
+            for j in range(hpb)]
+
+
+def _on_live_blocks(iq, ik, body):
+    """``body(on_diagonal)`` for the blocks at or below the diagonal."""
+    pl.when(ik < iq)(functools.partial(body, False))
+    pl.when(ik == iq)(functools.partial(body, True))
+
+
+def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                    acc_ref, m_ref, l_ref, *, scale, nk, dn, dr, hpb):
+    iq = pl.program_id(2)
+    ik = pl.program_id(3)
+
+    @pl.when(ik == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def attend(on_diagonal):
+        kr = kr_ref[0]
+        for j, (n, r) in enumerate(_mla_parts(dn, dr, hpb)):
+            v = v_ref[0, :, n]
+            s = _mla_scores(qn_ref[0, :, n], qr_ref[0, :, r],
+                            kn_ref[0, :, n], kr, scale, on_diagonal)
+            m_prev = m_ref[j]                  # [bq, 128] (replicated)
+            m_new = jnp.maximum(m_prev, jnp.broadcast_to(
+                jnp.max(s, axis=-1, keepdims=True), m_prev.shape))
+            corr = jnp.exp(m_prev[:, :1] - m_new[:, :1])    # [bq, 1]
+            p = jnp.exp(s - m_new[:, :1])                   # [bq, bk]
+            l_ref[j] = l_ref[j] * corr + jnp.broadcast_to(
+                jnp.sum(p, axis=-1, keepdims=True), m_prev.shape)
+            acc_ref[:, n] = acc_ref[:, n] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[j] = m_new
+
+    _on_live_blocks(iq, ik, attend)
+
+    @pl.when(ik == nk - 1)
+    def _finalize():
+        for j, (n, _) in enumerate(_mla_parts(dn, dr, hpb)):
+            l = jnp.maximum(l_ref[j][:, :1], 1e-30)
+            o_ref[0, :, n] = (acc_ref[:, n] / l).astype(o_ref.dtype)
+            lse_ref[j:j + 1] = _as_row(
+                m_ref[j][:, :1] + jnp.log(l)).astype(lse_ref.dtype)
+
+
+def _mla_ds(qn_ref, qr_ref, kn_ref, kr, v_ref, do_ref, lse_ref, delta_ref,
+            j, n, r, scale, on_diagonal):
+    """(p, ds) of head ``j`` of the cell, [bq, bk]: the probabilities
+    from the saved log-sum-exp and the scores' cotangent, ds in the
+    operands' type for the matmuls that follow."""
+    s = _mla_scores(qn_ref[0, :, n], qr_ref[0, :, r], kn_ref[0, :, n], kr,
+                    scale, on_diagonal)
+    p = jnp.exp(s - _as_col(lse_ref[j:j + 1]))
+    dov = jax.lax.dot_general(
+        do_ref[0, :, n], v_ref[0, :, n], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dov - _as_col(delta_ref[j:j + 1])) * scale
+    return p, ds.astype(qn_ref.dtype)
+
+
+def _mla_bwd_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
+                       lse_ref, dqn_ref, dqr_ref, delta_ref, accn_ref,
+                       accr_ref, *, scale, nk, dn, dr, hpb):
+    iq = pl.program_id(2)
+    ik = pl.program_id(3)
+
+    @pl.when(ik == 0)
+    def _init():
+        accn_ref[...] = jnp.zeros_like(accn_ref)
+        accr_ref[...] = jnp.zeros_like(accr_ref)
+        for j, (n, _) in enumerate(_mla_parts(dn, dr, hpb)):
+            delta_ref[j:j + 1] = _as_row(_delta(o_ref, do_ref, n))
+
+    def step(on_diagonal):
+        kr = kr_ref[0]
+        for j, (n, r) in enumerate(_mla_parts(dn, dr, hpb)):
+            _, ds = _mla_ds(qn_ref, qr_ref, kn_ref, kr, v_ref, do_ref,
+                            lse_ref, delta_ref, j, n, r, scale, on_diagonal)
+            dims = (((1,), (0,)), ((), ()))
+            accn_ref[:, n] += jax.lax.dot_general(
+                ds, kn_ref[0, :, n], dims,
+                preferred_element_type=jnp.float32)
+            accr_ref[:, r] += jax.lax.dot_general(
+                ds, kr, dims, preferred_element_type=jnp.float32)
+
+    _on_live_blocks(iq, ik, step)
+
+    @pl.when(ik == nk - 1)
+    def _finalize():
+        dqn_ref[0] = accn_ref[...].astype(dqn_ref.dtype)
+        dqr_ref[0] = accr_ref[...].astype(dqr_ref.dtype)
+
+
+def _mla_bwd_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
+                        lse_ref, delta_ref, dkn_ref, dkr_ref, dv_ref,
+                        dkn_acc, dkr_acc, dv_acc, *, scale, ng, nq, dn, dr,
+                        hpb):
+    ik = pl.program_id(1)
+    c = pl.program_id(2)
+    iq = pl.program_id(3)
+
+    @pl.when(iq == 0)
+    def _init():
+        dkn_acc[...] = jnp.zeros_like(dkn_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when((iq == 0) & (c == 0))
+    def _init_shared():
+        dkr_acc[...] = jnp.zeros_like(dkr_acc)
+
+    def step(on_diagonal):
+        kr = kr_ref[0]
+        for j, (n, r) in enumerate(_mla_parts(dn, dr, hpb)):
+            p, ds = _mla_ds(qn_ref, qr_ref, kn_ref, kr, v_ref, do_ref,
+                            lse_ref, delta_ref, j, n, r, scale, on_diagonal)
+            do = do_ref[0, :, n]
+            dims = (((0,), (0,)), ((), ()))
+            dv_acc[:, n] += jax.lax.dot_general(
+                p.astype(do.dtype), do, dims,
+                preferred_element_type=jnp.float32)         # [bk, dn]
+            dkn_acc[:, n] += jax.lax.dot_general(
+                ds, qn_ref[0, :, n], dims,
+                preferred_element_type=jnp.float32)
+            dkr_acc[...] += jax.lax.dot_general(
+                ds, qr_ref[0, :, r], dims,
+                preferred_element_type=jnp.float32)         # [bk, dr]
+
+    _on_live_blocks(iq, ik, step)
+
+    @pl.when(iq == nq - 1)
+    def _finalize():
+        dkn_ref[0] = dkn_acc[...].astype(dkn_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when((iq == nq - 1) & (c == ng - 1))
+    def _finalize_shared():
+        dkr_ref[0] = dkr_acc[...].astype(dkr_ref.dtype)
+
+
+class _MlaStatic(NamedTuple):
+    """What the latent-attention kernels are specialised on."""
+    scale: float
+    block: int      # bq == bk
+    dn: int         # width of q_nope, k_nope and v of a head
+    dr: int         # width of the rotary parts
+    interpret: bool
+
+    @property
+    def hpb(self) -> int:
+        return 128 // self.dr
+
+
+def _mla_params(sequential: int):
+    """The last ``sequential`` grid dimensions run in order; a block of
+    1,024 rows of two heads needs more than the 16 MiB of VMEM that a
+    kernel gets unasked (two [1024, 1024] float32 score squares and
+    their casts beside the double-buffered operands)."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (4 - sequential)
+        + ("arbitrary",) * sequential,
+        vmem_limit_bytes=64 * 1024 * 1024)
+
+
+@functools.partial(jax.jit, static_argnames=("static",))
+def mla_flash_fwd(qn, qr, kn, kr, v, *, static: _MlaStatic):
+    """(out [B, T, H*dn], lse [B, G, T/b, hpb, b]); jitted for the
+    reason ``_flash_fwd`` is."""
+    scale, blk, dn, dr, interpret = static
+    hpb = static.hpb
+    b, t, w = qn.shape
+    g, nb = w // (hpb * dn), t // blk
+    # index maps stop at the diagonal: a block above it is not fetched
+    q_map = lambda b, c, i, j: (b, i, c)                      # noqa: E731
+    kv_map = lambda b, c, i, j: (b, jnp.minimum(j, i), c)     # noqa: E731
+    kr_map = lambda b, c, i, j: (b, jnp.minimum(j, i), 0)     # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_mla_fwd_kernel, scale=scale, nk=nb, dn=dn,
+                          dr=dr, hpb=hpb),
+        grid=(b, g, nb, nb),
+        in_specs=[_seq_spec(blk, hpb * dn, q_map),
+                  _seq_spec(blk, hpb * dr, q_map),
+                  _seq_spec(blk, hpb * dn, kv_map),
+                  _seq_spec(blk, dr, kr_map),
+                  _seq_spec(blk, hpb * dn, kv_map)],
+        out_specs=[_seq_spec(blk, hpb * dn, q_map),
+                   _stat_spec(hpb, blk, lambda b, c, i, j: (b, c, i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b, t, w), qn.dtype),
+                   jax.ShapeDtypeStruct((b, g, nb, hpb, blk), jnp.float32)],
+        scratch_shapes=[_vmem((blk, hpb * dn)), _vmem((hpb, blk, 128)),
+                        _vmem((hpb, blk, 128))],
+        compiler_params=_mla_params(1),
+        interpret=interpret,
+    )(qn, qr, kn, kr, v)
+
+
+@functools.partial(jax.jit, static_argnames=("static",))
+def mla_flash_bwd(qn, qr, kn, kr, v, out, lse, g, *, static: _MlaStatic):
+    """(dqn, dqr, dkn, dkr, dv), shaped as the operands."""
+    scale, blk, dn, dr, interpret = static
+    hpb = static.hpb
+    b, t, w = qn.shape
+    ng, nb = w // (hpb * dn), t // blk
+    do = g.astype(qn.dtype)
+    like = jax.ShapeDtypeStruct
+    wide, rope = hpb * dn, hpb * dr
+
+    q_map = lambda b, c, i, j: (b, i, c)                      # noqa: E731
+    kv_map = lambda b, c, i, j: (b, jnp.minimum(j, i), c)     # noqa: E731
+    kr_map = lambda b, c, i, j: (b, jnp.minimum(j, i), 0)     # noqa: E731
+    stat = _stat_spec(hpb, blk, lambda b, c, i, j: (b, c, i, 0, 0))
+    dqn, dqr, delta = pl.pallas_call(
+        functools.partial(_mla_bwd_dq_kernel, scale=scale, nk=nb, dn=dn,
+                          dr=dr, hpb=hpb),
+        grid=(b, ng, nb, nb),
+        in_specs=[_seq_spec(blk, wide, q_map), _seq_spec(blk, rope, q_map),
+                  _seq_spec(blk, wide, kv_map), _seq_spec(blk, dr, kr_map),
+                  _seq_spec(blk, wide, kv_map),
+                  _seq_spec(blk, wide, q_map), _seq_spec(blk, wide, q_map),
+                  stat],
+        out_specs=[_seq_spec(blk, wide, q_map), _seq_spec(blk, rope, q_map),
+                   stat],
+        out_shape=[like(qn.shape, qn.dtype), like(qr.shape, qr.dtype),
+                   like(lse.shape, lse.dtype)],
+        scratch_shapes=[_vmem((blk, wide)), _vmem((blk, rope))],
+        compiler_params=_mla_params(1),
+        interpret=interpret,
+    )(qn, qr, kn, kr, v, out, do, lse)
+
+    # grid (batch, key block, head pair, query block): the last two in
+    # order, so that dk_r's sum over every head stays in scratch
+    q_map = lambda b, j, c, i: (b, jnp.maximum(i, j), c)      # noqa: E731
+    kv_map = lambda b, j, c, i: (b, j, c)                     # noqa: E731
+    kr_spec = _seq_spec(blk, dr, lambda b, j, c, i: (b, j, 0))
+    stat = _stat_spec(hpb, blk, lambda b, j, c, i: (
+        b, c, jnp.maximum(i, j), 0, 0))
+    dkn, dkr, dv = pl.pallas_call(
+        functools.partial(_mla_bwd_dkv_kernel, scale=scale, ng=ng, nq=nb,
+                          dn=dn, dr=dr, hpb=hpb),
+        grid=(b, nb, ng, nb),
+        in_specs=[_seq_spec(blk, wide, q_map), _seq_spec(blk, rope, q_map),
+                  _seq_spec(blk, wide, kv_map), kr_spec,
+                  _seq_spec(blk, wide, kv_map), _seq_spec(blk, wide, q_map),
+                  stat, stat],
+        out_specs=[_seq_spec(blk, wide, kv_map), kr_spec,
+                   _seq_spec(blk, wide, kv_map)],
+        out_shape=[like(kn.shape, kn.dtype), like(kr.shape, kr.dtype),
+                   like(v.shape, v.dtype)],
+        scratch_shapes=[_vmem((blk, wide)), _vmem((blk, dr)),
+                        _vmem((blk, wide))],
+        compiler_params=_mla_params(2),
+        interpret=interpret,
+    )(qn, qr, kn, kr, v, do, lse, delta)
+    return dqn, dqr, dkn, dkr, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def mla_flash_core(qn, qr, kn, kr, v, static: _MlaStatic):
+    """The attention's output [B, T, H*dn]; its backward pass keeps the
+    five operands, the output and the log-sum-exp."""
+    return mla_flash_fwd(qn, qr, kn, kr, v, static=static)[0]
+
+
+def _mla_core_fwd(qn, qr, kn, kr, v, static):
+    out, lse = mla_flash_fwd(qn, qr, kn, kr, v, static=static)
+    return out, (qn, qr, kn, kr, v, out, lse)
+
+
+def _mla_core_bwd(static, res, g):
+    return mla_flash_bwd(*res, g, static=static)
+
+
+mla_flash_core.defvjp(_mla_core_fwd, _mla_core_bwd)
+
+
+def mla_flash_shapes_ok(t: int, dn: int, dr: int, dv: int,
+                        heads: int) -> bool:
+    """Do the latent-attention kernels tile [*, t, heads, dn + dr]
+    queries against dv-wide values? One head of dn = dv lanes a 128-lane
+    block (or several), the rotary parts of ``128 // dr`` heads another,
+    rows in blocks of 128 or more."""
+    return (dn == dv and dn % 128 == 0 and dr in (64, 128)
+            and heads % (128 // dr) == 0 and _pick_block(t) >= 128)
+
+
+def mla_flash_static(t: int, dn: int, dr: int, scale: float | None = None,
+                     block: int | None = None,
+                     interpret: bool = False) -> _MlaStatic:
+    """What ``mla_flash_fwd`` / ``mla_flash_bwd`` are specialised on at
+    these shapes, with the notes of which path compiled (made here and
+    not in the jitted functions, whose traces jax caches)."""
+    blk = block or _pick_block(t)
+    if blk == 0 or t % blk:
+        raise ValueError(f"seq len {t} not divisible into flash blocks")
+    static = _MlaStatic(float((dn + dr) ** -0.5 if scale is None else scale),
+                        blk, dn, dr, interpret)
+    tracing.note_trace(
+        flash_layout="bthd", flash_lanes_per_block=static.hpb * dn,
+        flash_path="mla_multi_block", flash_causal_slabs=1)
+    return static

@@ -1,0 +1,214 @@
+"""Latent attention: the flash kernels at keys of 128 + 64 and values of
+128 (``ops/pallas/flash_attention.py``'s last section), interpreted on
+the CPU, against a masked softmax over the concatenated keys; and
+``ops/mla.py::latent_attention``, whose backward pass may keep the
+latents and run the up-projections again."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models.llama import apply_rope, rope_freqs
+from ray_tpu.ops import mla
+from ray_tpu.ops.pallas.flash_attention import (
+    mla_flash_core, mla_flash_shapes_ok, mla_flash_static,
+)
+from ray_tpu.parallel import make_mesh
+
+B, T, H, DN, DR = 2, 384, 4, 128, 64
+
+
+def _concatenated(qn, qr, kn, kr, v):
+    """Head i: softmax(mask([qn_i | qr_i] [kn_i | kr]^T / sqrt(192))) v_i,
+    the one rotary key copied to every head."""
+    b, t, h, _ = qn.shape
+    q = jnp.concatenate([qn, qr], -1)
+    k = jnp.concatenate(
+        [kn, jnp.broadcast_to(kr[:, :, None], (b, t, h, kr.shape[-1]))], -1)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+def _kernels(qn, qr, kn, kr, v, block):
+    """``mla_flash_core`` on the heads' [B, T, H, D] operands, folded to
+    the [B, T, H*D] it indexes as ``ops/mla.py``'s matmuls write it."""
+    b, t, h, dn = qn.shape
+    static = mla_flash_static(t, dn, qr.shape[-1], block=block,
+                              interpret=True)
+    out = mla_flash_core(qn.reshape(b, t, -1), qr.reshape(b, t, -1),
+                         kn.reshape(b, t, -1), kr, v.reshape(b, t, -1),
+                         static)
+    return out.reshape(b, t, h, dn)
+
+
+def _operands(seed=0, t=T):
+    ks = jax.random.split(jax.random.key(seed), 6)
+    return ((jax.random.normal(ks[0], (B, t, H, DN)),
+             jax.random.normal(ks[1], (B, t, H, DR)),
+             jax.random.normal(ks[2], (B, t, H, DN)),
+             jax.random.normal(ks[3], (B, t, DR)),
+             jax.random.normal(ks[4], (B, t, H, DN))),
+            jax.random.normal(ks[5], (B, t, H, DN)))
+
+
+@pytest.mark.parametrize("block", [128, 384], ids=["three_blocks", "one"])
+def test_kernel_forward_is_the_masked_softmax_over_concatenated_keys(block):
+    ops, _ = _operands()
+    got = _kernels(*ops, block=block)
+    np.testing.assert_allclose(got, _concatenated(*ops), atol=2e-5)
+
+
+@pytest.mark.parametrize("name, arg", [
+    ("dq_nope", 0), ("dq_rope", 1), ("dk_nope", 2), ("dk_rope", 3),
+    ("dv", 4)])
+def test_kernel_backward_gives_each_of_the_five_gradients(name, arg):
+    """``dk_rope`` is the sum over the heads (and, in the kernel, over
+    the head pairs of a key block: carried in scratch across the grid's
+    third dimension)."""
+    ops, w = _operands(1)
+
+    def through(fn):
+        return jax.grad(lambda *a: (fn(*a) * w).sum(), argnums=arg)(*ops)
+
+    got = through(lambda *a: _kernels(*a, block=128))
+    want = through(_concatenated)
+    assert got.shape == ops[arg].shape
+    np.testing.assert_allclose(got, want, atol=3e-5 * float(
+        jnp.abs(want).max()) + 1e-6, err_msg=name)
+
+
+def test_the_shapes_the_kernels_tile():
+    assert mla_flash_shapes_ok(8192, 128, 64, 128, 32)     # the cell's
+    assert not mla_flash_shapes_ok(8192, 128, 64, 64, 32)  # v != nope
+    assert not mla_flash_shapes_ok(8192, 128, 64, 128, 3)  # an odd head
+    assert not mla_flash_shapes_ok(64, 128, 64, 128, 4)    # a short row
+    assert not mla_flash_shapes_ok(8192, 16, 8, 16, 4)     # the tiny preset
+    with pytest.raises(ValueError, match="not divisible into flash blocks"):
+        mla_flash_static(100, 128, 64)
+
+
+# -- ops/mla.py ------------------------------------------------------------
+
+RQ, RKV = 32, 16
+
+
+def _latents(seed=2, t=256, h=2):
+    ks = jax.random.split(jax.random.key(seed), 8)
+    up = mla.UpProjections(
+        jax.random.normal(ks[3], (RQ, h * DN)) * 0.2,
+        jax.random.normal(ks[4], (RQ, h * DR)) * 0.2,
+        jax.random.normal(ks[5], (RKV, h * DN)) * 0.2,
+        jax.random.normal(ks[6], (RKV, h * DN)) * 0.2)
+    return (jax.random.normal(ks[0], (B, t, RQ)),
+            jax.random.normal(ks[1], (B, t, RKV)),
+            jax.random.normal(ks[2], (B, t, DR)), up,
+            jax.random.normal(ks[7], (B, t, h * DN)))
+
+
+def _plain(c_q, c_kv, k_r, up, angles, h):
+    """``latent_attention`` written out: up-project, rotate, attend."""
+    b, t, _ = c_q.shape
+    qn = (c_q @ up.q_nope).reshape(b, t, h, DN)
+    qr = apply_rope((c_q @ up.q_rope).reshape(b, t, h, DR), angles)
+    kn = (c_kv @ up.k_nope).reshape(b, t, h, DN)
+    v = (c_kv @ up.v).reshape(b, t, h, DN)
+    kr = apply_rope(k_r[:, :, None], angles)[:, :, 0]
+    return _concatenated(qn, qr, kn, kr, v).reshape(b, t, h * DN)
+
+
+@pytest.mark.parametrize("interpret", [True, False],
+                         ids=["kernels_interpreted", "xla_path"])
+def test_recomputed_up_projections_give_the_gradients_of_kept_ones(
+        interpret):
+    """``saved="latents"`` (the kernels' operands made again in the
+    backward pass from ``c_q``, ``c_kv``, ``k_r``) against
+    ``saved="expanded"`` and against the attention written out: the
+    output and the gradient of every operand, weights included."""
+    c_q, c_kv, k_r, up, w = _latents()
+    angles = rope_freqs(DR, 256, 10000.0)
+
+    def loss(saved):
+        def f(c_q, c_kv, k_r, up):
+            o = (_plain(c_q, c_kv, k_r, up, angles, 2) if saved is None
+                 else mla.latent_attention(
+                     c_q, c_kv, k_r, up, angles, n_head=2, saved=saved,
+                     interpret=interpret))
+            return (o * w).sum()
+        return jax.value_and_grad(f, argnums=(0, 1, 2, 3))
+
+    with jax.default_matmul_precision("highest"):
+        want, wants = loss(None)(c_q, c_kv, k_r, up)
+        kept, kepts = loss("expanded")(c_q, c_kv, k_r, up)
+        got, gots = loss("latents")(c_q, c_kv, k_r, up)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    assert float(kept) == pytest.approx(float(want), rel=1e-5)
+    for g, k, x in zip(*(jax.tree_util.tree_leaves(t)
+                         for t in (gots, kepts, wants))):
+        tol = 1e-4 * float(jnp.abs(x).max())
+        np.testing.assert_allclose(g, x, atol=tol)
+        np.testing.assert_allclose(g, k, atol=tol)
+
+
+def test_latents_are_what_the_backward_pass_keeps():
+    """With ``saved="latents"`` no residual is as wide as the kernels'
+    operands (``H * 128`` a token); with ``"expanded"`` q_nope, k_nope
+    and v are."""
+    c_q, c_kv, k_r, up, _ = _latents()
+    angles = rope_freqs(DR, 256, 10000.0)
+
+    def widths(saved):
+        _, pull = jax.vjp(
+            lambda *a: mla.latent_attention(
+                *a, up, angles, n_head=2, saved=saved, interpret=True).sum(),
+            c_q, c_kv, k_r)
+        return sorted(x.shape[-1] for x in jax.tree_util.tree_leaves(pull)
+                      if x.ndim == 3 and x.shape[1] == 256)
+
+    assert widths("latents").count(2 * DN) == 1          # the output
+    assert widths("expanded").count(2 * DN) == 4         # + q, k_nope, v
+
+
+def test_the_path_is_decided_from_backend_shapes_and_mesh(monkeypatch):
+    cell = (1, 8192, 32, 128, 64, 128)
+    assert mla.mla_path(*cell) == ("xla", ())            # the CPU
+    assert mla.mla_path(*cell, interpret=True) == ("kernel", ())
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="no mesh"):
+        mla.mla_path(*cell)                  # 8 devices here, no mesh
+    one = make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    assert mla.mla_path(*cell, mesh=one) == ("kernel", ())
+    dp = make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    assert mla.mla_path(2, *cell[1:], mesh=dp) == ("kernel", ("dp",))
+    assert mla.mla_path(1, *cell[1:], mesh=dp) == ("xla", ())   # init's batch
+    assert mla.mla_path(2, 64, 4, 16, 8, 16, mesh=dp) == ("xla", ())
+    for axis in ("sp", "tp", "ep"):
+        mesh = make_mesh({axis: 2}, devices=jax.devices()[:2])
+        with pytest.raises(NotImplementedError, match=f"{axis}=2"):
+            mla.mla_path(*cell, mesh=mesh)
+
+
+def test_the_kernels_under_a_shard_map_over_dp_are_the_bare_ones():
+    """Two rows over ``dp`` = 2, kernels interpreted on each device's
+    row, against the unsharded call: values and gradients."""
+    mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    c_q, c_kv, k_r, up, w = _latents(3)
+    angles = rope_freqs(DR, 256, 10000.0)
+
+    def run(mesh):
+        def f(c_q, c_kv, k_r, up):
+            return (mla.latent_attention(
+                c_q, c_kv, k_r, up, angles, n_head=2, mesh=mesh,
+                interpret=True) * w).sum()
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2, 3)))(
+            c_q, c_kv, k_r, up)
+
+    assert mla.mla_path(B, 256, 2, DN, DR, DN, mesh, True) == (
+        "kernel", ("dp",))
+    want, wants = run(None)
+    got, gots = run(mesh)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for g, x in zip(jax.tree_util.tree_leaves(gots),
+                    jax.tree_util.tree_leaves(wants)):
+        np.testing.assert_allclose(g, x, atol=1e-4 * float(jnp.abs(x).max()))

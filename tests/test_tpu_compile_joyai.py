@@ -1,0 +1,206 @@
+"""The JoyAI-LLM-Flash cell's new pieces compile for the real chip, with
+no chip here (as ``test_tpu_compile_nemotron.py``: the TPU compiler for a
+described v5e; nothing runs, so nothing here is a result or a time)."""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, no description
+        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _arg(device):
+    one = SingleDeviceSharding(device)
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one)
+
+
+def _on_one_chip(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
+
+
+def test_latent_attention_kernels_compile_for_v5e_at_the_cells_shape(v5e):
+    """One sequence of 8,192 tokens, 32 heads of 128 + 64 against values
+    of 128, in bfloat16, forward and backward: three custom calls, no
+    ``[T, T]`` array and no operand 256 wide (the rotary key is the one
+    ``[1, 8192, 64]`` array it is), temporaries a fraction of a GB."""
+    from ray_tpu.ops.pallas.flash_attention import (
+        mla_flash_core, mla_flash_static)
+    arg = _arg(v5e[0])
+    static = mla_flash_static(8192, 128, 64)
+
+    def loss(*operands):
+        return mla_flash_core(*operands, static).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg((1, 8192, 32 * 128), jnp.bfloat16),
+        arg((1, 8192, 32 * 64), jnp.bfloat16),
+        arg((1, 8192, 32 * 128), jnp.bfloat16),
+        arg((1, 8192, 64), jnp.bfloat16),
+        arg((1, 8192, 32 * 128), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "8192,8192" not in text
+    assert "8192,32,256" not in text and "8192,32,192" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_the_real_size_step_compiles_inside_the_chips_memory(
+        v5e, monkeypatch):
+    """The cell's step as the builder makes it (the dense layer, four
+    routed layers with 16 of 256 experts held, the MTP module, 16,384
+    rows of the vocabulary; adamw with a bf16 first moment) at 8,192
+    tokens: arguments + temporaries + unaliased outputs fit the v5e's
+    15.75 GB with the room the acceptance asks for, every layer's
+    attention is the kernel (6 x 3 custom calls beside the experts'),
+    and no ``[T, T]`` array exists."""
+    import optax
+
+    from ray_tpu import train
+    from ray_tpu.models.joyai import JoyAI, JoyAIConfig, joyai_loss_fn
+    from ray_tpu.util import tracing
+    _on_one_chip(monkeypatch)
+    arg = _arg(v5e[0])
+    cfg = JoyAIConfig.joyai_llm_flash(n_layer=5, experts_held=(0, 16),
+                                      vocab_size=16384)
+    model = JoyAI(cfg)
+    opt = optax.chain(
+        optax.clip_by_global_norm(1.0),
+        optax.adamw(2e-5, b1=0.9, b2=0.95, weight_decay=0.1,
+                    mu_dtype=jnp.bfloat16))
+    step = train.make_train_step(joyai_loss_fn(model, ce_chunk=2048), opt)
+    state = jax.tree.map(
+        lambda z: arg(z.shape, z.dtype),
+        jax.eval_shape(lambda: train.init_train_state(
+            model.init_params(jax.random.key(0)), opt, None)))
+    batch = {k: arg((1, cfg.seq_len), jnp.int32)
+             for k in ("tokens", "targets")}
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    compiled = step.lower(state, batch).compile()
+    assert notes["flash_path"] == "mla_multi_block"
+    assert notes["flash_layout"] == "bthd"
+    assert notes["mla_saved"] == "latents"
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + max(0, m.output_size_in_bytes - m.alias_size_in_bytes))
+    assert m.argument_size_in_bytes == pytest.approx(
+        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
+    assert total < 15.2e9
+    text = compiled.as_text()
+    assert text.count("mla_flash_fwd") >= 6
+    assert "8192,8192" not in text
+
+
+def _dp(v5e):
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    mesh = Mesh(np.array(v5e), ("dp",))
+    rows = NamedSharding(mesh, PartitionSpec("dp"))
+    whole = NamedSharding(mesh, PartitionSpec())
+    return mesh, (lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=rows)), (
+        lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=whole))
+
+
+def _narrow(**kw):
+    """Published head widths (128 + 64 against 128), little else."""
+    from ray_tpu.models.joyai import JoyAIConfig
+    return JoyAIConfig.tiny(**{**dict(
+        n_layer=2, n_embd=128, seq_len=256, n_head=2, q_rank=64, kv_rank=32,
+        nope_dim=128, rope_dim=64, v_dim=128), **kw})
+
+
+def test_a_model_compiles_for_four_chips_with_the_batch_over_dp(
+        v5e, monkeypatch):
+    """Given the mesh, latent attention's kernels run under a
+    ``shard_map`` over ``dp`` (a ``pallas_call`` has no SPMD rule), a
+    custom call a pass on each chip, and no chip gathers another's rows;
+    given none in a process of several devices, it fails loudly and does
+    not fall to the XLA attention."""
+    from ray_tpu.models.joyai import JoyAI, joyai_loss_fn
+    from ray_tpu.util import tracing
+    assert jax.device_count() > 1
+    mesh, rows, whole = _dp(v5e)
+    cfg = _narrow()
+    params = jax.tree.map(
+        lambda z: whole(z.shape, z.dtype),
+        jax.eval_shape(JoyAI(cfg).init_params, jax.random.key(0)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    batch = {k: rows((4, cfg.seq_len), jnp.int32)
+             for k in ("tokens", "targets")}
+
+    def grads(model):
+        loss = joyai_loss_fn(model, ce_chunk=64)
+        return jax.jit(jax.grad(lambda p, b: loss(p, b)[0])).lower(
+            params, batch)
+
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    text = grads(JoyAI(cfg, mesh=mesh)).compile().as_text()
+    assert notes["flash_path"] == "mla_multi_block"
+    assert text.count("mla_flash_fwd") >= 1 and "all-gather" not in text
+    with pytest.raises(NotImplementedError, match="no mesh"):
+        grads(JoyAI(cfg))
+
+
+def test_layers_at_one_shape_trace_each_kernel_once(monkeypatch):
+    """What holds ``setup_s`` (PERF.md section 6, PR 28): the two
+    functions that hold the ``pallas_call``s are jitted, so a step's
+    three blocks trace each kernel's body once and lower to one function
+    a pass, called three times. Interpreted, on the CPU: counting traces
+    needs nothing of the chip."""
+    from ray_tpu.models.llama import rope_freqs
+    from ray_tpu.ops import mla
+    import importlib
+    # the package exports the function under the module's name
+    fa = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
+    bodies = []
+    scores = fa._mla_scores
+    monkeypatch.setattr(fa, "_mla_scores",
+                        lambda *a: bodies.append(1) or scores(*a))
+    t, h = 128, 2           # no other test's shape
+    ks = jax.random.split(jax.random.key(0), 7)
+    up = mla.UpProjections(*(jax.random.normal(k, (16, h * w)) * 0.1
+                             for k, w in zip(ks, (128, 64, 128, 128))))
+    c = jax.random.normal(ks[4], (1, t, 16))
+    k_r = jax.random.normal(ks[5], (1, t, 64))
+    angles = rope_freqs(64, t, 1e4)
+
+    def three_layers(c, up):
+        y = 0.0
+        for i in range(3):
+            with jax.named_scope(f"h_{i}"):
+                y = y + mla.latent_attention(
+                    c, c, k_r, up, angles, n_head=h, interpret=True).sum()
+        return y
+
+    text = jax.jit(jax.grad(three_layers, argnums=(0, 1))).lower(
+        c, up).as_text()
+    assert text.count("call @mla_flash_fwd") == 3
+    assert text.count("call @mla_flash_bwd") == 3
+    # one head pair a cell, two heads a pair; each body branches on the
+    # diagonal: forward 2 x 2, dq 2 x 2, dk/dv 2 x 2 score products
+    assert len(bodies) == 12
