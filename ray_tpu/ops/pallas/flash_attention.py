@@ -670,10 +670,33 @@ def flash_attention_shapes_ok(t: int, d: int) -> bool:
 # multi-block ones above at bq == bk, with two differences: the block
 # that straddles the diagonal is the only one that pays for a mask, and
 # the index maps stop at the diagonal, so a skipped cell moves nothing.
-# ``dk_r`` is the sum over every head: the dk/dv kernel walks the head
-# pairs as its third, sequential, grid dimension and carries the sum in
-# VMEM scratch across them, so it is written once a key block.
 # Statistics and outputs are laid out as above.
+#
+# The backward pass is ONE kernel where its accumulators fit the VMEM
+# (``_mla_bwd_fits``: 32,768 rows do, 65,536 do not): grid
+# (batch, head pair, key block, query block), the last three in order.
+# A cell makes ``s``, ``p``, ``dP`` and ``ds`` of its block pair once
+# and feeds all five products from them. What stays in VMEM scratch
+# across cells, in float32, and is cast and written once:
+#
+#   dk_n, dv   [block, 256]     of the key block, across its query blocks
+#   dq_n, dq_r [T, 256 + 128]   of the head pair's WHOLE sequence, across
+#                               the key blocks; a query block's rows are
+#                               final at its diagonal and leave there
+#   dk_r       [T, 64]          the sum over every head, across the head
+#                               pairs; leaves in the last pair's cells
+#   delta      [T/block, 2, block]  rowsum(o * dO), made in the first key
+#                               block's cells, which every query block
+#                               passes
+#
+# An output block is indexed by the key block of the cell that fills it
+# (``dq``: the diagonal cell; ``dk_r``: block 0 until the last head
+# pair), so each is written to HBM once, when its index moves on.
+# A row too long for that takes the two kernels this one replaced, which
+# each make the scores and ``dP`` again (3 of the dq kernel's 5 and 3 of
+# the dk/dv kernel's 6 MXU passes a block pair): dq with the key block
+# innermost, dk/dv with the query block innermost and the head pairs as
+# the third, sequential, dimension that carries ``dk_r``.
 
 def _mla_scores(qn, qr, kn, kr, scale, on_diagonal: bool):
     """[bq, bk] scaled scores of one head: the two parts' products
@@ -837,6 +860,76 @@ def _mla_bwd_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
         dkr_ref[0] = dkr_acc[...].astype(dkr_ref.dtype)
 
 
+def _mla_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
+                    lse_ref, dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref,
+                    dqn_acc, dqr_acc, dkn_acc, dkr_acc, dv_acc, delta_ref,
+                    *, scale, ng, nb, blk, dn, dr, hpb):
+    """The whole backward pass of one (head pair, key block, query
+    block): ``_mla_ds`` once a head, five products from it."""
+    c = pl.program_id(1)
+    ik = pl.program_id(2)
+    iq = pl.program_id(3)
+    q_rows = pl.ds(pl.multiple_of(iq * blk, blk), blk)
+    k_rows = pl.ds(pl.multiple_of(ik * blk, blk), blk)
+    parts = _mla_parts(dn, dr, hpb)
+
+    @pl.when(ik == 0)
+    def _first_key_block():         # every query block passes here first
+        dqn_acc[q_rows] = jnp.zeros((blk, hpb * dn), jnp.float32)
+        dqr_acc[q_rows] = jnp.zeros((blk, hpb * dr), jnp.float32)
+        for j, (n, _) in enumerate(parts):
+            delta_ref[iq, j:j + 1] = _as_row(_delta(o_ref, do_ref, n))
+
+    @pl.when(iq == ik)
+    def _first_query_block():       # the key block's first live cell
+        dkn_acc[...] = jnp.zeros_like(dkn_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when((iq == ik) & (c == 0))
+    def _first_head_pair():
+        dkr_acc[k_rows] = jnp.zeros((blk, dr), jnp.float32)
+
+    def step(on_diagonal):
+        kr = kr_ref[0]
+        for j, (n, r) in enumerate(parts):
+            p, ds = _mla_ds(qn_ref, qr_ref, kn_ref, kr, v_ref, do_ref,
+                            lse_ref, delta_ref.at[iq], j, n, r, scale,
+                            on_diagonal)
+            do = do_ref[0, :, n]
+            over_q = (((0,), (0,)), ((), ()))      # [bq, bk]^T [bq, d]
+            over_k = (((1,), (0,)), ((), ()))      # [bq, bk] [bk, d]
+            dv_acc[:, n] += jax.lax.dot_general(
+                p.astype(do.dtype), do, over_q,
+                preferred_element_type=jnp.float32)
+            dkn_acc[:, n] += jax.lax.dot_general(
+                ds, qn_ref[0, :, n], over_q,
+                preferred_element_type=jnp.float32)
+            dkr_acc[k_rows] += jax.lax.dot_general(
+                ds, qr_ref[0, :, r], over_q,
+                preferred_element_type=jnp.float32)
+            dqn_acc[q_rows, n] += jax.lax.dot_general(
+                ds, kn_ref[0, :, n], over_k,
+                preferred_element_type=jnp.float32)
+            dqr_acc[q_rows, r] += jax.lax.dot_general(
+                ds, kr, over_k, preferred_element_type=jnp.float32)
+
+    _on_live_blocks(iq, ik, step)
+
+    @pl.when(iq == ik)              # no later key block reaches these rows
+    def _dq_whole():
+        dqn_ref[0] = dqn_acc[q_rows].astype(dqn_ref.dtype)
+        dqr_ref[0] = dqr_acc[q_rows].astype(dqr_ref.dtype)
+
+    @pl.when(iq == nb - 1)
+    def _dkv_whole():
+        dkn_ref[0] = dkn_acc[...].astype(dkn_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when((iq == nb - 1) & (c == ng - 1))
+    def _dkr_whole():
+        dkr_ref[0] = dkr_acc[k_rows].astype(dkr_ref.dtype)
+
+
 class _MlaStatic(NamedTuple):
     """What the latent-attention kernels are specialised on."""
     scale: float
@@ -844,29 +937,56 @@ class _MlaStatic(NamedTuple):
     dn: int         # width of q_nope, k_nope and v of a head
     dr: int         # width of the rotary parts
     interpret: bool
+    one_bwd: bool   # the backward pass is one kernel (``_mla_bwd_fits``)
 
     @property
     def hpb(self) -> int:
         return 128 // self.dr
 
 
-def _mla_params(sequential: int):
-    """The last ``sequential`` grid dimensions run in order; a block of
-    1,024 rows of two heads needs more than the 16 MiB of VMEM that a
-    kernel gets unasked (two [1024, 1024] float32 score squares and
-    their casts beside the double-buffered operands)."""
+# What a latent-attention kernel may hold in VMEM: a block of 1,024 rows
+# of two heads needs more than the 16 MiB that a kernel gets unasked
+# (two [1024, 1024] float32 score squares and their casts beside the
+# double-buffered operands). A v5e has 128 MiB.
+_MLA_VMEM = 64 * 1024 * 1024
+_MLA_BWD_VMEM = 100 * 1024 * 1024       # the one-kernel backward's
+
+
+def _mla_bwd_fits(t: int, blk: int, dn: int, dr: int) -> bool:
+    """Does the one-kernel backward pass fit ``_MLA_BWD_VMEM`` at ``t``
+    rows? Counted from the shapes. What grows with ``t``: the float32
+    accumulators of a head pair's whole sequence (dq_n, dq_r, and dk_r
+    padded to 128 lanes) and delta's rows (a pair's 2 padded to 8
+    sublanes): 2,080 bytes a row at the published widths. What a cell
+    holds whatever ``t`` is: the 2-byte operand and output blocks twice
+    over, dk_n's and dv's accumulators, and four [blk, blk] float32
+    squares for the body's scores, probabilities and cotangents, twice
+    what the compiler was seen to take (at 8,192 rows in blocks of 1,024
+    it allocates 35.3 MiB where this counts 43.3; PERF.md section 6, PR
+    35). 32,768 rows fit, 65,536 do not."""
+    hpb = 128 // dr
+    wide, rope = hpb * dn, hpb * dr
+    resident = t * 4 * (wide + rope + 128 + 8)
+    cell = (4 * blk * blk * 4
+            + 2 * 2 * blk * (7 * wide + 2 * rope + 2 * 128)
+            + 2 * blk * wide * 4)
+    return resident + cell <= _MLA_BWD_VMEM
+
+
+def _mla_params(sequential: int, vmem: int = _MLA_VMEM):
+    """The last ``sequential`` grid dimensions run in order."""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(
         dimension_semantics=("parallel",) * (4 - sequential)
         + ("arbitrary",) * sequential,
-        vmem_limit_bytes=64 * 1024 * 1024)
+        vmem_limit_bytes=vmem)
 
 
 @functools.partial(jax.jit, static_argnames=("static",))
 def mla_flash_fwd(qn, qr, kn, kr, v, *, static: _MlaStatic):
     """(out [B, T, H*dn], lse [B, G, T/b, hpb, b]); jitted for the
     reason ``_flash_fwd`` is."""
-    scale, blk, dn, dr, interpret = static
+    scale, blk, dn, dr, interpret, _ = static
     hpb = static.hpb
     b, t, w = qn.shape
     g, nb = w // (hpb * dn), t // blk
@@ -897,13 +1017,52 @@ def mla_flash_fwd(qn, qr, kn, kr, v, *, static: _MlaStatic):
 @functools.partial(jax.jit, static_argnames=("static",))
 def mla_flash_bwd(qn, qr, kn, kr, v, out, lse, g, *, static: _MlaStatic):
     """(dqn, dqr, dkn, dkr, dv), shaped as the operands."""
-    scale, blk, dn, dr, interpret = static
+    scale, blk, dn, dr, interpret, one_bwd = static
     hpb = static.hpb
     b, t, w = qn.shape
     ng, nb = w // (hpb * dn), t // blk
     do = g.astype(qn.dtype)
     like = jax.ShapeDtypeStruct
     wide, rope = hpb * dn, hpb * dr
+    grads = [like(x.shape, x.dtype) for x in (qn, qr, kn, kr, v)]
+
+    if one_bwd:
+        # grid (batch, head pair, key block, query block); the index maps
+        # stop at the diagonal; ``o`` is read in the first key block's
+        # cells alone (delta), and an output block is indexed by the key
+        # block of the cell that fills it
+        q_map = lambda b, c, j, i: (b, jnp.maximum(i, j), c)  # noqa: E731
+        kv_map = lambda b, c, j, i: (b, j, c)                 # noqa: E731
+        kr_map = lambda b, c, j, i: (b, j, 0)                 # noqa: E731
+        o_map = lambda b, c, j, i: (                          # noqa: E731
+            b, jnp.where(j == 0, i, nb - 1), c)
+        dkr_map = lambda b, c, j, i: (                        # noqa: E731
+            b, jnp.where(c == ng - 1, j, 0), 0)
+        stat = _stat_spec(hpb, blk, lambda b, c, j, i: (
+            b, c, jnp.maximum(i, j), 0, 0))
+        return tuple(pl.pallas_call(
+            functools.partial(_mla_bwd_kernel, scale=scale, ng=ng, nb=nb,
+                              blk=blk, dn=dn, dr=dr, hpb=hpb),
+            grid=(b, ng, nb, nb),
+            in_specs=[_seq_spec(blk, wide, q_map),
+                      _seq_spec(blk, rope, q_map),
+                      _seq_spec(blk, wide, kv_map),
+                      _seq_spec(blk, dr, kr_map),
+                      _seq_spec(blk, wide, kv_map),
+                      _seq_spec(blk, wide, o_map),
+                      _seq_spec(blk, wide, q_map), stat],
+            out_specs=[_seq_spec(blk, wide, kv_map),
+                       _seq_spec(blk, rope, kv_map),
+                       _seq_spec(blk, wide, kv_map),
+                       _seq_spec(blk, dr, dkr_map),
+                       _seq_spec(blk, wide, kv_map)],
+            out_shape=grads,
+            scratch_shapes=[_vmem((t, wide)), _vmem((t, rope)),
+                            _vmem((blk, wide)), _vmem((t, dr)),
+                            _vmem((blk, wide)), _vmem((nb, hpb, blk))],
+            compiler_params=_mla_params(3, _MLA_BWD_VMEM),
+            interpret=interpret,
+        )(qn, qr, kn, kr, v, out, do, lse))
 
     q_map = lambda b, c, i, j: (b, i, c)                      # noqa: E731
     kv_map = lambda b, c, i, j: (b, jnp.minimum(j, i), c)     # noqa: E731
@@ -920,8 +1079,7 @@ def mla_flash_bwd(qn, qr, kn, kr, v, out, lse, g, *, static: _MlaStatic):
                   stat],
         out_specs=[_seq_spec(blk, wide, q_map), _seq_spec(blk, rope, q_map),
                    stat],
-        out_shape=[like(qn.shape, qn.dtype), like(qr.shape, qr.dtype),
-                   like(lse.shape, lse.dtype)],
+        out_shape=[*grads[:2], like(lse.shape, lse.dtype)],
         scratch_shapes=[_vmem((blk, wide)), _vmem((blk, rope))],
         compiler_params=_mla_params(1),
         interpret=interpret,
@@ -944,8 +1102,7 @@ def mla_flash_bwd(qn, qr, kn, kr, v, out, lse, g, *, static: _MlaStatic):
                   stat, stat],
         out_specs=[_seq_spec(blk, wide, kv_map), kr_spec,
                    _seq_spec(blk, wide, kv_map)],
-        out_shape=[like(kn.shape, kn.dtype), like(kr.shape, kr.dtype),
-                   like(v.shape, v.dtype)],
+        out_shape=grads[2:],
         scratch_shapes=[_vmem((blk, wide)), _vmem((blk, dr)),
                         _vmem((blk, wide))],
         compiler_params=_mla_params(2),
@@ -992,9 +1149,13 @@ def mla_flash_static(t: int, dn: int, dr: int, scale: float | None = None,
     blk = block or _pick_block(t)
     if blk == 0 or t % blk:
         raise ValueError(f"seq len {t} not divisible into flash blocks")
+    one_bwd = _mla_bwd_fits(t, blk, dn, dr)
     static = _MlaStatic(float((dn + dr) ** -0.5 if scale is None else scale),
-                        blk, dn, dr, interpret)
+                        blk, dn, dr, interpret, one_bwd)
     tracing.note_trace(
         flash_layout="bthd", flash_lanes_per_block=static.hpb * dn,
-        flash_path="mla_multi_block", flash_causal_slabs=1)
+        flash_path="mla_multi_block", flash_causal_slabs=1,
+        flash_bwd_kernels=1 if one_bwd else 2)
+    if one_bwd:     # dq's accumulator holds the whole row in VMEM
+        tracing.note_trace(flash_bwd_resident_rows=t)
     return static

@@ -4,6 +4,9 @@ the CPU, against a masked softmax over the concatenated keys; and
 ``ops/mla.py::latent_attention``, whose backward pass may keep the
 latents and run the up-projections again."""
 
+import importlib
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +18,10 @@ from ray_tpu.ops.pallas.flash_attention import (
     mla_flash_core, mla_flash_shapes_ok, mla_flash_static,
 )
 from ray_tpu.parallel import make_mesh
+from ray_tpu.util import tracing
+
+# the package exports the function under the module's name
+fa = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
 
 B, T, H, DN, DR = 2, 384, 4, 128, 64
 
@@ -43,14 +50,22 @@ def _kernels(qn, qr, kn, kr, v, block):
     return out.reshape(b, t, h, dn)
 
 
-def _operands(seed=0, t=T):
+def _operands(seed=0, t=T, h=H):
     ks = jax.random.split(jax.random.key(seed), 6)
-    return ((jax.random.normal(ks[0], (B, t, H, DN)),
-             jax.random.normal(ks[1], (B, t, H, DR)),
-             jax.random.normal(ks[2], (B, t, H, DN)),
+    return ((jax.random.normal(ks[0], (B, t, h, DN)),
+             jax.random.normal(ks[1], (B, t, h, DR)),
+             jax.random.normal(ks[2], (B, t, h, DN)),
              jax.random.normal(ks[3], (B, t, DR)),
-             jax.random.normal(ks[4], (B, t, H, DN))),
-            jax.random.normal(ks[5], (B, t, H, DN)))
+             jax.random.normal(ks[4], (B, t, h, DN))),
+            jax.random.normal(ks[5], (B, t, h, DN)))
+
+
+@pytest.fixture
+def two_kernels(monkeypatch):
+    """No row fits: the backward pass is the dq and the dk/dv kernel.
+    The limit is the module's, not an argument: nothing selects a path
+    but the shapes."""
+    monkeypatch.setattr(fa, "_MLA_BWD_VMEM", 1 << 20)
 
 
 @pytest.mark.parametrize("block", [128, 384], ids=["three_blocks", "one"])
@@ -60,23 +75,89 @@ def test_kernel_forward_is_the_masked_softmax_over_concatenated_keys(block):
     np.testing.assert_allclose(got, _concatenated(*ops), atol=2e-5)
 
 
-@pytest.mark.parametrize("name, arg", [
-    ("dq_nope", 0), ("dq_rope", 1), ("dk_nope", 2), ("dk_rope", 3),
-    ("dv", 4)])
-def test_kernel_backward_gives_each_of_the_five_gradients(name, arg):
-    """``dk_rope`` is the sum over the heads (and, in the kernel, over
-    the head pairs of a key block: carried in scratch across the grid's
-    third dimension)."""
+FIVE = [("dq_nope", 0), ("dq_rope", 1), ("dk_nope", 2), ("dk_rope", 3),
+        ("dv", 4)]
+
+
+def _gradient(fn, ops, w, arg):
+    return jax.grad(lambda *a: (fn(*a) * w).sum(), argnums=arg)(*ops)
+
+
+def _holds_to_the_reference(name, arg, block, kernels):
     ops, w = _operands(1)
-
-    def through(fn):
-        return jax.grad(lambda *a: (fn(*a) * w).sum(), argnums=arg)(*ops)
-
-    got = through(lambda *a: _kernels(*a, block=128))
-    want = through(_concatenated)
+    got = _gradient(lambda *a: _kernels(*a, block=block), ops, w, arg)
+    want = _gradient(_concatenated, ops, w, arg)
+    assert mla_flash_static(T, DN, DR, block=block).one_bwd == (kernels == 1)
     assert got.shape == ops[arg].shape
     np.testing.assert_allclose(got, want, atol=3e-5 * float(
         jnp.abs(want).max()) + 1e-6, err_msg=name)
+
+
+@pytest.mark.parametrize("block", [384, 192, 128],
+                         ids=["one_block", "two_blocks", "three_blocks"])
+@pytest.mark.parametrize("name, arg", FIVE)
+def test_kernel_backward_gives_each_of_the_five_gradients(name, arg, block):
+    """The one-kernel backward pass: where only the diagonal block is
+    live (one block), where one block lies under it, and three blocks.
+    ``dq`` is carried in scratch across the key blocks and leaves at its
+    diagonal; ``dk_rope`` is the sum over the heads and, in the kernel,
+    over the head pairs: carried across the grid's second dimension."""
+    _holds_to_the_reference(name, arg, block, kernels=1)
+
+
+@pytest.mark.parametrize("name, arg", FIVE)
+def test_two_kernel_backward_gives_each_of_the_five_gradients(
+        name, arg, two_kernels):
+    """The path of rows too long for the one kernel's accumulators."""
+    _holds_to_the_reference(name, arg, 128, kernels=2)
+
+
+@pytest.mark.parametrize("name, arg", FIVE)
+def test_one_kernel_gives_the_two_kernels_gradients_bit_for_bit(
+        name, arg, monkeypatch):
+    """The same arithmetic in the same order: eight heads, so ``dk_r``'s
+    sum runs over four head pairs, two rows of the batch, two blocks."""
+    ops, w = _operands(5, t=256, h=8)
+
+    def through():
+        return _gradient(lambda *a: _kernels(*a, block=128), ops, w, arg)
+
+    one = through()
+    monkeypatch.setattr(fa, "_MLA_BWD_VMEM", 1 << 20)
+    assert not mla_flash_static(256, DN, DR, block=128).one_bwd
+    np.testing.assert_array_equal(one, through(), err_msg=name)
+
+
+MiB = 1 << 20
+
+
+@pytest.mark.parametrize("t, dn, dr, limit, kernels", [
+    (8192, 128, 64, None, 1),           # the cell
+    (32768, 128, 64, None, 1),          # 64 MiB of accumulators
+    (65536, 128, 64, None, 2),
+    (8192, 128, 64, 64 * MiB, 1),
+    (32768, 128, 64, 64 * MiB, 2),      # the limit decides
+    (16384, 256, 64, None, 1),
+    (32768, 256, 64, None, 2),          # the widths decide
+    (384, 128, 64, 1 * MiB, 2),
+], ids=lambda v: str(v))
+def test_the_backward_path_is_decided_from_rows_widths_and_the_limit(
+        t, dn, dr, limit, kernels, monkeypatch):
+    """``mla_flash_static`` notes how many kernels the backward pass is
+    and, where it is one, how many rows of ``dq`` stay in VMEM; it takes
+    no argument that could choose."""
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    if limit is not None:
+        monkeypatch.setattr(fa, "_MLA_BWD_VMEM", limit)
+    static = mla_flash_static(t, dn, dr)
+    assert static.one_bwd == (kernels == 1)
+    assert notes["flash_bwd_kernels"] == kernels
+    assert notes.get("flash_bwd_resident_rows") == (
+        t if kernels == 1 else None)
+    assert notes["flash_path"] == "mla_multi_block"
+    assert list(inspect.signature(mla_flash_static).parameters) == [
+        "t", "dn", "dr", "scale", "block", "interpret"]
 
 
 def test_the_shapes_the_kernels_tile():
@@ -189,9 +270,14 @@ def test_the_path_is_decided_from_backend_shapes_and_mesh(monkeypatch):
             mla.mla_path(*cell, mesh=mesh)
 
 
-def test_the_kernels_under_a_shard_map_over_dp_are_the_bare_ones():
+@pytest.mark.parametrize("limit", [None, 1 << 20],
+                         ids=["one_backward_kernel", "two"])
+def test_the_kernels_under_a_shard_map_over_dp_are_the_bare_ones(
+        limit, monkeypatch):
     """Two rows over ``dp`` = 2, kernels interpreted on each device's
     row, against the unsharded call: values and gradients."""
+    if limit is not None:
+        monkeypatch.setattr(fa, "_MLA_BWD_VMEM", limit)
     mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
     c_q, c_kv, k_r, up, w = _latents(3)
     angles = rope_freqs(DR, 256, 10000.0)

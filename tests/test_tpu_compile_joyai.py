@@ -40,30 +40,53 @@ def _on_one_chip(monkeypatch):
     monkeypatch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
 
 
-def test_latent_attention_kernels_compile_for_v5e_at_the_cells_shape(v5e):
-    """One sequence of 8,192 tokens, 32 heads of 128 + 64 against values
-    of 128, in bfloat16, forward and backward: three custom calls, no
-    ``[T, T]`` array and no operand 256 wide (the rotary key is the one
-    ``[1, 8192, 64]`` array it is), temporaries a fraction of a GB."""
+def _compiled_kernels(device, t, heads=32):
+    """Forward and backward of the latent-attention kernels at ``t``
+    rows, compiled for ``device``; with them the notes of the path."""
     from ray_tpu.ops.pallas.flash_attention import (
         mla_flash_core, mla_flash_static)
-    arg = _arg(v5e[0])
-    static = mla_flash_static(8192, 128, 64)
+    arg = _arg(device)
+    static = mla_flash_static(t, 128, 64)
 
     def loss(*operands):
         return mla_flash_core(*operands, static).astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        arg((1, 8192, 32 * 128), jnp.bfloat16),
-        arg((1, 8192, 32 * 64), jnp.bfloat16),
-        arg((1, 8192, 32 * 128), jnp.bfloat16),
-        arg((1, 8192, 64), jnp.bfloat16),
-        arg((1, 8192, 32 * 128), jnp.bfloat16)).compile()
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3
+        arg((1, t, heads * 128), jnp.bfloat16),
+        arg((1, t, heads * 64), jnp.bfloat16),
+        arg((1, t, heads * 128), jnp.bfloat16),
+        arg((1, t, 64), jnp.bfloat16),
+        arg((1, t, heads * 128), jnp.bfloat16)).compile()
+    return static, compiled, compiled.as_text()
+
+
+def test_latent_attention_kernels_compile_for_v5e_at_the_cells_shape(v5e):
+    """One sequence of 8,192 tokens, 32 heads of 128 + 64 against values
+    of 128, in bfloat16, forward and backward: **two** custom calls (the
+    backward pass is one kernel, and the compile succeeding is the proof
+    that its accumulators fit the VMEM limit it asks for), no ``[T, T]``
+    array and no operand 256 wide (the rotary key is the one ``[1, 8192,
+    64]`` array it is), temporaries a fraction of a GB."""
+    static, compiled, text = _compiled_kernels(v5e[0], 8192)
+    assert static.one_bwd
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "8192,8192" not in text
     assert "8192,32,256" not in text and "8192,32,192" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+@pytest.mark.parametrize("t, heads, kernels", [(32768, 8, 1), (65536, 4, 2)],
+                         ids=["the_longest_row_that_fits", "one_too_long"])
+def test_the_backward_path_compiles_where_its_accumulators_fit_and_not(
+        v5e, t, heads, kernels):
+    """``_mla_bwd_fits`` counts from the shapes; the compiler has the last
+    word. 32,768 rows (64 MiB of resident accumulators under the 100 MiB
+    the kernel asks for) compile as one backward kernel; 65,536 take the
+    pair, which holds a block's rows whatever the row's length."""
+    static, _, text = _compiled_kernels(v5e[0], t, heads)
+    assert static.one_bwd == (kernels == 1)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 + kernels
+    assert f"{t},{t}" not in text
 
 
 def test_the_real_size_step_compiles_inside_the_chips_memory(
@@ -73,8 +96,8 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     rows of the vocabulary; adamw with a bf16 first moment) at 8,192
     tokens: arguments + temporaries + unaliased outputs fit the v5e's
     15.75 GB with the room the acceptance asks for, every layer's
-    attention is the kernel (6 x 3 custom calls beside the experts'),
-    and no ``[T, T]`` array exists."""
+    attention is the kernel (a forward and ONE backward custom call a
+    layer beside the experts'), and no ``[T, T]`` array exists."""
     import optax
 
     from ray_tpu import train
@@ -102,6 +125,8 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert notes["flash_path"] == "mla_multi_block"
     assert notes["flash_layout"] == "bthd"
     assert notes["mla_saved"] == "latents"
+    assert notes["flash_bwd_kernels"] == 1
+    assert notes["flash_bwd_resident_rows"] == 8192
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.temp_size_in_bytes
              + max(0, m.output_size_in_bytes - m.alias_size_in_bytes))
@@ -110,6 +135,9 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert total < 15.2e9
     text = compiled.as_text()
     assert text.count("mla_flash_fwd") >= 6
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("mla_flash_bwd" in line for line in calls) == 6
     assert "8192,8192" not in text
 
 
@@ -170,7 +198,7 @@ def test_layers_at_one_shape_trace_each_kernel_once(monkeypatch):
     """What holds ``setup_s`` (PERF.md section 6, PR 28): the two
     functions that hold the ``pallas_call``s are jitted, so a step's
     three blocks trace each kernel's body once and lower to one function
-    a pass, called three times. Interpreted, on the CPU: counting traces
+    a pass (the backward pass one kernel), called three times. Interpreted, on the CPU: counting traces
     needs nothing of the chip."""
     from ray_tpu.models.llama import rope_freqs
     from ray_tpu.ops import mla
@@ -202,5 +230,5 @@ def test_layers_at_one_shape_trace_each_kernel_once(monkeypatch):
     assert text.count("call @mla_flash_fwd") == 3
     assert text.count("call @mla_flash_bwd") == 3
     # one head pair a cell, two heads a pair; each body branches on the
-    # diagonal: forward 2 x 2, dq 2 x 2, dk/dv 2 x 2 score products
-    assert len(bodies) == 12
+    # diagonal: forward 2 x 2, backward 2 x 2 score products
+    assert len(bodies) == 8
