@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import atexit
 import threading
+import time
 from typing import Any, Sequence
 
 from ray_tpu.core.actor import ActorClass, ActorHandle
@@ -115,6 +116,7 @@ def init(num_cpus: int | None = None,
     same test pattern as the reference's conftest injection.
     """
     global _runtime
+    t_call = time.monotonic()
     with _runtime_lock:
         if _runtime is not None:
             if ignore_reinit_error:
@@ -168,6 +170,7 @@ def init(num_cpus: int | None = None,
             if runtime_env:
                 _runtime.default_runtime_env = dict(runtime_env)
             atexit.register(_shutdown_at_exit)
+            _record_init(t_call, address, _runtime)
             return _runtime
         # Reference-signature compat kwargs with REAL mappings (driver
         # path only — address-mode rejects them above). Conflicts with
@@ -209,7 +212,18 @@ def init(num_cpus: int | None = None,
                 port=dashboard_port
                 if dashboard_port is not None else 8265)
         atexit.register(_shutdown_at_exit)
+        _record_init(t_call, "local", _runtime)
         return _runtime
+
+
+def _record_init(t_call: float, address: str, runtime) -> None:
+    """``core.init``: ``init()`` from call to return, in the process
+    ring (docs/observability.md). A fit writes the newest beside its own
+    spans: where a job's cold start begins."""
+    from ray_tpu.util import tracing
+    tracing.record_train_span(
+        "core.init", t_call, time.monotonic(),
+        {"address": address, "nodes": len(runtime.nodes())})
 
 
 def _resolve_address(address: str) -> str:

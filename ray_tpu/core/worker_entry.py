@@ -9,11 +9,19 @@ multiprocessing-spawn hazard) and carry no inherited interpreter state.
 
 from __future__ import annotations
 
-import sys
+import time
+
+T_FIRST_LINE = time.monotonic()     # before this module imports anything else
+
+import sys  # noqa: E402
 
 
 def main() -> None:
     import os
+
+    # Where a ``TrainWorker``'s ``train.worker.process`` span begins.
+    from ray_tpu.util import tracing
+    tracing.process_start = T_FIRST_LINE
 
     # The runtime set JAX_PLATFORMS for this worker (cpu without a TPU
     # resource, tpu with one). jax reads it at import; where something

@@ -10,6 +10,7 @@ from __future__ import annotations
 import os
 import subprocess
 import tempfile
+import time
 
 _SRC_DIR = os.path.dirname(os.path.abspath(__file__))
 _BUILD_DIR = os.path.join(_SRC_DIR, "_build")
@@ -35,6 +36,18 @@ def ensure_built() -> str | None:
     """Returns the library path, building if needed; None on failure."""
     if not _needs_build():
         return lib_path()
+    t0 = time.monotonic()
+    built = _build()
+    # Only a process that compiles records it (the first run of a
+    # tree): ``native.build`` in the process ring, which a fit writes
+    # beside its own spans (docs/observability.md).
+    from ray_tpu.util import tracing
+    tracing.record_train_span("native.build", t0, time.monotonic(),
+                              {"built": built is not None})
+    return built
+
+
+def _build() -> str | None:
     os.makedirs(_BUILD_DIR, exist_ok=True)
     srcs = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=_BUILD_DIR)

@@ -27,6 +27,8 @@ import threading
 import time
 from typing import Any, Callable, Iterable, Iterator
 
+from ray_tpu.train import session
+from ray_tpu.util import tracing
 from ray_tpu.util.tracing import annotation
 
 __all__ = ["DevicePrefetcher", "prefetch_to_device", "collect_counters"]
@@ -83,7 +85,9 @@ class DevicePrefetcher:
     producer's seconds in ``next(source)`` and in ``place``. Under a
     device profile the same three are the spans ``train.input.wait``
     (consumer thread), ``train.input.source`` and ``train.input.place``
-    (this prefetcher's thread).
+    (this prefetcher's thread). The first batch alone is a span kept
+    in Python, ``train.input.first_batch``: from this constructor to
+    the batch's hand-over, with that batch's three times.
     """
 
     def __init__(self, source: Iterable | Iterator,
@@ -101,6 +105,10 @@ class DevicePrefetcher:
                          "place_s": 0.0}
         if _collected is not None:
             _collected.append(self.counters)
+        # Until the first batch is handed over: when this was made. From
+        # the producer: that batch's seconds in source and in place.
+        self._t_made: float | None = time.monotonic()
+        self._first_s = (0.0, 0.0)
         self._thread = threading.Thread(
             target=self._run, daemon=True, name="device_prefetch")
         self._thread.start()
@@ -109,6 +117,7 @@ class DevicePrefetcher:
 
     def _run(self) -> None:
         counters = self.counters
+        first = True
         try:
             while not self._stop.is_set():
                 t0 = time.perf_counter()
@@ -123,6 +132,9 @@ class DevicePrefetcher:
                     with annotation("train.input.place"):
                         batch = self._place(batch)
                     counters["place_s"] += time.perf_counter() - t1
+                if first:       # nothing was placed before it
+                    first = False
+                    self._first_s = (t1 - t0, counters["place_s"])
                 # Bounded put, polling the stop flag so close() never
                 # deadlocks against a full queue.
                 while not self._stop.is_set():
@@ -157,7 +169,22 @@ class DevicePrefetcher:
                 raise err
             raise StopIteration
         self.counters["batches"] += 1
+        if self._t_made is not None:
+            self._record_first_batch()
         return item
+
+    def _record_first_batch(self) -> None:
+        """``train.input.first_batch``, once. Inside a fit the session
+        keeps its ends and the worker records it under
+        ``train.worker.loop``; outside it goes to the process ring,
+        under the train-path span open in the consumer's thread."""
+        t_made, self._t_made = self._t_made, None
+        source_s, place_s = self._first_s
+        ends = (t_made, time.monotonic(),
+                {"source_s": source_s, "place_s": place_s,
+                 "stall_s": self.counters["stall_s"]})
+        if not session.hold_first_batch(ends):
+            tracing.record_train_span("train.input.first_batch", *ends)
 
     def close(self) -> None:
         """Stop the producer and release queued batches."""

@@ -74,6 +74,13 @@ class _TrainSession:
         # step counter, both shipped to the head by the train
         # worker's metrics exporter.
         self._last_report_ts: float | None = None
+        # time.monotonic() at the first report(): where the
+        # ``train.worker.loop`` span's ``first_report_s`` ends.
+        self.t_first_report: float | None = None
+        # (start, end, attributes) of each prefetcher's
+        # ``train.input.first_batch``, recorded by the worker when the
+        # loop returns.
+        self.first_batches: list[tuple] = []
         from ray_tpu.util.metrics import Counter, Histogram
         tags = {"rank": str(context.world_rank)}
         self._m_step_time = Histogram(
@@ -94,6 +101,8 @@ class _TrainSession:
             now = time.monotonic()
             if self._last_report_ts is not None:
                 self._m_step_time.observe(now - self._last_report_ts)
+            else:
+                self.t_first_report = now
             self._last_report_ts = now
             self._m_steps.inc()
             ckpt_dir = None
@@ -126,6 +135,17 @@ def trace_target() -> dict:
     if _session is None:
         return {}
     return {"parent": _session.trace_ctx, "sink": _session.spans}
+
+
+def hold_first_batch(ends: tuple) -> bool:
+    """Inside a fit, keep the ends of a prefetcher's
+    ``train.input.first_batch`` for the worker, which records the span
+    under ``train.worker.loop`` when the loop returns: the loop's
+    thread makes no span. False outside a fit."""
+    if _session is None:
+        return False
+    _session.first_batches.append(ends)
+    return True
 
 
 def shutdown_session() -> None:

@@ -20,6 +20,8 @@ CHECKPOINT_FILE = "estimator.pkl"
 
 
 class SklearnTrainer(JaxTrainer):
+    _opens_jax_backend = False
+
     def __init__(self, *, estimator: Any, datasets: dict,
                  label_column: str,
                  scoring: str | None = None,

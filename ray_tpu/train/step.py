@@ -91,6 +91,9 @@ _COMPILE_KINDS = {
     "/jax/core/compile/backend_compile_duration": "backend",
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
 }
+# Told just before a ``cache_load``: the original compile's seconds less
+# this load's.
+_SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
 _CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
                  "/jax/compilation_cache/cache_misses": "miss"}
 _thread = threading.local()     # depth of open phases; cache outcome
@@ -114,12 +117,18 @@ def _on_compile_begins(event: str, _start: float, **_) -> None:
 def _on_compile_seconds(event: str, seconds: float, **kw) -> None:
     kind = _COMPILE_KINDS.get(event)
     if kind is None:
+        if event == _SAVED_EVENT:
+            _thread.saved = seconds
         return
     now = time.monotonic()
     if kind == "cache_load":
         # jax does not say whose load it is: the backend phase that
-        # holds it does, when it ends.
-        _thread.load = (now - seconds, now)
+        # holds it does, when it ends. ``compiled_in_s``: what the
+        # compile took when the entry was written (whole seconds, as
+        # the cache keeps it), to hold against the load: a load may
+        # cost more than its compile.
+        _thread.load = (now - seconds, now,
+                        (_take("saved") or 0.0) + seconds)
         return
     # Every jitted function called while another is traced or lowered
     # (the jnp functions) reports a trace of its own: the outermost
@@ -138,9 +147,11 @@ def _on_compile_seconds(event: str, seconds: float, **kw) -> None:
         if outcome:
             attributes["cache"] = outcome
         if load:
+            start, end, compiled_in_s = load
             tracing.record_train_span(
-                "train.compile", *load,
-                {**attributes, "kind": "cache_load"}, **target)
+                "train.compile", start, end,
+                {**attributes, "kind": "cache_load",
+                 "compiled_in_s": compiled_in_s}, **target)
     tracing.record_train_span("train.compile", now - seconds, now,
                               attributes, **target)
 

@@ -28,6 +28,7 @@ class TorchTrainer(JaxTrainer):
 
     _backend_setup = "setup_torch_distributed"
     _setup_single_worker = True
+    _opens_jax_backend = False
 
     def __init__(self, *args, torch_config=None, **kwargs):
         if torch_config is not None and not isinstance(torch_config,

@@ -68,12 +68,24 @@ class JaxTrainer:
     — or ``train.prefetch_to_device`` for a custom source — so host
     input staging overlaps device compute instead of serializing with
     it. ``DataContext.prefetch_batches`` is the overlap depth.
+
+    The loop finds jax started: each worker has joined
+    ``jax.distributed`` (a gang of more than one) and opened the
+    accelerator backend (the span ``train.worker.backend_init``) before
+    the loop is called. ``jax.config`` options read at use are set in
+    the loop as anywhere; what jax reads when the backend opens
+    (``XLA_FLAGS``, ``jax_num_cpu_devices``) comes with the worker's
+    environment, ``init(runtime_env={"env_vars": ...})``
+    (docs/QUICKSTART.md).
     """
 
     # Backend hook: which TrainWorker method builds the collective
     # group (jax.distributed here; torch gloo in train.torch).
     _backend_setup = "setup_distributed"
     _setup_single_worker = False
+    # Whether each worker opens jax's backend ahead of the user's loop,
+    # under the span ``train.worker.backend_init``.
+    _opens_jax_backend = True
 
     def __init__(self,
                  train_loop_per_worker: Callable,
@@ -127,12 +139,15 @@ class JaxTrainer:
                 "workers": self.scaling.num_workers,
                 "chips": self.scaling.num_workers
                 * self.scaling.worker_resources().get("TPU", 0)},
-                sink=spans):
+                sink=spans) as root:
             result = self._fit_with_restarts(trial_dir)
-        result.spans = [s.to_dict() for s in spans]
         # Into the process ring, so that tracing.get_spans() holds the
         # fit after it returns and after ray_tpu.shutdown().
-        tracing.get_tracer().add_spans(result.spans)
+        tracing.get_tracer().add_spans([s.to_dict() for s in spans])
+        # The whole cold start: what the ring holds of this process
+        # before the fit (``core.init``, ``native.build``), then the fit.
+        spans = tracing.process_spans(root.mono_start) + spans
+        result.spans = [s.to_dict() for s in spans]
         with open(os.path.join(trial_dir, "fit_trace.json"), "w") as f:
             json.dump(tracing.chrome_events(spans), f)
         return self._mirror(trial_dir, remote_uri, result)
@@ -221,7 +236,8 @@ class JaxTrainer:
 
         group = None
         try:
-            # Placement group, actor creation, worker boot and imports.
+            # Placement group, actor creation, worker boot and imports,
+            # barrier: ``WorkerGroup`` records the two parts.
             with phase("gang_start"):
                 try:
                     # A gang that is not placed in time is a
@@ -233,10 +249,10 @@ class JaxTrainer:
                             self.scaling.worker_resources()),
                         placement_strategy=(
                             self.scaling.placement_strategy),
+                        spans=spans,
                     )
                 except TimeoutError as e:
                     raise _WorkerGroupError(str(e), latest_ckpt) from e
-                group.barrier()
             if self.scaling.num_workers > 1 or self._setup_single_worker:
                 with phase("backend_setup"):
                     # Rank 0 advertises the rendezvous point from its
@@ -260,6 +276,7 @@ class JaxTrainer:
                     "restored_checkpoint_dir": restored,
                     # the workers' spans go under the fit's root
                     "trace_ctx": (span.trace_id, span.parent_id),
+                    "open_backend": self._opens_jax_backend,
                 }
                 if self.datasets:
                     # DataConfig.datasets_to_split: "all" or a list of

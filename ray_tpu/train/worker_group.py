@@ -106,6 +106,9 @@ class TrainWorker:
                   if shards_all else {})
         # The fit's trace: (trace id, the driver's ``train.fit`` span).
         trace_ctx = context_kwargs.pop("trace_ctx", None)
+        # A JaxTrainer's worker opens the accelerator backend itself,
+        # before the user's loop.
+        open_backend = context_kwargs.pop("open_backend", False)
         ctx = TrainContext(world_rank=self.rank,
                            world_size=self.world_size,
                            local_rank=self.rank,
@@ -114,6 +117,15 @@ class TrainWorker:
                            **context_kwargs)
         session = self._session = init_session(ctx, trace_ctx)
         target = {"parent": trace_ctx, "sink": session.spans}
+        if tracing.process_start is not None:
+            # Interpreter, imports, the dial back and the actor's
+            # construction (in a process taken warm from the pool, its
+            # wait there too); it ends where the boot begins.
+            import os
+            tracing.record_train_span(
+                "train.worker.process", tracing.process_start,
+                self._t_init, {"rank": self.rank, "pid": os.getpid()},
+                **target)
         tracing.record_train_span(
             "train.worker.boot", self._t_init, time.monotonic(),
             {"rank": self.rank}, **target)
@@ -125,12 +137,24 @@ class TrainWorker:
                                            {"rank": self.rank},
                                            **target) as span:
                     try:
+                        if open_backend:
+                            _open_backend(session.spans)
                         if _takes_config(fn):
                             fn(loop_config or {})
                         else:
                             fn()
                     finally:
                         span.attributes.update(input_totals())
+                        # what the loop's thread told the session and
+                        # made no span of: each prefetcher's first
+                        # batch, the first report
+                        for ends in session.first_batches:
+                            tracing.record_train_span(
+                                "train.input.first_batch", *ends,
+                                sink=session.spans)
+                        if session.t_first_report is not None:
+                            span.attributes["first_report_s"] = (
+                                session.t_first_report - span.mono_start)
             except BaseException:  # noqa: BLE001
                 self._error = traceback.format_exc()
             finally:
@@ -208,6 +232,30 @@ def _routable_ip() -> str:
         return "127.0.0.1"
 
 
+def _open_backend(spans: list) -> None:
+    """Open the accelerator backend (6-15 s on a TPU) in the loop's
+    thread, ahead of the user's loop: its first ``jax.devices()`` is
+    then a lookup, and the seconds are the program's own span (under
+    ``train.worker.loop``, in the worker's list ``spans``) and not the
+    user's code. So the loop finds jax started (``JaxTrainer``'s
+    docstring): what jax reads when the backend opens comes with the
+    worker's environment, not from the loop. A backend that does not
+    open (a worker pinned to a platform that is not there) leaves
+    ``error`` on the span and nothing else: the loop's own first use
+    of jax raises as it always did, and a loop that never touches jax
+    runs."""
+    try:
+        with tracing.train_span("train.worker.backend_init",
+                                sink=spans) as span:
+            import jax
+            devices = jax.devices()
+            span.attributes.update(platform=devices[0].platform,
+                                   device_kind=devices[0].device_kind,
+                                   devices=len(devices))
+    except RuntimeError:
+        pass
+
+
 def _takes_config(fn: Callable) -> bool:
     import inspect
     try:
@@ -221,31 +269,48 @@ class WorkerGroup:
     def __init__(self, num_workers: int,
                  resources_per_worker: dict[str, float],
                  placement_strategy: str = "STRICT_PACK",
-                 env_vars: dict | None = None):
+                 env_vars: dict | None = None,
+                 spans: list | None = None):
+        """The gang, placed, created and answering: two train-path
+        spans, kept in ``spans`` (the fit's list), else in the process
+        ring."""
         self.num_workers = num_workers
+        self.workers: list = []
         bundles = [dict(resources_per_worker) for _ in range(num_workers)]
-        # The group is always created, also where no node can place it
-        # yet: an unplaced bundle is the demand an autoscaler reads, and
-        # a slice that was drained may be on its way back.
-        self.pg = ray_tpu.placement_group(bundles,
-                                          strategy=placement_strategy)
-        if not self.pg.ready(timeout=120):
-            ray_tpu.remove_placement_group(self.pg)
-            raise TimeoutError(
-                f"placement group {bundles} ({placement_strategy}) was "
-                f"not placed within 120 s; available_resources() = "
-                f"{ray_tpu.available_resources()}")
+        with tracing.train_span("train.fit.gang_start.placement", {
+                "bundles": len(bundles), "strategy": placement_strategy},
+                sink=spans):
+            # The group is always created, also where no node can place
+            # it yet: an unplaced bundle is the demand an autoscaler
+            # reads, and a slice that was drained may be on its way
+            # back.
+            self.pg = ray_tpu.placement_group(
+                bundles, strategy=placement_strategy)
+            if not self.pg.ready(timeout=120):
+                ray_tpu.remove_placement_group(self.pg)
+                raise TimeoutError(
+                    f"placement group {bundles} ({placement_strategy}) "
+                    f"was not placed within 120 s; available_resources()"
+                    f" = {ray_tpu.available_resources()}")
         strategy = PlacementGroupSchedulingStrategy(self.pg)
-        self.workers = [
-            TrainWorker.options(
-                num_cpus=resources_per_worker.get("CPU", 1),
-                num_tpus=resources_per_worker.get("TPU", 0) or None,
-                resources={k: v for k, v in resources_per_worker.items()
-                           if k not in ("CPU", "TPU")},
-                scheduling_strategy=strategy,
-            ).remote(rank, num_workers, env_vars or {})
-            for rank in range(num_workers)
-        ]
+        # Actor creation, each worker process's start and imports,
+        # ``TrainWorker.__init__``, and the barrier that waits for all.
+        with tracing.train_span("train.fit.gang_start.actors",
+                                {"workers": num_workers}, sink=spans):
+            try:
+                for rank in range(num_workers):
+                    self.workers.append(TrainWorker.options(
+                        num_cpus=resources_per_worker.get("CPU", 1),
+                        num_tpus=resources_per_worker.get("TPU", 0) or None,
+                        resources={
+                            k: v for k, v in resources_per_worker.items()
+                            if k not in ("CPU", "TPU")},
+                        scheduling_strategy=strategy,
+                    ).remote(rank, num_workers, env_vars or {}))
+                self.barrier()
+            except BaseException:
+                self.shutdown()     # nobody else holds the group yet
+                raise
 
     def barrier(self, timeout: float = 120.0) -> None:
         ray_tpu.get([w.ping.remote() for w in self.workers],

@@ -15,11 +15,13 @@ Device profiling: ``profile_device()`` wraps ``jax.profiler.trace``
 
 The train path (``train_span``, ``record_train_span``, ``annotation``;
 docs/observability.md) needs no ``enable()``: the dozen phases of one
-``fit()`` are always recorded, on two clocks. ``time.monotonic()`` is
-the clock every process of one host shares, the one a benchmark's own
-stamps are on; a ``jax.profiler.TraceAnnotation`` of the same name puts
-the span on the host plane of a running device profile, on the
-profiler's clock beside the device operations. What happens every step
+``fit()``, and what the process did before it (``core.init``,
+``native.build``), are always recorded. ``time.monotonic()`` is the
+clock every process of one host shares, the one a benchmark's own
+stamps are on; a span that may be open during a device profile
+(``train_span``) is also a ``jax.profiler.TraceAnnotation`` of the same
+name, on the host plane of the profile, on the profiler's clock beside
+the device operations. What happens every step
 is an ``annotation`` alone: nothing is kept unless a profile runs.
 """
 
@@ -294,6 +296,11 @@ def annotation(name: str):
     return _TraceAnnotation(name)
 
 
+# ``time.monotonic()`` at the first line of this process's entry module,
+# where that module told it (``core/worker_entry.py``): the start of a
+# worker's ``train.worker.process`` span.
+process_start: float | None = None
+
 # The train-path span open in this thread: what the next one nests
 # under (``_current`` may hold a task's or a request's span instead).
 _train_current: contextvars.ContextVar = contextvars.ContextVar(
@@ -364,6 +371,24 @@ def record_train_span(name: str, mono_start: float, mono_end: float,
     return s
 
 
+# What a process records of itself before any fit, each the root of a
+# trace of its own.
+PROCESS_SPANS = ("core.init", "native.build")
+
+
+def process_spans(before: float) -> list[Span]:
+    """What this process did before a fit began: of the ring's
+    ``PROCESS_SPANS`` that ended by ``before`` (``time.monotonic()``),
+    the newest of each name. A fit writes them beside its own spans, so
+    that one file holds a cold start from ``init()`` on."""
+    newest: dict[str, Span] = {}
+    for s in _tracer.get_spans():
+        if (s.name in PROCESS_SPANS and s.parent_id is None
+                and 0.0 < s.mono_end <= before):
+            newest[s.name] = s      # the ring is in order of arrival
+    return sorted(newest.values(), key=lambda s: s.mono_start)
+
+
 # What code that runs while jax traces a program has to say about
 # the program it is building, by thread: ``train/step.py`` clears it
 # when the thread's outermost trace begins and puts it on that trace's
@@ -385,17 +410,32 @@ def take_trace_notes() -> dict:
 
 
 def self_seconds(spans: list[Span]) -> dict[str, float]:
-    """span id -> the span's time less what its direct children
-    cover (their union, clipped to the span), on the monotonic
-    clock."""
-    children: dict[str, list[Span]] = {}
+    """span id -> the span's own seconds on the monotonic clock: its
+    time less what its direct children cover, and less what siblings
+    of its own process that began after it cover of it (all as one
+    union, clipped to the span). Where two siblings overlap (a cache
+    load inside its ``backend`` compile; the first batch, whose
+    consumer waits while the producer's thread compiles) the seconds
+    under both are the later one's alone, so a process's self times
+    sum to no more than its wall."""
+    children: dict[str | None, list[Span]] = {}
     for s in spans:
         children.setdefault(s.parent_id, []).append(s)
+    place = {}          # span id -> where it stands among its siblings
+    for group in children.values():
+        group.sort(key=lambda c: c.mono_start)
+        place.update((c.span_id, i) for i, c in enumerate(group))
     out = {}
     for s in spans:
+        over = list(children.get(s.span_id, ()))
+        if s.parent_id is not None:
+            for c in children[s.parent_id][place[s.span_id] + 1:]:
+                if c.mono_start >= s.mono_end:
+                    break
+                if c.process == s.process:
+                    over.append(c)
         covered, at = 0.0, s.mono_start
-        for c in sorted(children.get(s.span_id, ()),
-                        key=lambda c: c.mono_start):
+        for c in sorted(over, key=lambda c: c.mono_start):
             a, b = max(c.mono_start, at), min(c.mono_end, s.mono_end)
             if b > a:
                 covered, at = covered + (b - a), b

@@ -193,12 +193,22 @@ PHASES = ["train.fit", "train.fit.gang_start", "train.fit.start_loop",
           "train.worker.loop"]
 
 
+def _own(result) -> list[dict]:
+    """The fit's own spans: ``Result.spans`` less what the process did
+    before it (``core.init``, ``native.build``, each a root of its
+    own)."""
+    root = next(s for s in result.spans if s["name"] == "train.fit")
+    return [s for s in result.spans if s["trace_id"] == root["trace_id"]]
+
+
 def test_fit_result_holds_every_phase_under_one_trace(fit):
     names = [s["name"] for s in fit.spans]
     for phase in PHASES:
         assert names.count(phase) == 1, (phase, names)
     assert "train.fit.backend_setup" not in names      # one worker: not run
-    assert len({s["trace_id"] for s in fit.spans}) == 1
+    outside = [s for s in fit.spans if s not in _own(fit)]
+    assert {s["name"] for s in outside} <= {"core.init", "native.build"}
+    assert all(s["parent_id"] is None for s in outside)
     assert len({s["process"] for s in fit.spans}) == 2  # driver and worker
     root = next(s for s in fit.spans if s["name"] == "train.fit")
     assert root["parent_id"] is None
@@ -233,7 +243,7 @@ def test_fit_spans_nest_on_the_monotonic_clock(fit):
 
 
 def test_fit_self_times_sum_to_no_more_than_the_fit(fit):
-    spans = [tracing.Span(**s) for s in fit.spans]
+    spans = [tracing.Span(**s) for s in _own(fit)]
     self_s = tracing.self_seconds(spans)
     root = next(s for s in spans if s.name == "train.fit")
     assert all(v >= -1e-9 for v in self_s.values())
@@ -270,9 +280,305 @@ def test_fit_trace_json_is_written_in_chrome_form(fit):
 
 
 def test_get_spans_holds_the_fit_after_shutdown(fit):
-    held = tracing.get_spans(fit.spans[0]["trace_id"])
+    own = _own(fit)
+    held = tracing.get_spans(own[0]["trace_id"])
     assert sorted(s.span_id for s in held) == sorted(
-        s["span_id"] for s in fit.spans)
+        s["span_id"] for s in own)
+    # and what came before the fit, each once, under its own trace
+    ring = [s.span_id for s in tracing.get_spans()]
+    for s in fit.spans:
+        assert ring.count(s["span_id"]) == 1, s["name"]
+
+
+# -- the set-up timeline: every second before the first report ------------
+
+SETUP_SPANS = ["train.fit.gang_start.placement",
+               "train.fit.gang_start.actors", "train.worker.process",
+               "train.worker.backend_init"]
+
+
+@pytest.mark.parametrize("name, parent, attributes", [
+    ("train.fit.gang_start.placement", "train.fit.gang_start",
+     {"bundles": 1, "strategy": "STRICT_PACK"}),
+    ("train.fit.gang_start.actors", "train.fit.gang_start", {"workers": 1}),
+    ("train.worker.process", "train.fit", {"rank": 0}),
+    ("train.worker.backend_init", "train.worker.loop",
+     {"platform": "cpu", "device_kind": "cpu"}),
+    ("train.input.first_batch", "train.worker.loop", {}),
+])
+def test_fit_holds_each_set_up_span_once_under_its_parent(
+        fit, name, parent, attributes):
+    own = _own(fit)
+    found = [s for s in own if s["name"] == name]
+    assert len(found) == 1, [s["name"] for s in own]
+    span, = found
+    above = next(s for s in own if s["name"] == parent)
+    assert span["parent_id"] == above["span_id"]
+    assert above["mono_start"] <= span["mono_start"] <= span["mono_end"]
+    if parent != "train.fit":
+        assert span["mono_end"] <= above["mono_end"]
+    assert attributes.items() <= span["attributes"].items()
+
+
+def test_gang_start_is_its_two_parts_and_little_else(fit):
+    by_name = {s["name"]: s for s in _own(fit)}
+    gang = by_name["train.fit.gang_start"]
+    placed = by_name["train.fit.gang_start.placement"]
+    actors = by_name["train.fit.gang_start.actors"]
+    assert gang["mono_start"] <= placed["mono_start"]
+    assert placed["mono_end"] <= actors["mono_start"]
+    assert actors["mono_end"] <= gang["mono_end"]
+    parts = sum(s["mono_end"] - s["mono_start"] for s in (placed, actors))
+    assert (gang["mono_end"] - gang["mono_start"]) - parts < 0.05
+
+
+def test_worker_process_ends_where_boot_begins(fit):
+    by_name = {s["name"]: s for s in _own(fit)}
+    process, boot = by_name["train.worker.process"], by_name[
+        "train.worker.boot"]
+    assert process["mono_end"] == boot["mono_start"]
+    assert process["process"] == boot["process"] == (
+        f"pid:{process['attributes']['pid']}")
+    # the process began after the driver asked for it, and its imports
+    # are the bulk of the actors' part
+    actors = by_name["train.fit.gang_start.actors"]
+    assert actors["mono_start"] < process["mono_start"] < process["mono_end"]
+    assert process["mono_end"] <= actors["mono_end"]
+
+
+def test_backend_opens_before_the_users_loop_touches_jax(fit):
+    by_name = {s["name"]: s for s in _own(fit)}
+    loop, opened = by_name["train.worker.loop"], by_name[
+        "train.worker.backend_init"]
+    assert opened["attributes"]["devices"] >= 1
+    first_compile = min(s["mono_start"] for s in _own(fit)
+                        if s["name"] == "train.compile")
+    assert loop["mono_start"] <= opened["mono_start"]
+    assert opened["mono_end"] <= first_compile
+
+
+def test_a_backend_that_does_not_open_is_left_to_the_users_loop(
+        monkeypatch):
+    """A worker pinned to a platform that is not there: the span tells
+    the error, and the loop's own first jax call raises as it always
+    did (tests/test_chip_smoke.py holds that end)."""
+    from ray_tpu.train import worker_group
+
+    def no_chip():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", no_chip)
+    sink: list = []
+    with tracing.train_span("train.worker.loop", sink=sink) as loop:
+        worker_group._open_backend(sink)
+    opened = sink[0]
+    assert opened.name == "train.worker.backend_init"
+    assert opened.parent_id == loop.span_id
+    assert opened.attributes == {"error": "RuntimeError"}
+
+
+def test_first_batch_span_holds_that_batchs_times(fit):
+    by_name = {s["name"]: s for s in _own(fit)}
+    first = by_name["train.input.first_batch"]
+    a = first["attributes"]
+    assert set(a) == {"source_s", "place_s", "stall_s"}
+    assert a["place_s"] > 0 and a["source_s"] >= 0
+    seconds = first["mono_end"] - first["mono_start"]
+    assert a["stall_s"] <= seconds + 1e-3
+    # that batch's times, not the four batches'
+    totals = by_name["train.worker.loop"]["attributes"]
+    assert a["place_s"] <= totals["input.place_s"]
+    assert a["stall_s"] <= totals["input.stall_s"] + 1e-9
+
+
+def test_first_report_s_is_told_on_the_loop_span(fit):
+    by_name = {s["name"]: s for s in _own(fit)}
+    loop = by_name["train.worker.loop"]
+    told = loop["attributes"]["first_report_s"]
+    assert 0 < told < loop["mono_end"] - loop["mono_start"]
+    # after the step compiled, before the poll that drained the last
+    step_traced = min(s["mono_end"] for s in _own(fit)
+                      if s["name"] == "train.compile"
+                      and s["attributes"]["fun_name"] == "step")
+    assert loop["mono_start"] + told > step_traced
+
+
+def test_first_report_is_set_once_a_session():
+    sess = train_session.init_session(train_session.TrainContext())
+    try:
+        assert sess.t_first_report is None
+        before = time.monotonic()
+        train_session.report({"i": 0})
+        first = sess.t_first_report
+        assert before <= first <= time.monotonic()
+        for i in range(1, 4):
+            train_session.report({"i": i})
+        assert sess.t_first_report == first < sess._last_report_ts
+    finally:
+        train_session.shutdown_session()
+
+
+def test_fit_holds_the_cold_start_before_it(fit):
+    """``core.init`` (and ``native.build`` where this process compiled
+    the library) ride the fit: ``Result.spans`` and ``fit_trace.json``
+    show a job from ``init()`` on."""
+    inits = [s for s in fit.spans if s["name"] == "core.init"]
+    assert len(inits) == 1      # the newest: this module ran one init
+    init, = inits
+    root = next(s for s in fit.spans if s["name"] == "train.fit")
+    assert init["parent_id"] is None
+    assert init["trace_id"] != root["trace_id"]
+    assert init["attributes"] == {"address": "local", "nodes": 1}
+    assert 0 < init["mono_start"] < init["mono_end"] <= root["mono_start"]
+    with open(os.path.join(fit.path, "fit_trace.json")) as f:
+        events = json.load(f)
+    assert [e["name"] for e in events].count("core.init") == 1
+    assert fit.spans[0]["mono_start"] == min(
+        s["mono_start"] for s in fit.spans)
+
+
+def test_process_spans_are_the_newest_of_each_name_before_the_fit(
+        monkeypatch):
+    monkeypatch.setattr(tracing, "_tracer", tracing.Tracer())
+    t = time.monotonic()
+    for name, a, b in (("core.init", t - 9, t - 8), ("core.init", t - 5, t - 4),
+                       ("native.build", t - 7, t - 6),
+                       ("core.init", t + 1, t + 2),        # after "the fit"
+                       ("train.compile", t - 3, t - 2)):   # not the process's
+        tracing.record_train_span(name, a, b)
+    with tracing.train_span("train.fit", sink=[]):
+        tracing.record_train_span("core.init", t - 1.5, t - 1.0)    # a child
+    got = tracing.process_spans(t)
+    assert [(s.name, s.mono_start) for s in got] == [
+        ("native.build", t - 7), ("core.init", t - 5)]
+
+
+def test_native_build_is_a_span_only_where_it_compiles(monkeypatch):
+    from ray_tpu.native import build
+    before = len(tracing.get_spans())
+    monkeypatch.setattr(build, "_needs_build", lambda: False)
+    assert build.ensure_built() == build.lib_path()
+    assert tracing.get_spans()[before:] == []
+    monkeypatch.setattr(build, "_needs_build", lambda: True)
+    monkeypatch.setattr(build, "_build", lambda: time.sleep(0.01))
+    assert build.ensure_built() is None
+    span, = tracing.get_spans()[before:]
+    assert span.name == "native.build" and span.parent_id is None
+    assert span.attributes == {"built": False}
+    assert span.mono_end - span.mono_start >= 0.01
+
+
+def test_a_span_without_a_sink_goes_to_the_ring_whatever_is_open_around_it():
+    """A list is handed on, never inherited: ``WorkerGroup`` is given
+    the fit's for the two parts of ``gang_start``; a span that is given
+    none nests under the one open around it and is kept in the ring."""
+    ring = len(tracing.get_spans())
+    sink: list = []
+    with tracing.train_span("train.fit.gang_start", sink=sink) as gang:
+        with tracing.train_span("train.fit.gang_start.placement",
+                                sink=sink) as placed:
+            pass
+        with tracing.train_span("train.fit.gang_start.actors") as actors:
+            pass
+        compiled = tracing.record_train_span("train.compile", 1.0, 2.0)
+    assert sink == [placed, gang]
+    assert tracing.get_spans()[ring:] == [actors, compiled]
+    assert {s.parent_id for s in (placed, actors, compiled)} == {gang.span_id}
+
+
+def test_self_seconds_count_overlapping_siblings_once():
+    """Two siblings of one process that overlap (a cache load inside its
+    ``backend`` compile; the first batch, whose consumer waits while
+    the producer's thread compiles): the seconds under both are the
+    later one's, the parent loses their union once, and a process's
+    self times sum to its wall. Another process's spans run beside."""
+    def span(name, a, b, parent, process="pid:2"):
+        return tracing.Span(name=name, trace_id="t", span_id=name,
+                            parent_id=parent, start=0.0, mono_start=a,
+                            mono_end=b, process=process)
+    spans = [span("fit", 0.0, 20.0, None, "pid:1"),
+             span("poll", 2.0, 19.0, "fit", "pid:1"),
+             span("loop", 2.0, 18.0, "fit"),
+             span("trace", 3.0, 6.0, "loop"),
+             span("first_batch", 4.0, 11.0, "loop"),
+             span("backend", 7.0, 12.0, "loop"),
+             span("cache_load", 8.0, 10.0, "loop")]
+    assert tracing.self_seconds(spans) == {
+        "fit": 3.0,             # 20 less poll and loop, side by side
+        "poll": 17.0,           # the worker's loop is not the driver's
+        "loop": 16.0 - 9.0,     # its children cover 3-12
+        "trace": 1.0,           # 3-4: the first batch began over it
+        "first_batch": 3.0,     # 4-7: then the backend compile
+        "backend": 3.0,         # 7-8 and 10-12: less its load
+        "cache_load": 2.0}
+    worker = sum(v for k, v in tracing.self_seconds(spans).items()
+                 if k not in ("fit", "poll"))
+    assert worker == 16.0
+
+
+def test_first_batch_outside_a_fit_goes_to_the_process_ring():
+    ring = len(tracing.get_spans())
+    pf = DevicePrefetcher(iter([1, 2, 3]), place=lambda b: b + 1, depth=1)
+    time.sleep(0.05)            # the producer runs ahead of the consumer
+    assert tracing.get_spans()[ring:] == []     # made, nothing handed over
+    with tracing.train_span("train.worker.loop") as loop:
+        assert next(pf) == 2
+    span, around = tracing.get_spans()[ring:]
+    assert list(pf) == [3, 4]
+    pf.close()
+    assert len(tracing.get_spans()) == ring + 2     # the first batch alone
+    assert span.name == "train.input.first_batch" and around is loop
+    # under the span open in the consumer's thread, kept in the ring
+    assert (span.trace_id, span.parent_id) == (loop.trace_id, loop.span_id)
+    assert span.mono_end - span.mono_start >= 0.05
+    assert span.attributes["stall_s"] < 0.05 <= span.mono_end - span.mono_start
+    assert span.attributes["place_s"] >= 0 and span.attributes["source_s"] >= 0
+
+
+def _configuring_loop(config):
+    import jax as _jax
+    import jax.numpy as _jnp
+
+    from ray_tpu import train
+    told = {"open": _jax._src.xla_bridge.backends_are_initialized()}
+    # an option jax reads when it is used: set in the loop as ever
+    _jax.config.update("jax_enable_x64", True)
+    told["dtype"] = str(_jnp.ones(()).dtype)
+    # one it reads when the backend opens: jax refuses it now
+    try:
+        _jax.config.update("jax_num_cpu_devices", 2)
+    except RuntimeError as e:
+        told["refused"] = str(e)
+    told["devices"] = len(_jax.devices())
+    train.report(told)
+
+
+def test_a_loop_that_configures_jax_finds_the_backend_open(tmp_path):
+    """The contract of a ``JaxTrainer`` loop since the program opens the
+    backend ahead of it (docs/QUICKSTART.md): an option jax reads at
+    use takes effect from the loop; one it reads at the opening comes
+    with the worker's environment, and jax refuses it in the loop by
+    name."""
+    import ray_tpu
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+    ray_tpu.init(num_cpus=4, ignore_reinit_error=False, runtime_env={
+        "env_vars": {"XLA_FLAGS": "--xla_force_host_platform_device_count=3"}})
+    try:
+        result = JaxTrainer(
+            _configuring_loop, scaling_config=ScalingConfig(num_workers=1),
+            run_config=RunConfig(name="fit", storage_path=str(tmp_path)),
+        ).fit()
+    finally:
+        ray_tpu.shutdown()
+    assert result.error is None, result.error
+    told = result.metrics
+    assert told["open"] is True
+    assert told["dtype"] == "float64"
+    assert "before backends are initialized" in told["refused"]
+    assert told["devices"] == 3
+    opened, = [s for s in result.spans
+               if s["name"] == "train.worker.backend_init"]
+    assert opened["attributes"]["devices"] == 3
 
 
 def _uneven_loop(config):
@@ -308,7 +614,7 @@ def test_a_finished_worker_hands_its_spans_over_once(uneven_fit):
     ids = [s["span_id"] for s in uneven_fit.spans]
     assert len(ids) == len(set(ids))
     names = [s["name"] for s in uneven_fit.spans]
-    for phase in PHASES + ["train.fit.backend_setup"]:
+    for phase in PHASES + ["train.fit.backend_setup", *SETUP_SPANS]:
         assert names.count(phase) == (
             2 if phase.startswith("train.worker.") else 1), (phase, names)
     ranks = sorted(s["attributes"]["rank"] for s in uneven_fit.spans
@@ -323,10 +629,148 @@ def test_a_finished_worker_hands_its_spans_over_once(uneven_fit):
 
 def test_worker_spans_stay_in_the_fit_with_tracing_enabled(uneven_fit):
     root = next(s for s in uneven_fit.spans if s["name"] == "train.fit")
-    assert {s["trace_id"] for s in uneven_fit.spans} == {root["trace_id"]}
-    for s in uneven_fit.spans:
-        if s["name"].startswith("train.worker."):
+    in_fit = [s for s in uneven_fit.spans if s["name"].startswith("train.")]
+    assert {s["trace_id"] for s in in_fit} == {root["trace_id"]}
+    loops = {s["span_id"] for s in in_fit
+             if s["name"] == "train.worker.loop"}
+    for s in in_fit:
+        if s["name"] == "train.worker.backend_init":
+            assert s["parent_id"] in loops
+        elif s["name"].startswith("train.worker."):
             assert s["parent_id"] == root["span_id"]
+
+
+# -- the identity of the set-up's parts, on hand-made spans ---------------
+
+def _setup_trace():
+    """``benchmark/benchlib/setup_trace.py``: the yardstick's reader of
+    these spans (the program does not import the benchmark)."""
+    import sys
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark"))
+    try:
+        from benchlib import setup_trace
+    finally:
+        sys.path.pop(0)
+    return setup_trace
+
+
+def _rec(name, start, end, process="pid:1", **attributes):
+    return {"name": name, "start": start, "end": end, "process": process,
+            "attributes": attributes}
+
+
+# t_start 100, train.fit from 103, the loop from 108 with its first report
+# 20 s in (128), the window opens at 131.5.
+_CONTAINERS = [
+    _rec("train.fit", 103.0, 160.0),
+    _rec("train.fit.poll", 108.5, 159.0),
+    _rec("train.worker.loop", 108.0, 158.0, "pid:2", first_report_s=20.0),
+]
+_DRIVER = [_rec("train.fit.gang_start", 103.0, 107.0),
+           _rec("train.fit.gang_start.actors", 103.1, 107.0),
+           _rec("train.fit.start_loop", 107.5, 108.5)]
+_WORKER = [_rec("train.worker.process", 103.5, 106.5, "pid:2"),
+           _rec("train.worker.backend_init", 108.0, 114.0, "pid:2"),
+           _rec("train.compile", 116.0, 120.0, "pid:2", kind="trace"),
+           _rec("train.input.first_batch", 119.0, 122.0, "pid:2"),
+           _rec("train.compile", 124.0, 129.5, "pid:2", kind="backend")]
+
+
+@pytest.mark.parametrize("spans, named_s, unnamed_s", [
+    # the containers cover everything and name nothing
+    (_CONTAINERS, 0.0, 25.0),
+    # the driver's spans alone: gang_start with its part inside it counted
+    # once (4), start_loop (1)
+    (_CONTAINERS + _DRIVER, 5.0, 20.0),
+    # the worker's alone: 3 + 6 + the trace and the first batch overlapping
+    # (116-122: 6) + the backend compile cut at the first report (4)
+    (_CONTAINERS + _WORKER, 19.0, 6.0),
+    # both processes on the one clock: the worker's process lies inside
+    # the driver's gang_start, its backend_init overlaps start_loop
+    (_CONTAINERS + _DRIVER + _WORKER, 20.5, 4.5),
+], ids=["containers", "driver", "worker", "merged"])
+def test_set_up_identity_on_hand_made_spans(spans, named_s, unnamed_s):
+    st = _setup_trace()
+    parts = st.cut(spans, 100.0, 131.5)
+    assert parts["before_fit_s"] == pytest.approx(3.0)
+    assert parts["warmup_s"] == pytest.approx(3.5)
+    assert parts["named_s"] == pytest.approx(named_s)
+    assert parts["unnamed_s"] == pytest.approx(unnamed_s)
+    assert (parts["before_fit_s"] + parts["named_s"] + parts["unnamed_s"]
+            + parts["warmup_s"]) == pytest.approx(parts["setup_s"], abs=1e-9)
+    assert parts["setup_s"] == 31.5
+    # the same seconds span by span, and the stretches under none
+    a, b = parts["t_fit"], parts["t_first_report"]
+    assert sum(st.named_by_label(spans, a, b).values()) == pytest.approx(
+        named_s, abs=1e-9)
+    assert sum(hi - lo for lo, hi in st.gaps(spans, a, b)) == pytest.approx(
+        unnamed_s, abs=1e-9)
+
+
+def test_set_up_parts_go_to_the_innermost_span_and_name_the_gaps():
+    st = _setup_trace()
+    spans = _CONTAINERS + _DRIVER + _WORKER
+    by = st.named_by_label(spans, 103.0, 128.0)
+    assert by == {
+        "train.fit.gang_start": pytest.approx(0.1),
+        "train.fit.gang_start.actors": pytest.approx(0.9),
+        "train.worker.process": pytest.approx(3.0),
+        "train.fit.start_loop": pytest.approx(0.5),
+        "train.worker.backend_init": pytest.approx(6.0),
+        "train.compile:trace": pytest.approx(3.0),
+        "train.input.first_batch": pytest.approx(3.0),
+        "train.compile:backend": pytest.approx(4.0)}
+    assert st.gaps(spans, 103.0, 128.0) == [
+        (107.0, 107.5), (114.0, 116.0), (122.0, 124.0)]
+
+
+def test_set_up_readers_say_nothing_of_a_program_without_the_spans():
+    st = _setup_trace()
+    old_program = [_rec("train.fit", 103.0, 160.0),
+                   _rec("train.worker.loop", 108.0, 158.0, "pid:2")]
+    assert st.cut([], 100.0, 131.5) is None
+    assert st.cut(old_program, 100.0, 131.5) == {
+        "setup_s": 31.5, "before_fit_s": 3.0}
+    assert st.span_s(old_program, "train.worker.process") is None
+    # the newest fit of a process that made two; a span that repeats
+    twice = old_program + [_rec("train.fit", 203.0, 260.0),
+                           _rec("train.input.first_batch", 210.0, 211.0),
+                           _rec("train.input.first_batch", 220.0, 220.5)]
+    assert st.cut(twice, 200.0, 231.5)["before_fit_s"] == 3.0
+    assert st.span_s(twice, "train.input.first_batch") == 1.0
+    assert st.span_s(twice, "train.input.first_batch", first=True) == 1.0
+    # workers side by side: the longest, not N times the wall; a gang
+    # that was started again: the newest's
+    gangs = [_rec("train.fit.gang_start", 103.0, 107.0),
+             _rec("train.worker.process", 103.5, 106.5, "pid:2"),
+             _rec("train.fit.gang_start", 140.0, 145.0),
+             _rec("train.worker.process", 140.5, 144.0, "pid:4"),
+             _rec("train.worker.process", 140.6, 144.5, "pid:5"),
+             _rec("train.input.first_batch", 150.0, 150.5, "pid:5"),
+             _rec("train.input.first_batch", 149.0, 149.2, "pid:4")]
+    assert st.span_s(gangs, "train.worker.process") == pytest.approx(3.9)
+    assert st.span_s(gangs, "train.input.first_batch",
+                     first=True) == pytest.approx(0.2)
+
+
+def test_set_up_records_read_a_fits_own_trace_file(fit):
+    """``fit_trace.json`` and ``Result.spans`` cut the same way: the tool
+    reads the file, the readers the tracer."""
+    st = _setup_trace()
+    with open(os.path.join(fit.path, "fit_trace.json")) as f:
+        from_file = st.records_from_chrome(json.load(f))
+    from_spans = st.records([tracing.Span(**s) for s in fit.spans])
+    root = next(s for s in fit.spans if s["name"] == "train.fit")
+    t_start, t_open = root["mono_start"] - 1.0, root["mono_end"]
+    a, b = st.cut(from_file, t_start, t_open), st.cut(from_spans, t_start,
+                                                      t_open)
+    assert a == {k: pytest.approx(v, abs=1e-5) for k, v in b.items()}
+    assert a["before_fit_s"] == pytest.approx(1.0, abs=1e-5)
+    assert 0 <= a["unnamed_s"] < a["t_first_report"] - a["t_fit"]
+    assert (a["before_fit_s"] + a["named_s"] + a["unnamed_s"]
+            + a["warmup_s"]) == pytest.approx(a["setup_s"], abs=1e-9)
 
 
 # -- tracing.py's train-path helpers --------------------------------------
@@ -615,6 +1059,84 @@ def test_the_listener_is_installed_once_a_process():
         train_step._on_cache_event) == 1
 
 
+def test_a_cache_hit_tells_what_its_compile_took(tmp_path):
+    """A load from the persistent cache is a ``cache_load`` span inside
+    its ``backend`` span, with ``compiled_in_s``: the seconds of the
+    compile that wrote the entry, to hold against the load's own."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def fresh_jit():        # the same program twice, two jit caches
+        def cached_twice(x):
+            return jnp.tanh(x) * 3.0 + jnp.cumsum(x)
+        return jax.jit(cached_twice)
+
+    train_step._listen_for_compiles()
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    prior = {n: getattr(jax.config, n) for n in names}
+    try:
+        for n, v in zip(names, (str(tmp_path), 0.0, 0)):
+            jax.config.update(n, v)
+        cc.reset_cache()
+        x = jnp.arange(7.0)
+        before = len(tracing.get_spans())
+        fresh_jit()(x)
+        missed = _compile_spans_since(before, "cached_twice")
+        before = len(tracing.get_spans())
+        fresh_jit()(x)
+        hit = _compile_spans_since(before, "cached_twice")
+    finally:
+        for n, v in prior.items():
+            jax.config.update(n, v)
+        cc.reset_cache()
+    assert [s.attributes["kind"] for s in missed] == [
+        "trace", "lower", "backend"]
+    assert missed[-1].attributes["cache"] == "miss"
+    assert [s.attributes["kind"] for s in hit] == [
+        "trace", "lower", "cache_load", "backend"]
+    load, backend = hit[2], hit[3]
+    assert backend.attributes["cache"] == load.attributes["cache"] == "hit"
+    assert backend.mono_start <= load.mono_start <= load.mono_end
+    assert load.mono_end <= backend.mono_end
+    # jax tells the compile's seconds less the load's: the sum is the
+    # compile's own as the entry holds it, in whole seconds
+    told = load.attributes["compiled_in_s"]
+    compiled = missed[-1].mono_end - missed[-1].mono_start
+    assert told == pytest.approx(int(compiled), abs=1e-6)
+    assert "compiled_in_s" not in backend.attributes
+    assert all("compiled_in_s" not in s.attributes for s in missed)
+
+
+def test_compiled_in_s_is_the_saving_plus_the_load():
+    """The listener's arithmetic, on the events as jax sends them."""
+    train_step._listen_for_compiles()
+    before = len(tracing.get_spans())
+    train_step._on_compile_begins(
+        "/jax/core/compile/backend_compile_duration", 0.0)
+    train_step._on_cache_event("/jax/compilation_cache/cache_hits")
+    train_step._on_compile_seconds(train_step._SAVED_EVENT, -1.25)
+    train_step._on_compile_seconds(
+        "/jax/compilation_cache/cache_retrieval_time_sec", 2.0)
+    train_step._on_compile_seconds(
+        "/jax/core/compile/backend_compile_duration", 2.5,
+        fun_name="jit(slow_to_load)")
+    load, backend = _compile_spans_since(before, "slow_to_load")
+    assert load.attributes["kind"] == "cache_load"
+    # a load that cost more than its compile: 2.0 s for 0.75
+    assert load.attributes["compiled_in_s"] == pytest.approx(0.75)
+    assert load.mono_end - load.mono_start == pytest.approx(2.0)
+    assert backend.mono_end - backend.mono_start == pytest.approx(2.5)
+    # the saving is handed over once: a miss after it carries none
+    train_step._on_compile_begins(
+        "/jax/core/compile/backend_compile_duration", 0.0)
+    train_step._on_compile_seconds(
+        "/jax/core/compile/backend_compile_duration", 0.1,
+        fun_name="jit(slow_to_load)")
+    assert "compiled_in_s" not in _compile_spans_since(
+        before, "slow_to_load")[-1].attributes
+
+
 def test_compile_spans_inside_a_session_go_to_its_list():
     sess = train_session.init_session(train_session.TrainContext(),
                                       trace_ctx=("a" * 16, "b" * 16))
@@ -653,6 +1175,12 @@ def test_ten_thousand_steps_allocate_no_span(monkeypatch):
     assert i == 9_999 and sess.results.qsize() == 10_000
     assert made == [] and sess.spans == []
     assert len(tracing.get_spans()) == ring
+    # the first batch's ends and the first report's time wait in the
+    # session for the worker, which makes the span when the loop returns
+    (t_made, t_handed, told), = sess.first_batches
+    assert t_made <= t_handed <= sess.t_first_report
+    assert set(told) == {"source_s", "place_s", "stall_s"}
+    assert sess.t_first_report <= sess._last_report_ts
     assert input_totals()["input.batches"] == 10_000
     # outside a collector a prefetcher is kept by nobody
     assert DevicePrefetcher(iter(()), depth=1).counters["batches"] == 0
