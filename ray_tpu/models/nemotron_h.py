@@ -209,6 +209,7 @@ class _Conv(nn.Module):
 
 class _GateNorm(nn.Module):
     config: NemotronHConfig
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, y, z):
@@ -216,7 +217,7 @@ class _GateNorm(nn.Module):
         scale = self.param("scale", nn.initializers.ones,
                            (cfg.mamba_inner,), cfg.param_dtype)
         return ssm.gated_group_rms_norm(y, z, scale, cfg.ssm_groups,
-                                        cfg.rms_eps)
+                                        cfg.rms_eps, mesh=self.mesh)
 
 
 class Mamba2Mixer(nn.Module):
@@ -248,8 +249,10 @@ class Mamba2Mixer(nn.Module):
         tracing.note_trace(
             ssm_tokens=b * t, ssm_heads=h, ssm_state=n,
             ssm_chunk=cfg.chunk, ssm_path=ssm.scan_path(
-                (b, t, h, p), (b, t, g, n), cfg.chunk, self.mesh))
-        y = _GateNorm(cfg, name="gate_norm")(y.reshape(b, t, inner), z)
+                (b, t, h, p), (b, t, g, n), cfg.chunk, self.mesh),
+            gate_norm_path=ssm.norm_path((b, t, inner), g, self.mesh))
+        y = _GateNorm(cfg, self.mesh, name="gate_norm")(
+            y.reshape(b, t, inner), z)
         return _dense(cfg)(cfg.n_embd, name="out_proj")(y)
 
 

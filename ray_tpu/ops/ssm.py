@@ -1,7 +1,10 @@
 """State-space (Mamba-2) ops: the selective scan in its chunked form,
 the depthwise causal convolution (with its SiLU) in front of it and the
-grouped gated RMSNorm behind it, both recomputed in the backward. Nemotron-H's ``M`` layers (``models/nemotron_h.py``)
-are the caller.
+grouped gated RMSNorm behind it. Of the three the scan and the norm are
+Pallas kernels where a TPU program can take them (below); the
+convolution is XLA's everywhere, recomputed in the backward, as is the
+norm on its XLA path. Nemotron-H's ``M`` layers
+(``models/nemotron_h.py``) are the caller.
 
 The recurrence, a head (``S`` is ``[P, N]``)::
 
@@ -42,6 +45,16 @@ chunk terms are batched matmuls that XLA places on the MXU, the
 squares arrays in HBM. Same mathematics, same precisions, same
 residual. A sequence split over chips (``sp``) would need the state
 passed between chips; the model refuses it by name.
+
+The gated norm follows the scan: ``norm_path()`` gives it its own two
+kernels (``ops/pallas/gated_norm.py``, ``pallas``) on a TPU where each
+of its groups is whole 128-lane tiles and the program is one the scan's
+kernels serve, bare or under the same ``shard_map`` over the batch, so
+that the norm stays in the row-major ``[B, T, H*P]`` the scan's kernel
+writes and ``out_proj``'s matmul reads; behind a custom call the XLA
+function's reshape to ``[.., groups, C / groups]`` was a relayout of a
+float32 array three times a layer. Everywhere else that XLA function
+runs (``xla``). The convolution has no kernel.
 """
 
 from __future__ import annotations
@@ -53,7 +66,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.pallas import ssd_scan
+from ray_tpu.ops.pallas import gated_norm, ssd_scan
 
 _BOUNDARY = "ssm_boundary_states"
 # what ``parallel/sharding.py`` maps the logical "batch" to
@@ -71,6 +84,19 @@ def scan_path(x_shape, state_shape, chunk: int, mesh=None) -> str:
             and _kernel_batch_axes(mesh, x_shape[0]) is not None):
         return "pallas_chunked"
     return "chunked_xla"
+
+
+def norm_path(shape, groups: int, mesh=None) -> str:
+    """Which gated norm ``gated_group_rms_norm`` compiles for ``y``
+    [b, T, C] in ``groups`` groups: ``pallas`` exactly where the scan
+    takes its kernels (a TPU, each group whole 128-lane tiles, and
+    ``_kernel_batch_axes`` finds the program one the kernels can
+    serve), else ``xla``."""
+    if (jax.default_backend() == "tpu" and len(shape) == 3
+            and gated_norm.shapes_ok(shape[-1], groups)
+            and _kernel_batch_axes(mesh, shape[0]) is not None):
+        return "pallas"
+    return "xla"
 
 
 def _kernel_batch_axes(mesh, batch: int):
@@ -197,13 +223,25 @@ def causal_conv1d_silu(x, weight, bias):
     return jax.nn.silu(y)
 
 
-@functools.partial(jax.checkpoint, static_argnums=(3, 4))
-def gated_group_rms_norm(y, z, scale, groups: int, eps: float):
+def gated_group_rms_norm(y, z, scale, groups: int, eps: float, *,
+                         mesh=None):
     """Mamba-2's output norm: ``RMSNorm(y * silu(z))`` with the mean
     square taken over each of ``groups`` equal slices of the last
     dimension and one ``scale`` over all of it; float32 inside, ``y``'s
     dtype out. Recomputed in the backward: ``y`` and ``z`` are kept, in
-    their own dtype, and none of the float32 products between."""
+    their own dtype, and none of the float32 products between. ``mesh``
+    is the mesh the program is sharded over, if the caller knows one:
+    ``norm_path`` decides from it between the kernels
+    (``ops/pallas/gated_norm.py``) and the XLA function below."""
+    if norm_path(y.shape, groups, mesh) == "pallas":
+        return gated_norm.gated_norm(
+            y, z, scale, groups=groups, eps=eps, mesh=mesh,
+            batch_axes=_kernel_batch_axes(mesh, y.shape[0]))
+    return _gated_group_rms_norm_xla(y, z, scale, groups, eps)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4))
+def _gated_group_rms_norm_xla(y, z, scale, groups: int, eps: float):
     dtype = y.dtype
     g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
     shape = g.shape

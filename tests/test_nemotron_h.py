@@ -365,11 +365,11 @@ def test_the_step_reports_and_notes_what_the_layers_are(monkeypatch):
     step.trace(state, _batch(cfg))
     assert {k: notes[k] for k in (
         "layer_pattern", "ssm_tokens", "ssm_heads", "ssm_state",
-        "ssm_chunk", "ssm_path", "moe_router", "moe_expert_kind",
-        "moe_experts_held")} == {
+        "ssm_chunk", "ssm_path", "gate_norm_path", "moe_router",
+        "moe_expert_kind", "moe_experts_held")} == {
         "layer_pattern": "MEM*E", "ssm_tokens": 128, "ssm_heads": 8,
         "ssm_state": 16, "ssm_chunk": 16, "ssm_path": "chunked_xla",
-        "moe_router": "sigmoid", "moe_expert_kind": "relu2",
+        "gate_norm_path": "xla", "moe_router": "sigmoid", "moe_expert_kind": "relu2",
         "moe_experts_held": [4, 4]}
     state, metrics = step(state, _batch(cfg))
     assert {"loss", "lm_loss", "moe_held_route_share",

@@ -4,7 +4,9 @@ sequence lengths that are and are not a multiple of the chunk; the
 convolution and the gated norm against their formulas; what the
 backward keeps; and the same for the scan's Pallas kernels
 (``ops/pallas/ssd_scan.py``) in ``interpret`` mode, with which of the
-two paths ``scan_path`` picks for a shape, a backend and a mesh."""
+two paths ``scan_path`` picks for a shape, a backend and a mesh; and the
+gated norm's kernels (``ops/pallas/gated_norm.py``) the same way, with
+``norm_path``."""
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +14,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops import ssm
-from ray_tpu.ops.pallas import ssd_scan
+from ray_tpu.ops.pallas import gated_norm, ssd_scan
 
 B, H, P, G, N, CHUNK = 2, 4, 8, 2, 16, 16
 
@@ -365,3 +367,122 @@ def test_kernel_scan_over_a_batch_sharded_mesh_is_the_one_device_scan():
 def test_shapes_the_kernels_do_not_tile_are_refused_by_name():
     with pytest.raises(ValueError, match="do not tile"):
         ssd_scan.ssd_scan(*_inputs(64), chunk=16, interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# the gated norm's kernels, interpreted here, against the XLA function
+# (which this backend's ``gated_group_rms_norm`` is)
+# ---------------------------------------------------------------------------
+
+def _norm_inputs(shape, dtype, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    y, z, dout = (jax.random.normal(k, shape).astype(dtype) for k in ks[:3])
+    return y, z, 1.0 + 0.5 * jax.random.normal(ks[3], shape[-1:]), dout
+
+
+def _norm_kernel(groups, **kw):
+    return lambda *a: gated_norm.gated_norm(*a, groups=groups, eps=1e-5,
+                                            interpret=True, **kw)
+
+
+def _value_and_grads(f, y, z, scale, dout):
+    out, vjp = jax.vjp(f, y, z, scale)
+    return (out, *vjp(dout))
+
+
+# (batch, T, C), groups: row blocks of 128 rows
+NORM_CASES = {
+    "one_group_a_ragged_last_block": ((2, 300, 256), 1),
+    "eight_groups_a_ragged_last_block": ((2, 300, 1024), 8),
+    "eight_groups_whole_blocks": ((2, 256, 1024), 8),
+    "fewer_rows_than_a_strip_holds": ((2, 7, 256), 2),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", NORM_CASES)
+def test_kernel_norm_is_the_xla_norm(monkeypatch, case, dtype):
+    """The value and all three gradients. Both are float32 inside and
+    round once on the way out: in float32 they differ by the order of
+    the sums, in bfloat16 by one step of the result's rounding at
+    most; ``scale``'s gradient is a float32 sum either way."""
+    shape, groups = NORM_CASES[case]
+    monkeypatch.setattr(gated_norm, "_BLOCK_BYTES",
+                        128 * shape[-1] * jnp.dtype(dtype).itemsize)
+    args = _norm_inputs(shape, dtype, seed=shape[1])
+    got = _value_and_grads(_norm_kernel(groups), *args)
+    want = _value_and_grads(
+        lambda *a: ssm.gated_group_rms_norm(*a, groups, 1e-5), *args)
+    step = 1e-5 if dtype == jnp.float32 else 2 ** -7
+    for name, g, w in zip("out dy dz dscale".split(), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        tol = 1e-5 if name == "dscale" else step
+        np.testing.assert_allclose(
+            g, w, rtol=tol, atol=tol * float(jnp.abs(w).max()),
+            err_msg=name)
+
+
+NORM_CELL = (1, 8192, 4096)
+
+
+@pytest.mark.parametrize("backend, shape, groups, path", [
+    ("tpu", NORM_CELL, 8, "pallas"),
+    ("cpu", NORM_CELL, 8, "xla"),
+    ("tpu", NORM_CELL, 1, "pallas"),
+    ("tpu", (1, 8192, 512), 8, "xla"),
+    ("tpu", (1, 8192, 4096 + 128), 8, "xla"),
+    ("tpu", (8192, 4096), 8, "xla"),
+], ids=["the_cell_on_a_tpu", "the_cell_on_a_cpu", "one_group",
+        "groups_of_64_fill_no_tile", "groups_that_do_not_divide",
+        "rows_with_no_batch"])
+def test_norm_path_reads_the_backend_and_the_shapes(
+        monkeypatch, backend, shape, groups, path):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert ssm.norm_path(shape, groups) == path
+
+
+@pytest.mark.parametrize("axes, batch, path", [
+    (None, 1, "xla"),
+    ({"dp": 1}, 1, "pallas"),
+    ({"dp": 4}, 4, "pallas"),
+    ({"dp": 2, "fsdp": 2}, 8, "pallas"),
+    ({"dp": 4}, 2, "xla"),
+    ({"dp": 2, "tp": 2}, 4, "xla"),
+    ({"ep": 2}, 4, "xla"),
+], ids=["no_mesh_in_a_process_of_eight_devices", "a_mesh_of_one_device",
+        "dp", "dp_and_fsdp", "a_batch_dp_does_not_divide", "dp_and_tp",
+        "ep_alone"])
+def test_norm_path_reads_the_devices_the_program_spans(
+        monkeypatch, axes, batch, path):
+    """The norm takes its kernels exactly where the scan does: the two
+    tables are one."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = None if axes is None else _mesh(**axes)
+    assert ssm.norm_path((batch, *NORM_CELL[1:]), 8, mesh) == path
+    x, state = ((batch, *shape[1:]) for shape in CELL[:2])
+    assert (ssm.scan_path(x, state, 128, mesh) == "pallas_chunked") == (
+        path == "pallas")
+
+
+def test_kernel_norm_over_a_batch_sharded_mesh_is_the_one_device_norm():
+    """Under the ``shard_map`` over ``dp`` each device norms its own
+    rows; ``scale`` is whole on both and its gradient is the sum of the
+    two devices'."""
+    mesh = _mesh(dp=2)
+    args = _norm_inputs((2, 48, 256), jnp.float32, seed=13)
+    want = _value_and_grads(_norm_kernel(2), *args)
+    got = jax.jit(lambda *a: _value_and_grads(
+        _norm_kernel(2, mesh=mesh, batch_axes=("dp",)), *a))(*args)
+    assert got[0].sharding.spec[0] in ("dp", ("dp",))
+    for name, g, w in zip("out dy dz dscale".split(), got, want):
+        np.testing.assert_allclose(
+            g, w, rtol=1e-6, atol=1e-6 * float(jnp.abs(w).max()),
+            err_msg=name)
+
+
+def test_columns_the_norms_kernels_do_not_tile_are_refused_by_name():
+    with pytest.raises(ValueError, match="do not tile"):
+        _norm_kernel(3)(*_norm_inputs((2, 16, 12), jnp.float32)[:3])

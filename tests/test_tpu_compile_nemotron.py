@@ -189,6 +189,55 @@ def test_a_mamba_layer_compiles_for_four_chips_with_the_batch_over_dp(
     assert (text.count("tpu_custom_call") >= 2) == given_the_mesh
 
 
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "dp_on_the_2x2"])
+def test_a_mamba_layer_at_the_cells_shape_keeps_the_kernels_layout(
+        v5e, monkeypatch, chips):
+    """One ``M`` layer at the cell's widths and 8,192 tokens, loss and
+    gradients, on one chip and with a sequence a chip over ``dp``: the
+    scan's two custom calls and the gated norm's two, both notes name
+    the kernels, and under ``gate_norm`` no float32 ``[rows, 8192,
+    4096]`` array is left in the optimised HLO: the XLA norm behind the
+    scan's custom call relaid one out three times a layer (PERF.md
+    section 6, PR 37); the kernels read and write bfloat16."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from ray_tpu.models.nemotron_h import (
+        NemotronH, NemotronHConfig, nemotron_h_loss_fn,
+    )
+    from ray_tpu.util import tracing
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if chips == 1:
+        mesh = Mesh(np.array(v5e[:1]), ("dp",))
+        rows = whole = _arg(v5e[0])
+    else:
+        mesh, rows, whole = _dp(v5e)
+    cfg = NemotronHConfig.nemotron_3_nano_30b_a3b(pattern="M",
+                                                  vocab_size=16384)
+    model = NemotronH(cfg, mesh=mesh)
+    params = jax.tree.map(
+        lambda z: whole(z.shape, z.dtype),
+        jax.eval_shape(lambda k: NemotronH(cfg).init_params(k, 1),
+                       jax.random.key(0)))
+    batch = {k: rows((chips, cfg.seq_len), jnp.int32)
+             for k in ("tokens", "targets")}
+    loss = nemotron_h_loss_fn(model)
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    text = jax.jit(jax.grad(lambda p, b: loss(p, b)[0])).lower(
+        params, batch).compile().as_text()
+    assert notes["ssm_path"] == "pallas_chunked"
+    assert notes["gate_norm_path"] == "pallas"
+    assert text.count("tpu_custom_call") == 4
+    assert len(re.findall(r"custom-call\(.*gate_norm", text)) == 2
+    under = [ln for ln in text.splitlines() if "gate_norm" in ln]
+    assert under
+    assert not [ln for ln in under
+                if re.search(r"= \(?f32\[\d+,8192,4096\]", ln)]
+
+
 def test_layers_at_one_shape_trace_each_scan_kernel_once(monkeypatch):
     """What holds ``setup_s`` (PERF.md section 6, PR 28: a kernel's
     price is per ``pallas_call`` equation): the two functions that hold
@@ -225,4 +274,35 @@ def test_layers_at_one_shape_trace_each_scan_kernel_once(monkeypatch):
         return two_layers(*args) * 2.0
 
     jax.jit(jax.grad(again, argnums=(0, 1))).lower(x, dt, head, bc, bc, head)
+    assert len(bodies) == 2
+
+
+def test_layers_at_one_shape_trace_each_norm_kernel_once(monkeypatch):
+    """The same for the gated norm's two kernels."""
+    from ray_tpu.ops.pallas import gated_norm
+    bodies = []
+    strips = gated_norm._strips         # each kernel's body, once
+    monkeypatch.setattr(
+        gated_norm, "_strips",
+        lambda *a: bodies.append(1) or strips(*a))
+    rows = jax.ShapeDtypeStruct((1, 48, 384), jnp.float32)  # no other test's
+    scale = jax.ShapeDtypeStruct((384,), jnp.float32)
+
+    def two_layers(y, z, scale):
+        for i in range(2):
+            with jax.named_scope(f"h_{i}"):
+                y = gated_norm.gated_norm(y, z, scale, groups=3, eps=1e-5,
+                                          interpret=True)
+        return (y ** 2).sum()       # so the last layer's value is used
+
+    text = jax.jit(jax.grad(two_layers, argnums=(0, 1, 2))).lower(
+        rows, rows, scale).as_text()
+    assert text.count("call @_norm_fwd") == 2
+    assert text.count("call @_norm_bwd") == 2
+    assert len(bodies) == 2             # forward's and backward's
+
+    def again(*args):                   # a new function: a new trace
+        return two_layers(*args) * 2.0
+
+    jax.jit(jax.grad(again, argnums=(0, 1, 2))).lower(rows, rows, scale)
     assert len(bodies) == 2
