@@ -11,7 +11,11 @@ expresses NVIDIA-Nemotron-3-Nano-30B-A3B. ``JoyAI`` is the
 DeepSeek-V3-shaped decoder (latent attention through ``ops/mla.py``, a
 leading dense layer, sigmoid-routed SwiGLU experts with a shared one
 through ``routed_ffn``, a multi-token-prediction module and its second
-loss); its config expresses JoyAI-LLM-Flash. ``MoETransformer`` is the
+loss); its config expresses JoyAI-LLM-Flash. ``Zaya`` is the CCA
+decoder (compressed convolutional attention through ``ops/cca.py``, a
+top-1 MLP router whose state runs from layer to layer and hands its
+routes to ``ops/moe.py::routed_experts``, scaled residuals, a tied
+table); its config expresses ZAYA1-8B. ``MoETransformer`` is the
 older top-1, capacity-dropping switch model
 on GPT-2 blocks, which goes when the dropless path runs under ``ep``
 (ROADMAP C5)."""
@@ -23,9 +27,10 @@ from ray_tpu.models.moe import MoEConfig, MoETransformer
 from ray_tpu.models.nemotron_h import NemotronH, NemotronHConfig
 from ray_tpu.models.resnet import ResNet, ResNet50Config
 from ray_tpu.models.vit import ViT, ViTConfig
+from ray_tpu.models.zaya import Zaya, ZayaConfig
 
 __all__ = [
     "GPT2", "GPT2Config", "JoyAI", "JoyAIConfig", "Llama", "LlamaConfig",
     "MoETransformer", "MoEConfig", "NemotronH", "NemotronHConfig",
-    "ResNet", "ResNet50Config", "ViT", "ViTConfig",
+    "ResNet", "ResNet50Config", "ViT", "ViTConfig", "Zaya", "ZayaConfig",
 ]

@@ -21,7 +21,15 @@ skew. What runs through it, by argument:
   the same sigmoid router over 256 experts, top-8 renormalised and
   scaled, with **SwiGLU** experts and ``experts_held``: the slabs walk
   three grouped matmuls an expert (``_slab`` with a ``w_gate``), 16 of
-  256 held in the benchmark's cell, a slab of 8,192 sorted rows.
+  256 held in the benchmark's cell, a slab of 8,192 sorted rows;
+- ZAYA1-8B (``models/zaya.py``) through ``routed_experts``, the form
+  that takes its routes from the caller: that model's router is an MLP
+  over a state the previous layer made, so it routes itself (top-1 of
+  16, 8 held: a slab of all 16,384 rows) and hands in ``weights`` and
+  ``experts``. ``routed_ffn`` is the same call with the routes made
+  from a matrix first (``_routed`` is what the two share): the sort,
+  the held share, the slabs, the grouped matmuls and the load are one
+  code.
 
 On a mesh that shards tokens (dp, fsdp, sp) each chip routes and sorts
 its own tokens under ``shard_map`` with the experts replicated; a mesh
@@ -389,24 +397,26 @@ def _held_part(x, flat, weights, counts, w_gate, w_up, w_down, top_k,
                   ).astype(x.dtype)
 
 
-def _routed_ffn_local(x, router_w, w_gate, w_up, w_down, select_bias=None,
-                      *, top_k, norm_topk_prob, over=(), router="softmax",
-                      route_scale=1.0, experts_held=None):
-    """The layer on the tokens in hand (``[..., d]``); the router's
-    sums are added over the mesh axes ``over`` so that the two losses
-    and the load are those of the global batch. ``w_gate`` None: the
-    two-matrix relu^2 expert."""
+def _given(x, weights, experts):
+    """The routes a caller made (``routed_experts``), a row a token;
+    no auxiliary loss belongs to them here: the last two are zeros."""
+    k = weights.shape[-1]
+    return (weights.reshape(-1, k).astype(jnp.float32),
+            experts.reshape(-1, k), jnp.float32(0), jnp.float32(0))
+
+
+def _routed_ffn_local(x, route_args, w_gate, w_up, w_down, *, route,
+                      num_experts, top_k, over=(), experts_held=None):
+    """The layer on the tokens in hand (``[..., d]``), their routes
+    made by ``route(x, *route_args)`` (``_route``, ``_route_sigmoid``
+    or ``_given``); the router's sums are added over the mesh axes
+    ``over`` so that the two losses and the load are those of the
+    global batch. ``w_gate`` None: the two-matrix relu^2 expert."""
     shape = x.shape
     x = x.reshape(-1, shape[-1])
-    t, e = x.shape[0], router_w.shape[-1]
+    t, e = x.shape[0], num_experts
     with jax.named_scope("router"):
-        if router == "sigmoid":
-            weights, experts, prob_sum, z_sum = _route_sigmoid(
-                x, router_w, select_bias, top_k, norm_topk_prob,
-                route_scale)
-        else:
-            weights, experts, prob_sum, z_sum = _route(
-                x, router_w, top_k, norm_topk_prob)
+        weights, experts, prob_sum, z_sum = route(x, *route_args)
         flat = experts.reshape(-1)
         counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
         total = (counts.astype(jnp.float32), prob_sum, z_sum,
@@ -525,17 +535,64 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         raise ValueError(f"expert {expert!r} with w_gate "
                          f"{'absent' if w_gate is None else 'given'}")
     e = router_w.shape[-1]
+    if router == "sigmoid":
+        route = functools.partial(
+            _route_sigmoid, top_k=top_k, norm_topk_prob=norm_topk_prob,
+            route_scale=route_scale)
+        route_args = (router_w, jnp.zeros((e,), jnp.float32)
+                      if select_bias is None else select_bias)
+    else:
+        route = functools.partial(_route, top_k=top_k,
+                                  norm_topk_prob=norm_topk_prob)
+        route_args = (router_w,)
+    said = {}
+    if (router, expert, experts_held) != ("softmax", "swiglu", None):
+        # beside today's keys, and only where one of them says something
+        said = dict(moe_router=router, moe_expert_kind=expert)
+    return _routed(x, route, route_args, False, w_gate, w_up, w_down,
+                   num_experts=e, top_k=top_k, mesh=mesh,
+                   experts_held=experts_held, said=said)
+
+
+def routed_experts(x, weights, experts, w_gate, w_up, w_down, *,
+                   num_experts: int, mesh=None,
+                   experts_held: tuple[int, int] | None = None):
+    """``routed_ffn`` for a caller that makes the routes itself (a
+    router that is not one matrix: ``models/zaya.py``'s is an MLP over
+    a state the previous layer made).
+
+    weights: [batch, seq, k] (or [tokens, k])  each token's ``k`` router
+                              weights, float32, carrying the gradient
+    experts: [batch, seq, k]  the experts they belong to, of
+                              ``num_experts``
+    ``x``, the experts' matrices (``w_gate`` None: relu^2 experts),
+    ``mesh`` and ``experts_held`` as ``routed_ffn``; the sort, the slabs
+    and the grouped matmuls are the same code. Returns ``(y, load)``:
+    the held experts' part of each token's sum and the routes each of
+    the ``num_experts`` experts received, ``[E]`` float32."""
+    y, _, _, load = _routed(
+        x, _given, (weights, experts), True, w_gate, w_up, w_down,
+        num_experts=num_experts, top_k=weights.shape[-1], mesh=mesh,
+        experts_held=experts_held,
+        said=dict(moe_router="caller",
+                  moe_expert_kind="relu2" if w_gate is None else "swiglu"))
+    return y, load
+
+
+def _routed(x, route, route_args, per_token: bool, w_gate, w_up, w_down, *,
+            num_experts, top_k, mesh, experts_held, said):
+    """What the two public forms share: the checks, ``shard_map`` over
+    the axes that shard tokens (``route_args`` enter sharded like the
+    tokens where they are ``per_token``, replicated where they are the
+    router's weights), the notes on the trace span."""
+    e = num_experts
     first, held = experts_held or (0, e)
     if not (0 <= first and first + held <= e and w_up.shape[0] == held):
         raise ValueError(f"experts_held {experts_held} of {e} experts, "
                          f"weights for {w_up.shape[0]}")
     local = functools.partial(
-        _routed_ffn_local, top_k=top_k, norm_topk_prob=norm_topk_prob,
-        router=router, route_scale=route_scale, experts_held=experts_held)
-    weights = (router_w, w_gate, w_up, w_down)
-    if router == "sigmoid":
-        weights += (jnp.zeros((e,), jnp.float32) if select_bias is None
-                    else select_bias,)
+        _routed_ffn_local, route=route, num_experts=e, top_k=top_k,
+        experts_held=experts_held)
     batch_axes, seq_axis = _token_axes(
         mesh, x.shape[0], x.shape[1] if x.ndim == 3 else 1)
     axes = batch_axes + ((seq_axis,) if seq_axis else ())
@@ -548,22 +605,22 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         # sums their gradients over the axes once.
         out = jax.shard_map(
             functools.partial(local, over=axes), mesh=mesh,
-            in_specs=(held_spec,) + tuple(
-                None if w is None else P() for w in weights),
+            in_specs=(held_spec,
+                      tuple(held_spec if per_token else P()
+                            for _ in route_args),
+                      None if w_gate is None else P(), P(), P()),
             out_specs=(held_spec, P(), P(), P()), check_vma=False)(
-                x, *weights)
+                x, route_args, w_gate, w_up, w_down)
         tokens //= math.prod(mesh.shape[a] for a in axes)
     else:
-        out = local(x, *weights)
+        out = local(x, route_args, w_gate, w_up, w_down)
     notes = dict(
         moe_tokens=tokens, moe_experts=e, moe_top_k=top_k,
         moe_routes=tokens * top_k, moe_path=grouped_matmul_path(),
         moe_axes=list(axes))
-    if (router, expert, experts_held) != ("softmax", "swiglu", None):
-        # beside today's keys, and only where one of them says something
+    if said:
         notes.update(
-            moe_router=router, moe_expert_kind=expert,
-            moe_experts_held=[first, held],
+            said, moe_experts_held=[first, held],
             moe_rows_sorted=held_rows(tokens * top_k, held, e))
     tracing.note_trace(**notes)
     return out
