@@ -177,6 +177,7 @@ class CCA(nn.Module):
     equations). ``qk`` holds W_q's and W_k's columns side by side, ``v``
     W_v1's and W_v2's: one matmul each."""
     config: ZayaConfig
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, h, attn_fn: Callable, angles):
@@ -194,7 +195,7 @@ class CCA(nn.Module):
                          jnp.float32)
         o = cca.cca_attention(qk, v, conv0, conv1, tau, angles,
                               n_head=cfg.n_head, n_kv_head=g,
-                              attn_fn=attn_fn)
+                              attn_fn=attn_fn, mesh=self.mesh)
         with jax.named_scope("out"):
             return _dense(cfg)(cfg.n_embd, name="out")(o)
 
@@ -291,8 +292,8 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, state, attn_fn: Callable, angles):
         cfg = self.config
-        a = CCA(cfg, name="attn")(_norm(cfg)(name="attn_norm")(x),
-                                  attn_fn, angles)
+        a = CCA(cfg, self.mesh, name="attn")(
+            _norm(cfg)(name="attn_norm")(x), attn_fn, angles)
         x = _Residual(cfg, name="attn_res")(x, a)
         y, state = MoE(cfg, self.mesh, name="mlp")(
             _norm(cfg)(name="mlp_norm")(x), state)
@@ -329,7 +330,9 @@ class Zaya(nn.Module):
                 "halo is not implemented. dp and fsdp shard the batch and "
                 "need nothing.")
         tracing.note_trace(
-            attn_kind="cca", cca_path=cca.cca_path(),
+            attn_kind="cca", **cca.path_notes(
+                (*tokens.shape, cfg.latent), cfg.n_head, cfg.n_kv_head,
+                cfg.conv_taps, self.mesh),
             cca_heads=[cfg.n_head, cfg.n_kv_head, cfg.head_dim],
             router_width=cfg.router_width)
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",

@@ -32,9 +32,16 @@ grouped convolution is a matmul a tap (bf16 operands, float32 sums).
 **Which attention runs.** ``attn_fn`` is the caller's (the models take
 theirs from ``ops/attention.py``): the equal-width flash kernel, with
 the key/value heads repeated up to the query heads first (4 copies at 8
-over 2; the kernel has no grouped form yet). The passes before it are
-XLA's: ``cca_path()`` says so, and is where a kernel for them will
-give its name.
+over 2; the kernel has no grouped form yet). The passes before it,
+``conv``, ``mix`` and ``rope``, are ``cca_path``'s decision from what
+it can observe: ``pallas`` (``ops/pallas/cca_mix.py``: one kernel
+forward and one backward over the row-major ``[q~ | k~]``, their
+custom calls under the scope ``mix``) on a TPU where each head is whole
+128-lane tiles, the rows a row reads before itself fit one sublane tile
+and the program is one the kernels can serve (one device, or a mesh
+that shards the batch alone, under a ``shard_map``); else ``xla``:
+``_qk_for_kernel`` as XLA's fusions under ``mixed_qk``, which is also
+the tests' reference and the CPU's path.
 """
 
 from __future__ import annotations
@@ -45,10 +52,33 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.pallas import cca_mix
+from ray_tpu.ops.ssm import _kernel_batch_axes
 
-def cca_path() -> str:
-    """What computes ``conv``, ``mix`` and ``rope``: XLA's fusions."""
+
+def cca_path(shape, n_head: int, n_kv_head: int, taps, mesh=None) -> str:
+    """What computes ``conv``, ``mix`` and ``rope`` for ``[q~ | k~]``
+    [B, T, (H + G) * D] at these taps: ``pallas`` on a TPU where the
+    kernels tile the shapes and ``_kernel_batch_axes`` finds the
+    program one they can serve (``ops/ssm.py``: one device, or a mesh
+    that shards the batch alone), else ``xla``."""
+    if (jax.default_backend() == "tpu" and len(shape) == 3
+            and cca_mix.shapes_ok(shape[-1], n_head, n_kv_head, taps)
+            and _kernel_batch_axes(mesh, shape[0]) is not None):
+        return "pallas"
     return "xla"
+
+
+def path_notes(shape, n_head: int, n_kv_head: int, taps, mesh=None) -> dict:
+    """``cca_path`` as the ``trace`` span's notes, with the kernels'
+    rows a block and the rows a row reads before itself where it is
+    ``pallas``."""
+    path = cca_path(shape, n_head, n_kv_head, taps, mesh)
+    if path != "pallas":
+        return {"cca_path": path}
+    return {"cca_path": path,
+            "cca_rows_per_block": cca_mix.block_rows(shape[1]),
+            "cca_halo_rows": cca_mix.halo_rows(taps)}
 
 
 def shift_rows(x, by: int = 1):
@@ -165,7 +195,7 @@ mixed_qk.defvjp(_mixed_qk_fwd, _mixed_qk_bwd)
 
 
 def cca_attention(qk, v, conv0, conv1, tau, angles, *, n_head: int,
-                  n_kv_head: int, attn_fn):
+                  n_kv_head: int, attn_fn, mesh=None):
     """Everything between CCA's projections and its output projection.
 
     qk:     [B, T, (H + G) * D]  the compressed queries, then the keys
@@ -174,10 +204,18 @@ def cca_attention(qk, v, conv0, conv1, tau, angles, *, n_head: int,
     conv1:  (w [K1, H+G, D, D], b)       the convolution within heads
     tau:    [G] float32                  the keys' temperature
     angles: [T, rotary / 2]              ``models/llama.py::rope_freqs``
+    mesh:   the devices the program spans, for ``cca_path``
     Returns o [B, T, H * D] in ``qk``'s dtype."""
     b, t, _ = qk.shape
     rep = n_head // n_kv_head
-    q, k = mixed_qk(qk, conv0, conv1, tau, angles, n_head, n_kv_head)
+    taps = (conv0[0].shape[0], conv1[0].shape[0])
+    if cca_path(qk.shape, n_head, n_kv_head, taps, mesh) == "pallas":
+        q, k = cca_mix.cca_mix(
+            qk, conv0, conv1, tau, angles, n_head=n_head,
+            n_kv_head=n_kv_head, mesh=mesh,
+            batch_axes=_kernel_batch_axes(mesh, b))
+    else:
+        q, k = mixed_qk(qk, conv0, conv1, tau, angles, n_head, n_kv_head)
     with jax.named_scope("core"):
         # the kernels take equal head counts (models/nemotron_h.py:273)
         k = jnp.repeat(k, rep, axis=2)
