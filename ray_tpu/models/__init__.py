@@ -15,7 +15,12 @@ loss); its config expresses JoyAI-LLM-Flash. ``Zaya`` is the CCA
 decoder (compressed convolutional attention through ``ops/cca.py``, a
 top-1 MLP router whose state runs from layer to layer and hands its
 routes to ``ops/moe.py::routed_experts``, scaled residuals, a tied
-table); its config expresses ZAYA1-8B. ``MoETransformer`` is the
+table); its config expresses ZAYA1-8B. ``SmallThinker`` is the
+window/global decoder (three windowed RoPE layers to one global layer
+without positions through ``causal_attention(window=...)``, a softmax
+router that reads the block's input before attention, ReGLU experts
+through ``routed_experts``, an untied head); its config expresses
+SmallThinker-21BA3B-Instruct. ``MoETransformer`` is the
 older top-1, capacity-dropping switch model
 on GPT-2 blocks, which goes when the dropless path runs under ``ep``
 (ROADMAP C5)."""
@@ -26,11 +31,13 @@ from ray_tpu.models.llama import Llama, LlamaConfig
 from ray_tpu.models.moe import MoEConfig, MoETransformer
 from ray_tpu.models.nemotron_h import NemotronH, NemotronHConfig
 from ray_tpu.models.resnet import ResNet, ResNet50Config
+from ray_tpu.models.smallthinker import SmallThinker, SmallThinkerConfig
 from ray_tpu.models.vit import ViT, ViTConfig
 from ray_tpu.models.zaya import Zaya, ZayaConfig
 
 __all__ = [
     "GPT2", "GPT2Config", "JoyAI", "JoyAIConfig", "Llama", "LlamaConfig",
     "MoETransformer", "MoEConfig", "NemotronH", "NemotronHConfig",
-    "ResNet", "ResNet50Config", "ViT", "ViTConfig", "Zaya", "ZayaConfig",
+    "ResNet", "ResNet50Config", "SmallThinker", "SmallThinkerConfig", "ViT",
+    "ViTConfig", "Zaya", "ZayaConfig",
 ]

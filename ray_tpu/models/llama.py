@@ -202,7 +202,10 @@ class LlamaAttention(nn.Module):
         k = rope(k, angles[:T])
         # GQA: repeat K/V groups up to n_head so the pluggable
         # attention impls (flash/ring/ulysses) see equal head counts.
-        # XLA fuses the broadcast; no extra HBM copy materializes.
+        # In front of the flash kernel (a custom call, which XLA cannot
+        # fuse into) each copy is written to HBM and its cotangent
+        # summed over the copies again: PERF.md section 7, ROADMAP B2
+        # (GQA-native K/V in the kernel).
         rep = cfg.n_head // cfg.n_kv_head
         if rep > 1:
             k = jnp.repeat(k, rep, axis=2)

@@ -10,7 +10,9 @@
   v of one shape; the kernels' other shape, latent attention's keys of
   128 + 64 against values of 128 with the rotary key shared by the
   heads, is ``ops/mla.py::latent_attention``'s, which has its own
-  dispatch (``mla_path``) to the kernels of the same file.
+  dispatch (``mla_path``) to the kernels of the same file. With a
+  ``window`` a row sees its last ``window`` keys only (a sliding-window
+  layer): the kernel's grids then run over the band's blocks alone.
 - ``ring_attention``: sequence-parallel causal attention over an ICI
   ring. The reference has NO sequence parallelism in-tree (SURVEY.md
   §5.7); here it is first-class: K/V blocks rotate around the ``sp``
@@ -53,8 +55,21 @@ def flash_eligible(t: int, d: int) -> bool:
 
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      scale: float | None = None,
-                     force_flash: bool = False) -> jax.Array:
+                     force_flash: bool = False,
+                     window: int | None = None) -> jax.Array:
     """Causal attention [B, T, H, D] -> [B, T, H, D].
+
+    ``window``: row t sees keys ``t - window < j <= t``, ``window`` of
+    them with itself (a sliding-window layer; None: every key up to t).
+    Where the kernel is eligible it takes the window and skips the
+    blocks below the band through its grids
+    (``flash_attention(window=...)``), else XLA's
+    ``dot_product_attention(local_window_size=(window - 1, 0))``. The
+    paths that split the sequence over chips know no band and refuse a
+    window: ``make_sharded_causal_attention`` raises
+    ``NotImplementedError`` on a mesh with ``sp > 1`` (ring and Ulysses;
+    the halo of keys a chip would need from its neighbour is not
+    written).
 
     Single-device TPU with cleanly-blocking shapes, q, k and v of one
     shape, runs the Pallas flash kernel
@@ -78,12 +93,17 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     if _flash_ok(q, k, v) and (force_flash or jax.device_count() == 1):
         from ray_tpu.ops.pallas.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=True, scale=scale)
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               window=window)
     if not q.shape == k.shape == v.shape:
         from ray_tpu.util import tracing
         tracing.note_trace(flash_path="xla", flash_layout="unequal_shapes")
-    return jax.nn.dot_product_attention(q, k, v, scale=scale,
-                                        is_causal=True)
+    if window is None or window >= q.shape[1]:
+        return jax.nn.dot_product_attention(q, k, v, scale=scale,
+                                            is_causal=True)
+    return jax.nn.dot_product_attention(
+        q, k, v, scale=scale, is_causal=True,
+        local_window_size=(window - 1, 0))
 
 
 def _block_attend(q, k, v, acc, row_max, row_sum, mask_mode, scale):
@@ -192,14 +212,16 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
                                   seq_axis="sp", head_axis="tp",
-                                  impl="auto"):
+                                  impl="auto", window: int | None = None):
     """Build an attention fn for activations sharded
     [batch->dp/fsdp, seq->sp, heads->tp]: shard_map-wrapped ring
     attention when the mesh has a real sp axis, dense attention
     otherwise. ``impl`` forces a path: "dense" is incompatible with a
     real sp axis (activations are sequence-sharded, so each device
     only holds a slice of K/V) and raises rather than silently
-    running ring."""
+    running ring. ``window`` (``causal_attention``'s) goes to each
+    chip's own call; with ``sp > 1`` it raises ``NotImplementedError``:
+    ring and Ulysses know no band."""
     from jax.sharding import PartitionSpec as P
 
     if impl not in ("auto", "dense", "ring", "ulysses"):
@@ -207,6 +229,14 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
                          "expected 'auto', 'dense', 'ring' or "
                          "'ulysses'")
     sp = mesh.shape.get(seq_axis, 1)
+    if window is not None and sp > 1:
+        raise NotImplementedError(
+            f"a window of {window} keys on a mesh with {seq_axis}={sp}: "
+            "ring and Ulysses attention compute the whole causal "
+            "triangle, and a chip's first rows would need the last "
+            f"{window - 1} keys of its neighbour (a halo), which is not "
+            "written. dp, fsdp and tp shard the batch and the heads and "
+            "need nothing.")
     if impl == "dense" and sp > 1:
         raise ValueError(
             f"attn_impl='dense' cannot run on a mesh with "
@@ -228,13 +258,14 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
             # A one-device mesh is a one-device program whatever else
             # the process can see (one chip of a four-chip host): the
             # kernel runs bare, with no device-count guard.
-            return functools.partial(causal_attention, force_flash=True)
+            return functools.partial(causal_attention, force_flash=True,
+                                     window=window)
         if not batch and heads is None:
             # Attention operands replicated over a multi-device mesh
             # (pp- or ep-only): no axis to shard_map over, and the
             # bare kernel has no SPMD rule, so this is the XLA path.
             def dense(q, k, v):
-                return causal_attention(q, k, v)
+                return causal_attention(q, k, v, window=window)
             return dense
         # Batch/head-sharded, sequence-replicated: shard_map so each
         # device runs the local block — this is what lets the Pallas
@@ -255,13 +286,14 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
             # Shapes that don't divide the mesh (e.g. the tiny batch
             # used by init tracing) take the plain XLA path.
             if q.shape[0] % n_batch or q.shape[2] % n_heads:
-                return causal_attention(q, k, v)
+                return causal_attention(q, k, v, window=window)
             d = q.shape[-1]
 
             def local(*qkv):
                 q, k, v = (x.reshape(*x.shape[:2], -1, d) for x in qkv)
                 return causal_attention(
-                    q, k, v, force_flash=True).reshape(qkv[0].shape)
+                    q, k, v, force_flash=True,
+                    window=window).reshape(qkv[0].shape)
 
             merged = jax.shard_map(local, mesh=mesh,
                                    in_specs=(spec, spec, spec),

@@ -29,7 +29,12 @@ skew. What runs through it, by argument:
   ``experts``. ``routed_ffn`` is the same call with the routes made
   from a matrix first (``_routed`` is what the two share): the sort,
   the held share, the slabs, the grouped matmuls and the load are one
-  code.
+  code;
+- SmallThinker-21B-A3B (``models/smallthinker.py``), also through
+  ``routed_experts``: its router is this file's softmax router
+  (``route_softmax``: top-6 of 64, renormalised) but reads the block's
+  input before attention, and its experts are **ReGLU** (``expert=
+  "reglu"``: ``relu`` where SwiGLU has ``silu``), 16 of 64 held.
 
 On a mesh that shards tokens (dp, fsdp, sp) each chip routes and sorts
 its own tokens under ``shard_map`` with the experts replicated; a mesh
@@ -272,11 +277,16 @@ def _route_sigmoid(x, router_w, select_bias, top_k: int,
             jnp.zeros((router_w.shape[-1],), jnp.float32), jnp.float32(0))
 
 
-def _experts(xs, w_gate, w_up, w_down, counts, rows_per_expert: int):
+_GATES = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}
+
+
+def _experts(xs, w_gate, w_up, w_down, counts, rows_per_expert: int,
+             kind: str = "swiglu"):
     """The experts on rows sorted by expert, ``counts[e]`` of them for
-    expert ``e`` (``rows_per_expert`` at an even load): SwiGLU (three
-    grouped matmuls), or with no ``w_gate`` the un-gated relu^2 expert
-    (two)."""
+    expert ``e`` (``rows_per_expert`` at an even load): gated (three
+    grouped matmuls; ``kind`` names the gate's activation: SwiGLU's
+    ``silu`` or ReGLU's ``relu``, whose backward is a mask), or with no
+    ``w_gate`` the un-gated relu^2 expert (two)."""
     dt = xs.dtype
     gmm = functools.partial(_grouped_matmul, group_sizes=counts,
                             rows_per_expert=rows_per_expert)
@@ -285,7 +295,7 @@ def _experts(xs, w_gate, w_up, w_down, counts, rows_per_expert: int):
     else:
         gate = gmm(xs, w_gate.astype(dt))
         up = gmm(xs, w_up.astype(dt))
-        hidden = jax.nn.silu(gate) * up
+        hidden = _GATES[kind](gate) * up
     return gmm(hidden, w_down.astype(dt))
 
 
@@ -309,8 +319,9 @@ def _slab(lo, static, x, order, weights, sizes, w_gate, w_up, w_down):
     expert ``e``): their tokens' rows gathered, multiplied by the
     experts that own them, weighted and added to their tokens' rows of
     a float32 ``[t, d]``. ``lo`` may be traced; ``static`` is (the
-    slab's rows, top_k, an expert's rows at an even load)."""
-    rows, top_k, per_expert = static
+    slab's rows, top_k, an expert's rows at an even load, the experts'
+    kind)."""
+    rows, top_k, per_expert, kind = static
     with jax.named_scope("dispatch"):
         ends = jnp.cumsum(sizes)
         own = (jnp.clip(ends, lo, lo + rows)
@@ -320,7 +331,7 @@ def _slab(lo, static, x, order, weights, sizes, w_gate, w_up, w_down):
         token = route // top_k
         xs = jnp.where(live, x[token], jnp.zeros((), x.dtype))
     with jax.named_scope("experts"):
-        ys = _experts(xs, w_gate, w_up, w_down, own, per_expert)
+        ys = _experts(xs, w_gate, w_up, w_down, own, per_expert, kind)
     with jax.named_scope("combine"):
         # rows past the real ones hold whatever the kernel left
         ys = jnp.where(live, ys, jnp.zeros((), ys.dtype)) \
@@ -375,7 +386,7 @@ _slabs.defvjp(_slabs_fwd, _slabs_bwd)
 
 
 def _held_part(x, flat, weights, counts, w_gate, w_up, w_down, top_k,
-               experts_held):
+               experts_held, kind):
     """The held experts' part of every token's sum, ``[t, d]``. The
     held experts' routes sort to the front (by expert; every absent
     expert's route takes one key behind them), and the sorted routes
@@ -392,9 +403,9 @@ def _held_part(x, flat, weights, counts, w_gate, w_up, w_down, top_k,
                             num_keys=1, is_stable=True)
         # whole slabs: the rows past the routes are never live
         order = jnp.pad(order, (0, -routes % rows))
-    return _slabs((rows, top_k, max(1, routes // counts.shape[0])), x, order,
-                  weights, counts[first:first + held], w_gate, w_up, w_down
-                  ).astype(x.dtype)
+    return _slabs((rows, top_k, max(1, routes // counts.shape[0]), kind), x,
+                  order, weights, counts[first:first + held], w_gate, w_up,
+                  w_down).astype(x.dtype)
 
 
 def _given(x, weights, experts):
@@ -406,12 +417,14 @@ def _given(x, weights, experts):
 
 
 def _routed_ffn_local(x, route_args, w_gate, w_up, w_down, *, route,
-                      num_experts, top_k, over=(), experts_held=None):
+                      num_experts, top_k, over=(), experts_held=None,
+                      kind="swiglu"):
     """The layer on the tokens in hand (``[..., d]``), their routes
     made by ``route(x, *route_args)`` (``_route``, ``_route_sigmoid``
     or ``_given``); the router's sums are added over the mesh axes
     ``over`` so that the two losses and the load are those of the
-    global batch. ``w_gate`` None: the two-matrix relu^2 expert."""
+    global batch. ``w_gate`` None: the two-matrix relu^2 expert; else
+    ``kind`` is the gated expert's (``_experts``)."""
     shape = x.shape
     x = x.reshape(-1, shape[-1])
     t, e = x.shape[0], num_experts
@@ -430,7 +443,7 @@ def _routed_ffn_local(x, route_args, w_gate, w_up, w_down, *, route,
         z = z_sum / n
     if experts_held is not None:
         y = _held_part(x, flat, weights, counts, w_gate, w_up, w_down,
-                       top_k, experts_held)
+                       top_k, experts_held, kind)
         return y.reshape(shape), aux, z, load
     with jax.named_scope("dispatch"):
         iota = jnp.arange(t * top_k, dtype=jnp.int32)
@@ -439,7 +452,7 @@ def _routed_ffn_local(x, route_args, w_gate, w_up, w_down, *, route,
         xs = _dispatch(x, order, inverse, top_k)
     with jax.named_scope("experts"):
         ys = _experts(xs, w_gate, w_up, w_down, counts,
-                      t * top_k // e)
+                      t * top_k // e, kind)
     with jax.named_scope("combine"):
         dt = x.dtype
         ys = _unsort(ys, order, inverse).reshape(t, top_k, -1)
@@ -496,8 +509,9 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     the ``top_k`` largest, renormalised only with ``norm_topk_prob``)
     or ``"sigmoid"`` (Nemotron-H, DeepSeek-V3: ``_route_sigmoid``, with
     ``select_bias`` [E] and ``route_scale``). ``expert``: ``"swiglu"``
-    (three matrices, ``down(silu(gate x) * up x)``) or ``"relu2"`` (two,
-    ``down(relu(up x)^2)``; ``w_gate`` is None). ``experts_held =
+    (three matrices, ``down(silu(gate x) * up x)``), ``"reglu"`` (the
+    same three with ``relu`` on the gate: SmallThinker's) or ``"relu2"``
+    (two, ``down(relu(up x)^2)``; ``w_gate`` is None). ``experts_held =
     (first, count)``: this caller holds experts ``first .. first +
     count - 1`` of the ``E`` the router scores (one chip's share under
     expert parallelism; default: all). The router still sees every
@@ -530,10 +544,7 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     """
     if router not in ("softmax", "sigmoid"):
         raise ValueError(f"unknown router {router!r}")
-    if (expert == "relu2") != (w_gate is None) or expert not in (
-            "swiglu", "relu2"):
-        raise ValueError(f"expert {expert!r} with w_gate "
-                         f"{'absent' if w_gate is None else 'given'}")
+    _check_kind(expert, w_gate)
     e = router_w.shape[-1]
     if router == "sigmoid":
         route = functools.partial(
@@ -551,12 +562,20 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         said = dict(moe_router=router, moe_expert_kind=expert)
     return _routed(x, route, route_args, False, w_gate, w_up, w_down,
                    num_experts=e, top_k=top_k, mesh=mesh,
-                   experts_held=experts_held, said=said)
+                   experts_held=experts_held, kind=expert, said=said)
+
+
+def _check_kind(expert: str, w_gate) -> None:
+    if (expert == "relu2") != (w_gate is None) or expert not in (
+            "swiglu", "reglu", "relu2"):
+        raise ValueError(f"expert {expert!r} with w_gate "
+                         f"{'absent' if w_gate is None else 'given'}")
 
 
 def routed_experts(x, weights, experts, w_gate, w_up, w_down, *,
                    num_experts: int, mesh=None,
-                   experts_held: tuple[int, int] | None = None):
+                   experts_held: tuple[int, int] | None = None,
+                   expert: str | None = None):
     """``routed_ffn`` for a caller that makes the routes itself (a
     router that is not one matrix: ``models/zaya.py``'s is an MLP over
     a state the previous layer made).
@@ -565,22 +584,43 @@ def routed_experts(x, weights, experts, w_gate, w_up, w_down, *,
                               weights, float32, carrying the gradient
     experts: [batch, seq, k]  the experts they belong to, of
                               ``num_experts``
-    ``x``, the experts' matrices (``w_gate`` None: relu^2 experts),
-    ``mesh`` and ``experts_held`` as ``routed_ffn``; the sort, the slabs
-    and the grouped matmuls are the same code. Returns ``(y, load)``:
-    the held experts' part of each token's sum and the routes each of
-    the ``num_experts`` experts received, ``[E]`` float32."""
+    ``x``, the experts' matrices, ``mesh`` and ``experts_held`` as
+    ``routed_ffn``; ``expert`` its kinds too (default: ``"relu2"``
+    without a ``w_gate``, else ``"swiglu"``). The sort, the slabs and
+    the grouped matmuls are the same code. Returns ``(y, load)``: the
+    held experts' part of each token's sum and the routes each of the
+    ``num_experts`` experts received, ``[E]`` float32. Where the
+    caller's router is one matrix it can make its routes with
+    ``route_softmax``, this file's router, from another place than the
+    experts' input (SmallThinker's reads the block's input, before
+    attention)."""
+    expert = expert or ("relu2" if w_gate is None else "swiglu")
+    _check_kind(expert, w_gate)
     y, _, _, load = _routed(
         x, _given, (weights, experts), True, w_gate, w_up, w_down,
         num_experts=num_experts, top_k=weights.shape[-1], mesh=mesh,
-        experts_held=experts_held,
-        said=dict(moe_router="caller",
-                  moe_expert_kind="relu2" if w_gate is None else "swiglu"))
+        experts_held=experts_held, kind=expert,
+        said=dict(moe_router="caller", moe_expert_kind=expert))
     return y, load
 
 
+def route_softmax(x, router_w, *, top_k: int, norm_topk_prob: bool = False):
+    """``routed_ffn``'s softmax router for a caller that routes from
+    another place than its experts' input: ``x`` [..., d] against the
+    bias-free ``router_w`` [d, E] in float32 at the highest precision,
+    the ``top_k`` largest probabilities, renormalised over the chosen
+    under ``norm_topk_prob`` (which is a softmax over the chosen logits
+    alone). Returns ``(weights [..., k], experts [..., k])`` for
+    ``routed_experts``; a router is a few values a token, so each chip
+    routes the tokens it holds under any sharding of them."""
+    weights, experts, _, _ = _route(x.reshape(-1, x.shape[-1]), router_w,
+                                    top_k, norm_topk_prob)
+    return (weights.reshape(*x.shape[:-1], top_k),
+            experts.reshape(*x.shape[:-1], top_k))
+
+
 def _routed(x, route, route_args, per_token: bool, w_gate, w_up, w_down, *,
-            num_experts, top_k, mesh, experts_held, said):
+            num_experts, top_k, mesh, experts_held, said, kind="swiglu"):
     """What the two public forms share: the checks, ``shard_map`` over
     the axes that shard tokens (``route_args`` enter sharded like the
     tokens where they are ``per_token``, replicated where they are the
@@ -592,7 +632,7 @@ def _routed(x, route, route_args, per_token: bool, w_gate, w_up, w_down, *,
                          f"weights for {w_up.shape[0]}")
     local = functools.partial(
         _routed_ffn_local, route=route, num_experts=e, top_k=top_k,
-        experts_held=experts_held)
+        experts_held=experts_held, kind=kind)
     batch_axes, seq_axis = _token_axes(
         mesh, x.shape[0], x.shape[1] if x.ndim == 3 else 1)
     axes = batch_axes + ((seq_axis,) if seq_axis else ())

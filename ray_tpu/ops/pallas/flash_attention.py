@@ -40,6 +40,14 @@ whole tiles — and so computes the triangle too, not the square
 (``_causal_slabs``; how many it walked is in the trace's notes,
 ``flash_causal_slabs``, 1 for the square).
 
+A window (``flash_attention(window=w)``: row t sees keys t - w < j <=
+t, the sliding-window layers of a window/global stack) adds the band's
+second edge: the multi-block grids' innermost dimension runs over the
+band's blocks alone, in the forward, the dq and the dk/dv kernel, so
+the blocks below the band are in no cell; only the two blocks that
+straddle an edge pay for a mask (``_band`` and the comment above ``_keys_of``).
+With no window every kernel is the program it was.
+
 Set-up: the two functions that hold the pallas_calls are jitted, so a
 model's layers, which call them at one shape, trace each kernel and
 lower it to Mosaic once a trace of the step, not once a layer.
@@ -82,10 +90,12 @@ def _pick_block(t: int, target: int = 1024) -> int:
     return best
 
 
-def _masked_scores(q, k, iq, ik, *, scale, bq, bk, causal):
+def _masked_scores(q, k, iq, ik, *, scale, bq, bk, causal, window=None):
     """Scaled q·kᵀ for one (q-block, k-block) pair with the causal
     mask applied in absolute coordinates — shared by the fwd and both
-    bwd kernels so the mask can never diverge between passes."""
+    bwd kernels so the mask can never diverge between passes. Under a
+    ``window`` a row sees its last ``window`` keys, itself among them:
+    ``row - window < col <= row``."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale        # [bq, bk]
@@ -95,8 +105,99 @@ def _masked_scores(q, k, iq, ik, *, scale, bq, bk, causal):
             jnp.int32, (bq, bk), 0)
         cols = col0 + jax.lax.broadcasted_iota(
             jnp.int32, (bq, bk), 1)
-        s = jnp.where(rows >= cols, s, _NEG_INF)
+        seen = rows >= cols
+        if window is not None:
+            seen &= cols > rows - window
+        s = jnp.where(seen, s, _NEG_INF)
     return s
+
+
+# ---------------------------------------------------------------------------
+# the band of a window, in blocks
+# ---------------------------------------------------------------------------
+#
+# Under a window the live blocks of a q-block no longer start at key
+# block 0, and the live q-blocks of a key block end: the multi-block
+# grids' innermost dimension then runs over the band alone (``nb``
+# cells, the most any outer block needs) and the index maps add the
+# band's first block, so the blocks below the band are in no cell, as
+# the blocks above the diagonal are in none of the causal grid's live
+# ones. A cell past its outer block's last live block (the first rows
+# of the sequence, whose band is cut by its start) is skipped and its
+# index clamped to the block before, so it moves nothing. Of a band's
+# blocks the one on the diagonal and the one on the band's lower edge
+# pay for a mask; the blocks between are wholly visible.
+
+def _keys_of(iq, bq, bk, window, most=jnp.maximum):
+    """(first, last) live key block of q-block ``iq`` (``most``: the
+    built-in ``max`` where ``iq`` is a Python number)."""
+    return (most(iq * bq - (window - 1), 0) // bk,
+            (iq * bq + bq - 1) // bk)
+
+
+def _queries_of(ik, bq, bk, window, nq, least=jnp.minimum):
+    """(first, last) live q-block of key block ``ik``."""
+    return ((ik * bk) // bq,
+            least((ik * bk + bk + window - 2) // bq, nq - 1))
+
+
+def _wholly_seen(iq, ik, bq, bk, window):
+    """No entry of the block pair is masked: it lies under the
+    diagonal and above the band's lower edge."""
+    return ((ik * bk + bk - 1 <= iq * bq)
+            & (ik * bk > iq * bq + bq - 1 - window))
+
+
+def _band(t, bq, bk, window):
+    """(cells of the innermost grid dimension over key blocks, the same
+    over q-blocks, block pairs a head walks) under ``window``; with
+    None the causal grid's: every block in the grid, those at or under
+    the diagonal walked."""
+    nq, nk = t // bq, t // bk
+    if window is None:
+        return nk, nq, sum((i * bq + bq - 1) // bk + 1 for i in range(nq))
+    def count(span):
+        first, last = span
+        return last - first + 1
+    keys = [count(_keys_of(i, bq, bk, window, max)) for i in range(nq)]
+    queries = [count(_queries_of(j, bq, bk, window, nq, min))
+               for j in range(nk)]
+    return max(keys), max(queries), sum(keys)
+
+
+def _key_cell(iq, cell, *, bq, bk, causal, window):
+    """(key block, is it live) of cell ``cell`` of q-block ``iq``'s
+    innermost grid dimension: the cell's own number, live at or under
+    the diagonal; under a window the band's first block plus the cell,
+    live up to the band's last."""
+    if window is None:
+        return cell, (not causal) or (cell * bk <= iq * bq + bq - 1)
+    first, last = _keys_of(iq, bq, bk, window)
+    return first + cell, first + cell <= last
+
+
+def _query_cell(ik, cell, *, bq, bk, causal, window, nq):
+    """The same for key block ``ik``'s q-blocks (the dk/dv kernel)."""
+    if window is None:
+        return cell, (not causal) or (ik * bk <= cell * bq + bq - 1)
+    first, last = _queries_of(ik, bq, bk, window, nq)
+    return first + cell, first + cell <= last
+
+
+def _on_live(iq, ik, live, body, *, bq, bk, causal, window):
+    """``body(masked)`` for a ``live`` block pair (``iq``, ``ik``).
+    Without a window every live pair is masked where the row is causal;
+    under one only a pair that straddles the diagonal or the band's
+    lower edge is. (A row whose window opens past the band's first
+    block meets that block wholly masked: the forward's p are exp(0)
+    there, and the first block with a key the row sees wipes them,
+    corr = 0.)"""
+    if window is None:
+        pl.when(live)(functools.partial(body, causal))
+        return
+    whole = _wholly_seen(iq, ik, bq, bk, window)
+    pl.when(live & whole)(functools.partial(body, False))
+    pl.when(live & jnp.logical_not(whole))(functools.partial(body, True))
 
 
 # Rows of a causal slab on the single-block path (``_causal_slabs``).
@@ -124,24 +225,33 @@ def _causal_slabs(t: int, causal: bool) -> int:
     once more and streams only S rows past each: at 128 that costs what
     the triangle saves. At t=768 three slabs 0.59 + 1.17 against 0.78 +
     1.57; at t=512 two slabs, three quarters of the square, 0.49 + 0.74
-    against 0.38 + 0.74: no gain, so the square stays."""
+    against 0.38 + 0.74: no gain, so the square stays. Callers ask for
+    a row under a ``window`` as for one that is not causal: it takes
+    the square too, with the band masked in it (a test's window, a tiny
+    preset's: the models' are longer than a block)."""
     if causal and t % _SLAB_ROWS == 0 and t >= 3 * _SLAB_ROWS:
         return t // _SLAB_ROWS
     return 1
 
 
-def _slab_scores(q, k, *, scale, causal):
+def _slab_scores(q, k, *, scale, causal, window=None):
     """Scaled q·kᵀ of one row slab, [S, c]: its S rows are the last S
     of the c columns it is given, so the causal mask can bite only in
     the last S columns (the slab's diagonal square, the same lower
-    triangle for every slab) and is applied nowhere else."""
+    triangle for every slab) and is applied nowhere else. A ``window``
+    comes with the one slab that is the square (``_causal_slabs``):
+    the band's lower edge is cut out of the same triangle."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     if causal:
         rows, cols = s.shape
-        below = (jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 0)
-                 >= jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 1))
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 1)
+        below = row >= col
+        if window is not None:
+            assert rows == cols, (rows, cols)
+            below &= col > row - window
         diag = jnp.where(below, s[:, cols - rows:], _NEG_INF)
         s = diag if cols == rows else jnp.concatenate(
             [s[:, :cols - rows], diag], axis=1)
@@ -204,7 +314,9 @@ def _delta(o_ref, do_ref, sl, rows=slice(None)):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, scale, bq, bk, nk, d, hpb,
-                causal):
+                causal, window=None):
+    """``nk``: the cells of the innermost grid dimension, every key
+    block or, under a ``window``, the band's (``_band``)."""
     iq = pl.program_id(2)
     ik = pl.program_id(3)
 
@@ -214,17 +326,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # Causal: skip blocks strictly above the diagonal.
-    diag_ok = (not causal) or (ik * bk <= iq * bq + bq - 1)
-
-    @pl.when(diag_ok)
-    def _attend():
+    def attend(kb, masked):
         for j, sl in enumerate(_head_slices(d, hpb)):
             q = q_ref[0, :, sl]                # [bq, d]
             k = k_ref[0, :, sl]                # [bk, d]
             v = v_ref[0, :, sl]
-            s = _masked_scores(q, k, iq, ik, scale=scale, bq=bq,
-                               bk=bk, causal=causal)
+            s = _masked_scores(q, k, iq, kb, scale=scale, bq=bq,
+                               bk=bk, causal=masked, window=window)
 
             m_prev = m_ref[j]                  # [bq, 128] (replicated)
             block_max = jnp.max(s, axis=-1, keepdims=True)  # [bq, 1]
@@ -239,6 +347,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 preferred_element_type=jnp.float32)         # [bq, d]
             acc_ref[:, sl] = acc_ref[:, sl] * corr + pv
             m_ref[j] = m_new
+
+    # Blocks above the diagonal, and below a window's band, are skipped.
+    where = dict(bq=bq, bk=bk, causal=causal, window=window)
+    kb, live = _key_cell(iq, ik, **where)
+    _on_live(iq, kb, live, functools.partial(attend, kb), **where)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -256,7 +369,7 @@ def _slab_rows(t, slabs):
 
 
 def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       *, scale, t, d, hpb, causal, slabs):
+                       *, scale, t, d, hpb, causal, slabs, window=None):
     """Single-block forward: the whole row fits one block, so plain
     (one-pass) softmax replaces the streaming max/sum scratch state —
     fewer VPU ops and no cross-iteration scratch.
@@ -272,7 +385,8 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     rows = _slab_rows(t, slabs)
     for h, sl in enumerate(_head_slices(d, hpb)):
         s = [_slab_scores(q_ref[0, r, sl], k_ref[0, :r.stop, sl],
-                          scale=scale, causal=causal) for r in rows]
+                          scale=scale, causal=causal, window=window)
+             for r in rows]
         m = [jnp.max(x, axis=-1, keepdims=True) for x in s]  # [S, 1]
         p = [jnp.exp(x - m_i) for x, m_i in zip(s, m)]
         l = [jnp.maximum(jnp.sum(x, axis=-1, keepdims=True), 1e-30)
@@ -289,8 +403,9 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "causal", "bq", "bk", "d", "hpb", "interpret"))
-def _flash_fwd(q, k, v, *, scale, causal, bq, bk, d, hpb, interpret):
+    "scale", "causal", "bq", "bk", "d", "hpb", "interpret", "window"))
+def _flash_fwd(q, k, v, *, scale, causal, bq, bk, d, hpb, interpret,
+               window=None):
     """(out [N, T, G*L], lse [N, G, T/bq, hpb, bq]). Under ``jax.jit`` so
     that a model's layers, which call it at one shape, trace and lower
     it once a trace of the step and share one ``func.func``: XLA
@@ -306,7 +421,9 @@ def _flash_fwd(q, k, v, *, scale, causal, bq, bk, d, hpb, interpret):
         return pl.pallas_call(
             functools.partial(_fwd_single_kernel, scale=scale, t=t,
                               d=d, hpb=hpb, causal=causal,
-                              slabs=_causal_slabs(t, causal)),
+                              slabs=_causal_slabs(
+                                  t, causal and window is None),
+                              window=window),
             grid=(n, g),
             in_specs=[seq, seq, seq],
             out_specs=[seq,
@@ -314,11 +431,12 @@ def _flash_fwd(q, k, v, *, scale, causal, bq, bk, d, hpb, interpret):
             out_shape=out_shape,
             interpret=interpret,
         )(q, k, v)
+    nk, kv_spec = _key_cells(t, bq, bk, lanes, window)
     q_spec = _seq_spec(bq, lanes, lambda b, c, i, j: (b, i, c))
-    kv_spec = _seq_spec(bk, lanes, lambda b, c, i, j: (b, j, c))
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk,
-                          nk=nk, d=d, hpb=hpb, causal=causal),
+                          nk=nk, d=d, hpb=hpb, causal=causal,
+                          window=window),
         grid=(n, g, nq, nk),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec,
@@ -332,6 +450,20 @@ def _flash_fwd(q, k, v, *, scale, causal, bq, bk, d, hpb, interpret):
         compiler_params=_compiler_params(),
         interpret=interpret,
     )(q, k, v)
+
+
+def _key_cells(t, bq, bk, lanes, window):
+    """(cells of the innermost dimension, the k / v block's spec) of a
+    grid (batch, lane block, q-block, key cell): every key block, or
+    under a ``window`` the band's, counted from the q-block's first
+    live one and clamped to its last (``_band``'s comment)."""
+    if window is None:
+        return t // bk, _seq_spec(bk, lanes, lambda b, c, i, j: (b, j, c))
+
+    def block(b, c, i, j):
+        first, last = _keys_of(i, bq, bk, window)
+        return (b, jnp.minimum(first + j, last), c)
+    return _band(t, bq, bk, window)[0], _seq_spec(bk, lanes, block)
 
 
 def _vmem(shape):
@@ -351,10 +483,11 @@ def _compiler_params():
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                    dq_ref, delta_ref, acc_ref, *, scale, bq, bk, nk, d,
-                   hpb, causal):
+                   hpb, causal, window=None):
     """dq of one q-block, and its ``delta`` (the row sums of o * do,
     made once where the block's o and do are at hand) for this kernel's
-    k-steps and for the dk/dv kernel after it."""
+    k-steps and for the dk/dv kernel after it. ``nk`` as in
+    ``_fwd_kernel``."""
     iq = pl.program_id(2)
     ik = pl.program_id(3)
 
@@ -364,10 +497,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         for j, sl in enumerate(_head_slices(d, hpb)):
             delta_ref[j:j + 1] = _as_row(_delta(o_ref, do_ref, sl))
 
-    diag_ok = (not causal) or (ik * bk <= iq * bq + bq - 1)
-
-    @pl.when(diag_ok)
-    def _step():
+    def step(kb, masked):
         for j, sl in enumerate(_head_slices(d, hpb)):
             q = q_ref[0, :, sl]
             k = k_ref[0, :, sl]
@@ -377,8 +507,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             do = do_ref[0, :, sl]
             lse = _as_col(lse_ref[j:j + 1])      # [bq, 1]
             delta = _as_col(delta_ref[j:j + 1])  # [bq, 1]
-            s = _masked_scores(q, k, iq, ik, scale=scale, bq=bq,
-                               bk=bk, causal=causal)
+            s = _masked_scores(q, k, iq, kb, scale=scale, bq=bq,
+                               bk=bk, causal=masked, window=window)
             p = jnp.exp(s - lse)                            # [bq, bk]
             dov = jax.lax.dot_general(
                 do, v, (((1,), (1,)), ((), ())),
@@ -388,6 +518,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
+    where = dict(bq=bq, bk=bk, causal=causal, window=window)
+    kb, live = _key_cell(iq, ik, **where)
+    _on_live(iq, kb, live, functools.partial(step, kb), **where)
+
     @pl.when(ik == nk - 1)
     def _finalize():
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
@@ -395,7 +529,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, scale, bq, bk, nq, d, hpb, causal):
+                    *, scale, bq, bk, nq, d, hpb, causal, window=None,
+                    q_blocks=None):
+    """``nq``: the cells of the innermost grid dimension, every q-block
+    or, under a ``window``, those of a key block's band: its live
+    q-blocks (of the row's ``q_blocks``) start at its diagonal and END
+    where the window has passed it."""
     ik = pl.program_id(2)
     iq = pl.program_id(3)
 
@@ -404,10 +543,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    diag_ok = (not causal) or (ik * bk <= iq * bq + bq - 1)
-
-    @pl.when(diag_ok)
-    def _step():
+    def step(qb, masked):
         for j, sl in enumerate(_head_slices(d, hpb)):
             q = q_ref[0, :, sl]
             k = k_ref[0, :, sl]
@@ -415,8 +551,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             do = do_ref[0, :, sl]              # bf16 operand for the MXU
             lse = _as_col(lse_ref[j:j + 1])      # [bq, 1]
             delta = _as_col(delta_ref[j:j + 1])  # [bq, 1]
-            s = _masked_scores(q, k, iq, ik, scale=scale, bq=bq,
-                               bk=bk, causal=causal)
+            s = _masked_scores(q, k, qb, ik, scale=scale, bq=bq,
+                               bk=bk, causal=masked, window=window)
             p = jnp.exp(s - lse)                            # [bq, bk]
             dv_acc[:, sl] += jax.lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -429,6 +565,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)         # [bk, d]
 
+    where = dict(bq=bq, bk=bk, causal=causal, window=window)
+    qb, live = _query_cell(ik, iq, nq=q_blocks, **where)
+    _on_live(qb, ik, live, functools.partial(step, qb), **where)
+
     @pl.when(iq == nq - 1)
     def _finalize():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
@@ -437,7 +577,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref, *acc, scale, t, d, hpb,
-                      causal, slabs):
+                      causal, slabs, window=None):
     """Single-block backward (t fits one block): computes the score
     matrix ONCE for dq, dk, AND dv — the two-pass kernels each
     recompute s/p/dov, so this saves a full [t,t] matmul + exp pass.
@@ -454,7 +594,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         lse = [_as_col(lse_ref[h:h + 1, r]) for r in rows]  # [S, 1]
         delta = [_delta(o_ref, do_ref, sl, r) for r in rows]
         s = [_slab_scores(q_ref[0, r, sl], k_ref[0, :r.stop, sl],
-                          scale=scale, causal=causal) for r in rows]
+                          scale=scale, causal=causal, window=window)
+             for r in rows]
         dov = [jax.lax.dot_general(
                    do_ref[0, r, sl], v_ref[0, :r.stop, sl],
                    (((1,), (1,)), ((), ())),
@@ -492,9 +633,9 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "causal", "bq", "bk", "d", "hpb", "interpret"))
+    "scale", "causal", "bq", "bk", "d", "hpb", "interpret", "window"))
 def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk, d, hpb,
-               interpret):
+               interpret, window=None):
     """(dq, dk, dv), each [N, T, G*L]; jitted for the reason
     ``_flash_fwd`` is."""
     n, t, w = q.shape
@@ -505,10 +646,11 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk, d, hpb,
     grads = [jax.ShapeDtypeStruct((n, t, w), x.dtype) for x in (q, k, v)]
     if nq == 1 and nk == 1:
         seq = _seq_spec(t, lanes, lambda b, c: (b, 0, c))
-        slabs = _causal_slabs(t, causal)
+        slabs = _causal_slabs(t, causal and window is None)
         return pl.pallas_call(
             functools.partial(_bwd_fused_kernel, scale=scale, t=t,
-                              d=d, hpb=hpb, causal=causal, slabs=slabs),
+                              d=d, hpb=hpb, causal=causal, slabs=slabs,
+                              window=window),
             grid=(n, ng),
             in_specs=[seq, seq, seq, seq, seq,
                       _stat_spec(hpb, t, lambda b, c: (b, c, 0, 0, 0))],
@@ -518,13 +660,14 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk, d, hpb,
             interpret=interpret,
         )(q, k, v, out, do, lse)
 
+    key_cells, kv_spec = _key_cells(t, bq, bk, lanes, window)
     q_spec = _seq_spec(bq, lanes, lambda b, c, i, j: (b, i, c))
-    kv_spec = _seq_spec(bk, lanes, lambda b, c, i, j: (b, j, c))
     stat = _stat_spec(hpb, bq, lambda b, c, i, j: (b, c, i, 0, 0))
     dq, delta = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, bq=bq, bk=bk,
-                          nk=nk, d=d, hpb=hpb, causal=causal),
-        grid=(n, ng, nq, nk),
+                          nk=key_cells, d=d, hpb=hpb, causal=causal,
+                          window=window),
+        grid=(n, ng, nq, key_cells),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, stat],
         out_specs=[q_spec, stat],
         out_shape=[grads[0], jax.ShapeDtypeStruct(lse.shape, lse.dtype)],
@@ -533,13 +676,28 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk, d, hpb,
         interpret=interpret,
     )(q, k, v, out, do, lse)
 
-    q_spec = _seq_spec(bq, lanes, lambda b, c, j, i: (b, i, c))
+    if window is None:
+        q_cells = nq
+
+        def q_block(j, i):
+            return i
+    else:
+        # a key block's live q-blocks, from its diagonal to where the
+        # window has passed it; cells past them are clamped to the last
+        q_cells = _band(t, bq, bk, window)[1]
+
+        def q_block(j, i):
+            first, last = _queries_of(j, bq, bk, window, nq)
+            return jnp.minimum(first + i, last)
+    q_spec = _seq_spec(bq, lanes, lambda b, c, j, i: (b, q_block(j, i), c))
     kv_spec = _seq_spec(bk, lanes, lambda b, c, j, i: (b, j, c))
-    stat = _stat_spec(hpb, bq, lambda b, c, j, i: (b, c, i, 0, 0))
+    stat = _stat_spec(hpb, bq,
+                      lambda b, c, j, i: (b, c, q_block(j, i), 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, bq=bq, bk=bk,
-                          nq=nq, d=d, hpb=hpb, causal=causal),
-        grid=(n, ng, nk, nq),
+                          nq=q_cells, d=d, hpb=hpb, causal=causal,
+                          window=window, q_blocks=nq),
+        grid=(n, ng, nk, q_cells),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat, stat],
         out_specs=[kv_spec, kv_spec],
         out_shape=grads[1:],
@@ -563,6 +721,7 @@ class _Static(NamedTuple):
     d: int          # head width
     hpb: int        # heads in a lane block
     interpret: bool
+    window: int | None = None   # keys a row sees, itself among them
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -599,8 +758,21 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     scale: float | None = None,
                     block_q: int | None = None,
                     block_k: int | None = None,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False,
+                    window: int | None = None) -> jax.Array:
     """Flash attention on [B, T, H, D]; differentiable (custom VJP).
+
+    ``window``: a row sees its last ``window`` keys, itself among them
+    (``t - window < j <= t``: the sliding-window layers of a
+    window/global stack); None, or a window no shorter than the row, is
+    plain causal attention and the program it was. The multi-block
+    grids run over the band alone, in both passes (``_band``'s
+    comment); a call given a window says so in the notes:
+    ``flash_window`` (``"none"`` where the row is no longer than it) and
+    ``flash_band_blocks``, the block pairs a head walks (70 at 16,384
+    rows in blocks of 1,024 under a window of 4,096, against the causal
+    grid's 136). In a stack that mixes windowed and global layers the
+    notes are those of the last windowed call.
 
     Falls back to the caller's dense path when shapes don't block
     cleanly — check with ``flash_attention_shapes_ok`` or catch
@@ -621,6 +793,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if bq == 0 or bk == 0 or t % bq or t % bk:
         raise ValueError(
             f"seq len {t} not divisible into flash blocks")
+    asked = window is not None
+    if asked:
+        if not causal or window < 1:
+            raise ValueError(f"window {window} (causal={causal}): a "
+                             "window is the last `window` keys of a "
+                             "causal row")
+        window = None if window >= t else int(window)
     direct = _heads_per_block(h, d)
     hpb = direct or 1           # folded: one head a block
     single = bq == t == bk
@@ -630,8 +809,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         flash_layout="bthd" if direct else "folded",
         flash_lanes_per_block=d * hpb,
         flash_path="single_block" if single else "multi_block",
-        flash_causal_slabs=_causal_slabs(t, causal) if single else 1)
-    static = _Static(float(scale), causal, bq, bk, d, hpb, interpret)
+        flash_causal_slabs=_causal_slabs(t, causal and window is None)
+        if single else 1)
+    if asked:   # a call without a window leaves the notes it left
+        tracing.note_trace(flash_window=window or "none",
+                           flash_band_blocks=_band(t, bq, bk, window)[2])
+    static = _Static(float(scale), causal, bq, bk, d, hpb, interpret, window)
     if direct:
         out = _flash_core(q.reshape(b, t, h * d), k.reshape(b, t, h * d),
                           v.reshape(b, t, h * d), static)

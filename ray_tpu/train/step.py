@@ -10,6 +10,7 @@ Python (SURVEY.md §3.4); here the collective IS part of the program.
 
 from __future__ import annotations
 
+import re
 import threading
 import time
 from typing import Any, Callable
@@ -47,7 +48,24 @@ def init_train_state(params, optimizer, mesh=None, extra=None,
                       opt_state=opt_state, extra=extra)
 
 
-def _step_body(loss_fn, optimizer, has_extra, grad_norm):
+def _group_norms(grads, groups: dict[str, str]) -> dict:
+    """{name: the global norm of the gradient leaves whose path (the
+    tree's keys joined by ``/``: ``h_1/attn/q/kernel``) the group's
+    regular expression finds}; a group that finds no leaf is an error."""
+    import optax
+    leaves = {"/".join(str(getattr(k, "key", k)) for k in path): g
+              for path, g in jax.tree_util.tree_flatten_with_path(grads)[0]}
+    norms = {}
+    for name, pattern in groups.items():
+        found = [g for path, g in leaves.items() if re.search(pattern, path)]
+        if not found:
+            raise ValueError(f"grad_groups[{name!r}] = {pattern!r} finds "
+                             f"none of {sorted(leaves)}")
+        norms[name] = optax.global_norm(found)
+    return norms
+
+
+def _step_body(loss_fn, optimizer, has_extra, grad_norm, grad_groups=None):
     def loss_and_report(params, batch):
         """A loss function may return ``(loss, report)``: the report's
         scalars ride beside the loss in the step's metrics. A scalar
@@ -75,6 +93,8 @@ def _step_body(loss_fn, optimizer, has_extra, grad_norm):
             new_params = optax.apply_updates(state.params, updates)
             if grad_norm:
                 metrics["grad_norm"] = optax.global_norm(grads)
+            if grad_groups:
+                metrics.update(_group_norms(grads, grad_groups))
             new_state = TrainState(step=state.step + 1,
                                    params=new_params, opt_state=new_opt,
                                    extra=new_extra)
@@ -178,7 +198,8 @@ def _listen_for_compiles() -> None:
 def make_train_step(loss_fn: Callable, optimizer,
                     has_extra: bool = False,
                     donate: bool = True,
-                    grad_norm: bool = True) -> Callable:
+                    grad_norm: bool = True,
+                    grad_groups: dict[str, str] | None = None) -> Callable:
     """Build the jitted step: forward, backward, gradient psum (via
     sharding propagation) and the optimizer update fused into ONE
     compiled program with the param/opt-state buffers donated — the
@@ -191,9 +212,14 @@ def make_train_step(loss_fn: Callable, optimizer,
     Returns step(state, batch) -> (state, metrics).
     ``grad_norm=False`` skips the global-norm metric (a full f32 read
     of every gradient leaf — measurable on HBM-bound steps).
+    ``grad_groups``: {metric name: regular expression over a leaf's
+    path, ``h_1/attn/q/kernel``}: the global norm of the gradient
+    leaves each finds rides in the metrics under its name (the
+    attention projections' alone, one layer's: where the whole norm is
+    dominated by other leaves). None adds nothing to the program.
     """
     _listen_for_compiles()
-    step = _step_body(loss_fn, optimizer, has_extra, grad_norm)
+    step = _step_body(loss_fn, optimizer, has_extra, grad_norm, grad_groups)
     return jax.jit(step, donate_argnums=(0,) if donate else ())
 
 

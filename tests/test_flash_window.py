@@ -1,0 +1,196 @@
+"""The window in the flash kernels (``ops/pallas/flash_attention.py``):
+the kernels in interpret mode against a dense masked softmax written
+from the definition, outputs and the three gradients; that the blocks
+below the band are in no grid cell; and the dispatch above the kernels
+(``ops/attention.py``)."""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import attention
+from ray_tpu.util import tracing
+
+fa = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
+
+
+def _dense(q, k, v, window):
+    """Row t sees keys t - window < j <= t, from the definition."""
+    t, d = q.shape[1], q.shape[-1]
+    s = jnp.einsum("bthd,bshd->bhts", q, k) / np.sqrt(d)
+    row, col = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    seen = col <= row
+    if window is not None:
+        seen &= col > row - window
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhts,bshd->bthd", p, v)
+
+
+def _qkvg(t, h, d, seed=0):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    return [jax.random.normal(k, (2, t, h, d), jnp.float32) for k in keys]
+
+
+@pytest.fixture
+def notes(monkeypatch):
+    """What the traced code says of itself, caught at the call: a train
+    step's listener, once a test of this process has built one, takes
+    the thread's notes away at the next trace."""
+    said = {}
+    monkeypatch.setattr(tracing, "note_trace", said.update)
+    return said
+
+
+def _out_and_grads(fn, q, k, v, g):
+    out = fn(q, k, v)
+    return (out, *jax.grad(lambda *a: (fn(*a) * g).sum(), (0, 1, 2))(q, k, v))
+
+
+# (rows, block): 256 rows in blocks of 64 is the multi-block grid, 128 in
+# one block the single-block body
+@pytest.mark.parametrize("t, block", [(256, 64), (128, 128)],
+                         ids=["multi_block", "single_block"])
+@pytest.mark.parametrize("h, d", [(1, 128), (2, 64)],
+                         ids=["one_head_a_block", "two_heads_a_block"])
+@pytest.mark.parametrize("window", [1, 24, 64, 65, 150, 4096],
+                         ids=["itself", "under_a_block", "a_block",
+                              "a_block_and_one", "several_blocks",
+                              "the_whole_row"])
+def test_windowed_kernels_are_the_dense_masked_softmax(window, h, d, t,
+                                                       block, notes):
+    q, k, v, g = _qkvg(t, h, d)
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, window=window, block_q=block,
+                                  block_k=block, interpret=True)
+    got = _out_and_grads(flash, q, k, v, g)
+    want = _out_and_grads(lambda *a: _dense(*a, window), q, k, v, g)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
+    if window >= t:
+        # the whole row: plain causal attention, the kernels' program
+        # without a window
+        assert notes["flash_window"] == "none"
+        causal = _out_and_grads(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, block_q=block, block_k=block, interpret=True),
+            q, k, v, g)
+        for a, b in zip(got, causal):
+            np.testing.assert_array_equal(a, b)
+    else:
+        assert notes["flash_window"] == window
+
+
+def test_a_call_without_a_window_leaves_the_notes_it_left(notes):
+    q, k, v, _ = _qkvg(128, 1, 128)
+    fa.flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
+    assert notes["flash_path"] == "multi_block"
+    assert "flash_window" not in notes and "flash_band_blocks" not in notes
+
+
+@pytest.mark.parametrize("t, block, window, cells, walked", [
+    (16384, 1024, 4096, 5, 70),     # the cell: 70 of the causal 136
+    (16384, 512, 4096, 9, 252),     # 63 blocks of 1,024's worth
+    (16384, 1024, None, 16, 136),
+    (256, 64, 24, 2, 7), (256, 64, 64, 2, 7), (256, 64, 65, 2, 7),
+    (256, 64, 66, 3, 9), (256, 64, 1, 1, 4), (256, 64, 150, 4, 10)])
+def test_blocks_below_the_band_are_in_no_grid_cell(t, block, window, cells,
+                                                   walked):
+    """``_band``: the innermost grid dimension is the band's cells (the
+    most any outer block needs) in the forward and dq kernels (key
+    cells) and in the dk/dv kernel (query cells), and the block pairs a
+    head walks are the live ones alone; against a count from the
+    definition."""
+    key_cells, query_cells, pairs = fa._band(t, block, block, window)
+    assert (key_cells, query_cells, pairs) == (cells, cells, walked)
+    n = t // block
+    live = np.zeros((n, n), bool)
+    w = t if window is None else window
+    for i in range(n):
+        for j in range(n):
+            rows = np.arange(i * block, (i + 1) * block)[:, None]
+            cols = np.arange(j * block, (j + 1) * block)[None, :]
+            live[i, j] = ((cols <= rows) & (cols > rows - w)).any()
+    assert pairs == live.sum()
+    if window is not None:
+        assert key_cells == live.sum(1).max()
+        assert query_cells == live.sum(0).max()
+        for i in range(n):      # the index maps: first + cell, clamped
+            first, last = (int(x) for x in fa._keys_of(i, block, block,
+                                                       window))
+            assert list(np.flatnonzero(live[i])) == list(
+                range(first, last + 1))
+        for j in range(n):
+            first, last = (int(x) for x in fa._queries_of(j, block, block,
+                                                          window, n))
+            assert list(np.flatnonzero(live[:, j])) == list(
+                range(first, last + 1))
+
+
+def test_the_windowed_grid_is_the_band_and_the_note_counts_it(notes):
+    """The lowered forward call's grid: (batch, lane blocks, q-blocks,
+    the band's cells), not the row's key blocks."""
+    q, k, v, _ = _qkvg(256, 1, 128)
+    fa.flash_attention(q, k, v, window=70, block_q=64, block_k=64,
+                       interpret=True)
+    assert notes["flash_band_blocks"] == fa._band(256, 64, 64, 70)[2] == 9
+    assert notes["flash_path"] == "multi_block"
+    jaxpr = jax.make_jaxpr(lambda q, k, v: fa._flash_fwd(
+        q, k, v, scale=1.0, causal=True, bq=64, bk=64, d=128, hpb=1,
+        interpret=False, window=70))(
+            *(x.reshape(2, 256, 128) for x in (q, k, v)))
+    grids = [e.params["jaxpr"].eqns[0].params["grid_mapping"].grid
+             for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
+    assert grids == [(2, 1, 4, 3)]
+
+
+def test_a_window_needs_a_causal_row():
+    q, k, v, _ = _qkvg(128, 1, 128)
+    with pytest.raises(ValueError, match="window"):
+        fa.flash_attention(q, k, v, causal=False, window=8, interpret=True)
+    with pytest.raises(ValueError, match="window"):
+        fa.flash_attention(q, k, v, window=0, interpret=True)
+
+
+# -- the dispatch above the kernels ----
+
+@pytest.mark.parametrize("window", [1, 24, 64, 1000])
+def test_causal_attention_takes_the_window_on_the_xla_path(window):
+    """On the CPU ``causal_attention`` is XLA's
+    ``dot_product_attention``: a query sees exactly ``window`` keys."""
+    q, k, v, _ = _qkvg(64, 2, 16)
+    got = attention.causal_attention(q, k, v, window=window)
+    np.testing.assert_allclose(got, _dense(q, k, v, window), atol=2e-5)
+
+
+def test_the_kernel_gets_the_window_where_it_is_eligible(monkeypatch):
+    q, k, v, _ = _qkvg(256, 1, 128)
+    asked = {}
+
+    def flash(q, k, v, **kw):
+        asked.update(kw)
+        return q
+    monkeypatch.setattr(attention, "_flash_ok", lambda *a: True)
+    monkeypatch.setattr(fa, "flash_attention", flash)
+    attention.causal_attention(q, k, v, force_flash=True, window=24)
+    assert asked["window"] == 24 and asked["causal"] is True
+    attention.causal_attention(q, k, v, force_flash=True)
+    assert asked["window"] is None
+
+
+def test_a_window_on_a_mesh_that_splits_the_sequence_is_refused():
+    from ray_tpu.parallel.mesh import make_mesh
+    devices = jax.devices()[:4]
+    mesh = make_mesh({"dp": 2, "sp": 2}, devices=devices)
+    with pytest.raises(NotImplementedError, match="halo"):
+        attention.make_sharded_causal_attention(mesh, window=16)
+    assert attention.make_sharded_causal_attention(mesh) is not None
+    # dp alone: each chip's own rows under shard_map, with the window
+    mesh = make_mesh({"dp": 4}, devices=devices)
+    q, k, v, _ = _qkvg(64, 2, 16)
+    q, k, v = (jnp.concatenate([x, x]) for x in (q, k, v))
+    got = attention.make_sharded_causal_attention(mesh, window=24)(q, k, v)
+    np.testing.assert_allclose(got, _dense(q, k, v, 24), atol=2e-5)
