@@ -26,11 +26,28 @@ either pass. A shape that cannot be blocked on whole 128-lane tiles
 kernels run it. ``flash_attention`` leaves which one ran in the trace's
 notes (``flash_layout`` = "bthd" | "folded").
 
-Grid (both passes): (batch, lane blocks) where a whole row fits one
-block (T <= 1024), else (batch, lane blocks, outer_block, inner_block)
-with the innermost grid dimension "arbitrary" (sequential on TPU), so
-VMEM scratch carries state across inner steps of one outer block.
-Folded, "batch" is batch*heads and there is one lane block.
+Grid: (batch, lane blocks) where a whole row fits one block (T <=
+1024), in both passes. A longer row's forward is (batch, lane blocks,
+q-block, key cell) with the innermost dimension "arbitrary" (sequential
+on TPU), so VMEM scratch carries the streaming softmax across the key
+blocks of one q-block. Its backward is ONE kernel (``_bwd_kernel``)
+over (batch, lane blocks, key block, query cell), the last two in
+order: a cell makes the scores, the probabilities, ``dO v^T`` and
+``ds`` of its block pair once and feeds ``dv``, ``dk`` and ``dq`` from
+them, five MXU passes; ``dk`` / ``dv`` of the key block ride in float32
+scratch across its cells, ``dq`` of the lane block's whole row
+(``[T, 128]``: 8.4 MB at 16,384 rows) across the key blocks, a
+q-block's rows leaving at its last live key block, and ``delta`` is
+made in VMEM and never an array in HBM. That is what equal blocks
+(``_pick_block``'s, every model's) take where those rows fit the VMEM
+the kernel asks for (``_bwd_fits``). Uneven blocks given by hand, and
+rows past the budget, take the pair it replaced: a dq kernel (key cell
+innermost) and a dk/dv kernel (query cell innermost), which hold a
+block's rows whatever the row's length and each make the scores and
+``dO v^T`` again, seven passes. Nothing but the shapes decides; the
+notes say which ran (``flash_bwd_kernels`` 1 | 2,
+``flash_bwd_resident_rows``). Folded, "batch" is batch*heads and there
+is one lane block.
 
 The causal triangle: the multi-block kernels skip the blocks above the
 diagonal through the grid. A single-block body has no grid to skip
@@ -43,8 +60,9 @@ whole tiles — and so computes the triangle too, not the square
 A window (``flash_attention(window=w)``: row t sees keys t - w < j <=
 t, the sliding-window layers of a window/global stack) adds the band's
 second edge: the multi-block grids' innermost dimension runs over the
-band's blocks alone, in the forward, the dq and the dk/dv kernel, so
-the blocks below the band are in no cell; only the two blocks that
+band's blocks alone, in the forward and in the backward (the one
+kernel's query cells; the pair's key and query cells), so the blocks
+below the band are in no cell; only the two blocks that
 straddle an edge pay for a mask (``_band`` and the comment above ``_keys_of``).
 With no window every kernel is the program it was.
 
@@ -403,13 +421,16 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "causal", "bq", "bk", "d", "hpb", "interpret", "window"))
+    "scale", "causal", "bq", "bk", "d", "hpb", "interpret", "window",
+    "one_bwd"))
 def _flash_fwd(q, k, v, *, scale, causal, bq, bk, d, hpb, interpret,
-               window=None):
+               window=None, one_bwd=False):
     """(out [N, T, G*L], lse [N, G, T/bq, hpb, bq]). Under ``jax.jit`` so
     that a model's layers, which call it at one shape, trace and lower
     it once a trace of the step and share one ``func.func``: XLA
-    inlines the calls again, each under its caller's scope."""
+    inlines the calls again, each under its caller's scope. Takes
+    ``_Static`` whole; ``one_bwd`` is the backward's alone."""
+    del one_bwd
     n, t, w = q.shape
     lanes = d * hpb
     g = w // lanes
@@ -471,10 +492,15 @@ def _vmem(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
 
-def _compiler_params():
+def _compiler_params(sequential: int = 1, vmem: int | None = None):
+    """The last ``sequential`` of a grid's four dimensions run in order;
+    ``vmem``: what the kernel may hold, where that is more than the
+    16 MiB a kernel gets unasked."""
     from jax.experimental.pallas import tpu as pltpu
-    return pltpu.CompilerParams(dimension_semantics=(
-        "parallel", "parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (4 - sequential)
+        + ("arbitrary",) * sequential,
+        vmem_limit_bytes=vmem)
 
 
 # ---------------------------------------------------------------------------
@@ -575,6 +601,134 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, delta_ref,
+                *, scale, blk, nb, cells, d, hpb, causal, window=None):
+    """The whole backward pass of one block pair at equal blocks (``blk``
+    rows each way, ``nb`` of them a row): ``s``, ``p``, ``dov`` and
+    ``ds`` made once and the three products fed from them, five MXU
+    passes where ``_bwd_dq_kernel`` and ``_bwd_dkv_kernel`` make seven.
+    Grid (batch, lane block, key block, query cell), the last two in
+    order; ``cells`` as ``_bwd_dkv_kernel``'s ``nq``. In float32 scratch:
+    ``dk`` / ``dv`` of the key block across its cells; ``dq`` of the
+    lane block's WHOLE row across the key blocks, a q-block's rows
+    zeroed in its first live key block and cast and written in its last;
+    ``delta``, made in that first one from the ``o`` fetched there
+    alone. Sums run in the pair's order (key blocks ascending into
+    ``dq``, q-blocks ascending into ``dk`` / ``dv``): the gradients are
+    the pair's bit for bit."""
+    ik = pl.program_id(2)
+    cell = pl.program_id(3)
+    where = dict(bq=blk, bk=blk, causal=causal, window=window)
+    qb, live = _query_cell(ik, cell, nq=nb, **where)
+    first_key = 0 if window is None else _keys_of(qb, blk, blk, window)[0]
+    last_key = qb if causal else nb - 1     # equal blocks: the diagonal
+    q_rows = pl.ds(pl.multiple_of(qb * blk, blk), blk)
+    heads = _head_slices(d, hpb)
+
+    @pl.when(live & (ik == first_key))
+    def _first_key_block():
+        dq_acc[q_rows] = jnp.zeros((blk, dq_acc.shape[1]), jnp.float32)
+        for j, sl in enumerate(heads):
+            delta_ref[qb, j:j + 1] = _as_row(_delta(o_ref, do_ref, sl))
+
+    @pl.when(cell == 0)
+    def _first_cell():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def step(masked):
+        for j, sl in enumerate(heads):
+            q = q_ref[0, :, sl]
+            k = k_ref[0, :, sl]
+            v = v_ref[0, :, sl]
+            do = do_ref[0, :, sl]              # bf16 operand for the MXU
+            lse = _as_col(lse_ref[j:j + 1])                 # [blk, 1]
+            delta = _as_col(delta_ref.at[qb][j:j + 1])      # [blk, 1]
+            s = _masked_scores(q, k, qb, ik, scale=scale, bq=blk,
+                               bk=blk, causal=masked, window=window)
+            p = jnp.exp(s - lse)                            # [blk, blk]
+            dv_acc[:, sl] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # [blk, d]
+            dov = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = (p * (dov - delta) * scale).astype(q.dtype)
+            dk_acc[:, sl] += jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dq_acc[q_rows, sl] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    _on_live(qb, ik, live, step, **where)
+
+    @pl.when(live & (ik == last_key))   # no later key block reaches them
+    def _dq_whole():
+        dq_ref[0] = dq_acc[q_rows].astype(dq_ref.dtype)
+
+    @pl.when(cell == cells - 1)
+    def _dkv_whole():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _bwd_blocks(nb, blk, causal, window):
+    """``_bwd_kernel``'s grid over ``nb`` blocks each way: (cells of the
+    innermost dimension, then three maps from (key block, cell) to the
+    q-block whose q, dO and statistics the cell reads, to the one whose
+    ``o`` it reads, and to the one whose ``dq`` it may fill). A dead
+    cell's q-block is clamped to a live one, so it moves nothing; ``o``
+    is fetched where a q-block meets its first live key block (delta)
+    and stays on the last one fetched elsewhere; ``dq``'s block is that
+    of the q-block whose last live key block this is (the diagonal's,
+    equal blocks), so each is written to HBM once, when the index moves
+    on."""
+    cells = _band(nb * blk, blk, blk, window)[1]
+
+    def last_q(j):      # the last live q-block of key block j
+        if window is None:
+            return nb - 1
+        return _queries_of(j, blk, blk, window, nb)[1]
+
+    def q_block(j, i):
+        if window is None:
+            return jnp.maximum(i, j) if causal else i
+        return jnp.minimum(j + i, last_q(j))
+
+    def o_block(j, i):
+        qb = q_block(j, i)
+        return jnp.where((j == 0) | (qb == last_q(j)), qb, last_q(j - 1))
+
+    def dq_block(j, i):
+        return j if causal else jnp.where(j == nb - 1, i, 0)
+    return cells, q_block, o_block, dq_block
+
+
+# What the one-kernel backward may hold in VMEM (a v5e has 128 MiB).
+_BWD_VMEM = 64 * 1024 * 1024
+
+
+def _bwd_fits(t: int, blk: int, lanes: int) -> bool:
+    """Does ``_bwd_kernel`` fit ``_BWD_VMEM`` at ``t`` rows in blocks of
+    ``blk``? Counted from the shapes, as ``_mla_bwd_fits`` is. What grows
+    with ``t``: the float32 ``dq`` of a lane block's whole row and
+    delta's rows (a block's heads padded to 8 sublanes), 544 bytes a row
+    at 128 lanes. What a cell holds whatever ``t`` is: the five 2-byte
+    operand blocks and the three output blocks twice over, the
+    statistics' block twice, ``dk``'s and ``dv``'s accumulators, and four
+    [blk, blk] float32 squares for the body's scores, probabilities and
+    cotangents."""
+    lanes = -(-lanes // 128) * 128
+    resident = t * 4 * (lanes + 8)
+    cell = (4 * blk * blk * 4
+            + 2 * 2 * blk * 8 * lanes
+            + 2 * 4 * 8 * blk
+            + 2 * blk * lanes * 4)
+    return resident + cell <= _BWD_VMEM
+
+
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref, *acc, scale, t, d, hpb,
                       causal, slabs, window=None):
@@ -633,11 +787,15 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "causal", "bq", "bk", "d", "hpb", "interpret", "window"))
+    "scale", "causal", "bq", "bk", "d", "hpb", "interpret", "window",
+    "one_bwd"))
 def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk, d, hpb,
-               interpret, window=None):
+               interpret, window=None, one_bwd=False):
     """(dq, dk, dv), each [N, T, G*L]; jitted for the reason
-    ``_flash_fwd`` is."""
+    ``_flash_fwd`` is. A row of several blocks is ``_bwd_kernel`` alone
+    where ``one_bwd`` (``flash_attention`` decides: equal blocks whose
+    resident rows fit, ``_bwd_fits``), else the dq kernel and then the
+    dk/dv kernel."""
     n, t, w = q.shape
     lanes = d * hpb
     ng = w // lanes
@@ -659,6 +817,34 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk, d, hpb,
             scratch_shapes=[_vmem((t, lanes))] * 2 if slabs > 1 else [],
             interpret=interpret,
         )(q, k, v, out, do, lse)
+
+    if one_bwd:
+        assert bq == bk and nq > 1, (bq, bk, nq)
+        cells, q_block, o_block, dq_block = _bwd_blocks(
+            nq, bq, causal, window)
+        q_spec = _seq_spec(
+            bq, lanes, lambda b, c, j, i: (b, q_block(j, i), c))
+        kv_spec = _seq_spec(bk, lanes, lambda b, c, j, i: (b, j, c))
+        return tuple(pl.pallas_call(
+            functools.partial(_bwd_kernel, scale=scale, blk=bq, nb=nq,
+                              cells=cells, d=d, hpb=hpb, causal=causal,
+                              window=window),
+            grid=(n, ng, nk, cells),
+            in_specs=[q_spec, kv_spec, kv_spec,
+                      _seq_spec(bq, lanes,
+                                lambda b, c, j, i: (b, o_block(j, i), c)),
+                      q_spec,
+                      _stat_spec(hpb, bq, lambda b, c, j, i: (
+                          b, c, q_block(j, i), 0, 0))],
+            out_specs=[_seq_spec(bq, lanes,
+                                 lambda b, c, j, i: (b, dq_block(j, i), c)),
+                       kv_spec, kv_spec],
+            out_shape=grads,
+            scratch_shapes=[_vmem((t, lanes)), _vmem((bk, lanes)),
+                            _vmem((bk, lanes)), _vmem((nq, hpb, bq))],
+            compiler_params=_compiler_params(2, _BWD_VMEM),
+            interpret=interpret,
+        )(q, k, v, out, do, lse))
 
     key_cells, kv_spec = _key_cells(t, bq, bk, lanes, window)
     q_spec = _seq_spec(bq, lanes, lambda b, c, i, j: (b, i, c))
@@ -722,6 +908,7 @@ class _Static(NamedTuple):
     hpb: int        # heads in a lane block
     interpret: bool
     window: int | None = None   # keys a row sees, itself among them
+    one_bwd: bool = False       # several blocks' backward is one kernel
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -814,7 +1001,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if asked:   # a call without a window leaves the notes it left
         tracing.note_trace(flash_window=window or "none",
                            flash_band_blocks=_band(t, bq, bk, window)[2])
-    static = _Static(float(scale), causal, bq, bk, d, hpb, interpret, window)
+    one_bwd = not single and bq == bk and _bwd_fits(t, bq, d * hpb)
+    if not single:
+        tracing.note_trace(flash_bwd_kernels=1 if one_bwd else 2)
+    if one_bwd:     # dq's accumulator holds the whole row in VMEM
+        tracing.note_trace(flash_bwd_resident_rows=t)
+    static = _Static(float(scale), causal, bq, bk, d, hpb, interpret, window,
+                     one_bwd)
     if direct:
         out = _flash_core(q.reshape(b, t, h * d), k.reshape(b, t, h * d),
                           v.reshape(b, t, h * d), static)
@@ -1156,15 +1349,6 @@ def _mla_bwd_fits(t: int, blk: int, dn: int, dr: int) -> bool:
     return resident + cell <= _MLA_BWD_VMEM
 
 
-def _mla_params(sequential: int, vmem: int = _MLA_VMEM):
-    """The last ``sequential`` grid dimensions run in order."""
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel",) * (4 - sequential)
-        + ("arbitrary",) * sequential,
-        vmem_limit_bytes=vmem)
-
-
 @functools.partial(jax.jit, static_argnames=("static",))
 def mla_flash_fwd(qn, qr, kn, kr, v, *, static: _MlaStatic):
     """(out [B, T, H*dn], lse [B, G, T/b, hpb, b]); jitted for the
@@ -1192,7 +1376,7 @@ def mla_flash_fwd(qn, qr, kn, kr, v, *, static: _MlaStatic):
                    jax.ShapeDtypeStruct((b, g, nb, hpb, blk), jnp.float32)],
         scratch_shapes=[_vmem((blk, hpb * dn)), _vmem((hpb, blk, 128)),
                         _vmem((hpb, blk, 128))],
-        compiler_params=_mla_params(1),
+        compiler_params=_compiler_params(1, _MLA_VMEM),
         interpret=interpret,
     )(qn, qr, kn, kr, v)
 
@@ -1243,7 +1427,7 @@ def mla_flash_bwd(qn, qr, kn, kr, v, out, lse, g, *, static: _MlaStatic):
             scratch_shapes=[_vmem((t, wide)), _vmem((t, rope)),
                             _vmem((blk, wide)), _vmem((t, dr)),
                             _vmem((blk, wide)), _vmem((nb, hpb, blk))],
-            compiler_params=_mla_params(3, _MLA_BWD_VMEM),
+            compiler_params=_compiler_params(3, _MLA_BWD_VMEM),
             interpret=interpret,
         )(qn, qr, kn, kr, v, out, do, lse))
 
@@ -1264,7 +1448,7 @@ def mla_flash_bwd(qn, qr, kn, kr, v, out, lse, g, *, static: _MlaStatic):
                    stat],
         out_shape=[*grads[:2], like(lse.shape, lse.dtype)],
         scratch_shapes=[_vmem((blk, wide)), _vmem((blk, rope))],
-        compiler_params=_mla_params(1),
+        compiler_params=_compiler_params(1, _MLA_VMEM),
         interpret=interpret,
     )(qn, qr, kn, kr, v, out, do, lse)
 
@@ -1288,7 +1472,7 @@ def mla_flash_bwd(qn, qr, kn, kr, v, out, lse, g, *, static: _MlaStatic):
         out_shape=grads[2:],
         scratch_shapes=[_vmem((blk, wide)), _vmem((blk, dr)),
                         _vmem((blk, wide))],
-        compiler_params=_mla_params(2),
+        compiler_params=_compiler_params(2, _MLA_VMEM),
         interpret=interpret,
     )(qn, qr, kn, kr, v, do, lse, delta)
     return dqn, dqr, dkn, dkr, dv

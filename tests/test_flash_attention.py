@@ -99,6 +99,15 @@ def the_square(monkeypatch):
     return run
 
 
+def _backward_notes(t, block):
+    """What a row of several equal blocks adds to the notes: its
+    backward is one kernel with dq's ``t`` rows resident (every shape
+    here fits the budget); a row of one block says nothing."""
+    if t == block:
+        return {}
+    return {"flash_bwd_kernels": 1, "flash_bwd_resident_rows": t}
+
+
 @pytest.mark.parametrize("causal", [True, False],
                          ids=["causal", "full"])
 @pytest.mark.parametrize("t,block", [(128, 128), (256, 128), (768, 768)],
@@ -127,7 +136,7 @@ def test_direct_layout_matches_dense_and_folded(h, d, t, block, causal,
     assert tracing.take_trace_notes() == {
         "flash_layout": "bthd", "flash_lanes_per_block": 128,
         "flash_path": "single_block" if t == block else "multi_block",
-        "flash_causal_slabs": slabs}
+        "flash_causal_slabs": slabs, **_backward_notes(t, block)}
     dense = _out_and_grads(
         lambda q, k, v: jax.nn.dot_product_attention(
             q, k, v, is_causal=causal), q, k, v)
@@ -283,4 +292,140 @@ def test_the_cells_shapes_choose_what_their_trace_notes_say(
     assert (out.shape, out.dtype) == (shape, jnp.bfloat16)
     assert tracing.take_trace_notes() == {
         "flash_layout": layout, "flash_lanes_per_block": lanes,
-        "flash_path": path, "flash_causal_slabs": slabs}
+        "flash_path": path, "flash_causal_slabs": slabs,
+        **_backward_notes(shape[1], 1024)}
+
+
+# -- the backward pass of a row of several blocks --------------------------
+
+@pytest.fixture
+def notes(monkeypatch):
+    """What ``flash_attention`` says of itself, caught at the call (a
+    train step's listener, once a test of this process has built one,
+    takes the thread's notes away at the next trace)."""
+    said = {}
+    monkeypatch.setattr(tracing, "note_trace", said.update)
+    return said
+
+
+def _dense(q, k, v, causal):
+    return jax.nn.dot_product_attention(q, k, v, is_causal=causal)
+
+
+def _grads(attn, q, k, v):
+    return _out_and_grads(attn, q, k, v)[1:]
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("blocks", [2, 3, 4])
+@pytest.mark.parametrize("h,d", [(1, 128), (2, 64)],
+                         ids=["one_head_a_block", "two_heads_a_block"])
+def test_one_kernel_backward_is_the_dense_one_and_the_pairs_bit_for_bit(
+        h, d, blocks, causal, notes, monkeypatch):
+    """``_bwd_kernel``: dq, dk, dv of a row of 2, 3 and 4 equal blocks
+    against the dense softmax's, and against the dq + dk/dv pair's
+    (reached by taking the budget away) to the bit: the same sums in the
+    same order. ``dq`` rides in scratch across the key blocks: not causal
+    it leaves in the last key block's cells, causal at its diagonal."""
+    q, k, v = _rand_qkv(b=2, t=64 * blocks, h=h, d=d, seed=7)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=64,
+                               block_k=64, interpret=True)
+    one = _grads(flash, q, k, v)
+    assert notes["flash_bwd_kernels"] == 1
+    assert notes["flash_bwd_resident_rows"] == 64 * blocks
+    dense = _grads(lambda *a: _dense(*a, causal), q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), one, dense):
+        assert float(jnp.abs(a - b).max()) < 5e-5, name
+    notes.clear()
+    monkeypatch.setattr(fa, "_BWD_VMEM", 1 << 10)
+    pair = _grads(flash, q, k, v)
+    assert notes["flash_bwd_kernels"] == 2
+    assert "flash_bwd_resident_rows" not in notes
+    for name, a, b in zip(("dq", "dk", "dv"), one, pair):
+        assert bool((a == b).all()), name
+
+
+MiB = 1 << 20
+
+
+@pytest.mark.parametrize(
+    "t, block_q, block_k, d, budget, kernels", [
+        (16384, None, None, 128, None, 1),   # the SmallThinker cell's row
+        (8192, None, None, 128, None, 1),    # ZAYA's and Nemotron's
+        (4096, None, None, 128, None, 1),    # OLMoE's
+        (65536, None, None, 128, None, 1),   # 34 MiB of dq
+        (131072, None, None, 128, None, 2),  # 68: the rows decide
+        (16384, None, None, 128, 24 * MiB, 2),   # the budget decides
+        (16384, 512, 512, 128, 24 * MiB, 1),     # the blocks decide
+        (4096, None, None, 64, None, 1),     # two heads a lane block
+        (4096, None, None, 32, None, 1),     # folded: 32 lanes pad to 128
+        (2048, 1024, 512, 128, None, 2),     # uneven blocks: the pair
+        (2048, 512, 1024, 128, None, 2),
+        (2048, 2048, 1024, 128, None, 2),    # one q-block, two key blocks
+        (1024, None, None, 128, None, None),     # one block: the fused body
+    ], ids=lambda v: str(v))
+def test_the_backward_path_is_decided_from_rows_blocks_and_the_budget(
+        t, block_q, block_k, d, budget, kernels, notes, monkeypatch):
+    """Equal blocks whose resident rows fit ``_BWD_VMEM`` take the one
+    kernel; uneven blocks and rows past the budget the pair. The
+    decision is in the notes and in the ``_Static`` that the jitted
+    functions are specialised on, and ``flash_attention`` takes no
+    argument that could choose. Traced only: no kernel runs."""
+    import inspect
+    handed = []
+    monkeypatch.setattr(fa, "_flash_core",
+                        lambda q, k, v, static: handed.append(static) or q)
+    if budget is not None:
+        monkeypatch.setattr(fa, "_BWD_VMEM", budget)
+    x = jax.ShapeDtypeStruct((1, t, 2, d), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: flash_attention(
+        q, k, v, block_q=block_q, block_k=block_k), x, x, x)
+    [static] = handed
+    assert static.one_bwd == (kernels == 1)
+    assert notes.get("flash_bwd_kernels") == kernels
+    assert notes.get("flash_bwd_resident_rows") == (
+        t if kernels == 1 else None)
+    assert notes["flash_path"] == (
+        "multi_block" if kernels else "single_block")
+    assert list(inspect.signature(flash_attention).parameters) == [
+        "q", "k", "v", "causal", "scale", "block_q", "block_k",
+        "interpret", "window"]
+
+
+@pytest.mark.parametrize("block_q, block_k", [(128, 64), (64, 128)],
+                         ids=["q128_k64", "q64_k128"])
+def test_uneven_blocks_still_take_the_pair_and_still_match(
+        block_q, block_k, notes):
+    q, k, v = _rand_qkv(t=256, seed=9)
+    got = _grads(lambda *a: flash_attention(
+        *a, block_q=block_q, block_k=block_k, interpret=True), q, k, v)
+    assert notes["flash_bwd_kernels"] == 2
+    want = _grads(lambda *a: _dense(*a, True), q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert float(jnp.abs(a - b).max()) < 5e-5, name
+
+
+@pytest.mark.parametrize("kernels", [1, 2], ids=["one_kernel", "the_pair"])
+def test_both_backward_paths_under_a_shard_map_over_dp(
+        kernels, notes, monkeypatch):
+    """Each chip's own rows of the batch through the kernels (what
+    ``make_sharded_causal_attention`` does on a dp mesh): the gradients
+    of the sharded call are those of the whole batch in one call."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    if kernels == 2:
+        monkeypatch.setattr(fa, "_BWD_VMEM", 1 << 10)
+    q, k, v = _rand_qkv(b=4, t=192, h=2, d=64, seed=11)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, block_q=64, block_k=64,
+                               interpret=True)
+    mesh = Mesh(jax.devices()[:4], ("dp",))
+    sharded = jax.shard_map(flash, mesh=mesh, in_specs=P("dp"),
+                            out_specs=P("dp"), check_vma=False)
+    got = _grads(sharded, q, k, v)
+    assert notes["flash_bwd_kernels"] == kernels
+    for name, a, b in zip(("dq", "dk", "dv"), got,
+                          _grads(flash, q, k, v)):
+        assert float(jnp.abs(a - b).max()) < 1e-6, name

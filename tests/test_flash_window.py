@@ -84,6 +84,40 @@ def test_windowed_kernels_are_the_dense_masked_softmax(window, h, d, t,
         assert notes["flash_window"] == window
 
 
+@pytest.mark.parametrize("blocks", [2, 3, 4])
+@pytest.mark.parametrize("h, d", [(1, 128), (2, 64)],
+                         ids=["one_head_a_block", "two_heads_a_block"])
+@pytest.mark.parametrize("window", [1, 24, 64, 65, 150, 4096],
+                         ids=["itself", "under_a_block", "a_block",
+                              "a_block_and_one", "several_blocks",
+                              "the_whole_row"])
+def test_one_kernel_backward_under_a_window_is_the_pairs_bit_for_bit(
+        window, h, d, blocks, notes, monkeypatch):
+    """The one-kernel backward where a q-block's first live key block is
+    no longer block 0 and a key block's live q-blocks end: dq, dk, dv
+    against the dense masked softmax's and, to the bit, against the dq +
+    dk/dv pair's (reached by taking the budget away)."""
+    q, k, v, g = _qkvg(64 * blocks, h, d, seed=3)
+
+    def grads(fn):
+        return _out_and_grads(fn, q, k, v, g)[1:]
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, window=window, block_q=64,
+                                  block_k=64, interpret=True)
+    one = grads(flash)
+    assert notes["flash_bwd_kernels"] == 1
+    assert notes["flash_bwd_resident_rows"] == 64 * blocks
+    for name, a, b in zip(("dq", "dk", "dv"), one,
+                          grads(lambda *a: _dense(*a, window))):
+        np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
+    monkeypatch.setattr(fa, "_BWD_VMEM", 1 << 10)
+    pair = grads(flash)
+    assert notes["flash_bwd_kernels"] == 2
+    for name, a, b in zip(("dq", "dk", "dv"), one, pair):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
 def test_a_call_without_a_window_leaves_the_notes_it_left(notes):
     q, k, v, _ = _qkvg(128, 1, 128)
     fa.flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
@@ -101,9 +135,15 @@ def test_blocks_below_the_band_are_in_no_grid_cell(t, block, window, cells,
                                                    walked):
     """``_band``: the innermost grid dimension is the band's cells (the
     most any outer block needs) in the forward and dq kernels (key
-    cells) and in the dk/dv kernel (query cells), and the block pairs a
-    head walks are the live ones alone; against a count from the
-    definition."""
+    cells) and in the one-kernel backward and the dk/dv kernel (query
+    cells), and the block pairs a head walks are the live ones alone;
+    against a count from the definition. Then the one-kernel backward's
+    grid walked as the chip walks it (``_bwd_blocks``): every live pair
+    met once, key blocks ascending for a q-block; a dead cell on the
+    blocks of the live cell next to it; ``o`` fetched once a q-block, in the
+    cell that meets its first live key block; ``dq``'s block that of the
+    q-block whose last live key block the cell is, its index never
+    going back."""
     key_cells, query_cells, pairs = fa._band(t, block, block, window)
     assert (key_cells, query_cells, pairs) == (cells, cells, walked)
     n = t // block
@@ -128,6 +168,41 @@ def test_blocks_below_the_band_are_in_no_grid_cell(t, block, window, cells,
                                                           window, n))
             assert list(np.flatnonzero(live[:, j])) == list(
                 range(first, last + 1))
+    grid_cells, q_block, o_block, dq_block = fa._bwd_blocks(
+        n, block, True, window)
+    assert grid_cells == query_cells
+    met, o_fetched, dq_at = [], [], []
+    for j in range(n):
+        cells_of_j = []
+        for i in range(grid_cells):
+            qb, is_live = fa._query_cell(j, i, bq=block, bk=block,
+                                         causal=True, window=window, nq=n)
+            at = (int(q_block(j, i)), int(o_block(j, i)),
+                  int(dq_block(j, i)))
+            cells_of_j.append((bool(is_live), at))
+            if bool(is_live):
+                assert at[0] == int(qb) and live[at[0], j]
+                met.append((at[0], j))
+                if j == np.flatnonzero(live[at[0]])[0]:
+                    assert at[1] == at[0]       # delta is made here
+                if j == np.flatnonzero(live[at[0]])[-1]:
+                    assert at[2] == at[0]       # dq leaves here
+            if not o_fetched or o_fetched[-1] != at[1]:
+                o_fetched.append(at[1])
+            if not dq_at or dq_at[-1] != at[2]:
+                dq_at.append(at[2])
+        # a dead cell is clamped to the live cell next to it (the
+        # diagonal's after it in the causal grid, whose dead cells open
+        # a key block's; the last live one before it under a window,
+        # where they close it), so it fetches and writes back nothing
+        alive = [i for i, (is_live, _) in enumerate(cells_of_j) if is_live]
+        for i, (is_live, at) in enumerate(cells_of_j):
+            if not is_live:
+                assert at == cells_of_j[
+                    min(alive, key=lambda a: abs(a - i))][1]
+    assert sorted(met) == sorted(zip(*np.nonzero(live)))
+    assert len(met) == pairs
+    assert o_fetched == list(range(n)) == dq_at
 
 
 def test_the_windowed_grid_is_the_band_and_the_note_counts_it(notes):
@@ -145,6 +220,22 @@ def test_the_windowed_grid_is_the_band_and_the_note_counts_it(notes):
     grids = [e.params["jaxpr"].eqns[0].params["grid_mapping"].grid
              for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
     assert grids == [(2, 1, 4, 3)]
+    # the backward: one call over (batch, lane blocks, key blocks, the
+    # band's q-cells) where the resident rows fit, else dq's grid over
+    # the band's key cells and dk/dv's over its q-cells
+    assert notes["flash_bwd_kernels"] == 1
+    assert notes["flash_bwd_resident_rows"] == 256
+    x = q.reshape(2, 256, 128)
+    for one_bwd, want in ((True, [(2, 1, 4, 3)]),
+                          (False, [(2, 1, 4, 3), (2, 1, 4, 3)])):
+        jaxpr = jax.make_jaxpr(lambda q, k, v, o, lse: fa._flash_bwd(
+            q, k, v, o, lse, o, scale=1.0, causal=True, bq=64, bk=64,
+            d=128, hpb=1, interpret=False, window=70, one_bwd=one_bwd))(
+                x, x, x, x, jnp.zeros((2, 1, 4, 1, 64), jnp.float32))
+        [call] = [e for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
+        assert [e.params["grid_mapping"].grid
+                for e in call.params["jaxpr"].eqns
+                if e.primitive.name == "pallas_call"] == want
 
 
 def test_a_window_needs_a_causal_row():

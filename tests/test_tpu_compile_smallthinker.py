@@ -38,9 +38,11 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     configuration file's ``cut.memory`` would have turned to ``remat``,
     every layer's attention is the equal-width multi-block kernel, the
     windowed layers' under a window of 4,096 with the band's 70 block
-    pairs a head and not the causal grid's 136, each kernel's call under
-    its layer's scope (``attn/core`` in layer 0, ``attn/window`` in
-    layers 1-3), and no ``[T, T]`` array exists."""
+    pairs a head and not the causal grid's 136, its backward pass ONE
+    kernel a layer with dq's 16,384 rows resident (four ``_flash_bwd``
+    custom calls where the dq + dk/dv pair made eight), each kernel's
+    call under its layer's scope (``attn/core`` in layer 0,
+    ``attn/window`` in layers 1-3), and no ``[T, T]`` array exists."""
     import re
 
     import optax
@@ -81,6 +83,8 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert notes["flash_layout"] == "bthd"
     assert notes["flash_window"] == 4096
     assert notes["flash_band_blocks"] == 70 < 16 * 17 // 2
+    assert notes["flash_bwd_kernels"] == 1
+    assert notes["flash_bwd_resident_rows"] == 16384
     assert notes["attn_kind"] == "window_global"
     assert notes["attn_layers"] == "gWWW"
     assert notes["moe_router_input"] == "pre_attention"
@@ -105,9 +109,9 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
              for line in calls]
     assert set(kinds) == {"_flash_fwd", "_flash_bwd", "gmm", "tgmm"}
     assert kinds.count("_flash_fwd") == 4
-    assert kinds.count("_flash_bwd") == 8       # dq, then dk/dv, a layer
+    assert kinds.count("_flash_bwd") == 4       # one kernel a layer
     flash = [line for kind, line in zip(kinds, calls) if "_flash_" in kind]
-    assert sum("/h_0/attn/core/" in line for line in flash) == 3
-    assert sum("/attn/window/" in line for line in flash) == 9
+    assert sum("/h_0/attn/core/" in line for line in flash) == 2
+    assert sum("/attn/window/" in line for line in flash) == 6
     assert not any("/h_0/attn/window/" in line for line in flash)
     assert "16384,16384" not in text

@@ -37,7 +37,8 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     unaliased outputs stay under the 15.0 GB at which the configuration
     file's ``cut.memory`` would have gone to four layers and at no more
     than the 13.17 GB of the step before CCA's kernels, every layer's
-    attention is the equal-width multi-block kernel, CCA's passes in
+    attention is the equal-width multi-block kernel with ONE backward
+    kernel a layer (dq's 8,192 rows resident), CCA's passes in
     front of it are ``ops/pallas/cca_mix.py``'s pair (two kinds of
     custom call beside the flash kernels' two and the grouped matmuls'
     two, one of each a layer, under ``attn/mix``), and no ``[T, T]``
@@ -75,6 +76,8 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     compiled = step.lower(state, batch).compile()
     assert notes["flash_path"] == "multi_block"
     assert notes["flash_layout"] == "bthd"
+    assert notes["flash_bwd_kernels"] == 1
+    assert notes["flash_bwd_resident_rows"] == 8192
     assert notes["cca_path"] == "pallas" and notes["attn_kind"] == "cca"
     assert notes["cca_halo_rows"] == 2
     assert 8192 % notes["cca_rows_per_block"] == 0
@@ -96,6 +99,8 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert set(kinds) == {"_flash_fwd", "_flash_bwd", "gmm", "tgmm",
                           "_mix_fwd", "_mix_bwd"}
     assert kinds.count("_mix_fwd") == kinds.count("_mix_bwd") == 5
+    assert kinds.count("_flash_fwd") == 5
+    assert kinds.count("_flash_bwd") == 5       # one kernel a layer
     assert all("/attn/mix/jit(_mix_" in line
                for kind, line in zip(kinds, calls) if "_mix_" in kind)
     assert "8192,8192" not in text
