@@ -5,7 +5,13 @@ The float32 router, ``top_k``, the ``tokens x k`` routes sorted by
 expert, grouped matmuls over the ragged groups in bf16 with float32
 accumulation, un-sort, weighted combine. No capacity, no dropped
 route: every route to an expert held here is computed whatever the
-skew. What runs through it, by argument:
+skew. Where a share of the experts is held, a slab of the sorted
+routes moves its rows without a scatter, in either pass
+(``ops/pallas/route_rows.py``, PR 43): ``take_rows`` gathers the slab's
+tokens' rows, ``sum_rows`` adds each token's weighted rows in float32
+and writes the ``[t, d]`` once in the activations' dtype, on a TPU as a
+gather into token order and a one-hot product on megablox ``tgmm`` that
+reads the live rows alone. What runs through it, by argument:
 
 - OLMoE-1B-7B (``models/llama.py``): the softmax router over 64
   experts, top-8 unnormalised, SwiGLU experts (three grouped matmuls),
@@ -58,6 +64,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.ops.pallas import route_rows
 from ray_tpu.util import tracing
 
 
@@ -181,10 +188,7 @@ def _gmm_tiling(rows_per_expert: int):
     function a hint, so that a step's layers share their kernels'
     jitted functions."""
     tm = min(_GMM_TILING[0], max(128, 1 << (rows_per_expert.bit_length() - 1)))
-
-    def width(d: int, most: int) -> int:
-        fits = range(most // 2, most + 1, 128)
-        return min(fits, key=lambda t: (-(-d // t) * t, -t))
+    width = route_rows.width_tile
 
     def tiles(m: int, k: int, n: int) -> tuple[int, int, int]:
         return (min(tm, m), min(width(k, _GMM_TILING[1]), k),
@@ -307,37 +311,40 @@ def held_rows(routes: int, held: int, experts: int) -> int:
     experts gathers and multiplies at a time (a slab; one is enough
     unless more routes than that land on them): ``_HELD_ROOM`` times
     the even share of the ``routes``, rounded up to the grouped
-    matmul's row tile, and never more than all of them."""
+    matmul's row tile, and never more than all of them. The gather
+    (``take_rows``) moves every row of the slab, 25 ns a row on a v5e;
+    the grouped matmuls and ``sum_rows`` read only the rows whose route
+    landed here."""
     tile = _GMM_TILING[0]
     even = routes * held / experts
     return min(routes, tile * math.ceil(_HELD_ROOM * even / tile))
 
 
-def _slab(lo, static, x, order, weights, sizes, w_gate, w_up, w_down):
+def _slab(lo, static, out, x, order, pos, weights, sizes, w_gate, w_up,
+          w_down):
     """The sorted routes ``lo .. lo + rows`` (``order`` holds the held
     experts' routes first, by expert, ``sizes[e]`` of them for held
-    expert ``e``): their tokens' rows gathered, multiplied by the
-    experts that own them, weighted and added to their tokens' rows of
-    a float32 ``[t, d]``. ``lo`` may be traced; ``static`` is (the
-    slab's rows, top_k, an expert's rows at an even load, the experts'
-    kind)."""
-    rows, top_k, per_expert, kind = static
+    expert ``e``; ``pos`` [t, k] is its inverse): their tokens' rows
+    taken (``route_rows.take_rows``: a gather, zero past the live ones),
+    multiplied by the experts that own them, and each token's rows
+    weighted and added up in float32 (``route_rows.sum_rows``: no
+    scatter, and no row past the live ones is read), ``[t, d]`` in the
+    dtype ``out``. ``lo`` may be traced; ``static`` is (the slab's
+    rows, top_k, an expert's rows at an even load, the experts' kind,
+    ``route_rows.rows_path``'s word)."""
+    rows, top_k, per_expert, kind, path = static
     with jax.named_scope("dispatch"):
         ends = jnp.cumsum(sizes)
         own = (jnp.clip(ends, lo, lo + rows)
                - jnp.clip(ends - sizes, lo, lo + rows))
-        route = lax.dynamic_slice(order, (lo,), (rows,))
-        live = (lo + jnp.arange(rows, dtype=jnp.int32) < ends[-1])[:, None]
-        token = route // top_k
-        xs = jnp.where(live, x[token], jnp.zeros((), x.dtype))
+        slab = route_rows.Slab(lax.dynamic_slice(order, (lo,), (rows,)),
+                               pos, lo, ends[-1])
+        xs = route_rows.take_rows(x, slab, path)
     with jax.named_scope("experts"):
+        # rows past the real ones hold whatever the kernel left
         ys = _experts(xs, w_gate, w_up, w_down, own, per_expert, kind)
     with jax.named_scope("combine"):
-        # rows past the real ones hold whatever the kernel left
-        ys = jnp.where(live, ys, jnp.zeros((), ys.dtype)) \
-            * weights.reshape(-1)[route][:, None].astype(ys.dtype)
-        return jnp.zeros(x.shape, jnp.float32).at[token].add(
-            ys.astype(jnp.float32))
+        return route_rows.sum_rows(ys, weights, slab, path, out)
 
 
 def _slabs_needed(sizes, rows):
@@ -345,20 +352,41 @@ def _slabs_needed(sizes, rows):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _slabs(static, x, order, weights, sizes, w_gate, w_up, w_down):
+def _slabs(static, x, order, pos, weights, sizes, w_gate, w_up, w_down):
     """Every slab of ``rows`` sorted routes that holds a real one,
-    added up: the first always, the next ones in a loop that runs as
-    often as the routes that landed here need (not at all at twice
-    the even share or less). The backward walks the same slabs,
+    added up, in ``x``'s dtype. The first slab's float32 sums are cast
+    and written once, and where it holds every route that landed here
+    (twice the even share or less) that is the answer; else all the
+    slabs, the first again, are walked in a loop that runs as often as
+    the routes need and adds their sums in float32, on the plain row
+    path (``_overflow``; the first stays
+    outside the conditional so that its scopes read ``mlp/experts``,
+    not ``mlp/cond/...``). The backward walks the same slabs,
     recomputing each (the gathered rows and the experts' hidden
     activations: 1% of a Nemotron step's operations), so nothing of a
     slab outlives it."""
-    args = (x, order, weights, sizes, w_gate, w_up, w_down)
+    args = (x, order, pos, weights, sizes, w_gate, w_up, w_down)
     rows = static[0]
-    return lax.fori_loop(
-        1, _slabs_needed(sizes, rows),
-        lambda j, y: y + _slab(j * rows, static, *args),
-        _slab(0, static, *args))
+    first = _slab(0, static, x.dtype, *args)
+    if rows == order.shape[0]:      # the slab holds every route there is
+        return first
+    return lax.cond(
+        _slabs_needed(sizes, rows) <= 1,
+        lambda: first,
+        lambda: lax.fori_loop(
+            0, _slabs_needed(sizes, rows),
+            lambda j, y: y + _slab(j * rows, _overflow(static), jnp.float32,
+                                   *args),
+            jnp.zeros(x.shape, jnp.float32)).astype(x.dtype))
+
+
+def _overflow(static):
+    """``static`` for the slabs of the overflow loops: their sums on the
+    plain row path. A step that never overflows pays for those loops'
+    kernels in its set-up alone (each sum a sort and a ``tgmm``: 6 s of
+    the SmallThinker cell's 50, PERF.md section 6, PR 43), and the
+    gathers by ``pos`` are exact too."""
+    return static[:-1] + ("xla",)
 
 
 def _slabs_fwd(static, *args):
@@ -366,27 +394,30 @@ def _slabs_fwd(static, *args):
 
 
 def _slabs_bwd(static, args, dy):
-    x, order, weights, sizes, *ws = args
+    x, order, pos, weights, sizes, *ws = args
     rows = static[0]
 
-    def pull(lo):
+    def pull(lo, static):
         return jax.vjp(
-            lambda x, weights, *ws: _slab(lo, static, x, order, weights,
-                                          sizes, *ws),
+            lambda x, weights, *ws: _slab(lo, static, x.dtype, x, order, pos,
+                                          weights, sizes, *ws),
             x, weights, *ws)[1](dy)
 
-    dx, dweights, *dws = lax.fori_loop(
-        1, _slabs_needed(sizes, rows),
-        lambda j, acc: jax.tree_util.tree_map(jnp.add, acc, pull(j * rows)),
-        pull(0))
-    return (dx, None, dweights, None, *dws)
+    dx, dweights, *dws = pull(0, static)
+    if rows < order.shape[0]:
+        dx, dweights, *dws = lax.fori_loop(
+            1, _slabs_needed(sizes, rows),
+            lambda j, acc: jax.tree_util.tree_map(
+                jnp.add, acc, pull(j * rows, _overflow(static))),
+            (dx, dweights, *dws))
+    return (dx, None, None, dweights, None, *dws)
 
 
 _slabs.defvjp(_slabs_fwd, _slabs_bwd)
 
 
 def _held_part(x, flat, weights, counts, w_gate, w_up, w_down, top_k,
-               experts_held, kind):
+               experts_held, kind, rows_path="xla"):
     """The held experts' part of every token's sum, ``[t, d]``. The
     held experts' routes sort to the front (by expert; every absent
     expert's route takes one key behind them), and the sorted routes
@@ -399,13 +430,15 @@ def _held_part(x, flat, weights, counts, w_gate, w_up, w_down, top_k,
     with jax.named_scope("dispatch"):
         local = flat - first
         key = jnp.where((local >= 0) & (local < held), local, held)
-        _, order = lax.sort((key, jnp.arange(routes, dtype=jnp.int32)),
-                            num_keys=1, is_stable=True)
+        iota = jnp.arange(routes, dtype=jnp.int32)
+        _, order = lax.sort((key, iota), num_keys=1, is_stable=True)
+        _, pos = lax.sort((order, iota), num_keys=1)
         # whole slabs: the rows past the routes are never live
         order = jnp.pad(order, (0, -routes % rows))
-    return _slabs((rows, top_k, max(1, routes // counts.shape[0]), kind), x,
-                  order, weights, counts[first:first + held], w_gate, w_up,
-                  w_down).astype(x.dtype)
+    return _slabs((rows, top_k, max(1, routes // counts.shape[0]), kind,
+                   rows_path), x, order,
+                  pos.reshape(-1, top_k), weights,
+                  counts[first:first + held], w_gate, w_up, w_down)
 
 
 def _given(x, weights, experts):
@@ -418,7 +451,7 @@ def _given(x, weights, experts):
 
 def _routed_ffn_local(x, route_args, w_gate, w_up, w_down, *, route,
                       num_experts, top_k, over=(), experts_held=None,
-                      kind="swiglu"):
+                      kind="swiglu", rows_path="xla"):
     """The layer on the tokens in hand (``[..., d]``), their routes
     made by ``route(x, *route_args)`` (``_route``, ``_route_sigmoid``
     or ``_given``); the router's sums are added over the mesh axes
@@ -443,7 +476,7 @@ def _routed_ffn_local(x, route_args, w_gate, w_up, w_down, *, route,
         z = z_sum / n
     if experts_held is not None:
         y = _held_part(x, flat, weights, counts, w_gate, w_up, w_down,
-                       top_k, experts_held, kind)
+                       top_k, experts_held, kind, rows_path)
         return y.reshape(shape), aux, z, load
     with jax.named_scope("dispatch"):
         iota = jnp.arange(t * top_k, dtype=jnp.int32)
@@ -519,9 +552,12 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     the held ones' and are neither gathered nor multiplied:
     ``held_rows`` (twice the even share) sorted rows are, with the
     group sizes saying how many are real, and further slabs of as many
-    only when more than that land here (``_slabs``). ``y`` is then
-    the held experts' part of each token's sum; what the absent experts
-    would add is left to the chips that hold them.
+    only when more than that land here (``_slabs``). A slab's rows come
+    by a gather and go back by a gather and a product, never a
+    scatter-add (``route_rows.take_rows`` / ``sum_rows``; float32
+    sums, written once in ``x``'s dtype). ``y`` is then the held
+    experts' part of each token's sum; what the absent experts would
+    add is left to the chips that hold them.
 
     Returns ``(y, aux, z, load)``: the output in ``x``'s shape and
     dtype (sum over each token's ``top_k`` experts of router weight
@@ -630,13 +666,19 @@ def _routed(x, route, route_args, per_token: bool, w_gate, w_up, w_down, *,
     if not (0 <= first and first + held <= e and w_up.shape[0] == held):
         raise ValueError(f"experts_held {experts_held} of {e} experts, "
                          f"weights for {w_up.shape[0]}")
-    local = functools.partial(
-        _routed_ffn_local, route=route, num_experts=e, top_k=top_k,
-        experts_held=experts_held, kind=kind)
     batch_axes, seq_axis = _token_axes(
         mesh, x.shape[0], x.shape[1] if x.ndim == 3 else 1)
     axes = batch_axes + ((seq_axis,) if seq_axis else ())
-    tokens = math.prod(x.shape[:-1])
+    tokens = math.prod(x.shape[:-1]) // math.prod(
+        mesh.shape[a] for a in axes)                    # a chip's
+    rows = held_rows(tokens * top_k, held, e)
+    # one global program over several devices: the plain form (a bare
+    # ``pallas_call`` has no SPMD rule)
+    rows_path = ("xla" if not axes and mesh is not None and mesh.size > 1
+                 else route_rows.rows_path(tokens, rows))
+    local = functools.partial(
+        _routed_ffn_local, route=route, num_experts=e, top_k=top_k,
+        experts_held=experts_held, kind=kind, rows_path=rows_path)
     if axes:
         from jax.sharding import PartitionSpec as P
         held_spec = P(batch_axes or None, seq_axis)
@@ -651,7 +693,6 @@ def _routed(x, route, route_args, per_token: bool, w_gate, w_up, w_down, *,
                       None if w_gate is None else P(), P(), P()),
             out_specs=(held_spec, P(), P(), P()), check_vma=False)(
                 x, route_args, w_gate, w_up, w_down)
-        tokens //= math.prod(mesh.shape[a] for a in axes)
     else:
         out = local(x, route_args, w_gate, w_up, w_down)
     notes = dict(
@@ -660,8 +701,10 @@ def _routed(x, route, route_args, per_token: bool, w_gate, w_up, w_down, *,
         moe_axes=list(axes))
     if said:
         notes.update(
-            said, moe_experts_held=[first, held],
-            moe_rows_sorted=held_rows(tokens * top_k, held, e))
+            said, moe_experts_held=[first, held], moe_rows_sorted=rows)
+        if experts_held is not None:
+            notes.update(moe_rows_path=rows_path,
+                         moe_rows_tile=route_rows.TILE)
     tracing.note_trace(**notes)
     return out
 

@@ -103,4 +103,21 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert kinds.count("_flash_bwd") == 5       # one kernel a layer
     assert all("/attn/mix/jit(_mix_" in line
                for kind, line in zip(kinds, calls) if "_mix_" in kind)
+    # the routed layer's row moves (PR 43): each token's sum is a gather
+    # and a tgmm under combine, its transpose the same under the call's
+    # scope, dispatch (a layer's first slab; the loops over further slabs
+    # sum on the plain path), and neither scope holds a scatter
+    assert notes["moe_rows_path"] == "tgmm" and notes["moe_rows_tile"] == 128
+    sums = [line for kind, line in zip(kinds, calls)
+            if kind == "tgmm" and "jit(_sum)" in line]
+    assert not any("/while/body/" in line for line in sums)
+    assert sum("/mlp/combine/jit(_sum)/jit(tgmm)" in line for line in sums) == 5
+    assert sum(bool(re.search(r"/mlp/\S*dispatch\S*/jit\(_sum\)/jit\(tgmm\)",
+                              line)) for line in sums) == 5
+    assert not [line for line in text.splitlines()
+                if " scatter(" in line and re.search(
+                    r"/mlp/[^ \"]*(dispatch|combine)", line)]
+    print("VMEM of the sums' tgmm:", sorted({
+        re.search(r'used_scoped_memory_configs[^]]*?"size":"(\d+)"', line)
+        .group(1) for line in sums if "experts" not in line}))
     assert "8192,8192" not in text
