@@ -83,7 +83,9 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     programs must NOT hit the bare kernel (pallas_call has no SPMD
     partitioning rule): use make_sharded_causal_attention, which
     shard_maps over the mesh and sets ``force_flash`` for the
-    per-device local block. Everything else takes the XLA path.
+    per-device local block. Everything else takes the XLA path. A row
+    too long for the kernel's backward (``NotImplementedError`` from
+    ``flash_attention``) is not sent there: it surfaces.
 
     Without ``force_flash`` nothing says how many devices the program
     spans, so the process's device count stands in for it: a caller

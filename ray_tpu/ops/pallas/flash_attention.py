@@ -14,40 +14,46 @@ compute path (SURVEY.md §5.7). API shape follows jax convention
 [batch, seq, heads, head_dim].
 
 Layout: the kernels index the projections' own [B, T, H*D] (the
-[B, T, H, D] arguments reshaped, which moves nothing): a block is
-(1, rows, 128) lanes of the last dimension — two heads side by side at
-D=64, walked as two static 64-lane halves in the body, or one head
-where D is a multiple of 128 — and the output is written the same way,
-as the output projection's operand. Nothing is transposed, padded or
-copied between the projections' matmuls and the custom calls, in
-either pass. A shape that cannot be blocked on whole 128-lane tiles
-(an odd head count at D=64, another head width) is folded to
-[B*H, T, D] first, one head a block, at a transpose each way; the same
-kernels run it. ``flash_attention`` leaves which one ran in the trace's
-notes (``flash_layout`` = "bthd" | "folded").
+[B, T, H, D] arguments reshaped, which moves nothing), 128 lanes of the
+last dimension a block, and write the output the same way: nothing is
+transposed, padded or copied between the projections' matmuls and the
+custom calls, in either pass. A shape that cannot be blocked on whole
+128-lane tiles is folded to [B*H, T, D] first, at a transpose each way;
+the same kernels run it (the comment above ``_head_slices``; which ran
+is in the trace's notes, ``flash_layout`` = "bthd" | "folded").
 
 Grid: (batch, lane blocks) where a whole row fits one block (T <=
 1024), in both passes. A longer row's forward is (batch, lane blocks,
 q-block, key cell) with the innermost dimension "arbitrary" (sequential
 on TPU), so VMEM scratch carries the streaming softmax across the key
-blocks of one q-block. Its backward is ONE kernel (``_bwd_kernel``)
-over (batch, lane blocks, key block, query cell), the last two in
-order: a cell makes the scores, the probabilities, ``dO v^T`` and
-``ds`` of its block pair once and feeds ``dv``, ``dk`` and ``dq`` from
-them, five MXU passes; ``dk`` / ``dv`` of the key block ride in float32
-scratch across its cells, ``dq`` of the lane block's whole row
-(``[T, 128]``: 8.4 MB at 16,384 rows) across the key blocks, a
-q-block's rows leaving at its last live key block, and ``delta`` is
-made in VMEM and never an array in HBM. That is what equal blocks
-(``_pick_block``'s, every model's) take where those rows fit the VMEM
-the kernel asks for (``_bwd_fits``). Uneven blocks given by hand, and
-rows past the budget, take the pair it replaced: a dq kernel (key cell
-innermost) and a dk/dv kernel (query cell innermost), which hold a
-block's rows whatever the row's length and each make the scores and
-``dO v^T`` again, seven passes. Nothing but the shapes decides; the
-notes say which ran (``flash_bwd_kernels`` 1 | 2,
-``flash_bwd_resident_rows``). Folded, "batch" is batch*heads and there
-is one lane block.
+blocks of one q-block. Blocks are square: one size ``blk`` each way
+(``_pick_block``'s, or the tests' ``block``). Folded, "batch" is
+batch*heads and there is one lane block.
+
+The backward pass is one kernel a shape, three in the file, and nothing
+but the shapes decides which:
+
+- ``_bwd_fused_kernel``, a row of one block (the GPT-2 cells): the
+  scores made once for dq, dk and dv, walked as the forward's slabs.
+- ``_bwd_kernel``, a row of several blocks at equal widths (OLMoE,
+  Nemotron, ZAYA, SmallThinker): grid (batch, lane blocks, key block,
+  query cell), the last two in order; a cell makes the scores and
+  ``ds`` of its block pair once, five MXU passes, and ``dq`` of the
+  lane block's whole row (``[T, 128]`` float32: 8.4 MB at 16,384 rows)
+  rides in VMEM scratch across the key blocks.
+- ``_mla_bwd_kernel``, latent attention's keys of two parts (JoyAI):
+  the same design with five gradients (the file's last section).
+
+The two multi-block kernels hold a whole row's ``dq`` in VMEM
+(``flash_bwd_resident_rows`` in the trace's notes), so the row has a
+limit: ``_bwd_fits`` (65,536 rows at 128 lanes fit ``_BWD_VMEM``,
+131,072 do not) and ``_mla_bwd_fits`` (32,768 fit ``_MLA_BWD_VMEM``,
+65,536 do not). A row past it is refused at trace time with a
+``NotImplementedError`` that gives the rows, the bytes and the budget;
+the ways on are to split the sequence over an ``sp`` mesh axis or to
+keep ``dq`` resident over the band's q-rows alone (ROADMAP C4). It is
+no ``ValueError``: that sends a caller to the dense path, and a dense
+``[T, T]`` at such a length is no fallback.
 
 The causal triangle: the multi-block kernels skip the blocks above the
 diagonal through the grid. A single-block body has no grid to skip
@@ -60,15 +66,10 @@ whole tiles — and so computes the triangle too, not the square
 A window (``flash_attention(window=w)``: row t sees keys t - w < j <=
 t, the sliding-window layers of a window/global stack) adds the band's
 second edge: the multi-block grids' innermost dimension runs over the
-band's blocks alone, in the forward and in the backward (the one
-kernel's query cells; the pair's key and query cells), so the blocks
-below the band are in no cell; only the two blocks that
-straddle an edge pay for a mask (``_band`` and the comment above ``_keys_of``).
-With no window every kernel is the program it was.
-
-Set-up: the two functions that hold the pallas_calls are jitted, so a
-model's layers, which call them at one shape, trace each kernel and
-lower it to Mosaic once a trace of the step, not once a layer.
+band's blocks alone, in the forward (key cells) and in the backward
+(query cells), so the blocks below the band are in no cell; only the
+two blocks that straddle an edge pay for a mask (``_band`` and the
+comment above ``_keys_of``). With no window none of it is traced.
 """
 
 from __future__ import annotations
@@ -92,12 +93,11 @@ def flash_attention_available() -> bool:
 def _pick_block(t: int, target: int = 1024) -> int:
     """Largest divisor of t that is <= target and a multiple of 8.
 
-    The rows of a block: its lanes are chosen from the head width
-    (``_heads_per_block``), and a grid cell is one (batch row, lane
-    block, q-block, k-block). Default target 1024: a grid cell has
-    its price (pipeline fill, scratch init, the streaming softmax's
-    extra VPU work), so a row that fits one block takes one, and the
-    block is cut inside the body instead, by static slices
+    The rows of a block, queries and keys alike (its lanes come from
+    the head width, ``_heads_per_block``). Default target 1024: a grid
+    cell has its price (pipeline fill, scratch init, the streaming
+    softmax's extra VPU work), so a row that fits one block takes one,
+    and the block is cut inside the body instead, by static slices
     (``_causal_slabs``): 33.1 ms of kernels a GPT-2 step so against
     46.6 for the square in one piece (one v5e chip, 12 layers of 32 x
     12 heads at T=1024; PERF.md section 6, PR 31)."""
@@ -108,21 +108,21 @@ def _pick_block(t: int, target: int = 1024) -> int:
     return best
 
 
-def _masked_scores(q, k, iq, ik, *, scale, bq, bk, causal, window=None):
+def _masked_scores(q, k, iq, ik, *, scale, blk, causal, window=None):
     """Scaled q·kᵀ for one (q-block, k-block) pair with the causal
-    mask applied in absolute coordinates — shared by the fwd and both
-    bwd kernels so the mask can never diverge between passes. Under a
-    ``window`` a row sees its last ``window`` keys, itself among them:
+    mask applied in absolute coordinates — shared by the forward and the
+    backward kernel so the mask can never diverge between passes. Under
+    a ``window`` a row sees its last ``window`` keys, itself among them:
     ``row - window < col <= row``."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale        # [bq, bk]
+        preferred_element_type=jnp.float32) * scale        # [blk, blk]
     if causal:
-        row0, col0 = iq * bq, ik * bk
+        row0, col0 = iq * blk, ik * blk
         rows = row0 + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 0)
+            jnp.int32, (blk, blk), 0)
         cols = col0 + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 1)
+            jnp.int32, (blk, blk), 1)
         seen = rows >= cols
         if window is not None:
             seen &= cols > rows - window
@@ -136,73 +136,75 @@ def _masked_scores(q, k, iq, ik, *, scale, bq, bk, causal, window=None):
 #
 # Under a window the live blocks of a q-block no longer start at key
 # block 0, and the live q-blocks of a key block end: the multi-block
-# grids' innermost dimension then runs over the band alone (``nb``
-# cells, the most any outer block needs) and the index maps add the
-# band's first block, so the blocks below the band are in no cell, as
-# the blocks above the diagonal are in none of the causal grid's live
-# ones. A cell past its outer block's last live block (the first rows
-# of the sequence, whose band is cut by its start) is skipped and its
-# index clamped to the block before, so it moves nothing. Of a band's
-# blocks the one on the diagonal and the one on the band's lower edge
-# pay for a mask; the blocks between are wholly visible.
+# grids' innermost dimension then runs over the band alone (the most
+# cells any outer block needs) and the index maps add the band's first
+# block, so the blocks below the band are in no cell, as the blocks
+# above the diagonal are in none of the causal grid's live ones. A cell
+# past its outer block's last live block (the first rows of the
+# sequence, whose band is cut by its start) is skipped and its index
+# clamped to the block before, so it moves nothing. Of a band's blocks
+# the one on the diagonal and the one on the band's lower edge pay for a
+# mask; the blocks between are wholly visible. Every edge below is
+# written from the rows it stands for (a block's first row, its last,
+# the window's far end), in blocks of ``blk`` rows each way.
 
-def _keys_of(iq, bq, bk, window, most=jnp.maximum):
-    """(first, last) live key block of q-block ``iq`` (``most``: the
+def _keys_of(iq, blk, window, most=jnp.maximum):
+    """(first, last) live key block of q-block ``iq``: the blocks of the
+    first key its first row sees and of its last row (``most``: the
     built-in ``max`` where ``iq`` is a Python number)."""
-    return (most(iq * bq - (window - 1), 0) // bk,
-            (iq * bq + bq - 1) // bk)
+    return (most(iq * blk - (window - 1), 0) // blk,
+            (iq * blk + blk - 1) // blk)
 
 
-def _queries_of(ik, bq, bk, window, nq, least=jnp.minimum):
-    """(first, last) live q-block of key block ``ik``."""
-    return ((ik * bk) // bq,
-            least((ik * bk + bk + window - 2) // bq, nq - 1))
+def _queries_of(ik, blk, window, nb, least=jnp.minimum):
+    """(first, last) live q-block of key block ``ik``, of ``nb``: the
+    blocks of its first key's own row and of the last row that sees its
+    last key."""
+    return ((ik * blk) // blk,
+            least((ik * blk + blk + window - 2) // blk, nb - 1))
 
 
-def _wholly_seen(iq, ik, bq, bk, window):
+def _wholly_seen(iq, ik, blk, window):
     """No entry of the block pair is masked: it lies under the
     diagonal and above the band's lower edge."""
-    return ((ik * bk + bk - 1 <= iq * bq)
-            & (ik * bk > iq * bq + bq - 1 - window))
+    return ((ik * blk + blk - 1 <= iq * blk)
+            & (ik * blk > iq * blk + blk - 1 - window))
 
 
-def _band(t, bq, bk, window):
-    """(cells of the innermost grid dimension over key blocks, the same
-    over q-blocks, block pairs a head walks) under ``window``; with
-    None the causal grid's: every block in the grid, those at or under
-    the diagonal walked."""
-    nq, nk = t // bq, t // bk
+def _band(t, blk, window):
+    """(cells of a multi-block grid's innermost dimension, block pairs a
+    head walks) under ``window``: the most live key blocks a q-block
+    has, which is also the most live q-blocks a key block has (blocks
+    are square), and the live pairs. With None the causal grid's: every
+    block a cell, the pairs at or under the diagonal walked."""
+    nb = t // blk
     if window is None:
-        return nk, nq, sum((i * bq + bq - 1) // bk + 1 for i in range(nq))
-    def count(span):
-        first, last = span
-        return last - first + 1
-    keys = [count(_keys_of(i, bq, bk, window, max)) for i in range(nq)]
-    queries = [count(_queries_of(j, bq, bk, window, nq, min))
-               for j in range(nk)]
-    return max(keys), max(queries), sum(keys)
+        return nb, nb * (nb + 1) // 2
+    spans = (_keys_of(i, blk, window, max) for i in range(nb))
+    keys = [last - first + 1 for first, last in spans]
+    return max(keys), sum(keys)
 
 
-def _key_cell(iq, cell, *, bq, bk, causal, window):
+def _key_cell(iq, cell, *, blk, causal, window):
     """(key block, is it live) of cell ``cell`` of q-block ``iq``'s
     innermost grid dimension: the cell's own number, live at or under
     the diagonal; under a window the band's first block plus the cell,
     live up to the band's last."""
     if window is None:
-        return cell, (not causal) or (cell * bk <= iq * bq + bq - 1)
-    first, last = _keys_of(iq, bq, bk, window)
+        return cell, (not causal) or (cell * blk <= iq * blk + blk - 1)
+    first, last = _keys_of(iq, blk, window)
     return first + cell, first + cell <= last
 
 
-def _query_cell(ik, cell, *, bq, bk, causal, window, nq):
-    """The same for key block ``ik``'s q-blocks (the dk/dv kernel)."""
+def _query_cell(ik, cell, *, blk, causal, window, nb):
+    """The same for key block ``ik``'s q-blocks (the backward kernel)."""
     if window is None:
-        return cell, (not causal) or (ik * bk <= cell * bq + bq - 1)
-    first, last = _queries_of(ik, bq, bk, window, nq)
+        return cell, (not causal) or (ik * blk <= cell * blk + blk - 1)
+    first, last = _queries_of(ik, blk, window, nb)
     return first + cell, first + cell <= last
 
 
-def _on_live(iq, ik, live, body, *, bq, bk, causal, window):
+def _on_live(iq, ik, live, body, *, blk, causal, window):
     """``body(masked)`` for a ``live`` block pair (``iq``, ``ik``).
     Without a window every live pair is masked where the row is causal;
     under one only a pair that straddles the diagonal or the band's
@@ -213,7 +215,7 @@ def _on_live(iq, ik, live, body, *, bq, bk, causal, window):
     if window is None:
         pl.when(live)(functools.partial(body, causal))
         return
-    whole = _wholly_seen(iq, ik, bq, bk, window)
+    whole = _wholly_seen(iq, ik, blk, window)
     pl.when(live & whole)(functools.partial(body, False))
     pl.when(live & jnp.logical_not(whole))(functools.partial(body, True))
 
@@ -237,16 +239,14 @@ def _causal_slabs(t: int, causal: bool) -> int:
     Why 256 rows, and three (one v5e chip, 32 x 12 heads of 64, forward
     + backward of a layer in ms; PERF.md section 6, PR 31). At t=1024:
     the square 1.18 + 2.76, two slabs of 512 0.94 + 2.17, four of 256
-    0.93 + 1.86, eight of 128 1.19 + 2.44. A matmul latches its right
-    operand into the array 128 x 128 at a time and streams the left
-    one's rows past it, so a slab of S rows latches k's and v's tiles
-    once more and streams only S rows past each: at 128 that costs what
-    the triangle saves. At t=768 three slabs 0.59 + 1.17 against 0.78 +
-    1.57; at t=512 two slabs, three quarters of the square, 0.49 + 0.74
-    against 0.38 + 0.74: no gain, so the square stays. Callers ask for
-    a row under a ``window`` as for one that is not causal: it takes
-    the square too, with the band masked in it (a test's window, a tiny
-    preset's: the models' are longer than a block)."""
+    0.93 + 1.86, eight of 128 1.19 + 2.44: a slab of S rows latches k's
+    and v's 128 x 128 tiles into the array once more and streams only S
+    rows past each, which at 128 costs what the triangle saves. At t=768
+    three slabs 0.59 + 1.17 against 0.78 + 1.57; at t=512 two slabs
+    0.49 + 0.74 against 0.38 + 0.74: no gain, so the square stays.
+    Callers ask for a row under a ``window`` as for one that is not
+    causal: it takes the square, with the band masked in it (a test's
+    window, a tiny preset's: the models' are longer than a block)."""
     if causal and t % _SLAB_ROWS == 0 and t >= 3 * _SLAB_ROWS:
         return t // _SLAB_ROWS
     return 1
@@ -284,8 +284,8 @@ def _slab_scores(q, k, *, scale, causal, window=None):
 # of the grid's first dimension, G blocks of L lanes along the last.
 # A block holds ``hpb`` heads of width ``d`` side by side (L = hpb*d),
 # which the body walks as static lane slices. ``lse`` (and ``delta``)
-# are [N, G, T/bq, hpb, bq] float32, a q-block's rows along the lanes:
-# a [bq, 1] column, the shape the scores broadcast against, fills one
+# are [N, G, T/blk, hpb, blk] float32, a q-block's rows along the lanes:
+# a [blk, 1] column, the shape the scores broadcast against, fills one
 # lane in 128 of the chip's tiles (201 MB a GPT-2 layer where this is
 # 1.6), so the body turns columns into rows on the way out and back
 # on the way in.
@@ -331,7 +331,7 @@ def _delta(o_ref, do_ref, sl, rows=slice(None)):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, bq, bk, nk, d, hpb,
+                acc_ref, m_ref, l_ref, *, scale, blk, nk, d, hpb,
                 causal, window=None):
     """``nk``: the cells of the innermost grid dimension, every key
     block or, under a ``window``, the band's (``_band``)."""
@@ -346,28 +346,28 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     def attend(kb, masked):
         for j, sl in enumerate(_head_slices(d, hpb)):
-            q = q_ref[0, :, sl]                # [bq, d]
-            k = k_ref[0, :, sl]                # [bk, d]
+            q = q_ref[0, :, sl]                # [blk, d]
+            k = k_ref[0, :, sl]
             v = v_ref[0, :, sl]
-            s = _masked_scores(q, k, iq, kb, scale=scale, bq=bq,
-                               bk=bk, causal=masked, window=window)
+            s = _masked_scores(q, k, iq, kb, scale=scale, blk=blk,
+                               causal=masked, window=window)
 
-            m_prev = m_ref[j]                  # [bq, 128] (replicated)
-            block_max = jnp.max(s, axis=-1, keepdims=True)  # [bq, 1]
+            m_prev = m_ref[j]                  # [blk, 128] (replicated)
+            block_max = jnp.max(s, axis=-1, keepdims=True)  # [blk, 1]
             m_new = jnp.maximum(m_prev, jnp.broadcast_to(
                 block_max, m_prev.shape))
-            corr = jnp.exp(m_prev[:, :1] - m_new[:, :1])    # [bq, 1]
-            p = jnp.exp(s - m_new[:, :1])                   # [bq, bk]
+            corr = jnp.exp(m_prev[:, :1] - m_new[:, :1])    # [blk, 1]
+            p = jnp.exp(s - m_new[:, :1])                   # [blk, blk]
             l_ref[j] = l_ref[j] * corr + jnp.broadcast_to(
                 jnp.sum(p, axis=-1, keepdims=True), m_prev.shape)
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [bq, d]
+                preferred_element_type=jnp.float32)         # [blk, d]
             acc_ref[:, sl] = acc_ref[:, sl] * corr + pv
             m_ref[j] = m_new
 
     # Blocks above the diagonal, and below a window's band, are skipped.
-    where = dict(bq=bq, bk=bk, causal=causal, window=window)
+    where = dict(blk=blk, causal=causal, window=window)
     kb, live = _key_cell(iq, ik, **where)
     _on_live(iq, kb, live, functools.partial(attend, kb), **where)
 
@@ -421,23 +421,21 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "causal", "bq", "bk", "d", "hpb", "interpret", "window",
-    "one_bwd"))
-def _flash_fwd(q, k, v, *, scale, causal, bq, bk, d, hpb, interpret,
-               window=None, one_bwd=False):
-    """(out [N, T, G*L], lse [N, G, T/bq, hpb, bq]). Under ``jax.jit`` so
-    that a model's layers, which call it at one shape, trace and lower
-    it once a trace of the step and share one ``func.func``: XLA
+    "scale", "causal", "blk", "d", "hpb", "interpret", "window"))
+def _flash_fwd(q, k, v, *, scale, causal, blk, d, hpb, interpret,
+               window=None):
+    """(out [N, T, G*L], lse [N, G, T/blk, hpb, blk]). Under ``jax.jit``
+    so that a model's layers, which call it at one shape, trace and
+    lower it once a trace of the step and share one ``func.func``: XLA
     inlines the calls again, each under its caller's scope. Takes
-    ``_Static`` whole; ``one_bwd`` is the backward's alone."""
-    del one_bwd
+    ``_Static`` whole."""
     n, t, w = q.shape
     lanes = d * hpb
     g = w // lanes
-    nq, nk = t // bq, t // bk
+    nb = t // blk
     out_shape = [jax.ShapeDtypeStruct((n, t, w), q.dtype),
-                 jax.ShapeDtypeStruct((n, g, nq, hpb, bq), jnp.float32)]
-    if nq == 1 and nk == 1:
+                 jax.ShapeDtypeStruct((n, g, nb, hpb, blk), jnp.float32)]
+    if nb == 1:
         seq = _seq_spec(t, lanes, lambda b, c: (b, 0, c))
         return pl.pallas_call(
             functools.partial(_fwd_single_kernel, scale=scale, t=t,
@@ -452,39 +450,36 @@ def _flash_fwd(q, k, v, *, scale, causal, bq, bk, d, hpb, interpret,
             out_shape=out_shape,
             interpret=interpret,
         )(q, k, v)
-    nk, kv_spec = _key_cells(t, bq, bk, lanes, window)
-    q_spec = _seq_spec(bq, lanes, lambda b, c, i, j: (b, i, c))
+    # key cells: every key block, or under a window the band's, counted
+    # from the q-block's first live one and clamped to its last
+    if window is None:
+        nk = nb
+        kv_spec = _seq_spec(blk, lanes, lambda b, c, i, j: (b, j, c))
+    else:
+        nk = _band(t, blk, window)[0]
+
+        def block(b, c, i, j):
+            first, last = _keys_of(i, blk, window)
+            return (b, jnp.minimum(first + j, last), c)
+        kv_spec = _seq_spec(blk, lanes, block)
+    q_spec = _seq_spec(blk, lanes, lambda b, c, i, j: (b, i, c))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk,
+        functools.partial(_fwd_kernel, scale=scale, blk=blk,
                           nk=nk, d=d, hpb=hpb, causal=causal,
                           window=window),
-        grid=(n, g, nq, nk),
+        grid=(n, g, nb, nk),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec,
-                   _stat_spec(hpb, bq, lambda b, c, i, j: (b, c, i, 0, 0))],
+                   _stat_spec(hpb, blk, lambda b, c, i, j: (b, c, i, 0, 0))],
         out_shape=out_shape,
         scratch_shapes=[
-            _vmem((bq, lanes)),      # acc
-            _vmem((hpb, bq, 128)),   # running max (replicated lanes)
-            _vmem((hpb, bq, 128)),   # running sum (replicated lanes)
+            _vmem((blk, lanes)),      # acc
+            _vmem((hpb, blk, 128)),   # running max (replicated lanes)
+            _vmem((hpb, blk, 128)),   # running sum (replicated lanes)
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
     )(q, k, v)
-
-
-def _key_cells(t, bq, bk, lanes, window):
-    """(cells of the innermost dimension, the k / v block's spec) of a
-    grid (batch, lane block, q-block, key cell): every key block, or
-    under a ``window`` the band's, counted from the q-block's first
-    live one and clamped to its last (``_band``'s comment)."""
-    if window is None:
-        return t // bk, _seq_spec(bk, lanes, lambda b, c, i, j: (b, j, c))
-
-    def block(b, c, i, j):
-        first, last = _keys_of(i, bq, bk, window)
-        return (b, jnp.minimum(first + j, last), c)
-    return _band(t, bq, bk, window)[0], _seq_spec(bk, lanes, block)
 
 
 def _vmem(shape):
@@ -507,121 +502,28 @@ def _compiler_params(sequential: int = 1, vmem: int | None = None):
 # backward
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                   dq_ref, delta_ref, acc_ref, *, scale, bq, bk, nk, d,
-                   hpb, causal, window=None):
-    """dq of one q-block, and its ``delta`` (the row sums of o * do,
-    made once where the block's o and do are at hand) for this kernel's
-    k-steps and for the dk/dv kernel after it. ``nk`` as in
-    ``_fwd_kernel``."""
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        for j, sl in enumerate(_head_slices(d, hpb)):
-            delta_ref[j:j + 1] = _as_row(_delta(o_ref, do_ref, sl))
-
-    def step(kb, masked):
-        for j, sl in enumerate(_head_slices(d, hpb)):
-            q = q_ref[0, :, sl]
-            k = k_ref[0, :, sl]
-            v = v_ref[0, :, sl]
-            # bf16 operands into the MXU (f32 operands run it at a
-            # fraction of peak); accumulation stays f32.
-            do = do_ref[0, :, sl]
-            lse = _as_col(lse_ref[j:j + 1])      # [bq, 1]
-            delta = _as_col(delta_ref[j:j + 1])  # [bq, 1]
-            s = _masked_scores(q, k, iq, kb, scale=scale, bq=bq,
-                               bk=bk, causal=masked, window=window)
-            p = jnp.exp(s - lse)                            # [bq, bk]
-            dov = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [bq, bk]
-            ds = p * (dov - delta) * scale
-            acc_ref[:, sl] += jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-    where = dict(bq=bq, bk=bk, causal=causal, window=window)
-    kb, live = _key_cell(iq, ik, **where)
-    _on_live(iq, kb, live, functools.partial(step, kb), **where)
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, scale, bq, bk, nq, d, hpb, causal, window=None,
-                    q_blocks=None):
-    """``nq``: the cells of the innermost grid dimension, every q-block
-    or, under a ``window``, those of a key block's band: its live
-    q-blocks (of the row's ``q_blocks``) start at its diagonal and END
-    where the window has passed it."""
-    ik = pl.program_id(2)
-    iq = pl.program_id(3)
-
-    @pl.when(iq == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    def step(qb, masked):
-        for j, sl in enumerate(_head_slices(d, hpb)):
-            q = q_ref[0, :, sl]
-            k = k_ref[0, :, sl]
-            v = v_ref[0, :, sl]
-            do = do_ref[0, :, sl]              # bf16 operand for the MXU
-            lse = _as_col(lse_ref[j:j + 1])      # [bq, 1]
-            delta = _as_col(delta_ref[j:j + 1])  # [bq, 1]
-            s = _masked_scores(q, k, qb, ik, scale=scale, bq=bq,
-                               bk=bk, causal=masked, window=window)
-            p = jnp.exp(s - lse)                            # [bq, bk]
-            dv_acc[:, sl] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [bk, d]
-            dov = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dov - delta) * scale                  # [bq, bk]
-            dk_acc[:, sl] += jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [bk, d]
-
-    where = dict(bq=bq, bk=bk, causal=causal, window=window)
-    qb, live = _query_cell(ik, iq, nq=q_blocks, **where)
-    _on_live(qb, ik, live, functools.partial(step, qb), **where)
-
-    @pl.when(iq == nq - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
-
-
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, delta_ref,
                 *, scale, blk, nb, cells, d, hpb, causal, window=None):
-    """The whole backward pass of one block pair at equal blocks (``blk``
-    rows each way, ``nb`` of them a row): ``s``, ``p``, ``dov`` and
-    ``ds`` made once and the three products fed from them, five MXU
-    passes where ``_bwd_dq_kernel`` and ``_bwd_dkv_kernel`` make seven.
-    Grid (batch, lane block, key block, query cell), the last two in
-    order; ``cells`` as ``_bwd_dkv_kernel``'s ``nq``. In float32 scratch:
-    ``dk`` / ``dv`` of the key block across its cells; ``dq`` of the
-    lane block's WHOLE row across the key blocks, a q-block's rows
-    zeroed in its first live key block and cast and written in its last;
-    ``delta``, made in that first one from the ``o`` fetched there
-    alone. Sums run in the pair's order (key blocks ascending into
-    ``dq``, q-blocks ascending into ``dk`` / ``dv``): the gradients are
-    the pair's bit for bit."""
+    """The whole backward pass of one block pair of a row of several
+    blocks (``blk`` rows each way, ``nb`` of them a row): ``s``, ``p``,
+    ``dov`` and ``ds`` made once and the three products fed from them,
+    five MXU passes. Grid (batch, lane block, key block, query cell), the
+    last two in order; ``cells``: those of the innermost dimension, every
+    q-block or, under a ``window``, those of a key block's band, whose
+    live q-blocks start at its diagonal and END where the window has
+    passed it. In float32 scratch: ``dk`` / ``dv`` of the key block
+    across its cells; ``dq`` of the lane block's WHOLE row across the key
+    blocks, a q-block's rows zeroed in its first live key block and cast
+    and written in its last; ``delta``, made in that first one from the
+    ``o`` fetched there alone and never an array in HBM. Sums run in the
+    grid's order (key blocks ascending into ``dq``, q-blocks into ``dk``
+    / ``dv``): a run gives the last run's gradients to the bit."""
     ik = pl.program_id(2)
     cell = pl.program_id(3)
-    where = dict(bq=blk, bk=blk, causal=causal, window=window)
-    qb, live = _query_cell(ik, cell, nq=nb, **where)
-    first_key = 0 if window is None else _keys_of(qb, blk, blk, window)[0]
+    where = dict(blk=blk, causal=causal, window=window)
+    qb, live = _query_cell(ik, cell, nb=nb, **where)
+    first_key = 0 if window is None else _keys_of(qb, blk, window)[0]
     last_key = qb if causal else nb - 1     # equal blocks: the diagonal
     q_rows = pl.ds(pl.multiple_of(qb * blk, blk), blk)
     heads = _head_slices(d, hpb)
@@ -645,8 +547,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             do = do_ref[0, :, sl]              # bf16 operand for the MXU
             lse = _as_col(lse_ref[j:j + 1])                 # [blk, 1]
             delta = _as_col(delta_ref.at[qb][j:j + 1])      # [blk, 1]
-            s = _masked_scores(q, k, qb, ik, scale=scale, bq=blk,
-                               bk=blk, causal=masked, window=window)
+            s = _masked_scores(q, k, qb, ik, scale=scale, blk=blk,
+                               causal=masked, window=window)
             p = jnp.exp(s - lse)                            # [blk, blk]
             dv_acc[:, sl] += jax.lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -682,15 +584,14 @@ def _bwd_blocks(nb, blk, causal, window):
     cell's q-block is clamped to a live one, so it moves nothing; ``o``
     is fetched where a q-block meets its first live key block (delta)
     and stays on the last one fetched elsewhere; ``dq``'s block is that
-    of the q-block whose last live key block this is (the diagonal's,
-    equal blocks), so each is written to HBM once, when the index moves
-    on."""
-    cells = _band(nb * blk, blk, blk, window)[1]
+    of the q-block whose last live key block this is (the diagonal's),
+    so each is written to HBM once, when the index moves on."""
+    cells = _band(nb * blk, blk, window)[0]
 
     def last_q(j):      # the last live q-block of key block j
         if window is None:
             return nb - 1
-        return _queries_of(j, blk, blk, window, nb)[1]
+        return _queries_of(j, blk, window, nb)[1]
 
     def q_block(j, i):
         if window is None:
@@ -706,43 +607,54 @@ def _bwd_blocks(nb, blk, causal, window):
     return cells, q_block, o_block, dq_block
 
 
-# What the one-kernel backward may hold in VMEM (a v5e has 128 MiB).
+# What ``_bwd_kernel`` may hold in VMEM (a v5e has 128 MiB).
 _BWD_VMEM = 64 * 1024 * 1024
 
 
-def _bwd_fits(t: int, blk: int, lanes: int) -> bool:
-    """Does ``_bwd_kernel`` fit ``_BWD_VMEM`` at ``t`` rows in blocks of
-    ``blk``? Counted from the shapes, as ``_mla_bwd_fits`` is. What grows
-    with ``t``: the float32 ``dq`` of a lane block's whole row and
-    delta's rows (a block's heads padded to 8 sublanes), 544 bytes a row
-    at 128 lanes. What a cell holds whatever ``t`` is: the five 2-byte
-    operand blocks and the three output blocks twice over, the
-    statistics' block twice, ``dk``'s and ``dv``'s accumulators, and four
-    [blk, blk] float32 squares for the body's scores, probabilities and
-    cotangents."""
+def _bwd_bytes(t: int, blk: int, lanes: int) -> int:
+    """What ``_bwd_kernel`` holds in VMEM at ``t`` rows in blocks of
+    ``blk``, counted from the shapes. What grows with ``t``: the float32
+    ``dq`` of a lane block's whole row and delta's rows (a block's heads
+    padded to 8 sublanes), 544 bytes a row at 128 lanes. What a cell
+    holds whatever ``t`` is: the five 2-byte operand blocks and the
+    three output blocks twice over, the statistics' block twice,
+    ``dk``'s and ``dv``'s accumulators, and four [blk, blk] float32
+    squares for the body's scores, probabilities and cotangents."""
     lanes = -(-lanes // 128) * 128
     resident = t * 4 * (lanes + 8)
     cell = (4 * blk * blk * 4
             + 2 * 2 * blk * 8 * lanes
             + 2 * 4 * 8 * blk
             + 2 * blk * lanes * 4)
-    return resident + cell <= _BWD_VMEM
+    return resident + cell
+
+
+def _bwd_fits(t: int, blk: int, lanes: int) -> bool:
+    return _bwd_bytes(t, blk, lanes) <= _BWD_VMEM
+
+
+def _row_past_the_budget(kernel: str, t: int, lanes: int, asked: int,
+                         budget: int) -> NotImplementedError:
+    """What ``flash_attention`` and ``mla_flash_static`` raise (module
+    docstring: why no ``ValueError``)."""
+    return NotImplementedError(
+        f"a row of {t} rows at {lanes} lanes: {kernel} keeps dq of the "
+        f"whole row in VMEM and would hold {asked} bytes of a budget of "
+        f"{budget}. Split the sequence over an `sp` mesh axis, or keep "
+        "dq resident over the band's q-rows alone (ROADMAP C4)")
 
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref, *acc, scale, t, d, hpb,
                       causal, slabs, window=None):
-    """Single-block backward (t fits one block): computes the score
-    matrix ONCE for dq, dk, AND dv — the two-pass kernels each
-    recompute s/p/dov, so this saves a full [t,t] matmul + exp pass.
-
-    Walked as the forward is: ``slabs`` static row slabs that stop at
-    the diagonal, a head's slabs phase by phase. A slab's dq is whole;
-    its shares of dk and dv (the keys it read) are summed over the
-    slabs in float32 VMEM scratch ``acc`` = (dk_acc, dv_acc) and cast
-    once — the last slab, which reads every key, goes first and
-    initialises them. One slab (the square) has nothing to sum and
-    takes no scratch."""
+    """Single-block backward (t fits one block): the score matrix made
+    ONCE for dq, dk and dv. Walked as the forward is: ``slabs`` static
+    row slabs that stop at the diagonal, a head's slabs phase by phase.
+    A slab's dq is whole; its shares of dk and dv (the keys it read) are
+    summed over the slabs in float32 VMEM scratch ``acc`` = (dk_acc,
+    dv_acc) and cast once — the last slab, which reads every key, goes
+    first and initialises them. One slab (the square) has nothing to sum
+    and takes no scratch."""
     rows = _slab_rows(t, slabs)[::-1]
     for h, sl in enumerate(_head_slices(d, hpb)):
         lse = [_as_col(lse_ref[h:h + 1, r]) for r in rows]  # [S, 1]
@@ -787,22 +699,20 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "causal", "bq", "bk", "d", "hpb", "interpret", "window",
-    "one_bwd"))
-def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk, d, hpb,
-               interpret, window=None, one_bwd=False):
+    "scale", "causal", "blk", "d", "hpb", "interpret", "window"))
+def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, blk, d, hpb,
+               interpret, window=None):
     """(dq, dk, dv), each [N, T, G*L]; jitted for the reason
-    ``_flash_fwd`` is. A row of several blocks is ``_bwd_kernel`` alone
-    where ``one_bwd`` (``flash_attention`` decides: equal blocks whose
-    resident rows fit, ``_bwd_fits``), else the dq kernel and then the
-    dk/dv kernel."""
+    ``_flash_fwd`` is. One kernel: the fused body for a row of one
+    block, ``_bwd_kernel`` for a row of several (whose resident rows
+    ``flash_attention`` has held to ``_bwd_fits``)."""
     n, t, w = q.shape
     lanes = d * hpb
     ng = w // lanes
-    nq, nk = t // bq, t // bk
+    nb = t // blk
     do = g.astype(q.dtype)
     grads = [jax.ShapeDtypeStruct((n, t, w), x.dtype) for x in (q, k, v)]
-    if nq == 1 and nk == 1:
+    if nb == 1:
         seq = _seq_spec(t, lanes, lambda b, c: (b, 0, c))
         slabs = _causal_slabs(t, causal and window is None)
         return pl.pallas_call(
@@ -818,80 +728,30 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk, d, hpb,
             interpret=interpret,
         )(q, k, v, out, do, lse)
 
-    if one_bwd:
-        assert bq == bk and nq > 1, (bq, bk, nq)
-        cells, q_block, o_block, dq_block = _bwd_blocks(
-            nq, bq, causal, window)
-        q_spec = _seq_spec(
-            bq, lanes, lambda b, c, j, i: (b, q_block(j, i), c))
-        kv_spec = _seq_spec(bk, lanes, lambda b, c, j, i: (b, j, c))
-        return tuple(pl.pallas_call(
-            functools.partial(_bwd_kernel, scale=scale, blk=bq, nb=nq,
-                              cells=cells, d=d, hpb=hpb, causal=causal,
-                              window=window),
-            grid=(n, ng, nk, cells),
-            in_specs=[q_spec, kv_spec, kv_spec,
-                      _seq_spec(bq, lanes,
-                                lambda b, c, j, i: (b, o_block(j, i), c)),
-                      q_spec,
-                      _stat_spec(hpb, bq, lambda b, c, j, i: (
-                          b, c, q_block(j, i), 0, 0))],
-            out_specs=[_seq_spec(bq, lanes,
-                                 lambda b, c, j, i: (b, dq_block(j, i), c)),
-                       kv_spec, kv_spec],
-            out_shape=grads,
-            scratch_shapes=[_vmem((t, lanes)), _vmem((bk, lanes)),
-                            _vmem((bk, lanes)), _vmem((nq, hpb, bq))],
-            compiler_params=_compiler_params(2, _BWD_VMEM),
-            interpret=interpret,
-        )(q, k, v, out, do, lse))
-
-    key_cells, kv_spec = _key_cells(t, bq, bk, lanes, window)
-    q_spec = _seq_spec(bq, lanes, lambda b, c, i, j: (b, i, c))
-    stat = _stat_spec(hpb, bq, lambda b, c, i, j: (b, c, i, 0, 0))
-    dq, delta = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, bq=bq, bk=bk,
-                          nk=key_cells, d=d, hpb=hpb, causal=causal,
+    cells, q_block, o_block, dq_block = _bwd_blocks(nb, blk, causal, window)
+    q_spec = _seq_spec(
+        blk, lanes, lambda b, c, j, i: (b, q_block(j, i), c))
+    kv_spec = _seq_spec(blk, lanes, lambda b, c, j, i: (b, j, c))
+    return tuple(pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, blk=blk, nb=nb,
+                          cells=cells, d=d, hpb=hpb, causal=causal,
                           window=window),
-        grid=(n, ng, nq, key_cells),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, stat],
-        out_specs=[q_spec, stat],
-        out_shape=[grads[0], jax.ShapeDtypeStruct(lse.shape, lse.dtype)],
-        scratch_shapes=[_vmem((bq, lanes))],
-        compiler_params=_compiler_params(),
+        grid=(n, ng, nb, cells),
+        in_specs=[q_spec, kv_spec, kv_spec,
+                  _seq_spec(blk, lanes,
+                            lambda b, c, j, i: (b, o_block(j, i), c)),
+                  q_spec,
+                  _stat_spec(hpb, blk, lambda b, c, j, i: (
+                      b, c, q_block(j, i), 0, 0))],
+        out_specs=[_seq_spec(blk, lanes,
+                             lambda b, c, j, i: (b, dq_block(j, i), c)),
+                   kv_spec, kv_spec],
+        out_shape=grads,
+        scratch_shapes=[_vmem((t, lanes)), _vmem((blk, lanes)),
+                        _vmem((blk, lanes)), _vmem((nb, hpb, blk))],
+        compiler_params=_compiler_params(2, _BWD_VMEM),
         interpret=interpret,
-    )(q, k, v, out, do, lse)
-
-    if window is None:
-        q_cells = nq
-
-        def q_block(j, i):
-            return i
-    else:
-        # a key block's live q-blocks, from its diagonal to where the
-        # window has passed it; cells past them are clamped to the last
-        q_cells = _band(t, bq, bk, window)[1]
-
-        def q_block(j, i):
-            first, last = _queries_of(j, bq, bk, window, nq)
-            return jnp.minimum(first + i, last)
-    q_spec = _seq_spec(bq, lanes, lambda b, c, j, i: (b, q_block(j, i), c))
-    kv_spec = _seq_spec(bk, lanes, lambda b, c, j, i: (b, j, c))
-    stat = _stat_spec(hpb, bq,
-                      lambda b, c, j, i: (b, c, q_block(j, i), 0, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, bq=bq, bk=bk,
-                          nq=q_cells, d=d, hpb=hpb, causal=causal,
-                          window=window, q_blocks=nq),
-        grid=(n, ng, nk, q_cells),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat, stat],
-        out_specs=[kv_spec, kv_spec],
-        out_shape=grads[1:],
-        scratch_shapes=[_vmem((bk, lanes)), _vmem((bk, lanes))],
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    )(q, k, v, out, do, lse))
 
 
 # ---------------------------------------------------------------------------
@@ -902,13 +762,15 @@ class _Static(NamedTuple):
     """What the kernels are specialised on, besides their shapes."""
     scale: float
     causal: bool
-    bq: int
-    bk: int
+    blk: int        # rows of a block, queries and keys alike
     d: int          # head width
     hpb: int        # heads in a lane block
     interpret: bool
     window: int | None = None   # keys a row sees, itself among them
-    one_bwd: bool = False       # several blocks' backward is one kernel
+
+    @property   # the name benchmark/tools/smallthinker_limit.py reads
+    def bk(self) -> int:
+        return self.blk
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -943,8 +805,7 @@ def _heads_per_block(h: int, d: int) -> int:
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     *, causal: bool = True,
                     scale: float | None = None,
-                    block_q: int | None = None,
-                    block_k: int | None = None,
+                    block: int | None = None,
                     interpret: bool = False,
                     window: int | None = None) -> jax.Array:
     """Flash attention on [B, T, H, D]; differentiable (custom VJP).
@@ -952,34 +813,33 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``window``: a row sees its last ``window`` keys, itself among them
     (``t - window < j <= t``: the sliding-window layers of a
     window/global stack); None, or a window no shorter than the row, is
-    plain causal attention and the program it was. The multi-block
-    grids run over the band alone, in both passes (``_band``'s
-    comment); a call given a window says so in the notes:
-    ``flash_window`` (``"none"`` where the row is no longer than it) and
-    ``flash_band_blocks``, the block pairs a head walks (70 at 16,384
-    rows in blocks of 1,024 under a window of 4,096, against the causal
-    grid's 136). In a stack that mixes windowed and global layers the
-    notes are those of the last windowed call.
+    plain causal attention. The multi-block grids run over the band
+    alone, in both passes (``_band``'s comment); a call given a window
+    says so in the notes: ``flash_window`` (``"none"`` where the row is
+    no longer than it) and ``flash_band_blocks``, the block pairs a head
+    walks (70 at 16,384 rows in blocks of 1,024 under a window of 4,096,
+    against the causal grid's 136). In a stack that mixes windowed and
+    global layers the notes are those of the last windowed call.
 
     Falls back to the caller's dense path when shapes don't block
     cleanly — check with ``flash_attention_shapes_ok`` or catch
-    ValueError.
+    ValueError. A row of several blocks too long for the backward
+    kernel's VMEM (``_bwd_fits``) is no such shape: it raises
+    ``NotImplementedError``, before anything is traced or noted.
+    ``block``: the rows of a block in place of ``_pick_block``'s, the
+    tests' way to the multi-block grids at sizes the CPU interprets.
 
-    The kernels read q, k, v where the projections wrote them and
-    write the output where the output projection reads it: [B, T, H, D]
-    is [B, T, H*D] for free, and a block is 128 lanes of it (see
-    ``_heads_per_block``). Shapes that cannot be blocked so are folded
-    to [B*H, T, D], at a transpose each way; which of the two ran is in
-    the trace's notes (``flash_layout``).
+    The kernels read q, k, v where the projections wrote them, 128
+    lanes of [B, T, H*D] a block (``_heads_per_block``); shapes that
+    cannot be blocked so are folded to [B*H, T, D], at a transpose each
+    way. Which of the two ran is in the notes (``flash_layout``).
     """
     b, t, h, d = q.shape
     if scale is None:
         scale = d ** -0.5
-    bq = block_q or _pick_block(t)
-    bk = block_k or _pick_block(t)
-    if bq == 0 or bk == 0 or t % bq or t % bk:
-        raise ValueError(
-            f"seq len {t} not divisible into flash blocks")
+    blk = block or _pick_block(t)
+    if blk == 0 or t % blk:
+        raise ValueError(f"seq len {t} not divisible into flash blocks")
     asked = window is not None
     if asked:
         if not causal or window < 1:
@@ -989,7 +849,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         window = None if window >= t else int(window)
     direct = _heads_per_block(h, d)
     hpb = direct or 1           # folded: one head a block
-    single = bq == t == bk
+    single = blk == t
+    if not single and not _bwd_fits(t, blk, d * hpb):
+        raise _row_past_the_budget(
+            "the backward kernel", t, d * hpb,
+            _bwd_bytes(t, blk, d * hpb), _BWD_VMEM)
     # Made here and not in the jitted functions: jax caches their
     # traces, so the step's second trace would find no note.
     tracing.note_trace(
@@ -1000,14 +864,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         if single else 1)
     if asked:   # a call without a window leaves the notes it left
         tracing.note_trace(flash_window=window or "none",
-                           flash_band_blocks=_band(t, bq, bk, window)[2])
-    one_bwd = not single and bq == bk and _bwd_fits(t, bq, d * hpb)
-    if not single:
-        tracing.note_trace(flash_bwd_kernels=1 if one_bwd else 2)
-    if one_bwd:     # dq's accumulator holds the whole row in VMEM
+                           flash_band_blocks=_band(t, blk, window)[1])
+    if not single:  # dq's accumulator holds the whole row in VMEM
         tracing.note_trace(flash_bwd_resident_rows=t)
-    static = _Static(float(scale), causal, bq, bk, d, hpb, interpret, window,
-                     one_bwd)
+    static = _Static(float(scale), causal, blk, d, hpb, interpret, window)
     if direct:
         out = _flash_core(q.reshape(b, t, h * d), k.reshape(b, t, h * d),
                           v.reshape(b, t, h * d), static)
@@ -1021,7 +881,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def flash_attention_shapes_ok(t: int, d: int) -> bool:
     return _pick_block(t) >= 128 and d % 8 == 0
-
 
 
 # ---------------------------------------------------------------------------
@@ -1043,17 +902,16 @@ def flash_attention_shapes_ok(t: int, d: int) -> bool:
 # no [B, T, H*(dn+dr)] key exists anywhere. A grid cell holds ``hpb =
 # 128 // dr`` heads (two), so that its block of ``qr`` is whole 128-lane
 # tiles; ``kr``'s block is the array's full 64 lanes. The grids are the
-# multi-block ones above at bq == bk, with two differences: the block
-# that straddles the diagonal is the only one that pays for a mask, and
-# the index maps stop at the diagonal, so a skipped cell moves nothing.
+# multi-block ones above, with two differences: the block that
+# straddles the diagonal is the only one that pays for a mask, and the
+# index maps stop at the diagonal, so a skipped cell moves nothing.
 # Statistics and outputs are laid out as above.
 #
-# The backward pass is ONE kernel where its accumulators fit the VMEM
-# (``_mla_bwd_fits``: 32,768 rows do, 65,536 do not): grid
-# (batch, head pair, key block, query block), the last three in order.
-# A cell makes ``s``, ``p``, ``dP`` and ``ds`` of its block pair once
-# and feeds all five products from them. What stays in VMEM scratch
-# across cells, in float32, and is cast and written once:
+# The backward pass is one kernel, grid (batch, head pair, key block,
+# query block), the last three in order. A cell makes ``s``, ``p``,
+# ``dP`` and ``ds`` of its block pair once and feeds all five products
+# from them. What stays in VMEM scratch across cells, in float32, and is
+# cast and written once (and bounds the row: ``_mla_bwd_fits``):
 #
 #   dk_n, dv   [block, 256]     of the key block, across its query blocks
 #   dq_n, dq_r [T, 256 + 128]   of the head pair's WHOLE sequence, across
@@ -1068,15 +926,10 @@ def flash_attention_shapes_ok(t: int, d: int) -> bool:
 # An output block is indexed by the key block of the cell that fills it
 # (``dq``: the diagonal cell; ``dk_r``: block 0 until the last head
 # pair), so each is written to HBM once, when its index moves on.
-# A row too long for that takes the two kernels this one replaced, which
-# each make the scores and ``dP`` again (3 of the dq kernel's 5 and 3 of
-# the dk/dv kernel's 6 MXU passes a block pair): dq with the key block
-# innermost, dk/dv with the query block innermost and the head pairs as
-# the third, sequential, dimension that carries ``dk_r``.
 
 def _mla_scores(qn, qr, kn, kr, scale, on_diagonal: bool):
-    """[bq, bk] scaled scores of one head: the two parts' products
-    summed; on the diagonal block (bq == bk) the causal mask."""
+    """[blk, blk] scaled scores of one head: the two parts' products
+    summed; on the diagonal block the causal mask."""
     dims = (((1,), (1,)), ((), ()))
     s = (jax.lax.dot_general(qn, kn, dims,
                              preferred_element_type=jnp.float32)
@@ -1119,11 +972,11 @@ def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
             v = v_ref[0, :, n]
             s = _mla_scores(qn_ref[0, :, n], qr_ref[0, :, r],
                             kn_ref[0, :, n], kr, scale, on_diagonal)
-            m_prev = m_ref[j]                  # [bq, 128] (replicated)
+            m_prev = m_ref[j]                  # [blk, 128] (replicated)
             m_new = jnp.maximum(m_prev, jnp.broadcast_to(
                 jnp.max(s, axis=-1, keepdims=True), m_prev.shape))
-            corr = jnp.exp(m_prev[:, :1] - m_new[:, :1])    # [bq, 1]
-            p = jnp.exp(s - m_new[:, :1])                   # [bq, bk]
+            corr = jnp.exp(m_prev[:, :1] - m_new[:, :1])    # [blk, 1]
+            p = jnp.exp(s - m_new[:, :1])                   # [blk, blk]
             l_ref[j] = l_ref[j] * corr + jnp.broadcast_to(
                 jnp.sum(p, axis=-1, keepdims=True), m_prev.shape)
             acc_ref[:, n] = acc_ref[:, n] * corr + jax.lax.dot_general(
@@ -1142,106 +995,13 @@ def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
                 m_ref[j][:, :1] + jnp.log(l)).astype(lse_ref.dtype)
 
 
-def _mla_ds(qn_ref, qr_ref, kn_ref, kr, v_ref, do_ref, lse_ref, delta_ref,
-            j, n, r, scale, on_diagonal):
-    """(p, ds) of head ``j`` of the cell, [bq, bk]: the probabilities
-    from the saved log-sum-exp and the scores' cotangent, ds in the
-    operands' type for the matmuls that follow."""
-    s = _mla_scores(qn_ref[0, :, n], qr_ref[0, :, r], kn_ref[0, :, n], kr,
-                    scale, on_diagonal)
-    p = jnp.exp(s - _as_col(lse_ref[j:j + 1]))
-    dov = jax.lax.dot_general(
-        do_ref[0, :, n], v_ref[0, :, n], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    ds = p * (dov - _as_col(delta_ref[j:j + 1])) * scale
-    return p, ds.astype(qn_ref.dtype)
-
-
-def _mla_bwd_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
-                       lse_ref, dqn_ref, dqr_ref, delta_ref, accn_ref,
-                       accr_ref, *, scale, nk, dn, dr, hpb):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-
-    @pl.when(ik == 0)
-    def _init():
-        accn_ref[...] = jnp.zeros_like(accn_ref)
-        accr_ref[...] = jnp.zeros_like(accr_ref)
-        for j, (n, _) in enumerate(_mla_parts(dn, dr, hpb)):
-            delta_ref[j:j + 1] = _as_row(_delta(o_ref, do_ref, n))
-
-    def step(on_diagonal):
-        kr = kr_ref[0]
-        for j, (n, r) in enumerate(_mla_parts(dn, dr, hpb)):
-            _, ds = _mla_ds(qn_ref, qr_ref, kn_ref, kr, v_ref, do_ref,
-                            lse_ref, delta_ref, j, n, r, scale, on_diagonal)
-            dims = (((1,), (0,)), ((), ()))
-            accn_ref[:, n] += jax.lax.dot_general(
-                ds, kn_ref[0, :, n], dims,
-                preferred_element_type=jnp.float32)
-            accr_ref[:, r] += jax.lax.dot_general(
-                ds, kr, dims, preferred_element_type=jnp.float32)
-
-    _on_live_blocks(iq, ik, step)
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        dqn_ref[0] = accn_ref[...].astype(dqn_ref.dtype)
-        dqr_ref[0] = accr_ref[...].astype(dqr_ref.dtype)
-
-
-def _mla_bwd_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
-                        lse_ref, delta_ref, dkn_ref, dkr_ref, dv_ref,
-                        dkn_acc, dkr_acc, dv_acc, *, scale, ng, nq, dn, dr,
-                        hpb):
-    ik = pl.program_id(1)
-    c = pl.program_id(2)
-    iq = pl.program_id(3)
-
-    @pl.when(iq == 0)
-    def _init():
-        dkn_acc[...] = jnp.zeros_like(dkn_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    @pl.when((iq == 0) & (c == 0))
-    def _init_shared():
-        dkr_acc[...] = jnp.zeros_like(dkr_acc)
-
-    def step(on_diagonal):
-        kr = kr_ref[0]
-        for j, (n, r) in enumerate(_mla_parts(dn, dr, hpb)):
-            p, ds = _mla_ds(qn_ref, qr_ref, kn_ref, kr, v_ref, do_ref,
-                            lse_ref, delta_ref, j, n, r, scale, on_diagonal)
-            do = do_ref[0, :, n]
-            dims = (((0,), (0,)), ((), ()))
-            dv_acc[:, n] += jax.lax.dot_general(
-                p.astype(do.dtype), do, dims,
-                preferred_element_type=jnp.float32)         # [bk, dn]
-            dkn_acc[:, n] += jax.lax.dot_general(
-                ds, qn_ref[0, :, n], dims,
-                preferred_element_type=jnp.float32)
-            dkr_acc[...] += jax.lax.dot_general(
-                ds, qr_ref[0, :, r], dims,
-                preferred_element_type=jnp.float32)         # [bk, dr]
-
-    _on_live_blocks(iq, ik, step)
-
-    @pl.when(iq == nq - 1)
-    def _finalize():
-        dkn_ref[0] = dkn_acc[...].astype(dkn_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
-
-    @pl.when((iq == nq - 1) & (c == ng - 1))
-    def _finalize_shared():
-        dkr_ref[0] = dkr_acc[...].astype(dkr_ref.dtype)
-
-
 def _mla_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
                     lse_ref, dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref,
                     dqn_acc, dqr_acc, dkn_acc, dkr_acc, dv_acc, delta_ref,
                     *, scale, ng, nb, blk, dn, dr, hpb):
     """The whole backward pass of one (head pair, key block, query
-    block): ``_mla_ds`` once a head, five products from it."""
+    block): a head's probabilities (from the saved log-sum-exp) and its
+    scores' cotangent ``ds`` made once, five products from them."""
     c = pl.program_id(1)
     ik = pl.program_id(2)
     iq = pl.program_id(3)
@@ -1268,12 +1028,17 @@ def _mla_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
     def step(on_diagonal):
         kr = kr_ref[0]
         for j, (n, r) in enumerate(parts):
-            p, ds = _mla_ds(qn_ref, qr_ref, kn_ref, kr, v_ref, do_ref,
-                            lse_ref, delta_ref.at[iq], j, n, r, scale,
-                            on_diagonal)
+            s = _mla_scores(qn_ref[0, :, n], qr_ref[0, :, r],
+                            kn_ref[0, :, n], kr, scale, on_diagonal)
+            p = jnp.exp(s - _as_col(lse_ref[j:j + 1]))
+            dov = jax.lax.dot_general(
+                do_ref[0, :, n], v_ref[0, :, n], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = p * (dov - _as_col(delta_ref.at[iq][j:j + 1])) * scale
+            ds = ds.astype(qn_ref.dtype)    # the matmuls' operand type
             do = do_ref[0, :, n]
-            over_q = (((0,), (0,)), ((), ()))      # [bq, bk]^T [bq, d]
-            over_k = (((1,), (0,)), ((), ()))      # [bq, bk] [bk, d]
+            over_q = (((0,), (0,)), ((), ()))      # [blk, blk]^T [blk, d]
+            over_k = (((1,), (0,)), ((), ()))      # [blk, blk] [blk, d]
             dv_acc[:, n] += jax.lax.dot_general(
                 p.astype(do.dtype), do, over_q,
                 preferred_element_type=jnp.float32)
@@ -1309,11 +1074,10 @@ def _mla_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
 class _MlaStatic(NamedTuple):
     """What the latent-attention kernels are specialised on."""
     scale: float
-    block: int      # bq == bk
+    block: int      # rows of a block, queries and keys alike
     dn: int         # width of q_nope, k_nope and v of a head
     dr: int         # width of the rotary parts
     interpret: bool
-    one_bwd: bool   # the backward pass is one kernel (``_mla_bwd_fits``)
 
     @property
     def hpb(self) -> int:
@@ -1325,35 +1089,37 @@ class _MlaStatic(NamedTuple):
 # (two [1024, 1024] float32 score squares and their casts beside the
 # double-buffered operands). A v5e has 128 MiB.
 _MLA_VMEM = 64 * 1024 * 1024
-_MLA_BWD_VMEM = 100 * 1024 * 1024       # the one-kernel backward's
+_MLA_BWD_VMEM = 100 * 1024 * 1024       # the backward kernel's
 
 
-def _mla_bwd_fits(t: int, blk: int, dn: int, dr: int) -> bool:
-    """Does the one-kernel backward pass fit ``_MLA_BWD_VMEM`` at ``t``
-    rows? Counted from the shapes. What grows with ``t``: the float32
-    accumulators of a head pair's whole sequence (dq_n, dq_r, and dk_r
-    padded to 128 lanes) and delta's rows (a pair's 2 padded to 8
-    sublanes): 2,080 bytes a row at the published widths. What a cell
-    holds whatever ``t`` is: the 2-byte operand and output blocks twice
-    over, dk_n's and dv's accumulators, and four [blk, blk] float32
-    squares for the body's scores, probabilities and cotangents, twice
-    what the compiler was seen to take (at 8,192 rows in blocks of 1,024
-    it allocates 35.3 MiB where this counts 43.3; PERF.md section 6, PR
-    35). 32,768 rows fit, 65,536 do not."""
+def _mla_bwd_bytes(t: int, blk: int, dn: int, dr: int) -> int:
+    """What ``_mla_bwd_kernel`` holds in VMEM at ``t`` rows, counted
+    from the shapes. What grows with ``t``: the float32 accumulators of
+    a head pair's whole sequence (dq_n, dq_r, and dk_r padded to 128
+    lanes) and delta's rows (a pair's 2 padded to 8 sublanes): 2,080
+    bytes a row at the published widths. What a cell holds whatever
+    ``t`` is: as ``_bwd_bytes`` counts it, the squares twice what the
+    compiler was seen to take (at 8,192 rows in blocks of 1,024 it
+    allocates 35.3 MiB where this counts 43.3; PERF.md 6, PR 35)."""
     hpb = 128 // dr
     wide, rope = hpb * dn, hpb * dr
     resident = t * 4 * (wide + rope + 128 + 8)
     cell = (4 * blk * blk * 4
             + 2 * 2 * blk * (7 * wide + 2 * rope + 2 * 128)
             + 2 * blk * wide * 4)
-    return resident + cell <= _MLA_BWD_VMEM
+    return resident + cell
+
+
+def _mla_bwd_fits(t: int, blk: int, dn: int, dr: int) -> bool:
+    # 32,768 rows fit at the published widths, 65,536 do not
+    return _mla_bwd_bytes(t, blk, dn, dr) <= _MLA_BWD_VMEM
 
 
 @functools.partial(jax.jit, static_argnames=("static",))
 def mla_flash_fwd(qn, qr, kn, kr, v, *, static: _MlaStatic):
     """(out [B, T, H*dn], lse [B, G, T/b, hpb, b]); jitted for the
     reason ``_flash_fwd`` is."""
-    scale, blk, dn, dr, interpret, _ = static
+    scale, blk, dn, dr, interpret = static
     hpb = static.hpb
     b, t, w = qn.shape
     g, nb = w // (hpb * dn), t // blk
@@ -1384,98 +1150,47 @@ def mla_flash_fwd(qn, qr, kn, kr, v, *, static: _MlaStatic):
 @functools.partial(jax.jit, static_argnames=("static",))
 def mla_flash_bwd(qn, qr, kn, kr, v, out, lse, g, *, static: _MlaStatic):
     """(dqn, dqr, dkn, dkr, dv), shaped as the operands."""
-    scale, blk, dn, dr, interpret, one_bwd = static
+    scale, blk, dn, dr, interpret = static
     hpb = static.hpb
     b, t, w = qn.shape
     ng, nb = w // (hpb * dn), t // blk
     do = g.astype(qn.dtype)
-    like = jax.ShapeDtypeStruct
     wide, rope = hpb * dn, hpb * dr
-    grads = [like(x.shape, x.dtype) for x in (qn, qr, kn, kr, v)]
-
-    if one_bwd:
-        # grid (batch, head pair, key block, query block); the index maps
-        # stop at the diagonal; ``o`` is read in the first key block's
-        # cells alone (delta), and an output block is indexed by the key
-        # block of the cell that fills it
-        q_map = lambda b, c, j, i: (b, jnp.maximum(i, j), c)  # noqa: E731
-        kv_map = lambda b, c, j, i: (b, j, c)                 # noqa: E731
-        kr_map = lambda b, c, j, i: (b, j, 0)                 # noqa: E731
-        o_map = lambda b, c, j, i: (                          # noqa: E731
-            b, jnp.where(j == 0, i, nb - 1), c)
-        dkr_map = lambda b, c, j, i: (                        # noqa: E731
-            b, jnp.where(c == ng - 1, j, 0), 0)
-        stat = _stat_spec(hpb, blk, lambda b, c, j, i: (
-            b, c, jnp.maximum(i, j), 0, 0))
-        return tuple(pl.pallas_call(
-            functools.partial(_mla_bwd_kernel, scale=scale, ng=ng, nb=nb,
-                              blk=blk, dn=dn, dr=dr, hpb=hpb),
-            grid=(b, ng, nb, nb),
-            in_specs=[_seq_spec(blk, wide, q_map),
-                      _seq_spec(blk, rope, q_map),
-                      _seq_spec(blk, wide, kv_map),
-                      _seq_spec(blk, dr, kr_map),
-                      _seq_spec(blk, wide, kv_map),
-                      _seq_spec(blk, wide, o_map),
-                      _seq_spec(blk, wide, q_map), stat],
-            out_specs=[_seq_spec(blk, wide, kv_map),
-                       _seq_spec(blk, rope, kv_map),
-                       _seq_spec(blk, wide, kv_map),
-                       _seq_spec(blk, dr, dkr_map),
-                       _seq_spec(blk, wide, kv_map)],
-            out_shape=grads,
-            scratch_shapes=[_vmem((t, wide)), _vmem((t, rope)),
-                            _vmem((blk, wide)), _vmem((t, dr)),
-                            _vmem((blk, wide)), _vmem((nb, hpb, blk))],
-            compiler_params=_compiler_params(3, _MLA_BWD_VMEM),
-            interpret=interpret,
-        )(qn, qr, kn, kr, v, out, do, lse))
-
-    q_map = lambda b, c, i, j: (b, i, c)                      # noqa: E731
-    kv_map = lambda b, c, i, j: (b, jnp.minimum(j, i), c)     # noqa: E731
-    kr_map = lambda b, c, i, j: (b, jnp.minimum(j, i), 0)     # noqa: E731
-    stat = _stat_spec(hpb, blk, lambda b, c, i, j: (b, c, i, 0, 0))
-    dqn, dqr, delta = pl.pallas_call(
-        functools.partial(_mla_bwd_dq_kernel, scale=scale, nk=nb, dn=dn,
-                          dr=dr, hpb=hpb),
-        grid=(b, ng, nb, nb),
-        in_specs=[_seq_spec(blk, wide, q_map), _seq_spec(blk, rope, q_map),
-                  _seq_spec(blk, wide, kv_map), _seq_spec(blk, dr, kr_map),
-                  _seq_spec(blk, wide, kv_map),
-                  _seq_spec(blk, wide, q_map), _seq_spec(blk, wide, q_map),
-                  stat],
-        out_specs=[_seq_spec(blk, wide, q_map), _seq_spec(blk, rope, q_map),
-                   stat],
-        out_shape=[*grads[:2], like(lse.shape, lse.dtype)],
-        scratch_shapes=[_vmem((blk, wide)), _vmem((blk, rope))],
-        compiler_params=_compiler_params(1, _MLA_VMEM),
-        interpret=interpret,
-    )(qn, qr, kn, kr, v, out, do, lse)
-
-    # grid (batch, key block, head pair, query block): the last two in
-    # order, so that dk_r's sum over every head stays in scratch
-    q_map = lambda b, j, c, i: (b, jnp.maximum(i, j), c)      # noqa: E731
-    kv_map = lambda b, j, c, i: (b, j, c)                     # noqa: E731
-    kr_spec = _seq_spec(blk, dr, lambda b, j, c, i: (b, j, 0))
-    stat = _stat_spec(hpb, blk, lambda b, j, c, i: (
+    # the index maps stop at the diagonal; ``o`` is read in the first
+    # key block's cells alone (delta)
+    q_map = lambda b, c, j, i: (b, jnp.maximum(i, j), c)      # noqa: E731
+    kv_map = lambda b, c, j, i: (b, j, c)                     # noqa: E731
+    kr_map = lambda b, c, j, i: (b, j, 0)                     # noqa: E731
+    o_map = lambda b, c, j, i: (                              # noqa: E731
+        b, jnp.where(j == 0, i, nb - 1), c)
+    dkr_map = lambda b, c, j, i: (                            # noqa: E731
+        b, jnp.where(c == ng - 1, j, 0), 0)
+    stat = _stat_spec(hpb, blk, lambda b, c, j, i: (
         b, c, jnp.maximum(i, j), 0, 0))
-    dkn, dkr, dv = pl.pallas_call(
-        functools.partial(_mla_bwd_dkv_kernel, scale=scale, ng=ng, nq=nb,
-                          dn=dn, dr=dr, hpb=hpb),
-        grid=(b, nb, ng, nb),
-        in_specs=[_seq_spec(blk, wide, q_map), _seq_spec(blk, rope, q_map),
-                  _seq_spec(blk, wide, kv_map), kr_spec,
-                  _seq_spec(blk, wide, kv_map), _seq_spec(blk, wide, q_map),
-                  stat, stat],
-        out_specs=[_seq_spec(blk, wide, kv_map), kr_spec,
+    return tuple(pl.pallas_call(
+        functools.partial(_mla_bwd_kernel, scale=scale, ng=ng, nb=nb,
+                          blk=blk, dn=dn, dr=dr, hpb=hpb),
+        grid=(b, ng, nb, nb),
+        in_specs=[_seq_spec(blk, wide, q_map),
+                  _seq_spec(blk, rope, q_map),
+                  _seq_spec(blk, wide, kv_map),
+                  _seq_spec(blk, dr, kr_map),
+                  _seq_spec(blk, wide, kv_map),
+                  _seq_spec(blk, wide, o_map),
+                  _seq_spec(blk, wide, q_map), stat],
+        out_specs=[_seq_spec(blk, wide, kv_map),
+                   _seq_spec(blk, rope, kv_map),
+                   _seq_spec(blk, wide, kv_map),
+                   _seq_spec(blk, dr, dkr_map),
                    _seq_spec(blk, wide, kv_map)],
-        out_shape=grads[2:],
-        scratch_shapes=[_vmem((blk, wide)), _vmem((blk, dr)),
-                        _vmem((blk, wide))],
-        compiler_params=_compiler_params(2, _MLA_VMEM),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (qn, qr, kn, kr, v)],
+        scratch_shapes=[_vmem((t, wide)), _vmem((t, rope)),
+                        _vmem((blk, wide)), _vmem((t, dr)),
+                        _vmem((blk, wide)), _vmem((nb, hpb, blk))],
+        compiler_params=_compiler_params(3, _MLA_BWD_VMEM),
         interpret=interpret,
-    )(qn, qr, kn, kr, v, do, lse, delta)
-    return dqn, dqr, dkn, dkr, dv
+    )(qn, qr, kn, kr, v, out, do, lse))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -1511,18 +1226,22 @@ def mla_flash_static(t: int, dn: int, dr: int, scale: float | None = None,
                      block: int | None = None,
                      interpret: bool = False) -> _MlaStatic:
     """What ``mla_flash_fwd`` / ``mla_flash_bwd`` are specialised on at
-    these shapes, with the notes of which path compiled (made here and
-    not in the jitted functions, whose traces jax caches)."""
+    these shapes, with the notes of the path (made here and not in the
+    jitted functions, whose traces jax caches). A row whose backward
+    accumulators do not fit (``_mla_bwd_fits``) is refused here, before
+    a kernel is traced."""
     blk = block or _pick_block(t)
     if blk == 0 or t % blk:
         raise ValueError(f"seq len {t} not divisible into flash blocks")
-    one_bwd = _mla_bwd_fits(t, blk, dn, dr)
     static = _MlaStatic(float((dn + dr) ** -0.5 if scale is None else scale),
-                        blk, dn, dr, interpret, one_bwd)
+                        blk, dn, dr, interpret)
+    if not _mla_bwd_fits(t, blk, dn, dr):
+        raise _row_past_the_budget(
+            "latent attention's backward kernel", t,
+            static.hpb * (dn + dr), _mla_bwd_bytes(t, blk, dn, dr),
+            _MLA_BWD_VMEM)
     tracing.note_trace(
         flash_layout="bthd", flash_lanes_per_block=static.hpb * dn,
         flash_path="mla_multi_block", flash_causal_slabs=1,
-        flash_bwd_kernels=1 if one_bwd else 2)
-    if one_bwd:     # dq's accumulator holds the whole row in VMEM
-        tracing.note_trace(flash_bwd_resident_rows=t)
+        flash_bwd_resident_rows=t)      # dq's rows held in VMEM
     return static

@@ -30,16 +30,16 @@ def _rand_qkv(b=2, t=256, h=4, d=64, dtype=jnp.float32, seed=0):
 def test_forward_matches_dense():
     q, k, v = _rand_qkv()
     ref = jax.nn.dot_product_attention(q, k, v, is_causal=True)
-    out = flash_attention(q, k, v, causal=True, block_q=64,
-                          block_k=64, interpret=True)
+    out = flash_attention(q, k, v, causal=True, block=64,
+                          interpret=True)
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
 
 def test_forward_non_causal():
     q, k, v = _rand_qkv(t=128)
     ref = jax.nn.dot_product_attention(q, k, v, is_causal=False)
-    out = flash_attention(q, k, v, causal=False, block_q=64,
-                          block_k=64, interpret=True)
+    out = flash_attention(q, k, v, causal=False, block=64,
+                          interpret=True)
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
 
@@ -47,8 +47,8 @@ def test_gradients_match_dense():
     q, k, v = _rand_qkv(t=128)
 
     def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, causal=True, block_q=64,
-                                block_k=64, interpret=True) ** 2).sum()
+        return (flash_attention(q, k, v, causal=True, block=64,
+                                interpret=True) ** 2).sum()
 
     def loss_ref(q, k, v):
         return (jax.nn.dot_product_attention(
@@ -65,7 +65,7 @@ def _folded(q, k, v, *, causal, block):
     every shape took before the kernels indexed [B, T, H*D], and the
     one a shape that cannot be blocked on 128 lanes still takes."""
     b, t, h, d = q.shape
-    static = fa._Static(d ** -0.5, causal, block, block, d, hpb=1,
+    static = fa._Static(d ** -0.5, causal, block, d, hpb=1,
                         interpret=True)
     out = fa._flash_core(*(x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
                            for x in (q, k, v)), static)
@@ -100,12 +100,10 @@ def the_square(monkeypatch):
 
 
 def _backward_notes(t, block):
-    """What a row of several equal blocks adds to the notes: its
-    backward is one kernel with dq's ``t`` rows resident (every shape
-    here fits the budget); a row of one block says nothing."""
-    if t == block:
-        return {}
-    return {"flash_bwd_kernels": 1, "flash_bwd_resident_rows": t}
+    """What a row of several blocks adds to the notes: its backward
+    kernel holds dq's ``t`` rows resident (every shape here fits the
+    budget); a row of one block says nothing."""
+    return {} if t == block else {"flash_bwd_resident_rows": t}
 
 
 @pytest.mark.parametrize("causal", [True, False],
@@ -129,8 +127,8 @@ def test_direct_layout_matches_dense_and_folded(h, d, t, block, causal,
     def direct():
         return _out_and_grads(
             lambda q, k, v: flash_attention(
-                q, k, v, causal=causal, block_q=block, block_k=block,
-                interpret=True), q, k, v)
+                q, k, v, causal=causal, block=block, interpret=True),
+            q, k, v)
     tracing.take_trace_notes()
     got = direct()
     assert tracing.take_trace_notes() == {
@@ -168,8 +166,8 @@ def test_slabs_leave_the_rows_log_sum_exp_the_squares(h, d, the_square):
     assert fa._causal_slabs(t, True) == 3
 
     def run():
-        return fa._flash_fwd(*args, scale=d ** -0.5, causal=True, bq=t,
-                             bk=t, d=d, hpb=hpb, interpret=True)[1]
+        return fa._flash_fwd(*args, scale=d ** -0.5, causal=True, blk=t,
+                             d=d, hpb=hpb, interpret=True)[1]
     lse = run()
     assert float(jnp.abs(lse - the_square(run)).max()) < 2e-6
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
@@ -216,19 +214,10 @@ def test_shapes_off_the_lane_tiles_take_the_fold_and_say_so(h, d, lanes):
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
 
-def test_uneven_block_sizes():
-    q, k, v = _rand_qkv(t=256)
-    ref = jax.nn.dot_product_attention(q, k, v, is_causal=True)
-    out = flash_attention(q, k, v, causal=True, block_q=128,
-                          block_k=64, interpret=True)
-    assert float(jnp.abs(out - ref).max()) < 2e-5
-
-
 def test_rejects_non_blockable_seq():
     q, k, v = _rand_qkv(t=100)
     with pytest.raises(ValueError, match="not divisible"):
-        flash_attention(q, k, v, block_q=64, block_k=64,
-                        interpret=True)
+        flash_attention(q, k, v, block=64, interpret=True)
 
 
 def test_shapes_ok_helper():
@@ -320,112 +309,172 @@ def _grads(attn, q, k, v):
 @pytest.mark.parametrize("blocks", [2, 3, 4])
 @pytest.mark.parametrize("h,d", [(1, 128), (2, 64)],
                          ids=["one_head_a_block", "two_heads_a_block"])
-def test_one_kernel_backward_is_the_dense_one_and_the_pairs_bit_for_bit(
-        h, d, blocks, causal, notes, monkeypatch):
-    """``_bwd_kernel``: dq, dk, dv of a row of 2, 3 and 4 equal blocks
-    against the dense softmax's, and against the dq + dk/dv pair's
-    (reached by taking the budget away) to the bit: the same sums in the
-    same order. ``dq`` rides in scratch across the key blocks: not causal
-    it leaves in the last key block's cells, causal at its diagonal."""
+def test_multi_block_backward_is_the_dense_softmaxs(h, d, blocks, causal,
+                                                    notes):
+    """``_bwd_kernel``: dq, dk, dv of a row of 2, 3 and 4 blocks against
+    the dense softmax's. ``dq`` rides in scratch across the key blocks:
+    not causal it leaves in the last key block's cells, causal at its
+    diagonal."""
     q, k, v = _rand_qkv(b=2, t=64 * blocks, h=h, d=d, seed=7)
-
-    def flash(q, k, v):
-        return flash_attention(q, k, v, causal=causal, block_q=64,
-                               block_k=64, interpret=True)
-    one = _grads(flash, q, k, v)
-    assert notes["flash_bwd_kernels"] == 1
+    got = _grads(lambda *a: flash_attention(
+        *a, causal=causal, block=64, interpret=True), q, k, v)
+    assert notes["flash_path"] == "multi_block"
     assert notes["flash_bwd_resident_rows"] == 64 * blocks
     dense = _grads(lambda *a: _dense(*a, causal), q, k, v)
-    for name, a, b in zip(("dq", "dk", "dv"), one, dense):
+    for name, a, b in zip(("dq", "dk", "dv"), got, dense):
         assert float(jnp.abs(a - b).max()) < 5e-5, name
-    notes.clear()
-    monkeypatch.setattr(fa, "_BWD_VMEM", 1 << 10)
-    pair = _grads(flash, q, k, v)
-    assert notes["flash_bwd_kernels"] == 2
-    assert "flash_bwd_resident_rows" not in notes
-    for name, a, b in zip(("dq", "dk", "dv"), one, pair):
+
+
+@pytest.mark.parametrize("window", [None, 70], ids=["causal", "window"])
+def test_the_backward_kernels_gradients_are_the_same_run_to_run(window):
+    """What ``correct`` leans on: the sums into ``dq``, ``dk`` and ``dv``
+    run in the grid's order, so two compiles of one program give the
+    same bits (three blocks, two rows of the batch, two heads a lane
+    block; under a window the band's cells alone)."""
+    q, k, v = _rand_qkv(b=2, t=192, h=2, d=64, seed=13)
+
+    def run():
+        jax.clear_caches()
+        return _grads(lambda *a: flash_attention(
+            *a, block=64, window=window, interpret=True), q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), run(), run()):
         assert bool((a == b).all()), name
 
 
 MiB = 1 << 20
+HELD = 30_998_528   # _bwd_bytes(16384, 1024, 128): 544 a row + 22.1 MB a cell
 
 
 @pytest.mark.parametrize(
-    "t, block_q, block_k, d, budget, kernels", [
-        (16384, None, None, 128, None, 1),   # the SmallThinker cell's row
-        (8192, None, None, 128, None, 1),    # ZAYA's and Nemotron's
-        (4096, None, None, 128, None, 1),    # OLMoE's
-        (65536, None, None, 128, None, 1),   # 34 MiB of dq
-        (131072, None, None, 128, None, 2),  # 68: the rows decide
-        (16384, None, None, 128, 24 * MiB, 2),   # the budget decides
-        (16384, 512, 512, 128, 24 * MiB, 1),     # the blocks decide
-        (4096, None, None, 64, None, 1),     # two heads a lane block
-        (4096, None, None, 32, None, 1),     # folded: 32 lanes pad to 128
-        (2048, 1024, 512, 128, None, 2),     # uneven blocks: the pair
-        (2048, 512, 1024, 128, None, 2),
-        (2048, 2048, 1024, 128, None, 2),    # one q-block, two key blocks
-        (1024, None, None, 128, None, None),     # one block: the fused body
+    "t, block, d, window, budget, fits", [
+        (16384, None, 128, None, None, True),   # the SmallThinker cell's row
+        (16384, None, 128, 4096, None, True),   # and its windowed layers'
+        (8192, None, 128, None, None, True),    # ZAYA's and Nemotron's
+        (4096, None, 128, None, None, True),    # OLMoE's
+        (65536, None, 128, None, None, True),   # 34 MiB of dq
+        (131072, None, 128, None, None, False),     # 68: the rows decide
+        (131072, None, 128, 4096, None, False),     # a window holds as much
+        (131072, None, 64, None, None, False),      # two heads a lane block
+        (131072, None, 32, None, None, False),  # folded: 32 lanes pad to 128
+        (16384, None, 128, None, 24 * MiB, False),  # the budget decides
+        (16384, 512, 128, None, 24 * MiB, True),    # the blocks decide
+        (16384, None, 128, None, HELD, True),       # to the byte
+        (16384, None, 128, None, HELD - 1, False),
+        (4096, None, 64, None, None, True),     # two heads a lane block
+        (4096, None, 32, None, None, True),     # folded
+        (1024, None, 128, None, 0, None),   # one block: the fused body
     ], ids=lambda v: str(v))
-def test_the_backward_path_is_decided_from_rows_blocks_and_the_budget(
-        t, block_q, block_k, d, budget, kernels, notes, monkeypatch):
-    """Equal blocks whose resident rows fit ``_BWD_VMEM`` take the one
-    kernel; uneven blocks and rows past the budget the pair. The
-    decision is in the notes and in the ``_Static`` that the jitted
-    functions are specialised on, and ``flash_attention`` takes no
-    argument that could choose. Traced only: no kernel runs."""
-    import inspect
+def test_a_row_past_the_backward_kernels_budget_is_refused_by_name(
+        t, block, d, window, budget, fits, notes, monkeypatch):
+    """A row of several blocks whose resident rows fit ``_BWD_VMEM``
+    takes ``_bwd_kernel`` and says how many rows it holds; one past it
+    raises ``NotImplementedError`` with the rows, the bytes and the
+    budget, before anything is noted or handed to the jitted functions
+    (a ``ValueError`` would send a caller to the dense path); a row of
+    one block never meets the budget. Traced only: no kernel runs."""
+    assert fa._bwd_bytes(16384, 1024, 128) == HELD
     handed = []
     monkeypatch.setattr(fa, "_flash_core",
                         lambda q, k, v, static: handed.append(static) or q)
     if budget is not None:
         monkeypatch.setattr(fa, "_BWD_VMEM", budget)
     x = jax.ShapeDtypeStruct((1, t, 2, d), jnp.bfloat16)
-    jax.eval_shape(lambda q, k, v: flash_attention(
-        q, k, v, block_q=block_q, block_k=block_k), x, x, x)
+
+    def trace():
+        jax.eval_shape(lambda q, k, v: flash_attention(
+            q, k, v, block=block, window=window), x, x, x)
+    if fits is False:
+        asked = fa._bwd_bytes(t, block or 1024, d)
+        with pytest.raises(NotImplementedError) as refused:
+            trace()
+        said = str(refused.value)
+        lanes = 32 if d == 32 else 128      # a folded head's own lanes
+        for part in (f"{t} rows", f"{lanes} lanes", f"{asked} bytes",
+                     f"budget of {fa._BWD_VMEM}", "`sp` mesh axis",
+                     "ROADMAP C4"):
+            assert part in said, (part, said)
+        assert not handed and not notes
+        return
+    trace()
     [static] = handed
-    assert static.one_bwd == (kernels == 1)
-    assert notes.get("flash_bwd_kernels") == kernels
-    assert notes.get("flash_bwd_resident_rows") == (
-        t if kernels == 1 else None)
+    assert static.blk == (block or 1024) == static.bk
+    assert notes.get("flash_bwd_resident_rows") == (t if fits else None)
     assert notes["flash_path"] == (
-        "multi_block" if kernels else "single_block")
+        "multi_block" if fits else "single_block")
+
+
+def test_nothing_in_a_signature_chooses_a_backward():
+    """One block size and no switch: the shapes decide, and what they
+    cannot hold is refused."""
+    import inspect
     assert list(inspect.signature(flash_attention).parameters) == [
-        "q", "k", "v", "causal", "scale", "block_q", "block_k",
-        "interpret", "window"]
+        "q", "k", "v", "causal", "scale", "block", "interpret", "window"]
+    assert list(inspect.signature(fa.mla_flash_static).parameters) == [
+        "t", "dn", "dr", "scale", "block", "interpret"]
+    assert fa._Static._fields == (
+        "scale", "causal", "blk", "d", "hpb", "interpret", "window")
+    assert fa._MlaStatic._fields == (
+        "scale", "block", "dn", "dr", "interpret")
 
 
-@pytest.mark.parametrize("block_q, block_k", [(128, 64), (64, 128)],
-                         ids=["q128_k64", "q64_k128"])
-def test_uneven_blocks_still_take_the_pair_and_still_match(
-        block_q, block_k, notes):
-    q, k, v = _rand_qkv(t=256, seed=9)
-    got = _grads(lambda *a: flash_attention(
-        *a, block_q=block_q, block_k=block_k, interpret=True), q, k, v)
-    assert notes["flash_bwd_kernels"] == 2
-    want = _grads(lambda *a: _dense(*a, True), q, k, v)
-    for name, a, b in zip(("dq", "dk", "dv"), got, want):
-        assert float(jnp.abs(a - b).max()) < 5e-5, name
+@pytest.mark.parametrize("window", [None, 4096], ids=["causal", "window"])
+@pytest.mark.parametrize("sharded", [False, True], ids=["bare", "dp_mesh"])
+def test_causal_attention_surfaces_the_refusal_and_takes_no_dense_path(
+        sharded, window, monkeypatch):
+    """The dispatch above the kernel: where the kernel is eligible and
+    the row is past its budget, ``causal_attention`` (bare, and as
+    ``make_sharded_causal_attention`` maps it over ``dp``) raises the
+    kernel's refusal; XLA's dense attention, whose ``[T, T]`` scores at
+    131,072 rows are 69 GB a head, is never asked."""
+    from jax.sharding import Mesh
+    from ray_tpu.ops import attention
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def dense(*a, **kw):
+        raise AssertionError("the dense path was taken")
+    monkeypatch.setattr(jax.nn, "dot_product_attention", dense)
+    if sharded:
+        attn = attention.make_sharded_causal_attention(
+            Mesh(jax.devices()[:2], ("dp",)), window=window)
+    else:
+        def attn(q, k, v):
+            return causal_attention(q, k, v, force_flash=True,
+                                    window=window)
+    x = jax.ShapeDtypeStruct((2, 131072, 2, 128), jnp.bfloat16)
+    with pytest.raises(NotImplementedError, match="131072 rows"):
+        jax.eval_shape(attn, x, x, x)
 
 
-@pytest.mark.parametrize("kernels", [1, 2], ids=["one_kernel", "the_pair"])
-def test_both_backward_paths_under_a_shard_map_over_dp(
-        kernels, notes, monkeypatch):
+def test_the_backward_kernel_under_a_shard_map_over_dp(notes):
     """Each chip's own rows of the batch through the kernels (what
     ``make_sharded_causal_attention`` does on a dp mesh): the gradients
     of the sharded call are those of the whole batch in one call."""
     from jax.sharding import Mesh, PartitionSpec as P
-    if kernels == 2:
-        monkeypatch.setattr(fa, "_BWD_VMEM", 1 << 10)
     q, k, v = _rand_qkv(b=4, t=192, h=2, d=64, seed=11)
 
     def flash(q, k, v):
-        return flash_attention(q, k, v, block_q=64, block_k=64,
-                               interpret=True)
+        return flash_attention(q, k, v, block=64, interpret=True)
     mesh = Mesh(jax.devices()[:4], ("dp",))
     sharded = jax.shard_map(flash, mesh=mesh, in_specs=P("dp"),
                             out_specs=P("dp"), check_vma=False)
     got = _grads(sharded, q, k, v)
-    assert notes["flash_bwd_kernels"] == kernels
+    assert notes["flash_bwd_resident_rows"] == 192
     for name, a, b in zip(("dq", "dk", "dv"), got,
                           _grads(flash, q, k, v)):
         assert float(jnp.abs(a - b).max()) < 1e-6, name
+
+
+def test_the_benchmarks_fault_tool_reaches_the_backward_by_static(notes):
+    """``benchmark/tools/smallthinker_limit.py`` plants a fault by calling
+    ``_flash_bwd(*res, g, **static._asdict())`` with the window a key
+    block (``static.bk``) wider: the names it reads stay, and the wider
+    band moves the gradients."""
+    q, k, v = _rand_qkv(b=1, t=256, h=1, d=128, seed=17)
+    static = fa._Static(128 ** -0.5, True, 64, 128, 1, True, window=70)
+    x = [a.reshape(1, 256, 128) for a in (q, k, v)]
+    out, lse = fa._flash_fwd(*x, **static._asdict())
+    wider = static._replace(window=static.window + static.bk)
+    right = fa._flash_bwd(*x, out, lse, out, **static._asdict())
+    wrong = fa._flash_bwd(*x, out, lse, out, **wider._asdict())
+    assert wider.window == 134
+    assert float(jnp.abs(right[0] - wrong[0]).max()) > 1e-3

@@ -64,8 +64,8 @@ def test_windowed_kernels_are_the_dense_masked_softmax(window, h, d, t,
     q, k, v, g = _qkvg(t, h, d)
 
     def flash(q, k, v):
-        return fa.flash_attention(q, k, v, window=window, block_q=block,
-                                  block_k=block, interpret=True)
+        return fa.flash_attention(q, k, v, window=window, block=block,
+                                  interpret=True)
     got = _out_and_grads(flash, q, k, v, g)
     want = _out_and_grads(lambda *a: _dense(*a, window), q, k, v, g)
     for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
@@ -76,7 +76,7 @@ def test_windowed_kernels_are_the_dense_masked_softmax(window, h, d, t,
         assert notes["flash_window"] == "none"
         causal = _out_and_grads(
             lambda q, k, v: fa.flash_attention(
-                q, k, v, block_q=block, block_k=block, interpret=True),
+                q, k, v, block=block, interpret=True),
             q, k, v, g)
         for a, b in zip(got, causal):
             np.testing.assert_array_equal(a, b)
@@ -91,36 +91,28 @@ def test_windowed_kernels_are_the_dense_masked_softmax(window, h, d, t,
                          ids=["itself", "under_a_block", "a_block",
                               "a_block_and_one", "several_blocks",
                               "the_whole_row"])
-def test_one_kernel_backward_under_a_window_is_the_pairs_bit_for_bit(
-        window, h, d, blocks, notes, monkeypatch):
-    """The one-kernel backward where a q-block's first live key block is
-    no longer block 0 and a key block's live q-blocks end: dq, dk, dv
-    against the dense masked softmax's and, to the bit, against the dq +
-    dk/dv pair's (reached by taking the budget away)."""
+def test_multi_block_backward_under_a_window_is_the_dense_masked_softmaxs(
+        window, h, d, blocks, notes):
+    """The backward kernel where a q-block's first live key block is no
+    longer block 0 and a key block's live q-blocks end: dq, dk, dv
+    against the dense masked softmax's."""
     q, k, v, g = _qkvg(64 * blocks, h, d, seed=3)
 
     def grads(fn):
         return _out_and_grads(fn, q, k, v, g)[1:]
 
-    def flash(q, k, v):
-        return fa.flash_attention(q, k, v, window=window, block_q=64,
-                                  block_k=64, interpret=True)
-    one = grads(flash)
-    assert notes["flash_bwd_kernels"] == 1
+    got = grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, window=window, block=64, interpret=True))
+    assert notes["flash_path"] == "multi_block"
     assert notes["flash_bwd_resident_rows"] == 64 * blocks
-    for name, a, b in zip(("dq", "dk", "dv"), one,
+    for name, a, b in zip(("dq", "dk", "dv"), got,
                           grads(lambda *a: _dense(*a, window))):
         np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
-    monkeypatch.setattr(fa, "_BWD_VMEM", 1 << 10)
-    pair = grads(flash)
-    assert notes["flash_bwd_kernels"] == 2
-    for name, a, b in zip(("dq", "dk", "dv"), one, pair):
-        np.testing.assert_array_equal(a, b, err_msg=name)
 
 
 def test_a_call_without_a_window_leaves_the_notes_it_left(notes):
     q, k, v, _ = _qkvg(128, 1, 128)
-    fa.flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
+    fa.flash_attention(q, k, v, block=64, interpret=True)
     assert notes["flash_path"] == "multi_block"
     assert "flash_window" not in notes and "flash_band_blocks" not in notes
 
@@ -133,19 +125,19 @@ def test_a_call_without_a_window_leaves_the_notes_it_left(notes):
     (256, 64, 66, 3, 9), (256, 64, 1, 1, 4), (256, 64, 150, 4, 10)])
 def test_blocks_below_the_band_are_in_no_grid_cell(t, block, window, cells,
                                                    walked):
-    """``_band``: the innermost grid dimension is the band's cells (the
-    most any outer block needs) in the forward and dq kernels (key
-    cells) and in the one-kernel backward and the dk/dv kernel (query
-    cells), and the block pairs a head walks are the live ones alone;
-    against a count from the definition. Then the one-kernel backward's
-    grid walked as the chip walks it (``_bwd_blocks``): every live pair
-    met once, key blocks ascending for a q-block; a dead cell on the
+    """``_band``: the innermost grid dimension is the band's cells, the
+    most live key blocks any q-block has (the forward's key cells) and,
+    blocks being square, the most live q-blocks any key block has (the
+    backward's query cells), and the block pairs a head walks are the
+    live ones alone; against a count from the definition. Then the
+    backward kernel's grid walked as the chip walks it (``_bwd_blocks``):
+    every live pair met once, key blocks ascending for a q-block; a dead cell on the
     blocks of the live cell next to it; ``o`` fetched once a q-block, in the
     cell that meets its first live key block; ``dq``'s block that of the
     q-block whose last live key block the cell is, its index never
     going back."""
-    key_cells, query_cells, pairs = fa._band(t, block, block, window)
-    assert (key_cells, query_cells, pairs) == (cells, cells, walked)
+    band_cells, pairs = fa._band(t, block, window)
+    assert (band_cells, pairs) == (cells, walked)
     n = t // block
     live = np.zeros((n, n), bool)
     w = t if window is None else window
@@ -156,27 +148,25 @@ def test_blocks_below_the_band_are_in_no_grid_cell(t, block, window, cells,
             live[i, j] = ((cols <= rows) & (cols > rows - w)).any()
     assert pairs == live.sum()
     if window is not None:
-        assert key_cells == live.sum(1).max()
-        assert query_cells == live.sum(0).max()
+        assert band_cells == live.sum(1).max() == live.sum(0).max()
         for i in range(n):      # the index maps: first + cell, clamped
-            first, last = (int(x) for x in fa._keys_of(i, block, block,
-                                                       window))
+            first, last = (int(x) for x in fa._keys_of(i, block, window))
             assert list(np.flatnonzero(live[i])) == list(
                 range(first, last + 1))
         for j in range(n):
-            first, last = (int(x) for x in fa._queries_of(j, block, block,
+            first, last = (int(x) for x in fa._queries_of(j, block,
                                                           window, n))
             assert list(np.flatnonzero(live[:, j])) == list(
                 range(first, last + 1))
     grid_cells, q_block, o_block, dq_block = fa._bwd_blocks(
         n, block, True, window)
-    assert grid_cells == query_cells
+    assert grid_cells == band_cells
     met, o_fetched, dq_at = [], [], []
     for j in range(n):
         cells_of_j = []
         for i in range(grid_cells):
-            qb, is_live = fa._query_cell(j, i, bq=block, bk=block,
-                                         causal=True, window=window, nq=n)
+            qb, is_live = fa._query_cell(j, i, blk=block, causal=True,
+                                         window=window, nb=n)
             at = (int(q_block(j, i)), int(o_block(j, i)),
                   int(dq_block(j, i)))
             cells_of_j.append((bool(is_live), at))
@@ -209,33 +199,28 @@ def test_the_windowed_grid_is_the_band_and_the_note_counts_it(notes):
     """The lowered forward call's grid: (batch, lane blocks, q-blocks,
     the band's cells), not the row's key blocks."""
     q, k, v, _ = _qkvg(256, 1, 128)
-    fa.flash_attention(q, k, v, window=70, block_q=64, block_k=64,
-                       interpret=True)
-    assert notes["flash_band_blocks"] == fa._band(256, 64, 64, 70)[2] == 9
+    fa.flash_attention(q, k, v, window=70, block=64, interpret=True)
+    assert notes["flash_band_blocks"] == fa._band(256, 64, 70)[1] == 9
     assert notes["flash_path"] == "multi_block"
     jaxpr = jax.make_jaxpr(lambda q, k, v: fa._flash_fwd(
-        q, k, v, scale=1.0, causal=True, bq=64, bk=64, d=128, hpb=1,
+        q, k, v, scale=1.0, causal=True, blk=64, d=128, hpb=1,
         interpret=False, window=70))(
             *(x.reshape(2, 256, 128) for x in (q, k, v)))
     grids = [e.params["jaxpr"].eqns[0].params["grid_mapping"].grid
              for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
     assert grids == [(2, 1, 4, 3)]
     # the backward: one call over (batch, lane blocks, key blocks, the
-    # band's q-cells) where the resident rows fit, else dq's grid over
-    # the band's key cells and dk/dv's over its q-cells
-    assert notes["flash_bwd_kernels"] == 1
+    # band's q-cells)
     assert notes["flash_bwd_resident_rows"] == 256
     x = q.reshape(2, 256, 128)
-    for one_bwd, want in ((True, [(2, 1, 4, 3)]),
-                          (False, [(2, 1, 4, 3), (2, 1, 4, 3)])):
-        jaxpr = jax.make_jaxpr(lambda q, k, v, o, lse: fa._flash_bwd(
-            q, k, v, o, lse, o, scale=1.0, causal=True, bq=64, bk=64,
-            d=128, hpb=1, interpret=False, window=70, one_bwd=one_bwd))(
-                x, x, x, x, jnp.zeros((2, 1, 4, 1, 64), jnp.float32))
-        [call] = [e for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
-        assert [e.params["grid_mapping"].grid
-                for e in call.params["jaxpr"].eqns
-                if e.primitive.name == "pallas_call"] == want
+    jaxpr = jax.make_jaxpr(lambda q, k, v, o, lse: fa._flash_bwd(
+        q, k, v, o, lse, o, scale=1.0, causal=True, blk=64, d=128, hpb=1,
+        interpret=False, window=70))(
+            x, x, x, x, jnp.zeros((2, 1, 4, 1, 64), jnp.float32))
+    [call] = [e for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
+    assert [e.params["grid_mapping"].grid
+            for e in call.params["jaxpr"].eqns
+            if e.primitive.name == "pallas_call"] == [(2, 1, 4, 3)]
 
 
 def test_a_window_needs_a_causal_row():

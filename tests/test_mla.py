@@ -5,7 +5,6 @@ the CPU, against a masked softmax over the concatenated keys; and
 latents and run the up-projections again."""
 
 import importlib
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -60,14 +59,6 @@ def _operands(seed=0, t=T, h=H):
             jax.random.normal(ks[5], (B, t, h, DN)))
 
 
-@pytest.fixture
-def two_kernels(monkeypatch):
-    """No row fits: the backward pass is the dq and the dk/dv kernel.
-    The limit is the module's, not an argument: nothing selects a path
-    but the shapes."""
-    monkeypatch.setattr(fa, "_MLA_BWD_VMEM", 1 << 20)
-
-
 @pytest.mark.parametrize("block", [128, 384], ids=["three_blocks", "one"])
 def test_kernel_forward_is_the_masked_softmax_over_concatenated_keys(block):
     ops, _ = _operands()
@@ -83,81 +74,75 @@ def _gradient(fn, ops, w, arg):
     return jax.grad(lambda *a: (fn(*a) * w).sum(), argnums=arg)(*ops)
 
 
-def _holds_to_the_reference(name, arg, block, kernels):
+@pytest.mark.parametrize("block", [384, 192, 128],
+                         ids=["one_block", "two_blocks", "three_blocks"])
+@pytest.mark.parametrize("name, arg", FIVE)
+def test_kernel_backward_gives_each_of_the_five_gradients(name, arg, block):
+    """The backward kernel: where only the diagonal block is live (one
+    block), where one block lies under it, and three blocks. ``dq`` is
+    carried in scratch across the key blocks and leaves at its diagonal;
+    ``dk_rope`` is the sum over the heads and, in the kernel, over the
+    head pairs: carried across the grid's second dimension."""
     ops, w = _operands(1)
     got = _gradient(lambda *a: _kernels(*a, block=block), ops, w, arg)
     want = _gradient(_concatenated, ops, w, arg)
-    assert mla_flash_static(T, DN, DR, block=block).one_bwd == (kernels == 1)
     assert got.shape == ops[arg].shape
     np.testing.assert_allclose(got, want, atol=3e-5 * float(
         jnp.abs(want).max()) + 1e-6, err_msg=name)
 
 
-@pytest.mark.parametrize("block", [384, 192, 128],
-                         ids=["one_block", "two_blocks", "three_blocks"])
-@pytest.mark.parametrize("name, arg", FIVE)
-def test_kernel_backward_gives_each_of_the_five_gradients(name, arg, block):
-    """The one-kernel backward pass: where only the diagonal block is
-    live (one block), where one block lies under it, and three blocks.
-    ``dq`` is carried in scratch across the key blocks and leaves at its
-    diagonal; ``dk_rope`` is the sum over the heads and, in the kernel,
-    over the head pairs: carried across the grid's second dimension."""
-    _holds_to_the_reference(name, arg, block, kernels=1)
-
-
-@pytest.mark.parametrize("name, arg", FIVE)
-def test_two_kernel_backward_gives_each_of_the_five_gradients(
-        name, arg, two_kernels):
-    """The path of rows too long for the one kernel's accumulators."""
-    _holds_to_the_reference(name, arg, 128, kernels=2)
-
-
-@pytest.mark.parametrize("name, arg", FIVE)
-def test_one_kernel_gives_the_two_kernels_gradients_bit_for_bit(
-        name, arg, monkeypatch):
-    """The same arithmetic in the same order: eight heads, so ``dk_r``'s
-    sum runs over four head pairs, two rows of the batch, two blocks."""
+def test_the_backward_kernels_gradients_are_the_same_run_to_run():
+    """What ``correct`` leans on: every sum runs in the grid's order
+    (key blocks into ``dq``, q-blocks into ``dk_n`` / ``dv``, then the
+    head pairs into ``dk_r``), so two compiles of one program give the
+    same bits: eight heads (four head pairs), two rows of the batch,
+    two blocks."""
     ops, w = _operands(5, t=256, h=8)
 
-    def through():
-        return _gradient(lambda *a: _kernels(*a, block=128), ops, w, arg)
-
-    one = through()
-    monkeypatch.setattr(fa, "_MLA_BWD_VMEM", 1 << 20)
-    assert not mla_flash_static(256, DN, DR, block=128).one_bwd
-    np.testing.assert_array_equal(one, through(), err_msg=name)
+    def run():
+        jax.clear_caches()
+        return jax.grad(lambda *a: (_kernels(*a, block=128) * w).sum(),
+                        argnums=(0, 1, 2, 3, 4))(*ops)
+    for (name, _), a, b in zip(FIVE, run(), run()):
+        np.testing.assert_array_equal(a, b, err_msg=name)
 
 
 MiB = 1 << 20
 
 
-@pytest.mark.parametrize("t, dn, dr, limit, kernels", [
-    (8192, 128, 64, None, 1),           # the cell
-    (32768, 128, 64, None, 1),          # 64 MiB of accumulators
-    (65536, 128, 64, None, 2),
-    (8192, 128, 64, 64 * MiB, 1),
-    (32768, 128, 64, 64 * MiB, 2),      # the limit decides
-    (16384, 256, 64, None, 1),
-    (32768, 256, 64, None, 2),          # the widths decide
-    (384, 128, 64, 1 * MiB, 2),
+@pytest.mark.parametrize("t, dn, dr, limit, fits", [
+    (8192, 128, 64, None, True),            # the cell
+    (32768, 128, 64, None, True),           # 64 MiB of accumulators
+    (65536, 128, 64, None, False),
+    (8192, 128, 64, 64 * MiB, True),
+    (32768, 128, 64, 64 * MiB, False),      # the limit decides
+    (16384, 256, 64, None, True),
+    (32768, 256, 64, None, False),          # the widths decide
+    (384, 128, 64, 1 * MiB, False),
 ], ids=lambda v: str(v))
-def test_the_backward_path_is_decided_from_rows_widths_and_the_limit(
-        t, dn, dr, limit, kernels, monkeypatch):
-    """``mla_flash_static`` notes how many kernels the backward pass is
-    and, where it is one, how many rows of ``dq`` stay in VMEM; it takes
-    no argument that could choose."""
+def test_a_row_past_the_backward_kernels_budget_is_refused_by_name(
+        t, dn, dr, limit, fits, monkeypatch):
+    """``mla_flash_static`` notes how many rows of ``dq`` the backward
+    kernel keeps in VMEM; where they do not fit ``_MLA_BWD_VMEM`` it
+    raises ``NotImplementedError`` with the rows, the lanes of a head
+    pair, the bytes and the budget, and notes nothing."""
     notes = {}
     monkeypatch.setattr(tracing, "note_trace", notes.update)
     if limit is not None:
         monkeypatch.setattr(fa, "_MLA_BWD_VMEM", limit)
-    static = mla_flash_static(t, dn, dr)
-    assert static.one_bwd == (kernels == 1)
-    assert notes["flash_bwd_kernels"] == kernels
-    assert notes.get("flash_bwd_resident_rows") == (
-        t if kernels == 1 else None)
+    if not fits:
+        asked = fa._mla_bwd_bytes(t, fa._pick_block(t), dn, dr)
+        with pytest.raises(NotImplementedError) as refused:
+            mla_flash_static(t, dn, dr)
+        for part in (f"{t} rows", f"{2 * (dn + dr)} lanes",
+                     f"{asked} bytes", f"budget of {fa._MLA_BWD_VMEM}",
+                     "`sp` mesh axis"):
+            assert part in str(refused.value), part
+        assert not notes
+        return
+    assert mla_flash_static(t, dn, dr).block == fa._pick_block(t)
+    assert notes["flash_bwd_resident_rows"] == t
     assert notes["flash_path"] == "mla_multi_block"
-    assert list(inspect.signature(mla_flash_static).parameters) == [
-        "t", "dn", "dr", "scale", "block", "interpret"]
 
 
 def test_the_shapes_the_kernels_tile():
@@ -270,14 +255,9 @@ def test_the_path_is_decided_from_backend_shapes_and_mesh(monkeypatch):
             mla.mla_path(*cell, mesh=mesh)
 
 
-@pytest.mark.parametrize("limit", [None, 1 << 20],
-                         ids=["one_backward_kernel", "two"])
-def test_the_kernels_under_a_shard_map_over_dp_are_the_bare_ones(
-        limit, monkeypatch):
+def test_the_kernels_under_a_shard_map_over_dp_are_the_bare_ones():
     """Two rows over ``dp`` = 2, kernels interpreted on each device's
     row, against the unsharded call: values and gradients."""
-    if limit is not None:
-        monkeypatch.setattr(fa, "_MLA_BWD_VMEM", limit)
     mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
     c_q, c_kv, k_r, up, w = _latents(3)
     angles = rope_freqs(DR, 256, 10000.0)
@@ -298,3 +278,37 @@ def test_the_kernels_under_a_shard_map_over_dp_are_the_bare_ones(
     for g, x in zip(jax.tree_util.tree_leaves(gots),
                     jax.tree_util.tree_leaves(wants)):
         np.testing.assert_allclose(g, x, atol=1e-4 * float(jnp.abs(x).max()))
+
+
+@pytest.mark.parametrize("mesh_axes", [None, {"dp": 2}],
+                         ids=["one_device", "dp2"])
+@pytest.mark.parametrize("backend, refused", [("tpu", True), ("cpu", False)],
+                         ids=["kernel_path", "xla_path_off_the_tpu"])
+def test_latent_attention_refuses_a_row_past_the_budget_on_the_kernel_path(
+        backend, refused, mesh_axes, monkeypatch):
+    """65,536 rows at the published widths, traced only. Where the
+    kernels would run (a TPU, shapes that tile; one device or a batch
+    over ``dp``) ``latent_attention`` raises the kernel's refusal before
+    anything is traced; off the TPU the XLA path knows no budget and is
+    as it was."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    n = 2 if mesh_axes else 1
+    mesh = make_mesh(mesh_axes or {"dp": 1}, devices=jax.devices()[:n])
+    t, h = 65536, 2
+    s = jax.ShapeDtypeStruct
+    up = mla.UpProjections(
+        s((RQ, h * DN), jnp.bfloat16), s((RQ, h * DR), jnp.bfloat16),
+        s((RKV, h * DN), jnp.bfloat16), s((RKV, h * DN), jnp.bfloat16))
+    angles = rope_freqs(DR, t, 10000.0)
+
+    def attend(c_q, c_kv, k_r, up):
+        return mla.latent_attention(c_q, c_kv, k_r, up, angles, n_head=h,
+                                    mesh=mesh)
+    args = (s((n, t, RQ), jnp.bfloat16), s((n, t, RKV), jnp.bfloat16),
+            s((n, t, DR), jnp.bfloat16), up)
+    if refused:
+        with pytest.raises(NotImplementedError, match="65536 rows"):
+            jax.eval_shape(attend, *args)
+    else:
+        out = jax.eval_shape(attend, *args)
+        assert out.shape == (n, t, h * DN)

@@ -84,25 +84,28 @@ def test_causal_slabs_compile_for_v5e_off_the_cells_shapes(
 LOCATIONS = re.compile(r"loc\(.*?\)$|^#loc.*$", re.M)
 
 
-@pytest.mark.parametrize("kernels", [1, 2], ids=["one_kernel", "the_pair"])
-@pytest.mark.parametrize("shape, window", [
-    ((1, 16384, 28, 128), None), ((1, 16384, 28, 128), 4096),
-    ((2, 8192, 8, 128), None)],
-    ids=["smallthinker_global", "smallthinker_window", "zaya"])
+@pytest.mark.parametrize("shape, window, used_mib", [
+    ((1, 16384, 28, 128), None, (8, 24)),
+    ((1, 16384, 28, 128), 4096, (8, 24)),
+    ((2, 8192, 8, 128), None, (8, 24)),
+    ((1, 65536, 2, 128), None, (34, 64)),
+    ((1, 65536, 2, 128), 4096, (34, 64))],
+    ids=["smallthinker_global", "smallthinker_window", "zaya",
+         "longest_row_that_fits", "longest_row_under_a_window"])
 def test_the_multi_block_backward_compiles_as_one_kernel_in_its_vmem(
-        v5e, monkeypatch, shape, window, kernels):
+        v5e, monkeypatch, shape, window, used_mib):
     """The SmallThinker cell's two layers (28 heads of 128 over 16,384
-    rows, causal and under the window of 4,096) and ZAYA's (2 x 8,192
-    rows, 8 heads): forward and backward are **two** custom calls, the
-    backward one kernel whose resident ``dq`` (8.4 MB of float32 at
-    16,384 rows) fits the VMEM it asks for, ``_BWD_VMEM`` in the call's
-    configuration — the compile succeeding is the proof, the compiler
-    refusing a kernel that holds more than it was granted. With the
-    budget taken away the same shapes take the dq + dk/dv pair, three
-    calls, none of which asks for more than a kernel gets unasked."""
+    rows, causal and under the window of 4,096), ZAYA's (2 x 8,192
+    rows, 8 heads), and the longest power of two ``_bwd_fits`` accepts,
+    65,536 rows (34 MiB of ``dq``; no cell runs it): forward and
+    backward are **two** custom calls, the backward one kernel whose
+    resident ``dq`` (8.4 MB of float32 at 16,384 rows) fits the VMEM it
+    asks for, ``_BWD_VMEM`` in the call's configuration — the compile
+    succeeding is the proof, the compiler refusing a kernel that holds
+    more than it was granted. ``_bwd_fits`` counts more than the
+    compiler takes: held to what the compiler used, the same row is
+    refused."""
     fa = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
-    if kernels == 2:
-        monkeypatch.setattr(fa, "_BWD_VMEM", 0)
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                              sharding=SingleDeviceSharding(v5e[0]))
 
@@ -111,7 +114,7 @@ def test_the_multi_block_backward_compiles_as_one_kernel_in_its_vmem(
             jnp.float32).sum()
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 1 + kernels
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     calls = "\n".join(line for line in text.splitlines()
                       if 'custom_call_target="tpu_custom_call"' in line)
     granted, used = ([int(size) for size in re.findall(
@@ -119,23 +122,21 @@ def test_the_multi_block_backward_compiles_as_one_kernel_in_its_vmem(
         calls)] for key in ("scoped_memory_configs",
                             "used_scoped_memory_configs"))
     print(f"VMEM used, MiB: {[round(u / 2**20, 2) for u in used]}")
-    assert len(used) == 1 + kernels
-    if kernels == 2:    # the pair asks for nothing and stays a block's
-        assert granted == [] and max(used) < 16 << 20
-    else:   # ``_bwd_fits`` counts more than the compiler takes
-        assert max(granted) == 64 << 20
-        assert 8 << 20 < max(used) < 24 << 20
-        monkeypatch.setattr(fa, "_BWD_VMEM", max(used))
-        assert not fa._bwd_fits(shape[1], 1024, 128)
+    assert len(used) == 2 and max(granted) == 64 << 20
+    assert used_mib[0] << 20 < max(used) < used_mib[1] << 20
+    monkeypatch.setattr(fa, "_BWD_VMEM", max(used))
+    assert not fa._bwd_fits(shape[1], 1024, 128)
+    with pytest.raises(NotImplementedError, match=f"{shape[1]} rows"):
+        jax.eval_shape(loss, x, x, x)
     assert f"{shape[1]},{shape[1]}" not in text
 
 
 def test_a_row_of_one_block_lowers_as_it_did_whatever_the_budget(
         v5e, monkeypatch):
     """The GPT-2 cells' shape (one block a row: the fused single-block
-    backward) never meets the decision: its lowered text, locations
-    stripped, is the same with the one-kernel budget at its value and
-    at nothing, two custom calls, and neither asks for VMEM."""
+    backward) never meets the budget: its lowered text, locations
+    stripped, is the same with ``_BWD_VMEM`` at its value and at
+    nothing, two custom calls, and neither asks for VMEM."""
     fa = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
     x = jax.ShapeDtypeStruct(SHAPES[0], jnp.bfloat16,
                              sharding=SingleDeviceSharding(v5e[0]))

@@ -67,25 +67,28 @@ def test_latent_attention_kernels_compile_for_v5e_at_the_cells_shape(v5e):
     that its accumulators fit the VMEM limit it asks for), no ``[T, T]``
     array and no operand 256 wide (the rotary key is the one ``[1, 8192,
     64]`` array it is), temporaries a fraction of a GB."""
-    static, compiled, text = _compiled_kernels(v5e[0], 8192)
-    assert static.one_bwd
+    _, compiled, text = _compiled_kernels(v5e[0], 8192)
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "8192,8192" not in text
     assert "8192,32,256" not in text and "8192,32,192" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
-@pytest.mark.parametrize("t, heads, kernels", [(32768, 8, 1), (65536, 4, 2)],
+@pytest.mark.parametrize("t, heads, fits", [(32768, 8, True),
+                                            (65536, 4, False)],
                          ids=["the_longest_row_that_fits", "one_too_long"])
-def test_the_backward_path_compiles_where_its_accumulators_fit_and_not(
-        v5e, t, heads, kernels):
+def test_the_backward_kernel_compiles_where_its_accumulators_fit(
+        v5e, t, heads, fits):
     """``_mla_bwd_fits`` counts from the shapes; the compiler has the last
     word. 32,768 rows (64 MiB of resident accumulators under the 100 MiB
-    the kernel asks for) compile as one backward kernel; 65,536 take the
-    pair, which holds a block's rows whatever the row's length."""
-    static, _, text = _compiled_kernels(v5e[0], t, heads)
-    assert static.one_bwd == (kernels == 1)
-    assert text.count('custom_call_target="tpu_custom_call"') == 1 + kernels
+    the kernel asks for) compile, forward and backward two custom calls;
+    65,536 are refused by name before anything is lowered."""
+    if not fits:
+        with pytest.raises(NotImplementedError, match=f"{t} rows"):
+            _compiled_kernels(v5e[0], t, heads)
+        return
+    _, _, text = _compiled_kernels(v5e[0], t, heads)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert f"{t},{t}" not in text
 
 
@@ -125,7 +128,6 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert notes["flash_path"] == "mla_multi_block"
     assert notes["flash_layout"] == "bthd"
     assert notes["mla_saved"] == "latents"
-    assert notes["flash_bwd_kernels"] == 1
     assert notes["flash_bwd_resident_rows"] == 8192
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.temp_size_in_bytes

@@ -76,7 +76,6 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     compiled = step.lower(state, batch).compile()
     assert notes["flash_path"] == "multi_block"
     assert notes["flash_layout"] == "bthd"
-    assert notes["flash_bwd_kernels"] == 1
     assert notes["flash_bwd_resident_rows"] == 8192
     assert notes["cca_path"] == "pallas" and notes["attn_kind"] == "cca"
     assert notes["cca_halo_rows"] == 2

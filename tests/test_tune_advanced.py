@@ -120,7 +120,11 @@ def _pbt_trainable(config):
     while step < 16:
         step += 1
         score += config["lr"]
-        time.sleep(0.02)
+        # long enough that four trials overlap when their workers start
+        # a second apart (six xdist workers beside the real-size
+        # compiles): a trial that ends before a better one has reported
+        # finds nobody to exploit, and ``exploit_count`` stays 0
+        time.sleep(0.1)
         tmp = tempfile.mkdtemp()
         with open(os.path.join(tmp, "state.json"), "w") as f:
             json.dump({"step": step, "score": score}, f)
@@ -137,7 +141,9 @@ def test_pbt_end_to_end(rt):
         quantile_fraction=0.25, seed=0)
     tuner = Tuner(
         _pbt_trainable,
-        param_space={"lr": grid_search([0.1, 0.1, 1.0, 1.0])},
+        # one trial alone at the bottom: of two that tie there, the one
+        # that reports is the bottom quantile only if the other is ahead
+        param_space={"lr": grid_search([0.1, 0.2, 1.0, 1.0])},
         tune_config=TuneConfig(scheduler=pbt, metric="score",
                                mode="max", max_concurrent_trials=4),
         run_config=RunConfig(storage_path=storage, name="pbt"),
