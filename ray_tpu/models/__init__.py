@@ -20,13 +20,19 @@ window/global decoder (three windowed RoPE layers to one global layer
 without positions through ``causal_attention(window=...)``, a softmax
 router that reads the block's input before attention, ReGLU experts
 through ``routed_experts``, an untied head); its config expresses
-SmallThinker-21BA3B-Instruct. ``MoETransformer`` is the
+SmallThinker-21BA3B-Instruct. ``KimiLinear`` is the hybrid
+linear-attention decoder (three Kimi Delta Attention layers, a chunked
+gated delta rule through ``ops/kda.py``, to one latent-attention layer
+with no query latent and no positions through ``ops/mla.py``, JoyAI's
+routed layer behind a leading dense one); its config expresses
+Kimi-Linear-48B-A3B-Instruct. ``MoETransformer`` is the
 older top-1, capacity-dropping switch model
 on GPT-2 blocks, which goes when the dropless path runs under ``ep``
 (ROADMAP C5)."""
 
 from ray_tpu.models.gpt2 import GPT2, GPT2Config
 from ray_tpu.models.joyai import JoyAI, JoyAIConfig
+from ray_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
 from ray_tpu.models.llama import Llama, LlamaConfig
 from ray_tpu.models.moe import MoEConfig, MoETransformer
 from ray_tpu.models.nemotron_h import NemotronH, NemotronHConfig
@@ -36,7 +42,8 @@ from ray_tpu.models.vit import ViT, ViTConfig
 from ray_tpu.models.zaya import Zaya, ZayaConfig
 
 __all__ = [
-    "GPT2", "GPT2Config", "JoyAI", "JoyAIConfig", "Llama", "LlamaConfig",
+    "GPT2", "GPT2Config", "JoyAI", "JoyAIConfig", "KimiLinear",
+    "KimiLinearConfig", "Llama", "LlamaConfig",
     "MoETransformer", "MoEConfig", "NemotronH", "NemotronHConfig",
     "ResNet", "ResNet50Config", "SmallThinker", "SmallThinkerConfig", "ViT",
     "ViTConfig", "Zaya", "ZayaConfig",

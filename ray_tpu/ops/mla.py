@@ -19,6 +19,13 @@ The down projections and the output projection are the model's own
 dense layers (``models/joyai.py``); this file holds everything between
 the latents and ``concat_i(o_i)``: ``latent_attention``.
 
+Kimi-Linear's global layers (``models/kimi_linear.py``) are the same
+attention with **no query latent and no rotation**: ``q_nope | q_rope =
+h W_q`` straight from the block's normed input, which the caller hands
+as ``c_q`` (no ``q_down`` scope opens; ``q_up`` is all of ``W_q``), and
+``angles=None``: ``q_rope`` and ``k_r`` enter the scores as they are and
+no ``rope`` scope opens. Same kernels, same shapes a head.
+
 **The up-projections' columns.** ``W_qb`` and ``W_kvb`` are held as two
 arrays each, the heads' 128-wide parts side by side in one (``[rq,
 H*dn]``, ``[rkv, H*dn]``, ``[rkv, H*dv]``) and the heads' rotary parts
@@ -146,6 +153,16 @@ def latent_attention(c_q, c_kv, k_r, up: UpProjections, angles, *,
     ``k_r`` [B, T, dr], the up-projections and the rotation's
     ``angles`` [T, dr/2] (``models/llama.py::rope_freqs``).
 
+    **A caller without a query latent** (``q_lora_rank`` null:
+    ``models/kimi_linear.py``) hands the block's normed input as
+    ``c_q`` and its one query projection's columns as ``up.q_nope`` /
+    ``up.q_rope``: ``q_up`` is then the whole of ``W_q``, which the
+    backward pass runs again as it runs any ``q_up`` again, and the
+    caller opens no ``q_down``. **``angles=None``** is attention with
+    no positions (``mla_use_nope``): the 64 "rotary" lanes stay in both
+    score products unrotated, no ``rope`` scope opens, in either pass,
+    and the note ``mla_positions`` says ``none`` (``rope`` otherwise).
+
     ``saved``: what the backward pass keeps (module docstring). The
     model takes the default; ``"expanded"`` is what the tests hold its
     gradients against, and the note ``mla_saved`` says which ran.
@@ -158,7 +175,8 @@ def latent_attention(c_q, c_kv, k_r, up: UpProjections, angles, *,
 
     if saved not in ("latents", "expanded"):
         raise ValueError(f"saved={saved!r}: 'latents' or 'expanded'")
-    tracing.note_trace(mla_saved=saved)
+    tracing.note_trace(mla_saved=saved,
+                       mla_positions="none" if angles is None else "rope")
     b, t, _ = c_q.shape
     dt = c_q.dtype
     dr = k_r.shape[-1]
@@ -172,6 +190,8 @@ def latent_attention(c_q, c_kv, k_r, up: UpProjections, angles, *,
             qn, qr = c_q @ q_nope_w.astype(dt), c_q @ q_rope_w.astype(dt)
         with jax.named_scope("kv_up"):
             kn, v = c_kv @ k_nope_w.astype(dt), c_kv @ v_w.astype(dt)
+        if angles is None:      # no positions: the lanes stay as they are
+            return qn, qr, kn, k_r, v
         with jax.named_scope("rope"):
             # in float32: apply_rope rounds the cosines to its operand's
             # type, and a rotation by bf16 cosines is another function,

@@ -55,6 +55,12 @@ writes and ``out_proj``'s matmul reads; behind a custom call the XLA
 function's reshape to ``[.., groups, C / groups]`` was a relayout of a
 float32 array three times a layer. Everywhere else that XLA function
 runs (``xla``). The convolution has no kernel.
+
+Kimi Delta Attention (``ops/kda.py``, ``models/kimi_linear.py``) takes
+two things from here: the convolution, three times a layer and without
+a bias, and ``sigmoid_gated_head_rms_norm`` at the end of this file, its
+own output gate (a sigmoid on the *normed* output, where Mamba-2 norms
+the gated one).
 """
 
 from __future__ import annotations
@@ -207,17 +213,18 @@ def _padded_scan(x, dt, A, B, C, D, *, chunk: int):
 
 
 @jax.checkpoint
-def causal_conv1d_silu(x, weight, bias):
+def causal_conv1d_silu(x, weight, bias=None):
     """``silu`` of the depthwise causal convolution over time: ``y[t,
     c] = bias[c] + sum_j weight[j, c] * x[t - (K - 1) + j, c]``, zeros
-    before the start. x [batch, T, C]; weight [K, C]; bias [C]. K
+    before the start. x [batch, T, C]; weight [K, C]; bias [C], or None
+    for a convolution without one (Kimi Delta Attention's three). K
     shifted multiply-adds (K is 4), recomputed in the backward: only
     ``x`` is kept, not the sum in front of the SiLU."""
     K = weight.shape[0]
     T = x.shape[1]
     padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     w = weight.astype(x.dtype)
-    y = bias.astype(x.dtype)
+    y = 0 if bias is None else bias.astype(x.dtype)
     for j in range(K):
         y = y + padded[:, j:j + T] * w[j]
     return jax.nn.silu(y)
@@ -248,3 +255,19 @@ def _gated_group_rms_norm_xla(y, z, scale, groups: int, eps: float):
     g = g.reshape(*shape[:-1], groups, shape[-1] // groups)
     g = g * lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
     return (g.reshape(shape) * scale.astype(jnp.float32)).astype(dtype)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4))
+def sigmoid_gated_head_rms_norm(o, gate, scale, heads: int, eps: float):
+    """Kimi Delta Attention's output gate: ``sigmoid(gate) *
+    RMSNorm_head(o)``, the norm over each of ``heads`` equal slices of
+    the last dimension with one ``scale`` [C / heads] shared by the
+    heads. Not ``gated_group_rms_norm``: that one norms the *gated*
+    product ``y * silu(z)``; this one gates the *normed* output, by a
+    sigmoid. float32 inside, ``gate``'s dtype out; recomputed in the
+    backward (``o`` and ``gate`` are kept). XLA everywhere."""
+    shape = o.shape
+    x = o.astype(jnp.float32).reshape(*shape[:-1], heads, shape[-1] // heads)
+    x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    x = (x * scale.astype(jnp.float32)).reshape(shape)
+    return (jax.nn.sigmoid(gate.astype(jnp.float32)) * x).astype(gate.dtype)

@@ -1,0 +1,102 @@
+"""One KDA layer's recurrence at the Kimi-Linear cell's shape, forward
+and backward, timed on the device this runs on, under variants of
+``ops/kda.py``'s two knobs (rows a recomputed group, matmul precision);
+each variant's distance from the first one's numbers beside its time.
+
+    python3 scripts/kda_timing.py [--rows 16384] [--variants a,b,...]
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--rows", type=int, default=16384)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--variants", default="")
+    ap.add_argument("--reps", type=int, default=3)
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from ray_tpu.ops import kda
+
+    P = lax.Precision
+    variants = {
+        "g512_highest": (512, P.HIGHEST),
+        "g512_high": (512, P.HIGH),
+        "g256_high": (256, P.HIGH),
+        "g128_high": (128, P.HIGH),
+        "g1024_high": (1024, P.HIGH),
+        "g512_default": (512, P.DEFAULT),
+    }
+    names = [v for v in args.variants.split(",") if v] or list(variants)
+    t, h, k = args.rows, args.heads, 128
+    rng = np.random.default_rng(0)
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+    q = jnp.asarray(unit(rng.normal(size=(1, t, h, k))) * k ** -0.5,
+                    jnp.float32)
+    kk = jnp.asarray(unit(rng.normal(size=(1, t, h, k))), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, t, h, k)) * 0.3, jnp.bfloat16)
+    g = jnp.asarray(-0.05 * np.log1p(np.exp(rng.normal(size=(1, t, h, k)))),
+                    jnp.float32)
+    beta = jnp.asarray(1 / (1 + np.exp(-rng.normal(size=(1, t, h)))),
+                       jnp.float32)
+    w = jnp.asarray(rng.normal(size=(1, t, h, k)), jnp.float32)
+    base = None
+    for name in names:
+        rows, precision = variants[name]
+        kda.GROUP_ROWS = rows
+        kda._matmul = functools.partial(
+            jnp.einsum, precision=precision,
+            preferred_element_type=jnp.float32)
+
+        def loss(q, kk, v, g, beta):
+            o = kda.kda_scan(q, kk, v, g, beta, chunk=64)
+            return jnp.sum(o * w), jnp.sqrt(jnp.mean(o * o))
+
+        fwd = jax.jit(lambda *a: loss(*a)[1])
+        both = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                          has_aux=True))
+        t0 = time.monotonic()
+        jax.block_until_ready(fwd(q, kk, v, g, beta))
+        (_, rms), grads = jax.block_until_ready(both(q, kk, v, g, beta))
+        compile_s = time.monotonic() - t0
+
+        def timed(fn):
+            best = 1e9
+            for _ in range(args.reps):
+                t0 = time.monotonic()
+                jax.block_until_ready(fn(q, kk, v, g, beta))
+                best = min(best, time.monotonic() - t0)
+            return best * 1e3
+        nums = {"rms": float(rms), **{
+            f"d{n}": float(jnp.sqrt(jnp.sum(jnp.square(
+                x.astype(jnp.float32)))))
+            for n, x in zip(("q", "k", "v", "g", "beta"), grads)}}
+        base = base or nums
+        print(json.dumps({
+            "variant": name, "device": jax.devices()[0].device_kind,
+            "forward_ms": timed(fwd), "both_ms": timed(both),
+            "compile_s": compile_s,
+            "off": {n: abs(nums[n] - base[n]) / abs(base[n]) for n in nums},
+            "peak_gb": (jax.devices()[0].memory_stats() or {}).get(
+                "peak_bytes_in_use", 0) / 1e9}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

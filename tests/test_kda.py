@@ -1,0 +1,142 @@
+"""``ops/kda.py``'s chunked gated delta rule against the recurrence it is
+a rearrangement of, token by token: outputs and the gradients of every
+operand."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import kda
+
+
+def recurrence(q, k, v, g, beta):
+    """``S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t
+    v_t^T``; ``o_t = S_t^T q_t``, one row at a time, float32."""
+    b, t, h, kd = q.shape
+
+    def step(S, row):
+        q, k, v, g, beta = row              # [b, H, .]; beta [b, H]
+        S = jnp.exp(g)[..., None] * S
+        read = jnp.sum(k[..., None] * S, -2)            # S^T k
+        S = S + k[..., None] * (beta[..., None] * (v - read))[..., None, :]
+        return S, jnp.sum(q[..., None] * S, -2)
+
+    rows = tuple(jnp.moveaxis(z.astype(jnp.float32), 1, 0)
+                 for z in (q, k, v, g, beta))
+    _, o = jax.lax.scan(
+        step, jnp.zeros((b, h, kd, v.shape[-1]), jnp.float32), rows)
+    return jnp.moveaxis(o, 0, 1)
+
+
+def operands(seed, b, t, h, kd, vd, decay=1.0, beta=None):
+    """q scaled, k of unit length, v, g = -decay * softplus(.), beta."""
+    rng = np.random.default_rng(seed)
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(rng.normal(size=(b, t, h, kd))) * kd ** -0.5
+    k = unit(rng.normal(size=(b, t, h, kd)))
+    v = rng.normal(size=(b, t, h, vd))
+    g = -decay * np.log1p(np.exp(rng.normal(size=(b, t, h, kd))))
+    bt = (1 / (1 + np.exp(-rng.normal(size=(b, t, h)))) if beta is None
+          else np.full((b, t, h), beta))
+    return tuple(jnp.asarray(z, jnp.float32) for z in (q, k, v, g, bt))
+
+
+def both(args, chunk):
+    """((outputs, gradients) of the chunked form, of the recurrence),
+    the gradients those of a fixed random projection of the output."""
+    weight = jnp.asarray(np.random.default_rng(7).normal(
+        size=args[2].shape), jnp.float32)
+
+    def of(fn):
+        def loss(*a):
+            o = fn(*a)
+            return jnp.sum(o * weight), o
+        (_, o), grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                           has_aux=True)(*args)
+        return o, grads
+    return (of(lambda *a: kda.kda_scan(*a, chunk=chunk)), of(recurrence))
+
+
+def close(got, want, rtol=2e-4):
+    scale = float(jnp.max(jnp.abs(want))) + 1e-30
+    np.testing.assert_allclose(np.asarray(got) / scale,
+                               np.asarray(want) / scale, atol=rtol, rtol=0)
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("t", [128, 150])        # whole chunks, and not
+@pytest.mark.parametrize("heads", [1, 3])
+def test_chunked_form_is_the_recurrence(chunk, t, heads):
+    args = operands(0, 2, t, heads, 32, 24)
+    (o, grads), (o_ref, grads_ref) = both(args, chunk)
+    close(o, o_ref)
+    for got, want in zip(grads, grads_ref):
+        close(got, want)
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_decays_that_overflow_exp_of_minus_the_running_sum(chunk):
+    """At ``decay`` 8 a chunk's running sum passes -100 in some channel
+    inside one chunk of 16 and -400 inside one of 64: ``exp(-G)`` is
+    infinite in float32 (past 88.7), so a form that scaled keys by it
+    would give nan or inf. The differences are what is exponentiated."""
+    args = operands(1, 1, 128, 2, 32, 32, decay=8.0)
+    G = np.cumsum(np.asarray(args[3])[:, :chunk], axis=1)
+    with np.errstate(over="ignore"):
+        assert np.isinf(np.exp(-G, dtype=np.float32)).any()
+    (o, grads), (o_ref, grads_ref) = both(args, chunk)
+    assert np.isfinite(np.asarray(o)).all()
+    close(o, o_ref)
+    for got, want in zip(grads, grads_ref):
+        assert np.isfinite(np.asarray(got)).all()
+        close(got, want)
+
+
+@pytest.mark.parametrize("case", ["no_decay", "no_write"])
+def test_the_plain_delta_rule_and_a_layer_that_writes_nothing(case):
+    if case == "no_decay":      # alpha = 1: the delta rule without a gate
+        args = operands(2, 1, 96, 2, 32, 32, decay=0.0)
+        (o, grads), (o_ref, grads_ref) = both(args, 16)
+        close(o, o_ref)
+        for got, want in zip(grads, grads_ref):
+            close(got, want)
+    else:                       # beta = 0: the state stays zero
+        args = operands(3, 1, 96, 2, 32, 32, beta=0.0)
+        o = kda.kda_scan(*args, chunk=16)
+        assert not np.asarray(o).any()
+
+
+def test_a_state_carried_across_exactly_one_chunk_boundary():
+    """Two chunks: the second's outputs read what the first wrote, and
+    differ from the same rows run alone from an empty state."""
+    chunk = 16
+    args = operands(4, 1, 2 * chunk, 2, 32, 32)
+    o = kda.kda_scan(*args, chunk=chunk)
+    close(o, recurrence(*args))
+    alone = kda.kda_scan(*(a[:, chunk:] for a in args), chunk=chunk)
+    assert float(jnp.max(jnp.abs(o[:, chunk:] - alone))) > 1e-3
+
+
+def test_groups_of_chunks_hand_the_state_on(monkeypatch):
+    """More rows than one recomputed group holds: the outer scan's
+    carry is the state."""
+    monkeypatch.setattr(kda, "GROUP_ROWS", 32)
+    args = operands(5, 1, 100, 2, 16, 16)
+    (o, grads), (o_ref, grads_ref) = both(args, 16)
+    close(o, o_ref)
+    for got, want in zip(grads, grads_ref):
+        close(got, want)
+
+
+@pytest.mark.parametrize("axis", ["sp", "tp"])
+def test_kda_path_refuses_a_split_sequence_or_split_heads(axis):
+    from ray_tpu.parallel import make_mesh
+    mesh = make_mesh({axis: 2}, devices=jax.devices()[:2])
+    with pytest.raises(NotImplementedError, match=f"{axis}=2"):
+        kda.kda_path((1, 64, 2, 16), 16, mesh)
+    assert kda.kda_path((1, 64, 2, 16), 16, None) == "xla_chunked"
+    dp = make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    assert kda.kda_path((2, 64, 2, 16), 16, dp) == "xla_chunked"

@@ -1,0 +1,114 @@
+"""The Kimi-Linear cell's step compiles for the real chip, with no chip
+here (as ``test_tpu_compile_smallthinker.py``: the TPU compiler for a
+described v5e; nothing runs, so nothing here is a result or a time)."""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, no description
+        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def test_the_real_size_step_compiles_inside_the_chips_memory(
+        v5e, monkeypatch):
+    """The cell's step as the builder makes it (layers 1-5, KKKMK, with 8
+    of 256 experts held, 20,480 rows of each table, the blocks
+    recomputed; adamw with a bf16 first moment) at 1 x 16,384 tokens:
+    arguments + temporaries + unaliased outputs stay under the 14.5 GB
+    that leave room for the device's own reserve, the MLA layer's
+    attention is the latent kernel pair with dq's 16,384 rows resident
+    and no rotation, the KDA layers run the chunked recurrence, no
+    flash kernel sits under ``kda``, and no ``[T, T]`` array exists."""
+    import re
+
+    import optax
+
+    from ray_tpu import train
+    from ray_tpu.models.kimi_linear import (
+        KimiLinear,
+        KimiLinearConfig,
+        kimi_linear_loss_fn,
+    )
+    from ray_tpu.util import tracing
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
+    one = SingleDeviceSharding(v5e[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = KimiLinearConfig.kimi_linear_48b_a3b(
+        n_layer=5, experts_held=(0, 8), vocab_size=20480, remat=True)
+    model = KimiLinear(cfg)
+    opt = optax.chain(
+        optax.clip_by_global_norm(1.0),
+        optax.adamw(2e-5, b1=0.9, b2=0.95, weight_decay=0.1,
+                    mu_dtype=jnp.bfloat16))
+    step = train.make_train_step(
+        kimi_linear_loss_fn(model, ce_chunk=2048), opt, grad_groups={
+            "grad_norm_kda_gates":
+            "^h_[0-9]+/kda/(f_a/kernel|f_b|A_log|dt_bias|b/kernel)$"})
+    state = jax.tree.map(
+        lambda z: arg(z.shape, z.dtype),
+        jax.eval_shape(lambda: train.init_train_state(
+            model.init_params(jax.random.key(0)), opt, None)))
+    batch = {k: arg((1, cfg.seq_len), jnp.int32)
+             for k in ("tokens", "targets")}
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    compiled = step.lower(state, batch).compile()
+    assert notes["attn_kind"] == "kda_mla"
+    assert notes["attn_layers"] == "KKKMK" and notes["blocks_remat"] is True
+    assert notes["kda_path"] == "xla_chunked" and notes["kda_chunk"] == 64
+    assert notes["kda_heads"] == 32 and notes["kda_state"] == [128, 128]
+    assert notes["flash_path"] == "mla_multi_block"
+    assert notes["flash_bwd_resident_rows"] == 16384
+    assert notes["mla_positions"] == "none"
+    assert notes["mla_saved"] == "latents" and notes["dense_layers"] == 1
+    assert notes["moe_router"] == "sigmoid" and notes["moe_top_k"] == 8
+    assert notes["moe_experts_held"] == [0, 8]
+    assert notes["moe_rows_sorted"] == 8192     # twice the even share
+    assert notes["moe_path"] == "megablox_gmm"
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + max(0, m.output_size_in_bytes - m.alias_size_in_bytes))
+    print(f"program {total / 1e9:.2f} GB: arguments "
+          f"{m.argument_size_in_bytes / 1e9:.2f}, temporaries "
+          f"{m.temp_size_in_bytes / 1e9:.2f}")
+    assert m.argument_size_in_bytes == pytest.approx(
+        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
+    assert 4e9 < total <= 14.5e9
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kinds = [re.search(r"jit\((\w+)\)/pallas_call", line).group(1)
+             for line in calls]
+    assert {"gmm", "tgmm"} <= set(kinds)
+    # the forward kernel twice (the block is recomputed), the backward once
+    assert kinds.count("mla_flash_fwd") == 2
+    assert kinds.count("mla_flash_bwd") == 1
+    flash = [line for kind, line in zip(kinds, calls) if "mla_flash" in kind]
+    assert all("/h_3/attn/core/" in line for line in flash)
+    assert not any("/kda/" in line for line in calls)
+    assert "/attn/rope/" not in text and "/attn/q_down/" not in text
+    assert "16384,16384" not in text
