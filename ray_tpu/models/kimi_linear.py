@@ -36,8 +36,9 @@ recurrence and the gate run again (``_kda_core`` is a
 ``jax.checkpoint``), as ``latent_attention`` keeps its latents. With
 ``remat`` the blocks are recomputed too (``models/llama.py``'s switch),
 keeping of each KDA mixer its gated output, so that the recurrence runs
-forward three times a step (the pass, ``_kda_core``'s recomputation, a
-group's inside ``kda_scan``) and not four.
+forward twice a step on the kernels (the pass, ``_kda_core``'s
+recomputation; a third time, a group's inside ``kda_scan``, on the XLA
+path) and not once more.
 
 It is the benchmark's sixth language model
 (``kimi-linear-48b-a3b.b1-t16384`` runs layers 1-5, KDA with the dense

@@ -43,23 +43,30 @@ rank-one write of a *corrected* value.
   that entered (``_read_out``).
 
 Everything here is float32, the matmuls at ``Precision.HIGH`` (three
-bfloat16 passes an operand pair): the recurrence is 2% of a Kimi-Linear
-step's operations and is bound by bytes and dependent steps, not by the
-MXU (PERF.md section 5).
+bfloat16 passes an operand pair; the kernels' at ``HIGHEST``, Mosaic
+having nothing between that and one pass): the recurrence is 2% of a
+Kimi-Linear step's operations (PERF.md section 5).
 
-**What is kept for the backward pass.** The rows are walked in groups
-of ``GROUP_ROWS`` (a ``lax.scan`` over groups; each group's chunks are
-made together and walked by an inner scan); a group is a
-``jax.checkpoint``, so the backward pass keeps ``q, k, v, g, beta`` and
-the state that entered each group (``[T / GROUP_ROWS, H, K, V]``
+**What is kept for the backward pass** (``xla_chunked``). The rows are
+walked in groups of ``GROUP_ROWS`` (a ``lax.scan`` over groups; each
+group's chunks are made together and walked by an inner scan); a group
+is a ``jax.checkpoint``, so the backward pass keeps ``q, k, v, g, beta``
+and the state that entered each group (``[T / GROUP_ROWS, H, K, V]``
 float32) and recomputes a group's inner quantities, its chunk-boundary
 states among them, when it reaches it.
 
-**Which path runs** (``kda_path``; the trace's note ``kda_path`` says):
-``xla_chunked``, the above, everywhere. A Pallas kernel pair that keeps
-a chunk's squares in VMEM is the first ``perf_opt`` this asks for
-(``kda_scan_roofline``). A sequence or the heads split over chips
-(``sp``, ``tp``) would need the state or the heads passed between
+**Which path runs** (``kda_path``; the trace's note ``kda_path`` says).
+``pallas_chunked``: ``ops/pallas/kda_scan.py``'s kernel pair, the same
+five equations with a chunk's arrays and the state in VMEM (no groups:
+the backward keeps the state entering every chunk and recomputes a
+chunk's squares from it), on a TPU backend where keys and values are
+one 128-lane tile a head, the chunk is 64 and the program is one
+device's (a ``pallas_call`` has no partitioning rule; a mesh of several
+devices, ``dp`` or ``fsdp``, takes the XLA path). ``xla_chunked``, the
+above, everywhere else: the CPU, the tiny preset's chunks of 16, other
+widths; it is also what the tests hold the kernels to. Nothing but
+what ``kda_path`` observes chooses. A sequence or the heads split over
+chips (``sp``, ``tp``) would need the state or the heads passed between
 chips: refused by name.
 """
 
@@ -71,6 +78,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.ops import ssm
+from ray_tpu.ops.pallas import kda_scan as kernels
 from ray_tpu.util import tracing
 
 SUB = 16            # rows of a sub-block of a chunk (``_scores``, ``_solve``)
@@ -80,10 +89,15 @@ _matmul = functools.partial(jnp.einsum, precision=lax.Precision.HIGH,
                             preferred_element_type=jnp.float32)
 
 
-def kda_path(shape, chunk: int, mesh=None) -> str:
-    """Which recurrence ``kda_scan`` compiles for ``q`` [b, T, H, K] at
-    this chunk on this mesh: ``xla_chunked``. Raises where the program
-    spans chips in a way that would split a sequence or its heads."""
+def kda_path(shape, chunk: int, mesh=None, *, values: int | None = None
+             ) -> str:
+    """Which recurrence ``kda_scan`` compiles for ``q`` [b, T, H, K]
+    and values ``values`` wide (as the keys, if not said) at this chunk
+    on this mesh:
+    ``pallas_chunked`` on a TPU where the kernels tile the widths and
+    the chunk and the program is one device's, else ``xla_chunked``.
+    Raises where the program spans chips in a way that would split a
+    sequence or its heads."""
     from ray_tpu.parallel.mesh import AXIS_SP, AXIS_TP
     if mesh is not None:
         for axis, what in ((AXIS_SP, "the sequence split over chips (a "
@@ -96,6 +110,13 @@ def kda_path(shape, chunk: int, mesh=None) -> str:
                     "batch and need nothing")
     if chunk % min(SUB, chunk):
         raise ValueError(f"chunk {chunk} is not whole sub-blocks of {SUB}")
+    # a ``pallas_call`` has no SPMD partitioning rule: the kernels where
+    # ``ops/ssm.py``'s rule finds a one-device program (no axis to map
+    # them over: its ``shard_map`` is not carried over)
+    if (jax.default_backend() == "tpu"
+            and kernels.shapes_ok(shape[-1], values or shape[-1], chunk)
+            and ssm._kernel_batch_axes(mesh, shape[0]) == ()):
+        return "pallas_chunked"
     return "xla_chunked"
 
 
@@ -242,22 +263,10 @@ def _group(S, rows, *, chunk: int):
     return S, jnp.moveaxis(o, 1, 3).reshape(b, n, h, -1)
 
 
-def kda_scan(q, k, v, g, beta, *, chunk: int = 64, mesh=None):
-    """The recurrence above over whole sequences, chunked; backward by
-    recomputation from the states entering each group of chunks.
-
-    q, k: [batch, T, H, K]  queries (already scaled) and unit keys
-    v:    [batch, T, H, V]
-    g:    [batch, T, H, K]  log-decays, <= 0, float32
-    beta: [batch, T, H]     step sizes, float32
-    Returns ``o`` [batch, T, H, V] float32. ``T`` need not be whole
-    chunks: the tail is padded with rows that neither decay nor write
-    the state. ``mesh`` is the mesh the program is sharded over, if the
-    caller knows one: ``kda_path`` decides from it."""
-    path = kda_path(q.shape, chunk, mesh)
+def _xla_chunked(q, k, v, g, beta, *, chunk: int):
+    """``kda_scan`` in XLA: groups of chunks under a ``lax.scan``, each a
+    ``jax.checkpoint``."""
     b, t, h, kd = q.shape
-    tracing.note_trace(kda_path=path, kda_chunk=chunk, kda_heads=h,
-                       kda_state=[kd, v.shape[-1]])
     per_group = chunk * max(1, min(GROUP_ROWS, t + (-t) % chunk) // chunk)
     pad = (-t) % per_group
     # [groups, b, rows a group, H, .]: no row moves for a batch of one
@@ -270,3 +279,23 @@ def kda_scan(q, k, v, g, beta, *, chunk: int = 64, mesh=None):
     _, o = lax.scan(group, jnp.zeros((b, h, kd, v.shape[-1]), jnp.float32),
                     rows)
     return jnp.moveaxis(o, 0, 1).reshape(b, t + pad, h, -1)[:, :t]
+
+
+def kda_scan(q, k, v, g, beta, *, chunk: int = 64, mesh=None):
+    """The recurrence above over whole sequences, chunked, by the path
+    ``kda_path`` names.
+
+    q, k: [batch, T, H, K]  queries (already scaled) and unit keys
+    v:    [batch, T, H, V]
+    g:    [batch, T, H, K]  log-decays, <= 0, float32
+    beta: [batch, T, H]     step sizes, float32
+    Returns ``o`` [batch, T, H, V] float32. ``T`` need not be whole
+    chunks: the tail is padded with rows that neither decay nor write
+    the state. ``mesh`` is the mesh the program is sharded over, if the
+    caller knows one: ``kda_path`` decides from it."""
+    path = kda_path(q.shape, chunk, mesh, values=v.shape[-1])
+    tracing.note_trace(kda_path=path, kda_chunk=chunk, kda_heads=q.shape[2],
+                       kda_state=[q.shape[-1], v.shape[-1]])
+    if path == "pallas_chunked":
+        return kernels.kda_scan(q, k, v, g, beta)
+    return _xla_chunked(q, k, v, g, beta, chunk=chunk)

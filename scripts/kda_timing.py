@@ -1,6 +1,7 @@
 """One KDA layer's recurrence at the Kimi-Linear cell's shape, forward
-and backward, timed on the device this runs on, under variants of
-``ops/kda.py``'s two knobs (rows a recomputed group, matmul precision);
+and backward, timed on the device this runs on: the XLA path under
+variants of ``ops/kda.py``'s two knobs (rows a recomputed group, matmul
+precision) and the kernel pair (``pallas``, ``ops/pallas/kda_scan.py``);
 each variant's distance from the first one's numbers beside its time.
 
     python3 scripts/kda_timing.py [--rows 16384] [--variants a,b,...]
@@ -32,6 +33,7 @@ def main() -> None:
     from jax import lax
 
     from ray_tpu.ops import kda
+    from ray_tpu.ops.pallas import kda_scan as kernels
 
     P = lax.Precision
     variants = {
@@ -41,6 +43,7 @@ def main() -> None:
         "g128_high": (128, P.HIGH),
         "g1024_high": (1024, P.HIGH),
         "g512_default": (512, P.DEFAULT),
+        "pallas": None,
     }
     names = [v for v in args.variants.split(",") if v] or list(variants)
     t, h, k = args.rows, args.heads, 128
@@ -59,14 +62,17 @@ def main() -> None:
     w = jnp.asarray(rng.normal(size=(1, t, h, k)), jnp.float32)
     base = None
     for name in names:
-        rows, precision = variants[name]
-        kda.GROUP_ROWS = rows
-        kda._matmul = functools.partial(
-            jnp.einsum, precision=precision,
-            preferred_element_type=jnp.float32)
+        if variants[name] is None:
+            scan = kernels.kda_scan
+        else:
+            kda.GROUP_ROWS, precision = variants[name]
+            kda._matmul = functools.partial(
+                jnp.einsum, precision=precision,
+                preferred_element_type=jnp.float32)
+            scan = functools.partial(kda._xla_chunked, chunk=64)
 
         def loss(q, kk, v, g, beta):
-            o = kda.kda_scan(q, kk, v, g, beta, chunk=64)
+            o = scan(q, kk, v, g, beta)
             return jnp.sum(o * w), jnp.sqrt(jnp.mean(o * o))
 
         fwd = jax.jit(lambda *a: loss(*a)[1])

@@ -44,9 +44,9 @@ def operands(seed, b, t, h, kd, vd, decay=1.0, beta=None):
     return tuple(jnp.asarray(z, jnp.float32) for z in (q, k, v, g, bt))
 
 
-def both(args, chunk):
-    """((outputs, gradients) of the chunked form, of the recurrence),
-    the gradients those of a fixed random projection of the output."""
+def outputs_and_gradients(args, *fns):
+    """(outputs, gradients) of each function, the gradients those of one
+    fixed random projection of the output."""
     weight = jnp.asarray(np.random.default_rng(7).normal(
         size=args[2].shape), jnp.float32)
 
@@ -57,7 +57,13 @@ def both(args, chunk):
         (_, o), grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
                                            has_aux=True)(*args)
         return o, grads
-    return (of(lambda *a: kda.kda_scan(*a, chunk=chunk)), of(recurrence))
+    return tuple(of(fn) for fn in fns)
+
+
+def both(args, chunk):
+    """Of the chunked form, of the recurrence."""
+    return outputs_and_gradients(
+        args, lambda *a: kda.kda_scan(*a, chunk=chunk), recurrence)
 
 
 def close(got, want, rtol=2e-4):
@@ -140,3 +146,136 @@ def test_kda_path_refuses_a_split_sequence_or_split_heads(axis):
     assert kda.kda_path((1, 64, 2, 16), 16, None) == "xla_chunked"
     dp = make_mesh({"dp": 2}, devices=jax.devices()[:2])
     assert kda.kda_path((2, 64, 2, 16), 16, dp) == "xla_chunked"
+
+
+# ---------------------------------------------------------------------------
+# the kernel pair (``ops/pallas/kda_scan.py``), interpreted on the CPU
+# ---------------------------------------------------------------------------
+
+from ray_tpu.ops.pallas import kda_scan as kernels  # noqa: E402
+
+
+def three_ways(args):
+    """Of the kernels, of ``xla_chunked``, of the recurrence."""
+    return outputs_and_gradients(
+        args, lambda *a: kernels.kda_scan(*a, interpret=True),
+        lambda *a: kda.kda_scan(*a, chunk=64), recurrence)
+
+
+def all_close(got, *wants):
+    (o, grads) = got
+    for o_ref, grads_ref in wants:
+        close(o, o_ref)
+        for g, want in zip(grads, grads_ref):
+            assert np.isfinite(np.asarray(g)).all()
+            close(g, want)
+
+
+@pytest.mark.parametrize("t", [128, 150])   # two chunks; not whole chunks
+@pytest.mark.parametrize("heads", [1, 3])
+def test_the_kernels_are_the_chunked_form_and_the_recurrence(t, heads):
+    """``o`` and ``dq, dk, dv, dg, dbeta``; 150 rows are three chunks in
+    two grid cells, so the state and its cotangent cross a cell."""
+    got, xla, ref = three_ways(operands(10 + heads, 1, t, heads, 128, 128))
+    all_close(got, xla, ref)
+
+
+def test_the_kernels_at_decays_that_overflow_exp_of_minus_the_running_sum():
+    args = operands(1, 1, 128, 2, 128, 128, decay=8.0)
+    G = np.cumsum(np.asarray(args[3])[:, :64], axis=1)
+    with np.errstate(over="ignore"):
+        assert np.isinf(np.exp(-G, dtype=np.float32)).any()
+    got, xla, ref = three_ways(args)
+    assert np.isfinite(np.asarray(got[0])).all()
+    all_close(got, xla, ref)
+
+
+def test_the_kernels_carry_a_state_across_exactly_one_chunk_boundary():
+    """One grid cell of two chunks (and of four heads): the second's
+    outputs read what the first wrote."""
+    args = operands(4, 1, 128, 4, 128, 128)
+    o = kernels.kda_scan(*args, interpret=True)
+    close(o, recurrence(*args))
+    alone = kernels.kda_scan(*(a[:, 64:] for a in args), interpret=True)
+    assert float(jnp.max(jnp.abs(o[:, 64:] - alone))) > 1e-3
+
+
+def test_the_kernels_take_bfloat16_values_and_return_their_cotangent_so():
+    q, k, v, g, beta = operands(6, 1, 128, 1, 128, 128)
+    v = v.astype(jnp.bfloat16)
+
+    def loss(fn, v):
+        return jnp.sum(jnp.sin(fn(q, k, v, g, beta)))
+    dv = jax.grad(lambda v: loss(
+        lambda *a: kernels.kda_scan(*a, interpret=True), v))(v)
+    want = jax.grad(lambda v: loss(
+        lambda *a: kda.kda_scan(*a, chunk=64), v))(v)
+    assert dv.dtype == jnp.bfloat16
+    close(dv.astype(jnp.float32), want.astype(jnp.float32), rtol=1e-2)
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def test_every_product_and_exp_in_both_kernels_is_float32():
+    """No bfloat16 operand inside the recurrence, no matmul below
+    ``HIGHEST`` (Mosaic has nothing between it and one bfloat16 pass),
+    read from the kernels' own jaxprs, both passes."""
+    args = operands(0, 1, 128, 1, 128, 128)
+    traced = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(kernels.kda_scan(*a)), argnums=(0, 1, 2, 3, 4)))(
+            *args)
+    calls = [e for e in _equations(traced.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 2                      # forward, backward
+    for call in calls:
+        inside = list(_equations(call.params["jaxpr"]))
+        dots = [e for e in inside if e.primitive.name == "dot_general"]
+        exps = [e for e in inside if e.primitive.name == "exp"]
+        assert len(dots) >= 20 and len(exps) >= 8
+        for e in dots:
+            assert {v.aval.dtype for v in e.invars} == {jnp.dtype("float32")}
+            assert e.outvars[0].aval.dtype == jnp.float32
+            assert e.params["precision"] is not None and all(
+                p == jax.lax.Precision.HIGHEST for p in e.params["precision"])
+        for e in exps:
+            assert e.invars[0].aval.dtype == jnp.float32
+        assert not any(v.aval.dtype == jnp.bfloat16
+                       for e in inside for v in e.outvars
+                       if hasattr(v.aval, "dtype"))
+
+
+@pytest.mark.parametrize("case, shape, chunk, want", [
+    ("tpu_tiles", (1, 16384, 32, 128), 64, "pallas_chunked"),
+    ("tpu_chunk_16", (1, 64, 2, 128), 16, "xla_chunked"),
+    ("tpu_keys_64", (1, 128, 2, 64), 64, "xla_chunked"),
+    ("tpu_values_64", (1, 128, 2, 128), 64, "xla_chunked"),
+    ("tpu_two_devices", (2, 128, 2, 128), 64, "xla_chunked"),
+    ("tpu_dp_mesh", (2, 128, 2, 128), 64, "xla_chunked"),
+    ("cpu_tiles", (1, 128, 2, 128), 64, "xla_chunked"),
+])
+def test_kda_path_takes_the_kernels_where_it_observes_they_fit(
+        monkeypatch, case, shape, chunk, want):
+    from ray_tpu.parallel import make_mesh
+    backend = case.split("_")[0]
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count",
+                        lambda: 2 if case == "tpu_two_devices" else 1)
+    mesh = (make_mesh({"dp": 2}, devices=jax.devices()[:2])
+            if case == "tpu_dp_mesh" else None)
+    values = 64 if case == "tpu_values_64" else None
+    assert kda.kda_path(shape, chunk, mesh, values=values) == want
+    if want == "pallas_chunked":        # a mesh of one device is one device
+        one = make_mesh({"dp": 1}, devices=jax.devices()[:1])
+        assert kda.kda_path(shape, chunk, one) == want
+        for axis in ("sp", "tp"):
+            split = make_mesh({axis: 2}, devices=jax.devices()[:2])
+            with pytest.raises(NotImplementedError, match=f"{axis}=2"):
+                kda.kda_path(shape, chunk, split)

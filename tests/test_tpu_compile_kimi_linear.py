@@ -37,8 +37,10 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     arguments + temporaries + unaliased outputs stay under the 14.5 GB
     that leave room for the device's own reserve, the MLA layer's
     attention is the latent kernel pair with dq's 16,384 rows resident
-    and no rotation, the KDA layers run the chunked recurrence, no
-    flash kernel sits under ``kda``, and no ``[T, T]`` array exists."""
+    and no rotation, the KDA layers run the recurrence's kernel pair
+    (the forward twice a layer, the backward once, all under ``scan``
+    and no other custom call under ``kda``), and no ``[T, T]`` array
+    exists."""
     import re
 
     import optax
@@ -79,7 +81,7 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     compiled = step.lower(state, batch).compile()
     assert notes["attn_kind"] == "kda_mla"
     assert notes["attn_layers"] == "KKKMK" and notes["blocks_remat"] is True
-    assert notes["kda_path"] == "xla_chunked" and notes["kda_chunk"] == 64
+    assert notes["kda_path"] == "pallas_chunked" and notes["kda_chunk"] == 64
     assert notes["kda_heads"] == 32 and notes["kda_state"] == [128, 128]
     assert notes["flash_path"] == "mla_multi_block"
     assert notes["flash_bwd_resident_rows"] == 16384
@@ -109,6 +111,15 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert kinds.count("mla_flash_bwd") == 1
     flash = [line for kind, line in zip(kinds, calls) if "mla_flash" in kind]
     assert all("/h_3/attn/core/" in line for line in flash)
-    assert not any("/kda/" in line for line in calls)
+    # four KDA layers: each kernel lowered once, called a layer
+    assert kinds.count("_kda_fwd") == 2 * 4
+    assert kinds.count("_kda_bwd") == 4
+    under_kda = [(kind, line) for kind, line in zip(kinds, calls)
+                 if "/kda/" in line]
+    assert len(under_kda) == 12
+    # the checkpoints' own names stand between the module and its scope
+    assert all(kind in ("_kda_fwd", "_kda_bwd") and re.search(
+        r"/kda/(checkpoint/|rematted_computation/)*scan/jit", line)
+        for kind, line in under_kda)
     assert "/attn/rope/" not in text and "/attn/q_down/" not in text
     assert "16384,16384" not in text
