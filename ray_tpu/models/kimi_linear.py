@@ -12,11 +12,12 @@ three **KDA** layers (Kimi Delta Attention) to one **MLA** layer.
   k, v = W_q h, W_k h, W_v h``, each through its own depthwise causal
   convolution of 4 taps and a SiLU (``ops/ssm.py::causal_conv1d_silu``,
   no bias); a head's ``q`` and ``k`` to unit length in float32, ``q``
-  times ``head_dim^-1/2``; the decay a channel ``g = -exp(A_log[head])
-  * softplus(W_f2 (W_f1 h) + dt_bias)``; the step size a head ``beta =
-  sigmoid(W_b h)``; the gated delta rule over a ``[128, 128]`` state a
-  head; ``y = W_o (sigmoid(W_g2 (W_g1 h) + b_g) * RMSNorm_head(o))``
-  (``ops/ssm.py::sigmoid_gated_head_rms_norm``);
+  times ``head_dim^-1/2`` (``ops/kda.py::kda_scan`` with
+  ``normalize_qk``: where, its path decides); the decay a channel ``g =
+  -exp(A_log[head]) * softplus(W_f2 (W_f1 h) + dt_bias)``; the step
+  size a head ``beta = sigmoid(W_b h)``; the gated delta rule over a
+  ``[128, 128]`` state a head; ``y = W_o (sigmoid(W_g2 (W_g1 h) + b_g)
+  * RMSNorm_head(o))`` (``ops/ssm.py::sigmoid_gated_head_rms_norm``);
 - **MLA** (``ops/mla.py``) with **no query latent and no rotation**
   (``q_lora_rank`` null, ``mla_use_nope``): ``q = W_q h`` straight to
   32 heads of 128 + 64, keys and values through a 512-wide normed
@@ -51,7 +52,10 @@ the loss ``models/gpt2.py::chunked_cross_entropy``.
 
 Program scopes (docs/observability.md): ``embed``; ``blocks`` with
 ``h_i/kda`` (``qkv``, ``conv``, ``qk_norm``, ``decay``, ``scan``,
-``out_gate``, ``out`` beneath) in a KDA layer and ``h_i/attn``
+``out_gate``, ``out`` beneath; ``qk_norm`` and ``scan`` are opened by
+``ops/kda.py::kda_scan``, and ``qk_norm`` is a scope of its XLA path:
+on the kernels a head's q and k are brought to unit length in VMEM, and
+that time is inside ``scan``) in a KDA layer and ``h_i/attn``
 (``q_up``, ``kv_down``, ``kv_up``, ``core``, ``out_proj``; no
 ``q_down``, no ``rope``) in an MLA layer, and ``h_i/mlp`` (a routed one:
 ``router``, ``dispatch``, ``experts``, ``combine``, ``shared``);
@@ -211,20 +215,16 @@ def _kda_core(q, k, v, f_low, b_logit, g_low, w, *, heads: int, chunk: int,
     with jax.named_scope("conv"):
         q, k, v = (ssm.causal_conv1d_silu(z, w[name]) for z, name in (
             (q, "q_conv"), (k, "k_conv"), (v, "v_conv")))
-    with jax.named_scope("qk_norm"):
-        def unit(x):
-            x = x.astype(f32).reshape(b, t, heads, kd)
-            return x * jax.lax.rsqrt(
-                jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-        q, k = unit(q) * kd ** -0.5, unit(k)
     with jax.named_scope("decay"):
         f = (f_low @ w["f_b"].astype(dt)).astype(f32) + w["dt_bias"]
         g = (-jnp.exp(w["A_log"])[:, None]
              * jax.nn.softplus(f).reshape(b, t, heads, kd))
         beta = jax.nn.sigmoid(b_logit.astype(f32))
-    with jax.named_scope("scan"):
-        o = kda.kda_scan(q, k, v.reshape(b, t, heads, kd), g, beta,
-                         chunk=chunk, mesh=mesh)
+    # a head's q and k go in as the convolutions left them: the
+    # recurrence's path brings them to unit length (``qk_norm``, ``scan``)
+    q, k, v = (z.reshape(b, t, heads, kd) for z in (q, k, v))
+    o = kda.kda_scan(q, k, v, g, beta, chunk=chunk, mesh=mesh,
+                     normalize_qk=True)
     out_sq = jnp.mean(jnp.square(o))
     with jax.named_scope("out_gate"):
         gate = g_low @ w["g_b"].astype(dt) + w["g_bias"].astype(dt)

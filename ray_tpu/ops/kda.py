@@ -59,10 +59,13 @@ states among them, when it reaches it.
 ``pallas_chunked``: ``ops/pallas/kda_scan.py``'s kernel pair, the same
 five equations with a chunk's arrays and the state in VMEM (no groups:
 the backward keeps the state entering every chunk and recomputes a
-chunk's squares from it), on a TPU backend where keys and values are
-one 128-lane tile a head, the chunk is 64 and the program is one
-device's (a ``pallas_call`` has no partitioning rule; a mesh of several
-devices, ``dp`` or ``fsdp``, takes the XLA path). ``xla_chunked``, the
+chunk's squares from it; given a mixer's un-normalised ``q`` and ``k``
+it also makes their unit rows there, where ``xla_chunked`` makes float32
+arrays of them first: ``kda_scan``'s ``normalize_qk``), on a TPU
+backend where keys and values are one 128-lane tile a head, the chunk
+is 64 and the program is one device's (a ``pallas_call`` has no
+partitioning rule; a mesh of several devices, ``dp`` or ``fsdp``, takes
+the XLA path). ``xla_chunked``, the
 above, everywhere else: the CPU, the tiny preset's chunks of 16, other
 widths; it is also what the tests hold the kernels to. Nothing but
 what ``kda_path`` observes chooses. A sequence or the heads split over
@@ -281,21 +284,47 @@ def _xla_chunked(q, k, v, g, beta, *, chunk: int):
     return jnp.moveaxis(o, 0, 1).reshape(b, t + pad, h, -1)[:, :t]
 
 
-def kda_scan(q, k, v, g, beta, *, chunk: int = 64, mesh=None):
+def unit_rows(x):
+    """``x`` [.., K] to unit length along its last axis, float32: ``x /
+    sqrt(sum x^2 + 1e-6)`` (fla's ``l2norm``; a row of zeros stays
+    zero). The kernels' ``_unit`` is this on a head's square in VMEM."""
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                         + kernels.NORM_EPS)
+
+
+def kda_scan(q, k, v, g, beta, *, chunk: int = 64, mesh=None,
+             normalize_qk: bool = False):
     """The recurrence above over whole sequences, chunked, by the path
     ``kda_path`` names.
 
-    q, k: [batch, T, H, K]  queries (already scaled) and unit keys
+    q, k: [batch, T, H, K]  queries (already scaled) and unit keys; or,
+                            with ``normalize_qk``, both as the mixer's
+                            convolutions left them, in any dtype
     v:    [batch, T, H, V]
     g:    [batch, T, H, K]  log-decays, <= 0, float32
     beta: [batch, T, H]     step sizes, float32
     Returns ``o`` [batch, T, H, V] float32. ``T`` need not be whole
     chunks: the tail is padded with rows that neither decay nor write
     the state. ``mesh`` is the mesh the program is sharded over, if the
-    caller knows one: ``kda_path`` decides from it."""
+    caller knows one: ``kda_path`` decides from it.
+
+    ``normalize_qk`` says what the caller hands over, not how it is
+    run: ``unit_rows(q) * K^-1/2`` and ``unit_rows(k)`` are what the
+    recurrence reads either way. ``xla_chunked`` makes them here, in
+    float32 arrays under the scope ``qk_norm``; ``pallas_chunked`` hands
+    the rows to the kernels as they are, which make a head's unit square
+    in VMEM and carry ``dq, dk`` back through the norm there. The
+    recurrence itself is under the scope ``scan`` on both paths."""
     path = kda_path(q.shape, chunk, mesh, values=v.shape[-1])
     tracing.note_trace(kda_path=path, kda_chunk=chunk, kda_heads=q.shape[2],
                        kda_state=[q.shape[-1], v.shape[-1]])
     if path == "pallas_chunked":
-        return kernels.kda_scan(q, k, v, g, beta)
-    return _xla_chunked(q, k, v, g, beta, chunk=chunk)
+        with jax.named_scope("scan"):
+            return kernels.kda_scan(q, k, v, g, beta,
+                                    normalize_qk=normalize_qk)
+    if normalize_qk:
+        with jax.named_scope("qk_norm"):
+            q, k = unit_rows(q) * q.shape[-1] ** -0.5, unit_rows(k)
+    with jax.named_scope("scan"):
+        return _xla_chunked(q, k, v, g, beta, chunk=chunk)

@@ -13,6 +13,21 @@ entered each chunk (float32 ``[T / 64, H, V, K]``, alive only inside one
 mixer's backward), from which the backward kernel recomputes a chunk's
 squares, again in VMEM.
 
+**``q`` and ``k`` come as the mixer's convolutions left them**
+(``normalize_qk``, which ``ops/kda.py`` sets for the model): the
+un-normalised rows in the projections' dtype, bfloat16 in the
+Kimi-Linear cell. A cell casts a head's square to float32 as it reads
+it and makes ``unit(q) * 128^-1/2`` and ``unit(k)`` there (``_unit``:
+``x * rsqrt(sum_lanes(x * x) + 1e-6)``, one more lane reduce beside
+``B``'s diagonal); the backward kernel carries its ``dq, dk`` back
+through the same map (``_unit_back``: ``r * (d - u * sum_lanes(d *
+u))``) and writes the cotangents of the rows as they came, in their
+dtype. No float32 copy of ``q`` or ``k`` and no cotangent of one
+reaches HBM, and the ``custom_vjp`` keeps the rows as they came (134 MB
+each a layer at 16,384 x 4,096 bfloat16 where the unit float32 copies
+were 268). Without the flag ``q`` and ``k`` are taken for the scaled
+queries and unit keys themselves, float32: the tests' way in.
+
 **A grid cell is two chunks of a block of heads** (``_ROWS`` = 128
 rows; ``heads_a_cell``: four heads, or two, or one). With keys and
 values 128 wide every array a head makes is then one ``[128, 128]``
@@ -65,7 +80,9 @@ mask). The state is kept transposed, ``[V, K]``, so that what a chunk
 keeps of it (``exp`` of the chunk's whole log-decay, a channel a lane)
 scales lanes.
 
-Precision: float32 operands, float32 accumulation, float32 ``exp``,
+Precision: float32 operands, float32 accumulation, float32 ``exp`` and
+``rsqrt`` (whatever dtype ``q``, ``k`` or ``v`` is stored in, nothing
+below float32 is computed with),
 every matmul at ``Precision.HIGHEST`` (Mosaic offers ``DEFAULT`` and
 ``HIGHEST``; the XLA path runs ``HIGH``).
 
@@ -99,6 +116,8 @@ _WIDTH = 128        # keys and values: one lane tile, and == _ROWS
 _LEVELS = (1, 2, 4, 8, 16, 32)
 _PACKED = 8         # levels from here up give the MXU only their lower rows
 _F32 = jnp.float32
+NORM_EPS = 1e-6                 # under the root of a row's unit length
+_Q_SCALE = _WIDTH ** -0.5       # the unit queries' scale: head_dim^-1/2
 
 
 def shapes_ok(kd: int, vd: int, chunk: int) -> bool:
@@ -262,10 +281,35 @@ def _read(ref, heads: int):
             for i in range(heads)]
 
 
-def _cell_of(q_ref, k_ref, g_ref, v_ref, beta_ref, *, heads, keep_levels):
-    return _Cell(*(_read(ref, heads) for ref in (q_ref, k_ref, g_ref, v_ref)),
-                 [beta_ref[i] for i in range(heads)],
+def _unit(x):
+    """(``x``'s rows at unit length, the factor that brought them there
+    [rows, 1]): ``x / sqrt(sum x^2 + 1e-6)``, float32, as the XLA path's
+    ``qk_norm`` (``ops/kda.py::unit_rows``). A row of zeros stays zero."""
+    r = lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + NORM_EPS)
+    return x * r, r
+
+
+def _unit_back(d, u, r):
+    """``_unit``'s transpose: the cotangent of the rows as they came,
+    from ``d``, the unit rows' ``u``, and the factor ``r``."""
+    return r * (d - u * jnp.sum(d * u, axis=1, keepdims=True))
+
+
+def _cell_of(q_ref, k_ref, g_ref, v_ref, beta_ref, *, heads, keep_levels,
+             normalize):
+    """The cell, and (``normalize``) what the backward needs to carry
+    ``dq, dk`` back to the rows as they came: (unit q, q's factor, k's
+    factor), the unit k being the cell's own ``k``."""
+    q, k, g, v = (_read(ref, heads) for ref in (q_ref, k_ref, g_ref, v_ref))
+    norms = None
+    if normalize:
+        q_unit, q_r = zip(*_each(_unit, q))
+        k, k_r = zip(*_each(_unit, k))
+        q = [u * _Q_SCALE for u in q_unit]
+        norms = q_unit, q_r, k_r
+    cell = _Cell(q, k, g, v, [beta_ref[i] for i in range(heads)],
                  keep_levels=keep_levels)
+    return cell, norms
 
 
 def _write(ref, arrays):
@@ -278,7 +322,7 @@ def _write(ref, arrays):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, o_ref, *rest,
-                heads: int, keep_states: bool):
+                heads: int, keep_states: bool, normalize: bool):
     enter_ref = rest[0] if keep_states else None
     state_ref = rest[-1]
 
@@ -286,8 +330,8 @@ def _fwd_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, o_ref, *rest,
     def _first():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    cell = _cell_of(q_ref, k_ref, g_ref, v_ref, beta_ref, heads=heads,
-                    keep_levels=False)
+    cell, _ = _cell_of(q_ref, k_ref, g_ref, v_ref, beta_ref, heads=heads,
+                       keep_levels=False, normalize=normalize)
     state = [state_ref[i] for i in range(heads)]        # [V, K] a head
     read, U = [], []
     for c in range(2):
@@ -344,18 +388,21 @@ def _state_scratch(heads: int):
     return pltpu.VMEM((heads, _WIDTH, _WIDTH), _F32)
 
 
-@functools.partial(jax.jit, static_argnames=("keep_states", "interpret"))
-def _kda_fwd(q, k, g, v, beta, *, keep_states, interpret):
+@functools.partial(jax.jit, static_argnames=("keep_states", "normalize",
+                                             "interpret"))
+def _kda_fwd(q, k, g, v, beta, *, keep_states, normalize, interpret):
     """``o`` [B, T, H*V] float32 and, if asked, the state entering each
-    chunk, transposed ([B, T / 64, H, V, K] float32). q, k, g [B, T,
-    H*K] float32; v [B, T, H*V]; beta [B, H, T / 128, 1, 128] float32;
+    chunk, transposed ([B, T / 64, H, V, K] float32). g [B, T, H*K]
+    float32; q, k like it, or (``normalize``) the un-normalised rows in
+    any dtype; v [B, T, H*V]; beta [B, H, T / 128, 1, 128] float32;
     ``T`` whole cells. Jitted so that a model's layers share one trace
     and one Mosaic lowering."""
     b_, t, hk = q.shape
     h, steps = hk // _WIDTH, t // _ROWS
     grid, per, rows, beta_s, enter = _specs(b_, h, steps, backward=False)
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=per, keep_states=keep_states),
+        functools.partial(_fwd_kernel, heads=per, keep_states=keep_states,
+                          normalize=normalize),
         grid=grid,
         in_specs=[rows, rows, rows, rows, beta_s],
         out_specs=[rows] + [enter] * keep_states,
@@ -375,7 +422,7 @@ def _kda_fwd(q, k, g, v, beta, *, keep_states, interpret):
 
 def _bwd_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, enter_ref, do_ref,
                 dq_ref, dk_ref, dg_ref, dv_ref, dbeta_ref, dstate_ref, *,
-                heads: int):
+                heads: int, normalize: bool):
     """One cell, the cells walked last to first and the cell's second
     chunk before its first. ``dstate_ref`` carries the cotangent of the
     state *leaving* the chunk at hand, transposed like the state."""
@@ -383,8 +430,8 @@ def _bwd_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, enter_ref, do_ref,
     def _first():
         dstate_ref[...] = jnp.zeros_like(dstate_ref)
 
-    cell = _cell_of(q_ref, k_ref, g_ref, v_ref, beta_ref, heads=heads,
-                    keep_levels=True)
+    cell, norms = _cell_of(q_ref, k_ref, g_ref, v_ref, beta_ref, heads=heads,
+                           keep_levels=True, normalize=normalize)
     q, k, zero = cell.q, cell.k, cell.zero
     do = _read(do_ref, heads)
     entered = [[enter_ref[c, i] for c in range(2)]      # [V, K] a chunk
@@ -474,6 +521,11 @@ def _bwd_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, enter_ref, do_ref,
         dG = _each(lambda dG, dqs, qs, dks, cols, ks:
                    dG + dqs * qs + (dks - cols) * ks,
                    dG, dqs, qs, dks, as_cols, ks)
+    if normalize:       # back to the rows as they came, still in VMEM
+        q_unit, q_r, k_r = norms
+        dq = _each(lambda dq, u, r: _unit_back(dq * _Q_SCALE, u, r),
+                   dq, q_unit, q_r)
+        dk = _each(_unit_back, dk, k, k_r)
     _write(dq_ref, dq)
     _write(dk_ref, dk)
     _write(dg_ref, _each(lambda dG: _dot(cell.ones, dG, _TN), dG))
@@ -482,21 +534,21 @@ def _bwd_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, enter_ref, do_ref,
                                keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _kda_bwd(q, k, g, v, beta, entering, do, *, interpret):
-    """(dq, dk, dg [B, T, H*K] float32, dv like v, dbeta like beta);
-    jitted for the reason ``_kda_fwd`` is."""
+@functools.partial(jax.jit, static_argnames=("normalize", "interpret"))
+def _kda_bwd(q, k, g, v, beta, entering, do, *, normalize, interpret):
+    """(dq like q, dk like k, dg [B, T, H*K] float32, dv like v, dbeta
+    like beta); jitted for the reason ``_kda_fwd`` is."""
     b_, t, hk = q.shape
     h, steps = hk // _WIDTH, t // _ROWS
     grid, per, rows, beta_s, enter = _specs(b_, h, steps, backward=True)
-    like = jax.ShapeDtypeStruct(q.shape, _F32)
+    dq, dk, dg, dv = (jax.ShapeDtypeStruct(z.shape, z.dtype)
+                      for z in (q, k, g, v))
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=per),
+        functools.partial(_bwd_kernel, heads=per, normalize=normalize),
         grid=grid,
         in_specs=[rows, rows, rows, rows, beta_s, enter, rows],
         out_specs=[rows, rows, rows, rows, beta_s],
-        out_shape=[like, like, like, jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct(beta.shape, _F32)],
+        out_shape=[dq, dk, dg, dv, jax.ShapeDtypeStruct(beta.shape, _F32)],
         scratch_shapes=[_state_scratch(per)],
         compiler_params=_compiler_params(),
         interpret=interpret,
@@ -507,31 +559,38 @@ def _kda_bwd(q, k, g, v, beta, entering, do, *, interpret):
 # public API with custom VJP
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kda_core(q, k, g, v, beta, interpret: bool):
-    return _kda_fwd(q, k, g, v, beta, keep_states=False,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _kda_core(q, k, g, v, beta, normalize: bool, interpret: bool):
+    return _kda_fwd(q, k, g, v, beta, keep_states=False, normalize=normalize,
                     interpret=interpret)[0]
 
 
-def _kda_core_fwd(q, k, g, v, beta, interpret):
+def _kda_core_fwd(q, k, g, v, beta, normalize, interpret):
     o, entering = _kda_fwd(q, k, g, v, beta, keep_states=True,
-                           interpret=interpret)
+                           normalize=normalize, interpret=interpret)
     return o, (q, k, g, v, beta, entering)
 
 
-def _kda_core_bwd(interpret, res, do):
-    return tuple(_kda_bwd(*res, do.astype(_F32), interpret=interpret))
+def _kda_core_bwd(normalize, interpret, res, do):
+    return tuple(_kda_bwd(*res, do.astype(_F32), normalize=normalize,
+                          interpret=interpret))
 
 
 _kda_core.defvjp(_kda_core_fwd, _kda_core_bwd)
 
 
-def kda_scan(q, k, v, g, beta, *, interpret: bool = False):
+def kda_scan(q, k, v, g, beta, *, normalize_qk: bool = False,
+             interpret: bool = False):
     """``ops/kda.py::kda_scan`` on the kernels: the same arguments (q, k,
     g [b, T, H, 128] float32; v [b, T, H, 128]; beta [b, T, H]), the
     same result ``o`` [b, T, H, 128] float32, differentiable in all
-    five. ``T`` need not be whole cells: the tail is padded with rows
-    that neither decay nor write the state."""
+    five. With ``normalize_qk`` (``ops/kda.py`` sets it) ``q`` and ``k``
+    are the un-normalised rows in whatever dtype they have: the kernels
+    read them so, make ``unit(q) * 128^-1/2`` and ``unit(k)`` in VMEM in
+    float32, and return ``dq, dk`` of the rows as they came, in their
+    dtype. ``T`` need not be whole cells: the tail is padded with rows
+    that neither decay nor write the state (a row of zeros stays zero
+    under the norm)."""
     b_, t, h, kd = q.shape
     if not shapes_ok(kd, v.shape[-1], CHUNK):
         raise ValueError(f"the kernels do not tile keys {kd}, values "
@@ -544,6 +603,8 @@ def kda_scan(q, k, v, g, beta, *, interpret: bool = False):
 
     steps = jnp.swapaxes(rows(beta.astype(_F32)), 1, 2).reshape(
         b_, h, (t + pad) // _ROWS, 1, _ROWS)
-    o = _kda_core(rows(q.astype(_F32)), rows(k.astype(_F32)),
-                  rows(g.astype(_F32)), rows(v), steps, interpret)
+    if not normalize_qk:    # unit rows are float32 rows
+        q, k = q.astype(_F32), k.astype(_F32)
+    o = _kda_core(rows(q), rows(k), rows(g.astype(_F32)), rows(v), steps,
+                  normalize_qk, interpret)
     return o[:, :t].reshape(b_, t, h, -1)

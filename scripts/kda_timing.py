@@ -4,7 +4,15 @@ variants of ``ops/kda.py``'s two knobs (rows a recomputed group, matmul
 precision) and the kernel pair (``pallas``, ``ops/pallas/kda_scan.py``);
 each variant's distance from the first one's numbers beside its time.
 
+A variant ``rows:<name>`` is norm + recurrence from the bfloat16 rows a
+mixer's convolutions leave, differentiated down to those rows:
+``ops/kda.py::unit_rows`` in XLA in front of ``<name>``, or, for
+``rows:pallas_fused``, the norm in the kernels' cells (``normalize_qk``).
+Their distances are from the first ``rows:`` variant's numbers.
+
     python3 scripts/kda_timing.py [--rows 16384] [--variants a,b,...]
+    python3 scripts/kda_timing.py --variants \
+        rows:g512_highest,rows:pallas,rows:pallas_fused
 """
 
 from __future__ import annotations
@@ -25,6 +33,8 @@ def main() -> None:
     ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--variants", default="")
     ap.add_argument("--reps", type=int, default=3)
+    ap.add_argument("--interpret", action="store_true",
+                    help="the kernels interpreted: a rehearsal on the CPU")
     args = ap.parse_args()
 
     import jax
@@ -44,34 +54,43 @@ def main() -> None:
         "g1024_high": (1024, P.HIGH),
         "g512_default": (512, P.DEFAULT),
         "pallas": None,
+        "pallas_fused": None,       # ``rows:`` only: the norm in the kernels
     }
-    names = [v for v in args.variants.split(",") if v] or list(variants)
+    names = [v for v in args.variants.split(",") if v] or [
+        v for v in variants if v != "pallas_fused"]
     t, h, k = args.rows, args.heads, 128
     rng = np.random.default_rng(0)
-
-    def unit(x):
-        return x / np.linalg.norm(x, axis=-1, keepdims=True)
-    q = jnp.asarray(unit(rng.normal(size=(1, t, h, k))) * k ** -0.5,
-                    jnp.float32)
-    kk = jnp.asarray(unit(rng.normal(size=(1, t, h, k))), jnp.float32)
+    # q and k as the convolutions leave them, and their unit rows
+    rows = [jnp.asarray(rng.normal(size=(1, t, h, k)) * 0.3, jnp.bfloat16)
+            for _ in range(2)]
+    units = [jax.jit(kda.unit_rows)(z) for z in rows]
+    units[0] = units[0] * k ** -0.5
     v = jnp.asarray(rng.normal(size=(1, t, h, k)) * 0.3, jnp.bfloat16)
     g = jnp.asarray(-0.05 * np.log1p(np.exp(rng.normal(size=(1, t, h, k)))),
                     jnp.float32)
     beta = jnp.asarray(1 / (1 + np.exp(-rng.normal(size=(1, t, h)))),
                        jnp.float32)
     w = jnp.asarray(rng.normal(size=(1, t, h, k)), jnp.float32)
-    base = None
+    bases = {}
     for name in names:
-        if variants[name] is None:
-            scan = kernels.kda_scan
+        from_rows, _, variant = name.rpartition(":")
+        fused = variant == "pallas_fused"
+        if fused and not from_rows:
+            ap.error("pallas_fused takes the rows: rows:pallas_fused")
+        if variants[variant] is None:
+            scan = functools.partial(kernels.kda_scan, normalize_qk=fused,
+                                     interpret=args.interpret)
         else:
-            kda.GROUP_ROWS, precision = variants[name]
+            kda.GROUP_ROWS, precision = variants[variant]
             kda._matmul = functools.partial(
                 jnp.einsum, precision=precision,
                 preferred_element_type=jnp.float32)
             scan = functools.partial(kda._xla_chunked, chunk=64)
+        q, kk = rows if from_rows else units
 
         def loss(q, kk, v, g, beta):
+            if from_rows and not fused:
+                q, kk = kda.unit_rows(q) * k ** -0.5, kda.unit_rows(kk)
             o = scan(q, kk, v, g, beta)
             return jnp.sum(o * w), jnp.sqrt(jnp.mean(o * o))
 
@@ -94,7 +113,7 @@ def main() -> None:
             f"d{n}": float(jnp.sqrt(jnp.sum(jnp.square(
                 x.astype(jnp.float32)))))
             for n, x in zip(("q", "k", "v", "g", "beta"), grads)}}
-        base = base or nums
+        base = bases.setdefault(from_rows, nums)
         print(json.dumps({
             "variant": name, "device": jax.devices()[0].device_kind,
             "forward_ms": timed(fwd), "both_ms": timed(both),

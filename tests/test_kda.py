@@ -2,6 +2,8 @@
 a rearrangement of, token by token: outputs and the gradients of every
 operand."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -214,6 +216,79 @@ def test_the_kernels_take_bfloat16_values_and_return_their_cotangent_so():
     close(dv.astype(jnp.float32), want.astype(jnp.float32), rtol=1e-2)
 
 
+# --- un-normalised q and k: the norm in the kernels' cells ------------------
+
+def raw_operands(seed, b, t, h, zero_rows=()):
+    """``operands`` at the kernels' widths with q and k as a mixer's
+    convolutions leave them: no unit length, no scale, each head its own
+    size; ``zero_rows``: rows of q and of k that are all zeros."""
+    _, _, v, g, beta = operands(seed, b, t, h, 128, 128)
+    rng = np.random.default_rng(100 + seed)
+    q, k = (rng.normal(size=(b, t, h, 128)) * rng.uniform(
+        0.1, 3.0, size=(1, 1, h, 1)) for _ in range(2))
+    for row in zero_rows:
+        q[:, row], k[:, row] = 0.0, 0.0
+    return jnp.asarray(q, jnp.float32), jnp.asarray(k, jnp.float32), v, g, beta
+
+
+def normed_three_ways(args):
+    """Of the kernels given the raw rows, of ``unit_rows`` + the XLA
+    chunked form (``kda_scan``'s own other path), of ``unit_rows`` + the
+    recurrence."""
+    def by_row(q, k, *rest):
+        return recurrence(kda.unit_rows(q) * q.shape[-1] ** -0.5,
+                          kda.unit_rows(k), *rest)
+    return outputs_and_gradients(
+        args,
+        lambda *a: kernels.kda_scan(*a, normalize_qk=True, interpret=True),
+        lambda *a: kda.kda_scan(*a, chunk=64, normalize_qk=True), by_row)
+
+
+@pytest.mark.parametrize("t, heads, zero_rows", [
+    (128, 1, ()), (128, 3, ()), (150, 1, ()), (150, 3, ()),
+    (200, 2, ()),               # a cell and 72 rows: the tail is padded
+    (128, 4, (5, 70)),          # a row of zeros in each chunk
+])
+def test_the_kernels_bring_raw_q_and_k_to_unit_length_themselves(
+        t, heads, zero_rows):
+    """``o`` and all five cotangents, ``dq`` and ``dk`` those of the
+    rows as they came. A row of zeros has the unit row zero and a
+    cotangent ``rsqrt(1e-6)`` times the unit row's: compared apart, so
+    that it does not set the scale for the others."""
+    args = raw_operands(20 + heads, 1, t, heads, zero_rows)
+    got, xla, ref = normed_three_ways(args)
+    if zero_rows:
+        rows = np.asarray(zero_rows)
+        assert not np.asarray(kda.unit_rows(args[1]))[:, rows].any()
+
+        def apart(result):
+            o, (dq, dk, *others) = result
+            live = jnp.ones((t,), bool).at[rows].set(False)[:, None, None]
+            return o, (*(jnp.where(live, z, 0.0) for z in (dq, dk)),
+                       dq[:, rows], dk[:, rows], *others)
+        got, xla, ref = apart(got), apart(xla), apart(ref)
+        assert float(jnp.max(jnp.abs(got[1][2]))) > 0
+    all_close(got, xla, ref)
+
+
+def test_bfloat16_q_and_k_come_back_as_bfloat16_cotangents():
+    """As the cell's convolutions hand them over: the kernels read the
+    bfloat16 rows, and the cotangents of those rows leave in bfloat16
+    (XLA's transpose of ``x.astype(float32)`` rounds at the same
+    point)."""
+    q, k, v, g, beta = raw_operands(7, 1, 128, 2)
+    q, k = q.astype(jnp.bfloat16), k.astype(jnp.bfloat16)
+
+    def grads(fn):
+        return jax.grad(lambda q, k: jnp.sum(jnp.sin(
+            fn(q, k, v, g, beta, normalize_qk=True))), argnums=(0, 1))(q, k)
+    got = grads(functools.partial(kernels.kda_scan, interpret=True))
+    want = grads(functools.partial(kda.kda_scan, chunk=64))
+    for z, ref in zip(got, want):
+        assert z.dtype == ref.dtype == jnp.bfloat16
+        close(z.astype(jnp.float32), ref.astype(jnp.float32), rtol=1e-2)
+
+
 def _equations(jaxpr):
     for eqn in jaxpr.eqns:
         yield eqn
@@ -224,14 +299,32 @@ def _equations(jaxpr):
                     yield from _equations(sub)
 
 
-def test_every_product_and_exp_in_both_kernels_is_float32():
+def _behind(eqn, made_by):
+    """The equations behind ``eqn``, nearest first: each one's first
+    operand that an equation made, back to a load."""
+    out = []
+    while True:
+        makers = [made_by[id(v)] for v in eqn.invars if id(v) in made_by]
+        if not makers:
+            return out
+        eqn = makers[0]
+        out.append(eqn)
+
+
+@pytest.mark.parametrize("normalize", [False, True])
+def test_every_product_and_exp_in_both_kernels_is_float32(normalize):
     """No bfloat16 operand inside the recurrence, no matmul below
     ``HIGHEST`` (Mosaic has nothing between it and one bfloat16 pass),
-    read from the kernels' own jaxprs, both passes."""
+    read from the kernels' own jaxprs, both passes. Given raw bfloat16
+    ``q, k`` (``normalize``) the norm is there too, in float32: a square,
+    its sum along the lanes, plus 1e-6, ``rsqrt``; and the only bfloat16
+    values are the operands as loaded and the cotangents as stored."""
     args = operands(0, 1, 128, 1, 128, 128)
+    if normalize:
+        args = tuple(z.astype(jnp.bfloat16) for z in args[:2]) + args[2:]
     traced = jax.make_jaxpr(jax.grad(
-        lambda *a: jnp.sum(kernels.kda_scan(*a)), argnums=(0, 1, 2, 3, 4)))(
-            *args)
+        lambda *a: jnp.sum(kernels.kda_scan(*a, normalize_qk=normalize)),
+        argnums=(0, 1, 2, 3, 4)))(*args)
     calls = [e for e in _equations(traced.jaxpr)
              if e.primitive.name == "pallas_call"]
     assert len(calls) == 2                      # forward, backward
@@ -247,9 +340,39 @@ def test_every_product_and_exp_in_both_kernels_is_float32():
                 p == jax.lax.Precision.HIGHEST for p in e.params["precision"])
         for e in exps:
             assert e.invars[0].aval.dtype == jnp.float32
-        assert not any(v.aval.dtype == jnp.bfloat16
-                       for e in inside for v in e.outvars
-                       if hasattr(v.aval, "dtype"))
+        half = [(e, v) for e in inside for v in e.outvars
+                if getattr(v.aval, "dtype", None) == jnp.bfloat16]
+        roots = [e for e in inside if e.primitive.name == "rsqrt"]
+        if not normalize:
+            assert not half and not roots
+            continue
+        made_by = {id(v): e for e in inside for v in e.outvars}
+        assert len(roots) == 2                      # q's and k's, one head
+        for e in roots:
+            assert e.invars[0].aval.dtype == e.outvars[0].aval.dtype \
+                == jnp.float32 and e.outvars[0].aval.shape == (128, 1)
+            before = _behind(e, made_by)
+            names = [b.primitive.name for b in before]
+            square = names.index("mul")
+            assert names[0] == "add" and "reduce_sum" in names[1:square]
+            assert names[square + 1:] == ["convert_element_type", "get"]
+            assert all(b.outvars[0].aval.dtype == jnp.float32
+                       for b in before[:-1])
+            assert [float(v.val) for v in before[0].invars
+                    if hasattr(v, "val")] == [pytest.approx(1e-6)]
+            assert before[square].invars[0] is before[square].invars[1]
+        # bfloat16: loaded and cast up at once, or cast down to be stored
+        # (a store hands back what it overwrote, which nothing reads)
+        used_by = {}
+        for e in inside:
+            for v in e.invars:
+                used_by.setdefault(id(v), []).append(e.primitive.name)
+        kinds = sorted((e.primitive.name, *used_by.get(id(v), []))
+                       for e, v in half)
+        loaded = [("get", "convert_element_type")] * 2          # q, k
+        stored = [("convert_element_type", "swap")] * 2 + [("swap",)] * 2
+        assert kinds == (loaded if call is calls[0]
+                         else sorted(stored + loaded))
 
 
 @pytest.mark.parametrize("case, shape, chunk, want", [

@@ -35,12 +35,14 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     of 256 experts held, 20,480 rows of each table, the blocks
     recomputed; adamw with a bf16 first moment) at 1 x 16,384 tokens:
     arguments + temporaries + unaliased outputs stay under the 14.5 GB
-    that leave room for the device's own reserve, the MLA layer's
+    that leave room for the device's own reserve (12.13 GB at PR 47,
+    12.92 before the norm of q and k moved into the kernels), the MLA layer's
     attention is the latent kernel pair with dq's 16,384 rows resident
     and no rotation, the KDA layers run the recurrence's kernel pair
     (the forward twice a layer, the backward once, all under ``scan``
-    and no other custom call under ``kda``), and no ``[T, T]`` array
-    exists."""
+    and no other custom call under ``kda``), which take ``q`` and ``k``
+    as the convolutions left them (no operation under ``kda/qk_norm``:
+    the scope is the XLA path's), and no ``[T, T]`` array exists."""
     import re
 
     import optax
@@ -121,5 +123,11 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert all(kind in ("_kda_fwd", "_kda_bwd") and re.search(
         r"/kda/(checkpoint/|rematted_computation/)*scan/jit", line)
         for kind, line in under_kda)
+    # the kernels bring q and k to unit length in their cells, from the
+    # convolutions' bfloat16 rows, and return those rows' cotangents
+    assert "qk_norm" not in text
+    for kind, line in under_kda:
+        rows = re.findall(r"(\w+)\[1,16384,4096\]", line)
+        assert rows.count("bf16") == (3 if kind == "_kda_fwd" else 6), line
     assert "/attn/rope/" not in text and "/attn/q_down/" not in text
     assert "16384,16384" not in text
