@@ -25,8 +25,14 @@ linear-attention decoder (three Kimi Delta Attention layers, a chunked
 gated delta rule through ``ops/kda.py``, to one latent-attention layer
 with no query latent and no positions through ``ops/mla.py``, JoyAI's
 routed layer behind a leading dense one); its config expresses
-Kimi-Linear-48B-A3B-Instruct. ``MoETransformer`` is the
-older top-1, capacity-dropping switch model
+Kimi-Linear-48B-A3B-Instruct. ``Phi4Flash`` is the
+decoder-hybrid-decoder (Mamba-1 scans through ``ops/ssm.py::mamba1_scan``
+and differential attention through
+``ops/attention.py::differential_attention``, windowed, in a
+self-decoder; gated memory units and cross attention that read one
+earlier layer's scan and one's K and V in a cross-decoder; LayerNorm, a
+tied table); its config expresses Phi-4-mini-flash-reasoning.
+``MoETransformer`` is the older top-1, capacity-dropping switch model
 on GPT-2 blocks, which goes when the dropless path runs under ``ep``
 (ROADMAP C5)."""
 
@@ -36,6 +42,7 @@ from ray_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
 from ray_tpu.models.llama import Llama, LlamaConfig
 from ray_tpu.models.moe import MoEConfig, MoETransformer
 from ray_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+from ray_tpu.models.phi4flash import Phi4Flash, Phi4FlashConfig
 from ray_tpu.models.resnet import ResNet, ResNet50Config
 from ray_tpu.models.smallthinker import SmallThinker, SmallThinkerConfig
 from ray_tpu.models.vit import ViT, ViTConfig
@@ -45,6 +52,7 @@ __all__ = [
     "GPT2", "GPT2Config", "JoyAI", "JoyAIConfig", "KimiLinear",
     "KimiLinearConfig", "Llama", "LlamaConfig",
     "MoETransformer", "MoEConfig", "NemotronH", "NemotronHConfig",
+    "Phi4Flash", "Phi4FlashConfig",
     "ResNet", "ResNet50Config", "SmallThinker", "SmallThinkerConfig", "ViT",
     "ViTConfig", "Zaya", "ZayaConfig",
 ]

@@ -13,6 +13,11 @@
   dispatch (``mla_path``) to the kernels of the same file. With a
   ``window`` a row sees its last ``window`` keys only (a sliding-window
   layer): the kernel's grids then run over the band's blocks alone.
+- ``differential_attention``: two softmax maps a pair of heads,
+  subtracted (Phi-4-mini-flash's ``S``, ``F`` and ``X`` layers): the four
+  equal-width products ``A1 v1, A1 v2, A2 v1, A2 v2`` as one call of the
+  function above over twice the heads, so the flash kernels and their
+  window run it; then the ``lambda`` combination and the pair's RMSNorm.
 - ``ring_attention``: sequence-parallel causal attention over an ICI
   ring. The reference has NO sequence parallelism in-tree (SURVEY.md
   §5.7); here it is first-class: K/V blocks rotate around the ``sp``
@@ -106,6 +111,72 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return jax.nn.dot_product_attention(
         q, k, v, scale=scale, is_causal=True,
         local_window_size=(window - 1, 0))
+
+
+def _to_query_pairs(x, rep: int):
+    """Key/value pairs [B, T, G, ...] to the query pairs that read them,
+    [B, T, G * rep, ...]: query pair ``j`` reads pair ``j // rep``."""
+    return jnp.repeat(x, rep, axis=2)
+
+
+def differential_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                           lam, lam_init: float, subln_scale: jax.Array,
+                           *, window: int | None = None, mesh=None,
+                           eps: float = 1e-5,
+                           scope: str = "core") -> jax.Array:
+    """Differential attention (arXiv:2410.05258 as Phi-4-mini-flash
+    runs it): q [B, T, 2P, D] is ``P`` pairs of adjacent heads ``(q1_j,
+    q2_j)``, k and v [B, T, 2G, D] ``G`` pairs ``(k1_i, k2_i)``, ``(v1_i,
+    v2_i)``; query pair ``j`` reads key/value pair ``j // (P // G)``::
+
+        A1 = softmax(q1 k1^T / sqrt(D) + mask)   A2 = softmax(q2 k2^T ..)
+        o_j = A1 [v1 | v2] - lam * A2 [v1 | v2]                  (2D wide)
+        o_j <- RMSNorm_2D(o_j) * subln_scale * (1 - lam_init)
+
+    ``lam`` is the layer's scalar (float32, traced), ``subln_scale`` its
+    one 2D-wide scale; the mask is causal, under ``window`` a row's last
+    ``window`` keys. Returns [B, T, P * 2D] in q's dtype, the output
+    projection's operand.
+
+    **Four equal-width products, one call**: the operands are written
+    out as ``4P`` heads of ``D``, a pair's ``[q1 q1 q2 q2]`` against
+    ``[k1 k1 k2 k2]`` and ``[v1 v2 v1 v2]``, so that ``causal_attention``
+    (the flash kernels on a TPU, their band under a window) serves
+    them and a pair's two results are each one 2D-wide block of its
+    output; each score map is then made twice. One call with D-wide keys
+    against 2D-wide values would make it once: the flash file has no
+    such kernel (ROADMAP B2). The trace's notes say so
+    (``attn_products``, ``attn_calls``, ``attn_pairs``). The copies sit
+    under the scope ``repeat``, the call under ``scope``, the
+    combination and the norm under ``diff``. With a ``mesh`` the call
+    goes through ``make_sharded_causal_attention``."""
+    from ray_tpu.util import tracing
+    b, t, heads, d = q.shape
+    pairs, kv_pairs = heads // 2, k.shape[2] // 2
+    if heads % 2 or k.shape[2] % 2 or pairs % kv_pairs or k.shape != v.shape:
+        raise ValueError(f"differential attention pairs adjacent heads: "
+                         f"{heads} query and {k.shape[2]} key/value heads")
+    tracing.note_trace(attn_pairs=[pairs, kv_pairs], attn_products=4,
+                       attn_calls=1)
+    attend = (functools.partial(causal_attention, window=window)
+              if mesh is None
+              else make_sharded_causal_attention(mesh, window=window))
+    rep = pairs // kv_pairs
+    with jax.named_scope("repeat"):
+        def kv(x, twice):       # [B, T, 2G, D] -> [B, T, 4P, D]
+            x = twice(x.reshape(b, t, kv_pairs, 2, d))
+            return _to_query_pairs(x, rep).reshape(b, t, 4 * pairs, d)
+        q4 = jnp.repeat(q, 2, axis=2)                       # q1 q1 q2 q2
+        k4 = kv(k, lambda x: jnp.repeat(x, 2, axis=3))      # k1 k1 k2 k2
+        v4 = kv(v, lambda x: jnp.concatenate([x, x], 3))    # v1 v2 v1 v2
+    with jax.named_scope(scope):
+        out = attend(q4, k4, v4)
+    with jax.named_scope("diff"):
+        out = out.reshape(b, t, pairs, 2, 2 * d).astype(jnp.float32)
+        o = out[..., 0, :] - lam * out[..., 1, :]
+        o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+        o = o * (subln_scale.astype(jnp.float32) * (1.0 - lam_init))
+        return o.astype(q.dtype).reshape(b, t, pairs * 2 * d)
 
 
 def _block_attend(q, k, v, acc, row_max, row_sum, mask_mode, scale):

@@ -1,0 +1,121 @@
+"""The Phi-4-mini-flash cell's step compiles for the real chip, with no
+chip here (as ``test_tpu_compile_kimi_linear.py``: the TPU compiler for a
+described v5e; nothing runs, so nothing here is a result or a time)."""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, no description
+        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def test_the_real_size_step_compiles_inside_the_chips_memory(
+        v5e, monkeypatch):
+    """The cell's step as the builder makes it (the rule at eight
+    layers, ``MSMSMFGX``, 25,088 rows of the tied table, the blocks
+    recomputed; adamw with a bf16 first moment) at 1 x 4,096 tokens:
+    arguments + temporaries + unaliased outputs stay under the chip's
+    15.75 GB (12.87 GB at PR 48); every attention layer's four products
+    are one call of the multi-block flash kernels over 80 heads of 64 in
+    the projections' own layout, the windowed layers' under the band of
+    a 512-key window in blocks of 1,024 (7 block pairs a head where the
+    causal grid walks 10); the scans run the chunked XLA path (no custom
+    call under ``mamba``) and no ``[T, T]`` array exists."""
+    import re
+
+    import optax
+
+    from ray_tpu import train
+    from ray_tpu.models.phi4flash import (
+        Phi4Flash,
+        Phi4FlashConfig,
+        phi4flash_loss_fn,
+    )
+    from ray_tpu.util import tracing
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
+    one = SingleDeviceSharding(v5e[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = Phi4FlashConfig.phi_4_mini_flash_reasoning(
+        n_layer=8, vocab_size=25088, remat=True)
+    model = Phi4Flash(cfg)
+    opt = optax.chain(
+        optax.clip_by_global_norm(1.0),
+        optax.adamw(2e-5, b1=0.9, b2=0.95, weight_decay=0.1,
+                    mu_dtype=jnp.bfloat16))
+    step = train.make_train_step(
+        phi4flash_loss_fn(model, ce_chunk=2048), opt, grad_groups={
+            "grad_norm_mamba_ssm": "^h_[0-9]+/mamba/",
+            "grad_norm_attn_diff":
+            "^h_[0-9]+/attn/(lambda_[qk][12]|subln|out/kernel)$",
+            "grad_norm_yoco_kv": "^h_5/attn/qkv/kernel$"})
+    state = jax.tree.map(
+        lambda z: arg(z.shape, z.dtype),
+        jax.eval_shape(lambda: train.init_train_state(
+            model.init_params(jax.random.key(0)), opt, None)))
+    batch = {k: arg((1, cfg.seq_len), jnp.int32)
+             for k in ("tokens", "targets")}
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    compiled = step.lower(state, batch).compile()
+    assert notes["attn_kind"] == "differential"
+    assert notes["layer_pattern"] == "MSMSMFGX"
+    assert notes["blocks_remat"] is True and notes["attn_window"] == 512
+    assert notes["ssm_kind"] == "mamba1" and notes["ssm_tokens"] == 4096
+    assert (notes["ssm_inner"], notes["ssm_state"], notes["ssm_dt_rank"],
+            notes["ssm_chunk"]) == (5120, 16, 160, 4)
+    assert notes["ssm_path"] == "xla_chunked"
+    assert notes["attn_pairs"] == [20, 10] and notes["attn_products"] == 4
+    assert notes["attn_calls"] == 1
+    assert (notes["yoco_memory_layer"], notes["yoco_kv_layer"]) == (4, 5)
+    # 80 heads of 64, two a 128-lane block of the projections' layout
+    assert notes["flash_layout"] == "bthd"
+    assert notes["flash_lanes_per_block"] == 128
+    assert notes["flash_path"] == "multi_block"
+    assert notes["flash_window"] == 512 and notes["flash_band_blocks"] == 7
+    assert notes["flash_bwd_resident_rows"] == 4096
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + max(0, m.output_size_in_bytes - m.alias_size_in_bytes))
+    print(f"program {total / 1e9:.2f} GB: arguments "
+          f"{m.argument_size_in_bytes / 1e9:.2f}, temporaries "
+          f"{m.temp_size_in_bytes / 1e9:.2f}")
+    assert m.argument_size_in_bytes == pytest.approx(
+        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
+    assert 4e9 < total <= 15.75e9
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # four attention layers: the forward kernel twice (the block is
+    # recomputed), the backward once, each over [1, 4096, 80 * 64]
+    assert len(calls) == 4 * 3
+    assert all(re.search(r"/h_[1357]/attn/(window|core|cross)/", line)
+               for line in calls)
+    assert sum("/attn/window/" in line for line in calls) == 2 * 3
+    assert sum("/attn/cross/" in line for line in calls) == 3
+    assert all("bf16[1,4096,5120]" in line for line in calls)
+    assert not any("/mamba/" in line or "/gmu/" in line for line in calls)
+    assert "4096,4096" not in text
