@@ -97,7 +97,7 @@ class Phi4FlashConfig:
     ssm_state: int = 16
     conv_kernel: int = 4
     dt_rank: int = 160                  # ceil(n_embd / 16)
-    ssm_chunk: int = 4                  # rows a recomputed chunk of the scan
+    ssm_chunk: int = 4                  # rows a chunk of the scan's XLA path
     time_step_min: float = 0.001
     time_step_max: float = 0.1
     time_step_floor: float = 1e-4

@@ -68,10 +68,10 @@ channel *and* by state, ``exp(dt_t[c] * A[c, n])`` over a ``[C, N]``
 state, with one ``B_t``, ``C_t`` for all channels. No matmul form
 covers it (the duality above needs one scalar decay a head), so
 neither ``_ssd`` nor the kernels of ``ops/pallas/ssd_scan.py`` can run
-it: it is an associative scan inside a chunk and a ``lax.scan`` over
-the chunks, float32 elementwise work throughout (``xla_chunked``, the
-one path ``mamba1_path`` names today). It takes the convolution from
-here as it is, with its bias.
+it: float32 elementwise work throughout, in a kernel pair that keeps
+the state in VMEM (``pallas_chunked``, ``ops/pallas/mamba1_scan.py``)
+or an associative scan a chunk under a ``lax.scan`` (``xla_chunked``),
+as ``mamba1_path`` decides. It takes the convolution with its bias.
 """
 
 from __future__ import annotations
@@ -285,11 +285,17 @@ def sigmoid_gated_head_rms_norm(o, gate, scale, heads: int, eps: float):
     return (jax.nn.sigmoid(gate.astype(jnp.float32)) * x).astype(gate.dtype)
 
 
-def mamba1_path(shape, chunk: int, mesh=None) -> str:
-    """Which scan ``mamba1_scan`` compiles for ``x`` [b, T, C] at this
-    chunk on this mesh: ``xla_chunked``, everywhere (a kernel would be
-    a second name here). Raises where the program spans chips in a way
-    that would split a sequence or its channels."""
+def mamba1_path(shape, states: int, chunk: int, mesh=None) -> str:
+    """Which scan ``mamba1_scan`` compiles for ``x`` [b, T, C] with
+    ``states`` states a channel on this mesh: ``pallas_chunked`` (the
+    kernels of ``ops/pallas/mamba1_scan.py``) on a TPU where they tile
+    the shapes and the program is one device's
+    (``_kernel_batch_axes(..) == ()``: no cell runs this scan on a mesh,
+    so ``ssd_scan``'s ``shard_map`` over the batch's axes is not carried
+    over), else ``xla_chunked`` at this ``chunk``, which is that path's
+    parameter alone. Raises where the program spans chips in a way that
+    would split a sequence or its channels."""
+    from ray_tpu.ops.pallas import mamba1_scan as kernels
     from ray_tpu.parallel.mesh import AXIS_SP, AXIS_TP
     if mesh is not None:
         for axis, what in ((AXIS_SP, "the sequence split over chips (a "
@@ -302,6 +308,10 @@ def mamba1_path(shape, chunk: int, mesh=None) -> str:
                     "the batch and need nothing")
     if chunk < 1:
         raise ValueError(f"a chunk of {chunk} rows")
+    if (jax.default_backend() == "tpu"
+            and kernels.shapes_ok(shape[-1], states)
+            and _kernel_batch_axes(mesh, shape[0]) == ()):
+        return "pallas_chunked"
     return "xla_chunked"
 
 
@@ -344,8 +354,8 @@ def _mamba1_walk(one, state, rows):
 
 
 def mamba1_scan(x, dt, A, B, C, D, *, chunk: int = 4, mesh=None):
-    """The Mamba-1 selective scan, chunked; backward by recomputation of
-    each chunk from the state that entered it.
+    """The Mamba-1 selective scan; backward by recomputation of each
+    chunk (or row block) from the state that entered it.
 
     x:  [batch, T, C]   the channels' inputs (any dtype)
     dt: [batch, T, C]   step sizes after softplus, float32
@@ -358,24 +368,39 @@ def mamba1_scan(x, dt, A, B, C, D, *, chunk: int = 4, mesh=None):
         h_t = exp(dt_t (x) A) * h_{t-1} + (dt_t * x_t) (x) B_t    [C, N]
         y_t = h_t . C_t + D * x_t
 
-    Inside a chunk an associative scan over the rows' (decay, write)
-    pairs; the ``[N, C]`` state carried from chunk to chunk by a
-    ``lax.scan``; each chunk a ``jax.checkpoint``, so that the
+    ``mesh`` is the mesh the program is sharded over, if the caller
+    knows one: ``mamba1_path`` decides (and refuses) from it, and from
+    the backend and the shapes, between two ways of doing the same
+    float32 arithmetic. ``pallas_chunked``: the kernel pair of
+    ``ops/pallas/mamba1_scan.py``, the state in VMEM along a sequence's
+    row blocks (of its own ``ROWS``; ``chunk`` is not its parameter).
+    ``xla_chunked``, everywhere else and the reference the kernels are
+    tested against: inside a chunk an associative scan over the rows'
+    (decay, write) pairs; the ``[N, C]`` state carried from chunk to
+    chunk by a ``lax.scan``; each chunk a ``jax.checkpoint``, so that the
     trajectory ``[T, C, N]`` (1.34 GB a layer at 4,096 rows of 5,120
     channels) is never whole in HBM: the backward keeps the inputs and
-    the state entering each chunk. **Short chunks win on the chip**: a
-    layer at 4,096 rows of 5,120 channels, forward + backward, reads
-    18.7 ms at chunks of 2 rows, 21.6 at 4, 26.7 at 8, 28.3 at 16, 93.1
-    at 64 and 219-303 at 128-512 (PERF.md section 6, PR 48): a chunk's
-    ``[L, N, C]`` arrays pass through HBM some thirty times in the
-    associative scan's levels, a turn of the loop costs ~2.6 us, and the
-    states kept (``[T / L, N, C]`` float32: 335 MB a layer at 4) grow
-    as the chunk shrinks. ``T`` need not be whole chunks: the
-    tail is padded with rows that neither decay nor write the state.
-    ``mesh`` is the mesh the program is sharded over, if the caller
-    knows one: ``mamba1_path`` decides (and refuses) from it."""
-    path = mamba1_path(x.shape, chunk, mesh)
+    the state entering each chunk. **Short chunks win on the chip** for
+    it: a layer at 4,096 rows of 5,120 channels, forward + backward,
+    reads 18.7 ms at chunks of 2 rows, 21.6 at 4, 26.7 at 8, 28.3 at 16,
+    93.1 at 64 and 219-303 at 128-512 (PERF.md section 6, PR 48; the
+    kernels: 5.7, PR 49): a chunk's ``[L, N, C]`` arrays pass through HBM
+    some thirty times in the associative scan's levels, a turn of the
+    loop costs ~2.6 us, and the states kept (``[T / L, N, C]`` float32:
+    335 MB a layer at 4) grow as the chunk shrinks. ``T`` need not be
+    whole chunks on either path: the tail is padded with rows that
+    neither decay nor write the state."""
+    path = mamba1_path(x.shape, A.shape[1], chunk, mesh)
+    if path == "pallas_chunked":
+        from ray_tpu.ops.pallas import mamba1_scan as kernels
+        tracing.note_trace(ssm_path=path, ssm_chunk=kernels.ROWS)
+        return kernels.mamba1_scan(x, dt, A, B, C, D)
     tracing.note_trace(ssm_path=path, ssm_chunk=chunk)
+    return _mamba1_xla_chunked(x, dt, A, B, C, D, chunk)
+
+
+def _mamba1_xla_chunked(x, dt, A, B, C, D, chunk: int):
+    """``mamba1_scan`` on its XLA path, whatever the backend."""
     b, t, c = x.shape
     f32 = jnp.float32
     x, dt, B, C = (z.astype(f32) for z in (x, dt, B, C))

@@ -115,14 +115,19 @@ def test_remote_node_counter_and_task_detail(obs_cluster):
     assert set(ran_on) == {node.node_id}
 
     rt = ray_tpu.core.api.get_runtime()
-    text = _wait_for(
-        lambda: (f'remote_node_probe_total{{node_id="{node.node_id}"}}'
-                 in rt.observability.prometheus_text())
-        and rt.observability.prometheus_text())
-    assert text, "remote node's counter never reached the head"
-    line = next(ln for ln in text.splitlines()
-                if ln.startswith("remote_node_probe_total{"))
-    assert float(line.rsplit(" ", 1)[1]) == 2.0
+
+    head = f'remote_node_probe_total{{node_id="{node.node_id}"}}'
+
+    def probe_line():
+        lines = rt.observability.prometheus_text().splitlines()
+        return next((ln for ln in lines if ln.startswith(head)), None)
+
+    assert _wait_for(probe_line), \
+        "remote node's counter never reached the head"
+    # The two tasks may run in two workers whose exporters flush apart:
+    # the line reads 1 between the two pushes, so wait for the merge.
+    _wait_for(lambda: float(probe_line().rsplit(" ", 1)[1]) >= 2.0)
+    assert float(probe_line().rsplit(" ", 1)[1]) == 2.0
 
     def remote_detail():
         rows = state_api.list_tasks(detail=True)

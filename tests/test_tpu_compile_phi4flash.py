@@ -35,12 +35,14 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     layers, ``MSMSMFGX``, 25,088 rows of the tied table, the blocks
     recomputed; adamw with a bf16 first moment) at 1 x 4,096 tokens:
     arguments + temporaries + unaliased outputs stay under the chip's
-    15.75 GB (12.87 GB at PR 48); every attention layer's four products
-    are one call of the multi-block flash kernels over 80 heads of 64 in
-    the projections' own layout, the windowed layers' under the band of
-    a 512-key window in blocks of 1,024 (7 block pairs a head where the
-    causal grid walks 10); the scans run the chunked XLA path (no custom
-    call under ``mamba``) and no ``[T, T]`` array exists."""
+    15.75 GB (12.87 GB at PR 48, 12.31 GB since the scans' kernels: PR
+    49); every attention layer's four products are one call of the
+    multi-block flash kernels over 80 heads of 64 in the projections' own
+    layout, the windowed layers' under the band of a 512-key window in
+    blocks of 1,024 (7 block pairs a head where the causal grid walks
+    10); the three scans run the kernel pair of
+    ``ops/pallas/mamba1_scan.py`` (custom calls under ``mamba/../scan``
+    alone, no ``while`` loop there) and no ``[T, T]`` array exists."""
     import re
 
     import optax
@@ -86,8 +88,8 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert notes["blocks_remat"] is True and notes["attn_window"] == 512
     assert notes["ssm_kind"] == "mamba1" and notes["ssm_tokens"] == 4096
     assert (notes["ssm_inner"], notes["ssm_state"], notes["ssm_dt_rank"],
-            notes["ssm_chunk"]) == (5120, 16, 160, 4)
-    assert notes["ssm_path"] == "xla_chunked"
+            notes["ssm_chunk"]) == (5120, 16, 160, 64)   # the kernels' rows
+    assert notes["ssm_path"] == "pallas_chunked"
     assert notes["attn_pairs"] == [20, 10] and notes["attn_products"] == 4
     assert notes["attn_calls"] == 1
     assert (notes["yoco_memory_layer"], notes["yoco_kv_layer"]) == (4, 5)
@@ -105,10 +107,12 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
           f"{m.temp_size_in_bytes / 1e9:.2f}")
     assert m.argument_size_in_bytes == pytest.approx(
         cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
-    assert 4e9 < total <= 15.75e9
+    assert 4e9 < total < 12.87e9        # 12.31 GB: PR 48's kept 335 MB a scan
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
+    scans = [line for line in calls if "/mamba/" in line]
+    calls = [line for line in calls if "/mamba/" not in line]
     # four attention layers: the forward kernel twice (the block is
     # recomputed), the backward once, each over [1, 4096, 80 * 64]
     assert len(calls) == 4 * 3
@@ -117,5 +121,12 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert sum("/attn/window/" in line for line in calls) == 2 * 3
     assert sum("/attn/cross/" in line for line in calls) == 3
     assert all("bf16[1,4096,5120]" in line for line in calls)
-    assert not any("/mamba/" in line or "/gmu/" in line for line in calls)
+    assert not any("/gmu/" in line for line in calls)
+    # three Mamba layers, the same count, each over [1, 4096, 5120]
+    assert all(re.search(r"/h_[024]/mamba/.*scan/", line) for line in scans)
+    assert sum("scan/jit(_mamba1_fwd)/" in line for line in scans) == 3 * 2
+    assert sum("scan/jit(_mamba1_bwd)/" in line for line in scans) == 3
+    assert len(scans) == 3 * 3
+    assert not any("/mamba/" in line and "/scan/" in line
+                   for line in text.splitlines() if " while(" in line)
     assert "4096,4096" not in text
