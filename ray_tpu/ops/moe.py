@@ -703,8 +703,7 @@ def _routed(x, route, route_args, per_token: bool, w_gate, w_up, w_down, *,
         notes.update(
             said, moe_experts_held=[first, held], moe_rows_sorted=rows)
         if experts_held is not None:
-            notes.update(moe_rows_path=rows_path,
-                         moe_rows_tile=route_rows.TILE)
+            notes.update(moe_rows_path=rows_path)
     tracing.note_trace(**notes)
     return out
 

@@ -15,6 +15,7 @@ import os
 import queue
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -73,7 +74,14 @@ class _TrainSession:
         # rank (the training step cadence) + a monotonically growing
         # step counter, both shipped to the head by the train
         # worker's metrics exporter.
-        self._last_report_ts: float | None = None
+        self.last_report_ts: float | None = None
+        # One entry a report after the first: (the report's index, its
+        # time, the seconds since the report before, the seconds that
+        # report itself took). The loop's thread appends and does
+        # nothing else; ``train/stall.py``'s watch thread, inside a fit,
+        # takes them out and finds the stalled ones.
+        self.reports: deque = deque(maxlen=4096)
+        self._report_s = 0.0
         # time.monotonic() at the first report(): where the
         # ``train.worker.loop`` span's ``first_report_s`` ends.
         self.t_first_report: float | None = None
@@ -99,11 +107,14 @@ class _TrainSession:
                checkpoint: "Checkpoint | None" = None) -> None:
         with annotation("train.report"):
             now = time.monotonic()
-            if self._last_report_ts is not None:
-                self._m_step_time.observe(now - self._last_report_ts)
+            if self.last_report_ts is not None:
+                interval = now - self.last_report_ts
+                self._m_step_time.observe(interval)
+                self.reports.append(
+                    (self._index, now, interval, self._report_s))
             else:
                 self.t_first_report = now
-            self._last_report_ts = now
+            self.last_report_ts = now
             self._m_steps.inc()
             ckpt_dir = None
             if checkpoint is not None:
@@ -118,6 +129,7 @@ class _TrainSession:
                                    index=self._index, t_report=now)
                 self._index += 1
             self.results.put(r)
+            self._report_s = time.monotonic() - now
 
 
 def init_session(context: TrainContext,

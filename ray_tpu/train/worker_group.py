@@ -21,6 +21,7 @@ from ray_tpu.core.placement_group import (
     PlacementGroupSchedulingStrategy,
 )
 from ray_tpu.train.prefetch import collect_counters
+from ray_tpu.train.stall import StallWatch
 from ray_tpu.util import tracing
 
 
@@ -136,6 +137,12 @@ class TrainWorker:
                         tracing.train_span("train.worker.loop",
                                            {"rank": self.rank},
                                            **target) as span:
+                    # beside the loop for as long as it runs: the
+                    # stalled steps, each a ``train.stall`` under this
+                    # span, and their totals on it
+                    watch = StallWatch(
+                        session, (span.trace_id, span.span_id),
+                        input_totals)
                     try:
                         if open_backend:
                             _open_backend(session.spans)
@@ -144,6 +151,7 @@ class TrainWorker:
                         else:
                             fn()
                     finally:
+                        span.attributes.update(watch.stop())
                         span.attributes.update(input_totals())
                         # what the loop's thread told the session and
                         # made no span of: each prefetcher's first

@@ -409,6 +409,17 @@ def take_trace_notes() -> dict:
     return vars(_trace_notes).pop("notes", {})
 
 
+def covered_s(spans, start: float, end: float) -> float:
+    """Seconds of ``[start, end]`` on the monotonic clock that the
+    spans cover, overlaps counted once."""
+    covered, at = 0.0, start
+    for c in sorted(spans, key=lambda c: c.mono_start):
+        a, b = max(c.mono_start, at), min(c.mono_end, end)
+        if b > a:
+            covered, at = covered + (b - a), b
+    return covered
+
+
 def self_seconds(spans: list[Span]) -> dict[str, float]:
     """span id -> the span's own seconds on the monotonic clock: its
     time less what its direct children cover, and less what siblings
@@ -434,12 +445,8 @@ def self_seconds(spans: list[Span]) -> dict[str, float]:
                     break
                 if c.process == s.process:
                     over.append(c)
-        covered, at = 0.0, s.mono_start
-        for c in sorted(over, key=lambda c: c.mono_start):
-            a, b = max(c.mono_start, at), min(c.mono_end, s.mono_end)
-            if b > a:
-                covered, at = covered + (b - a), b
-        out[s.span_id] = (s.mono_end - s.mono_start) - covered
+        out[s.span_id] = (s.mono_end - s.mono_start) - covered_s(
+            over, s.mono_start, s.mono_end)
     return out
 
 

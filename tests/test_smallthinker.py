@@ -488,7 +488,7 @@ def test_the_notes_say_the_layout_the_router_and_the_expert_kind(
     assert notes["moe_expert_kind"] == "reglu"
     assert notes["moe_experts_held"] == [4, 4] and notes["moe_top_k"] == 3
     # which row moves the held path compiled: off the TPU the plain form
-    assert notes["moe_rows_path"] == "xla" and notes["moe_rows_tile"] == 128
+    assert notes["moe_rows_path"] == "xla"
 
 
 def test_a_mesh_over_the_batch_gives_the_one_device_loss_and_sp_is_refused():

@@ -413,7 +413,7 @@ def test_first_report_is_set_once_a_session():
         assert before <= first <= time.monotonic()
         for i in range(1, 4):
             train_session.report({"i": i})
-        assert sess.t_first_report == first < sess._last_report_ts
+        assert sess.t_first_report == first < sess.last_report_ts
     finally:
         train_session.shutdown_session()
 
@@ -1180,7 +1180,7 @@ def test_ten_thousand_steps_allocate_no_span(monkeypatch):
     (t_made, t_handed, told), = sess.first_batches
     assert t_made <= t_handed <= sess.t_first_report
     assert set(told) == {"source_s", "place_s", "stall_s"}
-    assert sess.t_first_report <= sess._last_report_ts
+    assert sess.t_first_report <= sess.last_report_ts
     assert input_totals()["input.batches"] == 10_000
     # outside a collector a prefetcher is kept by nobody
     assert DevicePrefetcher(iter(()), depth=1).counters["batches"] == 0
