@@ -258,10 +258,10 @@ def cross_entropy_loss(logits, targets, ignore_index: int = -1):
 # a scope around the call alone does not reach the backward ``while``.
 # It returns the sums, not their quotient, so that a caller that holds
 # only part of the rows can add its sums to the others' first.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 @jax.named_scope("loss")
-def _chunked_ce_core(rows_c, emb, tgt_c, ignore_index):
-    sums, _ = _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index)
+def _chunked_ce_core(rows_c, emb, tgt_c, ignore_index, path):
+    sums, _ = _chunked_ce_fwd(rows_c, emb, tgt_c, ignore_index, path)
     return sums
 
 
@@ -287,18 +287,37 @@ def _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index):
         (rows_c, tgt_c))
 
 
+def _chunked_ce_fwd(rows_c, emb, tgt_c, ignore_index, path):
+    """((sum of the rows' losses, count of unmasked rows), lse [n,
+    chunk]) by ``path`` (``ce_path``): the scan above, or the kernel of
+    ``ops/pallas/ce_lse.py`` over all the rows at once, a row block its
+    own chunk, which writes no logit to HBM. The backward pass takes
+    either's ``lse``."""
+    if path == "xla_scan":
+        return _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index)
+    from ray_tpu.ops.pallas import ce_lse
+    n, chunk, e = rows_c.shape
+    tgt = tgt_c.reshape(-1)
+    mask = tgt != ignore_index
+    lse, picked = ce_lse.ce_lse_fwd(
+        rows_c.reshape(-1, e), emb, jnp.where(mask, tgt, 0))
+    nll = jnp.where(mask, lse - picked, 0.0)
+    return (nll.sum(), mask.sum()), lse.reshape(n, chunk)
+
+
 @jax.named_scope("loss")
-def _chunked_ce_core_fwd(rows_c, emb, tgt_c, ignore_index):
-    sums, lse_c = _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index)
+def _chunked_ce_core_fwd(rows_c, emb, tgt_c, ignore_index, path):
+    sums, lse_c = _chunked_ce_fwd(rows_c, emb, tgt_c, ignore_index, path)
     return sums, (rows_c, emb, tgt_c, lse_c)
 
 
 @jax.named_scope("loss")
-def _chunked_ce_core_bwd(ignore_index, res, g):
+def _chunked_ce_core_bwd(ignore_index, path, res, g):
     # Hand-written backward: recompute each chunk's logits but REUSE
     # the saved log-sum-exp (a jax.checkpoint formulation re-runs the
     # full logsumexp reduction too). dlogits = (softmax - onehot) *
-    # d tot, and d tot is 1/cnt of the caller's mean.
+    # d tot, and d tot is 1/cnt of the caller's mean. The same scan
+    # whichever ``path`` made the log-sum-exp.
     rows_c, emb, tgt_c, lse_c = res
     scale, _ = g
 
@@ -355,6 +374,51 @@ def _token_axes(mesh, batch: int, seq: int):
     return batch_axes, seq_axis
 
 
+def _mapped_over(axes):
+    """The mesh axes ``_token_axes`` found, as one tuple: what the
+    loss's ``shard_map`` splits the rows over (none: one program)."""
+    if axes is None:
+        return ()
+    batch_axes, seq_axis = axes
+    return batch_axes + ((seq_axis,) if seq_axis else ())
+
+
+def _chunks(rows: int, chunk_size: int):
+    """(rows of a chunk, chunks) that ``rows`` rows are padded to."""
+    chunk = min(chunk_size, rows)
+    return chunk, -(-rows // chunk)
+
+
+def ce_path(shape, vocab: int, dtype, mesh=None,
+            chunk_size: int = 2048) -> str:
+    """What ``chunked_cross_entropy`` compiles for the forward pass of
+    ``hidden`` [B, S, E] against a head of ``vocab`` rows on this mesh:
+    ``pallas_lse``, the kernel of ``ops/pallas/ce_lse.py``, on a TPU
+    backend where the rows are bfloat16, ``E`` and ``vocab`` are whole
+    128-lane tiles, the padded rows whole sublane tiles, and the
+    program that holds the rows is one device's: no mesh on a
+    one-device process, a one-device mesh, or a chip's rows under the
+    ``shard_map`` over the token axes (``_token_axes``); ``xla_scan``,
+    the scan over chunks, everywhere else (the CPU, float32 rows, a
+    head sharded on its vocabulary or shapes the axes do not divide,
+    where one program spans the devices and a ``pallas_call`` has no
+    partitioning rule). Every head shape of the benchmark's cells is
+    the kernel's: it beat the scan at each on the chip (PERF.md
+    section 6, PR 51)."""
+    from ray_tpu.ops.pallas import ce_lse
+    batch, seq, e = shape
+    axes = _token_axes(mesh, batch, seq)
+    if axes is None and (jax.device_count() if mesh is None
+                         else mesh.size) > 1:
+        return "xla_scan"
+    devices = math.prod(mesh.shape[a] for a in _mapped_over(axes))
+    chunk, n = _chunks(batch * seq // devices, chunk_size)
+    if (jax.default_backend() == "tpu" and dtype == jnp.bfloat16
+            and ce_lse.shapes_ok(n * chunk, e, vocab)):
+        return "pallas_lse"
+    return "xla_scan"
+
+
 @jax.named_scope("loss")
 def chunked_cross_entropy(hidden, embedding, targets,
                           ignore_index: int = -1,
@@ -364,11 +428,17 @@ def chunked_cross_entropy(hidden, embedding, targets,
     hand-written VJP (bwd recomputes each chunk's logits but reuses
     the saved per-row log-sum-exp).
 
-    TPU rationale: full GPT-2 logits are B*S*50304 f32 — 1.6 GB at
+    TPU rationale: full GPT-2 logits are B*S*50304 f32 — 6.6 GB at
     the bench shape — and the softmax/backward over them is pure HBM
-    traffic. Chunking keeps the live logits block at
+    traffic. The backward keeps the live logits block at
     chunk_size*vocab (~400 MB at 2048), trading one extra LM-head
-    matmul in bwd for most of that bandwidth.
+    matmul for most of that bandwidth. The forward, where ``ce_path``
+    says ``pallas_lse``, writes no logit to HBM at all: one kernel
+    over all the rows folds a ``[512, 1024]`` block of them at a time
+    into each row's running maximum and sum in VMEM
+    (``ops/pallas/ce_lse.py``); under ``xla_scan`` it is a scan over
+    the same chunks as the backward, a chunk's float32 logits written
+    by the matmul and read back by the log-sum-exp.
 
     On a ``mesh`` that shards tokens (dp, fsdp, sp) each chip chunks
     and scans the rows it holds, under ``shard_map``; the two sums and
@@ -383,23 +453,30 @@ def chunked_cross_entropy(hidden, embedding, targets,
     # Cast the tied embedding ONCE outside the scan (fwd and bwd both
     # consume the bf16 copy).
     emb = embedding.astype(hidden.dtype)
+    path = ce_path(hidden.shape, emb.shape[0], hidden.dtype, mesh,
+                   chunk_size)
 
     def sums(hidden, emb, targets, over=()):
         """(sum of the rows' losses, count of unmasked rows) of the
         rows in hand, added over the mesh axes ``over``."""
         rows = hidden.reshape(-1, E)
         tgt = targets.reshape(-1)
-        chunk = min(chunk_size, rows.shape[0])
-        pad = (-rows.shape[0]) % chunk
+        chunk, n = _chunks(rows.shape[0], chunk_size)
+        pad = n * chunk - rows.shape[0]
         if pad:
             rows = jnp.pad(rows, ((0, pad), (0, 0)))
             tgt = jnp.pad(tgt, (0, pad), constant_values=ignore_index)
-        n = rows.shape[0] // chunk
         tot_cnt = _chunked_ce_core(
             rows.reshape(n, chunk, E), emb, tgt.reshape(n, chunk),
-            ignore_index)
+            ignore_index, path)
+        if path == "pallas_lse":
+            from ray_tpu.ops.pallas import ce_lse
+            block_rows, tile = ce_lse.blocks(n * chunk, E, emb.shape[0])
+            tracing.note_trace(ce_fwd_rows=block_rows, ce_fwd_tile=tile)
+            tracing.count_trace(ce_fwd_calls=1)
         return jax.lax.psum(tot_cnt, over) if over else tot_cnt
 
+    tracing.note_trace(ce_path=path)
     axes = _token_axes(mesh, B, S)
     if axes is None:
         tot, cnt = sums(hidden, emb, targets)
@@ -407,7 +484,7 @@ def chunked_cross_entropy(hidden, embedding, targets,
         from jax.sharding import PartitionSpec
         batch_axes, seq_axis = axes
         tokens = PartitionSpec(batch_axes or None, seq_axis)
-        over = batch_axes + ((seq_axis,) if seq_axis else ())
+        over = _mapped_over(axes)
         # The head enters replicated, so the transpose reduces its
         # gradient over the axes: once, after the backward scan. All
         # mesh axes are manual, as in ops/attention.py: with only the

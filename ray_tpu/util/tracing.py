@@ -404,6 +404,15 @@ def note_trace(**attributes) -> None:
     vars(_trace_notes).setdefault("notes", {}).update(attributes)
 
 
+def count_trace(**counts) -> None:
+    """As ``note_trace``, for what a trace does several times: each
+    number is added to what the trace in progress has noted under that
+    key (the custom calls of a head that a step meets twice)."""
+    notes = vars(_trace_notes).setdefault("notes", {})
+    for key, n in counts.items():
+        notes[key] = notes.get(key, 0) + n
+
+
 def take_trace_notes() -> dict:
     """This thread's notes, handed over once."""
     return vars(_trace_notes).pop("notes", {})

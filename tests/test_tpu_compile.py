@@ -377,3 +377,66 @@ def test_routed_experts_compile_for_v5e(v5e, monkeypatch):
         arg((64, 2048, 1024), jnp.float32), arg((64, 2048, 1024), jnp.float32),
         arg((64, 1024, 2048), jnp.float32)).compile().as_text()
     assert text.count("tpu_custom_call") >= 9
+
+
+# hidden [B, S, E] and the rows of the table, the mesh the batch is over
+HEADS = {
+    "gpt2": ((32, 1024, 768), 50304, None),
+    "gpt2_dp4_a_chips_rows": ((128, 1024, 768), 50304, {"dp": 4}),
+    "zaya": ((2, 8192, 2048), 32896, None),
+}
+
+
+@pytest.mark.parametrize("head", list(HEADS), ids=list(HEADS))
+def test_the_lm_heads_forward_is_one_custom_call_under_loss(
+        v5e, monkeypatch, head):
+    """The chunked cross-entropy at the GPT-2 cells' head (32,768 rows a
+    chip against 50,304 = 3 x 131 lane tiles: a ragged last tile) and
+    ZAYA's (32,896 = 257 tiles, a prime), forward and backward: the
+    forward is ONE custom call, ``ops/pallas/ce_lse.py``'s, under
+    ``loss`` (under the ``shard_map`` on dp=4), inside the VMEM it asks
+    for (64 MiB); no float32 ``[rows, vocab]`` logits of all a chip's rows
+    exist, and the only ``[2048, vocab]`` float32 chunks are the
+    backward's."""
+    from ray_tpu.models import gpt2
+    from ray_tpu.ops.pallas import ce_lse
+    from ray_tpu.parallel.mesh import make_mesh
+    from ray_tpu.util import tracing
+
+    shape, vocab, axes = HEADS[head]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1 if not axes else 4)
+    mesh = make_mesh(axes, devices=v5e) if axes else None
+    rows_at = (NamedSharding(mesh, P("dp")) if axes
+               else SingleDeviceSharding(v5e[0]))
+    whole = (NamedSharding(mesh, P()) if axes
+             else SingleDeviceSharding(v5e[0]))
+    hidden = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=rows_at)
+    table = jax.ShapeDtypeStruct((vocab, shape[-1]), jnp.float32,
+                                 sharding=whole)
+    targets = jax.ShapeDtypeStruct(shape[:2], jnp.int32, sharding=rows_at)
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+
+    def loss(h, w, t):
+        return gpt2.chunked_cross_entropy(h, w, t, mesh=mesh)
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        hidden, table, targets).compile()
+    local = shape[0] * shape[1] // (4 if axes else 1)
+    block_rows, tile = ce_lse.blocks(local, shape[-1], vocab)
+    assert notes["ce_path"] == "pallas_lse"
+    assert (notes["ce_fwd_rows"], notes["ce_fwd_tile"]) == (block_rows, tile)
+    assert vocab % tile, "these two tables have a ragged last tile"
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert re.search(r'op_name="[^"]*loss/[^"]*jit\(_ce_lse_fwd\)', calls[0])
+    # the compile succeeding is the proof that the blocks fit what is
+    # asked for: the compiler refuses a kernel that holds more
+    assert int(re.search(
+        r'(?<!used_)scoped_memory_configs":\[\{"memory_space":"1",'
+        r'"offset":"\d+","size":"(\d+)"', calls[0]).group(1)
+               ) == ce_lse._VMEM_LIMIT
+    assert f"f32[{local},{vocab}]" not in text
+    assert "exponential_reduce" not in text

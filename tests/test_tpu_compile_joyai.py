@@ -3,6 +3,7 @@ no chip here (as ``test_tpu_compile_nemotron.py``: the TPU compiler for a
 described v5e; nothing runs, so nothing here is a result or a time)."""
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -140,6 +141,11 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert sum("mla_flash_bwd" in line for line in calls) == 6
+    # the head's forward (PR 51), once for the loss and once for the MTP
+    # module's pass over the same table
+    head = [line for line in calls if "jit(_ce_lse_fwd)" in line]
+    assert len(head) == 2 and all("/loss/" in line for line in head)
+    assert sum(bool(re.search(r"loss\)?/mtp/", line)) for line in head) == 1
     assert "8192,8192" not in text
 
 

@@ -108,6 +108,7 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     kinds = [re.search(r"jit\((\w+)\)/pallas_call", line).group(1)
              for line in calls]
     assert {"gmm", "tgmm"} <= set(kinds)
+    assert kinds.count("_ce_lse_fwd") == 1      # the head's forward (PR 51)
     # the forward kernel twice (the block is recomputed), the backward once
     assert kinds.count("mla_flash_fwd") == 2
     assert kinds.count("mla_flash_bwd") == 1

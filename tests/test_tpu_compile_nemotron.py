@@ -194,7 +194,8 @@ def test_a_mamba_layer_at_the_cells_shape_keeps_the_kernels_layout(
         v5e, monkeypatch, chips):
     """One ``M`` layer at the cell's widths and 8,192 tokens, loss and
     gradients, on one chip and with a sequence a chip over ``dp``: the
-    scan's two custom calls and the gated norm's two, both notes name
+    scan's two custom calls, the gated norm's two and the loss's one
+    (under the ``shard_map`` over ``dp`` as on one chip), the notes name
     the kernels, and under ``gate_norm`` no float32 ``[rows, 8192,
     4096]`` array is left in the optimised HLO: the XLA norm behind the
     scan's custom call relaid one out three times a layer (PERF.md
@@ -230,7 +231,10 @@ def test_a_mamba_layer_at_the_cells_shape_keeps_the_kernels_layout(
         params, batch).compile().as_text()
     assert notes["ssm_path"] == "pallas_chunked"
     assert notes["gate_norm_path"] == "pallas"
-    assert text.count("tpu_custom_call") == 4
+    # and the head's forward, one more (PR 51: ``ops/pallas/ce_lse.py``)
+    assert notes["ce_path"] == "pallas_lse"
+    assert text.count("tpu_custom_call") == 5
+    assert len(re.findall(r"custom-call\(.*jit\(_ce_lse_fwd\)", text)) == 1
     assert len(re.findall(r"custom-call\(.*gate_norm", text)) == 2
     under = [ln for ln in text.splitlines() if "gate_norm" in ln]
     assert under

@@ -112,7 +112,11 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     scans = [line for line in calls if "/mamba/" in line]
-    calls = [line for line in calls if "/mamba/" not in line]
+    head = [line for line in calls if "jit(_ce_lse_fwd)" in line]
+    calls = [line for line in calls if "/mamba/" not in line
+             and line not in head]
+    # the head's forward (PR 51): one kernel under ``loss``
+    assert len(head) == 1 and "/loss/" in head[0]
     # four attention layers: the forward kernel twice (the block is
     # recomputed), the backward once, each over [1, 4096, 80 * 64]
     assert len(calls) == 4 * 3

@@ -106,7 +106,10 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
              if 'custom_call_target="tpu_custom_call"' in line]
     kinds = [re.search(r"jit\((\w+)\)/pallas_call", line).group(1)
              for line in calls]
-    assert set(kinds) == {"_flash_fwd", "_flash_bwd", "gmm", "tgmm"}
+    assert set(kinds) == {"_flash_fwd", "_flash_bwd", "gmm", "tgmm",
+                          "_ce_lse_fwd"}
+    assert kinds.count("_ce_lse_fwd") == 1      # the head's forward (PR 51)
+    assert notes["ce_path"] == "pallas_lse"
     assert kinds.count("_flash_fwd") == 4
     assert kinds.count("_flash_bwd") == 4       # one kernel a layer
     flash = [line for kind, line in zip(kinds, calls) if "_flash_" in kind]

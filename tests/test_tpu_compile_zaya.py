@@ -41,8 +41,9 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     kernel a layer (dq's 8,192 rows resident), CCA's passes in
     front of it are ``ops/pallas/cca_mix.py``'s pair (two kinds of
     custom call beside the flash kernels' two and the grouped matmuls'
-    two, one of each a layer, under ``attn/mix``), and no ``[T, T]``
-    array exists."""
+    two, one of each a layer, under ``attn/mix``), the loss's forward is
+    ``ops/pallas/ce_lse.py``'s one call, and no ``[T, T]`` array
+    exists."""
     import re
 
     import optax
@@ -96,7 +97,13 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     kinds = [re.search(r"jit\((\w+)\)/pallas_call", line).group(1)
              for line in calls]
     assert set(kinds) == {"_flash_fwd", "_flash_bwd", "gmm", "tgmm",
-                          "_mix_fwd", "_mix_bwd"}
+                          "_mix_fwd", "_mix_bwd", "_ce_lse_fwd"}
+    # the head's forward (PR 51): one kernel over the 16,384 rows under
+    # ``loss``, no scan, and no pass over float32 logits behind it
+    assert notes["ce_path"] == "pallas_lse"
+    assert [("/loss/" in line, "/while/" in line) for kind, line
+            in zip(kinds, calls) if kind == "_ce_lse_fwd"] == [(True, False)]
+    assert "exponential_reduce" not in text
     assert kinds.count("_mix_fwd") == kinds.count("_mix_bwd") == 5
     assert kinds.count("_flash_fwd") == 5
     assert kinds.count("_flash_bwd") == 5       # one kernel a layer

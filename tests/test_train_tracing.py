@@ -978,6 +978,8 @@ def test_trace_notes_reach_their_trace_span_and_no_other():
     nobody listened does not reach a later trace."""
     def noted(x):
         tracing.note_trace(rows=7, axes=["dp"])
+        for _ in range(2):          # what a trace does twice adds up
+            tracing.count_trace(calls=1)
         return x + 1.0
 
     train_step._listen_for_compiles()
@@ -990,7 +992,7 @@ def test_trace_notes_reach_their_trace_span_and_no_other():
              if s.name == "train.compile"]
     with_notes = [a for a in spans if set(a) - {"kind", "fun_name", "cache"}]
     assert with_notes == [{"kind": "trace", "fun_name": "noted",
-                           "rows": 7, "axes": ["dp"]}]
+                           "rows": 7, "axes": ["dp"], "calls": 2}]
     assert [a["fun_name"] for a in spans if a["kind"] == "trace"] == [
         "noted", "<lambda>"]
 
@@ -1046,6 +1048,48 @@ def test_flash_notes_reach_both_traces_of_the_step_and_no_other_span(
                      if k.startswith("flash_")}
             assert noted == (want if s.attributes["kind"] == "trace"
                              else {}), s.attributes
+
+
+@pytest.mark.parametrize("steered", [False, True],
+                         ids=["cpu_scan", "kernel_interpreted"])
+def test_the_loss_says_which_forward_it_compiled(monkeypatch, steered):
+    """``chunked_cross_entropy``'s notes on the step's ``trace`` span:
+    ``ce_path``, and where that is the kernel's (steered onto it here,
+    interpreted: no chip in the sandbox) the row block, the vocabulary
+    tile and the custom calls a step; on the CPU ``xla_scan`` and no
+    ``ce_fwd_*`` key. No other span of the step carries them."""
+    import functools
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.ops.pallas import ce_lse
+
+    if steered:
+        monkeypatch.setattr(gpt2, "ce_path", lambda *a, **k: "pallas_lse")
+        monkeypatch.setattr(ce_lse, "ce_lse_fwd", functools.partial(
+            ce_lse.ce_lse_fwd, interpret=True))
+    cfg = gpt2.GPT2Config.tiny(n_head=2, n_embd=128, n_layer=1)
+    model = gpt2.GPT2(cfg)
+    opt = optax.sgd(0.1)
+    state = train_step.init_train_state(
+        model.init_params(jax.random.key(0)), opt)
+    tokens = jnp.zeros((2, cfg.seq_len), jnp.int32)
+    batch = {"tokens": tokens, "targets": tokens}
+    want = {"ce_path": "xla_scan"}
+    if steered:     # 128 rows in chunks of 64: one block; 256 columns
+        want = {"ce_path": "pallas_lse", "ce_fwd_rows": 128,
+                "ce_fwd_tile": 256, "ce_fwd_calls": 1}
+    before = len(tracing.get_spans())
+    step = train_step.make_train_step(
+        gpt2.gpt2_loss_fn(model, ce_chunk=64), opt, donate=False)
+    _, metrics = step(state, batch)
+    assert np.isfinite(float(metrics["loss"]))
+    spans = _compile_spans_since(before, "step")
+    assert "trace" in [s.attributes["kind"] for s in spans]
+    for s in spans:
+        noted = {k: v for k, v in s.attributes.items()
+                 if k.startswith("ce_")}
+        assert noted == (want if s.attributes["kind"] == "trace"
+                         else {}), s.attributes
 
 
 def test_the_listener_is_installed_once_a_process():
