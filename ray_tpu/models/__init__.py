@@ -32,6 +32,13 @@ and differential attention through
 self-decoder; gated memory units and cross attention that read one
 earlier layer's scan and one's K and V in a cross-decoder; LayerNorm, a
 tied table); its config expresses Phi-4-mini-flash-reasoning.
+``Laguna`` is the window/full decoder whose layers differ in head count
+(48 query heads on a full layer, 64 on a sliding one under a 512-key
+window, each layer reading its own entry of the published lists; YaRN on
+half of a full layer's lanes and plain RoPE on a sliding layer's, two
+position tables in one stack; a sigmoid gate a head on the core's
+output; JoyAI's routed layer behind a leading dense one); its config
+expresses Laguna-XS.2.
 ``MoETransformer`` is the older top-1, capacity-dropping switch model
 on GPT-2 blocks, which goes when the dropless path runs under ``ep``
 (ROADMAP C5)."""
@@ -39,6 +46,7 @@ on GPT-2 blocks, which goes when the dropless path runs under ``ep``
 from ray_tpu.models.gpt2 import GPT2, GPT2Config
 from ray_tpu.models.joyai import JoyAI, JoyAIConfig
 from ray_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
+from ray_tpu.models.laguna import Laguna, LagunaConfig
 from ray_tpu.models.llama import Llama, LlamaConfig
 from ray_tpu.models.moe import MoEConfig, MoETransformer
 from ray_tpu.models.nemotron_h import NemotronH, NemotronHConfig
@@ -50,7 +58,7 @@ from ray_tpu.models.zaya import Zaya, ZayaConfig
 
 __all__ = [
     "GPT2", "GPT2Config", "JoyAI", "JoyAIConfig", "KimiLinear",
-    "KimiLinearConfig", "Llama", "LlamaConfig",
+    "KimiLinearConfig", "Laguna", "LagunaConfig", "Llama", "LlamaConfig",
     "MoETransformer", "MoEConfig", "NemotronH", "NemotronHConfig",
     "Phi4Flash", "Phi4FlashConfig",
     "ResNet", "ResNet50Config", "SmallThinker", "SmallThinkerConfig", "ViT",

@@ -136,6 +136,43 @@ def rope_freqs(head_dim: int, seq_len: int, theta: float):
     return jnp.outer(t, inv)                     # [T, D/2]
 
 
+def yarn_freqs(rot_dim: int, seq_len: int, theta: float, *, factor: float,
+               original_len: int, beta_fast: float, beta_slow: float,
+               attention_factor: float | None = None):
+    """YaRN (arXiv:2309.00071, ``rope_type: yarn``): ``([T, rot_dim/2]``
+    angles, the amplitude on cos and sin). With ``f_i = theta^(-2i /
+    rot_dim)`` and ``c(n) = rot_dim ln(original_len / (2 pi n)) / (2 ln
+    theta)``, the pair whose wavelength turns ``n`` times in the
+    original length: ``low = floor(c(beta_fast))``, ``high =
+    ceil(c(beta_slow))``, both clamped to ``[0, rot_dim - 1]``; ``r_i =
+    clip((i - low) / (high - low), 0, 1)``; ``inv_i = (f_i / factor)
+    r_i + f_i (1 - r_i)``: the fast pairs keep their frequency, the
+    slow ones are stretched by ``factor``, a ramp between. The
+    amplitude is ``attention_factor``, by default ``0.1 ln(factor) +
+    1``; it goes on q's and k's rotated lanes alike, so the scores of
+    those lanes grow by its square."""
+    import math
+
+    half = rot_dim // 2
+
+    def turns(n: float) -> float:
+        return (rot_dim * math.log(original_len / (2 * math.pi * n))
+                / (2 * math.log(theta)))
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), rot_dim - 1)
+    if low == high:
+        high += 0.001        # the published code's guard
+    f = 1.0 / (theta ** (jnp.arange(0, rot_dim, 2, dtype=jnp.float32)
+                         / rot_dim))
+    r = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / (high - low),
+                 0.0, 1.0)
+    inv = f / factor * r + f * (1.0 - r)
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    t = jnp.arange(seq_len, dtype=jnp.float32)
+    return jnp.outer(t, inv), float(attention_factor)
+
+
 def apply_rope(x, angles):
     """x: [B, T, H, D]; rotate pairs (even, odd) by per-position
     angles [T, D/2]."""

@@ -1,0 +1,127 @@
+"""The Laguna-XS.2 cell's step compiles for the real chip, with no chip
+here (as ``test_tpu_compile_kimi_linear.py``: the TPU compiler for a
+described v5e; nothing runs, so nothing here is a result or a time)."""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, no description
+        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def test_the_real_size_step_compiles_inside_the_chips_memory(
+        v5e, monkeypatch):
+    """The cell's step as the builder makes it (layers 0-4 as published,
+    ``F S S S F`` at 48, 64, 64, 64, 48 query heads; 32 of 256 experts;
+    12,544 rows of the two tables; the blocks recomputed; adamw with a
+    bf16 first moment) at 1 x 16,384 tokens: arguments + temporaries +
+    unaliased outputs stay under 15.0 GB (13.04 GB at PR 52; without
+    ``remat`` the compiler asks for 16.64 GB of the chip's 15.75 and
+    refuses). Every layer's core is one call of the multi-block flash
+    kernels in the projections' own layout, the forward twice (the block
+    is recomputed) and the backward once: the two full layers' under
+    ``attn/core`` over 6,144 lanes, the three sliding layers' under
+    ``attn/window`` over 8,192 lanes and the band of a 512-key window in
+    blocks of 1,024 (31 block pairs a head where the causal grid walks
+    136). No ``[T, T]`` array exists."""
+    import re
+
+    import optax
+
+    from ray_tpu import train
+    from ray_tpu.models.laguna import Laguna, LagunaConfig, laguna_loss_fn
+    from ray_tpu.util import tracing
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
+    one = SingleDeviceSharding(v5e[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = LagunaConfig.laguna_xs_2(
+        n_layer=5, vocab_size=12544, experts_held=(0, 32), seq_len=16384,
+        remat=True)
+    model = Laguna(cfg)
+    opt = optax.chain(
+        optax.clip_by_global_norm(1.0),
+        optax.adamw(2e-5, b1=0.9, b2=0.95, weight_decay=0.1,
+                    mu_dtype=jnp.bfloat16))
+    step = train.make_train_step(laguna_loss_fn(model, ce_chunk=2048), opt)
+    state = jax.tree.map(
+        lambda z: arg(z.shape, z.dtype),
+        jax.eval_shape(lambda: train.init_train_state(
+            model.init_params(jax.random.key(0)), opt, None)))
+    batch = {k: arg((1, cfg.seq_len), jnp.int32)
+             for k in ("tokens", "targets")}
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    compiled = step.lower(state, batch).compile()
+    assert notes["attn_kind"] == "window_global"
+    assert notes["attn_layers"] == "FSSSF"
+    assert notes["attn_heads"] == "48,64,64,64,48"
+    assert notes["attn_window"] == 512 and notes["blocks_remat"] is True
+    assert notes["attn_gate"] == "headwise_sigmoid"
+    assert notes["rope_kind"] == "yarn_half|default"
+    assert notes["rope_attention_factor"] == pytest.approx(1.4158883)
+    # 48 and 64 heads of 128: one a 128-lane block of the projections'
+    # layout, in both kinds of layer
+    assert notes["flash_layout"] == "bthd"
+    assert notes["flash_lanes_per_block"] == 128
+    assert notes["flash_path"] == "multi_block"
+    assert notes["flash_window"] == 512 and notes["flash_band_blocks"] == 31
+    assert notes["flash_bwd_resident_rows"] == 16384
+    assert notes["moe_router"] == "sigmoid"
+    assert notes["moe_experts_held"] == [0, 32]
+    assert notes["moe_rows_sorted"] == 32768 and notes["moe_routes"] == 131072
+    assert notes["moe_path"] == "megablox_gmm"
+    assert notes["moe_rows_path"] == "tgmm"
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + max(0, m.output_size_in_bytes - m.alias_size_in_bytes))
+    print(f"program {total / 1e9:.2f} GB: arguments "
+          f"{m.argument_size_in_bytes / 1e9:.2f}, temporaries "
+          f"{m.temp_size_in_bytes / 1e9:.2f}")
+    assert m.argument_size_in_bytes == pytest.approx(
+        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
+    assert 4e9 < total < 15.0e9             # 13.04 GB at PR 52
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    flash = [line for line in calls if "/attn/" in line]
+    head = [line for line in calls if "jit(_ce_lse_fwd)" in line]
+    assert len(head) == 1 and "/loss/" in head[0]
+    # five layers: the forward kernel twice, the backward once
+    assert len(flash) == 5 * 3
+    assert all(re.search(r"/h_[04]/attn/core/|/h_[123]/attn/window/", line)
+               for line in flash)
+    core = [line for line in flash if "/attn/core/" in line]
+    window = [line for line in flash if "/attn/window/" in line]
+    assert len(core) == 2 * 3 and len(window) == 3 * 3
+    assert sum("jit(_flash_fwd)" in line for line in core) == 2 * 2
+    assert sum("jit(_flash_bwd)" in line for line in window) == 3
+    assert all("bf16[1,16384,6144]" in line for line in core)
+    assert all("bf16[1,16384,8192]" in line for line in window)
+    # the rest are the routed layers' grouped matmuls and row sums
+    rest = [line for line in calls if line not in flash + head]
+    assert rest and all(re.search(r"/h_[1234]/mlp/", line) for line in rest)
+    assert "16384,16384" not in text
