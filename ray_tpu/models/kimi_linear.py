@@ -39,7 +39,9 @@ recurrence and the gate run again (``_kda_core`` is a
 keeping of each KDA mixer its gated output, so that the recurrence runs
 forward twice a step on the kernels (the pass, ``_kda_core``'s
 recomputation; a third time, a group's inside ``kda_scan``, on the XLA
-path) and not once more.
+path) and not once more, and of the latent layer its core's output and
+row statistics (``ops/attention.py::remat_policy``), so that the latent
+flash forward kernel runs once (the note ``blocks_remat_keeps``).
 
 It is the benchmark's sixth language model
 (``kimi-linear-48b-a3b.b1-t16384`` runs layers 1-5, KDA with the dense
@@ -76,6 +78,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ray_tpu.models.joyai import MoE, _dense, _Down, _norm, _swiglu, _Up
 from ray_tpu.models.nemotron_h import _a_log_init, _conv_init, _dt_bias_init
 from ray_tpu.ops import kda, ssm
+from ray_tpu.ops.attention import remat_keeps, remat_policy
 from ray_tpu.ops.mla import UpProjections, latent_attention
 from ray_tpu.ops.moe import held_route_share
 from ray_tpu.util import tracing
@@ -339,7 +342,9 @@ class KimiLinear(nn.Module):
             attn_kind="kda_mla", attn_layers=cfg.layer_kinds,
             mla_ranks=[None, cfg.kv_rank],
             mla_qk_dims=[cfg.nope_dim, cfg.rope_dim], mla_v_dim=cfg.v_dim,
-            dense_layers=cfg.dense_layers, blocks_remat=cfg.remat)
+            dense_layers=cfg.dense_layers, blocks_remat=cfg.remat,
+            blocks_remat_keeps=",".join(remat_keeps(_KDA_OUT))
+            if cfg.remat else "")
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
@@ -347,10 +352,11 @@ class KimiLinear(nn.Module):
             x = self._constrain(wte(tokens))
         # a recomputed block keeps its KDA mixer's gated output (134 MB
         # a layer at 16,384 rows), so that its recomputation does not
-        # walk the recurrence once more than ``_kda_core``'s own does
-        block = nn.remat(
-            Block, policy=jax.checkpoint_policies.save_only_these_names(
-                _KDA_OUT)) if cfg.remat else Block
+        # walk the recurrence once more than ``_kda_core``'s own does,
+        # and its latent layer's output and row statistics (0.14 GB), so
+        # that the latent flash forward kernel runs once
+        block = (nn.remat(Block, policy=remat_policy(_KDA_OUT))
+                 if cfg.remat else Block)
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):
                 x = self._constrain(

@@ -44,8 +44,12 @@ the other window/global stack:
 
 With ``remat`` each block is recomputed in the backward pass
 (``nn.remat``, as ``models/kimi_linear.py``): the step at 16,384 rows
-would keep q and o at 8,192 lanes, eight copies of K and V and a
-32,768-row slab of sorted routes a layer.
+would keep q at 8,192 lanes, eight copies of K and V and a 32,768-row
+slab of sorted routes a layer. One thing a recomputed block does keep:
+its attention core's output and row statistics
+(``ops/attention.py::remat_policy``; 0.20 GB a full layer, 0.27 GB a
+sliding one), so the flash forward kernel runs once a layer and not
+again in the backward pass; the note ``blocks_remat_keeps`` names them.
 
 It is the benchmark's eighth language model
 (``laguna-xs.2.b1-t16384`` runs layers 0-4, ``F S S S F``, with one
@@ -74,7 +78,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models.joyai import MoE, _dense, _norm, _swiglu
 from ray_tpu.models.llama import apply_rope_half, rope_freqs, yarn_freqs
-from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.attention import (
+    causal_attention, remat_keeps, remat_policy)
 from ray_tpu.ops.moe import held_route_share
 from ray_tpu.util import tracing
 
@@ -356,15 +361,19 @@ class Laguna(nn.Module):
             rope_kind="yarn_half|default",
             rope_attention_factor=tables[False][1],
             dense_layers=cfg.n_layer - len(cfg.routed_layers),
-            blocks_remat=cfg.remat)
+            blocks_remat=cfg.remat,
+            blocks_remat_keeps=",".join(remat_keeps()) if cfg.remat else "")
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
         with jax.named_scope("embed"):
             x = self._constrain(wte(tokens))
-        # the amplitude is a number of the config, not of the trace
-        block = (nn.remat(Block, static_argnums=(3,)) if cfg.remat
-                 else Block)
+        # a recomputed block keeps its core's output and row statistics
+        # (0.2-0.27 GB a layer at 16,384 rows): the flash forward kernel
+        # runs once a layer, not twice. Static: the amplitude is a
+        # number of the config, not of the trace
+        block = (nn.remat(Block, static_argnums=(3,), policy=remat_policy())
+                 if cfg.remat else Block)
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):
                 angles, amplitude = tables[cfg.sliding(i)]

@@ -42,7 +42,10 @@ through ``models/gpt2.py::chunked_cross_entropy``).
 **What crosses blocks**: a block hands on, beside ``x``, the memory and
 the ``(K, V)`` pair (None before the layers that make them), as ZAYA's
 blocks hand on the router's state. With ``remat`` each block is
-recomputed in the backward pass and those are kept block outputs.
+recomputed in the backward pass and those are kept block outputs, as
+are an attention core's output and row statistics
+(``ops/attention.py::remat_policy``: the flash forward kernel runs once
+a layer; the note ``blocks_remat_keeps``).
 
 It is the benchmark's seventh language model
 (``phi-4-mini-flash-reasoning.b1-t4096`` runs the rule at ``N`` = 8,
@@ -70,7 +73,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models.nemotron_h import _conv_init, _dt_bias_init
 from ray_tpu.ops import ssm
-from ray_tpu.ops.attention import differential_attention
+from ray_tpu.ops.attention import (
+    differential_attention, remat_keeps, remat_policy)
 from ray_tpu.util import tracing
 
 
@@ -409,13 +413,16 @@ class Phi4Flash(nn.Module):
             ssm_tokens=tokens.size, ssm_inner=cfg.mamba_inner,
             ssm_state=cfg.ssm_state, ssm_dt_rank=cfg.dt_rank,
             yoco_memory_layer=cfg.memory_layer, yoco_kv_layer=cfg.kv_layer,
-            blocks_remat=cfg.remat)
+            blocks_remat=cfg.remat,
+            blocks_remat_keeps=",".join(remat_keeps()) if cfg.remat else "")
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
         with jax.named_scope("embed"):
             x = self._constrain(wte(tokens))
-        block = nn.remat(Block) if cfg.remat else Block
+        # a recomputed block keeps its attention core's output and row
+        # statistics (42 MB a layer at 4,096 rows), as models/laguna.py
+        block = nn.remat(Block, policy=remat_policy()) if cfg.remat else Block
         memory = kv = None
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):

@@ -36,8 +36,44 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 _NEG_INF = -1e30
+
+# The names of a flash core's output and of its rows' log-sum-exp: the
+# two residuals of its backward kernel that only the forward kernel
+# makes.
+ATTN_OUT = "attn_out"
+ATTN_LSE = "attn_lse"
+
+
+def name_core_results(out, lse):
+    """A forward kernel's two results under the names a recomputed
+    block's policy keeps (``remat_policy``), so that its backward pass
+    does not run the kernel again for them. For the forward rule of a
+    core's ``custom_vjp`` (``ops/pallas/flash_attention.py``,
+    ``ops/mla.py``), before the two part into primal and residuals: a
+    name on the primal alone would leave ``lse`` to be made again, and
+    the kernel with it."""
+    return checkpoint_name(out, ATTN_OUT), checkpoint_name(lse, ATTN_LSE)
+
+
+def remat_keeps(*more: str) -> tuple[str, ...]:
+    """The names a recomputed block keeps: a model's own (``more``)
+    first, then the attention cores' two."""
+    return (*more, ATTN_OUT, ATTN_LSE)
+
+
+def remat_policy(*more: str):
+    """``nn.remat`` / ``jax.checkpoint``'s policy for a block that holds
+    an attention core: everything is made again in the backward pass but
+    what carries one of ``remat_keeps(*more)``. So q, k and v are
+    projected again and the forward kernel, the dearest thing in the
+    block a byte kept, is not run again for an ``out`` and an ``lse``
+    the first pass made (docs/training_perf.md). The names are the
+    identity outside such a policy."""
+    return jax.checkpoint_policies.save_only_these_names(
+        *remat_keeps(*more))
 
 
 def _flash_ok(q, k, v) -> bool:

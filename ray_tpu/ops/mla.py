@@ -67,6 +67,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.attention import name_core_results
 from ray_tpu.util import tracing
 
 
@@ -231,7 +232,7 @@ def latent_attention(c_q, c_kv, k_r, up: UpProjections, angles, *,
         return fwd(*expand(*operands))[0]
 
     def attend_fwd(*operands):
-        out, lse = fwd(*expand(*operands))
+        out, lse = name_core_results(*fwd(*expand(*operands)))
         return out, (operands, out, lse)
 
     def attend_bwd(res, g):

@@ -81,6 +81,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ray_tpu.ops.attention import name_core_results
 from ray_tpu.util import tracing
 
 _NEG_INF = -1e30
@@ -779,7 +780,8 @@ def _flash_core(q, k, v, static: _Static):
 
 
 def _flash_core_fwd(q, k, v, static):
-    out, lse = _flash_fwd(q, k, v, **static._asdict())
+    out, lse = name_core_results(
+        *_flash_fwd(q, k, v, **static._asdict()))
     return out, (q, k, v, out, lse)
 
 
@@ -1201,7 +1203,8 @@ def mla_flash_core(qn, qr, kn, kr, v, static: _MlaStatic):
 
 
 def _mla_core_fwd(qn, qr, kn, kr, v, static):
-    out, lse = mla_flash_fwd(qn, qr, kn, kr, v, static=static)
+    out, lse = name_core_results(
+        *mla_flash_fwd(qn, qr, kn, kr, v, static=static))
     return out, (qn, qr, kn, kr, v, out, lse)
 
 

@@ -11,7 +11,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.attention import (
+    ATTN_LSE, ATTN_OUT, causal_attention, remat_keeps, remat_policy,
+)
 from ray_tpu.ops.pallas.flash_attention import (
     flash_attention,
     flash_attention_shapes_ok,
@@ -478,3 +480,101 @@ def test_the_benchmarks_fault_tool_reaches_the_backward_by_static(notes):
     wrong = fa._flash_bwd(*x, out, lse, out, **wider._asdict())
     assert wider.window == 134
     assert float(jnp.abs(right[0] - wrong[0]).max()) > 1e-3
+
+
+# ---------------------------------------------------------------------------
+# a recomputed block keeps its core's output and row statistics
+# ---------------------------------------------------------------------------
+
+def _kernel_calls(jaxpr) -> int:
+    """``pallas_call`` equations in a jaxpr, the jaxprs its equations
+    hold (a jit's, a checkpoint's, a custom rule's) among them."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _kernel_calls(sub)
+    return n
+
+
+def _block_of(core):
+    """A block round an attention core, as the models build it: the
+    core's operands are projections of the block's input, made inside
+    it, and its output goes through a gate and a matmul, so a
+    recomputed block has to make q, k and v again and reads ``out``
+    twice. Returns (block(x, w) -> a number, x, w)."""
+    t, e = 256, 128
+    kx, kw = jax.random.split(jax.random.key(23))
+    x = jax.random.normal(kx, (2, t, e))
+    if core.startswith("latent"):
+        from ray_tpu.models.llama import rope_freqs
+        from ray_tpu.ops import mla
+        ks = jax.random.split(kw, 6)
+        angles = rope_freqs(64, t, 10000.0)
+        w = (mla.UpProjections(*(
+            jax.random.normal(k, (r, 2 * d)) * 0.2
+            for k, r, d in zip(ks, (32, 32, 16, 16), (128, 64, 128, 128)))),
+            jax.random.normal(ks[4], (e, 32 + 16 + 64)) * 0.1,
+            jax.random.normal(ks[5], (2 * 128, e)) * 0.1)
+
+        def block(x, w):
+            up, down, out_w = w
+            c = x @ down
+            o = mla.latent_attention(
+                c[..., :32], c[..., 32:48], c[..., 48:], up, angles,
+                n_head=2, saved=core.removeprefix("latent_"),
+                interpret=True)
+            return ((o * jax.nn.sigmoid(o)) @ out_w * x).sum()
+        return block, x, w
+    window = {"causal": None, "window": 70}[core]
+    ks = jax.random.split(kw, 2)
+    w = (jax.random.normal(ks[0], (e, 3 * 2 * 64)) * 0.1,
+         jax.random.normal(ks[1], (2 * 64, e)) * 0.1)
+
+    def block(x, w):
+        qkv_w, out_w = w
+        q, k, v = jnp.split((x @ qkv_w).reshape(2, t, 6, 64), 3, axis=2)
+        o = flash_attention(q, k, v, block=64, interpret=True,
+                            window=window).reshape(2, t, -1)
+        return ((o * jax.nn.sigmoid(o)) @ out_w * x).sum()
+    return block, x, w
+
+
+def test_the_policy_keeps_a_models_own_names_first():
+    assert remat_keeps() == (ATTN_OUT, ATTN_LSE) == ("attn_out", "attn_lse")
+    assert remat_keeps("kda_gated_out") == (
+        "kda_gated_out", "attn_out", "attn_lse")
+
+
+@pytest.mark.parametrize(
+    "core", ["causal", "window", "latent_latents", "latent_expanded"])
+def test_a_recomputed_block_runs_the_forward_kernel_once(core):
+    """Under ``jax.checkpoint`` with ``remat_policy()`` the gradient of
+    a block holds two kernel calls, the forward and the backward, where
+    a checkpoint without a policy holds three (the forward again, for
+    an ``out`` and an ``lse`` the first pass made); the loss and the
+    gradients are the same bits as without any checkpoint, since the
+    backward kernel reads the same ``out`` and ``lse``; and outside a
+    checkpoint the names are the identity: two calls, as before them.
+    The gradients are run operation by operation: under a ``jit`` the
+    three are three programs, and XLA's CPU compiler fuses the latent
+    core's float32 rotation differently in each (an ulp, with the
+    policy or without any), which says nothing of what the operations
+    are."""
+    block, x, w = _block_of(core)
+
+    def grad(f):
+        return jax.value_and_grad(f, argnums=(0, 1))
+    kept = grad(jax.checkpoint(block, policy=remat_policy()))
+    more = grad(jax.checkpoint(block, policy=remat_policy("another")))
+    again = grad(jax.checkpoint(block))
+    plain = grad(block)
+    calls = {name: _kernel_calls(jax.make_jaxpr(f)(x, w).jaxpr)
+             for name, f in [("kept", kept), ("more", more),
+                             ("again", again), ("plain", plain)]}
+    assert calls == {"kept": 2, "more": 2, "again": 3, "plain": 2}
+    want = jax.tree_util.tree_leaves(plain(x, w))
+    got = jax.tree_util.tree_leaves(kept(x, w))
+    assert len(got) == len(want) > 3
+    assert all(bool((a == b).all()) for a, b in zip(got, want))
+    assert all(float(jnp.abs(a).max()) > 0 for a in want)

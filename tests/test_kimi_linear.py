@@ -435,6 +435,31 @@ def test_the_step_reports_the_kda_keys_and_notes_what_the_layers_are(
         < float(metrics["grad_norm"])
 
 
+@pytest.mark.parametrize("remat, keeps", [
+    (True, "kda_gated_out,attn_out,attn_lse"), (False, "")],
+    ids=["recomputed", "kept_whole"])
+def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
+                                                       monkeypatch):
+    """``blocks_remat_keeps`` beside ``blocks_remat``: the names a
+    recomputed block's policy keeps (the KDA mixers' gated output
+    first, then the latent core's output and row statistics), and every
+    block's checkpoint carries a policy; nothing where the blocks are
+    not recomputed."""
+    cfg = KimiLinearConfig.tiny(remat=remat, **F32)
+    model = KimiLinear(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    traced = jax.make_jaxpr(lambda p, t: model.apply(
+        {"params": p}, t, return_hidden=True,
+        mutable=["moe", "stats"])[0])(params, _batch(0, cfg)["tokens"])
+    assert notes["blocks_remat"] is remat
+    assert notes["blocks_remat_keeps"] == keeps
+    with_policy = [e for e in traced.jaxpr.eqns
+                   if e.primitive.name == "remat2" and e.params["policy"]]
+    assert len(with_policy) == (cfg.n_layer if remat else 0)
+
+
 def test_a_kda_layer_has_its_own_scopes_and_the_mla_layer_joyais():
     """``blocks/h_i/kda`` with its seven scopes in a KDA layer;
     ``blocks/h_3/attn`` with ``q_up``, ``kv_down``, ``kv_up``, ``core``

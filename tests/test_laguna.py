@@ -443,6 +443,30 @@ def test_a_train_step_runs_and_reports_the_load_of_every_routed_layer():
     assert float(metrics["lm_loss"]) == float(metrics["loss"])
 
 
+@pytest.mark.parametrize("remat, keeps", [
+    (True, "attn_out,attn_lse"), (False, "")],
+    ids=["recomputed", "kept_whole"])
+def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
+                                                       monkeypatch):
+    """``blocks_remat_keeps`` beside ``blocks_remat``: the names a
+    recomputed block's policy keeps (the attention cores' output and row
+    statistics), and every block's checkpoint carries a policy; nothing
+    where the blocks are not recomputed."""
+    cfg = LagunaConfig.tiny(remat=remat, **F32)
+    model = Laguna(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    traced = jax.make_jaxpr(lambda p, t: model.apply(
+        {"params": p}, t, return_hidden=True,
+        mutable=["moe", "stats"])[0])(params, _batch(0, cfg)["tokens"])
+    assert notes["blocks_remat"] is remat
+    assert notes["blocks_remat_keeps"] == keeps
+    with_policy = [e for e in traced.jaxpr.eqns
+                   if e.primitive.name == "remat2" and e.params["policy"]]
+    assert len(with_policy) == (cfg.n_layer if remat else 0)
+
+
 def test_the_notes_say_the_stack_the_tables_the_gate_and_the_share(
         monkeypatch):
     cfg = LagunaConfig.tiny(**F32)

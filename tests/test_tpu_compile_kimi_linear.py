@@ -83,6 +83,7 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     compiled = step.lower(state, batch).compile()
     assert notes["attn_kind"] == "kda_mla"
     assert notes["attn_layers"] == "KKKMK" and notes["blocks_remat"] is True
+    assert notes["blocks_remat_keeps"] == "kda_gated_out,attn_out,attn_lse"
     assert notes["kda_path"] == "pallas_chunked" and notes["kda_chunk"] == 64
     assert notes["kda_heads"] == 32 and notes["kda_state"] == [128, 128]
     assert notes["flash_path"] == "mla_multi_block"
@@ -109,8 +110,9 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
              for line in calls]
     assert {"gmm", "tgmm"} <= set(kinds)
     assert kinds.count("_ce_lse_fwd") == 1      # the head's forward (PR 51)
-    # the forward kernel twice (the block is recomputed), the backward once
-    assert kinds.count("mla_flash_fwd") == 2
+    # the forward kernel once (the block is recomputed and keeps its
+    # core's output and row statistics), the backward once
+    assert kinds.count("mla_flash_fwd") == 1
     assert kinds.count("mla_flash_bwd") == 1
     flash = [line for kind, line in zip(kinds, calls) if "mla_flash" in kind]
     assert all("/h_3/attn/core/" in line for line in flash)

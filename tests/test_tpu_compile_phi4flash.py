@@ -86,6 +86,7 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert notes["attn_kind"] == "differential"
     assert notes["layer_pattern"] == "MSMSMFGX"
     assert notes["blocks_remat"] is True and notes["attn_window"] == 512
+    assert notes["blocks_remat_keeps"] == "attn_out,attn_lse"
     assert notes["ssm_kind"] == "mamba1" and notes["ssm_tokens"] == 4096
     assert (notes["ssm_inner"], notes["ssm_state"], notes["ssm_dt_rank"],
             notes["ssm_chunk"]) == (5120, 16, 160, 64)   # the kernels' rows
@@ -117,13 +118,16 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
              and line not in head]
     # the head's forward (PR 51): one kernel under ``loss``
     assert len(head) == 1 and "/loss/" in head[0]
-    # four attention layers: the forward kernel twice (the block is
-    # recomputed), the backward once, each over [1, 4096, 80 * 64]
-    assert len(calls) == 4 * 3
+    # four attention layers: the forward kernel once (the block is
+    # recomputed and keeps its core's output and row statistics), the
+    # backward once, each over [1, 4096, 80 * 64]
+    assert len(calls) == 4 * 2
+    assert sum("jit(_flash_fwd)" in line for line in calls) == 4
+    assert sum("jit(_flash_bwd)" in line for line in calls) == 4
     assert all(re.search(r"/h_[1357]/attn/(window|core|cross)/", line)
                for line in calls)
-    assert sum("/attn/window/" in line for line in calls) == 2 * 3
-    assert sum("/attn/cross/" in line for line in calls) == 3
+    assert sum("/attn/window/" in line for line in calls) == 2 * 2
+    assert sum("/attn/cross/" in line for line in calls) == 2
     assert all("bf16[1,4096,5120]" in line for line in calls)
     assert not any("/gmu/" in line for line in calls)
     # three Mamba layers, the same count, each over [1, 4096, 5120]
