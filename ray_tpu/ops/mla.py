@@ -11,9 +11,20 @@ head that every query head shares. With ``h`` the normed block input:
     c_kv | k_r   = h W_kva, c_kv = RMSNorm(c_kv)    [rkv | dr] ``kv_down``
     k_nope | v   = c_kv W_kvb, per head [dn | dv]            ``kv_up``
     q_rope, k_r  take RoPE over interleaved pairs            ``rope``
-    s_i = (q_nope_i k_nope_i^T + q_rope_i k_r^T) / sqrt(dn + dr)
+    s_i = (q_nope_i k_nope_i^T + q_rope_i k_r^T) * scale
     o_i = causal_softmax(s_i) v_i                            ``core``
     y   = concat_i(o_i) W_o                                  ``out_proj``
+
+``scale`` is ``(dn + dr)^-1/2`` unless the caller gives one. **Under
+YaRN** (``models/joyai.py::YarnScaling``; Xing4.0-29B-A4B is the second
+model on this function) the DeepSeek-V3 code has two factors and both
+come through arguments: ``rope_amplitude``, on the rotated lanes of
+``q_rope`` and ``k_r`` alike (YaRN's factor on cos and sin), and
+``scale``, which carries ``m^2`` on the whole score, nope and rope
+parts alike. The scale is one of the kernels' static arguments
+(``mla_flash_static(t, dn, dr, scale)``), a constant inside their
+bodies: scaling ``q`` in front instead would cost a pass over it in
+each direction.
 
 The down projections and the output projection are the model's own
 dense layers (``models/joyai.py``); this file holds everything between
@@ -117,7 +128,7 @@ def mla_path(batch: int, t: int, n_head: int, dn: int, dr: int, dv: int,
     return "kernel", axes
 
 
-def _xla_core(qn, qr, kn, kr, v, n_head: int):
+def _xla_core(qn, qr, kn, kr, v, n_head: int, scale: float):
     """The same attention as one masked softmax over the concatenated
     keys, float32 scores: the path of the CPU and of shapes that do not
     tile."""
@@ -128,7 +139,7 @@ def _xla_core(qn, qr, kn, kr, v, n_head: int):
         kn.reshape(b, t, n_head, -1),
         jnp.broadcast_to(kr[:, :, None], (b, t, n_head, kr.shape[-1]))], -1)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+                   preferred_element_type=jnp.float32) * scale
     s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
     o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1).astype(v.dtype),
                    v.reshape(b, t, n_head, -1),
@@ -148,7 +159,8 @@ def _over_batch(fn, mesh, axes, n_in: int):
 
 def latent_attention(c_q, c_kv, k_r, up: UpProjections, angles, *,
                      n_head: int, saved: str = "latents", mesh=None,
-                     interpret: bool = False):
+                     interpret: bool = False, scale: float | None = None,
+                     rope_amplitude: float = 1.0):
     """``concat_i(o_i)``, [B, T, H*dv], from the normed latents ``c_q``
     [B, T, rq] and ``c_kv`` [B, T, rkv], the unrotated shared key
     ``k_r`` [B, T, dr], the up-projections and the rotation's
@@ -163,6 +175,13 @@ def latent_attention(c_q, c_kv, k_r, up: UpProjections, angles, *,
     no positions (``mla_use_nope``): the 64 "rotary" lanes stay in both
     score products unrotated, no ``rope`` scope opens, in either pass,
     and the note ``mla_positions`` says ``none`` (``rope`` otherwise).
+
+    ``scale`` multiplies the whole score, both products alike (None:
+    ``(dn + dr)^-1/2``); it is one of the kernels' static arguments and
+    costs no pass (the note ``mla_scale``). ``rope_amplitude``
+    multiplies the rotated lanes of ``q_rope`` and ``k_r`` (YaRN's
+    factor on cos and sin; 1 leaves the rotation as it is traced
+    without it).
 
     ``saved``: what the backward pass keeps (module docstring). The
     model takes the default; ``"expanded"`` is what the tests hold its
@@ -182,9 +201,11 @@ def latent_attention(c_q, c_kv, k_r, up: UpProjections, angles, *,
     dt = c_q.dtype
     dr = k_r.shape[-1]
     dn, dv = up.q_nope.shape[-1] // n_head, up.v.shape[-1] // n_head
+    scale = float((dn + dr) ** -0.5 if scale is None else scale)
+    tracing.note_trace(mla_scale=scale)
     path, axes = mla_path(b, t, n_head, dn, dr, dv, mesh, interpret)
 
-    def expand(c_q, c_kv, k_r, *up):
+    def expand(angles, c_q, c_kv, k_r, *up):
         """The kernel's five operands from the latents."""
         q_nope_w, q_rope_w, k_nope_w, v_w = up
         with jax.named_scope("q_up"):
@@ -199,10 +220,13 @@ def latent_attention(c_q, c_kv, k_r, up: UpProjections, angles, *,
             # not a rounding of this one (the gradient norm's distance
             # from the float32 reference halves; PERF.md 6, PR 34)
             f32 = jnp.float32
-            qr = apply_rope(qr.reshape(b, t, n_head, dr).astype(f32),
-                            angles[:t]).reshape(b, t, n_head * dr).astype(dt)
-            kr = apply_rope(k_r[:, :, None].astype(f32),
-                            angles[:t])[:, :, 0].astype(dt)
+            def amp(x):     # YaRN's factor on cos and sin
+                return x if rope_amplitude == 1.0 else x * rope_amplitude
+            qr = amp(apply_rope(qr.reshape(b, t, n_head, dr).astype(f32),
+                                angles[:t])).reshape(
+                                    b, t, n_head * dr).astype(dt)
+            kr = amp(apply_rope(k_r[:, :, None].astype(f32),
+                                angles[:t]))[:, :, 0].astype(dt)
         return qn, qr, kn, kr, v
 
     operands = (c_q, c_kv, k_r, *up)
@@ -210,38 +234,44 @@ def latent_attention(c_q, c_kv, k_r, up: UpProjections, angles, *,
         tracing.note_trace(flash_path="xla", flash_layout="concatenated")
 
         def attend(*operands):
-            qkv = expand(*operands)
+            qkv = expand(angles, *operands)
             with jax.named_scope("core"):
-                return _xla_core(*qkv, n_head)
+                return _xla_core(*qkv, n_head, scale)
         if saved == "latents":
             attend = jax.checkpoint(attend)
         return attend(*operands)
 
-    static = mla_flash_static(t, dn, dr, interpret=interpret)
+    static = mla_flash_static(t, dn, dr, scale, interpret=interpret)
 
     def kernel(fn, n_in):
         return jax.named_scope("core")(_over_batch(
             functools.partial(fn, static=static), mesh, axes, n_in))
 
     if saved == "expanded":     # the kernels' own custom_vjp keeps them
-        return kernel(mla_flash_core, 5)(*expand(*operands))
+        return kernel(mla_flash_core, 5)(*expand(angles, *operands))
     fwd, bwd = kernel(mla_flash_fwd, 5), kernel(mla_flash_bwd, 8)
 
+    # the angles enter as an operand of their own (None: a tree of no
+    # leaves), not through ``expand``'s closure: the backward
+    # rule is traced when the cotangent arrives, which under a
+    # recomputed block (``nn.remat``) is after the trace that made the
+    # angles has ended
     @jax.custom_vjp
-    def attend(*operands):
-        return fwd(*expand(*operands))[0]
+    def attend(angles, *operands):
+        return fwd(*expand(angles, *operands))[0]
 
-    def attend_fwd(*operands):
-        out, lse = name_core_results(*fwd(*expand(*operands)))
-        return out, (operands, out, lse)
+    def attend_fwd(angles, *operands):
+        out, lse = name_core_results(*fwd(*expand(angles, *operands)))
+        return out, (angles, operands, out, lse)
 
     def attend_bwd(res, g):
-        operands, out, lse = res
+        angles, operands, out, lse = res
         # as jax.checkpoint does: without the barrier XLA finds the
         # forward pass's identical matmuls and keeps their results
         # instead (common subexpressions), and nothing is saved
         operands, g = jax.lax.optimization_barrier((operands, g))
-        qkv, pull = jax.vjp(expand, *operands)
-        return pull(bwd(*qkv, out, lse, g))
+        qkv, pull = jax.vjp(functools.partial(expand, angles), *operands)
+        return (None if angles is None else jnp.zeros_like(angles),
+                *pull(bwd(*qkv, out, lse, g)))
     attend.defvjp(attend_fwd, attend_bwd)
-    return attend(*operands)
+    return attend(angles, *operands)

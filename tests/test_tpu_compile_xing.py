@@ -1,0 +1,243 @@
+"""The Xing4.0 cell's new pieces compile for the real chip, with no chip
+here (as ``test_tpu_compile_joyai.py``: the TPU compiler for a described
+v5e; nothing runs, so nothing here is a result or a time). The real-size
+step is compiled once for every assertion on it.
+
+**The reading that decided the cell's memory step** (``memory_analysis()``
+of the step below, PR 54): with the MTP module 913.5 M parameters compile
+to 14.73 GB (arguments 9.14, temporaries 5.59, of which 3.65 are the
+gradients), over the 14.6 GB the cell allows itself of the chip's 15.75;
+halving the loss's chunk leaves it where it is. Without the module, which
+is what the cell runs and this file compiles: 759.3 M, 12.03 GB
+(arguments 7.60, temporaries 4.43)."""
+
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+T = 4096        # the cell's row: rope_scaling's original length
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, no description
+        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _arg(device):
+    one = SingleDeviceSharding(device)
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one)
+
+
+def _cell_config():
+    from ray_tpu.models.joyai import JoyAIConfig
+    return JoyAIConfig.xing4_0_29b_a4b(
+        n_layer=5, dense_layers=1, experts_held=(0, 8), vocab_size=16384,
+        mtp_depth=0, remat=True)
+
+
+def test_latent_attention_kernels_compile_at_4096_rows_with_a_scale(v5e):
+    """One sequence of 4,096 tokens, 32 heads of 128 + 64 against values
+    of 128, at YaRN's scale (0.14468, a static argument of both
+    kernels): two custom calls, no ``[H, T, T]`` array, and the scale is
+    the compiled kernels' and not the default's."""
+    from ray_tpu.ops.pallas.flash_attention import (
+        mla_flash_core, mla_flash_static)
+    arg = _arg(v5e[0])
+    scale = _cell_config().mla_scale
+    static = mla_flash_static(T, 128, 64, scale)
+    assert static.scale == pytest.approx(0.144680, rel=1e-5)
+    assert mla_flash_static(T, 128, 64).scale == pytest.approx(192 ** -0.5)
+
+    def loss(*operands):
+        return mla_flash_core(*operands, static).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg((1, T, 32 * 128), jnp.bfloat16),
+        arg((1, T, 32 * 64), jnp.bfloat16),
+        arg((1, T, 32 * 128), jnp.bfloat16),
+        arg((1, T, 64), jnp.bfloat16),
+        arg((1, T, 32 * 128), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # no score matrix ([1, T, 32 x 128] is [1, 4096, 4096] here: the
+    # heads' own dimension tells them apart)
+    assert f"32,{T},{T}" not in text and f"{T},32,{T}" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_held_experts_compile_at_a_hidden_size_of_3584(v5e, monkeypatch):
+    """The cell's routed layer (4,096 tokens, 64 sigmoid-routed SwiGLU
+    experts of 1,024 of which 8 are held, top-4) at ``k`` = 3,584 = 7 x
+    512, no power of two: Mosaic takes the grouped matmuls at the tile
+    ``ops/moe.py`` chooses (nine custom calls: three matrices, each
+    forward, for its input and for its weights)."""
+    from ray_tpu.ops import moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe.grouped_matmul_path() == "megablox_gmm"
+    arg = _arg(v5e[0])
+
+    def loss(x, router, bias, gate, up, down):
+        y, _, _, _ = moe.routed_ffn(
+            x, router, gate, up, down, top_k=4, norm_topk_prob=True,
+            router="sigmoid", select_bias=bias, route_scale=2.0,
+            expert="swiglu", experts_held=(0, 8))
+        return y.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        arg((1, T, 3584), jnp.bfloat16), arg((3584, 64), jnp.float32),
+        arg((64,), jnp.float32), arg((8, 3584, 1024), jnp.float32),
+        arg((8, 3584, 1024), jnp.float32),
+        arg((8, 1024, 3584), jnp.float32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 9
+
+
+@pytest.fixture(scope="module")
+def real_size_step(v5e):
+    """The cell's step as the builder makes it, compiled once: (config,
+    the trace's notes, the compiled program, its text)."""
+    import optax
+
+    from ray_tpu import train
+    from ray_tpu.models.joyai import JoyAI, joyai_loss_fn
+    from ray_tpu.util import tracing
+    arg = _arg(v5e[0])
+    cfg = _cell_config()
+    model = JoyAI(cfg)
+    opt = optax.chain(
+        optax.clip_by_global_norm(1.0),
+        optax.adamw(2e-5, b1=0.9, b2=0.95, weight_decay=0.1,
+                    mu_dtype=jnp.bfloat16))
+    step = train.make_train_step(
+        joyai_loss_fn(model, ce_chunk=2048), opt,
+        grad_groups={"grad_norm_hc": r"(^|/)hc_(attn|mlp)/(phi|b|alpha)$"})
+    state = jax.tree.map(
+        lambda z: arg(z.shape, z.dtype),
+        jax.eval_shape(lambda: train.init_train_state(
+            model.init_params(jax.random.key(0)), opt, None)))
+    batch = {k: arg((1, cfg.seq_len), jnp.int32)
+             for k in ("tokens", "targets")}
+    notes = {}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        patch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
+        patch.setattr(tracing, "note_trace", notes.update)
+        compiled = step.lower(state, batch).compile()
+    return cfg, notes, compiled, compiled.as_text()
+
+
+def test_the_real_size_step_compiles_inside_the_chips_memory(real_size_step):
+    """One dense and four routed layers with 8 of 64 experts held, four
+    residual streams, 16,384 rows of both tables, adamw with a bf16
+    first moment, 4,096 tokens, blocks recomputed: arguments +
+    temporaries + unaliased outputs are inside the 14.6 GB that the
+    cell allows itself of the v5e's 15.75 (12.03 GB at PR 54; the
+    docstring above has the reading with the MTP module)."""
+    cfg, _, compiled, _ = real_size_step
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + max(0, m.output_size_in_bytes - m.alias_size_in_bytes))
+    print(f"program {total / 1e9:.2f} GB: arguments "
+          f"{m.argument_size_in_bytes / 1e9:.2f}, temporaries "
+          f"{m.temp_size_in_bytes / 1e9:.2f}")
+    assert cfg.num_params() == pytest.approx(759.3e6, rel=1e-4)
+    assert m.argument_size_in_bytes == pytest.approx(
+        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
+    assert 0.25 * 15.75e9 < total < 14.6e9
+
+
+def test_the_real_size_step_says_what_it_ran(real_size_step):
+    cfg, notes, _, _ = real_size_step
+    assert {k: notes[k] for k in (
+        "flash_path", "flash_layout", "mla_saved", "flash_bwd_resident_rows",
+        "rope_kind", "hc_mult", "hc_sinkhorn_iters", "hc_state_dtype",
+        "blocks_remat", "blocks_remat_keeps", "moe_path",
+        "moe_experts_held")} == {
+        "flash_path": "mla_multi_block", "flash_layout": "bthd",
+        "mla_saved": "latents", "flash_bwd_resident_rows": T,
+        "rope_kind": "yarn", "hc_mult": 4, "hc_sinkhorn_iters": 20,
+        "hc_state_dtype": "bfloat16", "blocks_remat": True,
+        "blocks_remat_keeps": "attn_out,attn_lse",
+        "moe_path": "megablox_gmm", "moe_experts_held": [0, 8]}
+    assert notes["mla_scale"] == pytest.approx(cfg.mla_scale)
+
+
+def test_the_real_size_steps_kernels_and_layouts(real_size_step):
+    """Every layer's attention is the kernel, forward once (a recomputed
+    block keeps its results) and backward once; the head's forward is
+    one custom call under ``loss``; no ``[H, T, T]`` array exists; and the
+    n-stream state is ``[1, T, n d]`` in bfloat16 everywhere: never
+    float32 at that width, never with the 4 streams second-minor."""
+    cfg, _, _, text = real_size_step
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    flash = [line for line in calls if "/attn/core/" in line]
+    assert sum("mla_flash_fwd" in line for line in flash) == 5
+    assert sum("mla_flash_bwd" in line for line in flash) == 5
+    head = [line for line in calls if "jit(_ce_lse_fwd)" in line]
+    assert len(head) == 1 and "/loss/" in head[0]
+    assert f"32,{T},{T}" not in text and f"{T},32,{T}" not in text
+    # the buffers are the entry computation's results (inside a fusion a
+    # float32 value of the state's width lives in registers)
+    entry = text[text.rindex("\nENTRY "):]
+    wide = cfg.hc_mult * cfg.n_embd
+    assert f"bf16[1,{T},{wide}]" in entry
+    assert f"f32[1,{T},{wide}]" not in entry
+    assert f"f32[{T},{wide}]" not in entry
+    assert not re.search(rf"\[(1,)?{T},{cfg.hc_mult},{cfg.n_embd}\]", entry)
+    # the maps' scopes reach the compiled program's op names
+    for scope in ("h_4/hc_attn/maps", "h_4/hc_mlp/post", "h_0/hc_mlp/pre",
+                  "embed/hc_expand", "blocks/hc_collapse"):
+        assert scope in text, scope
+
+
+def test_rotated_latent_attention_under_a_recomputed_block():
+    """The kernels' path (interpreted, on the CPU) inside
+    ``jax.checkpoint`` with the rotation's angles made outside it, as
+    ``nn.remat`` hands them to a block: the angles are an operand of the
+    attention's custom gradient, not a closed-over tracer of a trace
+    that has ended when the backward rule is traced (which raised
+    ``UnexpectedTracerError`` before PR 54; no earlier model rotated
+    latent attention inside a recomputed block). Gradients are those of
+    the kernels' own rule."""
+    from ray_tpu.models.llama import rope_freqs
+    from ray_tpu.ops import mla
+    t, h = 128, 2
+    ks = jax.random.split(jax.random.key(0), 6)
+    up = mla.UpProjections(*(jax.random.normal(k, (16, h * w)) * 0.1
+                             for k, w in zip(ks, (128, 64, 128, 128))))
+    c = jax.random.normal(ks[4], (1, t, 16))
+    k_r = jax.random.normal(ks[5], (1, t, 64))
+
+    def attend(c, up, angles, **kw):
+        return mla.latent_attention(c, c, k_r, up, angles, n_head=h,
+                                    interpret=True, scale=0.1, **kw).sum()
+
+    def recomputed(c, up):
+        return jax.checkpoint(attend)(c, up, rope_freqs(64, t, 1e4))
+
+    def plain(c, up):
+        return attend(c, up, rope_freqs(64, t, 1e4), saved="expanded")
+
+    got = jax.jit(jax.grad(recomputed, argnums=(0, 1)))(c, up)
+    want = jax.jit(jax.grad(plain, argnums=(0, 1)))(c, up)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.abs(g - w).max()) <= 1e-5 * float(jnp.abs(w).max())
