@@ -216,8 +216,8 @@ def _kda_core(q, k, v, f_low, b_logit, g_low, w, *, heads: int, chunk: int,
     kd = inner // heads
     f32, dt = jnp.float32, q.dtype
     with jax.named_scope("conv"):
-        q, k, v = (ssm.causal_conv1d_silu(z, w[name]) for z, name in (
-            (q, "q_conv"), (k, "k_conv"), (v, "v_conv")))
+        q, k, v = (ssm.causal_conv1d_silu(z, w[f"{n}_conv"], mesh=mesh)
+                   for z, n in zip((q, k, v), "qkv"))
     with jax.named_scope("decay"):
         f = (f_low @ w["f_b"].astype(dt)).astype(f32) + w["dt_bias"]
         g = (-jnp.exp(w["A_log"])[:, None]

@@ -196,6 +196,7 @@ class _Conv(nn.Module):
     """``conv1d``: the depthwise causal convolution's [K, C] kernel and
     bias, then SiLU."""
     config: NemotronHConfig
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, x):
@@ -204,7 +205,7 @@ class _Conv(nn.Module):
                        (cfg.conv_kernel, cfg.conv_width), cfg.param_dtype)
         b = self.param("bias", _conv_init(cfg), (cfg.conv_width,),
                        cfg.param_dtype)
-        return ssm.causal_conv1d_silu(x, w, b)
+        return ssm.causal_conv1d_silu(x, w, b, mesh=self.mesh)
 
 
 class _GateNorm(nn.Module):
@@ -233,7 +234,7 @@ class Mamba2Mixer(nn.Module):
         inner = cfg.mamba_inner
         zxbcdt = _dense(cfg)(inner + cfg.conv_width + h, name="in_proj")(x)
         z, xbc, dt = jnp.split(zxbcdt, [inner, inner + cfg.conv_width], -1)
-        xbc = _Conv(cfg, name="conv")(xbc)
+        xbc = _Conv(cfg, self.mesh, name="conv")(xbc)
         xs, bs, cs = jnp.split(xbc, [inner, inner + g * n], -1)
         dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (h,),
                              jnp.float32)

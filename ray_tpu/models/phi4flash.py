@@ -217,6 +217,7 @@ class _Conv(nn.Module):
     """``conv1d``: the depthwise causal convolution's [K, C] kernel and
     bias, then SiLU."""
     config: Phi4FlashConfig
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, x):
@@ -225,7 +226,7 @@ class _Conv(nn.Module):
                        (cfg.conv_kernel, cfg.mamba_inner), cfg.param_dtype)
         b = self.param("bias", _conv_init(cfg), (cfg.mamba_inner,),
                        cfg.param_dtype)
-        return ssm.causal_conv1d_silu(x, w, b)
+        return ssm.causal_conv1d_silu(x, w, b, mesh=self.mesh)
 
 
 class _DtProj(nn.Module):
@@ -259,7 +260,7 @@ class Mamba(nn.Module):
         f32 = jnp.float32
         x, z = jnp.split(_dense(cfg)(2 * inner, name="in_proj")(h), 2, -1)
         with jax.named_scope("conv"):
-            x = _Conv(cfg, name="conv1d")(x)
+            x = _Conv(cfg, self.mesh, name="conv1d")(x)
         dbc = _dense(cfg)(r + 2 * n, name="x_proj")(x)
         with jax.named_scope("dt"):
             dt = jax.nn.softplus(_DtProj(cfg, name="dt_proj")(dbc[..., :r]))

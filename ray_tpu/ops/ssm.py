@@ -1,10 +1,9 @@
 """State-space (Mamba) ops: the Mamba-2 selective scan in its chunked form,
 the depthwise causal convolution (with its SiLU) in front of it and the
-grouped gated RMSNorm behind it. Of the three the scan and the norm are
-Pallas kernels where a TPU program can take them (below); the
-convolution is XLA's everywhere, recomputed in the backward, as is the
-norm on its XLA path. Nemotron-H's ``M`` layers
-(``models/nemotron_h.py``) are the caller.
+grouped gated RMSNorm behind it. All three are Pallas kernels where a
+TPU program can take them (below) and XLA functions elsewhere, the
+convolution and the norm recomputed in the backward on either path.
+Nemotron-H's ``M`` layers (``models/nemotron_h.py``) are the caller.
 
 The recurrence, a head (``S`` is ``[P, N]``)::
 
@@ -54,12 +53,29 @@ that the norm stays in the row-major ``[B, T, H*P]`` the scan's kernel
 writes and ``out_proj``'s matmul reads; behind a custom call the XLA
 function's reshape to ``[.., groups, C / groups]`` was a relayout of a
 float32 array three times a layer. Everywhere else that XLA function
-runs (``xla``). The convolution has no kernel.
+runs (``xla``).
+
+The convolution goes in front of the scan: ``conv_path()`` gives it
+its own two kernels (``ops/pallas/causal_conv.py``, ``pallas``) on a
+TPU where ``x`` is ``[b, T, C]`` with ``C`` whole 128-lane tiles, a row
+reads no more rows before itself than one sublane tile holds, and the
+program is one device's or shards the batch alone (the same
+``_kernel_batch_axes``): each pass reads its operands once, the rows
+before a block through a second view of ``x`` and the rows after it
+carried in VMEM, float32 inside from the operands' own dtype. As XLA's
+fusions over a padded copy the forward ran at 2.9 times its bytes and
+the backward at 5.7 (PERF.md section 6, PRs 47 and 55). Everywhere else
+(the CPU, the tiny presets' widths, ``sp`` / ``tp``) the XLA function
+runs (``xla``), in the compute type, and is what the kernels are tested
+against. Both keep ``x`` for the backward and nothing else. Every call
+notes ``conv_path``, ``conv_taps`` and ``conv_cols`` for the trace in
+progress.
 
 Kimi Delta Attention (``ops/kda.py``, ``models/kimi_linear.py``) takes
 two things from here: the convolution, three times a layer and without
-a bias, and ``sigmoid_gated_head_rms_norm`` at the end of this file, its
-own output gate (a sigmoid on the *normed* output, where Mamba-2 norms
+a bias (36 kernel calls a step over four layers), and
+``sigmoid_gated_head_rms_norm`` at the end of this file, its own output
+gate (a sigmoid on the *normed* output, where Mamba-2 norms
 the gated one).
 
 **Mamba-1** (``mamba1_scan``, Phi-4-mini-flash's ``M`` layers,
@@ -83,7 +99,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.pallas import gated_norm, ssd_scan
+from ray_tpu.ops.pallas import causal_conv, gated_norm, ssd_scan
 from ray_tpu.util import tracing
 
 _BOUNDARY = "ssm_boundary_states"
@@ -112,6 +128,20 @@ def norm_path(shape, groups: int, mesh=None) -> str:
     serve), else ``xla``."""
     if (jax.default_backend() == "tpu" and len(shape) == 3
             and gated_norm.shapes_ok(shape[-1], groups)
+            and _kernel_batch_axes(mesh, shape[0]) is not None):
+        return "pallas"
+    return "xla"
+
+
+def conv_path(shape, taps: int, mesh=None) -> str:
+    """Which convolution ``causal_conv1d_silu`` compiles for ``x``
+    [b, T, C] at ``taps`` taps: ``pallas`` (the kernels of
+    ``ops/pallas/causal_conv.py``) on a TPU where ``C`` is whole
+    128-lane tiles, the rows a row reads before itself fit one sublane
+    tile and ``_kernel_batch_axes`` finds the program one the kernels
+    can serve, else ``xla``."""
+    if (jax.default_backend() == "tpu" and len(shape) == 3
+            and causal_conv.shapes_ok(shape[-1], taps)
             and _kernel_batch_axes(mesh, shape[0]) is not None):
         return "pallas"
     return "xla"
@@ -224,14 +254,30 @@ def _padded_scan(x, dt, A, B, C, D, *, chunk: int):
     return (y + D.astype(jnp.float32)[:, None] * x).astype(x.dtype)
 
 
-@jax.checkpoint
-def causal_conv1d_silu(x, weight, bias=None):
+def causal_conv1d_silu(x, weight, bias=None, *, mesh=None):
     """``silu`` of the depthwise causal convolution over time: ``y[t,
     c] = bias[c] + sum_j weight[j, c] * x[t - (K - 1) + j, c]``, zeros
     before the start. x [batch, T, C]; weight [K, C]; bias [C], or None
-    for a convolution without one (Kimi Delta Attention's three). K
-    shifted multiply-adds (K is 4), recomputed in the backward: only
-    ``x`` is kept, not the sum in front of the SiLU."""
+    for a convolution without one (Kimi Delta Attention's three).
+    Recomputed in the backward: only ``x`` is kept, not the sum in
+    front of the SiLU. ``mesh`` is the mesh the program is sharded
+    over, if the caller knows one: ``conv_path`` decides from it
+    between the kernels (``ops/pallas/causal_conv.py``, float32 inside)
+    and the XLA function below (the compute type throughout)."""
+    path = conv_path(x.shape, weight.shape[0], mesh)
+    tracing.note_trace(conv_path=path, conv_taps=weight.shape[0],
+                       conv_cols=x.shape[-1])
+    if path == "pallas":
+        return causal_conv.causal_conv(
+            x, weight, bias, mesh=mesh,
+            batch_axes=_kernel_batch_axes(mesh, x.shape[0]))
+    return _causal_conv1d_silu_xla(x, weight, bias)
+
+
+@jax.checkpoint
+def _causal_conv1d_silu_xla(x, weight, bias=None):
+    """K shifted multiply-adds (K is 4) over a padded copy of ``x`` and
+    a SiLU."""
     K = weight.shape[0]
     T = x.shape[1]
     padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))

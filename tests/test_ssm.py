@@ -6,7 +6,8 @@ backward keeps; and the same for the scan's Pallas kernels
 (``ops/pallas/ssd_scan.py``) in ``interpret`` mode, with which of the
 two paths ``scan_path`` picks for a shape, a backend and a mesh; and the
 gated norm's kernels (``ops/pallas/gated_norm.py``) the same way, with
-``norm_path``."""
+``norm_path``; and the convolution's (``ops/pallas/causal_conv.py``),
+with ``conv_path``."""
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +15,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops import ssm
-from ray_tpu.ops.pallas import gated_norm, ssd_scan
+from ray_tpu.ops.pallas import causal_conv, gated_norm, ssd_scan
 
 B, H, P, G, N, CHUNK = 2, 4, 8, 2, 16, 16
 
@@ -486,3 +487,211 @@ def test_kernel_norm_over_a_batch_sharded_mesh_is_the_one_device_norm():
 def test_columns_the_norms_kernels_do_not_tile_are_refused_by_name():
     with pytest.raises(ValueError, match="do not tile"):
         _norm_kernel(3)(*_norm_inputs((2, 16, 12), jnp.float32)[:3])
+
+
+# ---------------------------------------------------------------------------
+# the convolution's kernels, interpreted here, against the XLA function
+# (which this backend's ``causal_conv1d_silu`` is)
+# ---------------------------------------------------------------------------
+
+def _conv_inputs(shape, dtype, bias=True, taps=4, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    x, dy = (jax.random.normal(k, shape).astype(dtype) for k in ks[:2])
+    w = jax.random.uniform(ks[2], (taps, shape[-1]), minval=-0.5, maxval=0.5)
+    b = (jax.random.uniform(ks[3], shape[-1:], minval=-0.5, maxval=0.5)
+         if bias else None)
+    return x, w, b, dy
+
+
+def _conv_kernel(**kw):
+    return lambda *a: causal_conv.causal_conv(*a, interpret=True, **kw)
+
+
+def _conv_value_and_grads(f, x, w, b, dy):
+    """(y, dx, dw, dbias), the last left out where there is no bias."""
+    if b is None:
+        out, vjp = jax.vjp(lambda x, w: f(x, w, None), x, w)
+    else:
+        out, vjp = jax.vjp(f, x, w, b)
+    return (out, *vjp(dy))
+
+
+# (batch, T, C), the kernels' blocks: row blocks of 64 rows in strips of
+# 32 unless the case says otherwise
+CONV_CASES = {
+    "whole_blocks": ((2, 128, 128), {}),
+    "a_ragged_tail": ((2, 150, 128), {}),
+    "a_tail_of_whole_dead_strips": ((1, 70, 128), {}),
+    "shorter_than_one_block": ((2, 7, 128), {}),
+    "three_lane_blocks": ((2, 150, 384), {"lanes": 128}),
+    "a_strip_of_two_lane_slices": ((1, 96, 256), {"width": 128}),
+    "one_strip_a_block": ((1, 100, 256), {"strip": 64, "width": 256}),
+}
+
+
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "no_bias"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", CONV_CASES)
+def test_kernel_conv_is_the_xla_conv(case, dtype, bias):
+    """The value and every gradient, in the operands' dtypes. The
+    kernels are float32 inside and round once on the way out; the XLA
+    function multiplies and adds in ``x``'s dtype. So in float32 the two
+    differ by the order of the sums; in bfloat16 the kernels are held to
+    one step of the result's rounding from the XLA function *computed in
+    float32 from the same bfloat16 rows*, and the XLA function in
+    bfloat16 to what its own rounding of every product allows."""
+    shape, blocks = CONV_CASES[case]
+    blocks = {"rows": 64, "strip": 32, **blocks}
+    args = _conv_inputs(shape, dtype, bias, seed=shape[1])
+    got = _conv_value_and_grads(_conv_kernel(**blocks), *args)
+    want = _conv_value_and_grads(ssm._causal_conv1d_silu_xla, *args)
+    f32 = jnp.float32
+    exact = _conv_value_and_grads(
+        ssm._causal_conv1d_silu_xla,
+        *(None if a is None else a.astype(f32) for a in args))
+    assert len(got) == len(want) == (4 if bias else 3)
+    for name, g, w, e in zip("y dx dw dbias".split(), got, want, exact):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        g, w = g.astype(f32), w.astype(f32)
+        top = float(jnp.abs(e).max())
+        # dw and dbias leave the kernels as float32 sums
+        step = 1e-5 if dtype == f32 or name in ("dw", "dbias") else 2 ** -8
+        np.testing.assert_allclose(g, e, rtol=step, atol=step * top,
+                                   err_msg=name)
+        np.testing.assert_allclose(
+            g, w, rtol=1e-5 if dtype == f32 else 2 ** -5,
+            atol=(1e-5 if dtype == f32 else 2 ** -5) * top, err_msg=name)
+
+
+def test_kernel_conv_carries_the_halo_across_row_blocks_both_ways():
+    """One non-zero row each side of a block boundary: the forward's
+    rows below the boundary read the taps' products of the row above
+    it, and the backward hands the cotangents of the rows below it up,
+    each tap at its own distance and the first ``K - 1`` rows of the
+    sequence seeing zeros before them."""
+    t, c, rows = 128, 128, 64
+    w = jnp.arange(1.0, 5.0)[:, None] * jnp.ones((4, c))
+    kernel = _conv_kernel(rows=rows, strip=32)
+    x = jnp.zeros((1, t, c)).at[0, rows - 1].set(1.0).at[0, 0].set(2.0)
+    z = np.zeros((t,), np.float32)
+    z[rows - 1:rows + 3] = [4.0, 3.0, 2.0, 1.0]     # w[3], w[2], w[1], w[0]
+    z[0:4] = [8.0, 6.0, 4.0, 2.0]
+    np.testing.assert_allclose(kernel(x, w, None)[0, :, 0],
+                               z / (1 + np.exp(-z)), rtol=1e-6)
+    # silu'(0) = 1/2: dx[t] = sum_j w[j] / 2 * dy[t + 3 - j]
+    dy = jnp.zeros((1, t, c)).at[0, rows + 1].set(2.0)
+    dx = jax.vjp(lambda x: kernel(x, w, None), jnp.zeros((1, t, c)))[1](dy)[0]
+    want = np.zeros((t,), np.float32)
+    want[rows - 2:rows + 2] = [1.0, 2.0, 3.0, 4.0]
+    np.testing.assert_allclose(dx[0, :, 0], want, rtol=1e-6)
+
+
+def test_kernel_conv_is_causal():
+    """Later rows do not change earlier outputs, across a row block's
+    boundary too."""
+    x, w, b, _ = _conv_inputs((2, 100, 128), jnp.float32, seed=3)
+    kernel = _conv_kernel(rows=64, strip=32)
+    later = x.at[:, 70:].set(0.0)
+    np.testing.assert_array_equal(kernel(later, w, b)[:, :70],
+                                  kernel(x, w, b)[:, :70])
+
+
+def test_kernel_conv_keeps_x_and_no_float32_rows():
+    """What the backward keeps: ``x`` in its own dtype beside the taps
+    and the bias, as the XLA function's checkpoint does."""
+    from jax._src.ad_checkpoint import saved_residuals
+    x, w, b, _ = _conv_inputs((2, 64, 128), jnp.bfloat16)
+    saved = saved_residuals(
+        lambda *a: _conv_kernel()(*a).astype(jnp.float32).sum(), x, w, b)
+    rows = [aval for aval, _ in saved if aval.shape[:2] == x.shape[:2]]
+    assert [(r.shape, r.dtype) for r in rows] == [(x.shape, x.dtype)]
+
+
+CONV_CELL = (1, 16384, 4096)
+
+
+@pytest.mark.parametrize("backend, shape, taps, path", [
+    ("tpu", CONV_CELL, 4, "pallas"),
+    ("cpu", CONV_CELL, 4, "xla"),
+    ("tpu", (1, 8192, 6144), 4, "pallas"),
+    ("tpu", (2, 64, 96), 4, "xla"),
+    ("tpu", (1, 8192, 4096 + 64), 4, "xla"),
+    ("tpu", CONV_CELL, 18, "xla"),
+    ("tpu", CONV_CELL[1:], 4, "xla"),
+], ids=["the_cell_on_a_tpu", "the_cell_on_a_cpu", "nemotrons_cell",
+        "a_tiny_presets_width", "half_a_lane_tile_over",
+        "taps_that_reach_past_a_row_tile", "rows_with_no_batch"])
+def test_conv_path_reads_the_backend_and_the_shapes(
+        monkeypatch, backend, shape, taps, path):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert ssm.conv_path(shape, taps) == path
+
+
+@pytest.mark.parametrize("axes, batch, path", [
+    (None, 1, "xla"),
+    ({"dp": 1}, 1, "pallas"),
+    ({"dp": 4}, 4, "pallas"),
+    ({"dp": 2, "fsdp": 2}, 8, "pallas"),
+    ({"dp": 4}, 2, "xla"),
+    ({"dp": 2, "tp": 2}, 4, "xla"),
+    ({"sp": 2}, 4, "xla"),
+], ids=["no_mesh_in_a_process_of_eight_devices", "a_mesh_of_one_device",
+        "dp", "dp_and_fsdp", "a_batch_dp_does_not_divide", "dp_and_tp",
+        "sp_alone"])
+def test_conv_path_reads_the_devices_the_program_spans(
+        monkeypatch, axes, batch, path):
+    """The convolution takes its kernels where the norm does: a program
+    of one device, or one whose mesh shards the batch and nothing
+    else."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = None if axes is None else _mesh(**axes)
+    assert ssm.conv_path((batch, *CONV_CELL[1:]), 4, mesh) == path
+
+
+@pytest.mark.parametrize("backend, path", [("cpu", "xla"), ("tpu", "pallas")])
+def test_conv_notes_its_path_and_takes_it(monkeypatch, backend, path):
+    """``causal_conv1d_silu`` notes what ``conv_path`` said for the
+    trace in progress and calls that path: the kernels are handed the
+    operands and the axes the batch is sharded over."""
+    from ray_tpu.util import tracing
+    notes, calls = {}, []
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    monkeypatch.setattr(
+        causal_conv, "causal_conv", lambda x, *a, **kw: calls.append(kw) or x)
+    x, w, b, _ = _conv_inputs((1, 32, 256), jnp.float32)
+    y = ssm.causal_conv1d_silu(x, w, b)
+    assert notes == {"conv_path": path, "conv_taps": 4, "conv_cols": 256}
+    assert calls == ([{"mesh": None, "batch_axes": ()}] if path == "pallas"
+                     else [])
+    if path == "xla":
+        np.testing.assert_array_equal(
+            y, ssm._causal_conv1d_silu_xla(x, w, b))
+
+
+def test_kernel_conv_over_a_batch_sharded_mesh_is_the_one_device_conv():
+    """Under the ``shard_map`` over ``dp`` each device convolves its own
+    sequences; the taps and the bias are whole on both and their
+    gradients are the sum of the two devices'."""
+    mesh = _mesh(dp=2)
+    args = _conv_inputs((2, 48, 256), jnp.float32, seed=17)
+    want = _conv_value_and_grads(_conv_kernel(), *args)
+    got = jax.jit(lambda *a: _conv_value_and_grads(
+        _conv_kernel(mesh=mesh, batch_axes=("dp",)), *a))(*args)
+    assert got[0].sharding.spec[0] in ("dp", ("dp",))
+    for name, g, w in zip("y dx dw dbias".split(), got, want):
+        np.testing.assert_allclose(
+            g, w, rtol=1e-6, atol=1e-6 * float(jnp.abs(w).max()),
+            err_msg=name)
+
+
+@pytest.mark.parametrize("shape, taps", [((2, 16, 96), 4), ((2, 16, 128), 18)],
+                         ids=["columns", "taps"])
+def test_what_the_convs_kernels_do_not_tile_is_refused_by_name(shape, taps):
+    x, w, b, _ = _conv_inputs(shape, jnp.float32, taps=taps)
+    with pytest.raises(ValueError, match=f"do not tile {shape[-1]} columns "
+                       f"at {taps} taps"):
+        _conv_kernel()(x, w, b)

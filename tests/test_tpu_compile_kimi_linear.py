@@ -40,9 +40,13 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     attention is the latent kernel pair with dq's 16,384 rows resident
     and no rotation, the KDA layers run the recurrence's kernel pair
     (the forward twice a layer, the backward once, all under ``scan``
-    and no other custom call under ``kda``), which take ``q`` and ``k``
-    as the convolutions left them (no operation under ``kda/qk_norm``:
-    the scope is the XLA path's), and no ``[T, T]`` array exists."""
+    under ``scan``), which take ``q`` and ``k`` as the convolutions
+    left them (no operation under ``kda/qk_norm``: the scope is the XLA
+    path's), the three convolutions a layer are the kernel pair of
+    ``ops/pallas/causal_conv.py`` (under ``conv``: the forward in both
+    forward passes, the backward once; PR 55, at no more memory than the
+    XLA fusions' 12.05 GB) and no other custom call stands under
+    ``kda``, and no ``[T, T]`` array exists."""
     import re
 
     import optax
@@ -86,6 +90,8 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert notes["blocks_remat_keeps"] == "kda_gated_out,attn_out,attn_lse"
     assert notes["kda_path"] == "pallas_chunked" and notes["kda_chunk"] == 64
     assert notes["kda_heads"] == 32 and notes["kda_state"] == [128, 128]
+    assert notes["conv_path"] == "pallas"
+    assert (notes["conv_taps"], notes["conv_cols"]) == (4, 4096)
     assert notes["flash_path"] == "mla_multi_block"
     assert notes["flash_bwd_resident_rows"] == 16384
     assert notes["mla_positions"] == "none"
@@ -103,6 +109,7 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert m.argument_size_in_bytes == pytest.approx(
         cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
     assert 4e9 < total <= 14.5e9
+    assert total <= 12.05e9 + 0.05e9    # PR 54's program; 11.96 GB at PR 55
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
@@ -119,13 +126,21 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     # four KDA layers: each kernel lowered once, called a layer
     assert kinds.count("_kda_fwd") == 2 * 4
     assert kinds.count("_kda_bwd") == 4
+    # and their twelve convolutions: the forward in the step's forward
+    # pass and in ``_kda_core``'s recomputation, the backward once
+    assert kinds.count("_conv_fwd") == 2 * 12
+    assert kinds.count("_conv_bwd") == 12
     under_kda = [(kind, line) for kind, line in zip(kinds, calls)
                  if "/kda/" in line]
-    assert len(under_kda) == 12
+    assert len(under_kda) == 12 + 36
     # the checkpoints' own names stand between the module and its scope
-    assert all(kind in ("_kda_fwd", "_kda_bwd") and re.search(
-        r"/kda/(checkpoint/|rematted_computation/)*scan/jit", line)
-        for kind, line in under_kda)
+    scope_of = {"_kda_fwd": "scan", "_kda_bwd": "scan",
+                "_conv_fwd": "conv", "_conv_bwd": "conv"}
+    assert all(re.search(
+        r"/kda/(checkpoint/|rematted_computation/)*%s/jit" % scope_of[kind],
+        line) for kind, line in under_kda)
+    under_kda = [(kind, line) for kind, line in under_kda
+                 if scope_of[kind] == "scan"]
     # the kernels bring q and k to unit length in their cells, from the
     # convolutions' bfloat16 rows, and return those rows' cotangents
     assert "qk_norm" not in text

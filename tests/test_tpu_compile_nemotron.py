@@ -194,12 +194,13 @@ def test_a_mamba_layer_at_the_cells_shape_keeps_the_kernels_layout(
         v5e, monkeypatch, chips):
     """One ``M`` layer at the cell's widths and 8,192 tokens, loss and
     gradients, on one chip and with a sequence a chip over ``dp``: the
-    scan's two custom calls, the gated norm's two and the loss's one
-    (under the ``shard_map`` over ``dp`` as on one chip), the notes name
-    the kernels, and under ``gate_norm`` no float32 ``[rows, 8192,
-    4096]`` array is left in the optimised HLO: the XLA norm behind the
-    scan's custom call relaid one out three times a layer (PERF.md
-    section 6, PR 37); the kernels read and write bfloat16."""
+    scan's two custom calls, the gated norm's two, the convolution's two
+    (PR 55) and the loss's one (under the ``shard_map`` over ``dp`` as on
+    one chip), the notes name the kernels, and under ``gate_norm`` no
+    float32 ``[rows, 8192, 4096]`` array is left in the optimised HLO:
+    the XLA norm behind the scan's custom call relaid one out three times
+    a layer (PERF.md section 6, PR 37); the kernels read and write
+    bfloat16."""
     import re
 
     import numpy as np
@@ -231,9 +232,14 @@ def test_a_mamba_layer_at_the_cells_shape_keeps_the_kernels_layout(
         params, batch).compile().as_text()
     assert notes["ssm_path"] == "pallas_chunked"
     assert notes["gate_norm_path"] == "pallas"
+    assert notes["conv_path"] == "pallas"
+    assert (notes["conv_taps"], notes["conv_cols"]) == (4, 6144)
     # and the head's forward, one more (PR 51: ``ops/pallas/ce_lse.py``)
     assert notes["ce_path"] == "pallas_lse"
-    assert text.count("tpu_custom_call") == 5
+    assert text.count("tpu_custom_call") == 7
+    for kernel in ("_conv_fwd", "_conv_bwd"):
+        assert len(re.findall(
+            r"custom-call\(.*/conv/.*jit\(%s\)" % kernel, text)) == 1
     assert len(re.findall(r"custom-call\(.*jit\(_ce_lse_fwd\)", text)) == 1
     assert len(re.findall(r"custom-call\(.*gate_norm", text)) == 2
     under = [ln for ln in text.splitlines() if "gate_norm" in ln]
@@ -309,4 +315,35 @@ def test_layers_at_one_shape_trace_each_norm_kernel_once(monkeypatch):
         return two_layers(*args) * 2.0
 
     jax.jit(jax.grad(again, argnums=(0, 1, 2))).lower(rows, rows, scale)
+    assert len(bodies) == 2
+
+
+def test_layers_at_one_shape_trace_each_conv_kernel_once(monkeypatch):
+    """The same for the convolution's two kernels (PR 55: 36 calls a
+    step in the Kimi-Linear cell)."""
+    from ray_tpu.ops.pallas import causal_conv
+    bodies = []
+    steps = causal_conv._steps          # each kernel's body, once
+    monkeypatch.setattr(
+        causal_conv, "_steps",
+        lambda *a, **kw: bodies.append(1) or steps(*a, **kw))
+    rows = jax.ShapeDtypeStruct((1, 48, 640), jnp.float32)  # no other test's
+    taps = jax.ShapeDtypeStruct((4, 640), jnp.float32)
+
+    def two_layers(x, w):
+        for i in range(2):
+            with jax.named_scope(f"h_{i}"):
+                x = causal_conv.causal_conv(x, w, interpret=True)
+        return (x ** 2).sum()
+
+    text = jax.jit(jax.grad(two_layers, argnums=(0, 1))).lower(
+        rows, taps).as_text()
+    assert text.count("call @_conv_fwd") == 2
+    assert text.count("call @_conv_bwd") == 2
+    assert len(bodies) == 2             # forward's and backward's
+
+    def again(*args):                   # a new function: a new trace
+        return two_layers(*args) * 2.0
+
+    jax.jit(jax.grad(again, argnums=(0, 1))).lower(rows, taps)
     assert len(bodies) == 2

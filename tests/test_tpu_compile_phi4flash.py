@@ -41,8 +41,10 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     layout, the windowed layers' under the band of a 512-key window in
     blocks of 1,024 (7 block pairs a head where the causal grid walks
     10); the three scans run the kernel pair of
-    ``ops/pallas/mamba1_scan.py`` (custom calls under ``mamba/../scan``
-    alone, no ``while`` loop there) and no ``[T, T]`` array exists."""
+    ``ops/pallas/mamba1_scan.py`` (custom calls under ``mamba/../scan``,
+    no ``while`` loop there), their convolutions that of
+    ``ops/pallas/causal_conv.py`` (under ``mamba/../conv``; PR 55, the
+    program's 12.31 GB unmoved) and no ``[T, T]`` array exists."""
     import re
 
     import optax
@@ -91,6 +93,8 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert (notes["ssm_inner"], notes["ssm_state"], notes["ssm_dt_rank"],
             notes["ssm_chunk"]) == (5120, 16, 160, 64)   # the kernels' rows
     assert notes["ssm_path"] == "pallas_chunked"
+    assert notes["conv_path"] == "pallas"
+    assert (notes["conv_taps"], notes["conv_cols"]) == (4, 5120)
     assert notes["attn_pairs"] == [20, 10] and notes["attn_products"] == 4
     assert notes["attn_calls"] == 1
     assert (notes["yoco_memory_layer"], notes["yoco_kv_layer"]) == (4, 5)
@@ -109,10 +113,14 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert m.argument_size_in_bytes == pytest.approx(
         cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
     assert 4e9 < total < 12.87e9        # 12.31 GB: PR 48's kept 335 MB a scan
+    assert total <= 12.31e9 + 0.05e9    # PR 54's program, and PR 55's
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    scans = [line for line in calls if "/mamba/" in line]
+    convs = [line for line in calls if "/mamba/" in line
+             and "/conv/" in line]
+    scans = [line for line in calls if "/mamba/" in line
+             and line not in convs]
     head = [line for line in calls if "jit(_ce_lse_fwd)" in line]
     calls = [line for line in calls if "/mamba/" not in line
              and line not in head]
@@ -135,6 +143,12 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert sum("scan/jit(_mamba1_fwd)/" in line for line in scans) == 3 * 2
     assert sum("scan/jit(_mamba1_bwd)/" in line for line in scans) == 3
     assert len(scans) == 3 * 3
+    # and their convolutions: the forward in the block's forward pass
+    # and in its recomputation, the backward once
+    assert all(re.search(r"/h_[024]/mamba/.*conv/", line) for line in convs)
+    assert sum("conv1d/jit(_conv_fwd)/" in line for line in convs) == 3 * 2
+    assert sum("conv1d/jit(_conv_bwd)/" in line for line in convs) == 3
+    assert len(convs) == 3 * 3
     assert not any("/mamba/" in line and "/scan/" in line
                    for line in text.splitlines() if " while(" in line)
     assert "4096,4096" not in text
