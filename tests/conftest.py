@@ -80,6 +80,135 @@ def pytest_configure(config):
         "tier-1 runs the small-N variants)")
 
 
+# -- the suite's clock ---------------------------------------------------
+# The driver cuts the whole run at a time limit and counts a cut run only
+# as far as it got. A test that grows past the ceiling fails under its
+# own name, in the PR that grew it.
+
+CALL_CEILING_S = 180.0
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_makereport(item, call):
+    report = (yield).get_result()
+    if (report.when == "call" and report.passed
+            and report.duration > CALL_CEILING_S
+            and "slow" not in item.keywords):
+        report.outcome = "failed"
+        report.longrepr = (
+            f"took {report.duration:.0f} s: make it smaller or mark it "
+            f"slow (see README, Running it)")
+
+
+# -- programs for a described chip (tests/test_tpu_compile*.py) ----------
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described chips of a v5e:2x2, persistent cache off: a
+    compile for a described chip is written to the cache but cannot be
+    read back without the chip (it would warn and recompile)."""
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, no description
+        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def on_device(device):
+    """-> ``arg(shape, dtype)``: an abstract array on ``device``."""
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(device)
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one)
+
+
+def lower_real_size_step(device, model, loss_fn, batch_shape, **step_kw):
+    """A cell's step as its builder makes it (clipped adamw with a bf16
+    first moment, abstract state and batch on the one described chip),
+    traced and lowered for the chip, not compiled: -> (the trace's notes,
+    the lowered program)."""
+    import jax.numpy as jnp
+    import optax
+
+    from ray_tpu import train
+    from ray_tpu.util import tracing
+    arg = on_device(device)
+    opt = optax.chain(
+        optax.clip_by_global_norm(1.0),
+        optax.adamw(2e-5, b1=0.9, b2=0.95, weight_decay=0.1,
+                    mu_dtype=jnp.bfloat16))
+    step = train.make_train_step(loss_fn, opt, **step_kw)
+    state = jax.tree.map(
+        lambda z: arg(z.shape, z.dtype),
+        jax.eval_shape(lambda: train.init_train_state(
+            model.init_params(jax.random.key(0)), opt, None)))
+    batch = {k: arg(batch_shape, jnp.int32) for k in ("tokens", "targets")}
+    notes = {}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        patch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
+        patch.setattr(tracing, "note_trace", notes.update)
+        lowered = step.lower(state, batch)
+    return notes, lowered
+
+
+def kernel_calls(lowered) -> list[str]:
+    """The lowered program's ``tpu_custom_call``s, one line each as the
+    compiled text would name it: the scope path down to the primitive
+    (``.../h_0/attn/core/jit(_flash_fwd)/pallas_call``), then operand and
+    result shapes (``bf16[1,16384,6144]``). A jitted kernel is lowered
+    once and called a layer: each call site counts, under its own path."""
+    import re
+    text = lowered.as_text(debug_info=True)
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    bodies = {}
+    for head in re.finditer(r"^  func\.func \w+ @(\w+)\(", text, re.M):
+        bodies[head.group(1)] = re.findall(
+            r"(?:(?<![\w.])(?:func\.)?call @(\w+)|custom_call @tpu_custom_call)"
+            r"\(.* : (\(.*\) -> .*) loc\((#loc\d+)\)$",
+            text[head.end():text.index("\n  }", head.end())], re.M)
+
+    def walk(fn, path):
+        for callee, types, loc in bodies[fn]:
+            here = f"{path}/{names.get(loc, '')}".strip("/")
+            if callee:
+                yield from walk(callee, here)
+            else:
+                shapes = (f"{t.split('x')[-1]}[{','.join(t.split('x')[:-1])}]"
+                          for t in re.findall(r"tensor<(\w+)>", types))
+                yield f"{here} {' '.join(shapes)}"
+
+    return list(walk("main", ""))
+
+
+def kernel_kinds(lines) -> list[str]:
+    """Which jitted kernel each custom call is (``_flash_fwd``, ``gmm``):
+    of :func:`kernel_calls`' lines, or of the compiled text's."""
+    import re
+    return [re.search(r"jit\((\w+)\)/pallas_call", line).group(1)
+            for line in lines]
+
+
+def program_bytes(compiled):
+    """-> (``memory_analysis()``, arguments + temporaries + unaliased
+    outputs): what the program asks of the chip's memory."""
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + max(0, m.output_size_in_bytes - m.alias_size_in_bytes))
+    print(f"program {total / 1e9:.2f} GB: arguments "
+          f"{m.argument_size_in_bytes / 1e9:.2f}, temporaries "
+          f"{m.temp_size_in_bytes / 1e9:.2f}")
+    return m, total
+
+
 @pytest.fixture
 def rt():
     """A fresh multiprocess runtime per test."""

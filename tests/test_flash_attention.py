@@ -56,8 +56,8 @@ def test_gradients_match_dense():
         return (jax.nn.dot_product_attention(
             q, k, v, is_causal=True) ** 2).sum()
 
-    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g1, g2):
         assert float(jnp.abs(a - b).max()) < 5e-4
 
@@ -78,8 +78,8 @@ def _out_and_grads(attn, q, k, v):
     def loss(q, k, v):
         o = attn(q, k, v)
         return jnp.sum(o * jnp.cos(o)), o
-    (_, out), grads = jax.value_and_grad(
-        loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
     return (out, *grads)
 
 

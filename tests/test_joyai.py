@@ -115,9 +115,9 @@ def test_sigmoid_swiglu_held_drops_no_route_when_a_slab_overflows():
 
     args = (x, rw, gate[:2], up[:2], down[:2])
     with jax.default_matmul_precision("highest"):
-        (got, load), grads = jax.value_and_grad(
-            program, range(5), has_aux=True)(*args)
-        want, wants = jax.value_and_grad(dense, range(5))(*args)
+        (got, load), grads = jax.jit(jax.value_and_grad(
+            program, range(5), has_aux=True))(*args)
+        want, wants = jax.jit(jax.value_and_grad(dense, range(5)))(*args)
     assert float(load[:2].sum()) == K * T > held_rows(T * K, 2, E)
     assert held_rows(T * K, 2, E) == 2048
     assert float(got) == pytest.approx(float(want), rel=1e-5)

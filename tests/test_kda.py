@@ -56,8 +56,8 @@ def outputs_and_gradients(args, *fns):
         def loss(*a):
             o = fn(*a)
             return jnp.sum(o * weight), o
-        (_, o), grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
-                                           has_aux=True)(*args)
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
         return o, grads
     return tuple(of(fn) for fn in fns)
 

@@ -117,8 +117,8 @@ def test_sigmoid_weights_renormalise_and_scale(norm, scale):
 
 def test_the_selection_bias_takes_no_gradient():
     x, rw, up, down, bias = _layer_inputs(2)
-    g = jax.grad(lambda b: jnp.sum(
-        _sigmoid_layer(x, rw, up, down, b)[0] ** 2))(bias)
+    g = jax.jit(jax.grad(lambda b: jnp.sum(
+        _sigmoid_layer(x, rw, up, down, b)[0] ** 2)))(bias)
     np.testing.assert_array_equal(g, np.zeros(E, np.float32))
 
 
@@ -189,9 +189,9 @@ def test_no_route_is_dropped_when_more_land_here_than_a_slab_holds():
 
     args = (x, rw, up[:2], down[:2])
     with jax.default_matmul_precision("highest"):
-        (got, load), grads = jax.value_and_grad(
-            program, range(4), has_aux=True)(*args)
-        want, wants = jax.value_and_grad(dense, range(4))(*args)
+        (got, load), grads = jax.jit(jax.value_and_grad(
+            program, range(4), has_aux=True))(*args)
+        want, wants = jax.jit(jax.value_and_grad(dense, range(4)))(*args)
     assert float(load[:2].sum()) == 2 * T > held_rows(T * K, 2, E)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for g, w in zip(grads, wants):

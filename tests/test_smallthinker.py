@@ -71,10 +71,10 @@ def test_loss_every_gradient_leaf_and_the_routes_are_the_references(
     batch = _batch(seed, cfg)
     ref = mf.load_reference("smallthinker")
     with jax.default_matmul_precision("highest"):
-        (loss, report), grads = jax.value_and_grad(
-            smallthinker_loss_fn(model, ce_chunk=32), has_aux=True)(
+        (loss, report), grads = jax.jit(jax.value_and_grad(
+            smallthinker_loss_fn(model, ce_chunk=32), has_aux=True))(
                 params, batch)
-        logits = model.apply({"params": params}, batch["tokens"])
+        logits = jax.jit(model.apply)({"params": params}, batch["tokens"])
     want, want_grads, per_layer = ref.loss_and_grads(params, batch,
                                                      _spec(cfg))
     want_logits, (want_w, want_e), loads = ref.forward(
@@ -304,7 +304,7 @@ def test_reglu_is_its_plain_form_through_the_experts_and_the_slabs(held):
     assert notes["moe_expert_kind"] == "reglu"
     np.testing.assert_allclose(got, plain(x, weights, *own), atol=2e-5)
     g = jax.random.normal(keys[5], got.shape)
-    grads = [jax.grad(lambda *a: (fn(*a) * g).sum(), range(5))(
+    grads = [jax.jit(jax.grad(lambda *a: (fn(*a) * g).sum(), range(5)))(
         x, weights, *own) for fn in (layer, plain)]
     for a, b in zip(*grads):
         np.testing.assert_allclose(a, b, atol=2e-4)
@@ -354,7 +354,7 @@ def test_reglu_at_full_skew_walks_a_second_slab_and_is_the_references():
         got, load = layer(x, weights, wg, wu, wd)
         want = plain(x, weights, wg, wu, wd)
         g = jax.random.normal(keys[5], got.shape)
-        grads = [jax.grad(lambda *a: (fn(*a) * g).sum(), range(5))(
+        grads = [jax.jit(jax.grad(lambda *a: (fn(*a) * g).sum(), range(5)))(
             x, weights, wg, wu, wd)
             for fn in (lambda *a: layer(*a)[0], plain)]
     assert load.tolist() == [2 * t if i in chosen else 0 for i in range(e)]

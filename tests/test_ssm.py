@@ -9,6 +9,8 @@ gated norm's kernels (``ops/pallas/gated_norm.py``) the same way, with
 ``norm_path``; and the convolution's (``ops/pallas/causal_conv.py``),
 with ``conv_path``."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +20,12 @@ from ray_tpu.ops import ssm
 from ray_tpu.ops.pallas import causal_conv, gated_norm, ssd_scan
 
 B, H, P, G, N, CHUNK = 2, 4, 8, 2, 16, 16
+
+
+def _grad(f, argnums=0):
+    """``jax.grad``, compiled as one program: run operation by operation,
+    every step of an interpreted kernel is a compile of its own."""
+    return jax.jit(jax.grad(f, argnums))
 
 
 def _recurrence(x, dt, A, Bm, C, D):
@@ -67,9 +75,9 @@ def test_chunked_scan_gradients_are_the_recurrences(T):
     inside the chunks from the boundary states."""
     args = _inputs(T, seed=T)
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(lambda *a: jnp.sum(
+        got = _grad(lambda *a: jnp.sum(
             ssm.mamba2_scan(*a, chunk=CHUNK) ** 2), range(6))(*args)
-        want = jax.grad(lambda *a: jnp.sum(_recurrence(*a) ** 2),
+        want = _grad(lambda *a: jnp.sum(_recurrence(*a) ** 2),
                         range(6))(*args)
     for name, g, w in zip("x dt A B C D".split(), got, want):
         np.testing.assert_allclose(
@@ -138,7 +146,7 @@ def test_gated_norm_normalises_each_group():
     np.testing.assert_allclose(
         ssm.gated_group_rms_norm(y, z, scale, 3, 1e-5),
         g.reshape(2, 5, 12) * np.asarray(scale), rtol=1e-5, atol=1e-6)
-    grads = jax.grad(lambda *a: ssm.gated_group_rms_norm(
+    grads = _grad(lambda *a: ssm.gated_group_rms_norm(
         *a, 3, 1e-5).sum(), (0, 1, 2))(y, z, scale)
     assert all(bool(jnp.isfinite(g).all()) for g in grads)
 
@@ -187,8 +195,8 @@ def test_kernel_scan_gradients_are_the_recurrences(case):
     squares from the inputs and the state entering it."""
     shape, T = KERNEL_CASES[case]
     args = _inputs(T, seed=T + 1, shape=shape)
-    got = jax.grad(lambda *a: jnp.sum(_kernel(*a) ** 2), range(6))(*args)
-    want = jax.grad(lambda *a: jnp.sum(_recurrence(*a) ** 2),
+    got = _grad(lambda *a: jnp.sum(_kernel(*a) ** 2), range(6))(*args)
+    want = _grad(lambda *a: jnp.sum(_recurrence(*a) ** 2),
                     range(6))(*args)
     _close_gradients(got, want)
 
@@ -206,9 +214,9 @@ def test_kernel_scan_is_the_xla_scan_to_float32_rounding():
         np.testing.assert_allclose(
             _kernel(*args), want, rtol=1e-5,
             atol=1e-5 * float(jnp.abs(want).max()))
-        got = jax.grad(lambda *a: jnp.sum(_kernel(*a) ** 2),
+        got = _grad(lambda *a: jnp.sum(_kernel(*a) ** 2),
                        range(6))(*args)
-        want = jax.grad(lambda *a: jnp.sum(xla(*a) ** 2), range(6))(*args)
+        want = _grad(lambda *a: jnp.sum(xla(*a) ** 2), range(6))(*args)
     _close_gradients(got, want)
 
 
@@ -222,9 +230,9 @@ def test_kernel_scan_in_bfloat16_keeps_decays_in_float32():
     assert got.dtype == jnp.bfloat16
     err = jnp.abs(got.astype(jnp.float32) - want).max()
     assert float(err) < 0.03 * float(jnp.abs(want).max())
-    grads = jax.grad(lambda *a: jnp.sum(
+    grads = _grad(lambda *a: jnp.sum(
         _kernel(*a).astype(jnp.float32) ** 2), range(6))(*low)
-    wants = jax.grad(lambda *a: jnp.sum(_recurrence(*a) ** 2),
+    wants = _grad(lambda *a: jnp.sum(_recurrence(*a) ** 2),
                      range(6))(x, dt, A, Bm, C, D)
     assert [g.dtype for g in grads] == [a.dtype for a in low]
     for name, g, w in zip("x dt A B C D".split(), grads, wants):
@@ -245,8 +253,8 @@ def test_kernel_scan_takes_the_difference_of_the_sums_not_the_product():
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_allclose(got, _recurrence(*args), rtol=1e-4,
                                atol=1e-4)
-    grads = jax.grad(lambda *a: jnp.sum(_kernel(*a) ** 2), range(6))(*args)
-    wants = jax.grad(lambda *a: jnp.sum(_recurrence(*a) ** 2),
+    grads = _grad(lambda *a: jnp.sum(_kernel(*a) ** 2), range(6))(*args)
+    wants = _grad(lambda *a: jnp.sum(_recurrence(*a) ** 2),
                      range(6))(*args)
     assert all(bool(jnp.isfinite(g).all()) for g in grads)
     _close_gradients(grads, wants)
@@ -278,9 +286,9 @@ def test_kernel_scan_at_the_cells_head_width_in_bfloat16():
     exact = _recurrence(x, dt, A, Bm, C, D)
     assert float(jnp.abs(got.astype(jnp.float32) - exact).max()) < (
         0.03 * float(jnp.abs(exact).max()))
-    grads = jax.grad(loss(_kernel), range(6))(*low)
-    wants = jax.grad(loss(xla), range(6))(*low)
-    exacts = jax.grad(loss(_recurrence), range(6))(x, dt, A, Bm, C, D)
+    grads = _grad(loss(_kernel), range(6))(*low)
+    wants = _grad(loss(xla), range(6))(*low)
+    exacts = _grad(loss(_recurrence), range(6))(x, dt, A, Bm, C, D)
     for name, g, w, e in zip("x dt A B C D".split(), grads, wants, exacts):
         g, w = g.astype(jnp.float32), w.astype(jnp.float32)
         assert float(jnp.linalg.norm(g - w)) < (
@@ -358,7 +366,7 @@ def test_kernel_scan_over_a_batch_sharded_mesh_is_the_one_device_scan():
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
     assert got.sharding.spec[0] in ("dp", ("dp",))
     grads = jax.jit(jax.grad(loss(sharded), range(6)))(*args)
-    wants = jax.grad(loss(_kernel), range(6))(*args)
+    wants = _grad(loss(_kernel), range(6))(*args)
     for name, g, w in zip("x dt A B C D".split(), grads, wants):
         np.testing.assert_allclose(
             g, w, rtol=1e-5, atol=1e-5 * float(jnp.abs(w).max()),
@@ -386,6 +394,7 @@ def _norm_kernel(groups, **kw):
                                             interpret=True, **kw)
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def _value_and_grads(f, y, z, scale, dout):
     out, vjp = jax.vjp(f, y, z, scale)
     return (out, *vjp(dout))
@@ -507,6 +516,7 @@ def _conv_kernel(**kw):
     return lambda *a: causal_conv.causal_conv(*a, interpret=True, **kw)
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def _conv_value_and_grads(f, x, w, b, dy):
     """(y, dx, dw, dbias), the last left out where there is no bias."""
     if b is None:

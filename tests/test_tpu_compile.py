@@ -28,26 +28,6 @@ from ray_tpu.ops.pallas.flash_attention import flash_attention  # noqa: E402
 SHAPES = [(32, 1024, 12, 64), (8, 2048, 32, 128), (4, 4096, 16, 128)]
 
 
-@pytest.fixture(scope="module")
-def v5e():
-    """The four described chips of a v5e:2x2, persistent cache off: a
-    compile for a described chip is written to the cache but cannot be
-    read back without the chip (it would warn and recompile)."""
-    try:
-        from jax.experimental import topologies
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no libtpu, no description
-        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield list(topo.devices)
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
-
-
 def _loss(q, k, v):
     return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
 

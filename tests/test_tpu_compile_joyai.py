@@ -10,35 +10,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    try:
-        from jax.experimental import topologies
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no libtpu, no description
-        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield list(topo.devices)
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
-
-
-def _arg(device):
-    one = SingleDeviceSharding(device)
-    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
-                                                     sharding=one)
-
-
-def _on_one_chip(monkeypatch):
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
+from conftest import (  # noqa: E402
+    kernel_calls, lower_real_size_step, on_device, program_bytes)
 
 
 def _compiled_kernels(device, t, heads=32):
@@ -46,7 +19,7 @@ def _compiled_kernels(device, t, heads=32):
     rows, compiled for ``device``; with them the notes of the path."""
     from ray_tpu.ops.pallas.flash_attention import (
         mla_flash_core, mla_flash_static)
-    arg = _arg(device)
+    arg = on_device(device)
     static = mla_flash_static(t, 128, 64)
 
     def loss(*operands):
@@ -93,60 +66,51 @@ def test_the_backward_kernel_compiles_where_its_accumulators_fit(
     assert f"{t},{t}" not in text
 
 
-def test_the_real_size_step_compiles_inside_the_chips_memory(
-        v5e, monkeypatch):
+@pytest.fixture(scope="module")
+def real_size_step(v5e):
     """The cell's step as the builder makes it (the dense layer, four
     routed layers with 16 of 256 experts held, the MTP module, 16,384
     rows of the vocabulary; adamw with a bf16 first moment) at 8,192
-    tokens: arguments + temporaries + unaliased outputs fit the v5e's
-    15.75 GB with the room the acceptance asks for, every layer's
-    attention is the kernel (a forward and ONE backward custom call a
-    layer beside the experts'), and no ``[T, T]`` array exists."""
-    import optax
-
-    from ray_tpu import train
+    tokens, lowered once: (config, the trace's notes, the lowered
+    program)."""
     from ray_tpu.models.joyai import JoyAI, JoyAIConfig, joyai_loss_fn
-    from ray_tpu.util import tracing
-    _on_one_chip(monkeypatch)
-    arg = _arg(v5e[0])
     cfg = JoyAIConfig.joyai_llm_flash(n_layer=5, experts_held=(0, 16),
                                       vocab_size=16384)
     model = JoyAI(cfg)
-    opt = optax.chain(
-        optax.clip_by_global_norm(1.0),
-        optax.adamw(2e-5, b1=0.9, b2=0.95, weight_decay=0.1,
-                    mu_dtype=jnp.bfloat16))
-    step = train.make_train_step(joyai_loss_fn(model, ce_chunk=2048), opt)
-    state = jax.tree.map(
-        lambda z: arg(z.shape, z.dtype),
-        jax.eval_shape(lambda: train.init_train_state(
-            model.init_params(jax.random.key(0)), opt, None)))
-    batch = {k: arg((1, cfg.seq_len), jnp.int32)
-             for k in ("tokens", "targets")}
-    notes = {}
-    monkeypatch.setattr(tracing, "note_trace", notes.update)
-    compiled = step.lower(state, batch).compile()
+    return cfg, *lower_real_size_step(
+        v5e[0], model, joyai_loss_fn(model, ce_chunk=2048),
+        (1, cfg.seq_len))
+
+
+def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
+    """Every layer's attention is the kernel (a forward and ONE backward
+    custom call a layer beside the experts'), and no ``[T, T]`` array
+    exists."""
+    _, notes, lowered = real_size_step
     assert notes["flash_path"] == "mla_multi_block"
     assert notes["flash_layout"] == "bthd"
     assert notes["mla_saved"] == "latents"
     assert notes["flash_bwd_resident_rows"] == 8192
-    m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
-             + max(0, m.output_size_in_bytes - m.alias_size_in_bytes))
-    assert m.argument_size_in_bytes == pytest.approx(
-        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
-    assert total < 15.2e9
-    text = compiled.as_text()
-    assert text.count("mla_flash_fwd") >= 6
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    calls = kernel_calls(lowered)
+    assert sum("mla_flash_fwd" in line for line in calls) >= 6
     assert sum("mla_flash_bwd" in line for line in calls) == 6
     # the head's forward (PR 51), once for the loss and once for the MTP
     # module's pass over the same table
     head = [line for line in calls if "jit(_ce_lse_fwd)" in line]
     assert len(head) == 2 and all("/loss/" in line for line in head)
     assert sum(bool(re.search(r"loss\)?/mtp/", line)) for line in head) == 1
-    assert "8192,8192" not in text
+    assert "8192x8192" not in lowered.as_text()
+
+
+@pytest.mark.slow
+def test_the_real_size_step_compiles_inside_the_chips_memory(real_size_step):
+    """Arguments + temporaries + unaliased outputs fit the v5e's 15.75 GB
+    with the room the acceptance asks for."""
+    cfg, _, lowered = real_size_step
+    m, total = program_bytes(lowered.compile())
+    assert m.argument_size_in_bytes == pytest.approx(
+        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
+    assert total < 15.2e9
 
 
 def _dp(v5e):

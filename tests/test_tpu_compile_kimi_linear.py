@@ -1,90 +1,51 @@
-"""The Kimi-Linear cell's step compiles for the real chip, with no chip
-here (as ``test_tpu_compile_smallthinker.py``: the TPU compiler for a
-described v5e; nothing runs, so nothing here is a result or a time)."""
+"""The Kimi-Linear cell's step for the real chip, with no chip here (as
+``test_tpu_compile_smallthinker.py``): traced and lowered for a described
+v5e in tier-1, compiled by the TPU compiler on demand (``-m slow``).
+Nothing runs, so nothing here is a result or a time."""
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
+from conftest import (  # noqa: E402
+    kernel_calls, kernel_kinds, lower_real_size_step, program_bytes)
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    try:
-        from jax.experimental import topologies
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no libtpu, no description
-        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield list(topo.devices)
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
-
-
-def test_the_real_size_step_compiles_inside_the_chips_memory(
-        v5e, monkeypatch):
+def real_size_step(v5e):
     """The cell's step as the builder makes it (layers 1-5, KKKMK, with 8
     of 256 experts held, 20,480 rows of each table, the blocks
-    recomputed; adamw with a bf16 first moment) at 1 x 16,384 tokens:
-    arguments + temporaries + unaliased outputs stay under the 14.5 GB
-    that leave room for the device's own reserve (12.13 GB at PR 47,
-    12.92 before the norm of q and k moved into the kernels), the MLA layer's
-    attention is the latent kernel pair with dq's 16,384 rows resident
-    and no rotation, the KDA layers run the recurrence's kernel pair
-    (the forward twice a layer, the backward once, all under ``scan``
-    under ``scan``), which take ``q`` and ``k`` as the convolutions
-    left them (no operation under ``kda/qk_norm``: the scope is the XLA
-    path's), the three convolutions a layer are the kernel pair of
-    ``ops/pallas/causal_conv.py`` (under ``conv``: the forward in both
-    forward passes, the backward once; PR 55, at no more memory than the
-    XLA fusions' 12.05 GB) and no other custom call stands under
-    ``kda``, and no ``[T, T]`` array exists."""
-    import re
-
-    import optax
-
-    from ray_tpu import train
+    recomputed; adamw with a bf16 first moment) at 1 x 16,384 tokens,
+    lowered once: (config, the trace's notes, the lowered program)."""
     from ray_tpu.models.kimi_linear import (
         KimiLinear,
         KimiLinearConfig,
         kimi_linear_loss_fn,
     )
-    from ray_tpu.util import tracing
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
-    one = SingleDeviceSharding(v5e[0])
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
     cfg = KimiLinearConfig.kimi_linear_48b_a3b(
         n_layer=5, experts_held=(0, 8), vocab_size=20480, remat=True)
     model = KimiLinear(cfg)
-    opt = optax.chain(
-        optax.clip_by_global_norm(1.0),
-        optax.adamw(2e-5, b1=0.9, b2=0.95, weight_decay=0.1,
-                    mu_dtype=jnp.bfloat16))
-    step = train.make_train_step(
-        kimi_linear_loss_fn(model, ce_chunk=2048), opt, grad_groups={
+    return cfg, *lower_real_size_step(
+        v5e[0], model, kimi_linear_loss_fn(model, ce_chunk=2048),
+        (1, cfg.seq_len), grad_groups={
             "grad_norm_kda_gates":
             "^h_[0-9]+/kda/(f_a/kernel|f_b|A_log|dt_bias|b/kernel)$"})
-    state = jax.tree.map(
-        lambda z: arg(z.shape, z.dtype),
-        jax.eval_shape(lambda: train.init_train_state(
-            model.init_params(jax.random.key(0)), opt, None)))
-    batch = {k: arg((1, cfg.seq_len), jnp.int32)
-             for k in ("tokens", "targets")}
-    notes = {}
-    monkeypatch.setattr(tracing, "note_trace", notes.update)
-    compiled = step.lower(state, batch).compile()
+
+
+def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
+    """The MLA layer's attention is the latent kernel pair with dq's
+    16,384 rows resident and no rotation, the KDA layers run the
+    recurrence's kernel pair (the forward twice a layer, the backward
+    once, all under ``scan`` under ``scan``), which take ``q`` and ``k``
+    as the convolutions left them (no operation under ``kda/qk_norm``:
+    the scope is the XLA path's), the three convolutions a layer are the
+    kernel pair of ``ops/pallas/causal_conv.py`` (under ``conv``: the
+    forward in both forward passes, the backward once; PR 55) and no
+    other custom call stands under ``kda``, and no ``[T, T]`` array
+    exists."""
+    _, notes, lowered = real_size_step
     assert notes["attn_kind"] == "kda_mla"
     assert notes["attn_layers"] == "KKKMK" and notes["blocks_remat"] is True
     assert notes["blocks_remat_keeps"] == "kda_gated_out,attn_out,attn_lse"
@@ -100,21 +61,8 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert notes["moe_experts_held"] == [0, 8]
     assert notes["moe_rows_sorted"] == 8192     # twice the even share
     assert notes["moe_path"] == "megablox_gmm"
-    m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
-             + max(0, m.output_size_in_bytes - m.alias_size_in_bytes))
-    print(f"program {total / 1e9:.2f} GB: arguments "
-          f"{m.argument_size_in_bytes / 1e9:.2f}, temporaries "
-          f"{m.temp_size_in_bytes / 1e9:.2f}")
-    assert m.argument_size_in_bytes == pytest.approx(
-        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
-    assert 4e9 < total <= 14.5e9
-    assert total <= 12.05e9 + 0.05e9    # PR 54's program; 11.96 GB at PR 55
-    text = compiled.as_text()
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    kinds = [re.search(r"jit\((\w+)\)/pallas_call", line).group(1)
-             for line in calls]
+    calls = kernel_calls(lowered)
+    kinds = kernel_kinds(calls)
     assert {"gmm", "tgmm"} <= set(kinds)
     assert kinds.count("_ce_lse_fwd") == 1      # the head's forward (PR 51)
     # the forward kernel once (the block is recomputed and keeps its
@@ -143,9 +91,25 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
                  if scope_of[kind] == "scan"]
     # the kernels bring q and k to unit length in their cells, from the
     # convolutions' bfloat16 rows, and return those rows' cotangents
+    text = lowered.as_text(debug_info=True)
     assert "qk_norm" not in text
     for kind, line in under_kda:
         rows = re.findall(r"(\w+)\[1,16384,4096\]", line)
         assert rows.count("bf16") == (3 if kind == "_kda_fwd" else 6), line
     assert "/attn/rope/" not in text and "/attn/q_down/" not in text
-    assert "16384,16384" not in text
+    assert "16384x16384" not in text
+
+
+@pytest.mark.slow
+def test_the_real_size_step_compiles_inside_the_chips_memory(real_size_step):
+    """Arguments + temporaries + unaliased outputs stay under the 14.5 GB
+    that leave room for the device's own reserve (12.13 GB at PR 47,
+    12.92 before the norm of q and k moved into the kernels), and the
+    convolutions' kernels (PR 55) take no more memory than the XLA
+    fusions' 12.05 GB."""
+    cfg, _, lowered = real_size_step
+    m, total = program_bytes(lowered.compile())
+    assert m.argument_size_in_bytes == pytest.approx(
+        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
+    assert 4e9 < total <= 14.5e9
+    assert total <= 12.05e9 + 0.05e9    # PR 54's program; 11.96 GB at PR 55

@@ -1,83 +1,48 @@
-"""The Laguna-XS.2 cell's step compiles for the real chip, with no chip
-here (as ``test_tpu_compile_kimi_linear.py``: the TPU compiler for a
-described v5e; nothing runs, so nothing here is a result or a time)."""
+"""The Laguna-XS.2 cell's step for the real chip, with no chip here (as
+``test_tpu_compile_kimi_linear.py``): traced and lowered for a described
+v5e in tier-1, which is where a step says which kernels it takes; handed
+to the TPU compiler on demand (``-m slow``), which is where it says what
+memory it asks for. Nothing runs, so nothing here is a result or a
+time."""
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
+from conftest import (  # noqa: E402
+    kernel_calls, lower_real_size_step, program_bytes)
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    try:
-        from jax.experimental import topologies
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no libtpu, no description
-        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield list(topo.devices)
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
-
-
-def test_the_real_size_step_compiles_inside_the_chips_memory(
-        v5e, monkeypatch):
+def real_size_step(v5e):
     """The cell's step as the builder makes it (layers 0-4 as published,
     ``F S S S F`` at 48, 64, 64, 64, 48 query heads; 32 of 256 experts;
     12,544 rows of the two tables; the blocks recomputed; adamw with a
-    bf16 first moment) at 1 x 16,384 tokens: arguments + temporaries +
-    unaliased outputs stay under 14.0 GB (12.47 GB at PR 53, 13.04 at PR
-    52; without ``remat`` the compiler asks for 16.64 GB of the chip's
-    15.75 and refuses). Every layer's core is one call of the multi-block
-    flash kernels in the projections' own layout, the forward once and
-    the backward once: the block is recomputed, and keeps its core's
-    output and row statistics (``blocks_remat_keeps``), so the second
-    pass over the block runs no forward kernel. The two full layers' under
-    ``attn/core`` over 6,144 lanes, the three sliding layers' under
-    ``attn/window`` over 8,192 lanes and the band of a 512-key window in
-    blocks of 1,024 (31 block pairs a head where the causal grid walks
-    136). No ``[T, T]`` array exists."""
-    import re
-
-    import optax
-
-    from ray_tpu import train
+    bf16 first moment) at 1 x 16,384 tokens, lowered once: (config, the
+    trace's notes, the lowered program)."""
     from ray_tpu.models.laguna import Laguna, LagunaConfig, laguna_loss_fn
-    from ray_tpu.util import tracing
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
-    one = SingleDeviceSharding(v5e[0])
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
     cfg = LagunaConfig.laguna_xs_2(
         n_layer=5, vocab_size=12544, experts_held=(0, 32), seq_len=16384,
         remat=True)
     model = Laguna(cfg)
-    opt = optax.chain(
-        optax.clip_by_global_norm(1.0),
-        optax.adamw(2e-5, b1=0.9, b2=0.95, weight_decay=0.1,
-                    mu_dtype=jnp.bfloat16))
-    step = train.make_train_step(laguna_loss_fn(model, ce_chunk=2048), opt)
-    state = jax.tree.map(
-        lambda z: arg(z.shape, z.dtype),
-        jax.eval_shape(lambda: train.init_train_state(
-            model.init_params(jax.random.key(0)), opt, None)))
-    batch = {k: arg((1, cfg.seq_len), jnp.int32)
-             for k in ("tokens", "targets")}
-    notes = {}
-    monkeypatch.setattr(tracing, "note_trace", notes.update)
-    compiled = step.lower(state, batch).compile()
+    return cfg, *lower_real_size_step(
+        v5e[0], model, laguna_loss_fn(model, ce_chunk=2048),
+        (1, cfg.seq_len))
+
+
+def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
+    """Every layer's core is one call of the multi-block flash kernels in
+    the projections' own layout, the forward once and the backward once:
+    the block is recomputed, and keeps its core's output and row
+    statistics (``blocks_remat_keeps``), so the second pass over the
+    block runs no forward kernel. The two full layers' under
+    ``attn/core`` over 6,144 lanes, the three sliding layers' under
+    ``attn/window`` over 8,192 lanes and the band of a 512-key window in
+    blocks of 1,024 (31 block pairs a head where the causal grid walks
+    136). No ``[T, T]`` array exists."""
+    _, notes, lowered = real_size_step
     assert notes["attn_kind"] == "window_global"
     assert notes["attn_layers"] == "FSSSF"
     assert notes["attn_heads"] == "48,64,64,64,48"
@@ -98,18 +63,7 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert notes["moe_rows_sorted"] == 32768 and notes["moe_routes"] == 131072
     assert notes["moe_path"] == "megablox_gmm"
     assert notes["moe_rows_path"] == "tgmm"
-    m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
-             + max(0, m.output_size_in_bytes - m.alias_size_in_bytes))
-    print(f"program {total / 1e9:.2f} GB: arguments "
-          f"{m.argument_size_in_bytes / 1e9:.2f}, temporaries "
-          f"{m.temp_size_in_bytes / 1e9:.2f}")
-    assert m.argument_size_in_bytes == pytest.approx(
-        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
-    assert 4e9 < total < 14.0e9     # 12.47 GB at PR 53; 13.04 at PR 52
-    text = compiled.as_text()
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    calls = kernel_calls(lowered)
     flash = [line for line in calls if "/attn/" in line]
     head = [line for line in calls if "jit(_ce_lse_fwd)" in line]
     assert len(head) == 1 and "/loss/" in head[0]
@@ -129,4 +83,16 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     # the rest are the routed layers' grouped matmuls and row sums
     rest = [line for line in calls if line not in flash + head]
     assert rest and all(re.search(r"/h_[1234]/mlp/", line) for line in rest)
-    assert "16384,16384" not in text
+    assert "16384x16384" not in lowered.as_text()
+
+
+@pytest.mark.slow
+def test_the_real_size_step_compiles_inside_the_chips_memory(real_size_step):
+    """Arguments + temporaries + unaliased outputs stay under 14.0 GB
+    (12.47 GB at PR 53, 13.04 at PR 52; without ``remat`` the compiler
+    asks for 16.64 GB of the chip's 15.75 and refuses)."""
+    cfg, _, lowered = real_size_step
+    m, total = program_bytes(lowered.compile())
+    assert m.argument_size_in_bytes == pytest.approx(
+        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
+    assert 4e9 < total < 14.0e9     # 12.47 GB at PR 53; 13.04 at PR 52

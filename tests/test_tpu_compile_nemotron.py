@@ -12,23 +12,6 @@ import pytest  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 
-@pytest.fixture(scope="module")
-def v5e():
-    try:
-        from jax.experimental import topologies
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no libtpu, no description
-        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield list(topo.devices)
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
-
-
 def _arg(device):
     one = SingleDeviceSharding(device)
     return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,

@@ -3,88 +3,49 @@ chip here (as ``test_tpu_compile_kimi_linear.py``: the TPU compiler for a
 described v5e; nothing runs, so nothing here is a result or a time)."""
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
+from conftest import (  # noqa: E402
+    kernel_calls, lower_real_size_step, program_bytes)
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    try:
-        from jax.experimental import topologies
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no libtpu, no description
-        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield list(topo.devices)
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
-
-
-def test_the_real_size_step_compiles_inside_the_chips_memory(
-        v5e, monkeypatch):
+def real_size_step(v5e):
     """The cell's step as the builder makes it (the rule at eight
     layers, ``MSMSMFGX``, 25,088 rows of the tied table, the blocks
-    recomputed; adamw with a bf16 first moment) at 1 x 4,096 tokens:
-    arguments + temporaries + unaliased outputs stay under the chip's
-    15.75 GB (12.87 GB at PR 48, 12.31 GB since the scans' kernels: PR
-    49); every attention layer's four products are one call of the
+    recomputed; adamw with a bf16 first moment) at 1 x 4,096 tokens,
+    lowered once: (config, the trace's notes, the lowered program)."""
+    from ray_tpu.models.phi4flash import (
+        Phi4Flash,
+        Phi4FlashConfig,
+        phi4flash_loss_fn,
+    )
+    cfg = Phi4FlashConfig.phi_4_mini_flash_reasoning(
+        n_layer=8, vocab_size=25088, remat=True)
+    model = Phi4Flash(cfg)
+    return cfg, *lower_real_size_step(
+        v5e[0], model, phi4flash_loss_fn(model, ce_chunk=2048),
+        (1, cfg.seq_len), grad_groups={
+            "grad_norm_mamba_ssm": "^h_[0-9]+/mamba/",
+            "grad_norm_attn_diff":
+            "^h_[0-9]+/attn/(lambda_[qk][12]|subln|out/kernel)$",
+            "grad_norm_yoco_kv": "^h_5/attn/qkv/kernel$"})
+
+
+def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
+    """Every attention layer's four products are one call of the
     multi-block flash kernels over 80 heads of 64 in the projections' own
     layout, the windowed layers' under the band of a 512-key window in
     blocks of 1,024 (7 block pairs a head where the causal grid walks
     10); the three scans run the kernel pair of
     ``ops/pallas/mamba1_scan.py`` (custom calls under ``mamba/../scan``,
     no ``while`` loop there), their convolutions that of
-    ``ops/pallas/causal_conv.py`` (under ``mamba/../conv``; PR 55, the
-    program's 12.31 GB unmoved) and no ``[T, T]`` array exists."""
-    import re
-
-    import optax
-
-    from ray_tpu import train
-    from ray_tpu.models.phi4flash import (
-        Phi4Flash,
-        Phi4FlashConfig,
-        phi4flash_loss_fn,
-    )
-    from ray_tpu.util import tracing
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
-    one = SingleDeviceSharding(v5e[0])
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    cfg = Phi4FlashConfig.phi_4_mini_flash_reasoning(
-        n_layer=8, vocab_size=25088, remat=True)
-    model = Phi4Flash(cfg)
-    opt = optax.chain(
-        optax.clip_by_global_norm(1.0),
-        optax.adamw(2e-5, b1=0.9, b2=0.95, weight_decay=0.1,
-                    mu_dtype=jnp.bfloat16))
-    step = train.make_train_step(
-        phi4flash_loss_fn(model, ce_chunk=2048), opt, grad_groups={
-            "grad_norm_mamba_ssm": "^h_[0-9]+/mamba/",
-            "grad_norm_attn_diff":
-            "^h_[0-9]+/attn/(lambda_[qk][12]|subln|out/kernel)$",
-            "grad_norm_yoco_kv": "^h_5/attn/qkv/kernel$"})
-    state = jax.tree.map(
-        lambda z: arg(z.shape, z.dtype),
-        jax.eval_shape(lambda: train.init_train_state(
-            model.init_params(jax.random.key(0)), opt, None)))
-    batch = {k: arg((1, cfg.seq_len), jnp.int32)
-             for k in ("tokens", "targets")}
-    notes = {}
-    monkeypatch.setattr(tracing, "note_trace", notes.update)
-    compiled = step.lower(state, batch).compile()
+    ``ops/pallas/causal_conv.py`` (under ``mamba/../conv``; PR 55) and no
+    ``[T, T]`` array exists."""
+    _, notes, lowered = real_size_step
     assert notes["attn_kind"] == "differential"
     assert notes["layer_pattern"] == "MSMSMFGX"
     assert notes["blocks_remat"] is True and notes["attn_window"] == 512
@@ -104,19 +65,7 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert notes["flash_path"] == "multi_block"
     assert notes["flash_window"] == 512 and notes["flash_band_blocks"] == 7
     assert notes["flash_bwd_resident_rows"] == 4096
-    m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
-             + max(0, m.output_size_in_bytes - m.alias_size_in_bytes))
-    print(f"program {total / 1e9:.2f} GB: arguments "
-          f"{m.argument_size_in_bytes / 1e9:.2f}, temporaries "
-          f"{m.temp_size_in_bytes / 1e9:.2f}")
-    assert m.argument_size_in_bytes == pytest.approx(
-        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
-    assert 4e9 < total < 12.87e9        # 12.31 GB: PR 48's kept 335 MB a scan
-    assert total <= 12.31e9 + 0.05e9    # PR 54's program, and PR 55's
-    text = compiled.as_text()
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    calls = kernel_calls(lowered)
     convs = [line for line in calls if "/mamba/" in line
              and "/conv/" in line]
     scans = [line for line in calls if "/mamba/" in line
@@ -149,6 +98,23 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(
     assert sum("conv1d/jit(_conv_fwd)/" in line for line in convs) == 3 * 2
     assert sum("conv1d/jit(_conv_bwd)/" in line for line in convs) == 3
     assert len(convs) == 3 * 3
-    assert not any("/mamba/" in line and "/scan/" in line
-                   for line in text.splitlines() if " while(" in line)
-    assert "4096,4096" not in text
+    # no loop under the scans' scope: an operation in a loop's body is
+    # named under ``while``
+    text = lowered.as_text(debug_info=True)
+    assert not [name for name in re.findall(r'loc\("([^"]*)"', text)
+                if "/mamba/" in name and "/scan/" in name
+                and "while" in name]
+    assert "4096x4096" not in text
+
+
+@pytest.mark.slow
+def test_the_real_size_step_compiles_inside_the_chips_memory(real_size_step):
+    """Arguments + temporaries + unaliased outputs stay under the chip's
+    15.75 GB (12.87 GB at PR 48, 12.31 GB since the scans' kernels: PR
+    49; the convolutions' kernels, PR 55, leave it unmoved)."""
+    cfg, _, lowered = real_size_step
+    m, total = program_bytes(lowered.compile())
+    assert m.argument_size_in_bytes == pytest.approx(
+        cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
+    assert 4e9 < total < 12.87e9        # 12.31 GB: PR 48's kept 335 MB a scan
+    assert total <= 12.31e9 + 0.05e9    # PR 54's program, and PR 55's

@@ -1,7 +1,8 @@
 """The Xing4.0 cell's new pieces compile for the real chip, with no chip
 here (as ``test_tpu_compile_joyai.py``: the TPU compiler for a described
 v5e; nothing runs, so nothing here is a result or a time). The real-size
-step is compiled once for every assertion on it.
+step is lowered once for every assertion on it, in tier-1; the TPU compiler
+takes it on demand (``-m slow``).
 
 **The reading that decided the cell's memory step** (``memory_analysis()``
 of the step below, PR 54): with the MTP module 913.5 M parameters compile
@@ -19,32 +20,10 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
+from conftest import (  # noqa: E402
+    kernel_calls, lower_real_size_step, on_device, program_bytes)
 
 T = 4096        # the cell's row: rope_scaling's original length
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    try:
-        from jax.experimental import topologies
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no libtpu, no description
-        pytest.skip(f"cannot describe a v5e:2x2 here: {e}")
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield list(topo.devices)
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
-
-
-def _arg(device):
-    one = SingleDeviceSharding(device)
-    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
-                                                     sharding=one)
 
 
 def _cell_config():
@@ -61,7 +40,7 @@ def test_latent_attention_kernels_compile_at_4096_rows_with_a_scale(v5e):
     the compiled kernels' and not the default's."""
     from ray_tpu.ops.pallas.flash_attention import (
         mla_flash_core, mla_flash_static)
-    arg = _arg(v5e[0])
+    arg = on_device(v5e[0])
     scale = _cell_config().mla_scale
     static = mla_flash_static(T, 128, 64, scale)
     assert static.scale == pytest.approx(0.144680, rel=1e-5)
@@ -93,7 +72,7 @@ def test_held_experts_compile_at_a_hidden_size_of_3584(v5e, monkeypatch):
     from ray_tpu.ops import moe
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert moe.grouped_matmul_path() == "megablox_gmm"
-    arg = _arg(v5e[0])
+    arg = on_device(v5e[0])
 
     def loss(x, router, bias, gate, up, down):
         y, _, _, _ = moe.routed_ffn(
@@ -112,60 +91,61 @@ def test_held_experts_compile_at_a_hidden_size_of_3584(v5e, monkeypatch):
 
 @pytest.fixture(scope="module")
 def real_size_step(v5e):
-    """The cell's step as the builder makes it, compiled once: (config,
-    the trace's notes, the compiled program, its text)."""
-    import optax
-
-    from ray_tpu import train
+    """The cell's step as the builder makes it (one dense and four routed
+    layers with 8 of 64 experts held, four residual streams, 16,384 rows
+    of both tables, adamw with a bf16 first moment, 4,096 tokens, blocks
+    recomputed), lowered once: (config, the trace's notes, the lowered
+    program)."""
     from ray_tpu.models.joyai import JoyAI, joyai_loss_fn
-    from ray_tpu.util import tracing
-    arg = _arg(v5e[0])
     cfg = _cell_config()
     model = JoyAI(cfg)
-    opt = optax.chain(
-        optax.clip_by_global_norm(1.0),
-        optax.adamw(2e-5, b1=0.9, b2=0.95, weight_decay=0.1,
-                    mu_dtype=jnp.bfloat16))
-    step = train.make_train_step(
-        joyai_loss_fn(model, ce_chunk=2048), opt,
+    return cfg, *lower_real_size_step(
+        v5e[0], model, joyai_loss_fn(model, ce_chunk=2048),
+        (1, cfg.seq_len),
         grad_groups={"grad_norm_hc": r"(^|/)hc_(attn|mlp)/(phi|b|alpha)$"})
-    state = jax.tree.map(
-        lambda z: arg(z.shape, z.dtype),
-        jax.eval_shape(lambda: train.init_train_state(
-            model.init_params(jax.random.key(0)), opt, None)))
-    batch = {k: arg((1, cfg.seq_len), jnp.int32)
-             for k in ("tokens", "targets")}
-    notes = {}
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(jax, "default_backend", lambda: "tpu")
-        patch.setattr(jax, "device_count", lambda: 1)   # the cell's chip
-        patch.setattr(tracing, "note_trace", notes.update)
-        compiled = step.lower(state, batch).compile()
-    return cfg, notes, compiled, compiled.as_text()
 
 
+@pytest.mark.slow
 def test_the_real_size_step_compiles_inside_the_chips_memory(real_size_step):
-    """One dense and four routed layers with 8 of 64 experts held, four
-    residual streams, 16,384 rows of both tables, adamw with a bf16
-    first moment, 4,096 tokens, blocks recomputed: arguments +
-    temporaries + unaliased outputs are inside the 14.6 GB that the
-    cell allows itself of the v5e's 15.75 (12.03 GB at PR 54; the
-    docstring above has the reading with the MTP module)."""
-    cfg, _, compiled, _ = real_size_step
-    m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
-             + max(0, m.output_size_in_bytes - m.alias_size_in_bytes))
-    print(f"program {total / 1e9:.2f} GB: arguments "
-          f"{m.argument_size_in_bytes / 1e9:.2f}, temporaries "
-          f"{m.temp_size_in_bytes / 1e9:.2f}")
-    assert cfg.num_params() == pytest.approx(759.3e6, rel=1e-4)
+    """Arguments + temporaries + unaliased outputs are inside the 14.6 GB
+    that the cell allows itself of the v5e's 15.75 (12.03 GB at PR 54;
+    the docstring above has the reading with the MTP module). And what
+    only the compiled program says of the n-stream state: it is ``[1, T,
+    n d]`` in bfloat16 everywhere, never float32 at that width, never
+    with the 4 streams second-minor."""
+    cfg, _, lowered = real_size_step
+    compiled = lowered.compile()
+    m, total = program_bytes(compiled)
     assert m.argument_size_in_bytes == pytest.approx(
         cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
     assert 0.25 * 15.75e9 < total < 14.6e9
+    # the buffers are the entry computation's results (inside a fusion a
+    # float32 value of the state's width lives in registers)
+    text = compiled.as_text()
+    entry = text[text.rindex("\nENTRY "):]
+    wide = cfg.hc_mult * cfg.n_embd
+    assert f"bf16[1,{T},{wide}]" in entry
+    assert f"f32[1,{T},{wide}]" not in entry
+    assert f"f32[{T},{wide}]" not in entry
+    assert not re.search(rf"\[(1,)?{T},{cfg.hc_mult},{cfg.n_embd}\]", entry)
+
+
+def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
+    """Every layer's attention is the kernel, forward once (a recomputed
+    block keeps its results) and backward once; the head's forward is
+    one custom call under ``loss``."""
+    cfg, _, lowered = real_size_step
+    assert cfg.num_params() == pytest.approx(759.3e6, rel=1e-4)
+    calls = kernel_calls(lowered)
+    flash = [line for line in calls if "/attn/core/" in line]
+    assert sum("mla_flash_fwd" in line for line in flash) == 5
+    assert sum("mla_flash_bwd" in line for line in flash) == 5
+    head = [line for line in calls if "jit(_ce_lse_fwd)" in line]
+    assert len(head) == 1 and "/loss/" in head[0]
 
 
 def test_the_real_size_step_says_what_it_ran(real_size_step):
-    cfg, notes, _, _ = real_size_step
+    cfg, notes, _ = real_size_step
     assert {k: notes[k] for k in (
         "flash_path", "flash_layout", "mla_saved", "flash_bwd_resident_rows",
         "rope_kind", "hc_mult", "hc_sinkhorn_iters", "hc_state_dtype",
@@ -181,29 +161,13 @@ def test_the_real_size_step_says_what_it_ran(real_size_step):
 
 
 def test_the_real_size_steps_kernels_and_layouts(real_size_step):
-    """Every layer's attention is the kernel, forward once (a recomputed
-    block keeps its results) and backward once; the head's forward is
-    one custom call under ``loss``; no ``[H, T, T]`` array exists; and the
-    n-stream state is ``[1, T, n d]`` in bfloat16 everywhere: never
-    float32 at that width, never with the 4 streams second-minor."""
-    cfg, _, _, text = real_size_step
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    flash = [line for line in calls if "/attn/core/" in line]
-    assert sum("mla_flash_fwd" in line for line in flash) == 5
-    assert sum("mla_flash_bwd" in line for line in flash) == 5
-    head = [line for line in calls if "jit(_ce_lse_fwd)" in line]
-    assert len(head) == 1 and "/loss/" in head[0]
-    assert f"32,{T},{T}" not in text and f"{T},32,{T}" not in text
-    # the buffers are the entry computation's results (inside a fusion a
-    # float32 value of the state's width lives in registers)
-    entry = text[text.rindex("\nENTRY "):]
-    wide = cfg.hc_mult * cfg.n_embd
-    assert f"bf16[1,{T},{wide}]" in entry
-    assert f"f32[1,{T},{wide}]" not in entry
-    assert f"f32[{T},{wide}]" not in entry
-    assert not re.search(rf"\[(1,)?{T},{cfg.hc_mult},{cfg.n_embd}\]", entry)
-    # the maps' scopes reach the compiled program's op names
+    """No ``[H, T, T]`` array exists; the n-stream state enters and leaves
+    the blocks as ``[1, T, n d]`` in bfloat16; and the maps' scopes reach
+    the program's op names."""
+    cfg, _, lowered = real_size_step
+    text = lowered.as_text(debug_info=True)
+    assert f"32x{T}x{T}" not in text and f"{T}x32x{T}" not in text
+    assert f"tensor<1x{T}x{cfg.hc_mult * cfg.n_embd}xbf16>" in text
     for scope in ("h_4/hc_attn/maps", "h_4/hc_mlp/post", "h_0/hc_mlp/pre",
                   "embed/hc_expand", "blocks/hc_collapse"):
         assert scope in text, scope

@@ -115,22 +115,57 @@ def test_delay_preserves_ordering(clean_plan):
     wb.close()
 
 
-def test_heartbeats_absorbed_and_answered(clean_plan):
+class _Channel:
+    """A pair and what a test hangs on it: reader threads and heartbeat
+    monitors."""
+
+    def __init__(self):
+        self.a, self.b = _pair()
+        self.readers = []
+
+    def read(self, conn, into=lambda frame: None):
+        """Pump ``conn``'s application frames into ``into`` until the
+        connection dies."""
+        def pump():
+            try:
+                while True:
+                    into(conn.recv())
+            except (EOFError, OSError):
+                pass
+
+        self.readers.append(threading.Thread(target=pump, daemon=True))
+        self.readers[-1].start()
+
+    def shut(self):
+        for conn in (self.a, self.b):
+            conn.kill()     # deregisters its monitor, wakes its reader
+        for reader in self.readers:
+            reader.join(5)
+            assert not reader.is_alive()
+
+
+@pytest.fixture
+def channel(clean_plan):
+    """Pass or fail, a test leaves no monitor on the process's one
+    heartbeater and no reader behind: both ends are shut down (which
+    wakes a blocked reader; a bare ``close`` does not) and the readers
+    joined before the descriptors' numbers can go to the next test's
+    pair. A reader that outlived its pair read the length prefix of the
+    next pair's first frame off the reused number (PR 56: the frozen
+    channel's peer then broke on a short frame one heartbeat in, before
+    any deadline)."""
+    ch = _Channel()
+    yield ch
+    ch.shut()
+
+
+def test_heartbeats_absorbed_and_answered(channel):
     """Pings are auto-ponged inside recv and neither direction's
     application stream ever sees a heartbeat frame."""
-    wa, wb = _pair()
+    wa, wb = channel.a, channel.b
     got_b = []
     done = threading.Event()
-
-    def pump_b():
-        try:
-            while True:
-                got_b.append(wb.recv())
-                done.set()
-        except (EOFError, OSError):
-            pass
-
-    threading.Thread(target=pump_b, daemon=True).start()
+    channel.read(wb, lambda frame: (got_b.append(frame), done.set()))
     before_sent = wire.COUNTERS["heartbeats_sent"]
     wa.ping()                      # -> b absorbs it and pongs back
     wa.send(("app", 1))
@@ -141,53 +176,47 @@ def test_heartbeats_absorbed_and_answered(clean_plan):
     # frame — heartbeats are invisible to the application stream.
     assert wa.recv() == ("flush", 2)
     assert wire.COUNTERS["heartbeats_sent"] == before_sent + 1
-    wa.close()
-    wb.close()
 
 
-def test_heartbeater_kills_frozen_channel(clean_plan):
+def test_heartbeater_kills_frozen_channel(channel, clean_plan):
     """The silent-partition primitive: one direction frozen (reads
     hang, no RST) must be detected within the liveness deadline and
     converted into an explicit connection error for blocked
     readers."""
-    wa, wb = _pair()
+    wa, wb = channel.a, channel.b
     # a stops hearing ANYTHING (pongs included) — but its sends still
     # leave, exactly like a one-way link.
     clean_plan.install(wire.FaultRule("freeze", kind="wiretest",
                                       direction="recv", peer="peer-b"))
     # keep b pumping so pings would be answered if they arrived
-    threading.Thread(target=lambda: _drain(wb), daemon=True).start()
+    channel.read(wb)
     before = wire.COUNTERS["heartbeats_missed"]
     wire.heartbeater().register(wa, interval=0.1, timeout=0.5,
                                 expecting=lambda: True,
                                 name="frozen-test")
-    with pytest.raises((EOFError, OSError)):
+    began = time.monotonic()
+    with pytest.raises((EOFError, OSError)) as woke:
         wa.recv()                  # blocked reader wakes with error
-    assert wire.COUNTERS["heartbeats_missed"] == before + 1
-    wb.close()
+    assert wire.COUNTERS["heartbeats_missed"] == before + 1, (
+        f"woken {time.monotonic() - began:.3f} s in by {woke.value!r}, "
+        f"not by the deadline")
+    assert time.monotonic() - began >= 0.5      # the deadline, no sooner
 
 
-def _drain(conn):
-    try:
-        while True:
-            conn.recv()
-    except (EOFError, OSError):
-        pass
-
-
-def test_quiescent_exemption_no_pings_when_idle(clean_plan):
+def test_quiescent_exemption_no_pings_when_idle(channel):
     """A monitor with a false ``expecting`` predicate must send zero
-    heartbeat frames no matter how idle the channel is."""
-    wa, wb = _pair()
-    sent_before = wire.COUNTERS["heartbeats_sent"]
+    heartbeat frames no matter how idle the channel is: nothing reaches
+    the other end (the process's counter would also count the pings of
+    whatever else the process monitors)."""
+    wa, wb = channel.a, channel.b
     wire.heartbeater().register(wa, interval=0.05, timeout=10.0,
                                 expecting=lambda: False,
                                 name="idle-test")
     time.sleep(0.5)
-    assert wire.COUNTERS["heartbeats_sent"] == sent_before
+    assert not wb.poll(0)           # not one frame in ten intervals
     assert not wa.closed
-    wa.close()
-    wb.close()
+    wa.ping()                       # and one ping is one frame there
+    assert wb.poll(5)
 
 
 def test_dial_refused_names_peer():

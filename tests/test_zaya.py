@@ -64,9 +64,9 @@ def test_loss_every_gradient_leaf_and_the_routes_are_the_references(
     batch = _batch(seed, cfg)
     ref = mf.load_reference("zaya")
     with jax.default_matmul_precision("highest"):
-        (loss, report), grads = jax.value_and_grad(
-            zaya_loss_fn(model, ce_chunk=32), has_aux=True)(params, batch)
-        logits = model.apply({"params": params}, batch["tokens"])
+        (loss, report), grads = jax.jit(jax.value_and_grad(
+            zaya_loss_fn(model, ce_chunk=32), has_aux=True))(params, batch)
+        logits = jax.jit(model.apply)({"params": params}, batch["tokens"])
     want, want_grads = ref.loss_and_grads(params, batch, _spec(cfg))
     want_logits, _, loads = ref.forward(params, batch["tokens"], _spec(cfg))
     assert float(loss) == pytest.approx(want["loss"], rel=1e-5)
@@ -196,8 +196,8 @@ def test_the_router_state_of_a_layer_depends_on_the_layer_befores():
                                atol=1e-5)
     # and the program's loss feels the first layer's gamma only through
     # the later layers' routes: its gradient is there, layer 0's is zero
-    grads = jax.grad(lambda p: zaya_loss_fn(model, ce_chunk=32)(
-        p, _batch(0, cfg))[0])(params)
+    grads = jax.jit(jax.grad(lambda p: zaya_loss_fn(model, ce_chunk=32)(
+        p, _batch(0, cfg))[0]))(params)
     assert not np.any(grads["h_0"]["mlp"]["router"]["gamma"])   # r_{-1} = 0
     assert np.any(grads["h_1"]["mlp"]["router"]["gamma"])
     assert np.any(grads["h_0"]["mlp"]["router"]["down"]["kernel"])
@@ -260,10 +260,10 @@ def test_routed_experts_given_routes_is_routed_ffn_given_the_matrix(
 
     args = (x, rw, *own)
     diff = tuple(i for i, a in enumerate(args) if a is not None)
-    (la, (ya, load_a)), ga = jax.value_and_grad(
-        matrix, argnums=diff, has_aux=True)(*args)
-    (lb, (yb, load_b)), gb = jax.value_and_grad(
-        given, argnums=diff, has_aux=True)(*args)
+    (la, (ya, load_a)), ga = jax.jit(jax.value_and_grad(
+        matrix, argnums=diff, has_aux=True))(*args)
+    (lb, (yb, load_b)), gb = jax.jit(jax.value_and_grad(
+        given, argnums=diff, has_aux=True))(*args)
     np.testing.assert_allclose(ya, yb, atol=1e-6)
     np.testing.assert_array_equal(load_a, load_b)
     assert float(load_a.sum()) == 2 * T_ * top_k
@@ -348,8 +348,8 @@ def test_the_table_is_tied_and_takes_both_gradients():
     params = model.init_params(jax.random.key(0))
     assert "lm_head" not in params
     batch = _batch(0, cfg)
-    grads = jax.grad(lambda p: zaya_loss_fn(model, ce_chunk=32)(
-        p, batch)[0])(params)
+    grads = jax.jit(jax.grad(lambda p: zaya_loss_fn(model, ce_chunk=32)(
+        p, batch)[0]))(params)
     unseen = np.setdiff1d(np.arange(cfg.vocab_size),
                           np.asarray(batch["tokens"]))
     assert unseen.size      # rows no token looked up still get the head's
