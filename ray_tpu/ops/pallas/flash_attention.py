@@ -27,7 +27,7 @@ Grid: (batch, lane blocks) where a whole row fits one block (T <=
 q-block, key cell) with the innermost dimension "arbitrary" (sequential
 on TPU), so VMEM scratch carries the streaming softmax across the key
 blocks of one q-block. Blocks are square: one size ``blk`` each way
-(``_pick_block``'s, or the tests' ``block``). Folded, "batch" is
+(``_window_block``'s, or the tests' ``block``). Folded, "batch" is
 batch*heads and there is one lane block.
 
 The backward pass is one kernel a shape, three in the file, and nothing
@@ -66,10 +66,10 @@ whole tiles — and so computes the triangle too, not the square
 A window (``flash_attention(window=w)``: row t sees keys t - w < j <=
 t, the sliding-window layers of a window/global stack) adds the band's
 second edge: the multi-block grids' innermost dimension runs over the
-band's blocks alone, in the forward (key cells) and in the backward
-(query cells), so the blocks below the band are in no cell; only the
-two blocks that straddle an edge pay for a mask (``_band`` and the
-comment above ``_keys_of``). With no window none of it is traced.
+band's blocks alone, in both passes (key cells forward, query cells
+backward), so the blocks below the band are in no cell, and a window
+shorter than a block brings the block down to itself (``_band``, the
+comment above ``_keys_of``, ``_window_block``). Without one: no trace.
 """
 
 from __future__ import annotations
@@ -95,13 +95,13 @@ def _pick_block(t: int, target: int = 1024) -> int:
     """Largest divisor of t that is <= target and a multiple of 8.
 
     The rows of a block, queries and keys alike (its lanes come from
-    the head width, ``_heads_per_block``). Default target 1024: a grid
-    cell has its price (pipeline fill, scratch init, the streaming
-    softmax's extra VPU work), so a row that fits one block takes one,
-    and the block is cut inside the body instead, by static slices
-    (``_causal_slabs``): 33.1 ms of kernels a GPT-2 step so against
-    46.6 for the square in one piece (one v5e chip, 12 layers of 32 x
-    12 heads at T=1024; PERF.md section 6, PR 31)."""
+    the head width, ``_heads_per_block``), from the row alone: under a
+    window shorter than it ``_window_block`` chooses. Target 1024: a
+    grid cell has its price (pipeline fill, scratch init, the streaming
+    softmax's VPU work), so a row that fits one block takes one, cut
+    inside the body by static slices (``_causal_slabs``): 33.1 ms of
+    kernels a GPT-2 step so against 46.6 for the square in one piece
+    (one v5e chip, 12 layers of 32 x 12 heads; PERF.md 6, PR 31)."""
     best = 0
     for b in range(8, min(t, target) + 1, 8):
         if t % b == 0:
@@ -816,19 +816,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (``t - window < j <= t``: the sliding-window layers of a
     window/global stack); None, or a window no shorter than the row, is
     plain causal attention. The multi-block grids run over the band
-    alone, in both passes (``_band``'s comment); a call given a window
-    says so in the notes: ``flash_window`` (``"none"`` where the row is
-    no longer than it) and ``flash_band_blocks``, the block pairs a head
-    walks (70 at 16,384 rows in blocks of 1,024 under a window of 4,096,
-    against the causal grid's 136). In a stack that mixes windowed and
-    global layers the notes are those of the last windowed call.
+    alone, in both passes (``_band``'s comment), in blocks of
+    ``_window_block``'s choice (the window's own 512 rows under 512
+    keys); a call given a window says so in the notes (``_band_notes``):
+    ``flash_window`` (``"none"`` where the row is no longer than it),
+    ``flash_block_rows``, ``flash_band_blocks``, ``flash_band_area``. In
+    a stack of both kinds the notes are the last windowed call's.
 
     Falls back to the caller's dense path when shapes don't block
     cleanly — check with ``flash_attention_shapes_ok`` or catch
     ValueError. A row of several blocks too long for the backward
     kernel's VMEM (``_bwd_fits``) is no such shape: it raises
     ``NotImplementedError``, before anything is traced or noted.
-    ``block``: the rows of a block in place of ``_pick_block``'s, the
+    ``block``: the rows of a block in place of ``_window_block``'s, the
     tests' way to the multi-block grids at sizes the CPU interprets.
 
     The kernels read q, k, v where the projections wrote them, 128
@@ -839,9 +839,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     b, t, h, d = q.shape
     if scale is None:
         scale = d ** -0.5
-    blk = block or _pick_block(t)
-    if blk == 0 or t % blk:
-        raise ValueError(f"seq len {t} not divisible into flash blocks")
     asked = window is not None
     if asked:
         if not causal or window < 1:
@@ -849,6 +846,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                              "window is the last `window` keys of a "
                              "causal row")
         window = None if window >= t else int(window)
+    blk = block or _window_block(t, window)
+    if blk == 0 or t % blk:
+        raise ValueError(f"seq len {t} not divisible into flash blocks")
     direct = _heads_per_block(h, d)
     hpb = direct or 1           # folded: one head a block
     single = blk == t
@@ -866,7 +866,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         if single else 1)
     if asked:   # a call without a window leaves the notes it left
         tracing.note_trace(flash_window=window or "none",
-                           flash_band_blocks=_band(t, blk, window)[1])
+                           **_band_notes(t, blk, window))
     if not single:  # dq's accumulator holds the whole row in VMEM
         tracing.note_trace(flash_bwd_resident_rows=t)
     static = _Static(float(scale), causal, blk, d, hpb, interpret, window)
@@ -1248,3 +1248,52 @@ def mla_flash_static(t: int, dn: int, dr: int, scale: float | None = None,
         flash_path="mla_multi_block", flash_causal_slabs=1,
         flash_bwd_resident_rows=t)      # dq's rows held in VMEM
     return static
+
+
+# ---------------------------------------------------------------------------
+# the block of a windowed call
+# ---------------------------------------------------------------------------
+#
+# Below every kernel of the file, as the latent kernels are below the
+# equal-width ones: a kernel's body is serialized with the lines of the
+# frames above it (``flash_attention``'s call of ``_flash_core`` among
+# them), so what sits here moves no cell's compile-cache key but those
+# of the calls it gives another block.
+
+def _window_block(t: int, window: int | None) -> int:
+    """Rows of a block for a row of ``t`` under ``window`` (None: plain
+    causal): ``_pick_block(t)``, but the window's own size where a row
+    of several blocks has a window shorter than that block which is
+    whole 128-row tiles and divides the row. A q-block then meets two
+    key blocks of ``window`` rows, not two of ``_pick_block``'s: under
+    512 keys at 16,384 rows 63 pairs of 512 x 512 a head where blocks of
+    1,024 walk 31 pairs of four times the area for the same band (a
+    sliding layer of 64 heads, forward + backward on one v5e chip, 18.7
+    ms so against 25.3: PERF.md section 6, PR 57). A window of a block
+    or more keeps ``_pick_block``'s: under 4,096 keys blocks of 512
+    walk 63 pairs' worth for 70 and lose more than that to the cells'
+    own price (30.4 ms a layer of 28 heads against 23.7, same place).
+    Decided from the call's shapes alone, as ``_causal_slabs`` is."""
+    blk = _pick_block(t)
+    if (window is not None and window < blk < t and window % 128 == 0
+            and t % window == 0):
+        return window
+    return blk
+
+
+def _band_notes(t: int, blk: int, window: int | None) -> dict:
+    """What a call given a window says of its geometry: the block pairs
+    a head walks (70 at 16,384 rows in blocks of 1,024 under a window of
+    4,096, against the causal grid's 136; 63 of 512 under 512), the rows
+    of the block it ran in, and the score entries a head computes over
+    the entries its mask lets through (a row sees ``min(row + 1,
+    window)`` keys): how much dead area is left, 1.25 and 2.0 there. A
+    window no shorter than the row is the causal call: the grid's pairs
+    at or under the diagonal, or a single block's causal slabs."""
+    pairs = _band(t, blk, window)[1]
+    slabs = _causal_slabs(t, window is None) if blk == t else 1
+    walked = pairs * blk * blk * (slabs + 1) / (2 * slabs)
+    w = window or t
+    seen = w * (w + 1) // 2 + (t - w) * w
+    return dict(flash_band_blocks=pairs, flash_block_rows=blk,
+                flash_band_area=round(walked / seen, 3))

@@ -49,10 +49,11 @@ def _out_and_grads(fn, q, k, v, g):
     return (out, *jax.grad(lambda *a: (fn(*a) * g).sum(), (0, 1, 2))(q, k, v))
 
 
-# (rows, block): 256 rows in blocks of 64 is the multi-block grid, 128 in
-# one block the single-block body
-@pytest.mark.parametrize("t, block", [(256, 64), (128, 128)],
-                         ids=["multi_block", "single_block"])
+# (rows, block): 256 rows in blocks of 64 is the multi-block grid (192:
+# three blocks of it), 128 in one block the single-block body
+@pytest.mark.parametrize("t, block", [(256, 64), (128, 128), (192, 64)],
+                         ids=["multi_block", "single_block",
+                              "three_blocks"])
 @pytest.mark.parametrize("h, d", [(1, 128), (2, 64)],
                          ids=["one_head_a_block", "two_heads_a_block"])
 @pytest.mark.parametrize("window", [1, 24, 64, 65, 150, 4096],
@@ -110,11 +111,107 @@ def test_multi_block_backward_under_a_window_is_the_dense_masked_softmaxs(
         np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
 
 
+# -- the block a windowed call runs in ----
+
+@pytest.mark.parametrize("t, window, block", [
+    (16384, 512, 512),      # Laguna's sliding layers: the window's own
+    (4096, 512, 512),       # Phi-4-mini-flash's
+    (16384, 256, 256), (1152, 384, 384),
+    (16384, 4096, 1024),    # SmallThinker's: a window of four blocks
+    (16384, None, 1024), (16384, 1024, 1024),
+    (16384, 500, 1024),     # no whole number of 128-row tiles
+    (16384, 640, 1024),     # does not divide the row
+    (1024, 512, 1024),      # a row of one block
+    (64, 24, 64), (32, 8, 32)])    # the tiny presets'
+def test_the_block_of_a_windowed_call(t, window, block):
+    """``_window_block``: the window's size where a row of several
+    blocks has a window shorter than ``_pick_block``'s block, in whole
+    128-row tiles, that divides the row; ``_pick_block(t)`` in every
+    other case, and ``_pick_block`` itself as it was (the benchmark's
+    builders call it with the row alone)."""
+    assert fa._window_block(t, window) == block
+    picked = {16384: 1024, 4096: 1024, 1152: 576, 1024: 1024, 64: 64,
+              32: 32}[t]
+    assert fa._pick_block(t) == picked
+
+
+def test_an_explicit_block_is_the_callers(notes):
+    q, k, v, _ = _qkvg(2048, 1, 128)
+    jax.eval_shape(lambda *a: fa.flash_attention(
+        *a, window=512, block=1024, interpret=True), q, k, v)
+    assert notes["flash_block_rows"] == 1024
+    assert notes["flash_band_blocks"] == 3
+
+
+@pytest.mark.parametrize("h, d", [(1, 128), (2, 64)],
+                         ids=["one_head_a_block", "two_heads_a_block"])
+@pytest.mark.parametrize("t, window", [(1152, 384), (1536, 384)],
+                         ids=["three_blocks", "four_blocks"])
+def test_the_rules_own_blocks_are_the_dense_masked_softmax(t, window, h, d,
+                                                           notes):
+    """No ``block=``: the call runs in blocks of its window, every live
+    cell masked (one on the diagonal, one on the band's lower edge, none
+    wholly seen); output and the three gradients against the dense
+    masked softmax, and against the same call in ``_pick_block``'s
+    blocks."""
+    q, k, v, g = _qkvg(t, h, d, seed=5)
+
+    def flash(block):
+        return jax.jit(lambda q, k, v, g: _out_and_grads(
+            lambda *a: fa.flash_attention(*a, window=window, block=block,
+                                          interpret=True), q, k, v, g))
+    got = flash(None)(q, k, v, g)
+    n = t // window
+    assert notes["flash_path"] == "multi_block"
+    assert notes["flash_block_rows"] == window
+    assert notes["flash_band_blocks"] == 2 * n - 1
+    assert notes["flash_band_area"] == pytest.approx(2.0, abs=3e-3)
+    want = _out_and_grads(lambda *a: _dense(*a, window), q, k, v, g)
+    large = flash(fa._pick_block(t))(q, k, v, g)
+    assert notes["flash_block_rows"] == fa._pick_block(t) > window
+    for name, a, b, c in zip(("out", "dq", "dk", "dv"), got, want, large):
+        np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
+        np.testing.assert_allclose(a, c, atol=5e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("t, block, window, said", [
+    # Laguna's cell, now and before; SmallThinker's; Phi-4-mini-flash's
+    (16384, None, 512, (512, 63, 2.0)),
+    (16384, 1024, 512, (1024, 31, 3.936)),
+    (16384, None, 4096, (1024, 70, 1.25)),
+    (4096, None, 512, (512, 15, 1.999)),
+    (4096, 1024, 512, (1024, 7, 3.733)),
+    # a tiny preset's: one block, the square with the band masked in it
+    (64, None, 24, (64, 1, 3.251)),
+    # a window no shorter than the row: the causal call's, in the grid
+    # and in a single block's four causal slabs
+    (16384, None, 16384, (1024, 136, 1.062)),
+    (1024, None, 4096, (1024, 1, 1.249))])
+def test_a_windowed_call_says_its_block_and_its_dead_area(
+        t, block, window, said, notes):
+    """``flash_block_rows``, ``flash_band_blocks`` at the block that
+    ran, and ``flash_band_area``: the entries of the walked block pairs
+    over the entries the mask lets through, from the definition."""
+    x = jax.ShapeDtypeStruct((1, t, 1, 128), jnp.bfloat16)
+    jax.eval_shape(lambda *a: fa.flash_attention(
+        *a, window=window, block=block), x, x, x)
+    rows, pairs, area = said
+    assert notes["flash_block_rows"] == rows
+    assert notes["flash_band_blocks"] == pairs
+    assert notes["flash_band_area"] == pytest.approx(area, abs=1e-3)
+    seen = sum(min(row + 1, window) for row in range(t))
+    if rows < t:     # a grid's pairs are computed whole
+        assert notes["flash_band_area"] == pytest.approx(
+            pairs * rows * rows / seen, abs=1e-3)
+    assert notes["flash_window"] == ("none" if window >= t else window)
+
+
 def test_a_call_without_a_window_leaves_the_notes_it_left(notes):
     q, k, v, _ = _qkvg(128, 1, 128)
     fa.flash_attention(q, k, v, block=64, interpret=True)
     assert notes["flash_path"] == "multi_block"
-    assert "flash_window" not in notes and "flash_band_blocks" not in notes
+    assert not {"flash_window", "flash_band_blocks", "flash_block_rows",
+                "flash_band_area"} & set(notes)
 
 
 @pytest.mark.parametrize("t, block, window, cells, walked", [

@@ -69,9 +69,12 @@ LOCATIONS = re.compile(r"loc\(.*?\)$|^#loc.*$", re.M)
     ((1, 16384, 28, 128), 4096, (8, 24)),
     ((2, 8192, 8, 128), None, (8, 24)),
     ((1, 65536, 2, 128), None, (34, 64)),
-    ((1, 65536, 2, 128), 4096, (34, 64))],
+    ((1, 65536, 2, 128), 4096, (34, 64)),
+    ((1, 16384, 64, 128), 512, (4, 24)),
+    ((1, 4096, 80, 64), 512, (1, 24))],
     ids=["smallthinker_global", "smallthinker_window", "zaya",
-         "longest_row_that_fits", "longest_row_under_a_window"])
+         "longest_row_that_fits", "longest_row_under_a_window",
+         "laguna_window", "phi4flash_window"])
 def test_the_multi_block_backward_compiles_as_one_kernel_in_its_vmem(
         v5e, monkeypatch, shape, window, used_mib):
     """The SmallThinker cell's two layers (28 heads of 128 over 16,384
@@ -84,7 +87,10 @@ def test_the_multi_block_backward_compiles_as_one_kernel_in_its_vmem(
     succeeding is the proof, the compiler refusing a kernel that holds
     more than it was granted. ``_bwd_fits`` counts more than the
     compiler takes: held to what the compiler used, the same row is
-    refused."""
+    refused. Laguna's sliding layer (64 heads of 128 under 512 keys)
+    and Phi-4-mini-flash's (80 heads of 64 at 4,096 rows) run in blocks
+    of their window (``_window_block``: 512 rows), the others in
+    ``_pick_block``'s 1,024."""
     fa = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                              sharding=SingleDeviceSharding(v5e[0]))
@@ -105,7 +111,8 @@ def test_the_multi_block_backward_compiles_as_one_kernel_in_its_vmem(
     assert len(used) == 2 and max(granted) == 64 << 20
     assert used_mib[0] << 20 < max(used) < used_mib[1] << 20
     monkeypatch.setattr(fa, "_BWD_VMEM", max(used))
-    assert not fa._bwd_fits(shape[1], 1024, 128)
+    assert not fa._bwd_fits(
+        shape[1], fa._window_block(shape[1], window), 128)
     with pytest.raises(NotImplementedError, match=f"{shape[1]} rows"):
         jax.eval_shape(loss, x, x, x)
     assert f"{shape[1]},{shape[1]}" not in text

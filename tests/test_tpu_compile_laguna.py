@@ -40,8 +40,10 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     block runs no forward kernel. The two full layers' under
     ``attn/core`` over 6,144 lanes, the three sliding layers' under
     ``attn/window`` over 8,192 lanes and the band of a 512-key window in
-    blocks of 1,024 (31 block pairs a head where the causal grid walks
-    136). No ``[T, T]`` array exists."""
+    blocks of the window's own 512 rows (``_window_block``: 63 block
+    pairs of 512 x 512 a head, twice the band's area, where blocks of
+    1,024 walked 31 of four times that and the causal grid walks 136 of
+    1,024). No ``[T, T]`` array exists."""
     _, notes, lowered = real_size_step
     assert notes["attn_kind"] == "window_global"
     assert notes["attn_layers"] == "FSSSF"
@@ -56,7 +58,9 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["flash_layout"] == "bthd"
     assert notes["flash_lanes_per_block"] == 128
     assert notes["flash_path"] == "multi_block"
-    assert notes["flash_window"] == 512 and notes["flash_band_blocks"] == 31
+    assert notes["flash_window"] == 512 and notes["flash_band_blocks"] == 63
+    assert notes["flash_block_rows"] == 512
+    assert notes["flash_band_area"] == pytest.approx(2.0, abs=1e-3)
     assert notes["flash_bwd_resident_rows"] == 16384
     assert notes["moe_router"] == "sigmoid"
     assert notes["moe_experts_held"] == [0, 32]

@@ -39,8 +39,9 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     """Every attention layer's four products are one call of the
     multi-block flash kernels over 80 heads of 64 in the projections' own
     layout, the windowed layers' under the band of a 512-key window in
-    blocks of 1,024 (7 block pairs a head where the causal grid walks
-    10); the three scans run the kernel pair of
+    blocks of the window's 512 rows (``_window_block``: 15 block pairs
+    of 512 x 512 a head where blocks of 1,024 walked 7 of four times the
+    area and the causal grid walks 10 of 1,024); the three scans run the kernel pair of
     ``ops/pallas/mamba1_scan.py`` (custom calls under ``mamba/../scan``,
     no ``while`` loop there), their convolutions that of
     ``ops/pallas/causal_conv.py`` (under ``mamba/../conv``; PR 55) and no
@@ -63,7 +64,9 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["flash_layout"] == "bthd"
     assert notes["flash_lanes_per_block"] == 128
     assert notes["flash_path"] == "multi_block"
-    assert notes["flash_window"] == 512 and notes["flash_band_blocks"] == 7
+    assert notes["flash_window"] == 512 and notes["flash_band_blocks"] == 15
+    assert notes["flash_block_rows"] == 512
+    assert notes["flash_band_area"] == pytest.approx(2.0, abs=1e-3)
     assert notes["flash_bwd_resident_rows"] == 4096
     calls = kernel_calls(lowered)
     convs = [line for line in calls if "/mamba/" in line

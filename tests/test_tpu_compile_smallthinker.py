@@ -34,7 +34,9 @@ def real_size_step(v5e):
 def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     """Every layer's attention is the equal-width multi-block kernel, the
     windowed layers' under a window of 4,096 with the band's 70 block
-    pairs a head and not the causal grid's 136, its backward pass ONE
+    pairs a head and not the causal grid's 136 (in ``_pick_block``'s
+    1,024 rows: a window of four blocks bypasses ``_window_block``'s
+    smaller ones), its backward pass ONE
     kernel a layer with dq's 16,384 rows resident (four ``_flash_bwd``
     custom calls), each kernel's call under its layer's scope
     (``attn/core`` in layer 0, ``attn/window`` in layers 1-3), and no
@@ -44,6 +46,8 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["flash_layout"] == "bthd"
     assert notes["flash_window"] == 4096
     assert notes["flash_band_blocks"] == 70 < 16 * 17 // 2
+    assert notes["flash_block_rows"] == 1024
+    assert notes["flash_band_area"] == pytest.approx(1.25)
     assert notes["flash_bwd_resident_rows"] == 16384
     assert notes["attn_kind"] == "window_global"
     assert notes["attn_layers"] == "gWWW"
