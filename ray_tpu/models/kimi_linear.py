@@ -33,15 +33,25 @@ three **KDA** layers (Kimi Delta Attention) to one **MLA** layer.
 **What a KDA mixer keeps for the backward pass**: the six projections
 of its input (``W_q h``, ``W_k h``, ``W_v h`` and the narrow ``W_f1 h``,
 ``W_g1 h``, ``W_b h``); the convolutions, the norms, the decay, the
-recurrence and the gate run again (``_kda_core`` is a
-``jax.checkpoint``), as ``latent_attention`` keeps its latents. With
-``remat`` the blocks are recomputed too (``models/llama.py``'s switch),
-keeping of each KDA mixer its gated output, so that the recurrence runs
-forward twice a step on the kernels (the pass, ``_kda_core``'s
-recomputation; a third time, a group's inside ``kda_scan``, on the XLA
-path) and not once more, and of the latent layer its core's output and
-row statistics (``ops/attention.py::remat_policy``), so that the latent
-flash forward kernel runs once (the note ``blocks_remat_keeps``).
+recurrence and the gate's product ``W_g2 (W_g1 h) + b_g`` run again
+(``_kda_core`` is a ``jax.checkpoint``), as ``latent_attention`` keeps
+its latents. With ``remat`` the blocks are recomputed too
+(``models/llama.py``'s switch), keeping of each KDA mixer its gated
+output, so that the recurrence runs forward twice a step on the kernels
+(the pass, ``_kda_core``'s recomputation; a third time, a group's
+inside ``kda_scan``, on the XLA path) and not once more, and of the
+latent layer its core's output and row statistics
+(``ops/attention.py::remat_policy``), so that the latent flash forward
+kernel runs once (the note ``blocks_remat_keeps``). **The output gate**
+(``ops/ssm.py::sigmoid_gated_head_rms_norm``, handed the mixer's mesh;
+the note ``kda_gate_path``) on its kernels (``pallas``: a TPU, heads of
+whole 128-lane tiles, one device or a mesh that shards the batch alone)
+runs forward once a layer a step and backward once: the gated output is
+what the block keeps, and ``_kda_core``'s recomputation makes the
+gate's operands (the recurrence's ``o`` and ``gate``) for the backward
+kernel, not its result. As the XLA function (``xla``: everywhere else)
+it runs forward twice, the pass and its own ``jax.checkpoint``'s
+recomputation inside the backward.
 
 It is the benchmark's sixth language model
 (``kimi-linear-48b-a3b.b1-t16384`` runs layers 1-5, KDA with the dense
@@ -232,7 +242,7 @@ def _kda_core(q, k, v, f_low, b_logit, g_low, w, *, heads: int, chunk: int,
     with jax.named_scope("out_gate"):
         gate = g_low @ w["g_b"].astype(dt) + w["g_bias"].astype(dt)
         y = ssm.sigmoid_gated_head_rms_norm(
-            o.reshape(b, t, inner), gate, w["norm"], heads, eps)
+            o.reshape(b, t, inner), gate, w["norm"], heads, eps, mesh=mesh)
     return y, out_sq
 
 

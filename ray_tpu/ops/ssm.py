@@ -1,9 +1,11 @@
 """State-space (Mamba) ops: the Mamba-2 selective scan in its chunked form,
 the depthwise causal convolution (with its SiLU) in front of it and the
-grouped gated RMSNorm behind it. All three are Pallas kernels where a
-TPU program can take them (below) and XLA functions elsewhere, the
-convolution and the norm recomputed in the backward on either path.
-Nemotron-H's ``M`` layers (``models/nemotron_h.py``) are the caller.
+grouped gated RMSNorm behind it, and Kimi Delta Attention's output gate,
+which stands where that norm does behind another recurrence. All four
+are Pallas kernels where a TPU program can take them (below) and XLA
+functions elsewhere, the convolution, the norm and the gate recomputed
+in the backward on either path. Nemotron-H's ``M`` layers
+(``models/nemotron_h.py``) are the first three's caller.
 
 The recurrence, a head (``S`` is ``[P, N]``)::
 
@@ -74,9 +76,21 @@ progress.
 Kimi Delta Attention (``ops/kda.py``, ``models/kimi_linear.py``) takes
 two things from here: the convolution, three times a layer and without
 a bias (36 kernel calls a step over four layers), and
-``sigmoid_gated_head_rms_norm`` at the end of this file, its own output
-gate (a sigmoid on the *normed* output, where Mamba-2 norms
-the gated one).
+``sigmoid_gated_head_rms_norm``, its own output gate (a sigmoid on the
+*normed* output, where Mamba-2 norms the gated one; ``scale`` one
+head's width, shared by the heads). It decides as the norm does, by
+the same ``norm_path()`` with a head a group: ``pallas`` is the second
+kernel pair of ``ops/pallas/gated_norm.py`` (``head_gate_norm``; the
+same grid, blocks and kernel shells, another strip's arithmetic), which
+reads the recurrence's float32 ``o`` and the bfloat16 ``gate`` once a
+pass in the row-major ``[B, T, H*K]`` that the recurrence's kernel
+writes and ``W_o``'s matmul reads (8 kernel calls a step over four
+layers: a recomputed block keeps the gated output, so the forward
+kernel runs in the step's forward pass alone); ``xla`` the reshape-and-
+mean function under a ``jax.checkpoint``, the CPU's path and what the
+tests hold the kernels to. As that function it was 41.2 ms of the
+Kimi-Linear cell's step in passes over ``[1, 16384, 4096]`` float32
+arrays (PERF.md section 6, PR 58). Every call notes ``kda_gate_path``.
 
 **Mamba-1** (``mamba1_scan``, Phi-4-mini-flash's ``M`` layers,
 ``models/phi4flash.py``) is the older recurrence: the decay differs by
@@ -122,8 +136,9 @@ def scan_path(x_shape, state_shape, chunk: int, mesh=None) -> str:
 
 def norm_path(shape, groups: int, mesh=None) -> str:
     """Which gated norm ``gated_group_rms_norm`` compiles for ``y``
-    [b, T, C] in ``groups`` groups: ``pallas`` exactly where the scan
-    takes its kernels (a TPU, each group whole 128-lane tiles, and
+    [b, T, C] in ``groups`` groups, and ``sigmoid_gated_head_rms_norm``
+    for ``o`` [b, T, C] in as many heads: ``pallas`` exactly where the
+    scan takes its kernels (a TPU, each group whole 128-lane tiles, and
     ``_kernel_batch_axes`` finds the program one the kernels can
     serve), else ``xla``."""
     if (jax.default_backend() == "tpu" and len(shape) == 3
@@ -315,15 +330,31 @@ def _gated_group_rms_norm_xla(y, z, scale, groups: int, eps: float):
     return (g.reshape(shape) * scale.astype(jnp.float32)).astype(dtype)
 
 
-@functools.partial(jax.checkpoint, static_argnums=(3, 4))
-def sigmoid_gated_head_rms_norm(o, gate, scale, heads: int, eps: float):
+def sigmoid_gated_head_rms_norm(o, gate, scale, heads: int, eps: float, *,
+                                mesh=None):
     """Kimi Delta Attention's output gate: ``sigmoid(gate) *
     RMSNorm_head(o)``, the norm over each of ``heads`` equal slices of
     the last dimension with one ``scale`` [C / heads] shared by the
     heads. Not ``gated_group_rms_norm``: that one norms the *gated*
     product ``y * silu(z)``; this one gates the *normed* output, by a
     sigmoid. float32 inside, ``gate``'s dtype out; recomputed in the
-    backward (``o`` and ``gate`` are kept). XLA everywhere."""
+    backward (``o`` and ``gate`` are kept, in their own dtype). ``mesh``
+    is the mesh the program is sharded over, if the caller knows one:
+    ``norm_path`` decides from it, a head a group, between the second
+    kernel pair of ``ops/pallas/gated_norm.py`` (``head_gate_norm``)
+    and the XLA function below. Notes ``kda_gate_path`` for the trace
+    in progress."""
+    path = norm_path(o.shape, heads, mesh)
+    tracing.note_trace(kda_gate_path=path)
+    if path == "pallas":
+        return gated_norm.head_gate_norm(
+            o, gate, scale, heads=heads, eps=eps, mesh=mesh,
+            batch_axes=_kernel_batch_axes(mesh, o.shape[0]))
+    return _sigmoid_gated_head_rms_norm_xla(o, gate, scale, heads, eps)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4))
+def _sigmoid_gated_head_rms_norm_xla(o, gate, scale, heads: int, eps: float):
     shape = o.shape
     x = o.astype(jnp.float32).reshape(*shape[:-1], heads, shape[-1] // heads)
     x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)

@@ -417,12 +417,13 @@ def test_the_step_reports_the_kda_keys_and_notes_what_the_layers_are(
     step.trace(state, _batch(0, cfg))
     assert {k: notes[k] for k in (
         "attn_kind", "attn_layers", "kda_path", "kda_chunk", "kda_heads",
-        "kda_state", "mla_positions", "mla_saved", "dense_layers",
+        "kda_state", "kda_gate_path", "mla_positions", "mla_saved", "dense_layers",
         "flash_path", "moe_router", "moe_expert_kind",
         "moe_experts_held")} == {
         "attn_kind": "kda_mla", "attn_layers": "KKKMK",
         "kda_path": "xla_chunked", "kda_chunk": 16, "kda_heads": 2,
-        "kda_state": [16, 16], "mla_positions": "none",
+        "kda_state": [16, 16], "kda_gate_path": "xla",
+        "mla_positions": "none",
         "mla_saved": "latents", "dense_layers": 1, "flash_path": "xla",
         "moe_router": "sigmoid", "moe_expert_kind": "swiglu",
         "moe_experts_held": [4, 4]}
@@ -433,6 +434,32 @@ def test_the_step_reports_the_kda_keys_and_notes_what_the_layers_are(
     assert float(metrics["kda_out_rms"]) > 0
     assert 0 < float(metrics["grad_norm_kda_gates"]) \
         < float(metrics["grad_norm"])
+
+
+@pytest.mark.parametrize("axes", [None, {"dp": 2}], ids=["no_mesh", "dp"])
+def test_a_mixer_hands_its_mesh_to_the_one_output_gate(monkeypatch, axes):
+    """``ops/ssm.py::sigmoid_gated_head_rms_norm`` is the one entry and
+    decides from the mesh it is given; here, on the CPU, it is the XLA
+    function, which the comparison with the reference above holds to
+    the formula in the loss and every gradient leaf."""
+    from ray_tpu.models.kimi_linear import KDAMixer
+    from ray_tpu.ops import ssm
+    from ray_tpu.parallel import make_mesh
+    mesh = axes and make_mesh(axes, devices=jax.devices()[:2])
+    seen, gate = [], ssm.sigmoid_gated_head_rms_norm
+    monkeypatch.setattr(
+        ssm, "sigmoid_gated_head_rms_norm",
+        lambda *a, **kw: seen.append(kw) or gate(*a, **kw))
+    xla = []
+    monkeypatch.setattr(
+        ssm, "_sigmoid_gated_head_rms_norm_xla",
+        lambda o, gate, *a: xla.append(o.shape) or gate)
+    cfg = KimiLinearConfig.tiny(**F32)
+    mixer = KDAMixer(cfg, mesh)
+    h = jax.ShapeDtypeStruct((2, cfg.seq_len, cfg.n_embd), jnp.float32)
+    jax.eval_shape(lambda h: mixer.init_with_output(jax.random.key(0), h), h)
+    assert seen == [{"mesh": mesh}]
+    assert xla == [(2, cfg.seq_len, cfg.kda_inner)]
 
 
 @pytest.mark.parametrize("remat, keeps", [

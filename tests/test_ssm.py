@@ -6,7 +6,8 @@ backward keeps; and the same for the scan's Pallas kernels
 (``ops/pallas/ssd_scan.py``) in ``interpret`` mode, with which of the
 two paths ``scan_path`` picks for a shape, a backend and a mesh; and the
 gated norm's kernels (``ops/pallas/gated_norm.py``) the same way, with
-``norm_path``; and the convolution's (``ops/pallas/causal_conv.py``),
+``norm_path``, and that file's second pair, Kimi Delta Attention's
+output gate; and the convolution's (``ops/pallas/causal_conv.py``),
 with ``conv_path``."""
 
 import functools
@@ -496,6 +497,139 @@ def test_kernel_norm_over_a_batch_sharded_mesh_is_the_one_device_norm():
 def test_columns_the_norms_kernels_do_not_tile_are_refused_by_name():
     with pytest.raises(ValueError, match="do not tile"):
         _norm_kernel(3)(*_norm_inputs((2, 16, 12), jnp.float32)[:3])
+
+
+# ---------------------------------------------------------------------------
+# Kimi Delta Attention's output gate: the file's second kernel pair,
+# interpreted here, against the XLA function (which this backend's
+# ``sigmoid_gated_head_rms_norm`` is)
+# ---------------------------------------------------------------------------
+
+def _gate_inputs(shape, heads, dtype, seed=0):
+    """o, gate, scale [C / heads], dout."""
+    o, gate, _, dout = _norm_inputs(shape, dtype, seed)
+    scale = 1.0 + 0.5 * jax.random.normal(jax.random.key(seed + 1),
+                                          (shape[-1] // heads,))
+    return o, gate, scale, dout
+
+
+def _gate_kernel(heads, **kw):
+    return lambda *a: gated_norm.head_gate_norm(
+        *a, heads=heads, eps=1e-5, interpret=True, **kw)
+
+
+# (batch, T, C), heads: row blocks of 128 rows
+GATE_CASES = {
+    "one_head_a_ragged_last_block": ((2, 300, 128), 1),
+    "eight_heads_a_ragged_last_block": ((2, 300, 1024), 8),
+    "four_heads_of_two_tiles_whole_blocks": ((2, 256, 1024), 4),
+    "fewer_rows_than_a_strip_holds": ((2, 7, 256), 2),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", GATE_CASES)
+def test_kernel_output_gate_is_the_xla_output_gate(monkeypatch, case, dtype):
+    """The value and all three gradients, as
+    ``test_kernel_norm_is_the_xla_norm`` holds the other form:
+    ``scale``'s gradient is a float32 sum over the rows and the heads
+    either way."""
+    shape, heads = GATE_CASES[case]
+    monkeypatch.setattr(gated_norm, "_BLOCK_BYTES",
+                        128 * shape[-1] * jnp.dtype(dtype).itemsize)
+    args = _gate_inputs(shape, heads, dtype, seed=shape[1])
+    got = _value_and_grads(_gate_kernel(heads), *args)
+    want = _value_and_grads(
+        lambda *a: ssm.sigmoid_gated_head_rms_norm(*a, heads, 1e-5), *args)
+    step = 1e-5 if dtype == jnp.float32 else 2 ** -7
+    for name, g, w in zip("out do dgate dscale".split(), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        tol = 1e-5 if name == "dscale" else step
+        np.testing.assert_allclose(
+            g, w, rtol=tol, atol=tol * float(jnp.abs(w).max()),
+            err_msg=name)
+
+
+def test_kernel_output_gate_takes_its_dtype_from_the_gate():
+    """``o`` float32 from a recurrence and a bfloat16 ``gate``: the
+    result and ``dgate`` are the gate's, ``do`` is ``o``'s, as the XLA
+    function's."""
+    o, gate, scale, dout = _gate_inputs((1, 40, 256), 2, jnp.float32, seed=3)
+    gate, dout = gate.astype(jnp.bfloat16), dout.astype(jnp.bfloat16)
+    got = _value_and_grads(_gate_kernel(2), o, gate, scale, dout)
+    want = _value_and_grads(
+        lambda *a: ssm.sigmoid_gated_head_rms_norm(*a, 2, 1e-5),
+        o, gate, scale, dout)
+    for name, g, w in zip("out do dgate dscale".split(), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        w = w.astype(jnp.float32)
+        np.testing.assert_allclose(
+            g.astype(jnp.float32), w, rtol=2 ** -7,
+            atol=2 ** -7 * float(jnp.abs(w).max()), err_msg=name)
+
+
+KDA_GATE_CELL = (1, 16384, 4096)
+
+
+@pytest.mark.parametrize("backend, shape, heads, path", [
+    ("tpu", KDA_GATE_CELL, 32, "pallas"),
+    ("cpu", KDA_GATE_CELL, 32, "xla"),
+    ("tpu", (1, 64, 32), 2, "xla"),
+    ("tpu", (16384, 4096), 32, "xla"),
+], ids=["the_cell_on_a_tpu", "the_cell_on_a_cpu",
+        "the_tiny_models_heads_of_16", "rows_with_no_batch"])
+def test_norm_path_serves_32_heads_of_128(monkeypatch, backend, shape, heads,
+                                          path):
+    """The output gate asks ``norm_path`` with a head a group, and notes
+    the answer for the trace."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert ssm.norm_path(shape, heads) == path
+
+
+@pytest.mark.parametrize("axes, batch, path", [
+    ({"dp": 4}, 4, "pallas"), ({"dp": 2, "tp": 2}, 4, "xla")],
+    ids=["dp", "dp_and_tp"])
+def test_output_gate_notes_the_path_a_mesh_gives_it(monkeypatch, axes, batch,
+                                                    path):
+    """``sigmoid_gated_head_rms_norm`` hands its mesh to ``norm_path``
+    and notes ``kda_gate_path``; nothing else chooses."""
+    from ray_tpu.util import tracing
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh, seen = _mesh(**axes), {}
+    monkeypatch.setattr(tracing, "note_trace", seen.update)
+    monkeypatch.setattr(gated_norm, "head_gate_norm",
+                        lambda o, *a, **kw: ("kernels", kw["batch_axes"]))
+    monkeypatch.setattr(ssm, "_sigmoid_gated_head_rms_norm_xla",
+                        lambda *a: ("xla", None))
+    o = jax.ShapeDtypeStruct((batch, 256, 4096), jnp.bfloat16)
+    ran, batch_axes = ssm.sigmoid_gated_head_rms_norm(
+        o, o, None, 32, 1e-5, mesh=mesh)
+    assert seen == {"kda_gate_path": path}
+    assert (ran, batch_axes) == (("kernels", ("dp",)) if path == "pallas"
+                                 else ("xla", None))
+
+
+def test_kernel_output_gate_over_a_batch_sharded_mesh_is_the_one_device_gate():
+    """As the other form's: each device gates its own rows, and the
+    shared ``scale``'s gradient is the sum of the two devices'."""
+    mesh = _mesh(dp=2)
+    args = _gate_inputs((2, 48, 256), 2, jnp.float32, seed=13)
+    want = _value_and_grads(_gate_kernel(2), *args)
+    got = jax.jit(lambda *a: _value_and_grads(
+        _gate_kernel(2, mesh=mesh, batch_axes=("dp",)), *a))(*args)
+    assert got[0].sharding.spec[0] in ("dp", ("dp",))
+    for name, g, w in zip("out do dgate dscale".split(), got, want):
+        np.testing.assert_allclose(
+            g, w, rtol=1e-6, atol=1e-6 * float(jnp.abs(w).max()),
+            err_msg=name)
+
+
+def test_heads_the_output_gates_kernels_do_not_tile_are_refused_by_name():
+    with pytest.raises(ValueError, match="do not tile"):
+        _gate_kernel(2)(*_gate_inputs((2, 16, 32), 2, jnp.float32)[:3])
 
 
 # ---------------------------------------------------------------------------

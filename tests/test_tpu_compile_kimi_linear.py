@@ -42,9 +42,13 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     as the convolutions left them (no operation under ``kda/qk_norm``:
     the scope is the XLA path's), the three convolutions a layer are the
     kernel pair of ``ops/pallas/causal_conv.py`` (under ``conv``: the
-    forward in both forward passes, the backward once; PR 55) and no
-    other custom call stands under ``kda``, and no ``[T, T]`` array
-    exists."""
+    forward in both forward passes, the backward once; PR 55), the
+    output gate is the second kernel pair of ``ops/pallas/gated_norm.py``
+    (under ``out_gate``, PR 58: the forward once a layer, in the step's
+    forward pass, because a recomputed block keeps the gated output and
+    ``_kda_core``'s recomputation needs ``o`` and ``gate`` of it, not
+    its result; the backward once) and no other custom call stands
+    under ``kda``, and no ``[T, T]`` array exists."""
     _, notes, lowered = real_size_step
     assert notes["attn_kind"] == "kda_mla"
     assert notes["attn_layers"] == "KKKMK" and notes["blocks_remat"] is True
@@ -53,6 +57,7 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["kda_heads"] == 32 and notes["kda_state"] == [128, 128]
     assert notes["conv_path"] == "pallas"
     assert (notes["conv_taps"], notes["conv_cols"]) == (4, 4096)
+    assert notes["kda_gate_path"] == "pallas"
     assert notes["flash_path"] == "mla_multi_block"
     assert notes["flash_bwd_resident_rows"] == 16384
     assert notes["mla_positions"] == "none"
@@ -78,12 +83,25 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     # pass and in ``_kda_core``'s recomputation, the backward once
     assert kinds.count("_conv_fwd") == 2 * 12
     assert kinds.count("_conv_bwd") == 12
+    # and their output gates, each pass once
+    assert kinds.count("_head_gate_fwd") == 4
+    assert kinds.count("_head_gate_bwd") == 4
+    assert "_norm_fwd" not in kinds and "_norm_bwd" not in kinds
     under_kda = [(kind, line) for kind, line in zip(kinds, calls)
                  if "/kda/" in line]
-    assert len(under_kda) == 12 + 36
+    assert len(under_kda) == 12 + 36 + 8
     # the checkpoints' own names stand between the module and its scope
     scope_of = {"_kda_fwd": "scan", "_kda_bwd": "scan",
-                "_conv_fwd": "conv", "_conv_bwd": "conv"}
+                "_conv_fwd": "conv", "_conv_bwd": "conv",
+                "_head_gate_fwd": "out_gate", "_head_gate_bwd": "out_gate"}
+    for kind, line in under_kda:
+        if scope_of[kind] == "out_gate":
+            # the recurrence's float32 ``o`` and its cotangent, and
+            # nothing else of that width, at a kernel's edge
+            rows = re.findall(r"(\w+)\[1,16384,4096\]", line)
+            assert rows == (["f32", "bf16", "bf16"]
+                            if kind == "_head_gate_fwd" else
+                            ["f32", "bf16", "bf16", "f32", "bf16"]), line
     assert all(re.search(
         r"/kda/(checkpoint/|rematted_computation/)*%s/jit" % scope_of[kind],
         line) for kind, line in under_kda)
@@ -104,12 +122,13 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
 def test_the_real_size_step_compiles_inside_the_chips_memory(real_size_step):
     """Arguments + temporaries + unaliased outputs stay under the 14.5 GB
     that leave room for the device's own reserve (12.13 GB at PR 47,
-    12.92 before the norm of q and k moved into the kernels), and the
-    convolutions' kernels (PR 55) take no more memory than the XLA
-    fusions' 12.05 GB."""
+    12.92 before the norm of q and k moved into the kernels), the
+    convolutions' kernels (PR 55) took no more memory than the XLA
+    fusions' 12.05 GB, and the output gate's (PR 58) less than the
+    11.96 GB of its XLA function: 11.53."""
     cfg, _, lowered = real_size_step
     m, total = program_bytes(lowered.compile())
     assert m.argument_size_in_bytes == pytest.approx(
         cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
     assert 4e9 < total <= 14.5e9
-    assert total <= 12.05e9 + 0.05e9    # PR 54's program; 11.96 GB at PR 55
+    assert total <= 11.53e9 + 0.05e9    # PR 58's program; 11.96 GB at PR 55
