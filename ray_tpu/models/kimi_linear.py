@@ -36,20 +36,34 @@ of its input (``W_q h``, ``W_k h``, ``W_v h`` and the narrow ``W_f1 h``,
 recurrence and the gate's product ``W_g2 (W_g1 h) + b_g`` run again
 (``_kda_core`` is a ``jax.checkpoint``), as ``latent_attention`` keeps
 its latents. With ``remat`` the blocks are recomputed too
-(``models/llama.py``'s switch), keeping of each KDA mixer its gated
-output, so that the recurrence runs forward twice a step on the kernels
-(the pass, ``_kda_core``'s recomputation; a third time, a group's
-inside ``kda_scan``, on the XLA path) and not once more, and of the
-latent layer its core's output and row statistics
+(``models/llama.py``'s switch), and a block keeps of each KDA mixer
+three named arrays (``_KDA_KEEPS``): its gated output, and the
+recurrence's forward kernel's two results, ``o`` and the state entering
+every chunk (``ops/kda.py::SCAN_OUT``, ``SCAN_STATES``; float32, 268 +
+537 MB a layer at 16,384 rows of 32 heads), which are all that the rest
+of the backward pass reads of that kernel. The block's policy reaches
+through ``_kda_core``'s checkpoint (a policy is handed down into a
+``remat`` equation inside the one it is applied to): the kept arrays
+enter that checkpoint's recomputation as its inputs, the kernel there
+has no reader left and is dead code, and **the recurrence runs forward
+once a layer a step** on the kernels (twice before PR 59: the pass, and
+``_kda_core``'s recomputation in the block's second pass, which were
+one surplus run and not two) while the convolutions, the decay and the
+gate's product still run twice. The names are the kernels' forward
+rule's: the XLA path (``xla_chunked``) has none and keeps its own group
+states, so there the recurrence runs as before (the pass, ``_kda_core``'s
+recomputation, and a group's inside ``kda_scan``). Of the latent layer
+the block keeps its core's output and row statistics
 (``ops/attention.py::remat_policy``), so that the latent flash forward
-kernel runs once (the note ``blocks_remat_keeps``). **The output gate**
+kernel runs once (the note ``blocks_remat_keeps`` lists all five
+names). **The output gate**
 (``ops/ssm.py::sigmoid_gated_head_rms_norm``, handed the mixer's mesh;
 the note ``kda_gate_path``) on its kernels (``pallas``: a TPU, heads of
 whole 128-lane tiles, one device or a mesh that shards the batch alone)
 runs forward once a layer a step and backward once: the gated output is
-what the block keeps, and ``_kda_core``'s recomputation makes the
-gate's operands (the recurrence's ``o`` and ``gate``) for the backward
-kernel, not its result. As the XLA function (``xla``: everywhere else)
+what the block keeps, and ``_kda_core``'s recomputation hands the
+backward kernel its operands (the kept ``o``, and ``gate`` made again),
+not its result. As the XLA function (``xla``: everywhere else)
 it runs forward twice, the pass and its own ``jax.checkpoint``'s
 recomputation inside the backward.
 
@@ -95,6 +109,8 @@ from ray_tpu.util import tracing
 
 
 _KDA_OUT = "kda_gated_out"
+# what a recomputed block keeps of a KDA mixer (the module docstring)
+_KDA_KEEPS = (_KDA_OUT, kda.SCAN_OUT, kda.SCAN_STATES)
 
 
 @dataclass(frozen=True)
@@ -353,19 +369,19 @@ class KimiLinear(nn.Module):
             mla_ranks=[None, cfg.kv_rank],
             mla_qk_dims=[cfg.nope_dim, cfg.rope_dim], mla_v_dim=cfg.v_dim,
             dense_layers=cfg.dense_layers, blocks_remat=cfg.remat,
-            blocks_remat_keeps=",".join(remat_keeps(_KDA_OUT))
+            blocks_remat_keeps=",".join(remat_keeps(*_KDA_KEEPS))
             if cfg.remat else "")
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
         with jax.named_scope("embed"):
             x = self._constrain(wte(tokens))
-        # a recomputed block keeps its KDA mixer's gated output (134 MB
-        # a layer at 16,384 rows), so that its recomputation does not
-        # walk the recurrence once more than ``_kda_core``'s own does,
-        # and its latent layer's output and row statistics (0.14 GB), so
-        # that the latent flash forward kernel runs once
-        block = (nn.remat(Block, policy=remat_policy(_KDA_OUT))
+        # a recomputed block keeps of its KDA mixer the gated output
+        # (134 MB a layer at 16,384 rows) and the recurrence's ``o`` and
+        # chunk-entering states (268 + 537 MB), and of its latent layer
+        # the core's output and row statistics (0.14 GB): neither
+        # forward kernel runs again (the module docstring has how)
+        block = (nn.remat(Block, policy=remat_policy(*_KDA_KEEPS))
                  if cfg.remat else Block)
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):

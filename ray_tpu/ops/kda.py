@@ -59,7 +59,9 @@ states among them, when it reaches it.
 ``pallas_chunked``: ``ops/pallas/kda_scan.py``'s kernel pair, the same
 five equations with a chunk's arrays and the state in VMEM (no groups:
 the backward keeps the state entering every chunk and recomputes a
-chunk's squares from it; given a mixer's un-normalised ``q`` and ``k``
+chunk's squares from it, and the forward rule names ``o`` and those
+states, ``SCAN_OUT`` and ``SCAN_STATES``, for the policy of a recomputed
+block that would keep them; given a mixer's un-normalised ``q`` and ``k``
 it also makes their unit rows there, where ``xla_chunked`` makes float32
 arrays of them first: ``kda_scan``'s ``normalize_qk``), on a TPU
 backend where keys and values are one 128-lane tile a head, the chunk
@@ -85,6 +87,10 @@ from ray_tpu.ops import ssm
 from ray_tpu.ops.pallas import kda_scan as kernels
 from ray_tpu.util import tracing
 
+# the names of the kernels' forward results, for a recomputed block's
+# policy (``models/kimi_linear.py``), as ``ops/attention.py`` has its
+# cores' ``ATTN_OUT`` and ``ATTN_LSE``
+SCAN_OUT, SCAN_STATES = kernels.SCAN_OUT, kernels.SCAN_STATES
 SUB = 16            # rows of a sub-block of a chunk (``_scores``, ``_solve``)
 GROUP_ROWS = 512    # rows of one recomputed group of chunks
 # float32 operands as three bfloat16 passes (``HIGH``): 2^-16 a product

@@ -9,9 +9,15 @@ keys and queries, ``A``, ``B``, ``T = (I + Diag(beta) A)^-1``, ``W``,
 state is carried from chunk to chunk in VMEM scratch along a sequential
 grid axis, as ``ssd_scan.py`` carries Mamba's. HBM sees ``q, k, v, g,
 beta`` in and ``o`` out, and, under differentiation, the state that
-entered each chunk (float32 ``[T / 64, H, V, K]``, alive only inside one
-mixer's backward), from which the backward kernel recomputes a chunk's
-squares, again in VMEM.
+entered each chunk (float32 ``[T / 64, H, V, K]``), from which the
+backward kernel recomputes a chunk's squares, again in VMEM. The
+forward rule names its two results (``SCAN_OUT``, ``SCAN_STATES``). A
+caller under a plain ``jax.checkpoint`` makes both again in its
+backward pass, and the states are alive only inside that one mixer's
+backward; a recomputed block whose policy keeps the two names
+(``models/kimi_linear.py``) holds them from its first pass to its
+backward, 0.8 GB a layer at 16,384 rows of 32 heads, and the forward
+kernel runs once a step.
 
 **``q`` and ``k`` come as the mixer's convolutions left them**
 (``normalize_qk``, which ``ops/kda.py`` sets for the model): the
@@ -108,6 +114,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 CHUNK = 64          # the recurrence's chunk; the configuration's
@@ -118,6 +125,13 @@ _PACKED = 8         # levels from here up give the MXU only their lower rows
 _F32 = jnp.float32
 NORM_EPS = 1e-6                 # under the root of a row's unit length
 _Q_SCALE = _WIDTH ** -0.5       # the unit queries' scale: head_dim^-1/2
+# The names of the forward kernel's two results under differentiation,
+# ``o`` and the state entering each chunk: what the rest of the backward
+# pass reads of it. A recomputed block whose policy keeps both does not
+# run the kernel again (``models/kimi_linear.py``); outside a policy a
+# name is the identity.
+SCAN_OUT = "kda_scan_out"
+SCAN_STATES = "kda_scan_states"
 
 
 def shapes_ok(kd: int, vd: int, chunk: int) -> bool:
@@ -568,6 +582,11 @@ def _kda_core(q, k, g, v, beta, normalize: bool, interpret: bool):
 def _kda_core_fwd(q, k, g, v, beta, normalize, interpret):
     o, entering = _kda_fwd(q, k, g, v, beta, keep_states=True,
                            normalize=normalize, interpret=interpret)
+    # both named before they part into primal and residuals (the trap
+    # ``ops/attention.py::name_core_results`` records): a name on ``o``
+    # alone would leave ``entering`` to be made again, the kernel with it
+    o, entering = checkpoint_name(o, SCAN_OUT), checkpoint_name(
+        entering, SCAN_STATES)
     return o, (q, k, g, v, beta, entering)
 
 

@@ -10,9 +10,15 @@ mixer's convolutions leave, differentiated down to those rows:
 ``rows:pallas_fused``, the norm in the kernels' cells (``normalize_qk``).
 Their distances are from the first ``rows:`` variant's numbers.
 
+The variant ``fwd_states`` is the forward kernel alone on those rows
+(``_kda_fwd``, the norm in its cells), with and without the states
+entering every chunk among its results: what a step pays for the
+forward that differentiation runs (``keep_states``) over the one it
+would run for ``o`` alone.
+
     python3 scripts/kda_timing.py [--rows 16384] [--variants a,b,...]
     python3 scripts/kda_timing.py --variants \
-        rows:g512_highest,rows:pallas,rows:pallas_fused
+        rows:g512_highest,rows:pallas,rows:pallas_fused,fwd_states
 """
 
 from __future__ import annotations
@@ -57,7 +63,7 @@ def main() -> None:
         "pallas_fused": None,       # ``rows:`` only: the norm in the kernels
     }
     names = [v for v in args.variants.split(",") if v] or [
-        v for v in variants if v != "pallas_fused"]
+        v for v in variants if v != "pallas_fused"] + ["fwd_states"]
     t, h, k = args.rows, args.heads, 128
     rng = np.random.default_rng(0)
     # q and k as the convolutions leave them, and their unit rows
@@ -71,8 +77,32 @@ def main() -> None:
     beta = jnp.asarray(1 / (1 + np.exp(-rng.normal(size=(1, t, h)))),
                        jnp.float32)
     w = jnp.asarray(rng.normal(size=(1, t, h, k)), jnp.float32)
+    def best_ms(fn, *operands):
+        best = 1e9
+        for _ in range(args.reps):
+            t0 = time.monotonic()
+            jax.block_until_ready(fn(*operands))
+            best = min(best, time.monotonic() - t0)
+        return best * 1e3
+
     bases = {}
     for name in names:
+        if name == "fwd_states":
+            # the kernels' own layout: a head a 128-lane block of a row
+            flat = [z.reshape(1, t, h * k) for z in (*rows, g, v)]
+            steps = jnp.swapaxes(beta, 1, 2).reshape(1, h, t // 128, 1, 128)
+            ms = {}
+            for keep in (False, True):
+                fn = functools.partial(
+                    kernels._kda_fwd, keep_states=keep, normalize=True,
+                    interpret=args.interpret)
+                jax.block_until_ready(fn(*flat, steps))
+                ms[keep] = best_ms(fn, *flat, steps)
+            print(json.dumps({
+                "variant": name, "device": jax.devices()[0].device_kind,
+                "forward_ms": ms[False], "forward_keep_states_ms": ms[True]}),
+                flush=True)
+            continue
         from_rows, _, variant = name.rpartition(":")
         fused = variant == "pallas_fused"
         if fused and not from_rows:
@@ -103,12 +133,7 @@ def main() -> None:
         compile_s = time.monotonic() - t0
 
         def timed(fn):
-            best = 1e9
-            for _ in range(args.reps):
-                t0 = time.monotonic()
-                jax.block_until_ready(fn(q, kk, v, g, beta))
-                best = min(best, time.monotonic() - t0)
-            return best * 1e3
+            return best_ms(fn, q, kk, v, g, beta)
         nums = {"rms": float(rms), **{
             f"d{n}": float(jnp.sqrt(jnp.sum(jnp.square(
                 x.astype(jnp.float32)))))

@@ -197,6 +197,28 @@ def kernel_kinds(lines) -> list[str]:
             for line in lines]
 
 
+def equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations(sub)
+
+
+def live_kernel_calls(traced) -> list[int]:
+    """The ``pallas_call``s left in a traced function (``make_jaxpr``'s)
+    once what nothing reads is taken out, as lowering takes it out, each
+    by its number of results, sorted: where no chip's compiler is asked,
+    how often a recomputing program runs a kernel."""
+    from jax.interpreters import partial_eval as pe
+    live, _ = pe.dce_jaxpr(traced.jaxpr, [True] * len(traced.jaxpr.outvars))
+    return sorted(len(e.outvars) for e in equations(live)
+                  if e.primitive.name == "pallas_call")
+
+
 def program_bytes(compiled):
     """-> (``memory_analysis()``, arguments + temporaries + unaliased
     outputs): what the program asks of the chip's memory."""

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import equations, live_kernel_calls
 
 from ray_tpu.ops import kda
 
@@ -289,16 +290,6 @@ def test_bfloat16_q_and_k_come_back_as_bfloat16_cotangents():
         close(z.astype(jnp.float32), ref.astype(jnp.float32), rtol=1e-2)
 
 
-def _equations(jaxpr):
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for value in eqn.params.values():
-            for sub in value if isinstance(value, (list, tuple)) else [value]:
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    yield from _equations(sub)
-
-
 def _behind(eqn, made_by):
     """The equations behind ``eqn``, nearest first: each one's first
     operand that an equation made, back to a load."""
@@ -325,11 +316,11 @@ def test_every_product_and_exp_in_both_kernels_is_float32(normalize):
     traced = jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(kernels.kda_scan(*a, normalize_qk=normalize)),
         argnums=(0, 1, 2, 3, 4)))(*args)
-    calls = [e for e in _equations(traced.jaxpr)
+    calls = [e for e in equations(traced.jaxpr)
              if e.primitive.name == "pallas_call"]
     assert len(calls) == 2                      # forward, backward
     for call in calls:
-        inside = list(_equations(call.params["jaxpr"]))
+        inside = list(equations(call.params["jaxpr"]))
         dots = [e for e in inside if e.primitive.name == "dot_general"]
         exps = [e for e in inside if e.primitive.name == "exp"]
         assert len(dots) >= 20 and len(exps) >= 8
@@ -402,3 +393,49 @@ def test_kda_path_takes_the_kernels_where_it_observes_they_fit(
             split = make_mesh({axis: 2}, devices=jax.devices()[:2])
             with pytest.raises(NotImplementedError, match=f"{axis}=2"):
                 kda.kda_path(shape, chunk, split)
+
+
+# --- what a recomputing caller keeps of the forward kernel -------------------
+
+def _recomputed(kept):
+    def loss(*a):
+        return jnp.sum(jnp.sin(kernels.kda_scan(*a, interpret=True)))
+    if kept is None:
+        return loss
+    return jax.checkpoint(
+        loss, policy=jax.checkpoint_policies.save_only_these_names(*kept))
+
+
+@pytest.mark.parametrize("kept, forwards", [
+    (None, 1), ((), 2), ((kda.SCAN_OUT,), 2), ((kda.SCAN_STATES,), 2),
+    ((kda.SCAN_OUT, kda.SCAN_STATES), 1)],
+    ids=["not_recomputed", "keeps_nothing", "keeps_o_alone",
+         "keeps_the_states_alone", "keeps_both"])
+def test_a_caller_that_keeps_both_named_results_runs_the_forward_once(
+        kept, forwards):
+    """The forward rule names ``o`` and the chunk-entering states before
+    they part into primal and residuals (``kda_scan_out``,
+    ``kda_scan_states``, exported by ``ops/kda.py``): a checkpoint whose
+    policy keeps both does not run the forward kernel in its backward
+    pass, one that keeps either alone still does (the other result has
+    to be made again, and the kernel with it)."""
+    assert (kda.SCAN_OUT, kda.SCAN_STATES) == (
+        "kda_scan_out", "kda_scan_states")
+    traced = jax.make_jaxpr(jax.value_and_grad(
+        _recomputed(kept), argnums=(0, 1, 2, 3, 4)))(
+            *operands(0, 1, 128, 1, 128, 128))
+    # the forward kernel has 2 results under differentiation (``o``, the
+    # states entering the chunks), the backward 5
+    assert live_kernel_calls(traced) == [2] * forwards + [5]
+
+
+def test_the_kept_results_give_the_gradients_of_the_recomputed_ones():
+    """Keeping ``o`` and the states changes what runs, not what comes
+    out: the same kernels on the same operands, to the bit."""
+    args = operands(3, 1, 150, 2, 128, 128)
+    want, got = (jax.jit(jax.value_and_grad(
+        _recomputed(kept), argnums=(0, 1, 2, 3, 4)))(*args)
+        for kept in ((), (kda.SCAN_OUT, kda.SCAN_STATES)))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(a, b)

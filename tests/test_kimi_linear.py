@@ -4,6 +4,7 @@ over the layers, latent attention without a query latent or a rotation,
 what the two KDA keys of the cell's comparison see of a planted fault,
 and the shares of the experts against the uncut layer."""
 
+import functools
 import os
 import sys
 
@@ -12,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import live_kernel_calls
 
 from ray_tpu import train
 from ray_tpu.models import KimiLinear, KimiLinearConfig
@@ -463,13 +465,15 @@ def test_a_mixer_hands_its_mesh_to_the_one_output_gate(monkeypatch, axes):
 
 
 @pytest.mark.parametrize("remat, keeps", [
-    (True, "kda_gated_out,attn_out,attn_lse"), (False, "")],
+    (True, "kda_gated_out,kda_scan_out,kda_scan_states,attn_out,attn_lse"),
+    (False, "")],
     ids=["recomputed", "kept_whole"])
 def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
                                                        monkeypatch):
     """``blocks_remat_keeps`` beside ``blocks_remat``: the names a
-    recomputed block's policy keeps (the KDA mixers' gated output
-    first, then the latent core's output and row statistics), and every
+    recomputed block's policy keeps (the KDA mixers' gated output and
+    their recurrence's two named results first, then the latent core's
+    output and row statistics), and every
     block's checkpoint carries a policy; nothing where the blocks are
     not recomputed."""
     cfg = KimiLinearConfig.tiny(remat=remat, **F32)
@@ -485,6 +489,81 @@ def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
     with_policy = [e for e in traced.jaxpr.eqns
                    if e.primitive.name == "remat2" and e.params["policy"]]
     assert len(with_policy) == (cfg.n_layer if remat else 0)
+
+
+def _at_the_kernels_widths(remat):
+    """Two KDA layers with dense MLPs at the kernels' widths: heads of
+    128, chunks of 64, rows of 128 tokens. -> (model, loss function)."""
+    model = KimiLinear(KimiLinearConfig.tiny(
+        n_layer=2, mla_layers=(), dense_layers=2, kda_heads=2,
+        kda_head_dim=128, kda_chunk=64, seq_len=128, remat=remat, **F32))
+    return model, kimi_linear_loss_fn(model, ce_chunk=32)
+
+
+@functools.cache
+def _kernel_case():
+    model, _ = _at_the_kernels_widths(False)
+    params = _jittered(model.init_params(jax.random.key(5)), 5)
+    return params, _batch(5, model.config, rows=1)
+
+
+@pytest.fixture
+def on_the_kernels(monkeypatch):
+    """``_at_the_kernels_widths`` with the recurrence on
+    ``ops/pallas/kda_scan.py``'s kernels, interpreted: ``kda_path`` is
+    told what a TPU would answer. -> (remat -> (model, loss function),
+    parameters, a batch of one row)."""
+    from ray_tpu.ops.pallas import kda_scan as kernels
+    monkeypatch.setattr(kda, "kda_path", lambda *a, **kw: "pallas_chunked")
+    monkeypatch.setattr(kernels, "kda_scan", functools.partial(
+        kernels.kda_scan, interpret=True))
+    return (_at_the_kernels_widths, *_kernel_case())
+
+
+def test_recomputed_blocks_on_the_kernels_give_the_kept_blocks_numbers(
+        on_the_kernels):
+    """The loss, the report and every gradient leaf with ``remat`` (the
+    blocks keep the recurrence's ``o`` and states and never run its
+    forward kernel again) against without (``_kda_core``'s checkpoint
+    runs it again): the same kernels on the same operands."""
+    made, params, batch = on_the_kernels
+    (want, want_report), want_grads = jax.jit(jax.value_and_grad(
+        made(False)[1], has_aux=True))(params, batch)
+    (loss, report), grads = jax.jit(jax.value_and_grad(
+        made(True)[1], has_aux=True))(params, batch)
+    assert float(loss) == pytest.approx(float(want), rel=1e-6)
+    assert float(report["kda_out_rms"]) == pytest.approx(
+        float(want_report["kda_out_rms"]), rel=1e-6)
+    want_leaves = dict(_leaves_with_names(want_grads))
+    assert len(want_leaves) == len(jax.tree_util.tree_leaves(grads))
+    for name, got in _leaves_with_names(grads):
+        scale = max(float(np.abs(want_leaves[name]).max()), 1e-3)
+        np.testing.assert_allclose(got, want_leaves[name],
+                                   atol=1e-5 * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("remat, keeps, forwards", [
+    (True, None, 1), (True, ("kda_gated_out",), 2), (False, None, 2)],
+    ids=["recomputed", "recomputed_without_the_scans_names", "kept_whole"])
+def test_the_recurrences_forward_kernel_runs_once_a_layer_under_remat(
+        on_the_kernels, monkeypatch, remat, keeps, forwards):
+    """In the gradient's jaxpr, with what nothing reads taken out as
+    lowering takes it out: a recomputed block runs the recurrence's
+    forward kernel once a KDA layer (2 results: ``o`` and the states)
+    and its backward once (5); a policy that loses the kernels' two
+    names (PR 58's, the gated output alone) runs the forward twice, and
+    so does ``remat=False``, where ``_kda_core``'s checkpoint keeps the
+    six projections and nothing of the recurrence."""
+    from ray_tpu.models import kimi_linear
+    if keeps:
+        monkeypatch.setattr(kimi_linear, "_KDA_KEEPS", keeps)
+    made, params, batch = on_the_kernels
+    model, loss_fn = made(remat)
+    traced = jax.make_jaxpr(jax.value_and_grad(loss_fn, has_aux=True))(
+        params, batch)
+    layers = model.config.layer_kinds.count("K")
+    assert layers == 2
+    assert live_kernel_calls(traced) == [2] * forwards * layers + [5] * layers
 
 
 def test_a_kda_layer_has_its_own_scopes_and_the_mla_layer_joyais():

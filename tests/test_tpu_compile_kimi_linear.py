@@ -37,22 +37,27 @@ def real_size_step(v5e):
 def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     """The MLA layer's attention is the latent kernel pair with dq's
     16,384 rows resident and no rotation, the KDA layers run the
-    recurrence's kernel pair (the forward twice a layer, the backward
-    once, all under ``scan`` under ``scan``), which take ``q`` and ``k``
-    as the convolutions left them (no operation under ``kda/qk_norm``:
-    the scope is the XLA path's), the three convolutions a layer are the
-    kernel pair of ``ops/pallas/causal_conv.py`` (under ``conv``: the
-    forward in both forward passes, the backward once; PR 55), the
-    output gate is the second kernel pair of ``ops/pallas/gated_norm.py``
-    (under ``out_gate``, PR 58: the forward once a layer, in the step's
-    forward pass, because a recomputed block keeps the gated output and
+    recurrence's kernel pair (each once a layer, all under ``scan``
+    under ``scan``: the forward in the step's forward pass alone, with
+    the states entering every chunk among its results, because a
+    recomputed block keeps ``o`` and those states by name and its
+    policy reaches through ``_kda_core``'s checkpoint; PR 59, twice a
+    layer before), which take ``q`` and ``k`` as the convolutions left
+    them (no operation under ``kda/qk_norm``: the scope is the XLA
+    path's), the three convolutions a layer are the kernel pair of
+    ``ops/pallas/causal_conv.py`` (under ``conv``: the forward in both
+    forward passes, the backward once; PR 55), the output gate is the
+    second kernel pair of ``ops/pallas/gated_norm.py`` (under
+    ``out_gate``, PR 58: the forward once a layer, in the step's forward
+    pass, because a recomputed block keeps the gated output and
     ``_kda_core``'s recomputation needs ``o`` and ``gate`` of it, not
     its result; the backward once) and no other custom call stands
     under ``kda``, and no ``[T, T]`` array exists."""
     _, notes, lowered = real_size_step
     assert notes["attn_kind"] == "kda_mla"
     assert notes["attn_layers"] == "KKKMK" and notes["blocks_remat"] is True
-    assert notes["blocks_remat_keeps"] == "kda_gated_out,attn_out,attn_lse"
+    assert notes["blocks_remat_keeps"] == (
+        "kda_gated_out,kda_scan_out,kda_scan_states,attn_out,attn_lse")
     assert notes["kda_path"] == "pallas_chunked" and notes["kda_chunk"] == 64
     assert notes["kda_heads"] == 32 and notes["kda_state"] == [128, 128]
     assert notes["conv_path"] == "pallas"
@@ -76,8 +81,9 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert kinds.count("mla_flash_bwd") == 1
     flash = [line for kind, line in zip(kinds, calls) if "mla_flash" in kind]
     assert all("/h_3/attn/core/" in line for line in flash)
-    # four KDA layers: each kernel lowered once, called a layer
-    assert kinds.count("_kda_fwd") == 2 * 4
+    # four KDA layers: each kernel lowered once, called a layer, the
+    # forward in the first pass alone (its results are kept by name)
+    assert kinds.count("_kda_fwd") == 4
     assert kinds.count("_kda_bwd") == 4
     # and their twelve convolutions: the forward in the step's forward
     # pass and in ``_kda_core``'s recomputation, the backward once
@@ -89,7 +95,7 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert "_norm_fwd" not in kinds and "_norm_bwd" not in kinds
     under_kda = [(kind, line) for kind, line in zip(kinds, calls)
                  if "/kda/" in line]
-    assert len(under_kda) == 12 + 36 + 8
+    assert len(under_kda) == 8 + 36 + 8
     # the checkpoints' own names stand between the module and its scope
     scope_of = {"_kda_fwd": "scan", "_kda_bwd": "scan",
                 "_conv_fwd": "conv", "_conv_bwd": "conv",
@@ -114,6 +120,12 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     for kind, line in under_kda:
         rows = re.findall(r"(\w+)\[1,16384,4096\]", line)
         assert rows.count("bf16") == (3 if kind == "_kda_fwd" else 6), line
+        # the state entering each of the 256 chunks, float32: the
+        # forward's second result (``keep_states``), the backward's
+        # sixth operand, and no forward call is without it
+        assert line.count("f32[1,256,32,128,128]") == 1, line
+        if kind == "_kda_fwd":
+            assert "rematted_computation" not in line, line
     assert "/attn/rope/" not in text and "/attn/q_down/" not in text
     assert "16384x16384" not in text
 
@@ -122,13 +134,16 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
 def test_the_real_size_step_compiles_inside_the_chips_memory(real_size_step):
     """Arguments + temporaries + unaliased outputs stay under the 14.5 GB
     that leave room for the device's own reserve (12.13 GB at PR 47,
-    12.92 before the norm of q and k moved into the kernels), the
-    convolutions' kernels (PR 55) took no more memory than the XLA
-    fusions' 12.05 GB, and the output gate's (PR 58) less than the
-    11.96 GB of its XLA function: 11.53."""
+    12.92 before the norm of q and k moved into the kernels; 12.05 with
+    the convolutions' XLA fusions, 11.96 with their kernels, PR 55;
+    11.53 with the output gate's, PR 58). PR 59's 13.06: the 1.53 GB
+    more are the recurrence's ``o`` and chunk-entering states (0.8 GB a
+    KDA layer) held from a block's first pass to its backward, and they
+    bought the forward kernel's second run a layer, 55 ms of a 684 ms
+    step."""
     cfg, _, lowered = real_size_step
     m, total = program_bytes(lowered.compile())
     assert m.argument_size_in_bytes == pytest.approx(
         cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
     assert 4e9 < total <= 14.5e9
-    assert total <= 11.53e9 + 0.05e9    # PR 58's program; 11.96 GB at PR 55
+    assert total <= 13.07e9 + 0.05e9    # PR 59's program; 11.53 GB at PR 58
