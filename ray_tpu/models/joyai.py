@@ -47,7 +47,11 @@ things this file had not expressed:
 ``remat`` recomputes each block in the backward pass but for its
 attention core's output and row statistics (``ops/attention.py::
 remat_policy``, as ``models/kimi_linear.py``); what a recomputed block
-keeps is its input state, ``hc_mult`` streams wide.
+keeps is its input state, ``hc_mult`` streams wide, and at ``hc_mult`` >
+1 each sub-layer's residual maps with the 25 floats a token their
+backward kernel reads (``ops/pallas/hc_maps.py::MAPS_KEEPS``), so the
+second pass runs ``pre`` and ``post`` from them and the maps' norm,
+product and forward kernel once a step.
 
 It is the benchmark's fourth language model
 (``joyai-llm-flash.b1-t8192`` runs the dense layer, four routed layers
@@ -398,6 +402,7 @@ class _ResidualMaps(nn.Module):
     which is what a comparison with a reference needs to see the maps.
     Sows the largest ``|rowsum(H_res) - 1|``."""
     config: JoyAIConfig
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, x):
@@ -412,12 +417,12 @@ class _ResidualMaps(nn.Module):
         with jax.named_scope("maps"):
             maps = hc.hc_maps(x, phi, b, alpha, n=n,
                               iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
-                              clamp=cfg.hc_res_clamp)
+                              clamp=cfg.hc_res_clamp, mesh=self.mesh)
             self.sow("stats", "res_row_err", hc.res_row_err(maps[2]))
         return maps
 
 
-def _around(cfg: JoyAIConfig, name: str, f, x):
+def _around(cfg: JoyAIConfig, name: str, f, x, mesh=None):
     """``x + f(x)``, or the sub-layer ``f`` under its residual maps
     (scope and parameters ``hc_<name>``, beside the sub-layer's own). A
     function and not a method of ``Block``: flax would put the method's
@@ -425,7 +430,7 @@ def _around(cfg: JoyAIConfig, name: str, f, x):
     if cfg.hc_mult == 1:
         return x + f(x)
     scope = f"hc_{name}"
-    h_pre, h_post, h_res = _ResidualMaps(cfg, name=scope)(x)
+    h_pre, h_post, h_res = _ResidualMaps(cfg, mesh, name=scope)(x)
     with jax.named_scope(scope), jax.named_scope("pre"):
         u = hc.hc_pre(x, h_pre)
     y = f(u)
@@ -448,17 +453,29 @@ class Block(nn.Module):
         cfg = self.config
         attn = LatentAttention(cfg, self.mesh, name="attn")
         attn_norm = _norm(cfg)(name="attn_norm")
-        x = _around(cfg, "attn", lambda u: attn(attn_norm(u), angles), x)
+        x = _around(cfg, "attn", lambda u: attn(attn_norm(u), angles), x,
+                    self.mesh)
         mlp = (MoE(cfg, self.mesh, name="mlp") if self.routed
                else _swiglu(cfg, cfg.dense_width, "mlp"))
         mlp_norm = _norm(cfg)(name="mlp_norm")
-        return _around(cfg, "mlp", lambda u: mlp(mlp_norm(u)), x)
+        return _around(cfg, "mlp", lambda u: mlp(mlp_norm(u)), x, self.mesh)
+
+
+def _block_keeps(cfg: JoyAIConfig) -> tuple[str, ...]:
+    """The names a recomputed block keeps beside its attention core's:
+    at ``hc_mult`` > 1 each sub-layer's residual maps and what their
+    backward kernel reads (41 floats a token a sub-layer; on the maps'
+    XLA path nothing carries the names)."""
+    return hc.MAPS_KEEPS if cfg.hc_mult > 1 else ()
 
 
 def _block(cfg: JoyAIConfig):
     """``Block``, recomputed in the backward pass under ``remat`` but
-    for its attention core's output and row statistics."""
-    return nn.remat(Block, policy=remat_policy()) if cfg.remat else Block
+    for its attention core's output and row statistics and
+    ``_block_keeps``."""
+    if not cfg.remat:
+        return Block
+    return nn.remat(Block, policy=remat_policy(*_block_keeps(cfg)))
 
 
 class MTP(nn.Module):
@@ -513,13 +530,19 @@ class JoyAI(nn.Module):
             mtp_depth=cfg.mtp_depth, mtp_weight=cfg.mtp_weight,
             dense_layers=cfg.dense_layers)
         if cfg.remat:
-            tracing.note_trace(blocks_remat=True,
-                               blocks_remat_keeps=",".join(remat_keeps()))
+            tracing.note_trace(
+                blocks_remat=True,
+                blocks_remat_keeps=",".join(remat_keeps(*_block_keeps(cfg))))
         if n > 1:
             hc.refuse_split_state(self.mesh)
+            maps_path = hc.hc_maps_path(
+                (*tokens.shape, n * cfg.n_embd), n, self.mesh)
             tracing.note_trace(
                 hc_mult=n, hc_sinkhorn_iters=cfg.hc_sinkhorn_iters,
-                hc_state_dtype=jnp.dtype(cfg.dtype).name)
+                hc_state_dtype=jnp.dtype(cfg.dtype).name,
+                hc_maps_path=maps_path,
+                hc_maps_block=(hc.MAPS_BLOCK_TOKENS if maps_path == "pallas"
+                               else 0))
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))

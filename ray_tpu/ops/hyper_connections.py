@@ -42,13 +42,25 @@ bfloat16 too; the state already is); the norm's factor, a float32
 reduction over the ``n d`` lanes, multiplies the product after it
 (``(x r) phi = r (x phi)``), so no float32 copy of the state is made.
 
-Everything here is plain ``jax.numpy``: XLA fuses the two sigmoids and
-the unrolled loop into a few passes over 24 floats a token, and ``pre``
-and ``post`` into one pass each over the state. What the path needs of
-the HBM whatever implements it is ``(6 n + 5) d`` elements a token a
-sub-layer, forward and backward (``benchmark/benchlib/flops_xing.py::
-hc_train_cost``); a fused kernel for the maps and the two mixes is not
-here.
+**Who runs what.** ``pre`` and ``post`` are plain ``jax.numpy`` that XLA
+fuses into one pass each over the state, forward, and are at their
+bytes. The maps have two paths, which ``hc_maps_path`` chooses between
+from what it can see (the backend, the shapes, the mesh; no switch):
+``pallas``, the kernel pair of ``ops/pallas/hc_maps.py`` (PR 60), where
+XLA keeps the norm's reduction and the one product and the chain from
+those 25 floats a token to the three maps, the 20 normalisations and
+their backward, is one kernel a pass in VMEM; and ``xla``, the function
+below as ``jax.numpy``, whose loop XLA runs as ~45 fusions forward and
+twice that backward, a few microseconds each (the CPU's path, init
+tracing's, odd shapes', and what the tests hold the kernels to). The
+kernels' forward rule names the maps and what its backward reads
+(``MAPS_KEEPS``), and a recomputed block that keeps those names
+(``models/joyai.py``) makes none of them twice. What the whole path
+needs of the HBM whatever implements it is ``(6 n + 5) d`` elements a
+token a sub-layer, forward and backward (``benchmark/benchlib/
+flops_xing.py::hc_train_cost``); a kernel that shares one read of the
+state between the maps and ``pre``, or ``post`` with the next
+sub-layer's maps, is not here (ROADMAP A12).
 
 **Meshes.** ``dp`` / ``fsdp`` shard the batch and need nothing. ``sp``
 and ``tp`` are refused by name (``refuse_split_state``): a state whose
@@ -61,6 +73,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops.pallas import hc_maps as kernels
+from ray_tpu.ops.pallas.hc_maps import MAPS_KEEPS  # noqa: F401
+from ray_tpu.ops.ssm import _kernel_batch_axes
 
 
 def refuse_split_state(mesh) -> None:
@@ -122,11 +138,41 @@ def sinkhorn(a, iters: int, eps: float):
     return m
 
 
+# The tokens of a grid cell of the kernels.
+MAPS_BLOCK_TOKENS = kernels.BLOCK_ROWS * 128
+
+
+def hc_maps_path(shape, n: int, mesh=None) -> str:
+    """Which ``hc_maps`` compiles for a state ``shape`` [B, T, n d]:
+    ``pallas`` (the kernels of ``ops/pallas/hc_maps.py``) on a TPU where
+    ``T`` is whole 128-lane tiles and ``ops/ssm.py::
+    _kernel_batch_axes`` finds the program one the kernels can serve,
+    else ``xla``."""
+    if (jax.default_backend() == "tpu" and len(shape) == 3
+            and kernels.shapes_ok(shape[1])
+            and _kernel_batch_axes(mesh, shape[0]) is not None):
+        return "pallas"
+    return "xla"
+
+
 def hc_maps(x, phi, b, alpha, *, n: int, iters: int, eps: float,
-            clamp: float, norm_eps: float = 1e-6):
+            clamp: float, norm_eps: float = 1e-6, mesh=None):
     """``(H_pre [n, B, T], H_post [n, B, T], H_res [n, n, B, T])`` in
     float32 from the state ``x`` [B, T, n d], ``phi`` [n d, n^2 + 2n],
-    ``b`` [n^2 + 2n] and ``alpha`` [3] (module docstring)."""
+    ``b`` [n^2 + 2n] and ``alpha`` [3] (module docstring). ``mesh`` is
+    the mesh the program is sharded over, if the caller knows one:
+    ``hc_maps_path`` decides from it between the kernels and the
+    ``jax.numpy`` function below."""
+    if hc_maps_path(x.shape, n, mesh) == "pallas":
+        return kernels.hc_maps(
+            x, phi, b, alpha, n=n, iters=iters, eps=eps, clamp=clamp,
+            norm_eps=norm_eps, mesh=mesh,
+            batch_axes=_kernel_batch_axes(mesh, x.shape[0]))
+    return _hc_maps_xla(x, phi, b, alpha, n=n, iters=iters, eps=eps,
+                        clamp=clamp, norm_eps=norm_eps)
+
+
+def _hc_maps_xla(x, phi, b, alpha, *, n, iters, eps, clamp, norm_eps):
     f32 = jnp.float32
     r = jax.lax.rsqrt(jnp.mean(jnp.square(x.astype(f32)), -1) + norm_eps)
     # [B, T, c] from the matmul, then the 24 floats a token turned so

@@ -111,7 +111,7 @@ def main() -> None:
 def _timed(name: str, run, reps: int) -> dict:
     """``run()``'s time a call: the host's clock at its best of
     ``reps``, and the device's operations in a trace of ``reps`` calls
-    (their sum, and the longest by name)."""
+    (their sum, their number, and the longest by name)."""
     import jax
     wall = 1e9
     for _ in range(reps):
@@ -134,6 +134,7 @@ def _timed(name: str, run, reps: int) -> dict:
                 for e in line.events:
                     ms[e.name.split(" = ")[0]] += e.duration_ns / 1e6 / reps
     return {f"{name}_ms": sum(ms.values()), f"{name}_wall_ms": wall * 1e3,
+            f"{name}_n_ops": len(ms),
             f"{name}_ops": {k: round(v, 4) for k, v in ms.most_common(6)}}
 
 

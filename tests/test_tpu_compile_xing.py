@@ -21,7 +21,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
 from conftest import (  # noqa: E402
-    kernel_calls, lower_real_size_step, on_device, program_bytes)
+    kernel_calls, kernel_kinds, lower_real_size_step, on_device,
+    program_bytes)
 
 T = 4096        # the cell's row: rope_scaling's original length
 
@@ -144,18 +145,40 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert len(head) == 1 and "/loss/" in head[0]
 
 
+def test_the_real_size_steps_residual_maps_are_one_kernel_pair_a_sublayer(
+        real_size_step):
+    """Under every ``h_i/hc_attn/maps`` and ``h_i/hc_mlp/maps`` one
+    forward call (the recomputed block keeps its five results and makes
+    none again) and one backward call, over ``[24, 32, 128]``: the
+    4,096 tokens in the lanes, whole vector registers."""
+    cfg, _, lowered = real_size_step
+    maps = [line for line in kernel_calls(lowered)
+            if re.search(r"/h_\d/hc_(attn|mlp)/maps/", line)]
+    assert sorted(kernel_kinds(maps)) == (
+        ["_hc_maps_bwd"] * 10 + ["_hc_maps_fwd"] * 10)
+    for layer in range(cfg.n_layer):
+        for sub in ("attn", "mlp"):
+            here = [line for line in maps
+                    if f"/h_{layer}/hc_{sub}/maps/" in line]
+            assert sorted(kernel_kinds(here)) == [
+                "_hc_maps_bwd", "_hc_maps_fwd"], (layer, sub)
+    assert all(f"f32[24,{T // 128},128]" in line for line in maps)
+
+
 def test_the_real_size_step_says_what_it_ran(real_size_step):
     cfg, notes, _ = real_size_step
     assert {k: notes[k] for k in (
         "flash_path", "flash_layout", "mla_saved", "flash_bwd_resident_rows",
         "rope_kind", "hc_mult", "hc_sinkhorn_iters", "hc_state_dtype",
-        "blocks_remat", "blocks_remat_keeps", "moe_path",
-        "moe_experts_held")} == {
+        "hc_maps_path", "hc_maps_block", "blocks_remat",
+        "blocks_remat_keeps", "moe_path", "moe_experts_held")} == {
         "flash_path": "mla_multi_block", "flash_layout": "bthd",
         "mla_saved": "latents", "flash_bwd_resident_rows": T,
         "rope_kind": "yarn", "hc_mult": 4, "hc_sinkhorn_iters": 20,
-        "hc_state_dtype": "bfloat16", "blocks_remat": True,
-        "blocks_remat_keeps": "attn_out,attn_lse",
+        "hc_state_dtype": "bfloat16", "hc_maps_path": "pallas",
+        "hc_maps_block": 1024, "blocks_remat": True,
+        "blocks_remat_keeps": "hc_maps_pre,hc_maps_post,hc_maps_res,"
+                              "hc_maps_m,hc_maps_r,attn_out,attn_lse",
         "moe_path": "megablox_gmm", "moe_experts_held": [0, 8]}
     assert notes["mla_scale"] == pytest.approx(cfg.mla_scale)
 
