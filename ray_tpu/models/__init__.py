@@ -39,6 +39,11 @@ half of a full layer's lanes and plain RoPE on a sliding layer's, two
 position tables in one stack; a sigmoid gate a head on the core's
 output; JoyAI's routed layer behind a leading dense one); its config
 expresses Laguna-XS.2.
+``Ouro`` is the looped decoder (one stack of four-norm blocks run
+``ut_steps`` times on one parameter tree under ``nn.scan``, the final
+norm, an exit gate and the untied head after every pass, a loss weighted
+row by row by the learned exit distribution through
+``gpt2.chunked_cross_entropy_rows``); its config expresses Ouro-2.6B.
 ``MoETransformer`` is the older top-1, capacity-dropping switch model
 on GPT-2 blocks, which goes when the dropless path runs under ``ep``
 (ROADMAP C5)."""
@@ -50,6 +55,7 @@ from ray_tpu.models.laguna import Laguna, LagunaConfig
 from ray_tpu.models.llama import Llama, LlamaConfig
 from ray_tpu.models.moe import MoEConfig, MoETransformer
 from ray_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+from ray_tpu.models.ouro import Ouro, OuroConfig
 from ray_tpu.models.phi4flash import Phi4Flash, Phi4FlashConfig
 from ray_tpu.models.resnet import ResNet, ResNet50Config
 from ray_tpu.models.smallthinker import SmallThinker, SmallThinkerConfig
@@ -59,8 +65,8 @@ from ray_tpu.models.zaya import Zaya, ZayaConfig
 __all__ = [
     "GPT2", "GPT2Config", "JoyAI", "JoyAIConfig", "KimiLinear",
     "KimiLinearConfig", "Laguna", "LagunaConfig", "Llama", "LlamaConfig",
-    "MoETransformer", "MoEConfig", "NemotronH", "NemotronHConfig",
-    "Phi4Flash", "Phi4FlashConfig",
+    "MoETransformer", "MoEConfig", "NemotronH", "NemotronHConfig", "Ouro",
+    "OuroConfig", "Phi4Flash", "Phi4FlashConfig",
     "ResNet", "ResNet50Config", "SmallThinker", "SmallThinkerConfig", "ViT",
     "ViTConfig", "Zaya", "ZayaConfig",
 ]

@@ -256,13 +256,14 @@ def cross_entropy_loss(logits, targets, ignore_index: int = -1):
 
 # The ``loss`` scope is opened inside each half of the custom_vjp:
 # a scope around the call alone does not reach the backward ``while``.
-# It returns the sums, not their quotient, so that a caller that holds
-# only part of the rows can add its sums to the others' first.
+# It returns each row's loss (a masked row's is 0), their sum and the
+# count of unmasked rows, not a mean: a caller that holds only part of
+# the rows adds its sums to the others' first, and one that weights the
+# rows (``models/ouro.py``) hands the backward a cotangent a row.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 @jax.named_scope("loss")
 def _chunked_ce_core(rows_c, emb, tgt_c, ignore_index, path):
-    sums, _ = _chunked_ce_fwd(rows_c, emb, tgt_c, ignore_index, path)
-    return sums
+    return _chunked_ce_rows(rows_c, emb, tgt_c, ignore_index, path)[:2]
 
 
 def _chunk_logits(x_c, emb):
@@ -270,7 +271,7 @@ def _chunk_logits(x_c, emb):
                       preferred_element_type=jnp.float32)
 
 
-def _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index):
+def _chunked_ce_rows_scan(rows_c, emb, tgt_c, ignore_index):
     def one(carry, xt):
         x_c, t_c = xt
         logits = _chunk_logits(x_c, emb)
@@ -280,21 +281,27 @@ def _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index):
         picked = jnp.take_along_axis(logits, safe[:, None], 1)[:, 0]
         nll = jnp.where(mask, lse - picked, 0.0)
         tot, cnt = carry
-        return (tot + nll.sum(), cnt + mask.sum()), lse
+        return (tot + nll.sum(), cnt + mask.sum()), (nll, lse)
 
-    return jax.lax.scan(
+    sums, (nll, lse) = jax.lax.scan(
         one, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
         (rows_c, tgt_c))
+    return nll, sums, lse
 
 
-def _chunked_ce_fwd(rows_c, emb, tgt_c, ignore_index, path):
-    """((sum of the rows' losses, count of unmasked rows), lse [n,
-    chunk]) by ``path`` (``ce_path``): the scan above, or the kernel of
-    ``ops/pallas/ce_lse.py`` over all the rows at once, a row block its
-    own chunk, which writes no logit to HBM. The backward pass takes
-    either's ``lse``."""
+def _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index):
+    _, sums, lse = _chunked_ce_rows_scan(rows_c, emb, tgt_c, ignore_index)
+    return sums, lse
+
+
+def _chunked_ce_rows(rows_c, emb, tgt_c, ignore_index, path):
+    """(each row's loss [n, chunk], a masked row's 0; (their sum, the
+    count of unmasked rows); lse [n, chunk]) by ``path`` (``ce_path``):
+    the scan above, or the kernel of ``ops/pallas/ce_lse.py`` over all
+    the rows at once, a row block its own chunk, which writes no logit
+    to HBM. The backward pass takes either's ``lse``."""
     if path == "xla_scan":
-        return _chunked_ce_fwd_scan(rows_c, emb, tgt_c, ignore_index)
+        return _chunked_ce_rows_scan(rows_c, emb, tgt_c, ignore_index)
     from ray_tpu.ops.pallas import ce_lse
     n, chunk, e = rows_c.shape
     tgt = tgt_c.reshape(-1)
@@ -302,27 +309,37 @@ def _chunked_ce_fwd(rows_c, emb, tgt_c, ignore_index, path):
     lse, picked = ce_lse.ce_lse_fwd(
         rows_c.reshape(-1, e), emb, jnp.where(mask, tgt, 0))
     nll = jnp.where(mask, lse - picked, 0.0)
-    return (nll.sum(), mask.sum()), lse.reshape(n, chunk)
+    return (nll.reshape(n, chunk), (nll.sum(), mask.sum()),
+            lse.reshape(n, chunk))
+
+
+def _chunked_ce_fwd(rows_c, emb, tgt_c, ignore_index, path):
+    """((sum of the rows' losses, count of unmasked rows), lse [n,
+    chunk]) of ``_chunked_ce_rows``."""
+    return _chunked_ce_rows(rows_c, emb, tgt_c, ignore_index, path)[1:]
 
 
 @jax.named_scope("loss")
 def _chunked_ce_core_fwd(rows_c, emb, tgt_c, ignore_index, path):
-    sums, lse_c = _chunked_ce_fwd(rows_c, emb, tgt_c, ignore_index, path)
-    return sums, (rows_c, emb, tgt_c, lse_c)
+    nll, sums, lse_c = _chunked_ce_rows(rows_c, emb, tgt_c, ignore_index,
+                                        path)
+    return (nll, sums), (rows_c, emb, tgt_c, lse_c)
 
 
 @jax.named_scope("loss")
 def _chunked_ce_core_bwd(ignore_index, path, res, g):
     # Hand-written backward: recompute each chunk's logits but REUSE
     # the saved log-sum-exp (a jax.checkpoint formulation re-runs the
-    # full logsumexp reduction too). dlogits = (softmax - onehot) *
-    # d tot, and d tot is 1/cnt of the caller's mean. The same scan
-    # whichever ``path`` made the log-sum-exp.
+    # full logsumexp reduction too). dlogits = (softmax - onehot) times
+    # the row's cotangent: d tot (1/cnt of the caller's mean) on every
+    # row, plus the row's own where the caller weighted the rows. The
+    # same scan whichever ``path`` made the log-sum-exp.
     rows_c, emb, tgt_c, lse_c = res
-    scale, _ = g
+    d_rows, (d_tot, _) = g
+    scale_c = d_rows + d_tot
 
     def one(demb, xt):
-        x_c, t_c, lse = xt
+        x_c, t_c, lse, scale = xt
         logits = _chunk_logits(x_c, emb)
         mask = (t_c != ignore_index)
         p = jnp.exp(logits - lse[:, None])
@@ -340,7 +357,8 @@ def _chunked_ce_core_bwd(ignore_index, path, res, g):
         return demb, dx
 
     demb0 = jnp.zeros(emb.shape, jnp.float32)
-    demb, dx_c = jax.lax.scan(one, demb0, (rows_c, tgt_c, lse_c))
+    demb, dx_c = jax.lax.scan(one, demb0,
+                              (rows_c, tgt_c, lse_c, scale_c))
     return dx_c, demb.astype(emb.dtype), None
 
 
@@ -449,6 +467,32 @@ def chunked_cross_entropy(hidden, embedding, targets,
     gather what the caller sharded (a scan walks its leading axis in
     order, so a sharded chunk axis is gathered to every chip).
     """
+    tot, cnt = _chunked_ce(hidden, embedding, targets, ignore_index,
+                           chunk_size, mesh, by_row=False)
+    return tot / jnp.maximum(cnt, 1).astype(jnp.float32)
+
+
+@jax.named_scope("loss")
+def chunked_cross_entropy_rows(hidden, embedding, targets,
+                               ignore_index: int = -1,
+                               chunk_size: int = 2048, mesh=None):
+    """``chunked_cross_entropy`` before its mean: each row's loss,
+    float32 ``[B, S]``, a masked row's 0, differentiable a row (the
+    backward takes a cotangent a row). For a loss that weights the rows
+    by something the model learned (``models/ouro.py``: the exit
+    distribution over the passes, the passes' hidden states stacked on
+    the batch axis against the one head, so the head's gradient is
+    accumulated in one backward scan). The same core, forward paths
+    and ``shard_map`` over the token axes as the mean's; on a mesh the
+    rows come back sharded as ``targets`` are."""
+    return _chunked_ce(hidden, embedding, targets, ignore_index,
+                       chunk_size, mesh, by_row=True)
+
+
+def _chunked_ce(hidden, embedding, targets, ignore_index, chunk_size, mesh,
+                by_row: bool):
+    """``by_row``: each row's loss ``[B, S]``; else (their sum, the
+    count of unmasked rows), over the whole mesh."""
     B, S, E = hidden.shape
     # Cast the tied embedding ONCE outside the scan (fwd and bwd both
     # consume the bf16 copy).
@@ -456,9 +500,10 @@ def chunked_cross_entropy(hidden, embedding, targets,
     path = ce_path(hidden.shape, emb.shape[0], hidden.dtype, mesh,
                    chunk_size)
 
-    def sums(hidden, emb, targets, over=()):
-        """(sum of the rows' losses, count of unmasked rows) of the
-        rows in hand, added over the mesh axes ``over``."""
+    def in_hand(hidden, emb, targets, over=()):
+        """Of the rows in hand: their losses as ``targets`` is shaped,
+        or (the losses' sum, the count of unmasked rows) added over the
+        mesh axes ``over``."""
         rows = hidden.reshape(-1, E)
         tgt = targets.reshape(-1)
         chunk, n = _chunks(rows.shape[0], chunk_size)
@@ -466,39 +511,40 @@ def chunked_cross_entropy(hidden, embedding, targets,
         if pad:
             rows = jnp.pad(rows, ((0, pad), (0, 0)))
             tgt = jnp.pad(tgt, (0, pad), constant_values=ignore_index)
-        tot_cnt = _chunked_ce_core(
+        nll, tot_cnt = _chunked_ce_core(
             rows.reshape(n, chunk, E), emb, tgt.reshape(n, chunk),
             ignore_index, path)
+        tracing.count_trace(ce_rows=n * chunk)
         if path == "pallas_lse":
             from ray_tpu.ops.pallas import ce_lse
             block_rows, tile = ce_lse.blocks(n * chunk, E, emb.shape[0])
             tracing.note_trace(ce_fwd_rows=block_rows, ce_fwd_tile=tile)
             tracing.count_trace(ce_fwd_calls=1)
+        if by_row:
+            return nll.reshape(-1)[:targets.size].reshape(targets.shape)
         return jax.lax.psum(tot_cnt, over) if over else tot_cnt
 
     tracing.note_trace(ce_path=path)
     axes = _token_axes(mesh, B, S)
     if axes is None:
-        tot, cnt = sums(hidden, emb, targets)
-    else:
-        from jax.sharding import PartitionSpec
-        batch_axes, seq_axis = axes
-        tokens = PartitionSpec(batch_axes or None, seq_axis)
-        over = _mapped_over(axes)
-        # The head enters replicated, so the transpose reduces its
-        # gradient over the axes: once, after the backward scan. All
-        # mesh axes are manual, as in ops/attention.py: with only the
-        # token axes manual (``axis_names``) the bf16 sum's reduction
-        # gets a sharding constraint that aborts XLA's CPU compiler.
-        tot, cnt = jax.shard_map(
-            functools.partial(sums, over=over), mesh=mesh,
-            in_specs=(tokens, PartitionSpec(), tokens),
-            out_specs=PartitionSpec(),
-            check_vma=False)(hidden, emb, targets)
-        tracing.note_trace(
-            ce_rows_local=B * S // math.prod(mesh.shape[a] for a in over),
-            ce_axes=list(over))
-    return tot / jnp.maximum(cnt, 1).astype(jnp.float32)
+        return in_hand(hidden, emb, targets)
+    from jax.sharding import PartitionSpec
+    batch_axes, seq_axis = axes
+    tokens = PartitionSpec(batch_axes or None, seq_axis)
+    over = _mapped_over(axes)
+    tracing.note_trace(
+        ce_rows_local=B * S // math.prod(mesh.shape[a] for a in over),
+        ce_axes=list(over))
+    # The head enters replicated, so the transpose reduces its
+    # gradient over the axes: once, after the backward scan. All
+    # mesh axes are manual, as in ops/attention.py: with only the
+    # token axes manual (``axis_names``) the bf16 sum's reduction
+    # gets a sharding constraint that aborts XLA's CPU compiler.
+    return jax.shard_map(
+        functools.partial(in_hand, over=() if by_row else over), mesh=mesh,
+        in_specs=(tokens, PartitionSpec(), tokens),
+        out_specs=tokens if by_row else PartitionSpec(),
+        check_vma=False)(hidden, emb, targets)
 
 
 def gpt2_loss_fn(model: GPT2, fused_ce: bool = True,

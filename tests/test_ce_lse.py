@@ -189,3 +189,93 @@ def test_ce_path_decides_from_what_it_observes(case, monkeypatch):
     head = {**GPT2_HEAD, **overrides}
     assert gpt2.ce_path(head["shape"], head["vocab"], head["dtype"],
                         mesh) == want
+
+
+# -- each row's loss out, a cotangent a row in ----
+
+def _dense_rows(h, w, tgt):
+    """Each row's loss from a dense float32 log-softmax, an ignored
+    row's 0."""
+    logp = jax.nn.log_softmax(jnp.einsum("bse,ve->bsv", h, w,
+                                         precision="highest"), axis=-1)
+    mask = tgt != IGNORE
+    picked = jnp.take_along_axis(
+        logp, jnp.where(mask, tgt, 0)[..., None], -1)[..., 0]
+    return jnp.where(mask, -picked, 0.0)
+
+
+@pytest.mark.parametrize("path", ["xla_scan", "pallas_lse"])
+@pytest.mark.parametrize("rows_of, chunk", [
+    ((3, 40), 32), ((2, 64), 32), ((1, 48), 2048)],
+    ids=["padded_rows", "whole_chunks", "one_chunk"])
+def test_rows_and_their_gradients_under_a_cotangent_a_row(
+        rows_of, chunk, path, monkeypatch):
+    """``chunked_cross_entropy_rows`` against dense float32 log-softmax
+    rows: the values, and ``d hidden`` and ``d head`` under a random
+    cotangent a row, ignored rows and rows past the last chunk included,
+    on both forward paths (the kernel interpreted)."""
+    b, s = rows_of
+    e, v = 128, 5 * 128
+    rows, emb, tgt = _case(b, s, e, v, seed=5, ignored=0.2)
+    weight = jnp.asarray(np.random.default_rng(6).normal(size=(b, s)),
+                         jnp.float32)
+    said = {}
+    monkeypatch.setattr(gpt2.tracing, "note_trace", said.update)
+    monkeypatch.setattr(gpt2.tracing, "count_trace", said.update)
+    monkeypatch.setattr(gpt2, "ce_path", lambda *a, **k: path)
+    monkeypatch.setattr(ce_lse, "_TILE", 512)
+    monkeypatch.setattr(ce_lse, "_STRIP", 16)
+    monkeypatch.setattr(ce_lse, "_ROWS", 32)
+    monkeypatch.setattr(ce_lse, "ce_lse_fwd", functools.partial(
+        ce_lse.ce_lse_fwd, interpret=True))
+
+    def ours(h, w):
+        out = gpt2.chunked_cross_entropy_rows(
+            h, w, tgt, ignore_index=IGNORE, chunk_size=chunk)
+        return (out * weight).sum(), out
+
+    def dense(h, w):
+        out = _dense_rows(h, w, tgt)
+        return (out * weight).sum(), out
+
+    (_, got), (dh, dw) = jax.value_and_grad(ours, (0, 1), has_aux=True)(
+        rows, emb)
+    (_, want), (want_dh, want_dw) = jax.value_and_grad(
+        dense, (0, 1), has_aux=True)(rows, emb)
+    assert said["ce_path"] == path
+    assert said["ce_rows"] == -(-b * s // min(chunk, b * s)) * min(chunk,
+                                                                  b * s)
+    assert got.shape == (b, s) and got.dtype == jnp.float32
+    assert bool((got[tgt == IGNORE] == 0).all())
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for g, w in ((dh, want_dh), (dw, want_dw)):
+        np.testing.assert_allclose(g, w, rtol=1e-5,
+                                   atol=1e-5 * float(jnp.abs(w).max()))
+    # the mean is the rows' sum over the count, to a sum's reordering
+    mean = gpt2.chunked_cross_entropy(rows, emb, tgt, ignore_index=IGNORE,
+                                      chunk_size=chunk)
+    np.testing.assert_allclose(
+        float(mean), float(want.sum() / (tgt != IGNORE).sum()), rtol=1e-6)
+
+
+def test_rows_on_a_dp_mesh_come_back_sharded_as_the_targets():
+    """Under ``shard_map`` over the token axes each chip makes its own
+    rows' losses: the same values, the head's gradient reduced once."""
+    b, s, e, v = 4, 32, 128, 3 * 128
+    rows, emb, tgt = _case(b, s, e, v, seed=7, ignored=0.1)
+    weight = jnp.asarray(np.random.default_rng(8).normal(size=(b, s)),
+                         jnp.float32)
+    mesh = _mesh({"dp": 4})
+
+    def ours(h, w, mesh):
+        return (gpt2.chunked_cross_entropy_rows(
+            h, w, tgt, ignore_index=IGNORE, chunk_size=16, mesh=mesh)
+            * weight).sum()
+
+    want, want_g = jax.value_and_grad(ours, (0, 1))(rows, emb, None)
+    got, got_g = jax.jit(jax.value_and_grad(ours, (0, 1)),
+                         static_argnums=2)(rows, emb, mesh)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    for g, w in zip(got_g, want_g):
+        np.testing.assert_allclose(g, w, rtol=1e-5,
+                                   atol=1e-6 * float(jnp.abs(w).max()))

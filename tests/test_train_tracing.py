@@ -1057,7 +1057,7 @@ def test_the_loss_says_which_forward_it_compiled(monkeypatch, steered):
     ``ce_path``, and where that is the kernel's (steered onto it here,
     interpreted: no chip in the sandbox) the row block, the vocabulary
     tile and the custom calls a step; on the CPU ``xla_scan`` and no
-    ``ce_fwd_*`` key. No other span of the step carries them."""
+    ``ce_fwd_*`` key; on both ``ce_rows``, the rows that reached the core. No other span of the step carries them."""
     import functools
 
     from ray_tpu.models import gpt2
@@ -1074,10 +1074,10 @@ def test_the_loss_says_which_forward_it_compiled(monkeypatch, steered):
         model.init_params(jax.random.key(0)), opt)
     tokens = jnp.zeros((2, cfg.seq_len), jnp.int32)
     batch = {"tokens": tokens, "targets": tokens}
-    want = {"ce_path": "xla_scan"}
+    want = {"ce_path": "xla_scan", "ce_rows": 128}
     if steered:     # 128 rows in chunks of 64: one block; 256 columns
         want = {"ce_path": "pallas_lse", "ce_fwd_rows": 128,
-                "ce_fwd_tile": 256, "ce_fwd_calls": 1}
+                "ce_fwd_tile": 256, "ce_fwd_calls": 1, "ce_rows": 128}
     before = len(tracing.get_spans())
     step = train_step.make_train_step(
         gpt2.gpt2_loss_fn(model, ce_chunk=64), opt, donate=False)
