@@ -31,8 +31,10 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.attention import (
+    MLP_DOWN, MLP_GATE, MLP_UP, causal_attention)
 from ray_tpu.ops.moe import routed_ffn
 
 
@@ -253,6 +255,10 @@ class LlamaAttention(nn.Module):
 
 
 class SwiGLU(nn.Module):
+    """``W_d (SiLU(W_g x) * W_u x)``, no bias. The three products carry
+    names (``ops/attention.py::MLP_GATE``, ``MLP_UP``, ``MLP_DOWN``) that
+    a recomputed block's policy may list (``models/ouro.py``); under any
+    other policy, and outside one, a name is the identity."""
     config: LlamaConfig
 
     @nn.compact
@@ -261,9 +267,11 @@ class SwiGLU(nn.Module):
         dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype,
                         param_dtype=cfg.param_dtype,
                         kernel_init=nn.initializers.normal(0.02))
-        gate = dense(cfg.intermediate, name="gate")(x)
-        up = dense(cfg.intermediate, name="up")(x)
-        return dense(cfg.n_embd, name="down")(nn.silu(gate) * up)
+        gate = checkpoint_name(dense(cfg.intermediate, name="gate")(x),
+                               MLP_GATE)
+        up = checkpoint_name(dense(cfg.intermediate, name="up")(x), MLP_UP)
+        return checkpoint_name(
+            dense(cfg.n_embd, name="down")(nn.silu(gate) * up), MLP_DOWN)
 
 
 class _Experts(nn.Module):

@@ -45,7 +45,11 @@ blocks hand on the router's state. With ``remat`` each block is
 recomputed in the backward pass and those are kept block outputs, as
 are an attention core's output and row statistics
 (``ops/attention.py::remat_policy``: the flash forward kernel runs once
-a layer; the note ``blocks_remat_keeps``).
+a layer) and the MLP's ``gate_up`` product (``[T, 2 x 10,240]``, 168 MB
+a layer at 4,096 rows: the block's dearest matmul does not run twice;
+``down``'s product is added to the stream as it is, so nothing in the
+backward pass reads it and it needs no name); the note
+``blocks_remat_keeps`` lists them.
 
 It is the benchmark's seventh language model
 (``phi-4-mini-flash-reasoning.b1-t4096`` runs the rule at ``N`` = 8,
@@ -70,12 +74,16 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.nemotron_h import _conv_init, _dt_bias_init
 from ray_tpu.ops import ssm
 from ray_tpu.ops.attention import (
-    differential_attention, remat_keeps, remat_policy)
+    MLP_GATE_UP, differential_attention, remat_keeps, remat_policy)
 from ray_tpu.util import tracing
+
+# what a recomputed block keeps of its MLP (the module docstring)
+_MLP_KEEPS = (MLP_GATE_UP,)
 
 
 @dataclass(frozen=True)
@@ -359,8 +367,10 @@ class MLP(nn.Module):
     @nn.compact
     def __call__(self, h):
         cfg = self.config
-        g, u = jnp.split(_dense(cfg)(2 * cfg.mlp_width, name="gate_up")(h),
-                         2, -1)
+        # what a recomputed block keeps of this MLP (``Phi4Flash``)
+        gu = checkpoint_name(
+            _dense(cfg)(2 * cfg.mlp_width, name="gate_up")(h), MLP_GATE_UP)
+        g, u = jnp.split(gu, 2, -1)
         return _dense(cfg)(cfg.n_embd, name="down")(jax.nn.silu(g) * u)
 
 
@@ -415,15 +425,18 @@ class Phi4Flash(nn.Module):
             ssm_state=cfg.ssm_state, ssm_dt_rank=cfg.dt_rank,
             yoco_memory_layer=cfg.memory_layer, yoco_kv_layer=cfg.kv_layer,
             blocks_remat=cfg.remat,
-            blocks_remat_keeps=",".join(remat_keeps()) if cfg.remat else "")
+            blocks_remat_keeps=",".join(remat_keeps(*_MLP_KEEPS))
+            if cfg.remat else "")
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
         with jax.named_scope("embed"):
             x = self._constrain(wte(tokens))
         # a recomputed block keeps its attention core's output and row
-        # statistics (42 MB a layer at 4,096 rows), as models/laguna.py
-        block = nn.remat(Block, policy=remat_policy()) if cfg.remat else Block
+        # statistics (42 MB a layer at 4,096 rows), as models/laguna.py,
+        # and its MLP's gate_up product (168 MB a layer)
+        block = (nn.remat(Block, policy=remat_policy(*_MLP_KEEPS))
+                 if cfg.remat else Block)
         memory = kv = None
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):

@@ -420,3 +420,19 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
     fn = functools.partial(local_impl, axis_name=seq_axis)
     return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)
+
+
+# The names of a dense MLP's matmul products, beside ``ATTN_OUT`` and
+# ``ATTN_LSE`` in everything but place (``models/llama.py::SwiGLU``:
+# three, so that a model can keep a subset; ``models/phi4flash.py::MLP``:
+# ``[g | u]`` before the split). A recomputed block whose policy lists
+# one does not run that matmul a second time; which of them a model
+# lists is settled by its cell's memory, dearest millisecond a byte
+# first (docs/training_perf.md). They stand at the file's end because a
+# Mosaic kernel's body carries the line of every frame above it: a line
+# added above ``causal_attention`` would move the compile-cache key of
+# every step that holds a flash kernel.
+MLP_GATE = "mlp_gate"
+MLP_UP = "mlp_up"
+MLP_DOWN = "mlp_down"
+MLP_GATE_UP = "mlp_gate_up"

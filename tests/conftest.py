@@ -208,6 +208,33 @@ def equations(jaxpr):
                     yield from equations(sub)
 
 
+def matmuls(traced, *shapes) -> int:
+    """The ``dot_general``s of a traced function (``make_jaxpr``'s), the
+    jaxprs its equations hold among them, whose operands have one of
+    ``shapes`` (``(lhs shape, rhs shape)``: the rows' trailing axis
+    against the weight's leading one, a ``Dense``'s forward): how often a
+    recomputing program runs a layer's forward matmul. A loop's body
+    counts once."""
+    forward = (((len(shapes[0][0]) - 1,), (0,)), ((), ()))
+    return sum(
+        e.params["dimension_numbers"] == forward
+        and tuple(v.aval.shape for v in e.invars) in shapes
+        for e in equations(traced.jaxpr) if e.primitive.name == "dot_general")
+
+
+def same_bits(got, want) -> bool:
+    """Are two trees' leaves the same bits, leaf for leaf, with something
+    in every leaf of ``want``? For a recomputing program's loss and
+    gradients against the plain one's, run operation by operation: under
+    a ``jit`` the two are two programs, which XLA's CPU compiler may fuse
+    differently."""
+    import jax.numpy as jnp
+    got, want = (jax.tree_util.tree_leaves(t) for t in (got, want))
+    return (len(got) == len(want)
+            and all(bool((a == b).all()) for a, b in zip(got, want))
+            and all(float(jnp.abs(b).max()) > 0 for b in want))
+
+
 def live_kernel_calls(traced) -> list[int]:
     """The ``pallas_call``s left in a traced function (``make_jaxpr``'s)
     once what nothing reads is taken out, as lowering takes it out, each

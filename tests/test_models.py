@@ -387,3 +387,28 @@ def test_gpt2_loss_on_dp4_scans_local_rows_and_says_so():
     _, traces = traced_step(_mesh_of({"dp": 1}))
     assert len(traces) == 1
     assert not {"ce_rows_local", "ce_axes"} & set(traces[0])
+
+
+def test_the_mlps_names_are_the_identity_under_llamas_own_remat(monkeypatch):
+    """``SwiGLU``'s three products carry ``checkpoint_name``s for the
+    models whose recomputed blocks keep them (``models/ouro.py``). A
+    stack whose policy lists none of them — this file's own ``remat``,
+    ``nothing_saveable`` — lowers to the text it lowers to with the names
+    taken out, forward and backward."""
+    from ray_tpu.models import Llama, LlamaConfig
+    from ray_tpu.models import llama as llama_file
+    cfg = LlamaConfig.tiny(remat=True)
+    model = Llama(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    tokens = jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32)
+
+    def lowered():
+        text = jax.jit(jax.grad(lambda p, t: model.apply(
+            {"params": p}, t).astype(jnp.float32).sum())).lower(
+                params, tokens).as_text()
+        # a private function's number counts the functions before it
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+    named = lowered()
+    assert "gate" in named
+    monkeypatch.setattr(llama_file, "checkpoint_name", lambda x, name: x)
+    assert lowered() == named

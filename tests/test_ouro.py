@@ -14,12 +14,14 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import equations, matmuls, same_bits
 
 from ray_tpu import train
 from ray_tpu.models import Ouro, OuroConfig
 from ray_tpu.models import gpt2, ouro
 from ray_tpu.models.ouro import ouro_loss_fn
 from ray_tpu.parallel import make_mesh
+from ray_tpu.util import tracing
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                 "benchmark"))
@@ -400,3 +402,78 @@ def test_a_split_sequence_or_split_lanes_are_refused_by_name(axis):
     mesh = make_mesh({axis: 2}, devices=jax.devices()[:2])
     with pytest.raises(NotImplementedError, match=f"{axis}=2"):
         Ouro(cfg, mesh=mesh).init_params(jax.random.key(0))
+
+
+# -- what a recomputed block keeps ----
+
+@pytest.mark.parametrize("remat, keeps", [
+    (True, "mlp_down,mlp_up,mlp_gate[1:],attn_out,attn_lse"), (False, "")],
+    ids=["recomputed", "kept_whole"])
+def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
+                                                       monkeypatch):
+    """``blocks_remat_keeps`` beside ``blocks_remat``: the names a
+    recomputed block's policy keeps (its MLP's matmul products, the last
+    of them in the second half of the layers alone: ``name[k:]``; then the
+    attention core's output and row statistics), and every block of the
+    loop's body is a checkpoint that carries a policy; nothing where the
+    blocks are not recomputed."""
+    cfg = OuroConfig.tiny(remat=remat, **F32)
+    model = Ouro(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    traced = jax.make_jaxpr(lambda p, t: model.apply(
+        {"params": p}, t, return_hidden=True)[0])(
+            params, _batch(0, cfg)["tokens"])
+    assert notes["blocks_remat"] is remat
+    assert notes["blocks_remat_keeps"] == keeps
+    with_policy = [e for e in equations(traced.jaxpr)
+                   if e.primitive.name == "remat2" and e.params["policy"]]
+    assert len(with_policy) == (cfg.n_layer if remat else 0)
+
+
+def _mlp_forwards(remat) -> int:
+    """The MLPs' forward matmuls (``gate``, ``up``, ``down``) in the
+    traced loss and gradient of the tiny model: the loop's body counts
+    once, so ``3 n_layer`` where each runs once."""
+    cfg = OuroConfig.tiny(remat=remat, **F32)
+    model = Ouro(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    t, d, f = cfg.seq_len, cfg.n_embd, cfg.intermediate
+    traced = jax.make_jaxpr(jax.value_and_grad(
+        ouro_loss_fn(model, ce_chunk=16), has_aux=True))(
+            params, _batch(0, cfg))
+    return matmuls(traced, ((2, t, d), (d, f)), ((2, t, f), (f, d)))
+
+
+def test_a_recomputed_block_runs_the_kept_matmuls_once(monkeypatch):
+    """With ``remat`` the gradient holds the MLPs' forward matmuls once
+    in the layers that keep their three products (``_MLP_KEEPS``;
+    ``down``'s is read by the norm on the branch) and ``gate``'s once
+    more in the layers that keep two (``_block_keeps``: the first half).
+    With every layer keeping all three the count is that of the stack
+    kept whole; under the policy without any of the names each block
+    holds one more forward of all three."""
+    n = OuroConfig.tiny().n_layer
+    assert (_mlp_forwards(False), _mlp_forwards(True)) == (
+        3 * n, 3 * n + n // 2)
+    monkeypatch.setattr(ouro, "_first_keeping_all", lambda cfg: 0)
+    assert _mlp_forwards(True) == 3 * n
+    monkeypatch.setattr(ouro, "_block_keeps", lambda cfg, i: ())
+    assert _mlp_forwards(True) == 6 * n
+
+
+def test_a_recomputed_stack_gives_the_bits_of_the_one_kept_whole():
+    """Loss, report and every gradient leaf with ``remat`` are the same
+    bits as without: kept and recomputed products come from the same
+    matmuls (``conftest.same_bits``: no ``jit``)."""
+    got = {}
+    for remat in (False, True):
+        cfg = OuroConfig.tiny(remat=remat, **F32)
+        model = Ouro(cfg)
+        params = _jittered(model.init_params(jax.random.key(5)), 5)
+        got[remat] = jax.value_and_grad(
+            ouro_loss_fn(model, ce_chunk=16), has_aux=True)(
+                params, _batch(5, cfg))
+    assert len(jax.tree_util.tree_leaves(got[False])) > 30
+    assert same_bits(got[True], got[False])

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import matmuls, same_bits
 
 from ray_tpu import train
 from ray_tpu.models import Phi4Flash, Phi4FlashConfig
@@ -454,14 +455,15 @@ def test_the_step_reports_the_keys_and_notes_what_the_layers_are(
 
 
 @pytest.mark.parametrize("remat, keeps", [
-    (True, "attn_out,attn_lse"), (False, "")],
+    (True, "mlp_gate_up,attn_out,attn_lse"), (False, "")],
     ids=["recomputed", "kept_whole"])
 def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
                                                        monkeypatch):
     """``blocks_remat_keeps`` beside ``blocks_remat``: the names a
-    recomputed block's policy keeps (the attention cores' output and row
-    statistics), and every block's checkpoint carries a policy; nothing
-    where the blocks are not recomputed."""
+    recomputed block's policy keeps (the MLP's ``gate_up`` product, then
+    the attention cores' output and row statistics), and every block's
+    checkpoint carries a policy; nothing where the blocks are not
+    recomputed."""
     cfg = Phi4FlashConfig.tiny(remat=remat, **F32)
     model = Phi4Flash(cfg)
     params = jax.eval_shape(model.init_params, jax.random.key(0))
@@ -475,6 +477,49 @@ def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
     with_policy = [e for e in traced.jaxpr.eqns
                    if e.primitive.name == "remat2" and e.params["policy"]]
     assert len(with_policy) == (cfg.n_layer if remat else 0)
+
+
+def _mlp_forwards(remat) -> int:
+    """The MLPs' forward matmuls (``gate_up``, ``down``) in the traced
+    loss and gradient of the tiny model, at an MLP width no other
+    projection of it has."""
+    cfg = Phi4FlashConfig.tiny(remat=remat, mlp_width=48, **F32)
+    model = Phi4Flash(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    t, d, f = cfg.seq_len, cfg.n_embd, cfg.mlp_width
+    traced = jax.make_jaxpr(jax.value_and_grad(
+        phi4flash_loss_fn(model, ce_chunk=16), has_aux=True))(
+            params, _batch(0, cfg))
+    return matmuls(traced, ((2, t, d), (d, 2 * f)), ((2, t, f), (f, d)))
+
+
+def test_a_recomputed_block_runs_its_mlps_matmuls_once(monkeypatch):
+    """With ``remat`` the gradient holds as many of the MLPs' forward
+    matmuls as without it: the policy keeps ``gate_up``'s product
+    (``_MLP_KEEPS``), and nothing in the backward pass reads ``down``'s,
+    which is added to the stream as it is. Under the policy without that
+    name each block holds one more ``gate_up``, and still no second
+    ``down``."""
+    n = Phi4FlashConfig.tiny().n_layer
+    assert (_mlp_forwards(False), _mlp_forwards(True)) == (2 * n, 2 * n)
+    monkeypatch.setattr(model_file, "_MLP_KEEPS", ())
+    assert _mlp_forwards(True) == 3 * n
+
+
+def test_a_recomputed_stack_gives_the_bits_of_the_one_kept_whole():
+    """Loss, report and every gradient leaf with ``remat`` are the same
+    bits as without: kept and recomputed products come from the same
+    matmuls (``conftest.same_bits``: no ``jit``)."""
+    got = {}
+    for remat in (False, True):
+        cfg = Phi4FlashConfig.tiny(remat=remat, **F32)
+        model = Phi4Flash(cfg)
+        params = _jittered(model.init_params(jax.random.key(5)), 5)
+        got[remat] = jax.value_and_grad(
+            phi4flash_loss_fn(model, ce_chunk=16), has_aux=True)(
+                params, _batch(5, cfg))
+    assert len(jax.tree_util.tree_leaves(got[False])) > 100
+    assert same_bits(got[True], got[False])
 
 
 def test_every_kind_of_layer_has_its_own_scopes():

@@ -37,15 +37,17 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     so each block's core is one call site of the multi-block flash
     forward kernel and one of the backward (a recomputed block keeps its
     core's output and row statistics, so the second pass over the block
-    runs no forward kernel), ``[1, 4096, 16 x 128]`` in the projections'
-    own layout, under ``attn/core``. The four passes' rows reach the
-    loss's forward kernel in one call: 16,384 rows against the 49,152-row
-    head. No ``[T, T]`` array exists."""
+    runs no forward kernel; it keeps two or three of its MLP's products
+    too, which are XLA's matmuls and no call site here), ``[1, 4096, 16
+    x 128]`` in the projections' own layout, under ``attn/core``. The
+    four passes' rows reach the loss's forward kernel in one call:
+    16,384 rows against the 49,152-row head. No ``[T, T]`` array exists."""
     cfg, notes, lowered = real_size_step
     assert notes["attn_kind"] == "looped_full"
     assert notes["ut_steps"] == 4 and notes["ut_path"] == "scan"
     assert notes["rope_kind"] == "half" and notes["blocks_remat"] is True
-    assert notes["blocks_remat_keeps"] == "attn_out,attn_lse"
+    assert notes["blocks_remat_keeps"] == (
+        "mlp_down,mlp_up,mlp_gate[4:],attn_out,attn_lse")
     assert notes["flash_layout"] == "bthd"
     assert notes["flash_lanes_per_block"] == 128
     assert notes["flash_path"] == "multi_block"
@@ -67,15 +69,32 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
 
 @pytest.mark.slow
 def test_the_real_size_step_compiles_inside_the_chips_memory(real_size_step):
-    """Arguments + temporaries + unaliased outputs stay under 13.0 GB of
-    the chip's 15.75 (the configuration's ``cut.memory`` has the number
-    as compiled)."""
+    """The step's peak, ``memory_analysis().peak_memory_in_bytes``, stays
+    under 15.9 GB of the chip's 16.91 (15.75 GiB): 15.78 GB with
+    ``down``'s and ``up``'s products kept in every layer and ``gate``'s
+    in four of the eight (11.16 without them, 15.06 with the two
+    alone), which is what the chip's allocator reserves (9.70 GB beside
+    6.12 of arguments: my chip run, PR 62). **Arguments + temporaries,
+    the sum the other cells' tests hold and ``device.program_gb``
+    reports, reads 20.43 GB here and is not what the program takes**:
+    once a looped step's peak passes ~11.8 GB the compiler's
+    ``temp_size_in_bytes`` counts ~4.5 GB more than its own buffer
+    assignment holds (11.46 GB at the parent, where the two agree to
+    0.3; PERF.md section 6, PR 62). Held too, so that a change of
+    either shows. No fusion of the compiled step is XLA's own
+    rematerialisation (``.remat`` in its name), which is what the step
+    pays with when it is asked to keep more than fits."""
     cfg, _, lowered = real_size_step
-    m, total = program_bytes(lowered.compile())
+    compiled = lowered.compile()
+    m, total = program_bytes(compiled)
     assert m.argument_size_in_bytes == pytest.approx(
         cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
-    print(f"ouro step: total {total / 1e9:.2f} GB, arguments "
+    print(f"ouro step: peak {m.peak_memory_in_bytes / 1e9:.2f} GB, "
+          f"arguments + temporaries {total / 1e9:.2f}, arguments "
           f"{m.argument_size_in_bytes / 1e9:.2f}, temporaries "
           f"{m.temp_size_in_bytes / 1e9:.2f}, code "
           f"{m.generated_code_size_in_bytes / 1e9:.3f}")
-    assert 9.0e9 < total < 13.0e9
+    assert 9.0e9 < m.peak_memory_in_bytes < 15.78e9 + 0.1e9
+    assert total < 20.43e9 + 0.1e9
+    assert not re.findall(r"^\s+%?[\w.\-]*\.remat\d* = ", compiled.as_text(),
+                          re.M)
