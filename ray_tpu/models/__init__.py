@@ -44,11 +44,19 @@ expresses Laguna-XS.2.
 norm, an exit gate and the untied head after every pass, a loss weighted
 row by row by the learned exit distribution through
 ``gpt2.chunked_cross_entropy_rows``); its config expresses Ouro-2.6B.
+``Granite`` is the Mamba-2 / attention hybrid under Granite's four
+multipliers (``NemotronH``'s ``Mamba2Mixer`` at one group and chunk 256,
+nine layers in ten, grouped-query attention without positions at the
+scale ``attention_multiplier``, ``Phi4Flash``'s fused SwiGLU MLP in every
+block, a tied table read under ``embedding_multiplier`` and
+``logits_scaling``, the blocks recomputed with the scan's results kept by
+name); its config expresses granite-4.0-h-micro.
 ``MoETransformer`` is the older top-1, capacity-dropping switch model
 on GPT-2 blocks, which goes when the dropless path runs under ``ep``
 (ROADMAP C5)."""
 
 from ray_tpu.models.gpt2 import GPT2, GPT2Config
+from ray_tpu.models.granite import Granite, GraniteHybridConfig
 from ray_tpu.models.joyai import JoyAI, JoyAIConfig
 from ray_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
 from ray_tpu.models.laguna import Laguna, LagunaConfig
@@ -63,8 +71,8 @@ from ray_tpu.models.vit import ViT, ViTConfig
 from ray_tpu.models.zaya import Zaya, ZayaConfig
 
 __all__ = [
-    "GPT2", "GPT2Config", "JoyAI", "JoyAIConfig", "KimiLinear",
-    "KimiLinearConfig", "Laguna", "LagunaConfig", "Llama", "LlamaConfig",
+    "GPT2", "GPT2Config", "Granite", "GraniteHybridConfig", "JoyAI",
+    "JoyAIConfig", "KimiLinear", "KimiLinearConfig", "Laguna", "LagunaConfig", "Llama", "LlamaConfig",
     "MoETransformer", "MoEConfig", "NemotronH", "NemotronHConfig", "Ouro",
     "OuroConfig", "Phi4Flash", "Phi4FlashConfig",
     "ResNet", "ResNet50Config", "SmallThinker", "SmallThinkerConfig", "ViT",

@@ -28,6 +28,17 @@ Program scopes (docs/observability.md): ``embed``; ``blocks`` with, by
 the layer's kind, ``mamba`` (``in_proj``, ``conv``, ``scan``,
 ``gate_norm``, ``out_proj`` beneath), ``attn``, or ``mlp`` (``router``,
 ``dispatch``, ``experts``, ``combine``, ``shared`` beneath); ``loss``.
+
+**``Mamba2Mixer`` has two callers.** It reads of its config only what
+``Mamba2Dims`` lists, so any config that carries those fields runs it:
+this file's (64 heads of 64 in **8 groups**, chunk **128**, hidden
+2,688, never under ``nn.remat``) and ``models/granite.py``'s
+(granite-4.0-h-micro: 64 heads of 64 in **1 group**, so that the scan's
+kernels make a group's score square in each of its 8 head blocks and
+sum ``dB``, ``dC`` over them; chunk **256**; hidden 2,048; every block
+under ``nn.remat`` with the scan's two results kept by name,
+``ops/ssm.py::SCAN_OUT`` and ``SCAN_STATES``). The parameter tree, the
+scopes and the arithmetic are one; the names are the identity here.
 """
 
 from __future__ import annotations
@@ -50,8 +61,37 @@ from ray_tpu.util import tracing
 NANO_30B_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 
 
+class Mamba2Dims:
+    """What ``Mamba2Mixer`` (with ``_Conv``, ``_GateNorm`` and the
+    initialisers) reads of a config, and the two widths that follow
+    from it. A config that mixes this in and carries the fields runs
+    the mixer: ``NemotronHConfig`` here, ``GraniteHybridConfig`` in
+    ``models/granite.py``."""
+    n_embd: int
+    rms_eps: float
+    mamba_heads: int
+    mamba_head_dim: int
+    ssm_state: int
+    ssm_groups: int
+    conv_kernel: int
+    chunk: int
+    time_step_min: float
+    time_step_max: float
+    time_step_floor: float
+    dtype: Any
+    param_dtype: Any
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def conv_width(self) -> int:
+        return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state
+
+
 @dataclass(frozen=True)
-class NemotronHConfig:
+class NemotronHConfig(Mamba2Dims):
     """The keys of a ``nemotron_h`` ``config.json`` under this repo's
     names; the defaults are Nemotron-3-Nano-30B-A3B's."""
     vocab_size: int = 131072
@@ -120,14 +160,6 @@ class NemotronHConfig:
         return len(self.pattern)
 
     @property
-    def mamba_inner(self) -> int:
-        return self.mamba_heads * self.mamba_head_dim
-
-    @property
-    def conv_width(self) -> int:
-        return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state
-
-    @property
     def experts_span(self) -> tuple[int, int]:
         """(first, count) of the experts held; all of them by default."""
         return self.experts_held or (0, self.num_experts)
@@ -158,13 +190,13 @@ class NemotronHConfig:
                 + 2 * self.vocab_size * self.n_embd + self.n_embd)
 
 
-def _dense(cfg: NemotronHConfig):
+def _dense(cfg: Mamba2Dims):
     return partial(nn.Dense, use_bias=False, dtype=cfg.dtype,
                    param_dtype=cfg.param_dtype,
                    kernel_init=nn.initializers.normal(0.02))
 
 
-def _dt_bias_init(cfg: NemotronHConfig):
+def _dt_bias_init(cfg: Mamba2Dims):
     """``dt`` log-uniform in [time_step_min, time_step_max], floored;
     the bias is its inverse softplus (the Mamba-2 initialiser)."""
     def init(key, shape, dtype):
@@ -182,7 +214,7 @@ def _a_log_init(key, shape, dtype):
                    ).astype(dtype)
 
 
-def _conv_init(cfg: NemotronHConfig):
+def _conv_init(cfg: Mamba2Dims):
     """torch's ``Conv1d`` default for a depthwise kernel of width K,
     weight and bias: uniform in +-1/sqrt(K)."""
     bound = 1.0 / math.sqrt(cfg.conv_kernel)
@@ -195,7 +227,7 @@ def _conv_init(cfg: NemotronHConfig):
 class _Conv(nn.Module):
     """``conv1d``: the depthwise causal convolution's [K, C] kernel and
     bias, then SiLU."""
-    config: NemotronHConfig
+    config: Mamba2Dims
     mesh: Any = None
 
     @nn.compact
@@ -209,7 +241,7 @@ class _Conv(nn.Module):
 
 
 class _GateNorm(nn.Module):
-    config: NemotronHConfig
+    config: Mamba2Dims
     mesh: Any = None
 
     @nn.compact
@@ -222,7 +254,11 @@ class _GateNorm(nn.Module):
 
 
 class Mamba2Mixer(nn.Module):
-    config: NemotronHConfig
+    """The Mamba-2 mixer of any config with ``Mamba2Dims``'s fields
+    (the module docstring names the two). Where the caller's ``apply``
+    makes ``stats`` mutable it sows ``out_sq``, the mean square of the
+    scan's output ``y`` before the gate."""
+    config: Mamba2Dims
     mesh: Any = None
 
     @nn.compact
@@ -247,11 +283,16 @@ class Mamba2Mixer(nn.Module):
                 -jnp.exp(a_log), bs.reshape(b, t, g, n),
                 cs.reshape(b, t, g, n), skip, chunk=cfg.chunk,
                 mesh=self.mesh)
+        path = ssm.scan_path((b, t, h, p), (b, t, g, n), cfg.chunk,
+                             self.mesh)
         tracing.note_trace(
-            ssm_tokens=b * t, ssm_heads=h, ssm_state=n,
-            ssm_chunk=cfg.chunk, ssm_path=ssm.scan_path(
-                (b, t, h, p), (b, t, g, n), cfg.chunk, self.mesh),
+            ssm_tokens=b * t, ssm_heads=h, ssm_state=n, ssm_groups=g,
+            ssm_chunk=cfg.chunk, ssm_path=path,
+            ssm_blocks_per_group=ssm.score_squares_per_group(h, g, path),
             gate_norm_path=ssm.norm_path((b, t, inner), g, self.mesh))
+        if self.is_mutable_collection("stats"):
+            self.sow("stats", "out_sq",
+                     jnp.mean(jnp.square(y.astype(jnp.float32))))
         y = _GateNorm(cfg, self.mesh, name="gate_norm")(
             y.reshape(b, t, inner), z)
         return _dense(cfg)(cfg.n_embd, name="out_proj")(y)

@@ -320,17 +320,17 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
-                                  seq_axis="sp", head_axis="tp",
-                                  impl="auto", window: int | None = None):
+                                  seq_axis="sp", head_axis="tp", impl="auto",
+                                  window: int | None = None, scale=None):
     """Build an attention fn for activations sharded
     [batch->dp/fsdp, seq->sp, heads->tp]: shard_map-wrapped ring
     attention when the mesh has a real sp axis, dense attention
-    otherwise. ``impl`` forces a path: "dense" is incompatible with a
-    real sp axis (activations are sequence-sharded, so each device
-    only holds a slice of K/V) and raises rather than silently
-    running ring. ``window`` (``causal_attention``'s) goes to each
-    chip's own call; with ``sp > 1`` it raises ``NotImplementedError``:
-    ring and Ulysses know no band."""
+    otherwise. ``impl`` forces a path: "dense" cannot run on a real sp
+    axis (each device only holds a slice of K/V) and raises rather than
+    silently running ring. ``scale`` and ``window`` (both
+    ``causal_attention``'s) go to each chip's own call; a window with
+    ``sp > 1`` raises ``NotImplementedError``: ring and Ulysses know no
+    band."""
     from jax.sharding import PartitionSpec as P
 
     if impl not in ("auto", "dense", "ring", "ulysses"):
@@ -368,13 +368,13 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
             # the process can see (one chip of a four-chip host): the
             # kernel runs bare, with no device-count guard.
             return functools.partial(causal_attention, force_flash=True,
-                                     window=window)
+                                     window=window, scale=scale)
         if not batch and heads is None:
             # Attention operands replicated over a multi-device mesh
             # (pp- or ep-only): no axis to shard_map over, and the
             # bare kernel has no SPMD rule, so this is the XLA path.
             def dense(q, k, v):
-                return causal_attention(q, k, v, window=window)
+                return causal_attention(q, k, v, scale, window=window)
             return dense
         # Batch/head-sharded, sequence-replicated: shard_map so each
         # device runs the local block — this is what lets the Pallas
@@ -395,13 +395,13 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
             # Shapes that don't divide the mesh (e.g. the tiny batch
             # used by init tracing) take the plain XLA path.
             if q.shape[0] % n_batch or q.shape[2] % n_heads:
-                return causal_attention(q, k, v, window=window)
+                return causal_attention(q, k, v, scale, window=window)
             d = q.shape[-1]
 
             def local(*qkv):
                 q, k, v = (x.reshape(*x.shape[:2], -1, d) for x in qkv)
                 return causal_attention(
-                    q, k, v, force_flash=True,
+                    q, k, v, scale, force_flash=True,
                     window=window).reshape(qkv[0].shape)
 
             merged = jax.shard_map(local, mesh=mesh,
@@ -417,7 +417,7 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
              None)
     local_impl = (ulysses_attention if impl == "ulysses"
                   else ring_attention)
-    fn = functools.partial(local_impl, axis_name=seq_axis)
+    fn = functools.partial(local_impl, axis_name=seq_axis, scale=scale)
     return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)
 

@@ -8,14 +8,23 @@ VMEM, a head at a time, and never reach HBM; the state is carried from
 chunk to chunk in VMEM scratch; the only thing kept for the backward
 beside the inputs is the state *entering* each chunk (float32, what
 ``_ssd`` keeps under the name ``ssm_boundary_states``), from which the
-backward kernel recomputes a chunk's squares, again in VMEM.
+backward kernel recomputes a chunk's squares, again in VMEM. The
+forward rule names its two results (``SCAN_OUT``, ``SCAN_STATES``): a
+block recomputed under ``nn.remat`` whose policy lists them
+(``models/granite.py``) finds ``y`` and the entering states kept and
+does not run the forward kernel a second time; outside such a policy
+(``models/nemotron_h.py``) a name is the identity.
 
 Grid (both passes): (batch, head block, chunk), the chunk axis
 "arbitrary" (sequential), walked first to last by the forward and last
 to first by the backward, which carries the state's cotangent the same
 way. A head block is ``_HEADS`` = 8 heads of one group: their ``dt``
 rows are one float32 sublane tile, and they share the group's ``C.B^T``
-score square, made once a grid cell.
+score square, made once a grid cell. A group of more than eight heads
+(granite-4.0-h-micro: one group of 64) is ``blocks_per_group`` head
+blocks, each of which makes the group's square again and writes its
+own float32 share of ``dB`` and ``dC``, summed outside the kernel; one
+square a group is ROADMAP's.
 
 Layout (PERF.md section 6, PR 29: a ``[.., H, 64]`` array is half
 padding in 128-lane tiles): the kernels index ``x`` and ``y`` as
@@ -71,6 +80,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 # Heads in a block: one float32 sublane tile of ``dt``'s [H, T] rows.
@@ -86,6 +96,12 @@ def shapes_ok(h: int, p: int, g: int, n: int, chunk: int) -> bool:
     return (chunk > 0 and chunk % 128 == 0 and n > 0 and n % 128 == 0
             and g > 0 and h % g == 0 and (h // g) % _HEADS == 0
             and (_HEADS * p) % 128 == 0)
+
+
+def blocks_per_group(h: int, g: int) -> int:
+    """The head blocks of one group: how many grid cells make the
+    group's ``C.B^T`` score square a chunk (1 at eight heads a group)."""
+    return h // g // _HEADS
 
 
 # ---------------------------------------------------------------------------
@@ -397,6 +413,12 @@ def _ssd_bwd(x, dt, rate, skip, bm, cm, entering, dy, *, p, n, chunk,
 # public API with custom VJP
 # ---------------------------------------------------------------------------
 
+# The names of the forward kernel's two results, for the policy of a
+# recomputed block (``ops/ssm.py`` exports them).
+SCAN_OUT = "ssd_scan_out"
+SCAN_STATES = "ssd_scan_states"
+
+
 class _Static(NamedTuple):
     """What the kernels are specialised on, besides their shapes."""
     p: int          # head width
@@ -412,6 +434,10 @@ def _ssd_core(x, dt, rate, skip, bm, cm, static: _Static):
 
 def _ssd_core_fwd(x, dt, rate, skip, bm, cm, static):
     y, entering = _ssd_fwd(x, dt, rate, skip, bm, cm, **static._asdict())
+    # both named before they part into primal and residuals (the trap
+    # ``ops/attention.py::name_core_results`` records)
+    y, entering = checkpoint_name(y, SCAN_OUT), checkpoint_name(
+        entering, SCAN_STATES)
     return y, (x, dt, rate, skip, bm, cm, entering)
 
 

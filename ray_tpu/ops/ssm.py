@@ -4,8 +4,12 @@ grouped gated RMSNorm behind it, and Kimi Delta Attention's output gate,
 which stands where that norm does behind another recurrence. All four
 are Pallas kernels where a TPU program can take them (below) and XLA
 functions elsewhere, the convolution, the norm and the gate recomputed
-in the backward on either path. Nemotron-H's ``M`` layers
-(``models/nemotron_h.py``) are the first three's caller.
+in the backward on either path. The first three have one caller,
+``models/nemotron_h.py::Mamba2Mixer``, which two models run: Nemotron-H's
+``M`` layers (64 heads of 64, state 128 in 8 groups, chunk 128, 4 taps
+with bias) and granite-4.0-h-micro's ``mamba`` layers
+(``models/granite.py``: the same heads and state in **1 group**, chunk
+**256**, every block recomputed under ``nn.remat``).
 
 The recurrence, a head (``S`` is ``[P, N]``)::
 
@@ -45,7 +49,15 @@ Everywhere else it is pure XLA (``_ssd`` below, ``chunked_xla``): the
 chunk terms are batched matmuls that XLA places on the MXU, the
 squares arrays in HBM. Same mathematics, same precisions, same
 residual. A sequence split over chips (``sp``) would need the state
-passed between chips; the model refuses it by name.
+passed between chips; the model refuses it by name. The kernels'
+forward rule names its two results, the output ``y`` and the states
+entering the chunks (``SCAN_OUT``, ``SCAN_STATES``, 67 MB each a layer
+at 8,192 rows of 64 x 64 and chunk 256): a recomputed block whose
+policy lists them runs the forward kernel once a layer, not twice, and
+outside a policy the names are the identity. One group's ``C.B^T``
+square is made once a head block of eight heads: once a group at
+Nemotron's 8 heads a group, eight times at Granite's 64
+(``score_squares_per_group``, the note ``ssm_blocks_per_group``).
 
 The gated norm follows the scan: ``norm_path()`` gives it its own two
 kernels (``ops/pallas/gated_norm.py``, ``pallas``) on a TPU where each
@@ -117,6 +129,8 @@ from ray_tpu.ops.pallas import causal_conv, gated_norm, ssd_scan
 from ray_tpu.util import tracing
 
 _BOUNDARY = "ssm_boundary_states"
+# what a recomputed block keeps of the scan's kernels (``ssd_scan.py``)
+SCAN_OUT, SCAN_STATES = ssd_scan.SCAN_OUT, ssd_scan.SCAN_STATES
 # what ``parallel/sharding.py`` maps the logical "batch" to
 _BATCH_AXES = ("dp", "fsdp")
 
@@ -132,6 +146,13 @@ def scan_path(x_shape, state_shape, chunk: int, mesh=None) -> str:
             and _kernel_batch_axes(mesh, x_shape[0]) is not None):
         return "pallas_chunked"
     return "chunked_xla"
+
+
+def score_squares_per_group(h: int, g: int, path: str) -> int:
+    """How many times a chunk's ``C.B^T`` square is made for one group
+    of ``h // g`` heads on ``path``: once a head block of the kernels'
+    grid, once in ``_ssd``'s einsum."""
+    return ssd_scan.blocks_per_group(h, g) if path == "pallas_chunked" else 1
 
 
 def norm_path(shape, groups: int, mesh=None) -> str:

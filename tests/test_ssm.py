@@ -301,6 +301,100 @@ def test_kernel_scan_at_the_cells_head_width_in_bfloat16():
 CELL = ((1, 8192, 64, 64), (1, 8192, 8, 128), 128)
 
 
+# (batch, heads, head width, groups, state), T: chunks of 256, and one
+# group over two or three head blocks (granite-4.0-h-micro's scan: a
+# group's square is made in each of its head blocks, and ``dB``, ``dC``
+# leave the backward as a float32 share a head block, summed outside)
+KERNEL_CASES_256 = {
+    "one_group_two_head_blocks": ((1, 16, 16, 1, 128), 512),
+    "one_group_three_head_blocks_ragged_tail": ((1, 24, 16, 1, 128), 300),
+    "two_groups_batch_two": ((2, 16, 16, 2, 128), 256),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES_256)
+def test_kernel_scan_at_chunk_256_is_the_xla_scan(case):
+    """``y`` and the cotangents of ``x``, ``dt``, ``A``, ``B``, ``C``,
+    ``D`` against ``_ssd``'s at the same chunk, and ``y`` against the
+    recurrence as written."""
+    shape, T = KERNEL_CASES_256[case]
+    args = _inputs(T, seed=T + 2, shape=shape)
+
+    def kernel(*a):
+        return ssd_scan.ssd_scan(*a, chunk=256, interpret=True)
+
+    def xla(*a):
+        return ssm._padded_scan(*a, chunk=256)
+
+    with jax.default_matmul_precision("highest"):
+        got, want = jax.jit(kernel)(*args), xla(*args)
+        top = float(jnp.abs(want).max())
+        # a chunk's 256 log-decays are summed in float32 in another
+        # order on each of the three paths
+        for other in (want, _recurrence(*args)):
+            np.testing.assert_allclose(got, other, rtol=1e-4,
+                                       atol=1e-4 * top)
+        grads = _grad(lambda *a: jnp.sum(kernel(*a) ** 2), range(6))(*args)
+        wants = _grad(lambda *a: jnp.sum(xla(*a) ** 2), range(6))(*args)
+    _close_gradients(grads, wants)
+
+
+def test_one_groups_partial_db_dc_come_back_in_the_operands_dtype():
+    """bfloat16 operands, one group over two head blocks: the shares of
+    ``dB`` and ``dC`` are float32 inside and their sum is cast once."""
+    x, dt, A, Bm, C, D = _inputs(256, seed=9, shape=(1, 16, 16, 1, 128))
+    low = (x.astype(jnp.bfloat16), dt, A, Bm.astype(jnp.bfloat16),
+           C.astype(jnp.bfloat16), D)
+
+    def loss(f):
+        return lambda *a: jnp.sum(f(*a).astype(jnp.float32) ** 2)
+
+    grads = _grad(loss(lambda *a: ssd_scan.ssd_scan(
+        *a, chunk=256, interpret=True)), range(6))(*low)
+    wants = _grad(loss(lambda *a: ssm._padded_scan(*a, chunk=256)),
+                  range(6))(*low)
+    assert [g.dtype for g in grads] == [a.dtype for a in low]
+    for name, g, w in zip("x dt A B C D".split(), grads, wants):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert float(jnp.linalg.norm(g - w)) < (
+            0.01 * float(jnp.linalg.norm(w))), name
+
+
+@pytest.mark.parametrize("policy, forwards", [
+    ((ssm.SCAN_OUT, ssm.SCAN_STATES), 1), ((ssm.SCAN_OUT,), 2), ((), 2)],
+    ids=["both_names", "the_output_alone", "no_name"])
+def test_a_policy_that_keeps_the_scans_two_names_runs_its_forward_once(
+        policy, forwards):
+    """The gradient of a recomputed function round the kernels, with
+    what nothing reads taken out as lowering takes it out: the forward
+    kernel (2 results) once where the policy keeps ``y`` and the
+    entering states, twice where it loses either; the backward (6)
+    once. Outside a policy the names change nothing."""
+    from conftest import live_kernel_calls
+    args = _inputs(256, seed=4, shape=(1, 16, 16, 1, 128))
+
+    def kernel(*a):
+        return jnp.sin(ssd_scan.ssd_scan(*a, chunk=256, interpret=True))
+
+    kept = jax.checkpoint(
+        kernel, policy=jax.checkpoint_policies.save_only_these_names(*policy))
+    traced = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(kept(*a) ** 2), range(6)))(*args)
+    assert live_kernel_calls(traced) == [2] * forwards + [6]
+    assert live_kernel_calls(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(kernel(*a) ** 2), range(6)))(*args)) == [2, 6]
+
+
+@pytest.mark.parametrize("h, g, path, squares", [
+    (64, 8, "pallas_chunked", 1), (64, 1, "pallas_chunked", 8),
+    (64, 1, "chunked_xla", 1)],
+    ids=["nemotrons_groups", "granites_one_group", "the_xla_path"])
+def test_score_squares_per_group_counts_a_groups_head_blocks(h, g, path,
+                                                             squares):
+    assert ssm.score_squares_per_group(h, g, path) == squares
+    assert ssd_scan.shapes_ok(h, 64, g, 128, 256)
+
+
 def _mesh(**axes):
     from ray_tpu.parallel.mesh import make_mesh
     size = int(np.prod(list(axes.values())))
