@@ -36,9 +36,14 @@ what its policy keeps by name (``_block_keeps``; the note
 ``blocks_remat_keeps``): the scan's output and chunk-entering states
 (``ops/ssm.py::SCAN_OUT``, ``SCAN_STATES``: 134 MB a layer at 8,192 rows,
 and ``_ssd_fwd`` runs once a layer, not twice), the attention core's
-output and row statistics (``ops/attention.py::remat_policy``), and the
-MLP's ``gate_up`` product (268 MB a layer at 8,192 rows) on the layers
-from ``_first_keeping_gate_up`` on, which the cell's memory decides.
+output and row statistics (``ops/attention.py::remat_policy``), the
+stream between the block's two sub-layers (``_MIXER_STREAM``, 34 MB a
+layer: with it nothing in the second pass reads ``out_proj``'s product
+or ``o``'s, and neither matmul runs again), and, on the layers that the
+cell's memory decides, the MLP's ``gate_up`` product (268 MB a layer,
+from ``_first_keeping_gate_up`` on) and the three parts of the mixer's
+``in_proj`` product (``ops/ssm.py::IN_PROJ_PARTS``, 139 MB a layer, from
+``_first_keeping_in_proj`` on).
 
 ``sp`` and ``tp`` meshes are refused by name: the scan runs a whole
 sequence on one chip (a state passed from chip to chip is not
@@ -63,6 +68,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.llama import RMSNorm
 from ray_tpu.models.nemotron_h import Mamba2Dims, Mamba2Mixer, _dense
@@ -75,6 +81,8 @@ from ray_tpu.util import tracing
 _PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 # what a recomputed block keeps of its scan (the module docstring)
 _SCAN_KEEPS = (ssm.SCAN_OUT, ssm.SCAN_STATES)
+# the residual stream after the mixer, which the MLP's norm reads
+_MIXER_STREAM = "mixer_stream"
 
 
 @dataclass(frozen=True)
@@ -286,7 +294,7 @@ class Block(nn.Module):
             y = Mamba2Mixer(cfg, self.mesh, name="mamba")(h)
         else:
             y = Attention(cfg, self.mesh, name="attn")(h)
-        x = _add_scaled(x, y, m_r)
+        x = checkpoint_name(_add_scaled(x, y, m_r), _MIXER_STREAM)
         return _add_scaled(
             x, MLP(cfg, name="mlp")(_norm(cfg, "mlp_norm")(x)), m_r)
 
@@ -301,22 +309,38 @@ def _first_keeping_gate_up(cfg: GraniteHybridConfig) -> int:
     return 0
 
 
+def _first_keeping_in_proj(cfg: GraniteHybridConfig) -> int:
+    """The first of the layers whose policy lists the parts of the
+    mixer's ``in_proj`` product, decided as ``_first_keeping_gate_up``
+    is and against the same peak: every layer keeps them at 8,192
+    rows."""
+    return 0
+
+
 def _block_keeps(cfg: GraniteHybridConfig, i: int) -> tuple[str, ...]:
     """The names layer ``i``'s policy lists beside the attention
-    core's two: the scan's in every layer (a policy that lists a name
-    no value of the block carries keeps nothing for it), ``gate_up``'s
-    where memory allows."""
-    return ((MLP_GATE_UP,) if i >= _first_keeping_gate_up(cfg) else ()
-            ) + _SCAN_KEEPS
+    core's two: the stream after the mixer and the scan's in every
+    layer (a policy that lists a name no value of the block carries
+    keeps nothing for it), ``gate_up``'s and ``in_proj``'s where memory
+    allows."""
+    keeps = (_MIXER_STREAM, *_SCAN_KEEPS)
+    if i >= _first_keeping_in_proj(cfg):
+        keeps = ssm.IN_PROJ_PARTS + keeps
+    if i >= _first_keeping_gate_up(cfg):
+        keeps = (MLP_GATE_UP,) + keeps
+    return keeps
 
 
 def _keeps_note(cfg: GraniteHybridConfig) -> str:
     """``blocks_remat_keeps``: what ``_block_keeps`` gives the layers; a
     name that the layers from ``k`` > 0 on alone keep reads
     ``name[k:]``."""
-    first = _first_keeping_gate_up(cfg)
-    gate_up = MLP_GATE_UP if first == 0 else f"{MLP_GATE_UP}[{first}:]"
-    return ",".join(remat_keeps(gate_up, *_SCAN_KEEPS))
+    def since(first, *names):
+        return (f"{n}[{first}:]" if first else n for n in names)
+    return ",".join(remat_keeps(
+        *since(_first_keeping_gate_up(cfg), MLP_GATE_UP),
+        *since(_first_keeping_in_proj(cfg), *ssm.IN_PROJ_PARTS),
+        _MIXER_STREAM, *_SCAN_KEEPS))
 
 
 class Granite(nn.Module):

@@ -36,9 +36,10 @@ this file's (64 heads of 64 in **8 groups**, chunk **128**, hidden
 (granite-4.0-h-micro: 64 heads of 64 in **1 group**, so that the scan's
 kernels make a group's score square in each of its 8 head blocks and
 sum ``dB``, ``dC`` over them; chunk **256**; hidden 2,048; every block
-under ``nn.remat`` with the scan's two results kept by name,
-``ops/ssm.py::SCAN_OUT`` and ``SCAN_STATES``). The parameter tree, the
-scopes and the arithmetic are one; the names are the identity here.
+under ``nn.remat`` with the scan's two results and the three parts of
+``in_proj``'s product kept by name, ``ops/ssm.py::SCAN_OUT``,
+``SCAN_STATES`` and ``IN_PROJ_PARTS``). The parameter tree, the scopes
+and the arithmetic are one; the names are the identity here.
 """
 
 from __future__ import annotations
@@ -51,6 +52,7 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.llama import RMSNorm
 from ray_tpu.ops import ssm
@@ -269,7 +271,9 @@ class Mamba2Mixer(nn.Module):
                       cfg.ssm_state)
         inner = cfg.mamba_inner
         zxbcdt = _dense(cfg)(inner + cfg.conv_width + h, name="in_proj")(x)
-        z, xbc, dt = jnp.split(zxbcdt, [inner, inner + cfg.conv_width], -1)
+        # named for a recomputed block's policy; the identity outside one
+        z, xbc, dt = map(checkpoint_name, jnp.split(
+            zxbcdt, [inner, inner + cfg.conv_width], -1), ssm.IN_PROJ_PARTS)
         xbc = _Conv(cfg, self.mesh, name="conv")(xbc)
         xs, bs, cs = jnp.split(xbc, [inner, inner + g * n], -1)
         dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (h,),

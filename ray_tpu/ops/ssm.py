@@ -131,6 +131,9 @@ from ray_tpu.util import tracing
 _BOUNDARY = "ssm_boundary_states"
 # what a recomputed block keeps of the scan's kernels (``ssd_scan.py``)
 SCAN_OUT, SCAN_STATES = ssd_scan.SCAN_OUT, ssd_scan.SCAN_STATES
+# and of the mixer's ``in_proj``: the three parts its product is split
+# into (``z``, ``xBC``, ``dt``), which ``Mamba2Mixer`` names
+IN_PROJ_PARTS = ("mamba_z", "mamba_xbc", "mamba_dt")
 # what ``parallel/sharding.py`` maps the logical "batch" to
 _BATCH_AXES = ("dp", "fsdp")
 

@@ -7,6 +7,7 @@ Nemotron-H. What a recomputed block keeps, the step's notes and scopes
 are ``test_granite_remat.py``'s."""
 
 import dataclasses
+import functools
 import math
 import os
 import sys
@@ -199,6 +200,57 @@ def test_there_is_one_mamba2_mixer_and_both_configs_run_it():
     g = GraniteHybridConfig.tiny()
     ours = jax.eval_shape(Granite(g).init_params, jax.random.key(0))
     assert set(ours["h_0"]["mamba"]) == set(params["h_0"]["mamba"])
+
+
+def test_the_mixers_names_are_the_identity_for_nemotron(monkeypatch):
+    """``Mamba2Mixer`` names the three parts of ``in_proj``'s product
+    for a recomputed block's policy (``ops/ssm.py::IN_PROJ_PARTS``).
+    Nemotron's blocks are under no policy: with the names in place its
+    loss and every gradient leaf on one seed are bit for bit what they
+    are with the names taken out, its jaxpr differs by those ``name``
+    equations alone (no ``checkpoint`` more, and none whose policy could
+    read them), and the lowered program is the same text but for the
+    counters in its private functions' symbols (``@silu_105``: lowering
+    numbers them by the equations traced so far, a ``name`` among them,
+    and XLA inlines them)."""
+    import re
+    from conftest import equations
+    from ray_tpu.models import NemotronH, NemotronHConfig
+    from ray_tpu.models.nemotron_h import nemotron_h_loss_fn
+    from ray_tpu.ops import ssm
+    cfg = NemotronHConfig.tiny(**F32)
+    params = _jittered(NemotronH(cfg).init_params(jax.random.key(3)), 3,
+                       by=0.02)
+    batch = _batch(3, cfg)
+
+    def program():
+        fn = jax.value_and_grad(
+            nemotron_h_loss_fn(NemotronH(cfg), ce_chunk=16), has_aux=True)
+        eqns = list(equations(jax.make_jaxpr(fn)(params, batch).jaxpr))
+        return (eqns, jax.jit(fn).lower(params, batch).as_text(),
+                jax.jit(fn)(params, batch))
+
+    eqns, text, numbers = program()
+    named = [e.params["name"] for e in eqns if e.primitive.name == "name"]
+    layers = cfg.pattern.count("M")
+    assert layers and sorted(set(named) & set(ssm.IN_PROJ_PARTS)) == sorted(
+        ssm.IN_PROJ_PARTS)
+    monkeypatch.setattr(nemotron_h, "checkpoint_name", lambda x, _: x)
+    bare_eqns, bare_text, bare_numbers = program()
+    assert not {e.params["name"] for e in bare_eqns
+                if e.primitive.name == "name"} & set(ssm.IN_PROJ_PARTS)
+    assert [e.primitive.name for e in eqns if not (
+        e.primitive.name == "name"
+        and e.params["name"] in ssm.IN_PROJ_PARTS)] == [
+            e.primitive.name for e in bare_eqns]
+    policies = [e.params["policy"] for e in eqns
+                if e.primitive.name == "remat2" and e.params["policy"]]
+    assert len(policies) == layers      # the XLA scan's own, one a layer
+    unnumbered = functools.partial(re.sub, r"@(\w+?)_\d+\b", r"@\1")
+    assert unnumbered(text) == unnumbered(bare_text)
+    got, want = map(jax.tree_util.tree_leaves, (numbers, bare_numbers))
+    assert len(got) == len(want) > 20       # (a router's bias leaf is 0)
+    assert all(bool((a == b).all()) for a, b in zip(got, want))
 
 
 def test_nemotrons_loss_sows_nothing_and_granites_reports_the_scans_rms():

@@ -22,6 +22,10 @@ from ray_tpu.models.granite import granite_loss_fn
 from ray_tpu.ops import ssm
 from ray_tpu.util import tracing
 
+# ``blocks_remat_keeps`` with every name kept by every layer
+KEEPS_NOTE = ("mlp_gate_up,mamba_z,mamba_xbc,mamba_dt,mixer_stream,"
+              "ssd_scan_out,ssd_scan_states,attn_out,attn_lse")
+
 
 def test_the_step_reports_the_keys_and_notes_what_the_layers_are(
         monkeypatch):
@@ -47,16 +51,29 @@ def test_the_step_reports_the_keys_and_notes_what_the_layers_are(
     assert notes["ssm_blocks_per_group"] == 1      # the XLA path's einsum
     assert notes["gate_norm_path"] == "xla" and notes["conv_path"] == "xla"
     assert notes["blocks_remat"] is True
-    assert notes["blocks_remat_keeps"] == (
-        "mlp_gate_up,ssd_scan_out,ssd_scan_states,attn_out,attn_lse")
+    assert notes["blocks_remat_keeps"] == KEEPS_NOTE
 
 
-def test_the_keeps_note_says_from_which_layer_gate_up_is_kept(monkeypatch):
+@pytest.mark.parametrize("first_of, note, names", [
+    ("_first_keeping_gate_up", KEEPS_NOTE.replace(
+        "mlp_gate_up,", "mlp_gate_up[2:],"), ("mlp_gate_up",)),
+    ("_first_keeping_in_proj", KEEPS_NOTE.replace(
+        "mamba_z,mamba_xbc,mamba_dt",
+        "mamba_z[2:],mamba_xbc[2:],mamba_dt[2:]"), ssm.IN_PROJ_PARTS)],
+    ids=["gate_up", "in_proj"])
+def test_the_keeps_note_says_from_which_layer_a_name_is_kept(
+        monkeypatch, first_of, note, names):
+    """A name that memory gives to the layers from 2 on alone reads
+    ``name[2:]`` in the note and is listed by those layers' policies;
+    the stream after the mixer and the scan's two are every layer's."""
     cfg = GraniteHybridConfig.tiny(remat=True)
-    monkeypatch.setattr(model_file, "_first_keeping_gate_up", lambda cfg: 2)
-    assert model_file._keeps_note(cfg).startswith("mlp_gate_up[2:],ssd_")
-    assert model_file._block_keeps(cfg, 1) == (ssm.SCAN_OUT, ssm.SCAN_STATES)
-    assert model_file._block_keeps(cfg, 2)[0] == "mlp_gate_up"
+    assert model_file._keeps_note(cfg) == KEEPS_NOTE
+    every = set(model_file._block_keeps(cfg, 0))
+    assert every == set(KEEPS_NOTE.split(",")) - {"attn_out", "attn_lse"}
+    monkeypatch.setattr(model_file, first_of, lambda cfg: 2)
+    assert model_file._keeps_note(cfg) == note
+    assert set(model_file._block_keeps(cfg, 1)) == every - set(names)
+    assert set(model_file._block_keeps(cfg, 2)) == every
 
 
 @pytest.mark.parametrize("remat", [True, False],
@@ -127,6 +144,44 @@ def test_a_recomputed_block_runs_its_gate_up_matmul_once(monkeypatch):
     assert (forwards(False), forwards(True)) == (n, n)
     monkeypatch.setattr(model_file, "_first_keeping_gate_up", lambda cfg: n)
     assert forwards(True) == 2 * n
+
+
+def _off_the_policy(monkeypatch, what):
+    """Take ``in_proj``'s names, or the stream's, off every layer's
+    policy."""
+    if what == "in_proj":
+        monkeypatch.setattr(model_file, "_first_keeping_in_proj",
+                            lambda cfg: cfg.n_layer)
+    else:       # un-named: the policy's name is on no value
+        monkeypatch.setattr(model_file, "checkpoint_name", lambda x, _: x)
+
+
+@pytest.mark.parametrize("off, rows_by, by, whole, again", [
+    ("in_proj", 64, 128 + 160 + 16, 3, 3),
+    ("stream", 128, 64, 3, 3),
+    ("stream", 64, 64, 2, 1)],
+    ids=["mamba_in_proj", "mamba_out_proj", "attention_o"])
+def test_a_recomputed_block_runs_its_mixers_projections_once(
+        monkeypatch, off, rows_by, by, whole, again):
+    """The forward matmuls of one shape in the gradient's jaxpr (three
+    Mamba layers and one attention layer, whose ``q`` and ``o`` are both
+    ``[64, 64]``): ``in_proj``'s and ``out_proj``'s are as many with
+    ``remat`` as without, and ``o``'s with ``q``'s one more (``q`` is
+    projected again, ``o`` is not); with ``in_proj``'s names off the
+    policy, or the stream after the mixer not kept, ``again`` more."""
+    def forwards(remat):
+        cfg = GraniteHybridConfig.tiny(remat=remat, mlp_width=48, **F32)
+        model = Granite(cfg)
+        params = jax.eval_shape(model.init_params, jax.random.key(0))
+        traced = jax.make_jaxpr(jax.value_and_grad(
+            granite_loss_fn(model, ce_chunk=16), has_aux=True))(
+                params, _batch(0, cfg))
+        return matmuls(traced, ((2, cfg.seq_len, rows_by), (rows_by, by)))
+
+    q_again = 1 if (rows_by, by) == (64, 64) else 0
+    assert (forwards(False), forwards(True)) == (whole, whole + q_again)
+    _off_the_policy(monkeypatch, off)
+    assert forwards(True) == whole + q_again + again
 
 
 # the scan's kernels, interpreted: 16 heads of 16 in one group (two head
