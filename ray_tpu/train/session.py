@@ -89,6 +89,10 @@ class _TrainSession:
         # ``train.input.first_batch``, recorded by the worker when the
         # loop returns.
         self.first_batches: list[tuple] = []
+        # This process's devices, once the worker has opened the backend
+        # for the loop (``worker_group.py::_open_backend``): the stall
+        # watch samples their memory, and opens no backend to do so.
+        self.devices: list = []
         from ray_tpu.util.metrics import Counter, Histogram
         tags = {"rank": str(context.world_rank)}
         self._m_step_time = Histogram(

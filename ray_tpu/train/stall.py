@@ -21,8 +21,16 @@ interval went to (docs/observability.md, "Reading a straggler"):
 - where the loop's thread stood while the interval was open and
   already too long (:func:`where_of`).
 
+The same thread takes the fit's few samples of device memory, which
+``stop()`` hands to ``train.worker.loop`` with the stalls' totals
+(docs/observability.md, "Reading a step that does not fit"): at the
+first beat after the loop's first report and after its eighth
+(``HBM_AT_REPORTS``), and once at the end. The loop's thread and
+``session.report()`` run no line for it.
+
 The rule, the cause and the readers of ``/proc`` are pure functions of
-numbers handed in: ``tests/test_train_stall.py`` holds them by table.
+numbers handed in: ``tests/test_train_stall.py`` holds them by table,
+``tests/test_train_memory.py`` the samples.
 """
 
 from __future__ import annotations
@@ -53,6 +61,14 @@ FLOOR_S = 0.1           # a stall exceeds the median by this and by half
 MAX_SPANS = 256         # train.stall spans (and warnings) kept a loop
 SILENT_S = 60.0         # an open interval this long (and ten medians)
 SILENT_MEDIANS = 10     # gets one warning with the loop's stack
+STOP_WAIT_S = 5.0       # ``stop()`` waits this long for the thread
+# Device memory is sampled once this many reports have come: at the first
+# the step's executable is loaded and its arguments are live, by the
+# eighth donated outputs have come back and every relayout has compiled.
+HBM_AT_REPORTS = (1, 8)
+# The allocator's keys that are read, where the backend gives them.
+HBM_KEYS = ("bytes_limit", "bytes_in_use", "peak_bytes_in_use",
+            "bytes_reserved", "peak_bytes_reserved", "largest_alloc_size")
 
 # The program's own causes, in the order a tie is settled.
 NAMED = (("gc", "gc_s"), ("compile", "compile_s"),
@@ -178,6 +194,38 @@ def kernel_sample(tid: int, proc: str = "/proc") -> dict:
     return got
 
 
+# -- what the device's allocator counts -----------------------------------
+
+def hbm_sample(devices) -> list[dict]:
+    """``memory_stats()`` of each of the process's devices, cut to
+    ``HBM_KEYS``, with the device's ``id``. A backend that keeps no such
+    counters (the CPU's ``memory_stats()`` is None) yields nothing for
+    that device, and a key it does not give is left out: no made-up 0.
+    Nothing raises, and nothing here opens a backend: the devices are
+    those ``worker_group.py::_open_backend`` got."""
+    out = []
+    for device in devices:
+        try:
+            stats = device.memory_stats()
+        except Exception:  # noqa: BLE001 — a backend without the call
+            stats = None
+        if stats:
+            out.append({"id": device.id,
+                        **{k: stats[k] for k in HBM_KEYS if k in stats}})
+    return out
+
+
+def hbm_held(sample: dict) -> int | None:
+    """What one device holds for the job: the bytes in use plus the
+    bytes the allocator has reserved (on a TPU, the loaded programs'
+    temporaries, which ``bytes_in_use`` and its peak do not count; the
+    bytes in use alone where the backend gives no ``bytes_reserved``).
+    None where it does not give ``bytes_in_use``: no made-up 0."""
+    if "bytes_in_use" not in sample:
+        return None
+    return sample["bytes_in_use"] + sample.get("bytes_reserved", 0)
+
+
 # -- where the loop was ---------------------------------------------------
 
 def where_of(frame) -> dict:
@@ -219,7 +267,9 @@ class StallWatch:
     for that span: ``stalls``, ``stalled_s``, ``stall_frozen_s`` of the
     stalled intervals, and ``frozen_s``, every second of the loop's
     life in which no thread of the process ran (before the first
-    report too: a backend's opening that stops the machine)."""
+    report too: a backend's opening that stops the machine); and,
+    where the session has devices whose allocator counts, the ``hbm_*``
+    of :meth:`_hbm_totals`."""
 
     def __init__(self, session, parent: tuple[str, str],
                  input_totals: Callable[[], dict]):
@@ -242,6 +292,11 @@ class StallWatch:
         # ``frozen_s``: over the loop's whole life, set-up included
         self.totals = {"stalls": 0, "stalled_s": 0.0, "stall_frozen_s": 0.0,
                        "frozen_s": 0.0}
+        self._reports = 0       # reports read so far, after the first
+        self._hbm_at = list(HBM_AT_REPORTS)     # sample points to come
+        self._hbm_samples = 0
+        self._hbm_held: dict[int, int] = {}     # device id -> most held
+        self._hbm_last: list[dict] = []         # the newest sample
         gc.callbacks.append(self._on_gc)
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="train_stall_watch")
@@ -249,12 +304,16 @@ class StallWatch:
 
     def stop(self) -> dict:
         self._halt.set()
-        self._thread.join(timeout=5.0)
+        self._thread.join(timeout=STOP_WAIT_S)
         gc.callbacks.remove(self._on_gc)
         if not self._thread.is_alive():     # what came since its last beat
             self._read_reports(time.process_time())
             self._close_open()
-        return dict(self.totals)
+            # not beside a thread that may be held inside a sample of
+            # its own (a runtime that does not answer): the loop's error
+            # is then told without the end's sample
+            self._sample_hbm()
+        return {**self.totals, **self._hbm_totals()}
 
     def _on_gc(self, phase: str, _info: dict) -> None:
         if phase == "start":
@@ -282,6 +341,7 @@ class StallWatch:
                         (late,), due, now)
                 beat, cpu = now, cpu_now
                 self._read_reports(cpu_now)
+                self._sample_hbm_at_reports()
                 self._look_at_open(now, on_time)
                 if now - sampled >= KERNEL_EVERY_S:
                     self._kernel, sampled = kernel_sample(self._tid), now
@@ -290,6 +350,49 @@ class StallWatch:
         finally:
             self._end_annotation(self._open)    # in the thread that began it
 
+    def _sample_hbm(self) -> None:
+        """One sample of the devices ``_open_backend`` left on the
+        session (none: no call is made), kept as the newest and in each
+        device's most held. A device that does not say its bytes in use
+        is left out of it."""
+        sample = hbm_sample(self._session.devices)
+        held = {s["id"]: h for s in sample if (h := hbm_held(s)) is not None}
+        if held:
+            self._hbm_samples += 1
+            self._hbm_last = sample
+            for i, h in held.items():
+                self._hbm_held[i] = max(self._hbm_held.get(i, 0), h)
+
+    def _sample_hbm_at_reports(self) -> None:
+        # the first report appends nothing to ``session.reports``
+        reports = self._reports + (self._session.t_first_report is not None)
+        if self._hbm_at and reports >= self._hbm_at[0]:
+            self._hbm_at = [n for n in self._hbm_at if n > reports]
+            self._sample_hbm()
+
+    def _hbm_totals(self) -> dict:
+        """For ``train.worker.loop``: ``hbm_held_bytes``, the most any
+        device held at any sample; that device's high-water marks at the
+        last sample, each where the allocator gives the key;
+        ``hbm_held_by_device``, the most each held by its id, where there
+        is more than one; ``hbm_samples``. Nothing where no sample found
+        a counter."""
+        held = dict(sorted(self._hbm_held.items()))
+        if not held:
+            return {}
+        fullest = max(held, key=held.get)
+        last = next((s for s in self._hbm_last if s["id"] == fullest), {})
+        told = {"hbm_held_bytes": held[fullest],
+                **{name: last[key] for name, key in (
+                    ("hbm_peak_in_use_bytes", "peak_bytes_in_use"),
+                    ("hbm_peak_reserved_bytes", "peak_bytes_reserved"),
+                    ("hbm_largest_alloc_bytes", "largest_alloc_size"))
+                   if key in last},
+                "hbm_samples": self._hbm_samples}
+        if len(held) > 1:    # keys as a trace's JSON keeps them
+            told["hbm_held_by_device"] = {str(i): b for i, b in held.items()}
+        return told
+
     def _read_reports(self, cpu_s: float) -> None:
         reports = self._session.reports
         if not reports:
@@ -297,6 +400,7 @@ class StallWatch:
         counters = self._counters(cpu_s)
         while reports:
             step, t, interval, report_s = reports.popleft()
+            self._reports += 1
             seen = self._close_open()
             # no median is taken for an interval that cannot be a stall
             held = stall_limit(self._history) if interval > FLOOR_S else None

@@ -21,7 +21,7 @@ from ray_tpu.core.placement_group import (
     PlacementGroupSchedulingStrategy,
 )
 from ray_tpu.train.prefetch import collect_counters
-from ray_tpu.train.stall import StallWatch
+from ray_tpu.train.stall import StallWatch, hbm_sample
 from ray_tpu.util import tracing
 
 
@@ -139,13 +139,14 @@ class TrainWorker:
                                            **target) as span:
                     # beside the loop for as long as it runs: the
                     # stalled steps, each a ``train.stall`` under this
-                    # span, and their totals on it
+                    # span, their totals on it, and what the devices'
+                    # allocators held (``hbm_*``)
                     watch = StallWatch(
                         session, (span.trace_id, span.span_id),
                         input_totals)
                     try:
                         if open_backend:
-                            _open_backend(session.spans)
+                            session.devices = _open_backend(session.spans)
                         if _takes_config(fn):
                             fn(loop_config or {})
                         else:
@@ -240,7 +241,7 @@ def _routable_ip() -> str:
         return "127.0.0.1"
 
 
-def _open_backend(spans: list) -> None:
+def _open_backend(spans: list) -> list:
     """Open the accelerator backend (6-15 s on a TPU) in the loop's
     thread, ahead of the user's loop: its first ``jax.devices()`` is
     then a lookup, and the seconds are the program's own span (under
@@ -251,7 +252,15 @@ def _open_backend(spans: list) -> None:
     open (a worker pinned to a platform that is not there) leaves
     ``error`` on the span and nothing else: the loop's own first use
     of jax raises as it always did, and a loop that never touches jax
-    runs."""
+    runs.
+
+    Returns this process's devices (none where the backend did not
+    open), whose memory the stall watch samples. Where their allocator
+    counts, the span says how much there is and what was on the chip
+    before this job put anything there: ``hbm_limit_bytes`` (the
+    smallest ``bytes_limit``), ``hbm_in_use_at_open_bytes`` (the
+    largest ``bytes_in_use``: a warm process's previous fit, another
+    tenant)."""
     try:
         with tracing.train_span("train.worker.backend_init",
                                 sink=spans) as span:
@@ -260,8 +269,17 @@ def _open_backend(spans: list) -> None:
             span.attributes.update(platform=devices[0].platform,
                                    device_kind=devices[0].device_kind,
                                    devices=len(devices))
+            local = jax.local_devices()
+            sample = hbm_sample(local)
+            for name, key, of in (
+                    ("hbm_limit_bytes", "bytes_limit", min),
+                    ("hbm_in_use_at_open_bytes", "bytes_in_use", max)):
+                given = [s[key] for s in sample if key in s]
+                if given:
+                    span.attributes[name] = of(given)
+            return local
     except RuntimeError:
-        pass
+        return []
 
 
 def _takes_config(fn: Callable) -> bool:

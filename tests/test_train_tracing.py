@@ -377,6 +377,17 @@ def test_a_backend_that_does_not_open_is_left_to_the_users_loop(
     assert opened.attributes == {"error": "RuntimeError"}
 
 
+@pytest.mark.parametrize("name", ["train.worker.backend_init",
+                                  "train.worker.loop"])
+def test_a_backend_without_memory_counters_leaves_no_hbm_attribute(
+        fit, name):
+    """The CPU's ``memory_stats()`` is None: the two spans that carry
+    the allocator's counters on a TPU (tests/test_train_memory.py) say
+    nothing of memory, and no key holds a made-up 0."""
+    span = next(s for s in _own(fit) if s["name"] == name)
+    assert not [k for k in span["attributes"] if k.startswith("hbm_")]
+
+
 def test_first_batch_span_holds_that_batchs_times(fit):
     by_name = {s["name"]: s for s in _own(fit)}
     first = by_name["train.input.first_batch"]
