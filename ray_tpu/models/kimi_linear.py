@@ -55,8 +55,11 @@ states, so there the recurrence runs as before (the pass, ``_kda_core``'s
 recomputation, and a group's inside ``kda_scan``). Of the latent layer
 the block keeps its core's output and row statistics
 (``ops/attention.py::remat_policy``), so that the latent flash forward
-kernel runs once (the note ``blocks_remat_keeps`` lists all five
-names). **The output gate**
+kernel runs once, and of a routed layer its router's float32 product,
+choice, chosen scores and counts (``ops/moe.py::ROUTER_KEEPS``, 17 MB a
+layer), so that the product at the highest precision, ``top_k``, the
+gather and the counts' scatter-add run once (the note
+``blocks_remat_keeps`` lists all nine names). **The output gate**
 (``ops/ssm.py::sigmoid_gated_head_rms_norm``, handed the mixer's mesh;
 the note ``kda_gate_path``) on its kernels (``pallas``: a TPU, heads of
 whole 128-lane tiles, one device or a mesh that shards the batch alone)
@@ -104,7 +107,7 @@ from ray_tpu.models.nemotron_h import _a_log_init, _conv_init, _dt_bias_init
 from ray_tpu.ops import kda, ssm
 from ray_tpu.ops.attention import remat_keeps, remat_policy
 from ray_tpu.ops.mla import UpProjections, latent_attention
-from ray_tpu.ops.moe import held_route_share
+from ray_tpu.ops.moe import ROUTER_KEEPS, held_route_share
 from ray_tpu.util import tracing
 
 
@@ -369,8 +372,8 @@ class KimiLinear(nn.Module):
             mla_ranks=[None, cfg.kv_rank],
             mla_qk_dims=[cfg.nope_dim, cfg.rope_dim], mla_v_dim=cfg.v_dim,
             dense_layers=cfg.dense_layers, blocks_remat=cfg.remat,
-            blocks_remat_keeps=",".join(remat_keeps(*_KDA_KEEPS))
-            if cfg.remat else "")
+            blocks_remat_keeps=",".join(
+                remat_keeps(*_KDA_KEEPS, *ROUTER_KEEPS)) if cfg.remat else "")
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
@@ -380,9 +383,10 @@ class KimiLinear(nn.Module):
         # (134 MB a layer at 16,384 rows) and the recurrence's ``o`` and
         # chunk-entering states (268 + 537 MB), and of its latent layer
         # the core's output and row statistics (0.14 GB): neither
-        # forward kernel runs again (the module docstring has how)
-        block = (nn.remat(Block, policy=remat_policy(*_KDA_KEEPS))
-                 if cfg.remat else Block)
+        # forward kernel runs again (the module docstring has how); and
+        # of a routed layer the router's product and choice (17 MB)
+        block = (nn.remat(Block, policy=remat_policy(
+            *_KDA_KEEPS, *ROUTER_KEEPS)) if cfg.remat else Block)
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):
                 x = self._constrain(

@@ -43,13 +43,44 @@ the other window/global stack:
   token.
 
 With ``remat`` each block is recomputed in the backward pass
-(``nn.remat``, as ``models/kimi_linear.py``): the step at 16,384 rows
-would keep q at 8,192 lanes, eight copies of K and V and a 32,768-row
-slab of sorted routes a layer. One thing a recomputed block does keep:
-its attention core's output and row statistics
-(``ops/attention.py::remat_policy``; 0.20 GB a full layer, 0.27 GB a
-sliding one), so the flash forward kernel runs once a layer and not
-again in the backward pass; the note ``blocks_remat_keeps`` names them.
+(``nn.remat``, as ``models/kimi_linear.py``): kept whole, the step at
+16,384 rows would hold eight copies of K and V, the gated cores and a
+32,768-row slab of sorted routes a layer beside everything below.
+What a recomputed block does keep, by name (``_BLOCK_KEEPS`` and
+``ops/attention.py::remat_policy``; the note ``blocks_remat_keeps``
+lists them), is every matmul's product its backward pass reads and the
+flash forward's results, so that no matmul of a block and no forward
+kernel runs twice (bytes a layer at 16,384 rows):
+
+- its core's output and row statistics (0.20 GB a full layer, 0.27 a
+  sliding one);
+- a routed layer's router: the float32 product ``x W_r`` in front of
+  the sigmoid, the chosen ``experts``, their scores and the routes each
+  expert received (``ops/moe.py::ROUTER_KEEPS``, 17 MB): the second
+  pass makes the sigmoid again from the kept product and neither the
+  product at the highest precision, ``top_k``, the gather nor the
+  scatter-add of the counts;
+- ``W_o``'s product (67 MB): the stream between the two sub-layers is
+  then one add;
+- q, k and v (0.20 | 0.27 GB and 2 x 34 MB), so that the three
+  products run once. **A full layer keeps q and k as the rotation left
+  them**: the rotation's backward is linear in the cotangent and reads
+  the angles alone, so the float32 rotation is not made again either.
+  **A sliding layer keeps them as the products left them** and rotates
+  again (3.0 ms a layer): with nothing of the rotation left in its
+  backward pass, XLA lays that layer's backward rotation out with a
+  half head's 64 lanes minor, half of every 128-lane tile empty, and
+  it takes 8.7 ms where it took 4.1 (PERF.md section 6, PR 66). The
+  copies of K and V (``repeat``) are made again: they are what
+  GQA-native kernels would spare, not a name;
+- the ``gate`` and ``up`` products of layer 0's dense MLP (2 x 0.27
+  GB) and of each shared expert (2 x 17 MB), which ``models/llama.py::
+  SwiGLU`` names.
+
+The head gate's product (2048 -> 48 | 64, 0.1 ms a layer) and its
+multiply, whose output would be as large as q, are made again. The
+list is every layer's: the compiled step asks for 13.75 GB of the
+chip's 16.91 with it (``tests/test_tpu_compile_laguna.py``).
 
 It is the benchmark's eighth language model
 (``laguna-xs.2.b1-t16384`` runs layers 0-4, ``F S S S F``, with one
@@ -75,16 +106,27 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.joyai import MoE, _dense, _norm, _swiglu
 from ray_tpu.models.llama import apply_rope_half, rope_freqs, yarn_freqs
 from ray_tpu.ops.attention import (
-    causal_attention, remat_keeps, remat_policy)
-from ray_tpu.ops.moe import held_route_share
+    MLP_GATE, MLP_UP, causal_attention, remat_keeps, remat_policy)
+from ray_tpu.ops.moe import ROUTER_KEEPS, held_route_share
 from ray_tpu.util import tracing
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
+
+# ``Attention``'s own names: q and k (as the rotation left them in a
+# full layer, as the products left them in a sliding one), v, and
+# ``W_o``'s product
+_ATTN_Q, _ATTN_K, _ATTN_V = "attn_q", "attn_k", "attn_v"
+_ATTN_PROJ = "attn_out_proj"
+# what a recomputed block keeps beside its core's output and row
+# statistics, dearest millisecond a byte first (the module docstring)
+_BLOCK_KEEPS = (*ROUTER_KEEPS, _ATTN_PROJ, _ATTN_Q, _ATTN_K, _ATTN_V,
+                MLP_GATE, MLP_UP)
 
 
 @dataclass(frozen=True)
@@ -254,10 +296,19 @@ class Attention(nn.Module):
             v = _dense(cfg)(cfg.n_kv_head * hd, name="v")(h)
         q = q.reshape(b, t, heads, hd)
         k = k.reshape(b, t, cfg.n_kv_head, hd)
-        v = v.reshape(b, t, cfg.n_kv_head, hd)
+        v = checkpoint_name(v.reshape(b, t, cfg.n_kv_head, hd), _ATTN_V)
+        if self.sliding:
+            # named in front of the rotation (the module docstring)
+            q, k = checkpoint_name(q, _ATTN_Q), checkpoint_name(k, _ATTN_K)
         with jax.named_scope("rope"):
             q = _rotate(q, angles[:t], amplitude)
             k = _rotate(k, angles[:t], amplitude)
+        if not self.sliding:
+            # named behind the rotation: its backward is linear in the
+            # cotangent and reads the angles alone, so a block that
+            # keeps these makes neither the products nor the float32
+            # rotation again
+            q, k = checkpoint_name(q, _ATTN_Q), checkpoint_name(k, _ATTN_K)
         rep = heads // cfg.n_kv_head
         if rep > 1:
             # The equal-width kernels want as many key/value heads as
@@ -282,8 +333,8 @@ class Attention(nn.Module):
                 _dense(cfg)(heads, name="g")(h).astype(jnp.float32))
             o = o * g[..., None].astype(o.dtype)
         with jax.named_scope("out"):
-            return _dense(cfg)(cfg.n_embd, name="out")(
-                o.reshape(b, t, heads * hd))
+            return checkpoint_name(_dense(cfg)(cfg.n_embd, name="out")(
+                o.reshape(b, t, heads * hd)), _ATTN_PROJ)
 
 
 def _rotate(x, angles, amplitude: float):
@@ -362,17 +413,19 @@ class Laguna(nn.Module):
             rope_attention_factor=tables[False][1],
             dense_layers=cfg.n_layer - len(cfg.routed_layers),
             blocks_remat=cfg.remat,
-            blocks_remat_keeps=",".join(remat_keeps()) if cfg.remat else "")
+            blocks_remat_keeps=",".join(remat_keeps(*_BLOCK_KEEPS))
+            if cfg.remat else "")
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
         with jax.named_scope("embed"):
             x = self._constrain(wte(tokens))
-        # a recomputed block keeps its core's output and row statistics
-        # (0.2-0.27 GB a layer at 16,384 rows): the flash forward kernel
-        # runs once a layer, not twice. Static: the amplitude is a
-        # number of the config, not of the trace
-        block = (nn.remat(Block, static_argnums=(3,), policy=remat_policy())
+        # a recomputed block keeps ``_BLOCK_KEEPS`` and its core's output
+        # and row statistics: no matmul of it and no flash forward
+        # kernel runs twice (the module docstring). Static: the
+        # amplitude is a number of the config, not of the trace
+        block = (nn.remat(Block, static_argnums=(3,),
+                          policy=remat_policy(*_BLOCK_KEEPS))
                  if cfg.remat else Block)
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):

@@ -63,6 +63,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.pallas import route_rows
 from ray_tpu.util import tracing
@@ -260,6 +261,27 @@ def _route(x, router_w, top_k: int, norm_topk_prob: bool):
     return weights, experts, probs.sum(axis=0), jnp.sum(lse * lse)
 
 
+# The names of what the sigmoid router makes of a layer's ``[T, d]``
+# tokens, for a recomputed block's policy (``ops/attention.py::
+# remat_policy``; the identity under any other, and outside one): the
+# product ``x W``, float32 ``[T, E]``, so that the float32 matmul at the
+# highest precision runs once (in front of the sigmoid, not behind it:
+# a policy keeps a name's result, and the sigmoid's backward reads the
+# sigmoid's own, so a name behind it would be kept and never read; the
+# second pass makes the sigmoid again from the kept product, one fused
+# pass); the chosen ``experts`` and their scores ``[T, k]``, so that
+# neither ``top_k`` nor the gather runs again; and the routes each
+# expert received, ``[E]`` (``_routed_ffn_local``, any router's), a
+# scatter-add of ``T k`` ones that the grouped matmuls' sizes would
+# otherwise be made from a second time.
+ROUTER_LOGITS = "moe_router_logits"
+ROUTER_EXPERTS = "moe_router_experts"
+ROUTER_WEIGHTS = "moe_router_weights"
+ROUTER_COUNTS = "moe_router_counts"
+ROUTER_KEEPS = (ROUTER_LOGITS, ROUTER_EXPERTS, ROUTER_WEIGHTS,
+                ROUTER_COUNTS)
+
+
 def _route_sigmoid(x, router_w, select_bias, top_k: int,
                    norm_topk_prob: bool, route_scale: float):
     """The float32 sigmoid router (DeepSeek-V3's, Nemotron-H's): every
@@ -268,13 +290,17 @@ def _route_sigmoid(x, router_w, select_bias, top_k: int,
     the choice and carries no gradient), their weights are ``s``
     **without** it, divided by their sum (+1e-20) under
     ``norm_topk_prob``, times ``route_scale``. No auxiliary loss
-    belongs to it: the last two returns are zeros."""
-    scores = jax.nn.sigmoid(jnp.dot(
+    belongs to it: the last two returns are zeros. The product, the
+    choice and the chosen scores carry ``ROUTER_KEEPS``'s names, for a
+    recomputed block whose policy lists them."""
+    scores = jax.nn.sigmoid(checkpoint_name(jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
+        precision=lax.Precision.HIGHEST), ROUTER_LOGITS))
     _, experts = lax.top_k(
         scores + lax.stop_gradient(select_bias.astype(jnp.float32)), top_k)
-    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    experts = checkpoint_name(experts, ROUTER_EXPERTS)
+    weights = checkpoint_name(
+        jnp.take_along_axis(scores, experts, axis=-1), ROUTER_WEIGHTS)
     if norm_topk_prob:
         weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
     return (weights * route_scale, experts,
@@ -464,7 +490,8 @@ def _routed_ffn_local(x, route_args, w_gate, w_up, w_down, *, route,
     with jax.named_scope("router"):
         weights, experts, prob_sum, z_sum = route(x, *route_args)
         flat = experts.reshape(-1)
-        counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        counts = checkpoint_name(
+            jnp.zeros((e,), jnp.int32).at[flat].add(1), ROUTER_COUNTS)
         total = (counts.astype(jnp.float32), prob_sum, z_sum,
                  jnp.float32(t))
         if over:

@@ -222,6 +222,13 @@ def matmuls(traced, *shapes) -> int:
         for e in equations(traced.jaxpr) if e.primitive.name == "dot_general")
 
 
+def primitives(traced, name: str) -> int:
+    """How many equations of a traced function (``make_jaxpr``'s), the
+    jaxprs its equations hold among them, are the primitive ``name``
+    (``top_k``): how often a recomputing program makes a choice."""
+    return sum(e.primitive.name == name for e in equations(traced.jaxpr))
+
+
 def same_bits(got, want) -> bool:
     """Are two trees' leaves the same bits, leaf for leaf, with something
     in every leaf of ``want``? For a recomputing program's loss and

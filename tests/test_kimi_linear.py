@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from conftest import live_kernel_calls
+from conftest import live_kernel_calls, matmuls, primitives
 
 from ray_tpu import train
 from ray_tpu.models import KimiLinear, KimiLinearConfig
@@ -465,17 +465,19 @@ def test_a_mixer_hands_its_mesh_to_the_one_output_gate(monkeypatch, axes):
 
 
 @pytest.mark.parametrize("remat, keeps", [
-    (True, "kda_gated_out,kda_scan_out,kda_scan_states,attn_out,attn_lse"),
+    (True, "kda_gated_out,kda_scan_out,kda_scan_states,moe_router_logits,"
+     "moe_router_experts,moe_router_weights,moe_router_counts,attn_out,"
+     "attn_lse"),
     (False, "")],
     ids=["recomputed", "kept_whole"])
 def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
                                                        monkeypatch):
     """``blocks_remat_keeps`` beside ``blocks_remat``: the names a
     recomputed block's policy keeps (the KDA mixers' gated output and
-    their recurrence's two named results first, then the latent core's
-    output and row statistics), and every
-    block's checkpoint carries a policy; nothing where the blocks are
-    not recomputed."""
+    their recurrence's two named results first, then the routers'
+    product and choice, then the latent core's output and row
+    statistics), and every block's checkpoint carries a policy; nothing
+    where the blocks are not recomputed."""
     cfg = KimiLinearConfig.tiny(remat=remat, **F32)
     model = KimiLinear(cfg)
     params = jax.eval_shape(model.init_params, jax.random.key(0))
@@ -489,6 +491,31 @@ def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
     with_policy = [e for e in traced.jaxpr.eqns
                    if e.primitive.name == "remat2" and e.params["policy"]]
     assert len(with_policy) == (cfg.n_layer if remat else 0)
+
+
+@pytest.mark.parametrize("listed", [True, False],
+                         ids=["kept", "off_the_policy"])
+def test_a_recomputed_block_routes_once(monkeypatch, listed):
+    """The routers' float32 product (``[128, 64] x [64, 16]``, the four
+    routed layers) and ``top_k`` in the gradient's jaxpr: as often with
+    ``remat`` as in the stack kept whole; with the routers' names off
+    the policy, twice."""
+    from ray_tpu.models import kimi_linear
+    if not listed:
+        monkeypatch.setattr(kimi_linear, "ROUTER_KEEPS", ())
+
+    def routes(remat):
+        cfg = KimiLinearConfig.tiny(remat=remat, **F32)
+        model = KimiLinear(cfg)
+        params = jax.eval_shape(model.init_params, jax.random.key(0))
+        traced = jax.make_jaxpr(jax.value_and_grad(
+            kimi_linear_loss_fn(model, ce_chunk=32), has_aux=True))(
+                params, _batch(0, cfg))
+        return (matmuls(traced, ((128, 64), (64, 16))),
+                primitives(traced, "top_k"))
+
+    assert routes(False) == (4, 4)
+    assert routes(True) == ((4, 4) if listed else (8, 8))
 
 
 def _at_the_kernels_widths(remat):

@@ -3,6 +3,7 @@ plain reference on seeded random weights, the per-layer lists (kind, head
 count, MLP), the two position tables, the gate, and the shares of the
 experts against the uncut layer."""
 
+import functools
 import math
 import os
 import sys
@@ -12,9 +13,11 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import matmuls, primitives
 
 from ray_tpu import train
 from ray_tpu.models import Laguna, LagunaConfig
+from ray_tpu.models import laguna as model_file
 from ray_tpu.models.laguna import Attention, Block, MoE, laguna_loss_fn
 from ray_tpu.models.llama import rope_freqs, yarn_freqs
 from ray_tpu.util import tracing
@@ -443,15 +446,22 @@ def test_a_train_step_runs_and_reports_the_load_of_every_routed_layer():
     assert float(metrics["lm_loss"]) == float(metrics["loss"])
 
 
+# ``blocks_remat_keeps``: the routers' four, ``W_o``'s product, q, k and
+# v, the dense and shared MLPs' two, the cores' two
+KEEPS_NOTE = ("moe_router_logits,moe_router_experts,moe_router_weights,"
+              "moe_router_counts,attn_out_proj,attn_q,attn_k,attn_v,"
+              "mlp_gate,mlp_up,attn_out,attn_lse")
+
+
 @pytest.mark.parametrize("remat, keeps", [
-    (True, "attn_out,attn_lse"), (False, "")],
-    ids=["recomputed", "kept_whole"])
+    (True, KEEPS_NOTE), (False, "")], ids=["recomputed", "kept_whole"])
 def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
                                                        monkeypatch):
     """``blocks_remat_keeps`` beside ``blocks_remat``: the names a
-    recomputed block's policy keeps (the attention cores' output and row
-    statistics), and every block's checkpoint carries a policy; nothing
-    where the blocks are not recomputed."""
+    recomputed block's policy keeps (``_BLOCK_KEEPS``, then the
+    attention cores' output and row statistics), and every block's
+    checkpoint carries a policy; nothing where the blocks are not
+    recomputed."""
     cfg = LagunaConfig.tiny(remat=remat, **F32)
     model = Laguna(cfg)
     params = jax.eval_shape(model.init_params, jax.random.key(0))
@@ -465,6 +475,92 @@ def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
     with_policy = [e for e in traced.jaxpr.eqns
                    if e.primitive.name == "remat2" and e.params["policy"]]
     assert len(with_policy) == (cfg.n_layer if remat else 0)
+
+
+def test_a_recomputed_stack_gives_the_numbers_of_the_one_kept_whole():
+    """Loss, report and every gradient leaf with ``remat`` against
+    without, on the same parameters in float32, each one jitted program:
+    a kept array is the value the second pass would have made again, and
+    two programs may fuse their sums in another order (1e-6 of a leaf's
+    largest entry). The routes are the same routes."""
+    got = {}
+    for remat in (False, True):
+        cfg = LagunaConfig.tiny(remat=remat, **F32)
+        model = Laguna(cfg)
+        params = _jittered(model.init_params(jax.random.key(5)), 5)
+        got[remat] = jax.jit(jax.value_and_grad(
+            laguna_loss_fn(model, ce_chunk=32), has_aux=True))(
+                params, _batch(5, cfg))
+    (want, want_report), want_grads = got[False]
+    (loss, report), grads = got[True]
+    assert float(loss) == pytest.approx(float(want), rel=1e-6)
+    np.testing.assert_array_equal(report["moe_load"],
+                                  want_report["moe_load"])
+    assert float(report["attn_window_out_rms"]) == pytest.approx(
+        float(want_report["attn_window_out_rms"]), rel=1e-6)
+    want_leaves = dict(_leaves_with_names(want_grads))
+    assert len(want_leaves) > 40
+    for name, leaf in _leaves_with_names(grads):
+        scale = max(float(np.abs(want_leaves[name]).max()), 1e-3)
+        np.testing.assert_allclose(leaf, want_leaves[name],
+                                   atol=1e-6 * scale, err_msg=name)
+
+
+@functools.cache
+def _traced_gradient(remat, without=()):
+    """The tiny model's loss and gradient, traced (once a case), with
+    the names ``without`` taken off the blocks' policy: ``F S S S F`` at
+    6 and 8 heads of 16 over 2 key/value heads, layer 0's MLP dense at
+    160, four routed layers with a shared expert at 48 (so that its
+    products have another shape than k's and v's)."""
+    cfg = LagunaConfig.tiny(remat=remat, shared_width=48, **F32)
+    model = Laguna(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(model_file, "_BLOCK_KEEPS", tuple(
+            k for k in model_file._BLOCK_KEEPS if k not in without))
+        return cfg, jax.make_jaxpr(jax.value_and_grad(
+            laguna_loss_fn(model, ce_chunk=32), has_aux=True))(
+                params, _batch(0, cfg))
+
+
+# (what, its names, (rows' shape, weight's shape)..., how many a step):
+# d = 64, 2 x 64 tokens
+_KEPT_PRODUCTS = [
+    ("router", ("moe_router_logits",), [((128, 64), (64, 16))], 4),
+    ("attn_out", ("attn_out_proj",),
+     [((2, 64, 96), (96, 64)), ((2, 64, 128), (128, 64))], 5),
+    ("attn_q", ("attn_q",),
+     [((2, 64, 64), (64, 96)), ((2, 64, 64), (64, 128))], 5),
+    ("attn_k_v", ("attn_k", "attn_v"),
+     [((2, 64, 64), (64, 32))], 10),
+    ("dense_gate_up", ("mlp_gate", "mlp_up"),
+     [((2, 64, 64), (64, 160))], 2),
+    ("shared_gate_up", ("mlp_gate", "mlp_up"),
+     [((2, 64, 64), (64, 48))], 8)]
+
+
+@pytest.mark.parametrize("names, shapes, n", [c[1:] for c in _KEPT_PRODUCTS],
+                         ids=[c[0] for c in _KEPT_PRODUCTS])
+def test_a_recomputed_block_runs_each_kept_product_once(names, shapes, n):
+    """The forward matmuls of one product's shapes in the gradient's
+    jaxpr: as many with ``remat`` as in the stack kept whole (once a
+    layer that has the product); with its names off the policy, twice."""
+    assert [matmuls(_traced_gradient(remat)[1], *shapes)
+            for remat in (False, True)] == [n, n]
+    assert matmuls(_traced_gradient(True, names)[1], *shapes) == 2 * n
+
+
+@pytest.mark.parametrize("without, runs", [
+    ((), 1), (("moe_router_experts", "moe_router_weights",
+               "moe_router_counts"), 2)], ids=["kept", "product_alone"])
+def test_a_recomputed_router_chooses_once(without, runs):
+    """``top_k`` in the gradient's jaxpr: once a routed layer where the
+    policy lists the routers' names, twice where it lists the product
+    alone (the choice and the chosen scores are made again from the kept
+    product)."""
+    cfg, traced = _traced_gradient(True, without)
+    assert primitives(traced, "top_k") == len(cfg.routed_layers) * runs
 
 
 def test_the_notes_say_the_stack_the_tables_the_gate_and_the_share(

@@ -57,7 +57,9 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["attn_kind"] == "kda_mla"
     assert notes["attn_layers"] == "KKKMK" and notes["blocks_remat"] is True
     assert notes["blocks_remat_keeps"] == (
-        "kda_gated_out,kda_scan_out,kda_scan_states,attn_out,attn_lse")
+        "kda_gated_out,kda_scan_out,kda_scan_states,moe_router_logits,"
+        "moe_router_experts,moe_router_weights,moe_router_counts,attn_out,"
+        "attn_lse")
     assert notes["kda_path"] == "pallas_chunked" and notes["kda_chunk"] == 64
     assert notes["kda_heads"] == 32 and notes["kda_state"] == [128, 128]
     assert notes["conv_path"] == "pallas"
@@ -140,10 +142,16 @@ def test_the_real_size_step_compiles_inside_the_chips_memory(real_size_step):
     more are the recurrence's ``o`` and chunk-entering states (0.8 GB a
     KDA layer) held from a block's first pass to its backward, and they
     bought the forward kernel's second run a layer, 55 ms of a 684 ms
-    step."""
+    step. PR 66's 13.09: the four routers' float32 product and choice
+    (17 MB a layer) kept too, and no router's matmul left under
+    ``rematted_computation``."""
     cfg, _, lowered = real_size_step
-    m, total = program_bytes(lowered.compile())
+    compiled = lowered.compile()
+    m, total = program_bytes(compiled)
     assert m.argument_size_in_bytes == pytest.approx(
         cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
     assert 4e9 < total <= 14.5e9
-    assert total <= 13.07e9 + 0.05e9    # PR 59's program; 11.53 GB at PR 58
+    assert total <= 13.10e9 + 0.05e9    # PR 66's program; 13.06 at PR 59
+    assert not re.findall(
+        r"= \S+ convolution\(.*rematted_computation/h_\d/mlp/router/",
+        compiled.as_text())

@@ -36,7 +36,9 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     """Every layer's core is one call of the multi-block flash kernels in
     the projections' own layout, the forward once and the backward once:
     the block is recomputed, and keeps its core's output and row
-    statistics (``blocks_remat_keeps``), so the second pass over the
+    statistics (``blocks_remat_keeps``, behind the routers' product,
+    choice and counts, ``W_o``'s product, q, k and v, and the dense and
+    shared MLPs' ``gate`` and ``up``), so the second pass over the
     block runs no forward kernel. The two full layers' under
     ``attn/core`` over 6,144 lanes, the three sliding layers' under
     ``attn/window`` over 8,192 lanes and the band of a 512-key window in
@@ -49,7 +51,10 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["attn_layers"] == "FSSSF"
     assert notes["attn_heads"] == "48,64,64,64,48"
     assert notes["attn_window"] == 512 and notes["blocks_remat"] is True
-    assert notes["blocks_remat_keeps"] == "attn_out,attn_lse"
+    assert notes["blocks_remat_keeps"] == (
+        "moe_router_logits,moe_router_experts,moe_router_weights,"
+        "moe_router_counts,attn_out_proj,attn_q,attn_k,attn_v,"
+        "mlp_gate,mlp_up,attn_out,attn_lse")
     assert notes["attn_gate"] == "headwise_sigmoid"
     assert notes["rope_kind"] == "yarn_half|default"
     assert notes["rope_attention_factor"] == pytest.approx(1.4158883)
@@ -92,11 +97,32 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
 
 @pytest.mark.slow
 def test_the_real_size_step_compiles_inside_the_chips_memory(real_size_step):
-    """Arguments + temporaries + unaliased outputs stay under 14.0 GB
-    (12.47 GB at PR 53, 13.04 at PR 52; without ``remat`` the compiler
-    asks for 16.64 GB of the chip's 15.75 and refuses)."""
+    """Arguments + temporaries + unaliased outputs are 13.75 GB of the
+    chip's 16.909 (15.75 GiB): 12.47 at PR 53, which kept the cores'
+    results alone, and 1.27 more for what PR 66's list keeps of five
+    blocks (2.6 GB of arrays; the step's peak was a block's backward
+    pass, which held that block's own before). The pin is that figure
+    with 0.6 GB either way and no longer "under 14.0", which was
+    written in GiB's number against the 15.75: a step that keeps less
+    than its list says is a fault here too, and the chip's allocator
+    holds ~0.5 GB less than this sum (``device.hbm_held_gb``). Without
+    ``remat`` the compiler asks for 16.64 GB and refuses. No fusion is
+    XLA's own rematerialisation (``.remat`` in its name: what a step
+    pays with when its list asks for more than fits, PR 62), and of a
+    block's matmuls the second pass makes only the head gate's again
+    (2048 -> 48 | 64, 0.4 ms a step: not worth a name)."""
     cfg, _, lowered = real_size_step
-    m, total = program_bytes(lowered.compile())
+    compiled = lowered.compile()
+    m, total = program_bytes(compiled)
     assert m.argument_size_in_bytes == pytest.approx(
         cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
-    assert 4e9 < total < 14.0e9     # 12.47 GB at PR 53; 13.04 at PR 52
+    print(f"laguna step: arguments + temporaries {total / 1e9:.2f} GB, "
+          f"peak {m.peak_memory_in_bytes / 1e9:.2f}")
+    assert 13.2e9 < total < 14.4e9      # 13.75 GB; 12.47 at PR 53
+    text = compiled.as_text()
+    assert not re.findall(r"^\s+%?[\w.\-]*\.remat\d* = ", text, re.M)
+    again = re.findall(
+        r"= \S+ convolution\(.*rematted_computation/(h_\d/[\w/]+)/dot_general",
+        text)
+    assert len(again) == 5 and all(
+        re.fullmatch(r"h_\d/attn/gate/g", path) for path in again), again
