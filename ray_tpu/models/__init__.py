@@ -51,6 +51,15 @@ scale ``attention_multiplier``, ``Phi4Flash``'s fused SwiGLU MLP in every
 block, a tied table read under ``embedding_multiplier`` and
 ``logits_scaling``, the blocks recomputed with the scan's results kept by
 name); its config expresses granite-4.0-h-micro.
+``Qwen3Next`` is the Gated DeltaNet / gated attention hybrid (three
+gated delta rules with a decay a head, 16 key heads under 32 value
+heads, through ``ops/kda.py::gdn_scan`` to one grouped-query softmax
+layer at head width 256 with per-head zero-centred q/k norms, a quarter
+of the lanes rotated and an elementwise sigmoid gate on the core's
+output; every block a 512-expert top-10 softmax router through
+``routed_ffn`` beside a shared expert under a sigmoid gate; zero-centred
+RMSNorm throughout, an untied head); its config expresses
+Qwen3-Next-80B-A3B-Instruct.
 ``MoETransformer`` is the older top-1, capacity-dropping switch model
 on GPT-2 blocks, which goes when the dropless path runs under ``ep``
 (ROADMAP C5)."""
@@ -65,6 +74,7 @@ from ray_tpu.models.moe import MoEConfig, MoETransformer
 from ray_tpu.models.nemotron_h import NemotronH, NemotronHConfig
 from ray_tpu.models.ouro import Ouro, OuroConfig
 from ray_tpu.models.phi4flash import Phi4Flash, Phi4FlashConfig
+from ray_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
 from ray_tpu.models.resnet import ResNet, ResNet50Config
 from ray_tpu.models.smallthinker import SmallThinker, SmallThinkerConfig
 from ray_tpu.models.vit import ViT, ViTConfig
@@ -74,7 +84,8 @@ __all__ = [
     "GPT2", "GPT2Config", "Granite", "GraniteHybridConfig", "JoyAI",
     "JoyAIConfig", "KimiLinear", "KimiLinearConfig", "Laguna", "LagunaConfig", "Llama", "LlamaConfig",
     "MoETransformer", "MoEConfig", "NemotronH", "NemotronHConfig", "Ouro",
-    "OuroConfig", "Phi4Flash", "Phi4FlashConfig",
+    "OuroConfig", "Phi4Flash", "Phi4FlashConfig", "Qwen3Next",
+    "Qwen3NextConfig",
     "ResNet", "ResNet50Config", "SmallThinker", "SmallThinkerConfig", "ViT",
     "ViTConfig", "Zaya", "ZayaConfig",
 ]

@@ -73,6 +73,17 @@ widths; it is also what the tests hold the kernels to. Nothing but
 what ``kda_path`` observes chooses. A sequence or the heads split over
 chips (``sp``, ``tp``) would need the state or the heads passed between
 chips: refused by name.
+
+**A decay a head** (``gdn_scan``, at the file's end: Gated DeltaNet,
+arXiv:2412.06464). The same recurrence with ``alpha_t`` one number a
+head and a row, and ``Hk`` key heads serving ``H`` value heads (value
+head ``j`` reads q and k of key head ``j // (H / Hk)``). The decay then
+leaves the chunk's products: ``A = (K K^T) * D`` and ``B = (Q K^T) * D``
+with ``D_tj = exp(G_t - G_j)`` one ``[C, C]`` matrix a head, so there
+are no sub-blocks, no ``[16, 16, K]`` squares and no array of decays
+``K`` wide anywhere, in HBM or out of it; ``_solve``, ``_carry`` and
+``_read_out`` are shared with the form above, and so are the two paths
+and what chooses between them (``gdn_path``).
 """
 
 from __future__ import annotations
@@ -272,10 +283,11 @@ def _group(S, rows, *, chunk: int):
     return S, jnp.moveaxis(o, 1, 3).reshape(b, n, h, -1)
 
 
-def _xla_chunked(q, k, v, g, beta, *, chunk: int):
+def _xla_chunked(q, k, v, g, beta, *, chunk: int, group=_group):
     """``kda_scan`` in XLA: groups of chunks under a ``lax.scan``, each a
-    ``jax.checkpoint``."""
-    b, t, h, kd = q.shape
+    ``jax.checkpoint`` (``group``: ``_group``, or ``gdn_scan``'s)."""
+    b, t, kd = q.shape[0], q.shape[1], q.shape[-1]
+    h = v.shape[2]
     per_group = chunk * max(1, min(GROUP_ROWS, t + (-t) % chunk) // chunk)
     pad = (-t) % per_group
     # [groups, b, rows a group, H, .]: no row moves for a batch of one
@@ -284,7 +296,7 @@ def _xla_chunked(q, k, v, g, beta, *, chunk: int):
             b, (t + pad) // per_group, per_group, *z.shape[2:]), 1, 0)
         for name, z in (("q", q), ("k", k), ("v", v), ("g", g),
                         ("beta", beta))}
-    group = jax.checkpoint(functools.partial(_group, chunk=chunk))
+    group = jax.checkpoint(functools.partial(group, chunk=chunk))
     _, o = lax.scan(group, jnp.zeros((b, h, kd, v.shape[-1]), jnp.float32),
                     rows)
     return jnp.moveaxis(o, 0, 1).reshape(b, t + pad, h, -1)[:, :t]
@@ -334,3 +346,125 @@ def kda_scan(q, k, v, g, beta, *, chunk: int = 64, mesh=None,
             q, k = unit_rows(q) * q.shape[-1] ** -0.5, unit_rows(k)
     with jax.named_scope("scan"):
         return _xla_chunked(q, k, v, g, beta, chunk=chunk)
+
+
+# ---------------------------------------------------------------------------
+# a decay a head: Gated DeltaNet (arXiv:2412.06464)
+# ---------------------------------------------------------------------------
+
+def gdn_path(shape, chunk: int, mesh=None, *, values: int | None = None,
+             heads: int | None = None) -> str:
+    """``kda_path`` for the recurrence with a decay a head, ``shape``
+    that of ``q`` [b, T, Hk, K] under ``heads`` value heads (as many as
+    key heads, if not said): the same observations (backend, widths,
+    chunk, the devices the program spans) and that a kernel cell's
+    value heads are whole key heads' groups; the same two names, the
+    same refusals of ``sp`` and ``tp`` by name."""
+    path = kda_path(shape, chunk, mesh, values=values)
+    if path == "pallas_chunked" and not kernels.heads_ok(
+            heads or shape[2], shape[2]):
+        return "xla_chunked"
+    return path
+
+
+def _scalar_chunk_terms(q, k, v, g, beta, sub: int):
+    """``_chunk_terms`` where the decay is one number a row: q, k
+    [.., C, K]; v [.., C, V]; g, beta [.., C]. The decay leaves the
+    products: ``A = (K K^T) * D`` and ``B = (Q K^T) * D`` with ``D_tj =
+    exp(G_t - G_j)`` one ``[C, C]`` matrix a chunk (every exponent a
+    difference of running sums, at most 0 under the mask), no
+    sub-blocks and no ``[.., K]`` array of decays anywhere
+    (arXiv:2412.06464, section 3.3)."""
+    G = jnp.cumsum(g, axis=-1)                              # [.., C]
+    c = G.shape[-1]
+    row, col = jnp.arange(c)[:, None], jnp.arange(c)
+    D = jnp.exp(jnp.where(col <= row, G[..., :, None] - G[..., None, :],
+                          -jnp.inf))
+    A = jnp.where(col < row, _matmul("...id,...jd->...ij", k, k) * D, 0.0)
+    B = _matmul("...id,...jd->...ij", q, k) * D
+    T = _solve(A, beta, sub)
+    end = G[..., -1:]
+    to_row = jnp.exp(G)[..., None]
+    bv, bk = beta[..., None] * v, beta[..., None] * k * to_row
+    return {"B": B,
+            "Uv": _matmul("...ij,...jv->...iv", T, bv),
+            "W": _matmul("...ij,...jd->...id", T, bk),
+            "q_in": q * to_row,
+            "k_out": k * jnp.exp(end - G)[..., None],
+            "keep": jnp.exp(end)}           # [.., 1]: every channel's
+
+
+def _for_value_heads(z, rep: int):
+    """A key head's rows [b, Hk, ...] for each of its ``rep`` value
+    heads, [b, Hk * rep, ...]: value head ``j`` reads key head ``j //
+    rep`` (not ``j % Hk``: a key head's value heads are neighbours)."""
+    return jnp.repeat(z, rep, axis=1)
+
+
+def _scalar_group(S, rows, *, chunk: int, rep: int):
+    """``_group`` for a decay a head and ``rep`` value heads a key head:
+    q, k [b, rows, H / rep, K]; v [b, rows, H, V]; g, beta [b, rows, H].
+    A key head's q and k are repeated here, a group's rows at a time
+    (value head ``j`` reads key head ``j // rep``); the caller never
+    holds the copies."""
+    b, n, h, _ = rows["v"].shape
+
+    def by_chunk(z):        # [b, rows, H, ...] -> [b, H, chunks, C, ...]
+        z = z.astype(jnp.float32).reshape(b, n // chunk, chunk, *z.shape[2:])
+        return jnp.moveaxis(z, 3, 1)
+
+    q, k = (_for_value_heads(by_chunk(rows[name]), rep) for name in "qk")
+    terms = _scalar_chunk_terms(
+        q, k, *(by_chunk(rows[name]) for name in ("v", "g", "beta")),
+        min(SUB, chunk))
+    S, entering, U = _carry(S, terms)
+    o = _read_out(terms, entering, U)                   # [b, H, chunks, C, V]
+    return S, jnp.moveaxis(o, 1, 3).reshape(b, n, h, -1)
+
+
+def gdn_scan(q, k, v, g, beta, *, chunk: int = 64, mesh=None,
+             normalize_qk: bool = False):
+    """The gated delta rule with **a decay a head** (Gated DeltaNet,
+    arXiv:2412.06464): the recurrence of this file's head with
+    ``alpha_t`` one number a head and a row, over whole sequences,
+    chunked, by the path ``gdn_path`` names.
+
+    q, k: [batch, T, Hk, K]  as ``kda_scan``'s, over ``Hk`` key heads
+    v:    [batch, T, H, V]   ``H`` a multiple of ``Hk``: value head ``j``
+                             reads q and k of key head ``j // (H / Hk)``
+    g:    [batch, T, H]      log-decays, <= 0, float32: rank 3
+    beta: [batch, T, H]      step sizes, float32
+    Returns ``o`` [batch, T, H, V] float32; ``T`` need not be whole
+    chunks. ``normalize_qk`` as ``kda_scan``'s.
+
+    Nothing ``K`` wide is made of the decay on either path, and ``q``
+    and ``k`` stay ``Hk`` heads wide in HBM: ``xla_chunked`` repeats a
+    key head's rows a group of chunks at a time inside the recomputed
+    group (``_scalar_group``); ``pallas_chunked`` hands the kernels'
+    grid cell the key heads of its value heads (a ``BlockSpec``), reads
+    ``g`` as it reads ``beta`` and writes ``dg`` one float a row a
+    head. The kernels' forward rule names its results as ``kda_scan``'s
+    does (``SCAN_OUT``, ``SCAN_STATES``). The recurrence is under the
+    scope ``scan`` on both paths, ``qk_norm`` as ``kda_scan``'s."""
+    heads, key_heads = v.shape[2], q.shape[2]
+    if g.ndim != 3 or heads % key_heads or k.shape != q.shape:
+        raise ValueError(
+            f"gdn_scan: a decay a head is g [b, T, H], and H a multiple "
+            f"of the key heads: q {q.shape}, k {k.shape}, v {v.shape}, "
+            f"g {g.shape}")
+    rep = heads // key_heads
+    path = gdn_path(q.shape, chunk, mesh, values=v.shape[-1], heads=heads)
+    tracing.note_trace(gdn_path=path, gdn_chunk=chunk,
+                       gdn_heads=[key_heads, heads],
+                       gdn_state=[q.shape[-1], v.shape[-1]])
+    if path == "pallas_chunked":
+        with jax.named_scope("scan"):
+            return kernels.gdn_scan(q, k, v, g, beta,
+                                    normalize_qk=normalize_qk)
+    if normalize_qk:
+        with jax.named_scope("qk_norm"):
+            q, k = unit_rows(q) * q.shape[-1] ** -0.5, unit_rows(k)
+    with jax.named_scope("scan"):
+        return _xla_chunked(
+            q, k, v, g, beta, chunk=chunk,
+            group=functools.partial(_scalar_group, rep=rep))

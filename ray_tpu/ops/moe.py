@@ -250,9 +250,19 @@ _unsort.defvjp(_unsort_fwd, _unsort_bwd)
 def _route(x, router_w, top_k: int, norm_topk_prob: bool):
     """float32 router of ``[T, d]`` tokens: (weights [T, k], experts
     [T, k], sum over tokens of the probabilities [E], sum over tokens
-    of logsumexp(logits)^2)."""
-    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
+    of logsumexp(logits)^2). The product carries ``ROUTER_LOGITS``, so
+    that a recomputed block whose policy lists it runs the float32
+    matmul once. ``top_k`` it runs again: the chosen probabilities are
+    ``top_k``'s own values, differentiated through its own indices,
+    which no name reaches; taking them by a gather with a named choice
+    (``_route_sigmoid``'s way) spares the second ``top_k`` and costs as
+    much again at 512 experts (XLA's gather of ``[16384, 10]`` and its
+    scatter-add: 1.7 ms a layer each; PERF.md section 6, PR 67), and
+    would put that gather into every softmax-routed step, recomputed
+    or not."""
+    logits = checkpoint_name(
+        jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                precision=lax.Precision.HIGHEST), ROUTER_LOGITS)
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     probs = jnp.exp(logits - lse[:, None])
     weights, experts = lax.top_k(probs, top_k)

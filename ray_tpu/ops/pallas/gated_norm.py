@@ -163,6 +163,23 @@ def _gate_normed_grads(o, gate, scale, dout, eps):
     return do, dout * (n * scale) * (s * (1.0 - s)), ds * n
 
 
+def _silu_gate_normed(o, gate, scale, eps):
+    """Gated DeltaNet's: ``silu(gate) * RMSNorm(o) * scale``."""
+    return gate * jax.nn.sigmoid(gate) * (o * _factor(o, eps) * scale)
+
+
+def _silu_gate_normed_grads(o, gate, scale, dout, eps):
+    """(do, dgate, the rows' share of dscale) of ``_silu_gate_normed``."""
+    s = jax.nn.sigmoid(gate)
+    r = _factor(o, eps)
+    n = o * r
+    ds = dout * (gate * s)
+    dn = ds * scale
+    do = r * (dn - n * jnp.mean(dn * n, axis=-1, keepdims=True))
+    return (do, dout * (n * scale) * (s * (1.0 + gate * (1.0 - s))),
+            ds * n)
+
+
 def _scale_of(scale_ref, sl, c):
     """A group's lanes of a ``scale`` over all ``c`` columns; all of one
     that the groups share."""
@@ -231,6 +248,13 @@ def _head_gate_fwd(o, gate, scale, **static):
     """Kimi Delta Attention's output gate in ``gate``'s dtype; jitted
     for the reason ``_norm_fwd`` is, under a name of its own."""
     return _forward("gate_normed", o, gate, scale, **static)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _head_silu_gate_fwd(o, gate, scale, **static):
+    """Gated DeltaNet's output gate in ``gate``'s dtype: ``_head_gate_fwd``
+    with ``silu`` where that one has a sigmoid, under a name of its own."""
+    return _forward("silu_gate_normed", o, gate, scale, **static)
 
 
 # ---------------------------------------------------------------------------
@@ -306,6 +330,12 @@ def _head_gate_bwd(o, gate, scale, dout, **static):
     return _backward("gate_normed", o, gate, scale, dout, **static)
 
 
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _head_silu_gate_bwd(o, gate, scale, dout, **static):
+    """(do, dgate, dscale [1, C / heads] float32)."""
+    return _backward("silu_gate_normed", o, gate, scale, dout, **static)
+
+
 class _Form(NamedTuple):
     value: object   # a strip's (a, b, scale, eps) -> the result
     grads: object   # (a, b, scale, dout, eps) -> (da, db, dscale's rows)
@@ -318,7 +348,12 @@ _FORMS = {
     "norm_gated": _Form(_norm_gated, _norm_gated_grads, 0,
                         _norm_fwd, _norm_bwd),
     "gate_normed": _Form(_gate_normed, _gate_normed_grads, 1,
-                         _head_gate_fwd, _head_gate_bwd)}
+                         _head_gate_fwd, _head_gate_bwd),
+    "silu_gate_normed": _Form(_silu_gate_normed, _silu_gate_normed_grads, 1,
+                              _head_silu_gate_fwd, _head_silu_gate_bwd)}
+
+# the output gate's function -> the form that applies it to the normed ``o``
+HEAD_GATES = {"sigmoid": "gate_normed", "silu": "silu_gate_normed"}
 
 
 # ---------------------------------------------------------------------------
@@ -389,11 +424,14 @@ def gated_norm(y, z, scale, *, groups: int, eps: float,
 
 
 def head_gate_norm(o, gate, scale, *, heads: int, eps: float,
-                   interpret: bool = False, mesh=None, batch_axes=()):
+                   interpret: bool = False, mesh=None, batch_axes=(),
+                   gate_fn: str = "sigmoid"):
     """``ops/ssm.py::sigmoid_gated_head_rms_norm`` on the kernels: o,
     gate [b, T, C]; scale [C / heads], shared by the heads; the same
     result in ``gate``'s dtype, differentiable in all three. ``C`` and
     ``heads`` must pass ``shapes_ok``; ``T`` is any; ``mesh`` and
-    ``batch_axes`` as ``gated_norm``'s."""
-    return _apply("gate_normed", o, gate, scale, heads, eps, interpret,
-                  mesh, batch_axes)
+    ``batch_axes`` as ``gated_norm``'s. ``gate_fn``: ``sigmoid`` (Kimi
+    Delta Attention's) or ``silu`` (Gated DeltaNet's), a kernel pair
+    each (``HEAD_GATES``)."""
+    return _apply(HEAD_GATES[gate_fn], o, gate, scale, heads, eps,
+                  interpret, mesh, batch_axes)

@@ -79,6 +79,26 @@ diagonal, which with ``dT = dUv (beta v)^T + dW (beta k e^G)^T`` is
 ``-(T^T dUv) Uv^T - (T^T dW) W^T``), and the scores' backward needs no
 cotangent of the reference row ``m``: its two contributions cancel.
 
+**A decay a head** (``gdn_scan``: Gated DeltaNet's recurrence, which
+``ops/kda.py::gdn_scan`` runs here). The same two kernels with ``rep``
+set: ``g`` comes as ``beta`` does, ``[B, H, T / 128, 1, 128]``, one
+float a row a head, and ``dg`` leaves so; a cell's block of ``q`` and
+``k`` ``[B, T, Hk*128]`` holds the ``heads_a_cell / rep`` key heads that
+its value heads read (value head ``j``, key head ``j // rep``), and the
+backward writes a key head's ``dq, dk`` summed over its value heads, so
+no copy of a key head and nothing ``K`` wide of the decay reaches HBM.
+With one decay a row the scores need no levels (``_Cell.
+_scalar_scores``): ``A = (K K^T) * D`` and ``B = (Q K^T) * D`` with
+``D_tj = exp(G_t - G_j)``, one ``[256, 128] x [128, 128]`` product a
+*key* head where the channels' decays take six a head, no rescaled copy
+of q or k, and the backward through the scores is two products a value
+head (``_scalar_tail``) where the channels' is twelve. The running sums
+are kept ``[128, 128]`` with every lane alike, so the inverse by levels,
+the state's carry and everything after the scores are the code above,
+unchanged. Timed inside the Qwen3-Next cell's step at the same rows and
+states (PERF.md section 6, PR 67): a layer's forward 10.0 ms and backward
+17.1 where the channels' take 13.8 and 29.7.
+
 Layout: ``q, k, g, v, o`` as ``[B, T, H*128]``, a head one 128-lane
 block; ``beta`` as ``[B, H, T / 128, 1, 128]``, a cell's steps along
 the lanes (the kernel turns them into a column under the identity
@@ -208,7 +228,8 @@ class _Cell:
     entry, of [128, 128] float32 arrays unless said: ``t`` runs down
     the rows, ``j`` (or a channel) along the lanes."""
 
-    def __init__(self, q, k, g, v, beta_rows, *, keep_levels: bool):
+    def __init__(self, q, k, g, v, beta_rows, *, keep_levels: bool,
+                 scalar: bool = False):
         n = _ROWS
         t = lax.broadcasted_iota(jnp.int32, (n, n), 0)
         j = lax.broadcasted_iota(jnp.int32, (n, n), 1)
@@ -223,6 +244,10 @@ class _Cell:
                                 keepdims=True), beta_rows)
         # the running sums inside each chunk
         self.ones = ones = jnp.where(self.in_chunk & (t >= j), 1.0, zero)
+        if scalar:
+            self._scalar_scores(q, k, g)
+            self._after_scores()
+            return
         G = _each(lambda g: _dot(ones, g, _NN), g)
         at_start = G            # G at the start of a row's block of h rows
         A = [zero] * len(q)
@@ -230,20 +255,6 @@ class _Cell:
             eye, jnp.sum(q * k, axis=1, keepdims=True), zero), q, k)
         T, waiting = None, None
         self.levels = []
-
-        def wider(T, a_level, h):
-            """Blocks of h rows inverted -> blocks of 2h rows: ``T - T R
-            T`` with ``R`` level h's part of ``Diag(beta) A``, whose
-            rows, like the product's, are the lower halves' alone. A
-            level's two matmuls stand in the program after the next
-            level's scores, which do not wait for them."""
-            lower_left = _each(lambda b, a: _lower(b * a, h), beta, a_level)
-            if T is None:       # blocks of one row: the identity
-                return _each(lambda r: jnp.where(eye, 1.0, -r), lower_left)
-            right = _each(lambda r, T: _unpacked(_dot(r, T, _NN), h),
-                          lower_left, T)
-            return _each(lambda T, right: T - _unpacked(
-                _dot(_lower(T, h), right, _NN), h), T, right)
 
         for h in _LEVELS:
             lower = (t & h) != 0            # the row's half of its 2h block
@@ -255,7 +266,7 @@ class _Cell:
             scores = _each(lambda qs, ks: _dot(jnp.concatenate(
                 [_lower(qs, h), _lower(ks, h)], axis=0), ks, _NT), qs, ks)
             if waiting is not None:
-                T = wider(T, *waiting)
+                T = self._wider(T, *waiting)
             half = scores[0].shape[0] // 2
             waiting = _each(lambda s: jnp.where(
                 mask, _unpacked(s[half:], h), zero), scores), h
@@ -269,8 +280,65 @@ class _Cell:
             if h != _LEVELS[-1]:
                 at_start = _each(lambda s: jnp.where(lower, _roll(s, h), s),
                                  at_start)
-        T = wider(T, *waiting)
-        self.A, self.B, self.T = A, B, T
+        T = self._wider(T, *waiting)
+        self.A, self.B, self.T, self.G = A, B, T, G
+        self._after_scores()
+
+    def _scalar_scores(self, q, k, g):
+        """``A``, ``B`` and ``T`` where the decay is one number a row
+        (``g``: a head's [1, rows] row, a cell's steps along the lanes):
+        the decay leaves the products, ``A = (K K^T) * D`` and ``B = (Q
+        K^T) * D`` with ``D_tj = exp(G_t - G_j)`` one matrix a head
+        (every exponent a difference of running sums, at most 0 under
+        the mask): one product a key head where the channels' decays
+        take six levels of them, and no rescaled copy of q or k. ``q``
+        and ``k`` are the key heads', ``len(g) / len(k)`` value heads
+        to each. ``G`` is kept as the channels' is, every lane alike,
+        so that all that follows reads it as it reads theirs."""
+        n, zero, eye, ones = _ROWS, self.zero, self.eye, self.ones
+        rep = len(g) // len(k)
+        column = _each(lambda row: jnp.sum(
+            jnp.where(eye, row, zero), axis=1, keepdims=True), g)
+        G = _each(lambda c: _dot(ones, jnp.broadcast_to(c, (n, n)), _NN),
+                  column)                       # G_t, on every lane
+        across = _each(lambda row: _dot(jnp.broadcast_to(row, (n, n)), ones,
+                                        _NT), g)    # G_j, on every row
+        kept = self.in_chunk & (self.below | eye)
+        self.D = D = _each(lambda G, Gj: jnp.exp(
+            jnp.where(kept, G - Gj, -jnp.inf)), G, across)
+        scores = _each(lambda q, k: _dot(
+            jnp.concatenate([q, k], axis=0), k, _NT), q, k)     # [2 n, n]
+        self.B = [scores[i // rep][:n] * D[i] for i in range(len(g))]
+        self.A = A = [jnp.where(self.below, scores[i // rep][n:] * D[i],
+                                zero) for i in range(len(g))]
+        self.q = [q[i // rep] for i in range(len(g))]
+        self.k = [k[i // rep] for i in range(len(g))]
+        T = None
+        for h in _LEVELS:
+            mask = self.level_mask(h)
+            T = self._wider(T, _each(lambda A: jnp.where(mask, A, zero), A),
+                            h)
+        self.T, self.G = T, G
+
+    def _wider(self, T, a_level, h):
+        """Blocks of h rows inverted -> blocks of 2h rows: ``T - T R T``
+        with ``R`` level h's part of ``Diag(beta) A``, whose rows, like
+        the product's, are the lower halves' alone. With the channels'
+        decays a level's two matmuls stand in the program after the
+        next level's scores, which do not wait for them."""
+        eye = self.eye
+        lower_left = _each(lambda b, a: _lower(b * a, h), self.beta, a_level)
+        if T is None:       # blocks of one row: the identity
+            return _each(lambda r: jnp.where(eye, 1.0, -r), lower_left)
+        right = _each(lambda r, T: _unpacked(_dot(r, T, _NN), h),
+                      lower_left, T)
+        return _each(lambda T, right: T - _unpacked(
+            _dot(_lower(T, h), right, _NN), h), T, right)
+
+    def _after_scores(self):
+        """What both forms of the scores share from here on."""
+        G, q, k, v, beta, T = self.G, self.q, self.k, self.v, self.beta, \
+            self.T
         ends = _each(lambda G: [_last_row(G, c) for c in range(2)], G)
         self.to_row = _each(jnp.exp, G)             # chunk's start -> row
         self.to_end = _each(                        # row -> chunk's end
@@ -310,11 +378,19 @@ def _unit_back(d, u, r):
 
 
 def _cell_of(q_ref, k_ref, g_ref, v_ref, beta_ref, *, heads, keep_levels,
-             normalize):
+             normalize, rep=None):
     """The cell, and (``normalize``) what the backward needs to carry
     ``dq, dk`` back to the rows as they came: (unit q, q's factor, k's
-    factor), the unit k being the cell's own ``k``."""
-    q, k, g, v = (_read(ref, heads) for ref in (q_ref, k_ref, g_ref, v_ref))
+    factor), the unit k being the cell's own ``k``. ``rep``: None for a
+    decay a channel; for a decay a head (``g_ref`` then holds rows like
+    ``beta_ref``'s) the value heads to a key head, and ``q_ref``,
+    ``k_ref`` hold the cell's ``heads / rep`` key heads."""
+    scalar = rep is not None
+    key_heads = heads // rep if scalar else heads
+    q, k = _read(q_ref, key_heads), _read(k_ref, key_heads)
+    g = ([g_ref[i] for i in range(heads)] if scalar
+         else _read(g_ref, heads))
+    v = _read(v_ref, heads)
     norms = None
     if normalize:
         q_unit, q_r = zip(*_each(_unit, q))
@@ -322,7 +398,7 @@ def _cell_of(q_ref, k_ref, g_ref, v_ref, beta_ref, *, heads, keep_levels,
         q = [u * _Q_SCALE for u in q_unit]
         norms = q_unit, q_r, k_r
     cell = _Cell(q, k, g, v, [beta_ref[i] for i in range(heads)],
-                 keep_levels=keep_levels)
+                 keep_levels=keep_levels, scalar=scalar)
     return cell, norms
 
 
@@ -336,7 +412,7 @@ def _write(ref, arrays):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, o_ref, *rest,
-                heads: int, keep_states: bool, normalize: bool):
+                heads: int, keep_states: bool, normalize: bool, rep=None):
     enter_ref = rest[0] if keep_states else None
     state_ref = rest[-1]
 
@@ -345,7 +421,7 @@ def _fwd_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, o_ref, *rest,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     cell, _ = _cell_of(q_ref, k_ref, g_ref, v_ref, beta_ref, heads=heads,
-                       keep_levels=False, normalize=normalize)
+                       keep_levels=False, normalize=normalize, rep=rep)
     state = [state_ref[i] for i in range(heads)]        # [V, K] a head
     read, U = [], []
     for c in range(2):
@@ -436,7 +512,7 @@ def _kda_fwd(q, k, g, v, beta, *, keep_states, normalize, interpret):
 
 def _bwd_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, enter_ref, do_ref,
                 dq_ref, dk_ref, dg_ref, dv_ref, dbeta_ref, dstate_ref, *,
-                heads: int, normalize: bool):
+                heads: int, normalize: bool, rep=None):
     """One cell, the cells walked last to first and the cell's second
     chunk before its first. ``dstate_ref`` carries the cotangent of the
     state *leaving* the chunk at hand, transposed like the state."""
@@ -445,7 +521,7 @@ def _bwd_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, enter_ref, do_ref,
         dstate_ref[...] = jnp.zeros_like(dstate_ref)
 
     cell, norms = _cell_of(q_ref, k_ref, g_ref, v_ref, beta_ref, heads=heads,
-                           keep_levels=True, normalize=normalize)
+                           keep_levels=True, normalize=normalize, rep=rep)
     q, k, zero = cell.q, cell.k, cell.zero
     do = _read(do_ref, heads)
     entered = [[enter_ref[c, i] for c in range(2)]      # [V, K] a chunk
@@ -502,6 +578,10 @@ def _bwd_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, enter_ref, do_ref,
         dL, cell.A, dbv, cell.v, dbk, cell.k_in)
     _write(dv_ref, _each(jnp.multiply, cell.beta, dbv))
     dk_in = _each(jnp.multiply, cell.beta, dbk)
+    if rep is not None:
+        return _scalar_tail(
+            cell, norms, rep, dB, dA, dq_in, dk_in, dk_out, d_end, dbeta,
+            dq_ref, dk_ref, dg_ref, dbeta_ref)
     on_diag = _each(lambda dB: jnp.sum(jnp.where(cell.eye, dB, zero), axis=1,
                                        keepdims=True), dB)
     dq = _each(lambda dq_in, to_row, d, k: dq_in * to_row + d * k,
@@ -545,6 +625,58 @@ def _bwd_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, enter_ref, do_ref,
     _write(dg_ref, _each(lambda dG: _dot(cell.ones, dG, _TN), dG))
     for i in range(heads):
         dbeta_ref[i] = jnp.sum(jnp.where(cell.eye, dbeta[i], zero), axis=0,
+                               keepdims=True)
+
+
+def _scalar_tail(cell, norms, rep, dB, dA, dq_in, dk_in, dk_out, d_end,
+                 dbeta, dq_ref, dk_ref, dg_ref, dbeta_ref):
+    """The backward kernel from the scores on, for a decay a head: with
+    ``A = (K K^T) * D`` and ``B = (Q K^T) * D`` the scores' cotangents
+    go back through one pair of products a value head, a key head's
+    ``dq, dk`` are its value heads' summed, and the running sum's
+    cotangent is one number a row: the channels' terms summed over the
+    lanes, plus ``sum_j P_tj - sum_j P_jt`` with ``P = dB * B + dA * A``
+    (a score's exponent is ``G_t - G_j``). ``dg`` leaves as ``g`` came,
+    a head's steps along the lanes."""
+    n, zero, eye, ones = _ROWS, cell.zero, cell.eye, cell.ones
+    q, k = cell.q, cell.k
+    through = _each(lambda dB, dA, D: jnp.concatenate(
+        [dB * D, dA * D], axis=0), dB, dA, cell.D)              # [2 n, n]
+    as_rows = _each(lambda th, k: _dot(th, k, _NN), through, k)
+    as_cols = _each(lambda th, q, k: _dot(
+        th, jnp.concatenate([q, k], axis=0), _TN), through, q, k)
+    dq = _each(lambda dq_in, to_row, rows: dq_in * to_row + rows[:n],
+               dq_in, cell.to_row, as_rows)
+    dk = _each(lambda dk_in, to_row, dk_out, to_end, rows, cols:
+               dk_in * to_row + dk_out * to_end + rows[n:] + cols,
+               dk_in, cell.to_row, dk_out, cell.to_end, as_rows, as_cols)
+    # a key head's: its value heads' summed
+    dq = [sum(dq[i:i + rep][1:], dq[i]) for i in range(0, len(dq), rep)]
+    dk = [sum(dk[i:i + rep][1:], dk[i]) for i in range(0, len(dk), rep)]
+    if norms is not None:
+        q_unit, q_r, k_r = norms
+        dq = _each(lambda dq, u, r: _unit_back(dq * _Q_SCALE, u, r),
+                   dq, q_unit, q_r)
+        dk = _each(_unit_back, dk, k[::rep], k_r)
+    _write(dq_ref, dq)
+    _write(dk_ref, dk)
+    t = lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    j = lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    ones_t = jnp.where(cell.in_chunk & (j >= t), 1.0, zero)     # ones^T
+    for i in range(len(k)):
+        wide = (dq_in[i] * cell.q_in[i] + dk_in[i] * cell.k_in[i]
+                - dk_out[i] * cell.k_out[i]
+                + jnp.where(t == CHUNK - 1, d_end[0][i], zero)
+                + jnp.where(t == n - 1, d_end[1][i], zero))
+        P = dB[i] * cell.B[i] + dA[i] * cell.A[i]
+        down = jnp.sum(wide + P, axis=1, keepdims=True)         # [n, 1], t
+        across = jnp.sum(P, axis=0, keepdims=True)              # [1, n], j
+        # dg_s = sum_{t >= s, t in s's chunk} dG_t, a step a lane
+        back = jnp.sum(ones_t * across, axis=1, keepdims=True)  # [n, 1], s
+        dg_ref[i] = (jnp.sum(ones * down, axis=0, keepdims=True)
+                     - jnp.sum(jnp.where(eye, back, zero), axis=0,
+                               keepdims=True))
+        dbeta_ref[i] = jnp.sum(jnp.where(eye, dbeta[i], zero), axis=0,
                                keepdims=True)
 
 
@@ -625,5 +757,128 @@ def kda_scan(q, k, v, g, beta, *, normalize_qk: bool = False,
     if not normalize_qk:    # unit rows are float32 rows
         q, k = q.astype(_F32), k.astype(_F32)
     o = _kda_core(rows(q), rows(k), rows(g.astype(_F32)), rows(v), steps,
+                  normalize_qk, interpret)
+    return o[:, :t].reshape(b_, t, h, -1)
+
+
+# ---------------------------------------------------------------------------
+# a decay a head (Gated DeltaNet): the same kernels, the scalar scores
+# ---------------------------------------------------------------------------
+
+def heads_ok(heads: int, key_heads: int) -> bool:
+    """Whether a grid cell's value heads are whole key heads' groups."""
+    return (key_heads > 0 and heads % key_heads == 0
+            and heads_a_cell(heads) % (heads // key_heads) == 0)
+
+
+def _gdn_specs(b_, h, rep, steps, *, backward: bool):
+    """``_specs`` with the key heads' blocks: a cell's ``per / rep`` key
+    heads of ``q`` and ``k`` [B, T, (H / rep) * 128], walked as the
+    value heads' rows are."""
+    grid, per, rows, beta, enter = _specs(b_, h, steps, backward=backward)
+    keys = pl.BlockSpec((1, _ROWS, per // rep * _WIDTH), rows.index_map)
+    return grid, per, rows, keys, beta, enter
+
+
+@functools.partial(jax.jit, static_argnames=("keep_states", "normalize",
+                                             "interpret"))
+def _gdn_fwd(q, k, g, v, beta, *, keep_states, normalize, interpret):
+    """``_kda_fwd`` for a decay a head: q, k [B, T, Hk*128]; v [B, T,
+    H*128]; g like beta, [B, H, T / 128, 1, 128] float32."""
+    b_, t, hv = v.shape
+    h, steps = hv // _WIDTH, t // _ROWS
+    rep = hv // q.shape[-1]
+    grid, per, rows, keys, beta_s, enter = _gdn_specs(b_, h, rep, steps,
+                                                      backward=False)
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, heads=per, keep_states=keep_states,
+                          normalize=normalize, rep=rep),
+        grid=grid,
+        in_specs=[keys, keys, beta_s, rows, beta_s],
+        out_specs=[rows] + [enter] * keep_states,
+        out_shape=[jax.ShapeDtypeStruct(v.shape, _F32)] + [
+            jax.ShapeDtypeStruct((b_, 2 * steps, h, _WIDTH, _WIDTH), _F32)
+        ] * keep_states,
+        scratch_shapes=[_state_scratch(per)],
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+    )(q, k, g, v, beta)
+    return tuple(out) if keep_states else (out[0], None)
+
+
+@functools.partial(jax.jit, static_argnames=("normalize", "interpret"))
+def _gdn_bwd(q, k, g, v, beta, entering, do, *, normalize, interpret):
+    """(dq like q, dk like k, dg like g, dv like v, dbeta like beta):
+    ``dg`` one float a row a head."""
+    b_, t, hv = v.shape
+    h, steps = hv // _WIDTH, t // _ROWS
+    rep = hv // q.shape[-1]
+    grid, per, rows, keys, beta_s, enter = _gdn_specs(b_, h, rep, steps,
+                                                      backward=True)
+    dq, dk, dv = (jax.ShapeDtypeStruct(z.shape, z.dtype) for z in (q, k, v))
+    steps_f32 = jax.ShapeDtypeStruct(beta.shape, _F32)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=per, normalize=normalize,
+                          rep=rep),
+        grid=grid,
+        in_specs=[keys, keys, beta_s, rows, beta_s, enter, rows],
+        out_specs=[keys, keys, beta_s, rows, beta_s],
+        out_shape=[dq, dk, steps_f32, dv, steps_f32],
+        scratch_shapes=[_state_scratch(per)],
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+    )(q, k, g, v, beta, entering, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _gdn_core(q, k, g, v, beta, normalize: bool, interpret: bool):
+    return _gdn_fwd(q, k, g, v, beta, keep_states=False, normalize=normalize,
+                    interpret=interpret)[0]
+
+
+def _gdn_core_fwd(q, k, g, v, beta, normalize, interpret):
+    o, entering = _gdn_fwd(q, k, g, v, beta, keep_states=True,
+                           normalize=normalize, interpret=interpret)
+    # named as ``_kda_core_fwd`` names them, and for its reason
+    o, entering = checkpoint_name(o, SCAN_OUT), checkpoint_name(
+        entering, SCAN_STATES)
+    return o, (q, k, g, v, beta, entering)
+
+
+def _gdn_core_bwd(normalize, interpret, res, do):
+    return tuple(_gdn_bwd(*res, do.astype(_F32), normalize=normalize,
+                          interpret=interpret))
+
+
+_gdn_core.defvjp(_gdn_core_fwd, _gdn_core_bwd)
+
+
+def gdn_scan(q, k, v, g, beta, *, normalize_qk: bool = False,
+             interpret: bool = False):
+    """``ops/kda.py::gdn_scan`` on the kernels: q, k [b, T, Hk, 128]; v
+    [b, T, H, 128]; g, beta [b, T, H] (the decay a head); ``o`` [b, T,
+    H, 128] float32, differentiable in all five. ``normalize_qk`` and
+    the tail as ``kda_scan``'s. The key heads are never repeated in
+    HBM: a cell's block of ``q`` and ``k`` holds the ``heads_a_cell / (H
+    / Hk)`` key heads its value heads read, and writes their ``dq, dk``
+    summed over those value heads. ``g`` goes in, and ``dg`` comes out,
+    in ``beta``'s layout, one float a row a head."""
+    b_, t, h, vd = v.shape
+    if not (shapes_ok(q.shape[-1], vd, CHUNK) and heads_ok(h, q.shape[2])):
+        raise ValueError(f"the kernels do not tile keys {q.shape}, values "
+                         f"{v.shape}")
+    pad = (-t) % _ROWS
+
+    def rows(z):
+        z = z.reshape(b_, t, -1)
+        return jnp.pad(z, ((0, 0), (0, pad), (0, 0))) if pad else z
+
+    def steps(z):       # [b, T, H] -> [b, H, T / 128, 1, 128]
+        return jnp.swapaxes(rows(z.astype(_F32)), 1, 2).reshape(
+            b_, h, (t + pad) // _ROWS, 1, _ROWS)
+
+    if not normalize_qk:    # unit rows are float32 rows
+        q, k = q.astype(_F32), k.astype(_F32)
+    o = _gdn_core(rows(q), rows(k), steps(g), rows(v), steps(beta),
                   normalize_qk, interpret)
     return o[:, :t].reshape(b_, t, h, -1)

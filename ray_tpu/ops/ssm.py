@@ -355,7 +355,7 @@ def _gated_group_rms_norm_xla(y, z, scale, groups: int, eps: float):
 
 
 def sigmoid_gated_head_rms_norm(o, gate, scale, heads: int, eps: float, *,
-                                mesh=None):
+                                mesh=None, gate_fn: str = "sigmoid"):
     """Kimi Delta Attention's output gate: ``sigmoid(gate) *
     RMSNorm_head(o)``, the norm over each of ``heads`` equal slices of
     the last dimension with one ``scale`` [C / heads] shared by the
@@ -367,23 +367,36 @@ def sigmoid_gated_head_rms_norm(o, gate, scale, heads: int, eps: float, *,
     ``norm_path`` decides from it, a head a group, between the second
     kernel pair of ``ops/pallas/gated_norm.py`` (``head_gate_norm``)
     and the XLA function below. Notes ``kda_gate_path`` for the trace
-    in progress."""
+    in progress. ``gate_fn`` ``"silu"`` is Gated DeltaNet's gate,
+    ``silu(gate) * RMSNorm_head(o)``: the same two paths (a kernel pair
+    of its own), the note ``gdn_gate_path``."""
     path = norm_path(o.shape, heads, mesh)
-    tracing.note_trace(kda_gate_path=path)
+    tracing.note_trace(**{_HEAD_GATES[gate_fn][1]: path})
     if path == "pallas":
         return gated_norm.head_gate_norm(
             o, gate, scale, heads=heads, eps=eps, mesh=mesh,
-            batch_axes=_kernel_batch_axes(mesh, o.shape[0]))
-    return _sigmoid_gated_head_rms_norm_xla(o, gate, scale, heads, eps)
+            batch_axes=_kernel_batch_axes(mesh, o.shape[0]),
+            gate_fn=gate_fn)
+    return _sigmoid_gated_head_rms_norm_xla(o, gate, scale, heads, eps,
+                                            gate_fn)
 
 
-@functools.partial(jax.checkpoint, static_argnums=(3, 4))
-def _sigmoid_gated_head_rms_norm_xla(o, gate, scale, heads: int, eps: float):
+# the output gate's function by name, and the note its path goes under
+_HEAD_GATES = {"sigmoid": (jax.nn.sigmoid, "kda_gate_path"),
+               "silu": (jax.nn.silu, "gdn_gate_path")}
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4, 5))
+def _sigmoid_gated_head_rms_norm_xla(o, gate, scale, heads: int, eps: float,
+                                     gate_fn: str = "sigmoid"):
+    """The output gate in XLA, under the gate's function by name (the
+    sigmoid unless said: the name is from before there was a second)."""
     shape = o.shape
     x = o.astype(jnp.float32).reshape(*shape[:-1], heads, shape[-1] // heads)
     x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
     x = (x * scale.astype(jnp.float32)).reshape(shape)
-    return (jax.nn.sigmoid(gate.astype(jnp.float32)) * x).astype(gate.dtype)
+    return (_HEAD_GATES[gate_fn][0](gate.astype(jnp.float32)) * x).astype(
+        gate.dtype)
 
 
 def mamba1_path(shape, states: int, chunk: int, mesh=None) -> str:

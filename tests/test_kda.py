@@ -439,3 +439,159 @@ def test_the_kept_results_give_the_gradients_of_the_recomputed_ones():
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# a decay a head (``gdn_scan``): Gated DeltaNet's recurrence, key heads
+# under value heads
+# ---------------------------------------------------------------------------
+
+def head_operands(seed, b, t, key_heads, heads, kd, vd, decay=1.0):
+    """``operands`` with q and k over ``key_heads`` heads, v over
+    ``heads``, and ``g`` one number a row a value head."""
+    q, k, _, _, _ = operands(seed, b, t, key_heads, kd, vd)
+    _, _, v, g, beta = operands(seed + 50, b, t, heads, kd, vd, decay=decay)
+    return q, k, v, g[..., 0], beta
+
+
+def widened(fn):
+    """``fn`` (a decay a channel, as many key heads as value heads)
+    given ``gdn_scan``'s operands: value head ``j`` reads key head ``j
+    // rep``, and every channel the head's decay."""
+    def wide(q, k, v, g, beta, **kw):
+        rep = v.shape[2] // q.shape[2]
+        q, k = (jnp.repeat(z, rep, axis=2) for z in (q, k))
+        return fn(q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta,
+                  **kw)
+    return wide
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("t, key_heads, heads", [
+    (128, 1, 1), (150, 2, 4),       # whole chunks, and a tail; 2 under 4
+    (96, 3, 3)])
+def test_a_decay_a_head_is_the_recurrence_and_kda_fed_the_broadcast_decay(
+        chunk, t, key_heads, heads):
+    args = head_operands(30, 2, t, key_heads, heads, 32, 24)
+    got, fed, ref = outputs_and_gradients(
+        args, lambda *a: kda.gdn_scan(*a, chunk=chunk),
+        widened(functools.partial(kda.kda_scan, chunk=chunk)),
+        widened(recurrence))
+    all_close(got, fed, ref)
+
+
+def test_a_decay_a_head_that_overflows_exp_of_minus_the_running_sum():
+    args = head_operands(31, 1, 128, 2, 2, 32, 32, decay=8.0)
+    G = np.cumsum(np.asarray(args[3])[:, :64], axis=1)
+    with np.errstate(over="ignore"):
+        assert np.isinf(np.exp(-G, dtype=np.float32)).any()
+    got, ref = outputs_and_gradients(
+        args, lambda *a: kda.gdn_scan(*a, chunk=64), widened(recurrence))
+    assert np.isfinite(np.asarray(got[0])).all()
+    all_close(got, ref)
+
+
+def test_gdn_scan_refuses_a_decay_a_channel_and_heads_that_do_not_divide():
+    q, k, v, g, beta = head_operands(32, 1, 32, 2, 4, 16, 16)
+    with pytest.raises(ValueError, match="a decay a head"):
+        kda.gdn_scan(q, k, v, jnp.zeros(v.shape), beta, chunk=16)
+    with pytest.raises(ValueError, match="a decay a head"):
+        kda.gdn_scan(q, k, v[:, :, :3], g[..., :3], beta[..., :3], chunk=16)
+
+
+def test_gdn_scan_makes_nothing_as_wide_as_the_keys_of_its_decay():
+    """No ``[.., K]``-wide decay on the XLA path: no ``exp`` in the traced
+    gradient has a result as large as ``q`` widened to the value heads."""
+    b, t, hk, h, kd = 1, 128, 2, 4, 32
+    args = head_operands(33, b, t, hk, h, kd, kd)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(kda.gdn_scan(*a, chunk=64)),
+        argnums=(0, 1, 2, 3, 4)))(*args)
+    exps = [e for e in equations(jaxpr) if e.primitive.name == "exp"]
+    assert exps
+    assert max(int(np.prod(e.outvars[0].aval.shape)) for e in exps) \
+        <= b * t * h * 64            # a chunk's [C, C] matrix a head
+    assert jaxpr.out_avals[3].shape == (b, t, h)        # dg a row a head
+
+
+@pytest.mark.parametrize("axis", ["sp", "tp"])
+def test_gdn_path_refuses_a_split_sequence_or_split_heads(axis):
+    from ray_tpu.parallel import make_mesh
+    mesh = make_mesh({axis: 2}, devices=jax.devices()[:2])
+    with pytest.raises(NotImplementedError, match=f"{axis}=2"):
+        kda.gdn_path((1, 64, 2, 16), 16, mesh, heads=4)
+    assert kda.gdn_path((1, 64, 2, 16), 16, None, heads=4) == "xla_chunked"
+
+
+@pytest.mark.parametrize("case, shape, heads, chunk, want", [
+    ("the cell's", (1, 16384, 16, 128), 32, 64, "pallas_chunked"),
+    ("one key head a value head", (1, 256, 4, 128), 4, 64, "pallas_chunked"),
+    ("a cell's two heads under three", (1, 256, 2, 128), 6, 64,
+     "xla_chunked"),
+    ("narrow keys", (1, 256, 2, 64), 4, 64, "xla_chunked"),
+    ("the tiny preset's chunk", (1, 256, 2, 128), 4, 16, "xla_chunked")])
+def test_gdn_path_takes_the_kernels_where_it_observes_they_fit(
+        monkeypatch, case, shape, heads, chunk, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert kda.gdn_path(shape, chunk, None, heads=heads) == want
+
+
+def head_three_ways(args, **kw):
+    """Of the kernels, of ``xla_chunked``, of the recurrence."""
+    return outputs_and_gradients(
+        args, lambda *a: kernels.gdn_scan(*a, interpret=True, **kw),
+        lambda *a: kda.gdn_scan(*a, chunk=64, **kw), widened(recurrence))
+
+
+@pytest.mark.parametrize("t, key_heads, heads", [
+    (128, 1, 1), (150, 2, 4),       # three chunks in two cells; 2 under 4
+    (128, 1, 2)])                   # a cell of two heads on one key head
+def test_the_kernels_with_a_decay_a_head_are_the_chunked_form_and_the_rows(
+        t, key_heads, heads):
+    got, xla, ref = head_three_ways(
+        head_operands(40 + heads, 1, t, key_heads, heads, 128, 128))
+    assert got[1][0].shape == (1, t, key_heads, 128)        # dq a key head
+    assert got[1][3].shape == (1, t, heads)                 # dg a row a head
+    all_close(got, xla, ref)
+
+
+def test_the_kernels_with_a_decay_a_head_at_decays_that_overflow():
+    args = head_operands(41, 1, 128, 2, 2, 128, 128, decay=8.0)
+    got, xla, ref = head_three_ways(args)
+    assert np.isfinite(np.asarray(got[0])).all()
+    all_close(got, xla, ref)
+
+
+def test_the_kernels_with_a_decay_a_head_norm_raw_q_and_k_a_key_head():
+    q, k, v, g, beta = raw_operands(42, 1, 200, 2)
+    _, _, v, g, beta = head_operands(42, 1, 200, 2, 4, 128, 128)
+
+    def by_row(q, k, *rest):
+        return widened(recurrence)(
+            kda.unit_rows(q) * q.shape[-1] ** -0.5, kda.unit_rows(k), *rest)
+    got, xla, ref = outputs_and_gradients(
+        (q, k, v, g, beta),
+        lambda *a: kernels.gdn_scan(*a, normalize_qk=True, interpret=True),
+        lambda *a: kda.gdn_scan(*a, chunk=64, normalize_qk=True), by_row)
+    all_close(got, xla, ref)
+
+
+def test_the_kernels_with_a_decay_a_head_hold_no_decay_as_wide_as_the_keys():
+    """What HBM sees of the decay: ``g`` in and ``dg`` out of both
+    ``pallas_call``s are [b, H, T / 128, 1, 128], a float a row a head,
+    and ``q``, ``k`` and their cotangents the key heads' alone."""
+    b, t, hk, h = 1, 256, 2, 4
+    args = head_operands(43, b, t, hk, h, 128, 128)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(kernels.gdn_scan(*a, interpret=True)),
+        argnums=(0, 1, 2, 3, 4)))(*args)
+    calls = [e for e in equations(jaxpr) if e.primitive.name == "pallas_call"]
+    assert len(calls) == 2
+    steps, keys, values = (b, h, t // 128, 1, 128), (b, t, hk * 128), \
+        (b, t, h * 128)
+    fwd, bwd = calls
+    assert [v.aval.shape for v in fwd.invars] == [
+        keys, keys, steps, values, steps]
+    assert [v.aval.shape for v in bwd.outvars] == [
+        keys, keys, steps, values, steps]

@@ -621,21 +621,25 @@ GATE_CASES = {
 }
 
 
+@pytest.mark.parametrize("gate_fn", ["sigmoid", "silu"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("case", GATE_CASES)
-def test_kernel_output_gate_is_the_xla_output_gate(monkeypatch, case, dtype):
+def test_kernel_output_gate_is_the_xla_output_gate(monkeypatch, case, dtype,
+                                                   gate_fn):
     """The value and all three gradients, as
     ``test_kernel_norm_is_the_xla_norm`` holds the other form:
     ``scale``'s gradient is a float32 sum over the rows and the heads
-    either way."""
+    either way. Under either gate's function: Kimi Delta Attention's
+    sigmoid and Gated DeltaNet's ``silu`` are a kernel pair each."""
     shape, heads = GATE_CASES[case]
     monkeypatch.setattr(gated_norm, "_BLOCK_BYTES",
                         128 * shape[-1] * jnp.dtype(dtype).itemsize)
     args = _gate_inputs(shape, heads, dtype, seed=shape[1])
-    got = _value_and_grads(_gate_kernel(heads), *args)
+    got = _value_and_grads(_gate_kernel(heads, gate_fn=gate_fn), *args)
     want = _value_and_grads(
-        lambda *a: ssm.sigmoid_gated_head_rms_norm(*a, heads, 1e-5), *args)
+        lambda *a: ssm.sigmoid_gated_head_rms_norm(
+            *a, heads, 1e-5, gate_fn=gate_fn), *args)
     step = 1e-5 if dtype == jnp.float32 else 2 ** -7
     for name, g, w in zip("out do dgate dscale".split(), got, want):
         assert g.dtype == w.dtype and g.shape == w.shape, name
@@ -704,6 +708,10 @@ def test_output_gate_notes_the_path_a_mesh_gives_it(monkeypatch, axes, batch,
     assert seen == {"kda_gate_path": path}
     assert (ran, batch_axes) == (("kernels", ("dp",)) if path == "pallas"
                                  else ("xla", None))
+    seen.clear()    # Gated DeltaNet's gate: the same rule, its own note
+    assert ssm.sigmoid_gated_head_rms_norm(
+        o, o, None, 32, 1e-5, mesh=mesh, gate_fn="silu")[0] == ran
+    assert seen == {"gdn_gate_path": path}
 
 
 def test_kernel_output_gate_over_a_batch_sharded_mesh_is_the_one_device_gate():
