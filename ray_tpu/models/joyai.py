@@ -47,7 +47,8 @@ things this file had not expressed:
 ``remat`` recomputes each block in the backward pass but for its
 attention core's output and row statistics (``ops/attention.py::
 remat_policy``, as ``models/kimi_linear.py``); what a recomputed block
-keeps is its input state, ``hc_mult`` streams wide, and at ``hc_mult`` >
+keeps is its input state, ``hc_mult`` streams wide, its router's
+product and choice (``ops/moe.py::ROUTER_KEEPS``), and at ``hc_mult`` >
 1 each sub-layer's residual maps with the 25 floats a token their
 backward kernel reads (``ops/pallas/hc_maps.py::MAPS_KEEPS``), so the
 second pass runs ``pre`` and ``post`` from them and the maps' norm,
@@ -89,7 +90,7 @@ from ray_tpu.models.nemotron_h import _Router   # gate: [d, E] and its bias
 from ray_tpu.ops import hyper_connections as hc
 from ray_tpu.ops.attention import remat_keeps, remat_policy
 from ray_tpu.ops.mla import UpProjections, latent_attention
-from ray_tpu.ops.moe import held_route_share, routed_ffn
+from ray_tpu.ops.moe import ROUTER_KEEPS, held_route_share, routed_ffn
 from ray_tpu.util import tracing
 
 
@@ -463,10 +464,14 @@ class Block(nn.Module):
 
 def _block_keeps(cfg: JoyAIConfig) -> tuple[str, ...]:
     """The names a recomputed block keeps beside its attention core's:
-    at ``hc_mult`` > 1 each sub-layer's residual maps and what their
-    backward kernel reads (41 floats a token a sub-layer; on the maps'
-    XLA path nothing carries the names)."""
-    return hc.MAPS_KEEPS if cfg.hc_mult > 1 else ()
+    its router's product, choice, chosen scores and counts
+    (``ops/moe.py::ROUTER_KEEPS``: ``[T, E]`` and three ``[T, k]``
+    float32, so that the second pass runs neither the float32 product
+    nor the choice again), and at ``hc_mult`` > 1 each sub-layer's
+    residual maps and what their backward kernel reads (41 floats a
+    token a sub-layer; on the maps' XLA path nothing carries the
+    names)."""
+    return (*ROUTER_KEEPS, *(hc.MAPS_KEEPS if cfg.hc_mult > 1 else ()))
 
 
 def _block(cfg: JoyAIConfig):

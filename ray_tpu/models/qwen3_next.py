@@ -51,10 +51,11 @@ routed MLP. The equations, as this file runs them:
 blocks, as ``models/kimi_linear.py``; the note ``blocks_remat_keeps``
 lists the names), dearest a byte first: the attention core's output and
 row statistics (the flash forward kernel at 256 lanes, 0.14 GB); the
-router's float32 product and the routes each expert received
-(``ops/moe.py::ROUTER_KEEPS``, 34 MB a layer: 512 experts wide; of the
-four names the softmax router makes those two: ``top_k`` runs again,
-``ops/moe.py::_route`` has why); the
+router's float32 product, its choice, chosen probabilities, counts and
+``logsumexp`` (``ops/moe.py::ROUTER_KEEPS``, 35 MB a layer: 512 experts
+wide; since PR 68 the choice is ``ops/pallas/router_choice.py``'s kernel
+pair, which names all of them, so neither it nor a ``top_k`` runs
+again); the
 mixers' output projections' products (``_MIXER_PROJ``, 67 MB: the stream
 between a block's halves is then one add); a Gated DeltaNet mixer's
 gated output (``_GDN_OUT``, 134 MB) and the recurrence's forward

@@ -210,6 +210,7 @@ class Router(nn.Module):
     """The bias-free [d, E] router on the block's normed input: (weights
     [B, T, k] float32, experts [B, T, k])."""
     config: SmallThinkerConfig
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, h):
@@ -217,7 +218,8 @@ class Router(nn.Module):
         kernel = self.param("kernel", nn.initializers.normal(0.02),
                             (cfg.n_embd, cfg.num_experts), cfg.param_dtype)
         return route_softmax(h, kernel, top_k=cfg.top_k,
-                             norm_topk_prob=cfg.norm_topk_prob)
+                             norm_topk_prob=cfg.norm_topk_prob,
+                             mesh=self.mesh)
 
 
 class Experts(nn.Module):
@@ -258,7 +260,7 @@ class Block(nn.Module):
         # The routed layer's first piece, run before attention: under
         # ``mlp`` so that the readers of a routed layer's time find it.
         with jax.named_scope("mlp"):
-            weights, experts = Router(cfg, name="router")(h)
+            weights, experts = Router(cfg, self.mesh, name="router")(h)
         x = x + Attention(cfg, windowed, name="attn")(
             h, attn_fns[windowed], angles if cfg.rotated(self.layer) else None)
         return x + Experts(cfg, self.mesh, name="mlp")(

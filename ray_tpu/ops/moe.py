@@ -1,7 +1,11 @@
 """Mixture-of-Experts feed-forward layers. Two paths live here.
 
 ``routed_ffn`` is the one public models use: top-k, **dropless**.
-The float32 router, ``top_k``, the ``tokens x k`` routes sorted by
+The float32 router (its product in XLA at the highest precision; the
+choice, the scores, the ``top_k``, the chosen weights, the counts and
+the two sums, one Pallas kernel forward and one backward on a TPU,
+``ops/pallas/router_choice.py``, PR 68; ``top_k``, a gather and
+scatter-adds elsewhere), the ``tokens x k`` routes sorted by
 expert, grouped matmuls over the ragged groups in bf16 with float32
 accumulation, un-sort, weighted combine. No capacity, no dropped
 route: every route to an expert held here is computed whatever the
@@ -59,13 +63,16 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.pallas import route_rows
+from ray_tpu.ops.pallas import route_rows, router_choice
+from ray_tpu.ops.pallas.router_choice import (
+    ROUTER_COUNTS, ROUTER_EXPERTS, ROUTER_LSE, ROUTER_WEIGHTS)
 from ray_tpu.util import tracing
 
 
@@ -247,74 +254,105 @@ def _unsort_bwd(order, g):
 _unsort.defvjp(_unsort_fwd, _unsort_bwd)
 
 
-def _route(x, router_w, top_k: int, norm_topk_prob: bool):
-    """float32 router of ``[T, d]`` tokens: (weights [T, k], experts
-    [T, k], sum over tokens of the probabilities [E], sum over tokens
-    of logsumexp(logits)^2). The product carries ``ROUTER_LOGITS``, so
-    that a recomputed block whose policy lists it runs the float32
-    matmul once. ``top_k`` it runs again: the chosen probabilities are
-    ``top_k``'s own values, differentiated through its own indices,
-    which no name reaches; taking them by a gather with a named choice
-    (``_route_sigmoid``'s way) spares the second ``top_k`` and costs as
-    much again at 512 experts (XLA's gather of ``[16384, 10]`` and its
-    scatter-add: 1.7 ms a layer each; PERF.md section 6, PR 67), and
-    would put that gather into every softmax-routed step, recomputed
-    or not."""
-    logits = checkpoint_name(
+def _logits(x, router_w):
+    """A router's float32 product ``x W`` at the highest precision,
+    under ``ROUTER_LOGITS``: a recomputed block whose policy lists the
+    name runs the float32 matmul once."""
+    return checkpoint_name(
         jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                 precision=lax.Precision.HIGHEST), ROUTER_LOGITS)
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    probs = jnp.exp(logits - lse[:, None])
-    weights, experts = lax.top_k(probs, top_k)
+
+
+def _route(x, router_w, top_k: int, norm_topk_prob: bool, path: str = "xla"):
+    """float32 softmax router of ``[T, d]`` tokens: (weights [T, k],
+    experts [T, k], sum over tokens of the probabilities [E], sum over
+    tokens of logsumexp(logits)^2, the routes each expert received [E]
+    or None where the caller counts them). ``path``
+    (``router_choice.router_path``'s word) says who chooses between the
+    product and those: ``"pallas"`` the kernel pair of
+    ``ops/pallas/router_choice.py`` (``"interpret"``: the same,
+    interpreted), which makes the probabilities, the ``top_k``, the
+    counts and both sums in one pass over the product and its backward
+    in one more, and names its choice, chosen probabilities, counts and
+    ``lse`` (``ROUTER_KEEPS``), so that a recomputed block that keeps
+    them runs neither the kernel nor a ``top_k`` again; ``"xla"``
+    today's lines for the CPU, shapes that do not tile and one program
+    over several devices: the chosen probabilities are ``top_k``'s own
+    values, differentiated through its own indices, which no name
+    reaches, so there a recomputed block runs ``top_k`` twice (taking
+    them by a gather with a named choice costs as much again at 512
+    experts: 1.7 ms a layer for the gather and for its scatter-add,
+    PERF.md section 6, PR 67)."""
+    logits = _logits(x, router_w)
+    if path != "xla":
+        weights, experts, counts, prob_sum, lse = router_choice.router_choice(
+            logits, top_k=top_k, activation="softmax",
+            interpret=path == "interpret")
+    else:
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        probs = jnp.exp(logits - lse[:, None])
+        weights, experts = lax.top_k(probs, top_k)
+        counts, prob_sum = None, probs.sum(axis=0)
     if norm_topk_prob:
         weights = weights / weights.sum(axis=-1, keepdims=True)
-    return weights, experts, probs.sum(axis=0), jnp.sum(lse * lse)
+    return weights, experts, prob_sum, jnp.sum(lse * lse), counts
 
 
-# The names of what the sigmoid router makes of a layer's ``[T, d]``
-# tokens, for a recomputed block's policy (``ops/attention.py::
+# The names of what a router makes of a layer's ``[T, d]`` tokens, for a
+# recomputed block's policy (``ops/attention.py::
 # remat_policy``; the identity under any other, and outside one): the
 # product ``x W``, float32 ``[T, E]``, so that the float32 matmul at the
 # highest precision runs once (in front of the sigmoid, not behind it:
 # a policy keeps a name's result, and the sigmoid's backward reads the
 # sigmoid's own, so a name behind it would be kept and never read; the
-# second pass makes the sigmoid again from the kept product, one fused
-# pass); the chosen ``experts`` and their scores ``[T, k]``, so that
-# neither ``top_k`` nor the gather runs again; and the routes each
-# expert received, ``[E]`` (``_routed_ffn_local``, any router's), a
-# scatter-add of ``T k`` ones that the grouped matmuls' sizes would
-# otherwise be made from a second time.
+# second pass makes the scores again from the kept product, in the
+# backward kernel's VMEM or as one fused pass); the chosen ``experts``
+# and their scores ``[T, k]``, so that neither the choice's kernel nor
+# ``top_k`` and the gather run again; the routes each expert received,
+# ``[E]`` (the kernel's, or ``_routed_ffn_local``'s scatter-add of
+# ``T k`` ones for any router's), that the grouped matmuls' sizes would
+# otherwise be made from a second time; and the softmax router's
+# ``logsumexp`` a token ``[T]``, which only the kernel's forward makes
+# and its backward reads (``ops/pallas/router_choice.py`` names the last
+# four in its forward rule).
 ROUTER_LOGITS = "moe_router_logits"
-ROUTER_EXPERTS = "moe_router_experts"
-ROUTER_WEIGHTS = "moe_router_weights"
-ROUTER_COUNTS = "moe_router_counts"
 ROUTER_KEEPS = (ROUTER_LOGITS, ROUTER_EXPERTS, ROUTER_WEIGHTS,
-                ROUTER_COUNTS)
+                ROUTER_COUNTS, ROUTER_LSE)
 
 
 def _route_sigmoid(x, router_w, select_bias, top_k: int,
-                   norm_topk_prob: bool, route_scale: float):
+                   norm_topk_prob: bool, route_scale: float,
+                   path: str = "xla"):
     """The float32 sigmoid router (DeepSeek-V3's, Nemotron-H's): every
     expert scored ``s = sigmoid(x . W)`` on its own; the ``top_k`` are
     chosen by ``s + select_bias`` (the score-correction bias: it moves
     the choice and carries no gradient), their weights are ``s``
     **without** it, divided by their sum (+1e-20) under
     ``norm_topk_prob``, times ``route_scale``. No auxiliary loss
-    belongs to it: the last two returns are zeros. The product, the
-    choice and the chosen scores carry ``ROUTER_KEEPS``'s names, for a
-    recomputed block whose policy lists them."""
-    scores = jax.nn.sigmoid(checkpoint_name(jnp.dot(
-        x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST), ROUTER_LOGITS))
-    _, experts = lax.top_k(
-        scores + lax.stop_gradient(select_bias.astype(jnp.float32)), top_k)
-    experts = checkpoint_name(experts, ROUTER_EXPERTS)
-    weights = checkpoint_name(
-        jnp.take_along_axis(scores, experts, axis=-1), ROUTER_WEIGHTS)
+    belongs to it: the two sums are zeros. ``path`` as ``_route``'s:
+    the kernel pair, or ``top_k`` and a gather of the chosen scores.
+    The product, the choice and the chosen scores carry
+    ``ROUTER_KEEPS``'s names on either, for a recomputed block whose
+    policy lists them."""
+    logits = _logits(x, router_w)
+    if path != "xla":
+        weights, experts, counts, _, _ = router_choice.router_choice(
+            logits, select_bias, top_k=top_k, activation="sigmoid",
+            interpret=path == "interpret")
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, experts = lax.top_k(
+            scores + lax.stop_gradient(select_bias.astype(jnp.float32)),
+            top_k)
+        experts = checkpoint_name(experts, ROUTER_EXPERTS)
+        weights = checkpoint_name(
+            jnp.take_along_axis(scores, experts, axis=-1), ROUTER_WEIGHTS)
+        counts = None
     if norm_topk_prob:
         weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
     return (weights * route_scale, experts,
-            jnp.zeros((router_w.shape[-1],), jnp.float32), jnp.float32(0))
+            jnp.zeros((router_w.shape[-1],), jnp.float32), jnp.float32(0),
+            counts)
 
 
 _GATES = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}
@@ -477,20 +515,23 @@ def _held_part(x, flat, weights, counts, w_gate, w_up, w_down, top_k,
                   counts[first:first + held], w_gate, w_up, w_down)
 
 
-def _given(x, weights, experts):
+def _given(x, weights, experts, path: str = "xla"):
     """The routes a caller made (``routed_experts``), a row a token;
-    no auxiliary loss belongs to them here: the last two are zeros."""
+    no auxiliary loss belongs to them here (the two sums are zeros),
+    and their counts are the layer's to make."""
     k = weights.shape[-1]
     return (weights.reshape(-1, k).astype(jnp.float32),
-            experts.reshape(-1, k), jnp.float32(0), jnp.float32(0))
+            experts.reshape(-1, k), jnp.float32(0), jnp.float32(0), None)
 
 
 def _routed_ffn_local(x, route_args, w_gate, w_up, w_down, *, route,
                       num_experts, top_k, over=(), experts_held=None,
-                      kind="swiglu", rows_path="xla"):
+                      kind="swiglu", rows_path="xla", router_path="xla"):
     """The layer on the tokens in hand (``[..., d]``), their routes
-    made by ``route(x, *route_args)`` (``_route``, ``_route_sigmoid``
-    or ``_given``); the router's sums are added over the mesh axes
+    made by ``route(x, *route_args, path=router_path)`` (``_route``,
+    ``_route_sigmoid`` or ``_given``; the routes each expert received
+    are the router's kernel's, or where it gives none a scatter-add of
+    ``T k`` ones here); the router's sums are added over the mesh axes
     ``over`` so that the two losses and the load are those of the
     global batch. ``w_gate`` None: the two-matrix relu^2 expert; else
     ``kind`` is the gated expert's (``_experts``)."""
@@ -498,10 +539,12 @@ def _routed_ffn_local(x, route_args, w_gate, w_up, w_down, *, route,
     x = x.reshape(-1, shape[-1])
     t, e = x.shape[0], num_experts
     with jax.named_scope("router"):
-        weights, experts, prob_sum, z_sum = route(x, *route_args)
+        weights, experts, prob_sum, z_sum, counts = route(
+            x, *route_args, path=router_path)
         flat = experts.reshape(-1)
-        counts = checkpoint_name(
-            jnp.zeros((e,), jnp.int32).at[flat].add(1), ROUTER_COUNTS)
+        if counts is None:
+            counts = checkpoint_name(
+                jnp.zeros((e,), jnp.int32).at[flat].add(1), ROUTER_COUNTS)
         total = (counts.astype(jnp.float32), prob_sum, z_sum,
                  jnp.float32(t))
         if over:
@@ -578,7 +621,14 @@ def routed_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     ``router``: ``"softmax"`` (OLMoE: probabilities over all experts,
     the ``top_k`` largest, renormalised only with ``norm_topk_prob``)
     or ``"sigmoid"`` (Nemotron-H, DeepSeek-V3: ``_route_sigmoid``, with
-    ``select_bias`` [E] and ``route_scale``). ``expert``: ``"swiglu"``
+    ``select_bias`` [E] and ``route_scale``). Either chooses on the
+    path ``router_choice.router_path`` names for a chip's tokens: the
+    kernel pair of ``ops/pallas/router_choice.py`` on a TPU where the
+    shapes tile (inside the ``shard_map`` below a shard at a time),
+    XLA's ``top_k``, gather and scatter-adds on the CPU, for shapes
+    that do not tile and where the layer is one global program over
+    several devices; the same routes, weights and gradients on both,
+    said on the trace span as ``moe_router_path``. ``expert``: ``"swiglu"``
     (three matrices, ``down(silu(gate x) * up x)``), ``"reglu"`` (the
     same three with ``relu`` on the gate: SmallThinker's) or ``"relu2"``
     (two, ``down(relu(up x)^2)``; ``w_gate`` is None). ``experts_held =
@@ -677,7 +727,8 @@ def routed_experts(x, weights, experts, w_gate, w_up, w_down, *,
     return y, load
 
 
-def route_softmax(x, router_w, *, top_k: int, norm_topk_prob: bool = False):
+def route_softmax(x, router_w, *, top_k: int, norm_topk_prob: bool = False,
+                  mesh=None):
     """``routed_ffn``'s softmax router for a caller that routes from
     another place than its experts' input: ``x`` [..., d] against the
     bias-free ``router_w`` [d, E] in float32 at the highest precision,
@@ -685,11 +736,52 @@ def route_softmax(x, router_w, *, top_k: int, norm_topk_prob: bool = False):
     under ``norm_topk_prob`` (which is a softmax over the chosen logits
     alone). Returns ``(weights [..., k], experts [..., k])`` for
     ``routed_experts``; a router is a few values a token, so each chip
-    routes the tokens it holds under any sharding of them."""
-    weights, experts, _, _ = _route(x.reshape(-1, x.shape[-1]), router_w,
-                                    top_k, norm_topk_prob)
-    return (weights.reshape(*x.shape[:-1], top_k),
-            experts.reshape(*x.shape[:-1], top_k))
+    routes the tokens it holds under any sharding of them: on a
+    ``mesh`` that shards tokens the choice's kernel pair runs a shard
+    at a time under ``shard_map``, as ``routed_ffn``'s, and the plain
+    lines stay one global program. The path is said on the trace span
+    as ``moe_router_path``."""
+    shards = _token_shards(mesh, x)
+    path = ("xla" if shards.one_program else router_choice.router_path(
+        shards.tokens, router_w.shape[-1], top_k))
+
+    def local(x, router_w):
+        weights, experts, *_ = _route(x.reshape(-1, x.shape[-1]), router_w,
+                                      top_k, norm_topk_prob, path)
+        return (weights.reshape(*x.shape[:-1], top_k),
+                experts.reshape(*x.shape[:-1], top_k))
+
+    if shards.axes and path != "xla":
+        from jax.sharding import PartitionSpec as P
+        local = jax.shard_map(
+            local, mesh=mesh, in_specs=(shards.spec, P()),
+            out_specs=(shards.spec, shards.spec), check_vma=False)
+    tracing.note_trace(moe_router_path=path)
+    return local(x, router_w)
+
+
+class _Shards(NamedTuple):
+    """How a mesh shards a ``[batch, seq, d]`` (or ``[tokens, d]``)
+    activation's tokens: the axes of ``_token_axes`` in one tuple, the
+    activation's ``PartitionSpec`` over them, a chip's tokens, and
+    whether the layer is one global program over several devices (no
+    axis shards the tokens of a mesh of more than one), where the plain
+    forms run: a bare ``pallas_call`` has no SPMD rule."""
+    axes: tuple
+    spec: Any
+    tokens: int
+    one_program: bool
+
+
+def _token_shards(mesh, x) -> _Shards:
+    from jax.sharding import PartitionSpec as P
+    batch_axes, seq_axis = _token_axes(
+        mesh, x.shape[0], x.shape[1] if x.ndim == 3 else 1)
+    axes = batch_axes + ((seq_axis,) if seq_axis else ())
+    return _Shards(axes, P(batch_axes or None, seq_axis),
+                   math.prod(x.shape[:-1]) // math.prod(
+                       mesh.shape[a] for a in axes),
+                   not axes and mesh is not None and mesh.size > 1)
 
 
 def _routed(x, route, route_args, per_token: bool, w_gate, w_up, w_down, *,
@@ -703,22 +795,19 @@ def _routed(x, route, route_args, per_token: bool, w_gate, w_up, w_down, *,
     if not (0 <= first and first + held <= e and w_up.shape[0] == held):
         raise ValueError(f"experts_held {experts_held} of {e} experts, "
                          f"weights for {w_up.shape[0]}")
-    batch_axes, seq_axis = _token_axes(
-        mesh, x.shape[0], x.shape[1] if x.ndim == 3 else 1)
-    axes = batch_axes + ((seq_axis,) if seq_axis else ())
-    tokens = math.prod(x.shape[:-1]) // math.prod(
-        mesh.shape[a] for a in axes)                    # a chip's
+    axes, held_spec, tokens, one_program = _token_shards(mesh, x)
     rows = held_rows(tokens * top_k, held, e)
-    # one global program over several devices: the plain form (a bare
-    # ``pallas_call`` has no SPMD rule)
-    rows_path = ("xla" if not axes and mesh is not None and mesh.size > 1
+    rows_path = ("xla" if one_program
                  else route_rows.rows_path(tokens, rows))
+    # a caller's routes (``per_token``) were chosen elsewhere
+    router_path = ("xla" if one_program or per_token
+                   else router_choice.router_path(tokens, e, top_k))
     local = functools.partial(
         _routed_ffn_local, route=route, num_experts=e, top_k=top_k,
-        experts_held=experts_held, kind=kind, rows_path=rows_path)
+        experts_held=experts_held, kind=kind, rows_path=rows_path,
+        router_path=router_path)
     if axes:
         from jax.sharding import PartitionSpec as P
-        held_spec = P(batch_axes or None, seq_axis)
         # All mesh axes manual, as in ops/attention.py and the chunked
         # cross-entropy: the weights enter replicated, so the transpose
         # sums their gradients over the axes once.
@@ -741,6 +830,8 @@ def _routed(x, route, route_args, per_token: bool, w_gate, w_up, w_down, *,
             said, moe_experts_held=[first, held], moe_rows_sorted=rows)
         if experts_held is not None:
             notes.update(moe_rows_path=rows_path)
+    if not per_token:
+        notes.update(moe_router_path=router_path)
     tracing.note_trace(**notes)
     return out
 

@@ -197,6 +197,44 @@ def kernel_kinds(lines) -> list[str]:
             for line in lines]
 
 
+def scope_primitives(lowered, scope: str) -> set[str]:
+    """The primitives the lowered program runs right under ``scope``
+    (``/mlp/router/``): the last piece of every operation's name stack
+    that holds it (``top_k``, ``gather``, ``scatter-add``,
+    ``jit(_choice_fwd)``, ``dot_general``), whatever layer and pass."""
+    import re
+    return set(re.findall(
+        r'^#loc\d+ = loc\("[^"]*%s([^"/]*)"' % re.escape(scope),
+        lowered.as_text(debug_info=True), re.M))
+
+
+def router_choice_calls(lowered, layers: int, *shapes: str,
+                        counts_scattered: bool = False) -> None:
+    """The routers' choice of a lowered step on the ``pallas`` path
+    (``ops/pallas/router_choice.py``): the forward and the backward
+    kernel under ``.../mlp/router`` once a routed layer each, on the
+    transposed product ``shapes[0]`` (``f32[512,16384]``) and the
+    ``[k, T]`` arrays ``shapes[1:]``, none under ``rematted_computation``
+    (a recomputed block keeps what the forward kernel made), and no
+    ``top_k``, gather or scatter left beside them (``counts_scattered``:
+    but the counts' scatter-add, where the routes reach the experts
+    through ``routed_experts``, which counts them itself)."""
+    calls = kernel_calls(lowered)
+    choice = [(kind, line) for kind, line in zip(kernel_kinds(calls), calls)
+              if kind.startswith("_choice_")]
+    assert sorted(kind for kind, _ in choice) == (
+        ["_choice_bwd"] * layers + ["_choice_fwd"] * layers)
+    for kind, line in choice:
+        assert "/mlp/router/jit(%s)/pallas_call" % kind in line, line
+        assert "rematted_computation" not in line, line
+        assert all(shape in line for shape in shapes), line
+    left = scope_primitives(lowered, "/mlp/router/")
+    assert {"jit(_choice_fwd)", "jit(_choice_bwd)", "dot_general"} <= left
+    assert [p for p in left if "top_k" in p or "gather" in p
+            or "scatter" in p or "sort" in p] == (
+                ["scatter-add"] if counts_scattered else []), left
+
+
 def equations(jaxpr):
     """Every equation of a jaxpr and of the jaxprs its equations hold."""
     for eqn in jaxpr.eqns:

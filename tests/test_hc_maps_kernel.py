@@ -14,6 +14,7 @@ import pytest
 from conftest import equations
 
 from ray_tpu.ops import hyper_connections as hc
+from ray_tpu.ops.moe import ROUTER_KEEPS
 from ray_tpu.ops.pallas import hc_maps as kernels
 from ray_tpu.parallel import make_mesh
 
@@ -226,11 +227,14 @@ def test_recomputed_blocks_on_the_kernels_give_the_xla_paths_numbers(
 
 
 @pytest.mark.parametrize("hc_mult, keeps", [
-    (4, (*kernels.MAPS_KEEPS, "attn_out", "attn_lse")),
-    (1, ("attn_out", "attn_lse"))], ids=["four_streams", "one_stream"])
+    (4, (*ROUTER_KEEPS, *kernels.MAPS_KEEPS, "attn_out", "attn_lse")),
+    (1, (*ROUTER_KEEPS, "attn_out", "attn_lse"))],
+    ids=["four_streams", "one_stream"])
 def test_what_a_recomputed_block_keeps_by_name(monkeypatch, hc_mult, keeps):
-    """The policy's names at ``hc_mult`` 4, and at ``hc_mult`` 1 the
-    parent's (JoyAI's and Kimi-Linear's blocks lower as they did)."""
+    """The policy's names at ``hc_mult`` 4 and at ``hc_mult`` 1: the
+    routers' first (since PR 68 a recomputed block runs neither its
+    router's product nor the choice again), then the maps' where there
+    are streams, then the cores' two."""
     from ray_tpu.models import joyai
     from ray_tpu.ops import attention
     asked = []

@@ -466,8 +466,8 @@ def test_a_mixer_hands_its_mesh_to_the_one_output_gate(monkeypatch, axes):
 
 @pytest.mark.parametrize("remat, keeps", [
     (True, "kda_gated_out,kda_scan_out,kda_scan_states,moe_router_logits,"
-     "moe_router_experts,moe_router_weights,moe_router_counts,attn_out,"
-     "attn_lse"),
+     "moe_router_experts,moe_router_weights,moe_router_counts,"
+     "moe_router_lse,attn_out,attn_lse"),
     (False, "")],
     ids=["recomputed", "kept_whole"])
 def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,

@@ -446,11 +446,11 @@ def test_a_train_step_runs_and_reports_the_load_of_every_routed_layer():
     assert float(metrics["lm_loss"]) == float(metrics["loss"])
 
 
-# ``blocks_remat_keeps``: the routers' four, ``W_o``'s product, q, k and
+# ``blocks_remat_keeps``: the routers' five, ``W_o``'s product, q, k and
 # v, the dense and shared MLPs' two, the cores' two
 KEEPS_NOTE = ("moe_router_logits,moe_router_experts,moe_router_weights,"
-              "moe_router_counts,attn_out_proj,attn_q,attn_k,attn_v,"
-              "mlp_gate,mlp_up,attn_out,attn_lse")
+              "moe_router_counts,moe_router_lse,attn_out_proj,attn_q,attn_k,"
+              "attn_v,mlp_gate,mlp_up,attn_out,attn_lse")
 
 
 @pytest.mark.parametrize("remat, keeps", [

@@ -211,7 +211,7 @@ def test_the_held_layer_notes_what_it_is_at_trace_time(monkeypatch):
         "moe_routes": T * K, "moe_path": "ragged_dot", "moe_axes": [],
         "moe_router": "sigmoid", "moe_expert_kind": "relu2",
         "moe_experts_held": [4, 2], "moe_rows_sorted": T * K // 4,
-        "moe_rows_path": "xla"}
+        "moe_rows_path": "xla", "moe_router_path": "xla"}
 
 
 def test_held_experts_route_their_own_tokens_on_a_dp_mesh():

@@ -294,7 +294,7 @@ def test_the_routed_layer_notes_its_path_at_trace_time(monkeypatch):
     jax.jit(lambda *a: routed_ffn(*a, top_k=2)[0]).trace(*_layer_inputs())
     assert notes == {"moe_tokens": 96, "moe_experts": 8, "moe_top_k": 2,
                      "moe_routes": 192, "moe_path": "ragged_dot",
-                     "moe_axes": []}
+                     "moe_axes": [], "moe_router_path": "xla"}
 
 
 def test_sharding_patterns_name_the_routed_parameters():

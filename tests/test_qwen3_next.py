@@ -358,8 +358,8 @@ def test_the_step_reports_the_keys_and_notes_what_the_layers_are(
 
 @pytest.mark.parametrize("remat, keeps", [
     (True, "moe_router_logits,moe_router_experts,moe_router_weights,"
-     "moe_router_counts,mixer_out_proj,gdn_gated_out,kda_scan_out,"
-     "kda_scan_states,gdn_in_proj,attn_out,attn_lse"),
+     "moe_router_counts,moe_router_lse,mixer_out_proj,gdn_gated_out,"
+     "kda_scan_out,kda_scan_states,gdn_in_proj,attn_out,attn_lse"),
     (False, "")],
     ids=["recomputed", "kept_whole"])
 def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,

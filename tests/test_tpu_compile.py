@@ -345,9 +345,14 @@ def test_routed_experts_compile_for_v5e(v5e, monkeypatch):
     tokens, 64 experts x 1,024, top-8), forward and backward: on a TPU
     the grouped matmuls are the megablox Pallas kernel, which Mosaic has
     to take at the tile ``ops/moe.py`` chose (nine custom calls: three
-    matrices, each forward, for its input and for its weights)."""
+    matrices, each forward, for its input and for its weights). The
+    softmax router over the 64 experts, both its sums read by the loss,
+    chooses by ``ops/pallas/router_choice.py``'s pair."""
     from ray_tpu.ops import moe
+    from ray_tpu.util import tracing
 
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert moe.grouped_matmul_path() == "megablox_gmm"
     one = SingleDeviceSharding(v5e[0])
@@ -364,6 +369,57 @@ def test_routed_experts_compile_for_v5e(v5e, monkeypatch):
         arg((64, 2048, 1024), jnp.float32), arg((64, 2048, 1024), jnp.float32),
         arg((64, 1024, 2048), jnp.float32)).compile().as_text()
     assert text.count("tpu_custom_call") >= 9
+    # the router's choice is the kernel pair, once each, under the
+    # layer's ``router`` scope, and no ``top_k`` or gather beside it
+    assert notes["moe_router_path"] == "pallas"
+    for kernel in ("_choice_fwd", "_choice_bwd"):
+        assert len(re.findall(
+            r"custom-call\(.*router\)*/jit\(%s\)\)*/pallas_call" % kernel,
+            text)) == 1, kernel
+    assert not re.search(r"router\)*/(top_k|gather|scatter)", text)
+
+
+@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
+def test_the_routers_choice_compiles_under_shard_map_on_dp4(
+        v5e, monkeypatch, router):
+    """A router over a batch that four chips share (dp=4): the choice's
+    kernel pair a shard at a time under ``shard_map``, over a chip's own
+    4,096 tokens, forward and backward, and one global program over the
+    four chips (a batch they do not divide) on XLA's lines."""
+    from ray_tpu.ops import moe
+    from ray_tpu.util import tracing
+
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(v5e, ("dp",))
+
+    def arg(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def loss(x, w, bias):
+        if router == "softmax":
+            return moe.route_softmax(x, w, top_k=8, mesh=mesh)[0].sum()
+        shards = moe._token_shards(mesh, x)
+        route = jax.shard_map(
+            lambda x, w, bias: moe._route_sigmoid(
+                x.reshape(-1, x.shape[-1]), w, bias, 8, True, 2.5,
+                "pallas")[0],
+            mesh=mesh, in_specs=(shards.spec, P(), P()),
+            out_specs=P(shards.axes), check_vma=False)
+        return route(x, w, bias).sum()
+
+    args = (arg((256, 256), jnp.float32), arg((256,), jnp.float32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        arg((4, 4096, 256), jnp.bfloat16, P("dp")), *args
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "f32[256,4096]" in text              # a chip's own tokens
+    if router == "softmax":
+        assert notes.pop("moe_router_path") == "pallas"
+        jax.jit(loss).trace(arg((3, 4096, 256), jnp.bfloat16), *args)
+        assert notes["moe_router_path"] == "xla"
 
 
 # hidden [B, S, E] and the rows of the table, the mesh the batch is over

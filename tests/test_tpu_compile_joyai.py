@@ -11,7 +11,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
 from conftest import (  # noqa: E402
-    kernel_calls, lower_real_size_step, on_device, program_bytes)
+    kernel_calls, lower_real_size_step, on_device, program_bytes,
+    router_choice_calls)
 
 
 def _compiled_kernels(device, t, heads=32):
@@ -99,6 +100,10 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     head = [line for line in calls if "jit(_ce_lse_fwd)" in line]
     assert len(head) == 2 and all("/loss/" in line for line in head)
     assert sum(bool(re.search(r"loss\)?/mtp/", line)) for line in head) == 1
+    # the routers' choice (four layers and the MTP module's block): the
+    # kernel pair once a layer, no ``top_k`` or gather left
+    assert notes["moe_router_path"] == "pallas"
+    router_choice_calls(lowered, 5, "f32[256,8192]", "i32[8,8192]")
     assert "8192x8192" not in lowered.as_text()
 
 

@@ -10,7 +10,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import pytest  # noqa: E402
 from conftest import (  # noqa: E402
-    kernel_calls, kernel_kinds, lower_real_size_step, program_bytes)
+    kernel_calls, kernel_kinds, lower_real_size_step, program_bytes,
+    router_choice_calls)
 
 
 @pytest.fixture(scope="module")
@@ -58,8 +59,8 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["attn_layers"] == "KKKMK" and notes["blocks_remat"] is True
     assert notes["blocks_remat_keeps"] == (
         "kda_gated_out,kda_scan_out,kda_scan_states,moe_router_logits,"
-        "moe_router_experts,moe_router_weights,moe_router_counts,attn_out,"
-        "attn_lse")
+        "moe_router_experts,moe_router_weights,moe_router_counts,"
+        "moe_router_lse,attn_out,attn_lse")
     assert notes["kda_path"] == "pallas_chunked" and notes["kda_chunk"] == 64
     assert notes["kda_heads"] == 32 and notes["kda_state"] == [128, 128]
     assert notes["conv_path"] == "pallas"
@@ -73,6 +74,10 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["moe_experts_held"] == [0, 8]
     assert notes["moe_rows_sorted"] == 8192     # twice the even share
     assert notes["moe_path"] == "megablox_gmm"
+    # the four routers' choice: the kernel pair once a layer, nothing of
+    # it under ``rematted_computation``, no ``top_k`` or gather left
+    assert notes["moe_router_path"] == "pallas"
+    router_choice_calls(lowered, 4, "f32[256,16384]", "i32[8,16384]")
     calls = kernel_calls(lowered)
     kinds = kernel_kinds(calls)
     assert {"gmm", "tgmm"} <= set(kinds)

@@ -12,7 +12,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import pytest  # noqa: E402
 from conftest import (  # noqa: E402
-    kernel_calls, lower_real_size_step, program_bytes)
+    kernel_calls, lower_real_size_step, program_bytes, router_choice_calls)
 
 
 @pytest.fixture(scope="module")
@@ -53,7 +53,7 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["attn_window"] == 512 and notes["blocks_remat"] is True
     assert notes["blocks_remat_keeps"] == (
         "moe_router_logits,moe_router_experts,moe_router_weights,"
-        "moe_router_counts,attn_out_proj,attn_q,attn_k,attn_v,"
+        "moe_router_counts,moe_router_lse,attn_out_proj,attn_q,attn_k,attn_v,"
         "mlp_gate,mlp_up,attn_out,attn_lse")
     assert notes["attn_gate"] == "headwise_sigmoid"
     assert notes["rope_kind"] == "yarn_half|default"
@@ -72,6 +72,10 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["moe_rows_sorted"] == 32768 and notes["moe_routes"] == 131072
     assert notes["moe_path"] == "megablox_gmm"
     assert notes["moe_rows_path"] == "tgmm"
+    # the four routers' choice: the kernel pair once a layer, nothing of
+    # it under ``rematted_computation``, no ``top_k`` or gather left
+    assert notes["moe_router_path"] == "pallas"
+    router_choice_calls(lowered, 4, "f32[256,16384]", "i32[8,16384]")
     calls = kernel_calls(lowered)
     flash = [line for line in calls if "/attn/" in line]
     head = [line for line in calls if "jit(_ce_lse_fwd)" in line]
@@ -89,7 +93,7 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert sum("jit(_flash_bwd)" in line for line in flash) == 5
     assert all("bf16[1,16384,6144]" in line for line in core)
     assert all("bf16[1,16384,8192]" in line for line in window)
-    # the rest are the routed layers' grouped matmuls and row sums
+    # the rest are the routed layers' choice, grouped matmuls and row sums
     rest = [line for line in calls if line not in flash + head]
     assert rest and all(re.search(r"/h_[1234]/mlp/", line) for line in rest)
     assert "16384x16384" not in lowered.as_text()

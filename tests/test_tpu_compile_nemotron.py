@@ -3,6 +3,7 @@ chip here (as ``test_tpu_compile.py``: the TPU compiler for a described
 v5e; nothing runs, so nothing here is a result or a time)."""
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -23,9 +24,13 @@ def test_held_experts_compile_for_v5e_at_the_cells_widths(v5e, monkeypatch):
     the kernel's 1,024 tile; 1,856 is 14.5 lane tiles) over a slab of
     6,144 of the 49,152 sorted routes, forward and backward: six
     megablox custom calls (two matrices, each forward, for its input
-    and for its weights) inside the slab loop."""
+    and for its weights) inside the slab loop. The sigmoid router over
+    the 128 experts chooses by ``ops/pallas/router_choice.py``'s pair."""
     from ray_tpu.ops import moe
+    from ray_tpu.util import tracing
 
+    notes = {}
+    monkeypatch.setattr(tracing, "note_trace", notes.update)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert moe.held_rows(8192 * 6, 8, 128) == 6144
     arg = _arg(v5e[0])
@@ -42,6 +47,14 @@ def test_held_experts_compile_for_v5e_at_the_cells_widths(v5e, monkeypatch):
         arg((8, 2688, 1856), jnp.float32), arg((8, 1856, 2688), jnp.float32),
         arg((128,), jnp.float32)).compile().as_text()
     assert text.count("tpu_custom_call") >= 6
+    # the router's choice is the kernel pair, once each, under the
+    # layer's ``router`` scope, and no ``top_k`` or gather beside it
+    assert notes["moe_router_path"] == "pallas"
+    for kernel in ("_choice_fwd", "_choice_bwd"):
+        assert len(re.findall(
+            r"custom-call\(.*router\)*/jit\(%s\)\)*/pallas_call" % kernel,
+            text)) == 1, kernel
+    assert not re.search(r"router\)*/(top_k|gather|scatter)", text)
 
 
 def test_chunked_scan_compiles_for_v5e_at_the_cells_shape(v5e):

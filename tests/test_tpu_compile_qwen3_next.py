@@ -10,7 +10,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import pytest  # noqa: E402
 from conftest import (  # noqa: E402
-    kernel_calls, kernel_kinds, lower_real_size_step, program_bytes)
+    kernel_calls, kernel_kinds, lower_real_size_step, program_bytes,
+    router_choice_calls)
 
 GRAD_GROUPS = {
     "grad_norm_gdn_gates": "^h_[0-9]+/gdn/(A_log|dt_bias|ba/kernel)$",
@@ -47,14 +48,15 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     gate the ``silu`` pair of ``ops/pallas/gated_norm.py`` under
     ``gdn/out_gate``; the one attention layer is the flash pair at 256
     lanes a block under ``attn/core``; the 512-wide router's experts
-    held run on the grouped matmuls; no ``[T, T]`` array exists."""
+    held run on the grouped matmuls, chosen by the routers' kernel pair;
+    no ``[T, T]`` array exists."""
     cfg, notes, lowered = real_size_step
     assert notes["attn_kind"] == "gdn_gated"
     assert notes["attn_layers"] == "LLLF" and notes["blocks_remat"] is True
     assert notes["blocks_remat_keeps"] == (
         "moe_router_logits,moe_router_experts,moe_router_weights,"
-        "moe_router_counts,mixer_out_proj,gdn_gated_out,kda_scan_out,"
-        "kda_scan_states,gdn_in_proj,attn_out,attn_lse")
+        "moe_router_counts,moe_router_lse,mixer_out_proj,gdn_gated_out,"
+        "kda_scan_out,kda_scan_states,gdn_in_proj,attn_out,attn_lse")
     assert notes["gdn_path"] == "pallas_chunked" and notes["gdn_chunk"] == 64
     assert notes["gdn_heads"] == [16, 32] and notes["gdn_state"] == [128, 128]
     assert notes["gdn_gate_path"] == "pallas"
@@ -70,6 +72,12 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["moe_rows_sorted"] == 20480    # twice the even share
     assert notes["moe_routes"] == 163840
     assert notes["moe_path"] == "megablox_gmm"
+    # the routers' choice: the kernel pair once a layer on the product
+    # transposed, 512 experts down the sublanes; the recomputed blocks
+    # keep its choice, weights, counts and lse, so neither it nor a
+    # ``top_k`` runs under ``rematted_computation``
+    assert notes["moe_router_path"] == "pallas"
+    router_choice_calls(lowered, 4, "f32[512,16384]", "i32[10,16384]")
     assert "kda_path" not in notes and "kda_gate_path" not in notes
     calls = kernel_calls(lowered)
     kinds = kernel_kinds(calls)

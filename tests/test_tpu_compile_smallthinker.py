@@ -9,7 +9,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import pytest  # noqa: E402
 from conftest import (  # noqa: E402
-    kernel_calls, kernel_kinds, lower_real_size_step, program_bytes)
+    kernel_calls, kernel_kinds, lower_real_size_step, program_bytes,
+    router_choice_calls)
 
 
 @pytest.fixture(scope="module")
@@ -57,10 +58,16 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["moe_experts_held"] == [0, 16]
     assert notes["moe_rows_sorted"] == 49152    # twice the even share
     assert notes["moe_path"] == "megablox_gmm"
+    # the routers in front of attention choose by the kernel pair
+    # (``route_softmax``), once a layer; the counts stay
+    # ``routed_experts``' scatter-add
+    assert notes["moe_router_path"] == "pallas"
+    router_choice_calls(lowered, 4, "f32[64,16384]", "i32[6,16384]",
+                        counts_scattered=True)
     calls = kernel_calls(lowered)
     kinds = kernel_kinds(calls)
     assert set(kinds) == {"_flash_fwd", "_flash_bwd", "gmm", "tgmm",
-                          "_ce_lse_fwd"}
+                          "_ce_lse_fwd", "_choice_fwd", "_choice_bwd"}
     assert kinds.count("_ce_lse_fwd") == 1      # the head's forward (PR 51)
     assert notes["ce_path"] == "pallas_lse"
     assert kinds.count("_flash_fwd") == 4

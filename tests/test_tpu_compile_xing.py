@@ -22,7 +22,7 @@ import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
 from conftest import (  # noqa: E402
     kernel_calls, kernel_kinds, lower_real_size_step, on_device,
-    program_bytes)
+    program_bytes, router_choice_calls)
 
 T = 4096        # the cell's row: rope_scaling's original length
 
@@ -143,6 +143,10 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert sum("mla_flash_bwd" in line for line in flash) == 5
     head = [line for line in calls if "jit(_ce_lse_fwd)" in line]
     assert len(head) == 1 and "/loss/" in head[0]
+    # the four routers' choice: the kernel pair once a layer (the
+    # recomputed blocks keep the routers' names since PR 68), no
+    # ``top_k`` or gather left
+    router_choice_calls(lowered, 4, "f32[64,4096]", "i32[4,4096]")
 
 
 def test_the_real_size_steps_residual_maps_are_one_kernel_pair_a_sublayer(
@@ -171,15 +175,20 @@ def test_the_real_size_step_says_what_it_ran(real_size_step):
         "flash_path", "flash_layout", "mla_saved", "flash_bwd_resident_rows",
         "rope_kind", "hc_mult", "hc_sinkhorn_iters", "hc_state_dtype",
         "hc_maps_path", "hc_maps_block", "blocks_remat",
-        "blocks_remat_keeps", "moe_path", "moe_experts_held")} == {
+        "blocks_remat_keeps", "moe_path", "moe_experts_held",
+        "moe_router_path")} == {
         "flash_path": "mla_multi_block", "flash_layout": "bthd",
         "mla_saved": "latents", "flash_bwd_resident_rows": T,
         "rope_kind": "yarn", "hc_mult": 4, "hc_sinkhorn_iters": 20,
         "hc_state_dtype": "bfloat16", "hc_maps_path": "pallas",
         "hc_maps_block": 1024, "blocks_remat": True,
-        "blocks_remat_keeps": "hc_maps_pre,hc_maps_post,hc_maps_res,"
-                              "hc_maps_m,hc_maps_r,attn_out,attn_lse",
-        "moe_path": "megablox_gmm", "moe_experts_held": [0, 8]}
+        "blocks_remat_keeps": "moe_router_logits,moe_router_experts,"
+                              "moe_router_weights,moe_router_counts,"
+                              "moe_router_lse,hc_maps_pre,hc_maps_post,"
+                              "hc_maps_res,hc_maps_m,hc_maps_r,attn_out,"
+                              "attn_lse",
+        "moe_path": "megablox_gmm", "moe_experts_held": [0, 8],
+        "moe_router_path": "pallas"}
     assert notes["mla_scale"] == pytest.approx(cfg.mla_scale)
 
 

@@ -9,7 +9,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import pytest  # noqa: E402
 from conftest import (  # noqa: E402
-    kernel_calls, kernel_kinds, lower_real_size_step, program_bytes)
+    kernel_calls, kernel_kinds, lower_real_size_step, program_bytes,
+    scope_primitives)
 
 
 @pytest.fixture(scope="module")
@@ -46,6 +47,11 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["moe_experts_held"] == [0, 8]
     assert notes["moe_rows_sorted"] == 16384    # twice the even share: all
     assert notes["moe_path"] == "megablox_gmm"
+    # its router is its own MLP and arg-max: no choice of ``ops/moe.py``'s
+    # is made, on either path (PR 68 changed nothing here)
+    assert "moe_router_path" not in notes
+    assert not scope_primitives(lowered, "/mlp/router/") & {
+        "jit(_choice_fwd)", "jit(_choice_bwd)", "top_k"}
     calls = kernel_calls(lowered)
     kinds = kernel_kinds(calls)
     assert set(kinds) == {"_flash_fwd", "_flash_bwd", "gmm", "tgmm",

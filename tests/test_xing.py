@@ -369,8 +369,11 @@ def test_the_step_carries_the_residual_paths_scopes_notes_and_report(
         "hc_mult": 4, "hc_sinkhorn_iters": 3, "hc_state_dtype": "float32",
         "hc_maps_path": "xla", "hc_maps_block": 0,
         "rope_kind": "yarn", "blocks_remat": True,
-        "blocks_remat_keeps": "hc_maps_pre,hc_maps_post,hc_maps_res,"
-                              "hc_maps_m,hc_maps_r,attn_out,attn_lse"}
+        "blocks_remat_keeps": "moe_router_logits,moe_router_experts,"
+                              "moe_router_weights,moe_router_counts,"
+                              "moe_router_lse,hc_maps_pre,hc_maps_post,"
+                              "hc_maps_res,hc_maps_m,hc_maps_r,attn_out,"
+                              "attn_lse"}
     assert notes["mla_scale"] == pytest.approx(cfg.mla_scale)
     assert cfg.mla_scale == pytest.approx(
         24 ** -0.5 * (0.1 * np.log(4.0) + 1.0) ** 2)
