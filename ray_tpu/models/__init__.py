@@ -26,8 +26,8 @@ gated delta rule through ``ops/kda.py``, to one latent-attention layer
 with no query latent and no positions through ``ops/mla.py``, JoyAI's
 routed layer behind a leading dense one); its config expresses
 Kimi-Linear-48B-A3B-Instruct. ``Phi4Flash`` is the
-decoder-hybrid-decoder (Mamba-1 scans through ``ops/ssm.py::mamba1_scan``
-and differential attention through
+decoder-hybrid-decoder (Mamba-1 scans through
+``ops/mamba1.py::mamba1_scan`` and differential attention through
 ``ops/attention.py::differential_attention``, windowed, in a
 self-decoder; gated memory units and cross attention that read one
 earlier layer's scan and one's K and V in a cross-decoder; LayerNorm, a

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import causal_attention, ring_attention
+from ray_tpu.ops.pallas import program
 from ray_tpu.util import tracing
 
 
@@ -365,38 +366,26 @@ def _chunked_ce_core_bwd(ignore_index, path, res, g):
 _chunked_ce_core.defvjp(_chunked_ce_core_fwd, _chunked_ce_core_bwd)
 
 
-def _token_axes(mesh, batch: int, seq: int):
-    """(batch axes, sequence axis or None): the mesh axes of size > 1
-    that shard a ``[batch, seq, ...]`` array's tokens — dp and fsdp on
-    the batch, sp on the sequence, as ``train.step.batch_spec`` places
-    them and the attention dispatch maps over them. None where the
-    chunk scan has to stay one global scan."""
-    if mesh is None or mesh.size == 1:
-        return None
-    from ray_tpu.parallel.mesh import AXIS_DP, AXIS_FSDP, AXIS_SP
+def _loss_axes(mesh, batch: int, seq: int):
+    """(batch axes, sequence axis or None) that the loss's ``shard_map``
+    splits the rows of a ``[batch, seq, ...]`` array over
+    (``program.token_axes``: dp and fsdp on the batch, sp on the
+    sequence); ``((), None)`` where the chunk scan has to stay one
+    global scan."""
     from ray_tpu.parallel.sharding import DEFAULT_RULES
 
-    if DEFAULT_RULES.mesh_axis("vocab", mesh) is not None:
+    if (mesh is not None
+            and DEFAULT_RULES.mesh_axis("vocab", mesh) is not None):
         # The head is sharded on its vocabulary axis (tp): mapping
         # over the token axes alone would hand every chip the whole
         # head. A vocabulary-parallel cross-entropy is another path.
-        return None
-    batch_axes = tuple(a for a in (AXIS_DP, AXIS_FSDP)
-                       if mesh.shape.get(a, 1) > 1)
-    seq_axis = AXIS_SP if mesh.shape.get(AXIS_SP, 1) > 1 else None
-    if not batch_axes and seq_axis is None:
-        return None
-    n_batch = math.prod(mesh.shape[a] for a in batch_axes)
-    if batch % n_batch or (seq_axis and seq % mesh.shape[seq_axis]):
-        return None     # e.g. the tiny batch of init-time tracing
-    return batch_axes, seq_axis
+        return (), None
+    return program.token_axes(mesh, batch, seq)
 
 
-def _mapped_over(axes):
-    """The mesh axes ``_token_axes`` found, as one tuple: what the
+def _mapped_over(axes) -> tuple:
+    """The mesh axes ``_loss_axes`` found, as one tuple: what the
     loss's ``shard_map`` splits the rows over (none: one program)."""
-    if axes is None:
-        return ()
     batch_axes, seq_axis = axes
     return batch_axes + ((seq_axis,) if seq_axis else ())
 
@@ -416,7 +405,7 @@ def ce_path(shape, vocab: int, dtype, mesh=None,
     128-lane tiles, the padded rows whole sublane tiles, and the
     program that holds the rows is one device's: no mesh on a
     one-device process, a one-device mesh, or a chip's rows under the
-    ``shard_map`` over the token axes (``_token_axes``); ``xla_scan``,
+    ``shard_map`` over the token axes (``_loss_axes``); ``xla_scan``,
     the scan over chunks, everywhere else (the CPU, float32 rows, a
     head sharded on its vocabulary or shapes the axes do not divide,
     where one program spans the devices and a ``pallas_call`` has no
@@ -425,11 +414,11 @@ def ce_path(shape, vocab: int, dtype, mesh=None,
     section 6, PR 51)."""
     from ray_tpu.ops.pallas import ce_lse
     batch, seq, e = shape
-    axes = _token_axes(mesh, batch, seq)
-    if axes is None and (jax.device_count() if mesh is None
-                         else mesh.size) > 1:
+    over = _mapped_over(_loss_axes(mesh, batch, seq))
+    if not over and (jax.device_count() if mesh is None
+                     else mesh.size) > 1:
         return "xla_scan"
-    devices = math.prod(mesh.shape[a] for a in _mapped_over(axes))
+    devices = math.prod(mesh.shape[a] for a in over)
     chunk, n = _chunks(batch * seq // devices, chunk_size)
     if (jax.default_backend() == "tpu" and dtype == jnp.bfloat16
             and ce_lse.shapes_ok(n * chunk, e, vocab)):
@@ -525,13 +514,12 @@ def _chunked_ce(hidden, embedding, targets, ignore_index, chunk_size, mesh,
         return jax.lax.psum(tot_cnt, over) if over else tot_cnt
 
     tracing.note_trace(ce_path=path)
-    axes = _token_axes(mesh, B, S)
-    if axes is None:
+    batch_axes, seq_axis = axes = _loss_axes(mesh, B, S)
+    over = _mapped_over(axes)
+    if not over:
         return in_hand(hidden, emb, targets)
     from jax.sharding import PartitionSpec
-    batch_axes, seq_axis = axes
     tokens = PartitionSpec(batch_axes or None, seq_axis)
-    over = _mapped_over(axes)
     tracing.note_trace(
         ce_rows_local=B * S // math.prod(mesh.shape[a] for a in over),
         ce_axes=list(over))

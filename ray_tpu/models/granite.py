@@ -32,18 +32,18 @@ no routed layer and no router). A token's row, ``E`` the tied table:
   lookup's path (x 12) and the head's (/ 8).
 
 With ``remat`` each block is recomputed in the backward pass but for
-what its policy keeps by name (``_block_keeps``; the note
-``blocks_remat_keeps``): the scan's output and chunk-entering states
-(``ops/ssm.py::SCAN_OUT``, ``SCAN_STATES``: 134 MB a layer at 8,192 rows,
-and ``_ssd_fwd`` runs once a layer, not twice), the attention core's
-output and row statistics (``ops/attention.py::remat_policy``), the
-stream between the block's two sub-layers (``_MIXER_STREAM``, 34 MB a
-layer: with it nothing in the second pass reads ``out_proj``'s product
-or ``o``'s, and neither matmul runs again), and, on the layers that the
-cell's memory decides, the MLP's ``gate_up`` product (268 MB a layer,
-from ``_first_keeping_gate_up`` on) and the three parts of the mixer's
-``in_proj`` product (``ops/ssm.py::IN_PROJ_PARTS``, 139 MB a layer, from
-``_first_keeping_in_proj`` on).
+what its policy keeps by name (``_BLOCK_KEEPS``, through
+``ops/remat.py::block``; the note ``blocks_remat_keeps``): the scan's
+output and chunk-entering states (``SSD_SCAN_OUT``, ``SSD_SCAN_STATES``:
+134 MB a layer at 8,192 rows, and ``_ssd_fwd`` runs once a layer, not
+twice), the attention core's output and row statistics
+(``ops/remat.py::remat_policy``), the stream between the block's two
+sub-layers (``MIXER_STREAM``, 34 MB a layer: with it nothing in the
+second pass reads ``out_proj``'s product or ``o``'s, and neither matmul
+runs again), and, as the cell's memory decides, the MLP's ``gate_up``
+product (268 MB a layer) and the three parts of the mixer's ``in_proj``
+product (``IN_PROJ_PARTS``, 139 MB a layer): every layer keeps both at
+8,192 rows.
 
 ``sp`` and ``tp`` meshes are refused by name: the scan runs a whole
 sequence on one chip (a state passed from chip to chip is not
@@ -73,16 +73,21 @@ from jax.ad_checkpoint import checkpoint_name
 from ray_tpu.models.llama import RMSNorm
 from ray_tpu.models.nemotron_h import Mamba2Dims, Mamba2Mixer, _dense
 from ray_tpu.models.phi4flash import MLP
-from ray_tpu.ops import ssm
-from ray_tpu.ops.attention import (
-    MLP_GATE_UP, causal_attention, remat_keeps, remat_policy)
+from ray_tpu.ops import remat
+from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.pallas import program
+from ray_tpu.ops.remat import (
+    IN_PROJ_PARTS, MIXER_STREAM, MLP_GATE_UP, SSD_SCAN_OUT, SSD_SCAN_STATES)
 from ray_tpu.util import tracing
 
 _PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
-# what a recomputed block keeps of its scan (the module docstring)
-_SCAN_KEEPS = (ssm.SCAN_OUT, ssm.SCAN_STATES)
-# the residual stream after the mixer, which the MLP's norm reads
-_MIXER_STREAM = "mixer_stream"
+# What a recomputed block keeps beside its attention core's two (the
+# module docstring). Every layer of the ten keeps all of it at 8,192
+# rows, decided against ``peak_memory_in_bytes`` of the cell's compiled
+# step (tests/test_tpu_compile_granite.py); a larger step would give
+# ``gate_up``'s and ``in_proj``'s names a first layer (``{name: first}``).
+_BLOCK_KEEPS = (MLP_GATE_UP, *IN_PROJ_PARTS, MIXER_STREAM, SSD_SCAN_OUT,
+                SSD_SCAN_STATES)
 
 
 @dataclass(frozen=True)
@@ -294,53 +299,9 @@ class Block(nn.Module):
             y = Mamba2Mixer(cfg, self.mesh, name="mamba")(h)
         else:
             y = Attention(cfg, self.mesh, name="attn")(h)
-        x = checkpoint_name(_add_scaled(x, y, m_r), _MIXER_STREAM)
+        x = checkpoint_name(_add_scaled(x, y, m_r), MIXER_STREAM)
         return _add_scaled(
             x, MLP(cfg, name="mlp")(_norm(cfg, "mlp_norm")(x)), m_r)
-
-
-def _first_keeping_gate_up(cfg: GraniteHybridConfig) -> int:
-    """The first of the layers whose policy lists the MLP's ``gate_up``
-    product; the layers before it make it again in the backward pass.
-    Decided against ``memory_analysis().peak_memory_in_bytes`` of the
-    cell's step (tests/test_tpu_compile_granite.py; not arguments +
-    temporaries: PERF.md section 7 (19)): every layer of the ten keeps
-    it at 8,192 rows."""
-    return 0
-
-
-def _first_keeping_in_proj(cfg: GraniteHybridConfig) -> int:
-    """The first of the layers whose policy lists the parts of the
-    mixer's ``in_proj`` product, decided as ``_first_keeping_gate_up``
-    is and against the same peak: every layer keeps them at 8,192
-    rows."""
-    return 0
-
-
-def _block_keeps(cfg: GraniteHybridConfig, i: int) -> tuple[str, ...]:
-    """The names layer ``i``'s policy lists beside the attention
-    core's two: the stream after the mixer and the scan's in every
-    layer (a policy that lists a name no value of the block carries
-    keeps nothing for it), ``gate_up``'s and ``in_proj``'s where memory
-    allows."""
-    keeps = (_MIXER_STREAM, *_SCAN_KEEPS)
-    if i >= _first_keeping_in_proj(cfg):
-        keeps = ssm.IN_PROJ_PARTS + keeps
-    if i >= _first_keeping_gate_up(cfg):
-        keeps = (MLP_GATE_UP,) + keeps
-    return keeps
-
-
-def _keeps_note(cfg: GraniteHybridConfig) -> str:
-    """``blocks_remat_keeps``: what ``_block_keeps`` gives the layers; a
-    name that the layers from ``k`` > 0 on alone keep reads
-    ``name[k:]``."""
-    def since(first, *names):
-        return (f"{n}[{first}:]" if first else n for n in names)
-    return ",".join(remat_keeps(
-        *since(_first_keeping_gate_up(cfg), MLP_GATE_UP),
-        *since(_first_keeping_in_proj(cfg), *ssm.IN_PROJ_PARTS),
-        _MIXER_STREAM, *_SCAN_KEEPS))
 
 
 class Granite(nn.Module):
@@ -356,28 +317,20 @@ class Granite(nn.Module):
         from ray_tpu.parallel.sharding import constrain
         return constrain(x, self.mesh, "batch", "seq", None)
 
-    def _refuse_sp_tp(self):
-        for axis, what in (
-                ("sp", "the scan runs a whole sequence on one chip; a "
-                 "state passed from chip to chip is not implemented"),
-                ("tp", "the scan's kernels and the fused gate_up have no "
-                 "tp path")):
-            if self.mesh is not None and self.mesh.shape.get(axis, 1) > 1:
-                raise NotImplementedError(
-                    f"Granite on a mesh with {axis}="
-                    f"{self.mesh.shape[axis]}: {what}. dp and fsdp shard "
-                    "the batch and need nothing.")
-
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False):
         cfg = self.config
-        self._refuse_sp_tp()
+        program.refuse(
+            self.mesh, "Granite",
+            sp="a state passed from chip to chip (the scan runs a whole "
+               "sequence on one chip)",
+            tp="a tp path for the scan's kernels and the fused gate_up")
         tracing.note_trace(
             attn_kind="gqa_nope_scaled", attn_scale=cfg.attention_multiplier,
             layer_pattern="".join("*" if k == "attention" else "M"
                                   for k in cfg.layer_types),
             blocks_remat=cfg.remat,
-            blocks_remat_keeps=_keeps_note(cfg) if cfg.remat else "")
+            blocks_remat_keeps=remat.keeps_note(cfg.remat, _BLOCK_KEEPS))
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
@@ -385,8 +338,7 @@ class Granite(nn.Module):
             x = self._constrain(wte(tokens) * cfg.embedding_multiplier)
         with jax.named_scope("blocks"):
             for i, kind in enumerate(cfg.layer_types):
-                block = (nn.remat(Block, policy=remat_policy(
-                    *_block_keeps(cfg, i))) if cfg.remat else Block)
+                block = remat.block(Block, cfg.remat, _BLOCK_KEEPS, i)
                 x = self._constrain(
                     block(cfg, kind, self.mesh, name=f"h_{i}")(x))
             x = _norm(cfg, "norm_f")(x)

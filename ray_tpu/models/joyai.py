@@ -45,12 +45,12 @@ things this file had not expressed:
   score, which ``latent_attention(scale=...)`` hands to the kernels.
 
 ``remat`` recomputes each block in the backward pass but for its
-attention core's output and row statistics (``ops/attention.py::
+attention core's output and row statistics (``ops/remat.py::
 remat_policy``, as ``models/kimi_linear.py``); what a recomputed block
 keeps is its input state, ``hc_mult`` streams wide, its router's
-product and choice (``ops/moe.py::ROUTER_KEEPS``), and at ``hc_mult`` >
-1 each sub-layer's residual maps with the 25 floats a token their
-backward kernel reads (``ops/pallas/hc_maps.py::MAPS_KEEPS``), so the
+product and choice (``ops/remat.py::ROUTER_KEEPS``), and at ``hc_mult``
+> 1 each sub-layer's residual maps with the 25 floats a token their
+backward kernel reads (``MAPS_KEEPS``), so the
 second pass runs ``pre`` and ``post`` from them and the maps' norm,
 product and forward kernel once a step.
 
@@ -87,10 +87,11 @@ import jax.numpy as jnp
 
 from ray_tpu.models.llama import RMSNorm, SwiGLU, rope_freqs, yarn_freqs
 from ray_tpu.models.nemotron_h import _Router   # gate: [d, E] and its bias
-from ray_tpu.ops import hyper_connections as hc
-from ray_tpu.ops.attention import remat_keeps, remat_policy
+from ray_tpu.ops import hyper_connections as hc, remat
 from ray_tpu.ops.mla import UpProjections, latent_attention
-from ray_tpu.ops.moe import ROUTER_KEEPS, held_route_share, routed_ffn
+from ray_tpu.ops.moe import held_route_share, routed_ffn
+from ray_tpu.ops.pallas import program
+from ray_tpu.ops.remat import MAPS_KEEPS, ROUTER_KEEPS
 from ray_tpu.util import tracing
 
 
@@ -462,25 +463,13 @@ class Block(nn.Module):
         return _around(cfg, "mlp", lambda u: mlp(mlp_norm(u)), x, self.mesh)
 
 
-def _block_keeps(cfg: JoyAIConfig) -> tuple[str, ...]:
-    """The names a recomputed block keeps beside its attention core's:
-    its router's product, choice, chosen scores and counts
-    (``ops/moe.py::ROUTER_KEEPS``: ``[T, E]`` and three ``[T, k]``
-    float32, so that the second pass runs neither the float32 product
-    nor the choice again), and at ``hc_mult`` > 1 each sub-layer's
-    residual maps and what their backward kernel reads (41 floats a
-    token a sub-layer; on the maps' XLA path nothing carries the
-    names)."""
-    return (*ROUTER_KEEPS, *(hc.MAPS_KEEPS if cfg.hc_mult > 1 else ()))
-
-
-def _block(cfg: JoyAIConfig):
-    """``Block``, recomputed in the backward pass under ``remat`` but
-    for its attention core's output and row statistics and
-    ``_block_keeps``."""
-    if not cfg.remat:
-        return Block
-    return nn.remat(Block, policy=remat_policy(*_block_keeps(cfg)))
+def _keeps(cfg: JoyAIConfig) -> tuple[str, ...]:
+    """The names a recomputed block keeps beside its attention core's
+    (``ops/remat.py`` has what each is): its router's, so that the
+    second pass runs neither the float32 product nor the choice again,
+    and at ``hc_mult`` > 1 each sub-layer's residual maps' (on the maps'
+    XLA path nothing carries them)."""
+    return (*ROUTER_KEEPS, *(MAPS_KEEPS if cfg.hc_mult > 1 else ()))
 
 
 class MTP(nn.Module):
@@ -502,7 +491,8 @@ class MTP(nn.Module):
         if cfg.hc_mult > 1:
             with jax.named_scope("hc_expand"):
                 u = hc.hc_expand(u, cfg.hc_mult)
-        u = _block(cfg)(cfg, True, self.mesh, name="h")(u, angles)
+        u = remat.block(Block, cfg.remat, _keeps(cfg))(
+            cfg, True, self.mesh, name="h")(u, angles)
         if cfg.hc_mult > 1:
             with jax.named_scope("hc_collapse"):
                 u = hc.hc_collapse(u, cfg.hc_mult)
@@ -537,9 +527,9 @@ class JoyAI(nn.Module):
         if cfg.remat:
             tracing.note_trace(
                 blocks_remat=True,
-                blocks_remat_keeps=",".join(remat_keeps(*_block_keeps(cfg))))
+                blocks_remat_keeps=remat.keeps_note(True, _keeps(cfg)))
         if n > 1:
-            hc.refuse_split_state(self.mesh)
+            program.refuse(self.mesh, "hyper-connections", **hc.SPLIT_STATE)
             maps_path = hc.hc_maps_path(
                 (*tokens.shape, n * cfg.n_embd), n, self.mesh)
             tracing.note_trace(
@@ -567,7 +557,7 @@ class JoyAI(nn.Module):
         else:
             angles = rope_freqs(cfg.rope_dim, cfg.seq_len, cfg.rope_theta)
         h_mtp = None
-        block = _block(cfg)
+        block = remat.block(Block, cfg.remat, _keeps(cfg))
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):
                 x = block(cfg, i >= cfg.dense_layers, self.mesh,

@@ -10,14 +10,14 @@ three **KDA** layers (Kimi Delta Attention) to one **MLA** layer.
   MLP_l(RMSNorm(x))``;
 - **KDA** (``ops/kda.py`` has the recurrence and its chunked form): ``q,
   k, v = W_q h, W_k h, W_v h``, each through its own depthwise causal
-  convolution of 4 taps and a SiLU (``ops/ssm.py::causal_conv1d_silu``,
+  convolution of 4 taps and a SiLU (``ops/conv1d.py::causal_conv1d_silu``,
   no bias); a head's ``q`` and ``k`` to unit length in float32, ``q``
   times ``head_dim^-1/2`` (``ops/kda.py::kda_scan`` with
   ``normalize_qk``: where, its path decides); the decay a channel ``g =
   -exp(A_log[head]) * softplus(W_f2 (W_f1 h) + dt_bias)``; the step
   size a head ``beta = sigmoid(W_b h)``; the gated delta rule over a
   ``[128, 128]`` state a head; ``y = W_o (sigmoid(W_g2 (W_g1 h) + b_g)
-  * RMSNorm_head(o))`` (``ops/ssm.py::sigmoid_gated_head_rms_norm``);
+  * RMSNorm_head(o))`` (``ops/gated_norm.py::sigmoid_gated_head_rms_norm``);
 - **MLA** (``ops/mla.py``) with **no query latent and no rotation**
   (``q_lora_rank`` null, ``mla_use_nope``): ``q = W_q h`` straight to
   32 heads of 128 + 64, keys and values through a 512-wide normed
@@ -37,10 +37,11 @@ recurrence and the gate's product ``W_g2 (W_g1 h) + b_g`` run again
 (``_kda_core`` is a ``jax.checkpoint``), as ``latent_attention`` keeps
 its latents. With ``remat`` the blocks are recomputed too
 (``models/llama.py``'s switch), and a block keeps of each KDA mixer
-three named arrays (``_KDA_KEEPS``): its gated output, and the
+three named arrays (``_BLOCK_KEEPS``): its gated output, and the
 recurrence's forward kernel's two results, ``o`` and the state entering
-every chunk (``ops/kda.py::SCAN_OUT``, ``SCAN_STATES``; float32, 268 +
-537 MB a layer at 16,384 rows of 32 heads), which are all that the rest
+every chunk (``ops/remat.py::KDA_SCAN_OUT``, ``KDA_SCAN_STATES``;
+float32, 268 + 537 MB a layer at 16,384 rows of 32 heads), which are all
+that the rest
 of the backward pass reads of that kernel. The block's policy reaches
 through ``_kda_core``'s checkpoint (a policy is handed down into a
 ``remat`` equation inside the one it is applied to): the kept arrays
@@ -54,13 +55,13 @@ rule's: the XLA path (``xla_chunked``) has none and keeps its own group
 states, so there the recurrence runs as before (the pass, ``_kda_core``'s
 recomputation, and a group's inside ``kda_scan``). Of the latent layer
 the block keeps its core's output and row statistics
-(``ops/attention.py::remat_policy``), so that the latent flash forward
+(``ops/remat.py::remat_policy``), so that the latent flash forward
 kernel runs once, and of a routed layer its router's float32 product,
-choice, chosen scores and counts (``ops/moe.py::ROUTER_KEEPS``, 17 MB a
+choice, chosen scores and counts (``ops/remat.py::ROUTER_KEEPS``, 17 MB a
 layer), so that the product at the highest precision, ``top_k``, the
 gather and the counts' scatter-add run once (the note
 ``blocks_remat_keeps`` lists all nine names). **The output gate**
-(``ops/ssm.py::sigmoid_gated_head_rms_norm``, handed the mixer's mesh;
+(``ops/gated_norm.py::sigmoid_gated_head_rms_norm``, handed the mixer's mesh;
 the note ``kda_gate_path``) on its kernels (``pallas``: a TPU, heads of
 whole 128-lane tiles, one device or a mesh that shards the batch alone)
 runs forward once a layer a step and backward once: the gated output is
@@ -104,16 +105,17 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.joyai import MoE, _dense, _Down, _norm, _swiglu, _Up
 from ray_tpu.models.nemotron_h import _a_log_init, _conv_init, _dt_bias_init
-from ray_tpu.ops import kda, ssm
-from ray_tpu.ops.attention import remat_keeps, remat_policy
+from ray_tpu.ops import conv1d, gated_norm, kda, remat
 from ray_tpu.ops.mla import UpProjections, latent_attention
-from ray_tpu.ops.moe import ROUTER_KEEPS, held_route_share
+from ray_tpu.ops.moe import held_route_share
+from ray_tpu.ops.remat import (
+    KDA_OUT, KDA_SCAN_OUT, KDA_SCAN_STATES, ROUTER_KEEPS)
 from ray_tpu.util import tracing
 
 
-_KDA_OUT = "kda_gated_out"
-# what a recomputed block keeps of a KDA mixer (the module docstring)
-_KDA_KEEPS = (_KDA_OUT, kda.SCAN_OUT, kda.SCAN_STATES)
+# what a recomputed block keeps (the module docstring): a KDA mixer's
+# gated output and its recurrence's two, a routed layer's router's five
+_BLOCK_KEEPS = (KDA_OUT, KDA_SCAN_OUT, KDA_SCAN_STATES, *ROUTER_KEEPS)
 
 
 @dataclass(frozen=True)
@@ -245,7 +247,7 @@ def _kda_core(q, k, v, f_low, b_logit, g_low, w, *, heads: int, chunk: int,
     kd = inner // heads
     f32, dt = jnp.float32, q.dtype
     with jax.named_scope("conv"):
-        q, k, v = (ssm.causal_conv1d_silu(z, w[f"{n}_conv"], mesh=mesh)
+        q, k, v = (conv1d.causal_conv1d_silu(z, w[f"{n}_conv"], mesh=mesh)
                    for z, n in zip((q, k, v), "qkv"))
     with jax.named_scope("decay"):
         f = (f_low @ w["f_b"].astype(dt)).astype(f32) + w["dt_bias"]
@@ -260,7 +262,7 @@ def _kda_core(q, k, v, f_low, b_logit, g_low, w, *, heads: int, chunk: int,
     out_sq = jnp.mean(jnp.square(o))
     with jax.named_scope("out_gate"):
         gate = g_low @ w["g_b"].astype(dt) + w["g_bias"].astype(dt)
-        y = ssm.sigmoid_gated_head_rms_norm(
+        y = gated_norm.sigmoid_gated_head_rms_norm(
             o.reshape(b, t, inner), gate, w["norm"], heads, eps, mesh=mesh)
     return y, out_sq
 
@@ -304,7 +306,7 @@ class KDAMixer(nn.Module):
             mesh=self.mesh))(q, k, v, f_low, b_logit, g_low, w)
         self.sow("stats", "out_sq", out_sq)
         # what a recomputed block keeps of this mixer (``KimiLinear``)
-        y = checkpoint_name(y, _KDA_OUT)
+        y = checkpoint_name(y, KDA_OUT)
         with jax.named_scope("out"):
             return dense(cfg.n_embd, name="out")(y)
 
@@ -372,8 +374,7 @@ class KimiLinear(nn.Module):
             mla_ranks=[None, cfg.kv_rank],
             mla_qk_dims=[cfg.nope_dim, cfg.rope_dim], mla_v_dim=cfg.v_dim,
             dense_layers=cfg.dense_layers, blocks_remat=cfg.remat,
-            blocks_remat_keeps=",".join(
-                remat_keeps(*_KDA_KEEPS, *ROUTER_KEEPS)) if cfg.remat else "")
+            blocks_remat_keeps=remat.keeps_note(cfg.remat, _BLOCK_KEEPS))
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
@@ -385,8 +386,7 @@ class KimiLinear(nn.Module):
         # the core's output and row statistics (0.14 GB): neither
         # forward kernel runs again (the module docstring has how); and
         # of a routed layer the router's product and choice (17 MB)
-        block = (nn.remat(Block, policy=remat_policy(
-            *_KDA_KEEPS, *ROUTER_KEEPS)) if cfg.remat else Block)
+        block = remat.block(Block, cfg.remat, _BLOCK_KEEPS)
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):
                 x = self._constrain(

@@ -47,7 +47,7 @@ With ``remat`` each block is recomputed in the backward pass
 16,384 rows would hold eight copies of K and V, the gated cores and a
 32,768-row slab of sorted routes a layer beside everything below.
 What a recomputed block does keep, by name (``_BLOCK_KEEPS`` and
-``ops/attention.py::remat_policy``; the note ``blocks_remat_keeps``
+``ops/remat.py::remat_policy``; the note ``blocks_remat_keeps``
 lists them), is every matmul's product its backward pass reads and the
 flash forward's results, so that no matmul of a block and no forward
 kernel runs twice (bytes a layer at 16,384 rows):
@@ -56,7 +56,7 @@ kernel runs twice (bytes a layer at 16,384 rows):
   sliding one);
 - a routed layer's router: the float32 product ``x W_r`` in front of
   the sigmoid, the chosen ``experts``, their scores and the routes each
-  expert received (``ops/moe.py::ROUTER_KEEPS``, 17 MB): the second
+  expert received (``ops/remat.py::ROUTER_KEEPS``, 17 MB): the second
   pass makes the sigmoid again from the kept product and neither the
   product at the highest precision, ``top_k``, the gather nor the
   scatter-add of the counts;
@@ -110,22 +110,20 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.joyai import MoE, _dense, _norm, _swiglu
 from ray_tpu.models.llama import apply_rope_half, rope_freqs, yarn_freqs
-from ray_tpu.ops.attention import (
-    MLP_GATE, MLP_UP, causal_attention, remat_keeps, remat_policy)
-from ray_tpu.ops.moe import ROUTER_KEEPS, held_route_share
+from ray_tpu.ops import remat
+from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.remat import (
+    ATTN_K, ATTN_PROJ, ATTN_Q, ATTN_V, MLP_GATE, MLP_UP, ROUTER_KEEPS)
+from ray_tpu.ops.moe import held_route_share
 from ray_tpu.util import tracing
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
 
-# ``Attention``'s own names: q and k (as the rotation left them in a
-# full layer, as the products left them in a sliding one), v, and
-# ``W_o``'s product
-_ATTN_Q, _ATTN_K, _ATTN_V = "attn_q", "attn_k", "attn_v"
-_ATTN_PROJ = "attn_out_proj"
 # what a recomputed block keeps beside its core's output and row
-# statistics, dearest millisecond a byte first (the module docstring)
-_BLOCK_KEEPS = (*ROUTER_KEEPS, _ATTN_PROJ, _ATTN_Q, _ATTN_K, _ATTN_V,
+# statistics, dearest millisecond a byte first (the module docstring;
+# q and k as rotated in a full layer, as projected in a sliding one)
+_BLOCK_KEEPS = (*ROUTER_KEEPS, ATTN_PROJ, ATTN_Q, ATTN_K, ATTN_V,
                 MLP_GATE, MLP_UP)
 
 
@@ -296,10 +294,10 @@ class Attention(nn.Module):
             v = _dense(cfg)(cfg.n_kv_head * hd, name="v")(h)
         q = q.reshape(b, t, heads, hd)
         k = k.reshape(b, t, cfg.n_kv_head, hd)
-        v = checkpoint_name(v.reshape(b, t, cfg.n_kv_head, hd), _ATTN_V)
+        v = checkpoint_name(v.reshape(b, t, cfg.n_kv_head, hd), ATTN_V)
         if self.sliding:
             # named in front of the rotation (the module docstring)
-            q, k = checkpoint_name(q, _ATTN_Q), checkpoint_name(k, _ATTN_K)
+            q, k = checkpoint_name(q, ATTN_Q), checkpoint_name(k, ATTN_K)
         with jax.named_scope("rope"):
             q = _rotate(q, angles[:t], amplitude)
             k = _rotate(k, angles[:t], amplitude)
@@ -308,7 +306,7 @@ class Attention(nn.Module):
             # cotangent and reads the angles alone, so a block that
             # keeps these makes neither the products nor the float32
             # rotation again
-            q, k = checkpoint_name(q, _ATTN_Q), checkpoint_name(k, _ATTN_K)
+            q, k = checkpoint_name(q, ATTN_Q), checkpoint_name(k, ATTN_K)
         rep = heads // cfg.n_kv_head
         if rep > 1:
             # The equal-width kernels want as many key/value heads as
@@ -334,7 +332,7 @@ class Attention(nn.Module):
             o = o * g[..., None].astype(o.dtype)
         with jax.named_scope("out"):
             return checkpoint_name(_dense(cfg)(cfg.n_embd, name="out")(
-                o.reshape(b, t, heads * hd)), _ATTN_PROJ)
+                o.reshape(b, t, heads * hd)), ATTN_PROJ)
 
 
 def _rotate(x, angles, amplitude: float):
@@ -413,8 +411,7 @@ class Laguna(nn.Module):
             rope_attention_factor=tables[False][1],
             dense_layers=cfg.n_layer - len(cfg.routed_layers),
             blocks_remat=cfg.remat,
-            blocks_remat_keeps=",".join(remat_keeps(*_BLOCK_KEEPS))
-            if cfg.remat else "")
+            blocks_remat_keeps=remat.keeps_note(cfg.remat, _BLOCK_KEEPS))
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
@@ -424,9 +421,8 @@ class Laguna(nn.Module):
         # and row statistics: no matmul of it and no flash forward
         # kernel runs twice (the module docstring). Static: the
         # amplitude is a number of the config, not of the trace
-        block = (nn.remat(Block, static_argnums=(3,),
-                          policy=remat_policy(*_BLOCK_KEEPS))
-                 if cfg.remat else Block)
+        block = remat.block(Block, cfg.remat, _BLOCK_KEEPS,
+                            static_argnums=(3,))
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):
                 angles, amplitude = tables[cfg.sliding(i)]

@@ -33,9 +33,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.attention import (
-    MLP_DOWN, MLP_GATE, MLP_UP, causal_attention)
+from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.moe import routed_ffn
+from ray_tpu.ops.remat import MLP_DOWN, MLP_GATE, MLP_UP
 
 
 @dataclass(frozen=True)
@@ -256,7 +256,7 @@ class LlamaAttention(nn.Module):
 
 class SwiGLU(nn.Module):
     """``W_d (SiLU(W_g x) * W_u x)``, no bias. The three products carry
-    names (``ops/attention.py::MLP_GATE``, ``MLP_UP``, ``MLP_DOWN``) that
+    names (``ops/remat.py::MLP_GATE``, ``MLP_UP``, ``MLP_DOWN``) that
     a recomputed block's policy may list (``models/ouro.py``); under any
     other policy, and outside one, a name is the identity."""
     config: LlamaConfig

@@ -37,8 +37,8 @@ this file's (64 heads of 64 in **8 groups**, chunk **128**, hidden
 kernels make a group's score square in each of its 8 head blocks and
 sum ``dB``, ``dC`` over them; chunk **256**; hidden 2,048; every block
 under ``nn.remat`` with the scan's two results and the three parts of
-``in_proj``'s product kept by name, ``ops/ssm.py::SCAN_OUT``,
-``SCAN_STATES`` and ``IN_PROJ_PARTS``). The parameter tree, the scopes
+``in_proj``'s product kept by name, ``ops/remat.py::SSD_SCAN_OUT``,
+``SSD_SCAN_STATES`` and ``IN_PROJ_PARTS``). The parameter tree, the scopes
 and the arithmetic are one; the names are the identity here.
 """
 
@@ -55,9 +55,11 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.llama import RMSNorm
-from ray_tpu.ops import ssm
+from ray_tpu.ops import conv1d, gated_norm, ssm
 from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.moe import held_route_share, routed_ffn
+from ray_tpu.ops.pallas import program
+from ray_tpu.ops.remat import IN_PROJ_PARTS
 from ray_tpu.util import tracing
 
 NANO_30B_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -239,7 +241,7 @@ class _Conv(nn.Module):
                        (cfg.conv_kernel, cfg.conv_width), cfg.param_dtype)
         b = self.param("bias", _conv_init(cfg), (cfg.conv_width,),
                        cfg.param_dtype)
-        return ssm.causal_conv1d_silu(x, w, b, mesh=self.mesh)
+        return conv1d.causal_conv1d_silu(x, w, b, mesh=self.mesh)
 
 
 class _GateNorm(nn.Module):
@@ -251,8 +253,8 @@ class _GateNorm(nn.Module):
         cfg = self.config
         scale = self.param("scale", nn.initializers.ones,
                            (cfg.mamba_inner,), cfg.param_dtype)
-        return ssm.gated_group_rms_norm(y, z, scale, cfg.ssm_groups,
-                                        cfg.rms_eps, mesh=self.mesh)
+        return gated_norm.gated_group_rms_norm(
+            y, z, scale, cfg.ssm_groups, cfg.rms_eps, mesh=self.mesh)
 
 
 class Mamba2Mixer(nn.Module):
@@ -273,7 +275,7 @@ class Mamba2Mixer(nn.Module):
         zxbcdt = _dense(cfg)(inner + cfg.conv_width + h, name="in_proj")(x)
         # named for a recomputed block's policy; the identity outside one
         z, xbc, dt = map(checkpoint_name, jnp.split(
-            zxbcdt, [inner, inner + cfg.conv_width], -1), ssm.IN_PROJ_PARTS)
+            zxbcdt, [inner, inner + cfg.conv_width], -1), IN_PROJ_PARTS)
         xbc = _Conv(cfg, self.mesh, name="conv")(xbc)
         xs, bs, cs = jnp.split(xbc, [inner, inner + g * n], -1)
         dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (h,),
@@ -293,7 +295,7 @@ class Mamba2Mixer(nn.Module):
             ssm_tokens=b * t, ssm_heads=h, ssm_state=n, ssm_groups=g,
             ssm_chunk=cfg.chunk, ssm_path=path,
             ssm_blocks_per_group=ssm.score_squares_per_group(h, g, path),
-            gate_norm_path=ssm.norm_path((b, t, inner), g, self.mesh))
+            gate_norm_path=gated_norm.norm_path((b, t, inner), g, self.mesh))
         if self.is_mutable_collection("stats"):
             self.sow("stats", "out_sq",
                      jnp.mean(jnp.square(y.astype(jnp.float32))))
@@ -428,15 +430,11 @@ class NemotronH(nn.Module):
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False):
         cfg = self.config
-        if (self.mesh is not None and "M" in cfg.pattern
-                and self.mesh.shape.get(cfg.sp_axis, 1) > 1):
-            raise NotImplementedError(
-                f"a Mamba-2 layer on a mesh with {cfg.sp_axis}="
-                f"{self.mesh.shape[cfg.sp_axis]}: the scan runs a whole "
-                "sequence on one chip; a sequence split over chips needs "
-                "the state passed from chip to chip, which is not "
-                "implemented. dp and fsdp shard the batch and need "
-                "nothing.")
+        if "M" in cfg.pattern:
+            program.refuse(self.mesh, "a Mamba-2 layer", **{
+                cfg.sp_axis: "the state passed from chip to chip that a "
+                "sequence split over chips needs (the scan runs a whole "
+                "sequence on one chip)"})
         tracing.note_trace(layer_pattern=cfg.pattern)
         with jax.named_scope("embed"):
             x = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",

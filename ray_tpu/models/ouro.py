@@ -31,16 +31,16 @@ token's row, ``L`` layers, ``R`` passes:
 holds ``L`` blocks and every block leaf's gradient is the sum over the
 passes that the scan's transpose makes. With ``remat`` a block is
 recomputed in the backward pass but for its attention core's output and
-row statistics (``ops/attention.py::remat_policy``), as
+row statistics (``ops/remat.py::remat_policy``), as
 ``models/laguna.py``, and for its MLP's matmul products
-(``_MLP_KEEPS``): what ``R x L`` applications keep is each one's input
+(``_mlp_keeps``): what ``R x L`` applications keep is each one's input
 and those. The MLP's names are chosen by the milliseconds a kept GB
 saves, which the contraction's depth sets: ``down``'s product (``[T,
 d]``, made by a matmul ``intermediate`` deep, and read in the backward
 pass because the block norms it on the branch) before ``up``'s and
 ``gate``'s (``[T, intermediate]``, ``d`` deep), for as long as the
 cell's memory lasts. On the chip that is ``down`` and ``up`` in every
-layer and ``gate`` in the second half of them (``_block_keeps``): with
+layer and ``gate`` in the second half of them (``_mlp_keeps``): with
 ``gate`` kept in all, the step at 8 layers x 4 passes x 4,096 rows no
 longer fits beside its own backward loop, XLA recomputes ``down``'s
 transposed product to make room, and the step is slower than with two
@@ -77,14 +77,25 @@ import jax.numpy as jnp
 
 from ray_tpu.models.joyai import _dense, _norm
 from ray_tpu.models.llama import SwiGLU, apply_rope_half, rope_freqs
-from ray_tpu.ops.attention import (
-    MLP_DOWN, MLP_GATE, MLP_UP, causal_attention, remat_keeps, remat_policy)
+from ray_tpu.ops import remat
+from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.pallas import program
+from ray_tpu.ops.remat import MLP_DOWN, MLP_GATE, MLP_UP
 from ray_tpu.util import tracing
 
-# what a recomputed block keeps of its MLP, dearest a byte first (the
-# module docstring): at 4,096 rows 16.8 MB (down) and 46.1 MB each (up,
-# gate) an application
-_MLP_KEEPS = (MLP_DOWN, MLP_UP, MLP_GATE)
+
+def _mlp_keeps(cfg) -> dict[str, int]:
+    """What a recomputed block keeps of its MLP, dearest a byte first
+    (the module docstring), as ``{name: the first layer that keeps
+    it}``: at 4,096 rows 16.8 MB (down) and 46.1 MB each (up, gate) an
+    application."""
+    return {MLP_DOWN: 0, MLP_UP: 0,
+            # the second half of the stack alone: where memory ends the
+            # list, it ends it layer by layer (on the chip the last four
+            # of eight read 466.3 ms a step, the first four 468.1, three
+            # 469.8, five 473.9, none 478.9, all 493.5: PERF.md section
+            # 6, PR 62)
+            MLP_GATE: cfg.n_layer // 2}
 
 
 @dataclass(frozen=True)
@@ -215,36 +226,12 @@ class ExitGate(nn.Module):
                         axis=-1) + b[0].astype(jnp.float32))
 
 
-def _first_keeping_all(cfg) -> int:
-    """The first of the layers that keep every name of ``_MLP_KEEPS``;
-    those before it keep all but the last: the second half of the stack.
-    Where memory ends the list, it ends it layer by layer (on the chip
-    the last four of eight read 466.3 ms a step, the first four 468.1,
-    three 469.8, five 473.9, none 478.9, all 493.5: PERF.md section 6,
-    PR 62)."""
-    return cfg.n_layer // 2
-
-
-def _block_keeps(cfg, i: int) -> tuple[str, ...]:
-    """The MLP's names that layer ``i``'s policy lists."""
-    return _MLP_KEEPS if i >= _first_keeping_all(cfg) else _MLP_KEEPS[:-1]
-
-
-def _keeps_note(cfg) -> str:
-    """``blocks_remat_keeps``: what ``_block_keeps`` gives the layers; a
-    name that the layers from ``k`` on alone keep reads ``name[k:]``."""
-    *every, last = _MLP_KEEPS
-    return ",".join(remat_keeps(
-        *every, f"{last}[{_first_keeping_all(cfg)}:]"))
-
-
 def _one_pass(mdl, h, angles):
     """The stack, the final norm and the exit gate once, on ``mdl``'s
     own parameters: ``h_{t-1} -> (h_t, (h_t, the gate's logit))``."""
     cfg = mdl.config
     for i in range(cfg.n_layer):
-        block = (nn.remat(Block, policy=remat_policy(*_block_keeps(cfg, i)))
-                 if cfg.remat else Block)
+        block = remat.block(Block, cfg.remat, _mlp_keeps(cfg), i)
         h = mdl._constrain(block(cfg, mdl.mesh, name=f"h_{i}")(h, angles))
     h = _norm(cfg)(name="norm_f")(h)
     return h, (h, ExitGate(cfg, name="exit_gate")(h))
@@ -265,25 +252,21 @@ class Ouro(nn.Module):
         from ray_tpu.parallel.sharding import constrain
         return constrain(x, self.mesh, "batch", "seq", None)
 
-    def _refuse_sp_tp(self):
-        for axis in ("sp", "tp"):
-            if self.mesh is not None and self.mesh.shape.get(axis, 1) > 1:
-                raise NotImplementedError(
-                    f"Ouro on a mesh with {axis}={self.mesh.shape[axis]}: "
-                    f"the passes' rows are stacked on the sequence axis "
-                    f"for the loss and the exit gate has no tp path")
-
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False):
         cfg = self.config
-        self._refuse_sp_tp()
+        program.refuse(
+            self.mesh, "Ouro",
+            sp="the sequence split over chips (the passes' rows are "
+               "stacked on the sequence axis for the loss)",
+            tp="a tp path for the exit gate")
         t = tokens.shape[1]
         if t > cfg.seq_len:
             raise ValueError(f"a row of {t} tokens, {cfg.seq_len} positions")
         tracing.note_trace(
             attn_kind="looped_full", ut_steps=cfg.ut_steps, ut_path="scan",
             rope_kind="half", blocks_remat=cfg.remat,
-            blocks_remat_keeps=_keeps_note(cfg) if cfg.remat else "")
+            blocks_remat_keeps=remat.keeps_note(cfg.remat, _mlp_keeps(cfg)))
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))

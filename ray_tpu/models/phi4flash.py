@@ -13,7 +13,7 @@ mixer of one of five kinds, by the architecture's own rule
 (``Phi4FlashConfig.kind``; layer ``l`` from 0, ``N`` layers, ``N % 4 ==
 0``; even layers hold a Mamba-family mixer, odd layers attention):
 
-- ``M``, ``l <= N/2`` even, **Mamba-1** (``ops/ssm.py::mamba1_scan``):
+- ``M``, ``l <= N/2`` even, **Mamba-1** (``ops/mamba1.py::mamba1_scan``):
   ``[x | z] = W_in h``; ``x = SiLU(conv4(x) + b)``; ``[delta | B | C] =
   W_x x``; ``dt = softplus(W_dt delta + b_dt)`` in float32; the
   selective scan over a ``[5120, 16]`` state whose decay differs by
@@ -44,7 +44,7 @@ the ``(K, V)`` pair (None before the layers that make them), as ZAYA's
 blocks hand on the router's state. With ``remat`` each block is
 recomputed in the backward pass and those are kept block outputs, as
 are an attention core's output and row statistics
-(``ops/attention.py::remat_policy``: the flash forward kernel runs once
+(``ops/remat.py::remat_policy``: the flash forward kernel runs once
 a layer) and the MLP's ``gate_up`` product (``[T, 2 x 10,240]``, 168 MB
 a layer at 4,096 rows: the block's dearest matmul does not run twice;
 ``down``'s product is added to the stream as it is, so nothing in the
@@ -77,9 +77,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.nemotron_h import _conv_init, _dt_bias_init
-from ray_tpu.ops import ssm
-from ray_tpu.ops.attention import (
-    MLP_GATE_UP, differential_attention, remat_keeps, remat_policy)
+from ray_tpu.ops import conv1d, mamba1, remat
+from ray_tpu.ops.attention import differential_attention
+from ray_tpu.ops.remat import MLP_GATE_UP
 from ray_tpu.util import tracing
 
 # what a recomputed block keeps of its MLP (the module docstring)
@@ -234,7 +234,7 @@ class _Conv(nn.Module):
                        (cfg.conv_kernel, cfg.mamba_inner), cfg.param_dtype)
         b = self.param("bias", _conv_init(cfg), (cfg.mamba_inner,),
                        cfg.param_dtype)
-        return ssm.causal_conv1d_silu(x, w, b, mesh=self.mesh)
+        return conv1d.causal_conv1d_silu(x, w, b, mesh=self.mesh)
 
 
 class _DtProj(nn.Module):
@@ -275,9 +275,9 @@ class Mamba(nn.Module):
         a_log = self.param("A_log", _a_log_init, (inner, n), f32)
         skip = self.param("D", nn.initializers.ones, (inner,), f32)
         with jax.named_scope("scan"):
-            y = ssm.mamba1_scan(x, dt, -jnp.exp(a_log), dbc[..., r:r + n],
-                                dbc[..., r + n:], skip, chunk=cfg.ssm_chunk,
-                                mesh=self.mesh)
+            y = mamba1.mamba1_scan(
+                x, dt, -jnp.exp(a_log), dbc[..., r:r + n], dbc[..., r + n:],
+                skip, chunk=cfg.ssm_chunk, mesh=self.mesh)
         self.sow("stats", "out_sq", jnp.mean(jnp.square(y)))
         with jax.named_scope("gate"):
             gated = (y * jax.nn.silu(z.astype(f32))).astype(cfg.dtype)
@@ -425,8 +425,7 @@ class Phi4Flash(nn.Module):
             ssm_state=cfg.ssm_state, ssm_dt_rank=cfg.dt_rank,
             yoco_memory_layer=cfg.memory_layer, yoco_kv_layer=cfg.kv_layer,
             blocks_remat=cfg.remat,
-            blocks_remat_keeps=",".join(remat_keeps(*_MLP_KEEPS))
-            if cfg.remat else "")
+            blocks_remat_keeps=remat.keeps_note(cfg.remat, _MLP_KEEPS))
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
@@ -435,8 +434,7 @@ class Phi4Flash(nn.Module):
         # a recomputed block keeps its attention core's output and row
         # statistics (42 MB a layer at 4,096 rows), as models/laguna.py,
         # and its MLP's gate_up product (168 MB a layer)
-        block = (nn.remat(Block, policy=remat_policy(*_MLP_KEEPS))
-                 if cfg.remat else Block)
+        block = remat.block(Block, cfg.remat, _MLP_KEEPS)
         memory = kv = None
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):

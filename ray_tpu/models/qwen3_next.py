@@ -18,7 +18,7 @@ routed MLP. The equations, as this file runs them:
   + 4,096: 16 key heads and 32 value heads of 128), ``[b | a] = h W_ba``
   (32 + 32); ``[q | k | v] = silu(conv1d([q | k | v]))``, depthwise,
   causal, 4 taps, no bias, 8,192 channels in one call
-  (``ops/ssm.py::causal_conv1d_silu``); ``beta = sigmoid(b)``, ``g =
+  (``ops/conv1d.py::causal_conv1d_silu``); ``beta = sigmoid(b)``, ``g =
   -exp(A_log) * softplus(a + dt_bias)`` in float32, **one a value head**;
   a head's q and k to unit length (``x * rsqrt(sum x^2 + 1e-6)``), q
   times ``128^-1/2`` (where, the recurrence's path decides:
@@ -26,7 +26,7 @@ routed MLP. The equations, as this file runs them:
   state ``S`` ``[128, 128]`` from zero: ``S <- exp(g_t) S``; ``u_t =
   beta_t (v_t - S^T k_t)``; ``S <- S + k_t u_t^T``; ``o_t = S^T q_t``;
   ``y = RMSNorm_128(o) * w * silu(z)`` a head in float32
-  (``ops/ssm.py::sigmoid_gated_head_rms_norm`` with ``gate_fn="silu"``);
+  (``ops/gated_norm.py::sigmoid_gated_head_rms_norm`` with ``gate_fn="silu"``);
   ``y W_out`` (4,096 -> 2,048);
 - **F, gated attention**: ``q, gate = h W_q, h W_g`` (16 heads of 256
   each; the published ``q_proj`` makes both, a head's 256 + 256 side by
@@ -52,15 +52,15 @@ blocks, as ``models/kimi_linear.py``; the note ``blocks_remat_keeps``
 lists the names), dearest a byte first: the attention core's output and
 row statistics (the flash forward kernel at 256 lanes, 0.14 GB); the
 router's float32 product, its choice, chosen probabilities, counts and
-``logsumexp`` (``ops/moe.py::ROUTER_KEEPS``, 35 MB a layer: 512 experts
+``logsumexp`` (``ops/remat.py::ROUTER_KEEPS``, 35 MB a layer: 512 experts
 wide; since PR 68 the choice is ``ops/pallas/router_choice.py``'s kernel
 pair, which names all of them, so neither it nor a ``top_k`` runs
 again); the
-mixers' output projections' products (``_MIXER_PROJ``, 67 MB: the stream
+mixers' output projections' products (``MIXER_PROJ``, 67 MB: the stream
 between a block's halves is then one add); a Gated DeltaNet mixer's
-gated output (``_GDN_OUT``, 134 MB) and the recurrence's forward
+gated output (``GDN_OUT``, 134 MB) and the recurrence's forward
 kernel's two results, ``o`` and the state entering every chunk
-(``ops/kda.py::SCAN_OUT``, ``SCAN_STATES``: 268 + 537 MB a layer at
+(``KDA_SCAN_OUT``, ``KDA_SCAN_STATES``: 268 + 537 MB a layer at
 16,384 rows of 32 heads), so that the recurrence runs forward once a
 layer a step as Kimi-Linear's does (that file's docstring has how the
 policy reaches through ``_gdn_core``'s checkpoint). The projections,
@@ -102,17 +102,16 @@ from ray_tpu.models.joyai import _dense, _Experts, _swiglu
 from ray_tpu.models.laguna import _rotate
 from ray_tpu.models.llama import _Router, rope_freqs
 from ray_tpu.models.nemotron_h import _conv_init
-from ray_tpu.ops import kda, ssm
-from ray_tpu.ops.attention import causal_attention, remat_keeps, remat_policy
-from ray_tpu.ops.moe import ROUTER_KEEPS, held_route_share, routed_ffn
+from ray_tpu.ops import conv1d, gated_norm, kda, remat
+from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.moe import held_route_share, routed_ffn
+from ray_tpu.ops.remat import (
+    GDN_IN, GDN_OUT, KDA_SCAN_OUT, KDA_SCAN_STATES, MIXER_PROJ, ROUTER_KEEPS)
 from ray_tpu.util import tracing
 
-_GDN_OUT = "gdn_gated_out"
-_GDN_IN = "gdn_in_proj"
-_MIXER_PROJ = "mixer_out_proj"
 # what a recomputed block keeps (the module docstring)
-_BLOCK_KEEPS = (*ROUTER_KEEPS, _MIXER_PROJ, _GDN_OUT, kda.SCAN_OUT,
-                kda.SCAN_STATES, _GDN_IN)
+_BLOCK_KEEPS = (*ROUTER_KEEPS, MIXER_PROJ, GDN_OUT, KDA_SCAN_OUT,
+                KDA_SCAN_STATES, GDN_IN)
 
 
 @dataclass(frozen=True)
@@ -289,7 +288,7 @@ def _gdn_core(qkv, z, b_logit, a, w, *, key_heads: int, heads: int,
     kd = z.shape[-1] // heads
     keys = key_heads * kd
     with jax.named_scope("conv"):
-        qkv = ssm.causal_conv1d_silu(qkv, w["conv"], mesh=mesh)
+        qkv = conv1d.causal_conv1d_silu(qkv, w["conv"], mesh=mesh)
     with jax.named_scope("decay"):
         g, beta = _decay(a, b_logit, w["A_log"], w["dt_bias"])
     # a head's q and k go in as the convolution left them, 16 heads
@@ -302,7 +301,7 @@ def _gdn_core(qkv, z, b_logit, a, w, *, key_heads: int, heads: int,
                      normalize_qk=True)
     out_sq = jnp.mean(jnp.square(o))
     with jax.named_scope("out_gate"):
-        y = ssm.sigmoid_gated_head_rms_norm(
+        y = gated_norm.sigmoid_gated_head_rms_norm(
             o.reshape(b, t, heads * kd), z, w["norm"], heads, eps,
             mesh=mesh, gate_fn=_OUT_GATE)
     return y, out_sq
@@ -325,9 +324,9 @@ class GatedDeltaNet(nn.Module):
         dense = _dense(cfg)
         with jax.named_scope("qkvz"):
             qkvz = checkpoint_name(
-                dense(2 * keys + 2 * inner, name="qkvz")(h), _GDN_IN)
+                dense(2 * keys + 2 * inner, name="qkvz")(h), GDN_IN)
         with jax.named_scope("ba"):
-            ba = checkpoint_name(dense(2 * heads, name="ba")(h), _GDN_IN)
+            ba = checkpoint_name(dense(2 * heads, name="ba")(h), GDN_IN)
         w = {
             "conv": self.param("conv", _conv_init(cfg),
                                (cfg.conv_kernel, 2 * keys + inner),
@@ -343,10 +342,10 @@ class GatedDeltaNet(nn.Module):
                 qkvz[..., :2 * keys + inner], qkvz[..., 2 * keys + inner:],
                 ba[..., :heads], ba[..., heads:], w)
         self.sow("stats", "out_sq", out_sq)
-        y = checkpoint_name(y, _GDN_OUT)
+        y = checkpoint_name(y, GDN_OUT)
         with jax.named_scope("out"):
             return checkpoint_name(dense(cfg.n_embd, name="out")(y),
-                                   _MIXER_PROJ)
+                                   MIXER_PROJ)
 
 
 def _attn_fn(cfg: Qwen3NextConfig, mesh):
@@ -398,7 +397,7 @@ class GatedAttention(nn.Module):
                 gate.astype(jnp.float32)).astype(o.dtype)
         with jax.named_scope("out"):
             return checkpoint_name(dense(cfg.n_embd, name="out")(o),
-                                   _MIXER_PROJ)
+                                   MIXER_PROJ)
 
 
 class MoE(nn.Module):
@@ -470,16 +469,14 @@ class Qwen3Next(nn.Module):
             attn_gate="elementwise_sigmoid", rope_kind="half",
             rope_lanes=cfg.rotated_lanes, norm_kind="zero_centred",
             moe_shared_gate="sigmoid", blocks_remat=cfg.remat,
-            blocks_remat_keeps=",".join(remat_keeps(*_BLOCK_KEEPS))
-            if cfg.remat else "")
+            blocks_remat_keeps=remat.keeps_note(cfg.remat, _BLOCK_KEEPS))
         angles = rope_freqs(cfg.rotated_lanes, t, cfg.rope_theta)
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
         with jax.named_scope("embed"):
             x = self._constrain(wte(tokens))
-        block = (nn.remat(Block, policy=remat_policy(*_BLOCK_KEEPS))
-                 if cfg.remat else Block)
+        block = remat.block(Block, cfg.remat, _BLOCK_KEEPS)
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):
                 x = self._constrain(

@@ -55,7 +55,7 @@ from ray_tpu.models.llama import RMSNorm, rope_freqs
 from ray_tpu.ops import cca
 from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.moe import held_route_share, routed_experts
-from ray_tpu.parallel.mesh import AXIS_SP
+from ray_tpu.ops.pallas import program
 from ray_tpu.util import tracing
 
 
@@ -321,14 +321,11 @@ class Zaya(nn.Module):
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False):
         cfg = self.config
-        if self.mesh is not None and self.mesh.shape.get(AXIS_SP, 1) > 1:
-            raise NotImplementedError(
-                f"CCA on a mesh with {AXIS_SP}="
-                f"{self.mesh.shape[AXIS_SP]}: the convolutions and the "
-                "shifted value read the previous token's row, which a "
-                "sequence split over chips holds on the neighbour; that "
-                "halo is not implemented. dp and fsdp shard the batch and "
-                "need nothing.")
+        program.refuse(
+            self.mesh, "CCA",
+            sp="the halo of a sequence split over chips (the convolutions "
+               "and the shifted value read the previous token's row, which "
+               "the neighbour holds)")
         tracing.note_trace(
             attn_kind="cca", **cca.path_notes(
                 (*tokens.shape, cfg.latent), cfg.n_head, cfg.n_kv_head,

@@ -32,49 +32,15 @@ Shapes follow jax convention: [batch, seq, heads, head_dim].
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
+
+from ray_tpu.ops.pallas import program
 
 _NEG_INF = -1e30
-
-# The names of a flash core's output and of its rows' log-sum-exp: the
-# two residuals of its backward kernel that only the forward kernel
-# makes.
-ATTN_OUT = "attn_out"
-ATTN_LSE = "attn_lse"
-
-
-def name_core_results(out, lse):
-    """A forward kernel's two results under the names a recomputed
-    block's policy keeps (``remat_policy``), so that its backward pass
-    does not run the kernel again for them. For the forward rule of a
-    core's ``custom_vjp`` (``ops/pallas/flash_attention.py``,
-    ``ops/mla.py``), before the two part into primal and residuals: a
-    name on the primal alone would leave ``lse`` to be made again, and
-    the kernel with it."""
-    return checkpoint_name(out, ATTN_OUT), checkpoint_name(lse, ATTN_LSE)
-
-
-def remat_keeps(*more: str) -> tuple[str, ...]:
-    """The names a recomputed block keeps: a model's own (``more``)
-    first, then the attention cores' two."""
-    return (*more, ATTN_OUT, ATTN_LSE)
-
-
-def remat_policy(*more: str):
-    """``nn.remat`` / ``jax.checkpoint``'s policy for a block that holds
-    an attention core: everything is made again in the backward pass but
-    what carries one of ``remat_keeps(*more)``. So q, k and v are
-    projected again and the forward kernel, the dearest thing in the
-    block a byte kept, is not run again for an ``out`` and an ``lse``
-    the first pass made (docs/training_perf.md). The names are the
-    identity outside such a policy."""
-    return jax.checkpoint_policies.save_only_these_names(
-        *remat_keeps(*more))
-
 
 def _flash_ok(q, k, v) -> bool:
     """This function's kernel shape: three equal [B, T, H, D]. (Unequal
@@ -319,9 +285,9 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           tiled=True)
 
 
-def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
-                                  seq_axis="sp", head_axis="tp", impl="auto",
-                                  window: int | None = None, scale=None):
+def make_sharded_causal_attention(mesh, seq_axis="sp", head_axis="tp",
+                                  impl="auto", window: int | None = None,
+                                  scale=None):
     """Build an attention fn for activations sharded
     [batch->dp/fsdp, seq->sp, heads->tp]: shard_map-wrapped ring
     attention when the mesh has a real sp axis, dense attention
@@ -358,9 +324,9 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
             f"(got {seq_axis}={sp}); the O(seq/sp) per-device K/V "
             f"memory you asked for does not exist on this mesh — use "
             f"'auto' or add a {seq_axis} axis")
+    # the batch's axes are the ones ``program.batch_axes`` maps over
+    batch = tuple(a for a in program.BATCH_AXES if mesh.shape.get(a, 1) > 1)
     if sp <= 1:
-        batch = tuple(a for a in batch_axes
-                      if mesh.shape.get(a, 1) > 1)
         heads = (head_axis if mesh.shape.get(head_axis, 1) > 1
                  else None)
         if mesh.size == 1:
@@ -386,9 +352,7 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
         # the chip's 128-lane tiles, so XLA lays it out with T
         # innermost and copies it into and out of the kernel's layout.
         spec = P(batch if batch else None, None, heads)
-        n_batch = 1
-        for a in batch:
-            n_batch *= mesh.shape[a]
+        n_batch = math.prod(mesh.shape[a] for a in batch)
         n_heads = mesh.shape[head_axis] if heads else 1
 
         def dispatch(q, k, v):
@@ -411,7 +375,6 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
                             for x in (q, k, v))).reshape(q.shape)
         return dispatch
 
-    batch = tuple(a for a in batch_axes if mesh.shape.get(a, 1) > 1)
     spec = P(batch if batch else None, seq_axis,
              head_axis if mesh.shape.get(head_axis, 1) > 1 else None,
              None)
@@ -420,19 +383,3 @@ def make_sharded_causal_attention(mesh, batch_axes=("dp", "fsdp"),
     fn = functools.partial(local_impl, axis_name=seq_axis, scale=scale)
     return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)
-
-
-# The names of a dense MLP's matmul products, beside ``ATTN_OUT`` and
-# ``ATTN_LSE`` in everything but place (``models/llama.py::SwiGLU``:
-# three, so that a model can keep a subset; ``models/phi4flash.py::MLP``:
-# ``[g | u]`` before the split). A recomputed block whose policy lists
-# one does not run that matmul a second time; which of them a model
-# lists is settled by its cell's memory, dearest millisecond a byte
-# first (docs/training_perf.md). They stand at the file's end because a
-# Mosaic kernel's body carries the line of every frame above it: a line
-# added above ``causal_attention`` would move the compile-cache key of
-# every step that holds a flash kernel.
-MLP_GATE = "mlp_gate"
-MLP_UP = "mlp_up"
-MLP_DOWN = "mlp_down"
-MLP_GATE_UP = "mlp_gate_up"

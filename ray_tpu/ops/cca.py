@@ -52,19 +52,18 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.pallas import cca_mix
-from ray_tpu.ops.ssm import _kernel_batch_axes
+from ray_tpu.ops.pallas import cca_mix, program
 
 
 def cca_path(shape, n_head: int, n_kv_head: int, taps, mesh=None) -> str:
     """What computes ``conv``, ``mix`` and ``rope`` for ``[q~ | k~]``
     [B, T, (H + G) * D] at these taps: ``pallas`` on a TPU where the
-    kernels tile the shapes and ``_kernel_batch_axes`` finds the
-    program one they can serve (``ops/ssm.py``: one device, or a mesh
-    that shards the batch alone), else ``xla``."""
+    kernels tile the shapes and ``program.batch_axes`` finds the
+    program one they can serve (one device, or a mesh that shards the
+    batch alone), else ``xla``."""
     if (jax.default_backend() == "tpu" and len(shape) == 3
             and cca_mix.shapes_ok(shape[-1], n_head, n_kv_head, taps)
-            and _kernel_batch_axes(mesh, shape[0]) is not None):
+            and program.batch_axes(mesh, shape[0]) is not None):
         return "pallas"
     return "xla"
 
@@ -213,7 +212,7 @@ def cca_attention(qk, v, conv0, conv1, tau, angles, *, n_head: int,
         q, k = cca_mix.cca_mix(
             qk, conv0, conv1, tau, angles, n_head=n_head,
             n_kv_head=n_kv_head, mesh=mesh,
-            batch_axes=_kernel_batch_axes(mesh, b))
+            batch_axes=program.batch_axes(mesh, b))
     else:
         q, k = mixed_qk(qk, conv0, conv1, tau, angles, n_head, n_kv_head)
     with jax.named_scope("core"):

@@ -63,7 +63,7 @@ state between the maps and ``pre``, or ``post`` with the next
 sub-layer's maps, is not here (ROADMAP A12).
 
 **Meshes.** ``dp`` / ``fsdp`` shard the batch and need nothing. ``sp``
-and ``tp`` are refused by name (``refuse_split_state``): a state whose
+and ``tp`` are refused by name (``SPLIT_STATE``): a state whose
 ``n d`` lanes are split over chips would need the norm's and the
 product's partial sums gathered a sub-layer, and a split sequence
 nothing, but neither has been run.
@@ -74,26 +74,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.pallas import hc_maps as kernels
-from ray_tpu.ops.pallas.hc_maps import MAPS_KEEPS  # noqa: F401
-from ray_tpu.ops.ssm import _kernel_batch_axes
+from ray_tpu.ops.pallas import hc_maps as kernels, program
 
 
-def refuse_split_state(mesh) -> None:
-    """Raises where ``mesh`` splits the sequence or the lanes."""
-    if mesh is None:
-        return
-    from ray_tpu.parallel.mesh import AXIS_SP, AXIS_TP
-    for axis, what in ((AXIS_SP, "the n-stream state with the sequence "
-                        "split over chips"),
-                       (AXIS_TP, "the n-stream state with its lanes split "
-                        "over chips (the maps' norm and product would sum "
-                        "over chips a sub-layer)")):
-        if mesh.shape.get(axis, 1) > 1:
-            raise NotImplementedError(
-                f"hyper-connections on a mesh with {axis}="
-                f"{mesh.shape[axis]}: {what} is not implemented; dp and "
-                "fsdp shard the batch and need nothing")
+# what a mesh that splits the state would take, for ``program.refuse``
+# (``models/joyai.py``, before a layer is built)
+SPLIT_STATE = {
+    "sp": "the n-stream state with the sequence split over chips",
+    "tp": "the n-stream state with its lanes split over chips (the maps' "
+          "norm and product would sum over chips a sub-layer)"}
 
 
 def map_width(n: int) -> int:
@@ -145,12 +134,11 @@ MAPS_BLOCK_TOKENS = kernels.BLOCK_ROWS * 128
 def hc_maps_path(shape, n: int, mesh=None) -> str:
     """Which ``hc_maps`` compiles for a state ``shape`` [B, T, n d]:
     ``pallas`` (the kernels of ``ops/pallas/hc_maps.py``) on a TPU where
-    ``T`` is whole 128-lane tiles and ``ops/ssm.py::
-    _kernel_batch_axes`` finds the program one the kernels can serve,
-    else ``xla``."""
+    ``T`` is whole 128-lane tiles and ``program.batch_axes`` finds the
+    program one the kernels can serve, else ``xla``."""
     if (jax.default_backend() == "tpu" and len(shape) == 3
             and kernels.shapes_ok(shape[1])
-            and _kernel_batch_axes(mesh, shape[0]) is not None):
+            and program.batch_axes(mesh, shape[0]) is not None):
         return "pallas"
     return "xla"
 
@@ -167,7 +155,7 @@ def hc_maps(x, phi, b, alpha, *, n: int, iters: int, eps: float,
         return kernels.hc_maps(
             x, phi, b, alpha, n=n, iters=iters, eps=eps, clamp=clamp,
             norm_eps=norm_eps, mesh=mesh,
-            batch_axes=_kernel_batch_axes(mesh, x.shape[0]))
+            batch_axes=program.batch_axes(mesh, x.shape[0]))
     return _hc_maps_xla(x, phi, b, alpha, n=n, iters=iters, eps=eps,
                         clamp=clamp, norm_eps=norm_eps)
 

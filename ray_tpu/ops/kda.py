@@ -60,7 +60,7 @@ states among them, when it reaches it.
 five equations with a chunk's arrays and the state in VMEM (no groups:
 the backward keeps the state entering every chunk and recomputes a
 chunk's squares from it, and the forward rule names ``o`` and those
-states, ``SCAN_OUT`` and ``SCAN_STATES``, for the policy of a recomputed
+states, ``ops/remat.py::KDA_SCAN_*``, for the policy of a recomputed
 block that would keep them; given a mixer's un-normalised ``q`` and ``k``
 it also makes their unit rows there, where ``xla_chunked`` makes float32
 arrays of them first: ``kda_scan``'s ``normalize_qk``), on a TPU
@@ -94,14 +94,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops import ssm
-from ray_tpu.ops.pallas import kda_scan as kernels
+from ray_tpu.ops.pallas import kda_scan as kernels, program
 from ray_tpu.util import tracing
 
-# the names of the kernels' forward results, for a recomputed block's
-# policy (``models/kimi_linear.py``), as ``ops/attention.py`` has its
-# cores' ``ATTN_OUT`` and ``ATTN_LSE``
-SCAN_OUT, SCAN_STATES = kernels.SCAN_OUT, kernels.SCAN_STATES
 SUB = 16            # rows of a sub-block of a chunk (``_scores``, ``_solve``)
 GROUP_ROWS = 512    # rows of one recomputed group of chunks
 # float32 operands as three bfloat16 passes (``HIGH``): 2^-16 a product
@@ -118,24 +113,18 @@ def kda_path(shape, chunk: int, mesh=None, *, values: int | None = None
     the chunk and the program is one device's, else ``xla_chunked``.
     Raises where the program spans chips in a way that would split a
     sequence or its heads."""
-    from ray_tpu.parallel.mesh import AXIS_SP, AXIS_TP
-    if mesh is not None:
-        for axis, what in ((AXIS_SP, "the sequence split over chips (a "
-                            "recurrent state passed from chip to chip)"),
-                           (AXIS_TP, "the heads split over chips")):
-            if mesh.shape.get(axis, 1) > 1:
-                raise NotImplementedError(
-                    f"kda on a mesh with {axis}={mesh.shape[axis]}: {what} "
-                    "is not implemented for it; dp and fsdp shard the "
-                    "batch and need nothing")
+    program.refuse(
+        mesh, "kda",
+        sp="the sequence split over chips (a recurrent state passed from "
+           "chip to chip)",
+        tp="the heads split over chips")
     if chunk % min(SUB, chunk):
         raise ValueError(f"chunk {chunk} is not whole sub-blocks of {SUB}")
-    # a ``pallas_call`` has no SPMD partitioning rule: the kernels where
-    # ``ops/ssm.py``'s rule finds a one-device program (no axis to map
-    # them over: its ``shard_map`` is not carried over)
+    # the kernels where the rule finds a one-device program (no axis to
+    # map them over: ``program.over_batch`` is not carried over)
     if (jax.default_backend() == "tpu"
             and kernels.shapes_ok(shape[-1], values or shape[-1], chunk)
-            and ssm._kernel_batch_axes(mesh, shape[0]) == ()):
+            and program.batch_axes(mesh, shape[0]) == ()):
         return "pallas_chunked"
     return "xla_chunked"
 
@@ -444,7 +433,7 @@ def gdn_scan(q, k, v, g, beta, *, chunk: int = 64, mesh=None,
     grid cell the key heads of its value heads (a ``BlockSpec``), reads
     ``g`` as it reads ``beta`` and writes ``dg`` one float a row a
     head. The kernels' forward rule names its results as ``kda_scan``'s
-    does (``SCAN_OUT``, ``SCAN_STATES``). The recurrence is under the
+    does (``KDA_SCAN_OUT``, ``KDA_SCAN_STATES``). The recurrence is under the
     scope ``scan`` on both paths, ``qk_norm`` as ``kda_scan``'s."""
     heads, key_heads = v.shape[2], q.shape[2]
     if g.ndim != 3 or heads % key_heads or k.shape != q.shape:

@@ -72,13 +72,13 @@ cell whose note says so.
 from __future__ import annotations
 
 import functools
-import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import name_core_results
+from ray_tpu.ops.pallas import program
+from ray_tpu.ops.remat import name_core_results
 from ray_tpu.util import tracing
 
 
@@ -97,35 +97,25 @@ def mla_path(batch: int, t: int, n_head: int, dn: int, dr: int, dv: int,
     ``ops/attention.py`` decides for equal widths; raises where a TPU
     program with shapes that tile cannot reach the kernel."""
     from ray_tpu.ops.pallas.flash_attention import mla_flash_shapes_ok
-    from ray_tpu.parallel.mesh import (
-        AXIS_DP, AXIS_EP, AXIS_FSDP, AXIS_SP, AXIS_TP)
 
-    if mesh is not None:
-        for axis, what in ((AXIS_SP, "the sequence split over chips (keys "
-                            "passed round a ring)"),
-                           (AXIS_TP, "the heads split over chips"),
-                           (AXIS_EP, "attention operands replicated over an "
-                            "expert axis")):
-            if mesh.shape.get(axis, 1) > 1:
-                raise NotImplementedError(
-                    f"latent attention on a mesh with {axis}="
-                    f"{mesh.shape[axis]}: {what} is not implemented for "
-                    "it; dp and fsdp shard the batch and need nothing")
+    program.refuse(
+        mesh, "latent attention",
+        sp="the sequence split over chips (keys passed round a ring)",
+        tp="the heads split over chips",
+        ep="attention operands replicated over an expert axis")
     if not interpret and (
             jax.default_backend() != "tpu"
             or not mla_flash_shapes_ok(t, dn, dr, dv, n_head)):
         return "xla", ()
-    if mesh is None or mesh.size == 1:
-        if mesh is None and not interpret and jax.device_count() != 1:
-            raise NotImplementedError(
-                "latent attention in a process of "
-                f"{jax.device_count()} devices and no mesh: a bare Pallas "
-                "kernel cannot be partitioned; give the model its mesh")
-        return "kernel", ()
-    axes = tuple(a for a in (AXIS_DP, AXIS_FSDP) if mesh.shape.get(a, 1) > 1)
-    if batch % math.prod(mesh.shape[a] for a in axes):
-        return "xla", ()        # e.g. the tiny batch of init-time tracing
-    return "kernel", axes
+    if mesh is None and not interpret and jax.device_count() != 1:
+        raise NotImplementedError(
+            "latent attention in a process of "
+            f"{jax.device_count()} devices and no mesh: a bare Pallas "
+            "kernel cannot be partitioned; give the model its mesh")
+    # no mesh: interpreted kernels (tests) run bare whatever the process
+    # holds; None: e.g. the tiny batch of init-time tracing
+    axes = () if mesh is None else program.batch_axes(mesh, batch)
+    return ("xla", ()) if axes is None else ("kernel", axes)
 
 
 def _xla_core(qn, qr, kn, kr, v, n_head: int, scale: float):
@@ -145,16 +135,6 @@ def _xla_core(qn, qr, kn, kr, v, n_head: int, scale: float):
                    v.reshape(b, t, n_head, -1),
                    preferred_element_type=jnp.float32)
     return o.reshape(b, t, -1).astype(v.dtype)
-
-
-def _over_batch(fn, mesh, axes, n_in: int):
-    """``fn`` on each chip's rows of the batch (every operand and
-    result carries the batch first); ``fn`` itself where no axis maps."""
-    if not axes:
-        return fn
-    from jax.sharding import PartitionSpec as P
-    return jax.shard_map(fn, mesh=mesh, in_specs=(P(axes),) * n_in,
-                         out_specs=P(axes), check_vma=False)
 
 
 def latent_attention(c_q, c_kv, k_r, up: UpProjections, angles, *,
@@ -244,8 +224,10 @@ def latent_attention(c_q, c_kv, k_r, up: UpProjections, angles, *,
     static = mla_flash_static(t, dn, dr, scale, interpret=interpret)
 
     def kernel(fn, n_in):
-        return jax.named_scope("core")(_over_batch(
-            functools.partial(fn, static=static), mesh, axes, n_in))
+        # every operand and result carries the batch first
+        return jax.named_scope("core")(program.over_batch(
+            functools.partial(fn, static=static), mesh, axes,
+            in_specs=(0,) * n_in, out_specs=0))
 
     if saved == "expanded":     # the kernels' own custom_vjp keeps them
         return kernel(mla_flash_core, 5)(*expand(angles, *operands))

@@ -70,9 +70,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.pallas import route_rows, router_choice
-from ray_tpu.ops.pallas.router_choice import (
-    ROUTER_COUNTS, ROUTER_EXPERTS, ROUTER_LSE, ROUTER_WEIGHTS)
+from ray_tpu.ops.pallas import program, route_rows, router_choice
+from ray_tpu.ops.remat import (
+    ROUTER_COUNTS, ROUTER_EXPERTS, ROUTER_LOGITS, ROUTER_WEIGHTS)
 from ray_tpu.util import tracing
 
 
@@ -296,28 +296,6 @@ def _route(x, router_w, top_k: int, norm_topk_prob: bool, path: str = "xla"):
     if norm_topk_prob:
         weights = weights / weights.sum(axis=-1, keepdims=True)
     return weights, experts, prob_sum, jnp.sum(lse * lse), counts
-
-
-# The names of what a router makes of a layer's ``[T, d]`` tokens, for a
-# recomputed block's policy (``ops/attention.py::
-# remat_policy``; the identity under any other, and outside one): the
-# product ``x W``, float32 ``[T, E]``, so that the float32 matmul at the
-# highest precision runs once (in front of the sigmoid, not behind it:
-# a policy keeps a name's result, and the sigmoid's backward reads the
-# sigmoid's own, so a name behind it would be kept and never read; the
-# second pass makes the scores again from the kept product, in the
-# backward kernel's VMEM or as one fused pass); the chosen ``experts``
-# and their scores ``[T, k]``, so that neither the choice's kernel nor
-# ``top_k`` and the gather run again; the routes each expert received,
-# ``[E]`` (the kernel's, or ``_routed_ffn_local``'s scatter-add of
-# ``T k`` ones for any router's), that the grouped matmuls' sizes would
-# otherwise be made from a second time; and the softmax router's
-# ``logsumexp`` a token ``[T]``, which only the kernel's forward makes
-# and its backward reads (``ops/pallas/router_choice.py`` names the last
-# four in its forward rule).
-ROUTER_LOGITS = "moe_router_logits"
-ROUTER_KEEPS = (ROUTER_LOGITS, ROUTER_EXPERTS, ROUTER_WEIGHTS,
-                ROUTER_COUNTS, ROUTER_LSE)
 
 
 def _route_sigmoid(x, router_w, select_bias, top_k: int,
@@ -574,39 +552,6 @@ def _routed_ffn_local(x, route_args, w_gate, w_up, w_down, *, route,
     return y.reshape(shape), aux, z, load
 
 
-def _token_axes(mesh, batch: int, seq: int):
-    """The mesh axes of size > 1 that shard a ``[batch, seq, d]``
-    activation's tokens, as ``train.step.batch_spec`` places them:
-    (dp and fsdp on the batch, sp or None on the sequence). ((), None)
-    where the layer stays one global program: no mesh, one device, or
-    shapes the axes do not divide (the tiny batch of init-time
-    tracing). The layer replicates its experts on every chip, so a
-    mesh that shards them (ep, tp) is refused by name."""
-    if mesh is None or mesh.size == 1:
-        return (), None
-    from ray_tpu.parallel.mesh import (
-        AXIS_DP, AXIS_EP, AXIS_FSDP, AXIS_SP, AXIS_TP)
-    for axis, what in ((AXIS_EP, "expert parallelism (an all_to_all of "
-                        "the sorted routes)"),
-                       (AXIS_TP, "tensor parallelism (each expert's "
-                        "width split over the axis)")):
-        if mesh.shape.get(axis, 1) > 1:
-            raise NotImplementedError(
-                f"routed_ffn on a mesh with {axis}={mesh.shape[axis]}: "
-                "the dropless top-k path replicates its experts and "
-                f"sorts each chip's own tokens; {what} is not "
-                "implemented for it yet, and the shard_map would gather "
-                "every expert onto every chip each step. The top-1 "
-                "moe_ffn / SwitchFFN still run under ep.")
-    batch_axes = tuple(a for a in (AXIS_DP, AXIS_FSDP)
-                       if mesh.shape.get(a, 1) > 1)
-    seq_axis = AXIS_SP if mesh.shape.get(AXIS_SP, 1) > 1 else None
-    if (batch % math.prod(mesh.shape[a] for a in batch_axes)
-            or (seq_axis and seq % mesh.shape[seq_axis])):
-        return (), None
-    return batch_axes, seq_axis
-
-
 def routed_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                norm_topk_prob: bool = False, mesh=None,
                router: str = "softmax", select_bias=None,
@@ -762,11 +707,12 @@ def route_softmax(x, router_w, *, top_k: int, norm_topk_prob: bool = False,
 
 class _Shards(NamedTuple):
     """How a mesh shards a ``[batch, seq, d]`` (or ``[tokens, d]``)
-    activation's tokens: the axes of ``_token_axes`` in one tuple, the
-    activation's ``PartitionSpec`` over them, a chip's tokens, and
-    whether the layer is one global program over several devices (no
-    axis shards the tokens of a mesh of more than one), where the plain
-    forms run: a bare ``pallas_call`` has no SPMD rule."""
+    activation's tokens: the axes of ``program.token_axes`` in one
+    tuple, the activation's ``PartitionSpec`` over them, a chip's
+    tokens, and whether the layer is one global program over several
+    devices (no axis shards the tokens of a mesh of more than one),
+    where the plain forms run: a bare ``pallas_call`` has no SPMD
+    rule."""
     axes: tuple
     spec: Any
     tokens: int
@@ -774,8 +720,20 @@ class _Shards(NamedTuple):
 
 
 def _token_shards(mesh, x) -> _Shards:
+    """The layer replicates its experts on every chip, so a mesh that
+    shards them (ep, tp) is refused by name."""
     from jax.sharding import PartitionSpec as P
-    batch_axes, seq_axis = _token_axes(
+    program.refuse(
+        mesh, "routed_ffn",
+        ep="expert parallelism of the dropless top-k path (an all_to_all "
+           "of the sorted routes: as it is the layer replicates its "
+           "experts and sorts each chip's own tokens, and the shard_map "
+           "would gather every expert onto every chip each step; the "
+           "top-1 moe_ffn / SwitchFFN still run under ep)",
+        tp="tensor parallelism of the dropless top-k path (each expert's "
+           "width split over the axis: as it is the layer replicates its "
+           "experts)")
+    batch_axes, seq_axis = program.token_axes(
         mesh, x.shape[0], x.shape[1] if x.ndim == 3 else 1)
     axes = batch_axes + ((seq_axis,) if seq_axis else ())
     return _Shards(axes, P(batch_axes or None, seq_axis),

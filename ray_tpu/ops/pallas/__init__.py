@@ -18,6 +18,11 @@ mesh), never from an environment variable:
 - ``router_choice``: a router's scores, ``top_k``, chosen weights,
   counts and sums in one pass over the product, its backward a one-hot
   pass (``ops/moe.py::_route`` / ``_route_sigmoid``).
+
+``program`` is not a kernel: it answers once, for all of them, which
+program a bare ``pallas_call`` may run in. The dispatchers are a level
+up (``ops/*.py``), and nothing here imports from that level but the
+names a recomputed block keeps (``ops/remat.py``).
 """
 
 from ray_tpu.ops.pallas.flash_attention import (

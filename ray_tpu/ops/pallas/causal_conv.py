@@ -1,7 +1,7 @@
 """The depthwise causal convolution with its SiLU as two Pallas TPU
 kernels, forward and backward, under one ``custom_vjp``.
 
-The mathematics is ``ops/ssm.py::causal_conv1d_silu``'s: ``y[t, c] =
+The mathematics is ``ops/conv1d.py::causal_conv1d_silu``'s: ``y[t, c] =
 silu(bias[c] + sum_j w[j, c] * x[t - (K - 1) + j, c])``, zeros before
 the start. What differs is the traffic and the precision. As XLA's
 fusions the forward (a padded copy of ``x``, four shifted slices of it)
@@ -57,7 +57,7 @@ the ``pallas_call``s are jitted, so a model's layers trace and lower
 each kernel once a shape; a ``pallas_call`` has no SPMD partitioning
 rule, so ``causal_conv`` takes the mesh and the axes the batch is
 sharded over and maps the kernels over them. Which programs get the
-kernels is ``ops/ssm.py::conv_path``'s decision.
+kernels is ``ops/conv1d.py::conv_path``'s decision.
 
 What one v5e chip showed (PERF.md section 6, PR 55; ``scripts/
 conv_timing.py``, the device's time in a profile, a call alone,
@@ -91,6 +91,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.pallas import program
 
 _F32 = jnp.float32
 # One operand's block. Both passes double-buffer every operand and
@@ -364,19 +366,14 @@ _conv_core.defvjp(_conv_core_fwd, _conv_core_bwd)
 
 def causal_conv(x, weight, bias=None, *, interpret: bool = False, mesh=None,
                 batch_axes=(), **blocks):
-    """``ops/ssm.py::causal_conv1d_silu`` on the kernels: x [b, T, C];
+    """``ops/conv1d.py::causal_conv1d_silu`` on the kernels: x [b, T, C];
     weight [K, C]; bias [C] or None; the same result in ``x``'s dtype,
     differentiable in all three, the cotangents in the operands'
     dtypes. ``C`` and ``K`` must pass ``shapes_ok``; ``T`` is any.
     ``blocks`` (``rows``, ``lanes``, ``strip``, ``width``) are
-    ``_blocks``'s, for ``scripts/conv_timing.py`` and the tests.
-
-    A program that spans the devices of ``mesh`` names in
-    ``batch_axes`` the axes its batch is sharded over, and the kernels
-    run under a ``shard_map`` over them (as ``gated_norm.gated_norm``):
-    a sequence needs nothing of another's, and the weights, held whole
-    on every device, have their cotangents summed over the axes by the
-    map's transpose."""
+    ``_blocks``'s, for ``scripts/conv_timing.py`` and the tests;
+    ``mesh`` and ``batch_axes`` are ``program.over_batch``'s (a sequence
+    needs nothing of another's, the weights are whole on every device)."""
     _, t, c = x.shape
     k = weight.shape[0]
     if not shapes_ok(c, k):
@@ -384,12 +381,8 @@ def causal_conv(x, weight, bias=None, *, interpret: bool = False, mesh=None,
             f"the convolution's kernels do not tile {c} columns at {k} taps")
     core = functools.partial(_conv_core, st=_Static(
         *_blocks(t, c, x.dtype.itemsize, **blocks), interpret))
-    if batch_axes:
-        from jax.sharding import PartitionSpec
-        rows_spec, whole = PartitionSpec(tuple(batch_axes)), PartitionSpec()
-        core = jax.shard_map(
-            core, mesh=mesh, in_specs=(rows_spec, whole, whole),
-            out_specs=rows_spec, check_vma=False)
+    core = program.over_batch(core, mesh, batch_axes,
+                              in_specs=(0, None, None), out_specs=0)
     if bias is None:
         bias = jnp.zeros((c,), _F32)
     return core(x, weight.astype(_F32), bias.astype(_F32)[None])

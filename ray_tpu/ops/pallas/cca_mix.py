@@ -114,6 +114,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops.pallas import program
+
 _F32 = jnp.float32
 # Rows of a block: 1.3 MB of ``[q~ | k~]``; the backward double-buffers
 # three such operands and one result beside 2.7 MB of the weights'
@@ -536,14 +538,9 @@ def cca_mix(qk, conv0, conv1, tau, angles, *, n_head: int, n_kv_head: int,
     """``ops/cca.py::_qk_for_kernel`` on the kernels: the same operands,
     (q [B, T, H, D], k [B, T, G, D]) in ``qk``'s dtype, differentiable
     in ``qk``, both convolutions' weights and biases and ``tau``. The
-    shapes must pass ``shapes_ok``; ``T`` is any.
-
-    A program that spans the devices of ``mesh`` names in
-    ``batch_axes`` the axes its batch is sharded over, and the kernels
-    run under a ``shard_map`` over them (as ``gated_norm.gated_norm``):
-    a sequence needs nothing of another's, and the weights, held whole
-    on every device, have their cotangents summed over the axes by the
-    map's transpose."""
+    shapes must pass ``shapes_ok``; ``T`` is any; ``mesh`` and
+    ``batch_axes`` are ``program.over_batch``'s (a sequence needs
+    nothing of another's, the weights are whole on every device)."""
     b_, t, c = qk.shape
     (w0, b0), (w1, b1) = conv0, conv1
     taps = (w0.shape[0], w1.shape[0])
@@ -555,12 +552,8 @@ def cca_mix(qk, conv0, conv1, tau, angles, *, n_head: int, n_kv_head: int,
             f"{n_kv_head} heads at taps {taps}, {2 * half} lanes turning")
     core = functools.partial(_mix_core, st=_Static(
         n_head, n_kv_head, half, block_rows(t), _STRIP, interpret))
-    if batch_axes:
-        from jax.sharding import PartitionSpec
-        rows_spec, whole = PartitionSpec(tuple(batch_axes)), PartitionSpec()
-        core = jax.shard_map(
-            core, mesh=mesh, in_specs=(rows_spec,) + (whole,) * 7,
-            out_specs=(rows_spec, rows_spec), check_vma=False)
+    core = program.over_batch(core, mesh, batch_axes,
+                              in_specs=(0,) + (None,) * 7, out_specs=(0, 0))
     f32 = functools.partial(jnp.asarray, dtype=_F32)
     with jax.named_scope("mix"):
         q, k = core(qk, f32(w0), f32(b0)[None], f32(w1), f32(b1)[None],

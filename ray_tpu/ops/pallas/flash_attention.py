@@ -81,7 +81,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ray_tpu.ops.attention import name_core_results
+from ray_tpu.ops.remat import name_core_results
 from ray_tpu.util import tracing
 
 _NEG_INF = -1e30

@@ -6,7 +6,7 @@ shells; a strip's arithmetic (``_FORMS``) and ``scale``'s shape are what
 differ.
 
 **Mamba-2's** (``norm_gated``). The mathematics and the precisions are
-``ops/ssm.py::gated_group_rms_norm``'s: ``RMSNorm(y * silu(z))``, the
+``ops/gated_norm.py::gated_group_rms_norm``'s: ``RMSNorm(y * silu(z))``, the
 mean square over each of ``groups`` equal slices of the last dimension,
 one ``scale`` over all of it, float32 inside and ``y``'s dtype out.
 What differs is the layout. The scan's kernel writes ``y`` and
@@ -18,7 +18,7 @@ nothing leaves that layout: each pass reads its operands once and
 writes its results once.
 
 **Kimi Delta Attention's** (``gate_normed``, PR 58):
-``ops/ssm.py::sigmoid_gated_head_rms_norm``'s ``sigmoid(gate) *
+``ops/gated_norm.py::sigmoid_gated_head_rms_norm``'s ``sigmoid(gate) *
 RMSNorm_head(o) * scale``: the *normed* output gated, by a sigmoid,
 where Mamba-2 norms the gated product; the mean square over each of
 ``heads`` equal slices (a group above), ``scale`` ``[C / heads]`` shared
@@ -63,7 +63,7 @@ what a trace's operations are called), so a model's layers trace and
 lower each kernel once; a ``pallas_call`` has no SPMD partitioning
 rule, so ``gated_norm`` and ``head_gate_norm`` take the mesh and the
 axes the batch is sharded over and map the kernels over them. Which
-programs get the kernels is ``ops/ssm.py::norm_path``'s decision.
+programs get the kernels is ``ops/gated_norm.py::norm_path``'s decision.
 
 What one v5e chip showed at 1 x 8,192 rows of 4,096 bfloat16 columns in
 8 groups (PERF.md section 6, PR 37): timed alone in a loop the forward
@@ -85,6 +85,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+
+from ray_tpu.ops.pallas import program
 
 _F32 = jnp.float32
 # One operand's block. Both passes double-buffer every operand and
@@ -389,10 +391,9 @@ _norm_core.defvjp(_norm_core_fwd, _norm_core_bwd)
 
 def _apply(form, a, b, scale, groups, eps, interpret, mesh, batch_axes):
     """``form``'s kernels over a, b [b, T, C] and ``scale`` [1, C] or
-    [1, C / groups]: bare, or under a ``shard_map`` over ``batch_axes``
-    of ``mesh`` (as ``ssd_scan.ssd_scan``): a row's norm needs nothing
-    of another's, and ``scale``, held whole on every device, has its
-    cotangent summed over the axes by the map's transpose."""
+    [1, C / groups], bare or over ``batch_axes`` of ``mesh``
+    (``program.over_batch``): a row's norm needs nothing of another's,
+    and ``scale`` is held whole on every device."""
     _, t, c = a.shape
     if not shapes_ok(c, groups):
         raise ValueError(
@@ -401,24 +402,17 @@ def _apply(form, a, b, scale, groups, eps, interpret, mesh, batch_axes):
     itemsize = max(a.dtype.itemsize, b.dtype.itemsize)
     core = functools.partial(_norm_core, form=form, static=_Static(
         groups, float(eps), _block_rows(t, c, itemsize), interpret))
-    if batch_axes:
-        from jax.sharding import PartitionSpec
-        rows_spec, whole = PartitionSpec(tuple(batch_axes)), PartitionSpec()
-        core = jax.shard_map(
-            core, mesh=mesh, in_specs=(rows_spec, rows_spec, whole),
-            out_specs=rows_spec, check_vma=False)
+    core = program.over_batch(core, mesh, batch_axes,
+                              in_specs=(0, 0, None), out_specs=0)
     return core(a, b, scale.astype(_F32)[None])
 
 
 def gated_norm(y, z, scale, *, groups: int, eps: float,
                interpret: bool = False, mesh=None, batch_axes=()):
-    """``ops/ssm.py::gated_group_rms_norm`` on the kernels: y, z
+    """``ops/gated_norm.py::gated_group_rms_norm`` on the kernels: y, z
     [b, T, C]; scale [C]; the same result, differentiable in all three.
-    ``C`` and ``groups`` must pass ``shapes_ok``; ``T`` is any.
-
-    A program that spans the devices of ``mesh`` names in
-    ``batch_axes`` the axes its batch is sharded over, and the kernels
-    run under a ``shard_map`` over them (``_apply``)."""
+    ``C`` and ``groups`` must pass ``shapes_ok``; ``T`` is any; ``mesh``
+    and ``batch_axes`` are ``program.over_batch``'s."""
     return _apply("norm_gated", y, z, scale, groups, eps, interpret, mesh,
                   batch_axes)
 
@@ -426,7 +420,7 @@ def gated_norm(y, z, scale, *, groups: int, eps: float,
 def head_gate_norm(o, gate, scale, *, heads: int, eps: float,
                    interpret: bool = False, mesh=None, batch_axes=(),
                    gate_fn: str = "sigmoid"):
-    """``ops/ssm.py::sigmoid_gated_head_rms_norm`` on the kernels: o,
+    """``ops/gated_norm.py::sigmoid_gated_head_rms_norm`` on the kernels: o,
     gate [b, T, C]; scale [C / heads], shared by the heads; the same
     result in ``gate``'s dtype, differentiable in all three. ``C`` and
     ``heads`` must pass ``shapes_ok``; ``T`` is any; ``mesh`` and

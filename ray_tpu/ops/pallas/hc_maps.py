@@ -86,20 +86,14 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
+from ray_tpu.ops.pallas import program
+from ray_tpu.ops.remat import MAPS_KEEPS
+
 _F32 = jnp.float32
 _LANES = 128
 # Rows of 128 tokens a grid cell: one vector register an entry.
 BLOCK_ROWS = 8
 _VMEM_LIMIT = 32 << 20
-# What a recomputed block keeps of a sub-layer's maps: the kernel's
-# three results, and the product and the norm's factor its backward
-# reads.
-MAPS_PRE = "hc_maps_pre"
-MAPS_POST = "hc_maps_post"
-MAPS_RES = "hc_maps_res"
-MAPS_M = "hc_maps_m"
-MAPS_R = "hc_maps_r"
-MAPS_KEEPS = (MAPS_PRE, MAPS_POST, MAPS_RES, MAPS_M, MAPS_R)
 
 
 def shapes_ok(t: int) -> bool:
@@ -355,7 +349,7 @@ def _forward(x, phi, b, alpha, static: _Static):
     scalars = jnp.concatenate([b.astype(_F32), alpha.astype(_F32)])[None]
     pre, post, res = _hc_maps_fwd(scalars, m, r, static=static)
     # all five named before they part into primal and residuals (the
-    # trap ``ops/attention.py::name_core_results`` records)
+    # trap ``ops/remat.py::name_core_results`` records)
     pre, post, res, m, r = (checkpoint_name(v, name) for v, name in zip(
         (pre, post, res, m, r), MAPS_KEEPS))
     maps = (pre.reshape(n, b_, t), post.reshape(n, b_, t),
@@ -397,25 +391,16 @@ def hc_maps(x, phi, b, alpha, *, n: int, iters: int, eps: float,
     """``ops/hyper_connections.py::hc_maps`` on the kernels: the state
     ``x`` [B, T, n d], ``phi`` [n d, n^2 + 2n], ``b`` [n^2 + 2n],
     ``alpha`` [3]; the same three float32 maps, differentiable in all
-    four. ``T`` must pass ``shapes_ok``.
-
-    A program that spans the devices of ``mesh`` names in ``batch_axes``
-    the axes its batch is sharded over, and everything runs under a
-    ``shard_map`` over them: a token's maps need nothing of another's,
-    and the parameters, held whole on every device, have their
-    cotangents summed over the axes by the map's transpose."""
+    four. ``T`` must pass ``shapes_ok``; ``mesh`` and ``batch_axes``
+    are ``program.over_batch``'s (a token's maps need nothing of
+    another's, the parameters are whole on every device)."""
     if not shapes_ok(x.shape[1]):
         raise ValueError(
             f"the residual maps' kernels do not tile a state {x.shape}")
     core = functools.partial(_maps_core, static=_Static(
         n, iters, float(eps), float(clamp), float(norm_eps), interpret))
-    if batch_axes:
-        from jax.sharding import PartitionSpec
-        rows_spec, whole = PartitionSpec(tuple(batch_axes)), PartitionSpec()
-        tokens = PartitionSpec(None, tuple(batch_axes))
-        core = jax.shard_map(
-            core, mesh=mesh, in_specs=(rows_spec, whole, whole, whole),
-            out_specs=(tokens, tokens,
-                       PartitionSpec(None, None, tuple(batch_axes))),
-            check_vma=False)
+    # the maps come back streams first: [n, B, T] and [n, n, B, T]
+    core = program.over_batch(core, mesh, batch_axes,
+                              in_specs=(0, None, None, None),
+                              out_specs=(1, 1, 2))
     return core(x, phi, b, alpha)

@@ -11,7 +11,7 @@ grid axis, as ``ssd_scan.py`` carries Mamba's. HBM sees ``q, k, v, g,
 beta`` in and ``o`` out, and, under differentiation, the state that
 entered each chunk (float32 ``[T / 64, H, V, K]``), from which the
 backward kernel recomputes a chunk's squares, again in VMEM. The
-forward rule names its two results (``SCAN_OUT``, ``SCAN_STATES``). A
+forward rule names its two results (``ops/remat.py::KDA_SCAN_*``). A
 caller under a plain ``jax.checkpoint`` makes both again in its
 backward pass, and the states are alive only inside that one mixer's
 backward; a recomputed block whose policy keeps the two names
@@ -137,6 +137,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
+from ray_tpu.ops.remat import KDA_SCAN_OUT, KDA_SCAN_STATES
+
 CHUNK = 64          # the recurrence's chunk; the configuration's
 _ROWS = 2 * CHUNK   # rows of a grid cell: two chunks
 _WIDTH = 128        # keys and values: one lane tile, and == _ROWS
@@ -145,13 +147,6 @@ _PACKED = 8         # levels from here up give the MXU only their lower rows
 _F32 = jnp.float32
 NORM_EPS = 1e-6                 # under the root of a row's unit length
 _Q_SCALE = _WIDTH ** -0.5       # the unit queries' scale: head_dim^-1/2
-# The names of the forward kernel's two results under differentiation,
-# ``o`` and the state entering each chunk: what the rest of the backward
-# pass reads of it. A recomputed block whose policy keeps both does not
-# run the kernel again (``models/kimi_linear.py``); outside a policy a
-# name is the identity.
-SCAN_OUT = "kda_scan_out"
-SCAN_STATES = "kda_scan_states"
 
 
 def shapes_ok(kd: int, vd: int, chunk: int) -> bool:
@@ -715,10 +710,10 @@ def _kda_core_fwd(q, k, g, v, beta, normalize, interpret):
     o, entering = _kda_fwd(q, k, g, v, beta, keep_states=True,
                            normalize=normalize, interpret=interpret)
     # both named before they part into primal and residuals (the trap
-    # ``ops/attention.py::name_core_results`` records): a name on ``o``
+    # ``ops/remat.py::name_core_results`` records): a name on ``o``
     # alone would leave ``entering`` to be made again, the kernel with it
-    o, entering = checkpoint_name(o, SCAN_OUT), checkpoint_name(
-        entering, SCAN_STATES)
+    o, entering = checkpoint_name(o, KDA_SCAN_OUT), checkpoint_name(
+        entering, KDA_SCAN_STATES)
     return o, (q, k, g, v, beta, entering)
 
 
@@ -840,8 +835,8 @@ def _gdn_core_fwd(q, k, g, v, beta, normalize, interpret):
     o, entering = _gdn_fwd(q, k, g, v, beta, keep_states=True,
                            normalize=normalize, interpret=interpret)
     # named as ``_kda_core_fwd`` names them, and for its reason
-    o, entering = checkpoint_name(o, SCAN_OUT), checkpoint_name(
-        entering, SCAN_STATES)
+    o, entering = checkpoint_name(o, KDA_SCAN_OUT), checkpoint_name(
+        entering, KDA_SCAN_STATES)
     return o, (q, k, g, v, beta, entering)
 
 

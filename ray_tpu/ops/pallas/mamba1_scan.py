@@ -1,7 +1,7 @@
 """The Mamba-1 selective scan (arXiv:2312.00752 section 3) as two Pallas
 TPU kernels, forward and backward, under one ``custom_vjp``.
 
-The recurrence and the precisions are ``ops/ssm.py::mamba1_scan``'s (its
+The recurrence and the precisions are ``ops/mamba1.py::mamba1_scan``'s (its
 docstring states them): what differs is where the arrays live. The
 ``[N, C]`` state stands in VMEM scratch from the first row block of a
 sequence to the last; a block reads its rows of ``x, dt, B, C`` once and
@@ -75,7 +75,7 @@ it to Mosaic once a trace of the step (PERF.md section 6, PR 28). Every
 loop is a ``fori_loop``; two rows are written out a turn (``_down_rows``).
 
 Devices: a ``pallas_call`` has no SPMD partitioning rule, so the kernels
-are one device's; ``ops/ssm.py::mamba1_path`` gives them one-device
+are one device's; ``ops/mamba1.py::mamba1_path`` gives them one-device
 programs alone.
 
 What one v5e chip showed is in PERF.md section 6 (PR 49).
@@ -431,7 +431,7 @@ _mamba1_core.defvjp(_mamba1_core_fwd, _mamba1_core_bwd)
 
 def mamba1_scan(x, dt, A, B, C, D, *, rows: int = ROWS, unroll: int = 2,
                 interpret: bool = False):
-    """``ops/ssm.py::mamba1_scan`` on the kernels: the same arguments (x
+    """``ops/mamba1.py::mamba1_scan`` on the kernels: the same arguments (x
     [b, T, C] any dtype; dt [b, T, C]; A [C, N]; B, C [b, T, N]; D [C]),
     the same result ``y`` [b, T, C] float32, differentiable in all six,
     each cotangent in its argument's dtype. The shapes must pass

@@ -74,18 +74,16 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
+# the names of what the choice makes, for a recomputed block's policy
+from ray_tpu.ops.remat import (
+    ROUTER_COUNTS, ROUTER_EXPERTS, ROUTER_LSE, ROUTER_WEIGHTS)
+
 _F32 = jnp.float32
 _LANES = 128
 # float32 entries of the product a grid step holds: 512 KB a block, a
 # few MB of VMEM with the rounds' temporaries.
 _BLOCK = 128 * 1024
 _VMEM_LIMIT = 64 << 20
-
-# The names of what the choice makes, for a recomputed block's policy.
-ROUTER_EXPERTS = "moe_router_experts"
-ROUTER_WEIGHTS = "moe_router_weights"
-ROUTER_COUNTS = "moe_router_counts"
-ROUTER_LSE = "moe_router_lse"
 
 
 def shapes_ok(tokens: int, experts: int, top_k: int) -> bool:

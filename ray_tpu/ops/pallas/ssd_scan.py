@@ -9,7 +9,7 @@ chunk to chunk in VMEM scratch; the only thing kept for the backward
 beside the inputs is the state *entering* each chunk (float32, what
 ``_ssd`` keeps under the name ``ssm_boundary_states``), from which the
 backward kernel recomputes a chunk's squares, again in VMEM. The
-forward rule names its two results (``SCAN_OUT``, ``SCAN_STATES``): a
+forward rule names its two results (``ops/remat.py::SSD_SCAN_*``): a
 block recomputed under ``nn.remat`` whose policy lists them
 (``models/granite.py``) finds ``y`` and the entering states kept and
 does not run the forward kernel a second time; outside such a policy
@@ -82,6 +82,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+
+from ray_tpu.ops.pallas import program
+from ray_tpu.ops.remat import SSD_SCAN_OUT, SSD_SCAN_STATES
 
 # Heads in a block: one float32 sublane tile of ``dt``'s [H, T] rows.
 _HEADS = 8
@@ -413,12 +416,6 @@ def _ssd_bwd(x, dt, rate, skip, bm, cm, entering, dy, *, p, n, chunk,
 # public API with custom VJP
 # ---------------------------------------------------------------------------
 
-# The names of the forward kernel's two results, for the policy of a
-# recomputed block (``ops/ssm.py`` exports them).
-SCAN_OUT = "ssd_scan_out"
-SCAN_STATES = "ssd_scan_states"
-
-
 class _Static(NamedTuple):
     """What the kernels are specialised on, besides their shapes."""
     p: int          # head width
@@ -435,9 +432,9 @@ def _ssd_core(x, dt, rate, skip, bm, cm, static: _Static):
 def _ssd_core_fwd(x, dt, rate, skip, bm, cm, static):
     y, entering = _ssd_fwd(x, dt, rate, skip, bm, cm, **static._asdict())
     # both named before they part into primal and residuals (the trap
-    # ``ops/attention.py::name_core_results`` records)
-    y, entering = checkpoint_name(y, SCAN_OUT), checkpoint_name(
-        entering, SCAN_STATES)
+    # ``ops/remat.py::name_core_results`` records)
+    y, entering = checkpoint_name(y, SSD_SCAN_OUT), checkpoint_name(
+        entering, SSD_SCAN_STATES)
     return y, (x, dt, rate, skip, bm, cm, entering)
 
 
@@ -459,14 +456,9 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 128,
     same result, differentiable in all six. The shapes must pass
     ``shapes_ok``; ``T`` need not be a multiple of ``chunk`` (the tail
     is padded with steps that neither decay nor write the state).
-
-    A ``pallas_call`` has no SPMD partitioning rule. A program that
-    spans the devices of ``mesh`` names in ``batch_axes`` the axes its
-    batch is sharded over, and the kernels run under a ``shard_map``
-    over them: a sequence's scan needs nothing of another's, so each
-    device runs its own rows of the batch, and ``A`` and ``D``, held
-    whole on every device, have their cotangents summed over the axes
-    by the map's transpose."""
+    ``mesh`` and ``batch_axes`` are ``program.over_batch``'s: a
+    sequence's scan needs nothing of another's, and ``A`` and ``D`` are
+    held whole on every device."""
     b_, t, h, p = x.shape
     g, n = B.shape[2:]
     if not shapes_ok(h, p, g, n, chunk):
@@ -479,18 +471,11 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 128,
         z = z.reshape(b_, t, -1)
         return jnp.pad(z, ((0, 0), (0, pad), (0, 0))) if pad else z
 
-    core = functools.partial(_ssd_core,
-                             static=_Static(p, n, chunk, interpret))
-    if batch_axes:
-        # The shards cross the boundary as the kernels index them,
-        # heads merged (``ops/attention.py`` says why).
-        from jax.sharding import PartitionSpec
-        rows_spec, whole = PartitionSpec(tuple(batch_axes)), PartitionSpec()
-        core = jax.shard_map(
-            core, mesh=mesh,
-            in_specs=(rows_spec, rows_spec, whole, whole, rows_spec,
-                      rows_spec),
-            out_specs=rows_spec, check_vma=False)
+    # The shards cross the boundary as the kernels index them, heads
+    # merged (``ops/attention.py`` says why).
+    core = program.over_batch(
+        functools.partial(_ssd_core, static=_Static(p, n, chunk, interpret)),
+        mesh, batch_axes, in_specs=(0, 0, None, None, 0, 0), out_specs=0)
     y = core(
         rows(x), jnp.swapaxes(rows(dt.astype(_F32)), 1, 2),
         A.astype(_F32)[:, None], jnp.repeat(D.astype(_F32), p)[None],

@@ -2,7 +2,7 @@
 (Kimi-Linear's 1 x 16,384 x 4,096 without a bias, Nemotron's 1 x 8,192
 x 6,144 and Phi-4-mini-flash's 1 x 4,096 x 5,120 with one; bfloat16
 rows, 4 float32 taps), forward and forward + backward, timed on the
-device this runs on: ``ops/ssm.py``'s XLA function (``xla``) against the
+device this runs on: ``ops/conv1d.py``'s XLA function (``xla``) against the
 kernel pair of ``ops/pallas/causal_conv.py`` at its own blocks
 (``pallas``) or at given ones (``pallas:<rows>:<strip>:<width>[:<lanes>]``,
 0 for the kernel's own); each variant's largest distance from the first
@@ -51,7 +51,7 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.ops import ssm
+    from ray_tpu.ops import conv1d
     from ray_tpu.ops.pallas import causal_conv
 
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
@@ -71,7 +71,7 @@ def main() -> None:
             for variant in [v for v in args.variants.split(",") if v]:
                 kind, *numbers = variant.split(":")
                 if kind == "xla":
-                    conv = ssm._causal_conv1d_silu_xla
+                    conv = conv1d._causal_conv1d_silu_xla
                 else:
                     blocks = {k: int(n) for k, n in zip(
                         ("rows", "strip", "width", "lanes"), numbers)

@@ -2,7 +2,7 @@
 16,384 rows x 32 heads of 128; ``o`` float32 as the recurrence's kernel
 writes it, or ``--o-dtype bfloat16``; a bfloat16 ``gate``, a float32
 ``scale`` of 128), forward and forward + backward, timed on the device
-this runs on: ``ops/ssm.py``'s XLA function (``xla``) against the second
+this runs on: ``ops/gated_norm.py``'s XLA function (``xla``) against the second
 kernel pair of ``ops/pallas/gated_norm.py`` (``pallas``); with
 ``scope:`` in front the whole of a mixer's ``out_gate`` scope behind
 ``g_a``: ``gate = g_low @ g_b + g_bias`` from a ``[rows, 128]`` ``g_low``
@@ -48,7 +48,7 @@ def main() -> None:
     import numpy as np
 
     from conv_timing import _timed
-    from ray_tpu.ops import ssm
+    from ray_tpu.ops import gated_norm as norms
     from ray_tpu.ops.pallas import gated_norm
 
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
@@ -67,8 +67,8 @@ def main() -> None:
     def norm_of(kind):
         if kind == "xla":
             return lambda o, gate, scale: (
-                ssm._sigmoid_gated_head_rms_norm_xla(o, gate, scale, heads,
-                                                     eps))
+                norms._sigmoid_gated_head_rms_norm_xla(o, gate, scale, heads,
+                                                       eps))
         return lambda o, gate, scale: gated_norm.head_gate_norm(
             o, gate, scale, heads=heads, eps=eps, interpret=args.interpret)
 

@@ -1,6 +1,6 @@
 """One Mamba-1 layer's selective scan at the Phi-4-mini-flash cell's shape
 (1 x 4,096 rows of 5,120 channels, 16 states), forward and forward +
-backward, timed on the device this runs on: ``ops/ssm.py``'s XLA path at
+backward, timed on the device this runs on: ``ops/mamba1.py``'s XLA path at
 its chunk (``xla:<chunk>``) against the kernel pair of
 ``ops/pallas/mamba1_scan.py`` at a row block and an unroll
 (``pallas:<rows>:<unroll>``); each variant's largest distance from the
@@ -42,7 +42,7 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.ops import ssm
+    from ray_tpu.ops import mamba1
     from ray_tpu.ops.pallas import mamba1_scan as kernels
 
     t, c, n = args.rows, args.channels, args.states
@@ -63,7 +63,8 @@ def main() -> None:
         kind, *numbers = name.split(":")
         numbers = [int(z) for z in numbers]
         if kind == "xla":
-            scan = functools.partial(ssm._mamba1_xla_chunked, chunk=numbers[0])
+            scan = functools.partial(mamba1._mamba1_xla_chunked,
+                                     chunk=numbers[0])
         else:
             scan = functools.partial(kernels.mamba1_scan, rows=numbers[0],
                                      unroll=numbers[1],

@@ -386,8 +386,8 @@ def test_cca_path_names_what_runs_the_passes(
         "dp_and_sp", "dp_and_tp"])
 def test_cca_path_reads_the_devices_the_program_spans(
         monkeypatch, axes, batch, path):
-    """As the scan's and the gated norm's kernels (``ops/ssm.py::
-    _kernel_batch_axes``): one device bare, a mesh that shards the
+    """As the scan's and the gated norm's kernels (``ops/pallas/
+    program.py::batch_axes``): one device bare, a mesh that shards the
     batch alone under a ``shard_map``; a sequence split over chips
     needs a halo across them, which is not there."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

@@ -11,13 +11,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.ops.attention import (
-    ATTN_LSE, ATTN_OUT, causal_attention, remat_keeps, remat_policy,
-)
+from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.pallas.flash_attention import (
     flash_attention,
     flash_attention_shapes_ok,
 )
+from ray_tpu.ops.remat import ATTN_LSE, ATTN_OUT, remat_keeps, remat_policy
 from ray_tpu.util import tracing
 
 # the package exports the function under the module's name

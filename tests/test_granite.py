@@ -204,7 +204,7 @@ def test_there_is_one_mamba2_mixer_and_both_configs_run_it():
 
 def test_the_mixers_names_are_the_identity_for_nemotron(monkeypatch):
     """``Mamba2Mixer`` names the three parts of ``in_proj``'s product
-    for a recomputed block's policy (``ops/ssm.py::IN_PROJ_PARTS``).
+    for a recomputed block's policy (``ops/remat.py::IN_PROJ_PARTS``).
     Nemotron's blocks are under no policy: with the names in place its
     loss and every gradient leaf on one seed are bit for bit what they
     are with the names taken out, its jaxpr differs by those ``name``
@@ -217,7 +217,7 @@ def test_the_mixers_names_are_the_identity_for_nemotron(monkeypatch):
     from conftest import equations
     from ray_tpu.models import NemotronH, NemotronHConfig
     from ray_tpu.models.nemotron_h import nemotron_h_loss_fn
-    from ray_tpu.ops import ssm
+    from ray_tpu.ops.remat import IN_PROJ_PARTS
     cfg = NemotronHConfig.tiny(**F32)
     params = _jittered(NemotronH(cfg).init_params(jax.random.key(3)), 3,
                        by=0.02)
@@ -233,15 +233,15 @@ def test_the_mixers_names_are_the_identity_for_nemotron(monkeypatch):
     eqns, text, numbers = program()
     named = [e.params["name"] for e in eqns if e.primitive.name == "name"]
     layers = cfg.pattern.count("M")
-    assert layers and sorted(set(named) & set(ssm.IN_PROJ_PARTS)) == sorted(
-        ssm.IN_PROJ_PARTS)
+    assert layers and sorted(set(named) & set(IN_PROJ_PARTS)) == sorted(
+        IN_PROJ_PARTS)
     monkeypatch.setattr(nemotron_h, "checkpoint_name", lambda x, _: x)
     bare_eqns, bare_text, bare_numbers = program()
     assert not {e.params["name"] for e in bare_eqns
-                if e.primitive.name == "name"} & set(ssm.IN_PROJ_PARTS)
+                if e.primitive.name == "name"} & set(IN_PROJ_PARTS)
     assert [e.primitive.name for e in eqns if not (
         e.primitive.name == "name"
-        and e.params["name"] in ssm.IN_PROJ_PARTS)] == [
+        and e.params["name"] in IN_PROJ_PARTS)] == [
             e.primitive.name for e in bare_eqns]
     policies = [e.params["policy"] for e in eqns
                 if e.primitive.name == "remat2" and e.params["policy"]]

@@ -19,7 +19,9 @@ from ray_tpu import train
 from ray_tpu.models import Granite, GraniteHybridConfig
 from ray_tpu.models import granite as model_file
 from ray_tpu.models.granite import granite_loss_fn
-from ray_tpu.ops import ssm
+from ray_tpu.ops import remat, ssm
+from ray_tpu.ops.pallas import program
+from ray_tpu.ops.remat import IN_PROJ_PARTS
 from ray_tpu.util import tracing
 
 # ``blocks_remat_keeps`` with every name kept by every layer
@@ -54,26 +56,31 @@ def test_the_step_reports_the_keys_and_notes_what_the_layers_are(
     assert notes["blocks_remat_keeps"] == KEEPS_NOTE
 
 
-@pytest.mark.parametrize("first_of, note, names", [
-    ("_first_keeping_gate_up", KEEPS_NOTE.replace(
-        "mlp_gate_up,", "mlp_gate_up[2:],"), ("mlp_gate_up",)),
-    ("_first_keeping_in_proj", KEEPS_NOTE.replace(
-        "mamba_z,mamba_xbc,mamba_dt",
-        "mamba_z[2:],mamba_xbc[2:],mamba_dt[2:]"), ssm.IN_PROJ_PARTS)],
+def _kept_from(first: int, *names):
+    """``granite._BLOCK_KEEPS`` with ``names`` kept from layer ``first``
+    on alone (``ops/remat.py::layer_keeps``'s mapping)."""
+    return {n: first if n in names else 0 for n in model_file._BLOCK_KEEPS}
+
+
+@pytest.mark.parametrize("note, names", [
+    (KEEPS_NOTE.replace("mlp_gate_up,", "mlp_gate_up[2:],"),
+     ("mlp_gate_up",)),
+    (KEEPS_NOTE.replace("mamba_z,mamba_xbc,mamba_dt",
+                        "mamba_z[2:],mamba_xbc[2:],mamba_dt[2:]"),
+     IN_PROJ_PARTS)],
     ids=["gate_up", "in_proj"])
-def test_the_keeps_note_says_from_which_layer_a_name_is_kept(
-        monkeypatch, first_of, note, names):
+def test_the_keeps_note_says_from_which_layer_a_name_is_kept(note, names):
     """A name that memory gives to the layers from 2 on alone reads
     ``name[2:]`` in the note and is listed by those layers' policies;
     the stream after the mixer and the scan's two are every layer's."""
-    cfg = GraniteHybridConfig.tiny(remat=True)
-    assert model_file._keeps_note(cfg) == KEEPS_NOTE
-    every = set(model_file._block_keeps(cfg, 0))
+    keeps = model_file._BLOCK_KEEPS
+    assert remat.keeps_note(True, keeps) == KEEPS_NOTE
+    every = set(remat.layer_keeps(keeps, 0))
     assert every == set(KEEPS_NOTE.split(",")) - {"attn_out", "attn_lse"}
-    monkeypatch.setattr(model_file, first_of, lambda cfg: 2)
-    assert model_file._keeps_note(cfg) == note
-    assert set(model_file._block_keeps(cfg, 1)) == every - set(names)
-    assert set(model_file._block_keeps(cfg, 2)) == every
+    keeps = _kept_from(2, *names)
+    assert remat.keeps_note(True, keeps) == note
+    assert set(remat.layer_keeps(keeps, 1)) == every - set(names)
+    assert set(remat.layer_keeps(keeps, 2)) == every
 
 
 @pytest.mark.parametrize("remat", [True, False],
@@ -142,7 +149,8 @@ def test_a_recomputed_block_runs_its_gate_up_matmul_once(monkeypatch):
 
     n = GraniteHybridConfig.tiny().n_layer
     assert (forwards(False), forwards(True)) == (n, n)
-    monkeypatch.setattr(model_file, "_first_keeping_gate_up", lambda cfg: n)
+    monkeypatch.setattr(model_file, "_BLOCK_KEEPS",
+                        _kept_from(n, "mlp_gate_up"))
     assert forwards(True) == 2 * n
 
 
@@ -150,8 +158,8 @@ def _off_the_policy(monkeypatch, what):
     """Take ``in_proj``'s names, or the stream's, off every layer's
     policy."""
     if what == "in_proj":
-        monkeypatch.setattr(model_file, "_first_keeping_in_proj",
-                            lambda cfg: cfg.n_layer)
+        monkeypatch.setattr(model_file, "_BLOCK_KEEPS", _kept_from(
+            GraniteHybridConfig.tiny().n_layer, *IN_PROJ_PARTS))
     else:       # un-named: the policy's name is on no value
         monkeypatch.setattr(model_file, "checkpoint_name", lambda x, _: x)
 
@@ -211,7 +219,7 @@ def on_the_kernels(monkeypatch):
     from ray_tpu.ops.pallas import ssd_scan as kernels
     monkeypatch.setattr(ssm, "scan_path",
                         lambda *a, **kw: "pallas_chunked")
-    monkeypatch.setattr(ssm, "_kernel_batch_axes", lambda *a: ())
+    monkeypatch.setattr(program, "batch_axes", lambda *a: ())
     monkeypatch.setattr(kernels, "ssd_scan", functools.partial(
         kernels.ssd_scan, interpret=True))
     return (_at_the_kernels_widths, *_kernel_case())
@@ -228,8 +236,10 @@ def test_the_scans_forward_kernel_runs_once_a_layer_under_remat(
     and its backward once (6); a policy that loses one of the two names
     runs the forward twice. Kept whole, the ``custom_vjp`` holds its own
     residuals: once."""
-    if keeps:
-        monkeypatch.setattr(model_file, "_SCAN_KEEPS", keeps)
+    if keeps:       # the scan's states off the policy
+        monkeypatch.setattr(model_file, "_BLOCK_KEEPS", tuple(
+            n for n in model_file._BLOCK_KEEPS
+            if not n.startswith("ssd_scan") or n in keeps))
     made, params, batch = on_the_kernels
     model, loss_fn = made(remat)
     traced = jax.make_jaxpr(jax.value_and_grad(loss_fn, has_aux=True))(
@@ -283,7 +293,7 @@ def test_the_flash_forward_is_traced_once_under_the_policy(monkeypatch):
     whole = calls(False)
     assert len(whole) >= 2 and calls(True) == whole
     assert set(scales) == {1 / 64}
-    monkeypatch.setattr(model_file, "remat_policy",
+    monkeypatch.setattr(remat, "remat_policy",
                         lambda *names: jax.checkpoint_policies
                         .save_only_these_names(*names))
     assert len(calls(True)) == len(whole) + 1

@@ -14,8 +14,8 @@ import pytest
 from conftest import equations
 
 from ray_tpu.ops import hyper_connections as hc
-from ray_tpu.ops.moe import ROUTER_KEEPS
 from ray_tpu.ops.pallas import hc_maps as kernels
+from ray_tpu.ops.remat import MAPS_KEEPS, ROUTER_KEEPS
 from ray_tpu.parallel import make_mesh
 
 D = 32
@@ -159,12 +159,14 @@ def on_the_kernels(monkeypatch):
 
 
 def _block_and_operands(hc_mult, remat):
-    from ray_tpu.models.joyai import JoyAIConfig, _block
+    from ray_tpu.models.joyai import Block, JoyAIConfig, _keeps
     from ray_tpu.models.llama import rope_freqs
+    from ray_tpu.ops import remat as keeping
     cfg = JoyAIConfig.tiny_xing(
         hc_mult=hc_mult, seq_len=128, hc_sinkhorn_iters=3, remat=remat,
         dtype=jnp.float32)
-    block = _block(cfg)(cfg, False, name="h")
+    block = keeping.block(Block, cfg.remat, _keeps(cfg))(
+        cfg, False, name="h")
     x = jax.random.normal(jax.random.key(0),
                           (1, cfg.seq_len, hc_mult * cfg.n_embd))
     angles = rope_freqs(cfg.rope_dim, cfg.seq_len, cfg.rope_theta)
@@ -204,7 +206,7 @@ def test_a_policy_without_the_maps_names_runs_the_forward_kernel_twice(
     """What the names are for: the parent's policy (the attention
     core's two names alone) makes every map again in the second pass."""
     from ray_tpu.models import joyai
-    monkeypatch.setattr(joyai, "_block_keeps", lambda cfg: ())
+    monkeypatch.setattr(joyai, "_keeps", lambda cfg: ())
     _, loss, params, x = _block_and_operands(4, True)
     traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x)
     assert _kernels_run(traced) == ["_bwd_kernel"] * 2 + ["_fwd_kernel"] * 4
@@ -227,7 +229,7 @@ def test_recomputed_blocks_on_the_kernels_give_the_xla_paths_numbers(
 
 
 @pytest.mark.parametrize("hc_mult, keeps", [
-    (4, (*ROUTER_KEEPS, *kernels.MAPS_KEEPS, "attn_out", "attn_lse")),
+    (4, (*ROUTER_KEEPS, *MAPS_KEEPS, "attn_out", "attn_lse")),
     (1, (*ROUTER_KEEPS, "attn_out", "attn_lse"))],
     ids=["four_streams", "one_stream"])
 def test_what_a_recomputed_block_keeps_by_name(monkeypatch, hc_mult, keeps):
@@ -236,12 +238,13 @@ def test_what_a_recomputed_block_keeps_by_name(monkeypatch, hc_mult, keeps):
     router's product nor the choice again), then the maps' where there
     are streams, then the cores' two."""
     from ray_tpu.models import joyai
-    from ray_tpu.ops import attention
-    asked = []
+    from ray_tpu.ops import remat
+    asked, policy = [], remat.remat_policy
     monkeypatch.setattr(
-        joyai, "remat_policy",
-        lambda *more: asked.append(more) or attention.remat_policy(*more))
+        remat, "remat_policy",
+        lambda *more: asked.append(more) or policy(*more))
     cfg = joyai.JoyAIConfig.tiny_xing(hc_mult=hc_mult, remat=True)
-    joyai._block(cfg)
+    remat.block(joyai.Block, cfg.remat, joyai._keeps(cfg))
     assert asked == [keeps[:-2]]
-    assert attention.remat_keeps(*joyai._block_keeps(cfg)) == keeps
+    assert remat.remat_keeps(*joyai._keeps(cfg)) == keeps
+    assert remat.keeps_note(True, joyai._keeps(cfg)) == ",".join(keeps)

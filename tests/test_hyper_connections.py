@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops import hyper_connections as hc
+from ray_tpu.ops.pallas import program
 from ray_tpu.parallel import make_mesh
 
 B, T, D = 2, 5, 8
@@ -207,8 +208,10 @@ def test_the_state_stays_in_its_type_and_the_maps_in_float32():
     ("sp", "sequence split over chips"), ("tp", "lanes split over chips")])
 def test_a_split_state_is_refused_by_name(axis, says):
     mesh = make_mesh({axis: 2}, devices=jax.devices()[:2])
+    def refuse(mesh):   # as ``models/joyai.py`` does before a layer is built
+        program.refuse(mesh, "hyper-connections", **hc.SPLIT_STATE)
     with pytest.raises(NotImplementedError, match=f"{axis}=2") as err:
-        hc.refuse_split_state(mesh)
+        refuse(mesh)
     assert says in str(err.value)
-    hc.refuse_split_state(None)
-    hc.refuse_split_state(make_mesh({"dp": 2}, devices=jax.devices()[:2]))
+    refuse(None)
+    refuse(make_mesh({"dp": 2}, devices=jax.devices()[:2]))

@@ -11,6 +11,7 @@ import pytest
 from conftest import equations, live_kernel_calls
 
 from ray_tpu.ops import kda
+from ray_tpu.ops.remat import KDA_SCAN_OUT, KDA_SCAN_STATES
 
 
 def recurrence(q, k, v, g, beta):
@@ -407,8 +408,8 @@ def _recomputed(kept):
 
 
 @pytest.mark.parametrize("kept, forwards", [
-    (None, 1), ((), 2), ((kda.SCAN_OUT,), 2), ((kda.SCAN_STATES,), 2),
-    ((kda.SCAN_OUT, kda.SCAN_STATES), 1)],
+    (None, 1), ((), 2), ((KDA_SCAN_OUT,), 2), ((KDA_SCAN_STATES,), 2),
+    ((KDA_SCAN_OUT, KDA_SCAN_STATES), 1)],
     ids=["not_recomputed", "keeps_nothing", "keeps_o_alone",
          "keeps_the_states_alone", "keeps_both"])
 def test_a_caller_that_keeps_both_named_results_runs_the_forward_once(
@@ -419,7 +420,7 @@ def test_a_caller_that_keeps_both_named_results_runs_the_forward_once(
     policy keeps both does not run the forward kernel in its backward
     pass, one that keeps either alone still does (the other result has
     to be made again, and the kernel with it)."""
-    assert (kda.SCAN_OUT, kda.SCAN_STATES) == (
+    assert (KDA_SCAN_OUT, KDA_SCAN_STATES) == (
         "kda_scan_out", "kda_scan_states")
     traced = jax.make_jaxpr(jax.value_and_grad(
         _recomputed(kept), argnums=(0, 1, 2, 3, 4)))(
@@ -435,7 +436,7 @@ def test_the_kept_results_give_the_gradients_of_the_recomputed_ones():
     args = operands(3, 1, 150, 2, 128, 128)
     want, got = (jax.jit(jax.value_and_grad(
         _recomputed(kept), argnums=(0, 1, 2, 3, 4)))(*args)
-        for kept in ((), (kda.SCAN_OUT, kda.SCAN_STATES)))
+        for kept in ((), (KDA_SCAN_OUT, KDA_SCAN_STATES)))
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_array_equal(a, b)

@@ -440,21 +440,21 @@ def test_the_step_reports_the_kda_keys_and_notes_what_the_layers_are(
 
 @pytest.mark.parametrize("axes", [None, {"dp": 2}], ids=["no_mesh", "dp"])
 def test_a_mixer_hands_its_mesh_to_the_one_output_gate(monkeypatch, axes):
-    """``ops/ssm.py::sigmoid_gated_head_rms_norm`` is the one entry and
+    """``ops/gated_norm.py::sigmoid_gated_head_rms_norm`` is the one entry and
     decides from the mesh it is given; here, on the CPU, it is the XLA
     function, which the comparison with the reference above holds to
     the formula in the loss and every gradient leaf."""
     from ray_tpu.models.kimi_linear import KDAMixer
-    from ray_tpu.ops import ssm
+    from ray_tpu.ops import gated_norm
     from ray_tpu.parallel import make_mesh
     mesh = axes and make_mesh(axes, devices=jax.devices()[:2])
-    seen, gate = [], ssm.sigmoid_gated_head_rms_norm
+    seen, gate = [], gated_norm.sigmoid_gated_head_rms_norm
     monkeypatch.setattr(
-        ssm, "sigmoid_gated_head_rms_norm",
+        gated_norm, "sigmoid_gated_head_rms_norm",
         lambda *a, **kw: seen.append(kw) or gate(*a, **kw))
     xla = []
     monkeypatch.setattr(
-        ssm, "_sigmoid_gated_head_rms_norm_xla",
+        gated_norm, "_sigmoid_gated_head_rms_norm_xla",
         lambda o, gate, *a: xla.append(o.shape) or gate)
     cfg = KimiLinearConfig.tiny(**F32)
     mixer = KDAMixer(cfg, mesh)
@@ -502,7 +502,9 @@ def test_a_recomputed_block_routes_once(monkeypatch, listed):
     the policy, twice."""
     from ray_tpu.models import kimi_linear
     if not listed:
-        monkeypatch.setattr(kimi_linear, "ROUTER_KEEPS", ())
+        monkeypatch.setattr(kimi_linear, "_BLOCK_KEEPS", tuple(
+            n for n in kimi_linear._BLOCK_KEEPS
+            if not n.startswith("moe_router")))
 
     def routes(remat):
         cfg = KimiLinearConfig.tiny(remat=remat, **F32)
@@ -583,7 +585,9 @@ def test_the_recurrences_forward_kernel_runs_once_a_layer_under_remat(
     six projections and nothing of the recurrence."""
     from ray_tpu.models import kimi_linear
     if keeps:
-        monkeypatch.setattr(kimi_linear, "_KDA_KEEPS", keeps)
+        monkeypatch.setattr(kimi_linear, "_BLOCK_KEEPS", tuple(
+            n for n in kimi_linear._BLOCK_KEEPS
+            if not n.startswith("kda_") or n in keeps))
     made, params, batch = on_the_kernels
     model, loss_fn = made(remat)
     traced = jax.make_jaxpr(jax.value_and_grad(loss_fn, has_aux=True))(

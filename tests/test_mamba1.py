@@ -1,4 +1,4 @@
-"""``ops/ssm.py::mamba1_scan``: the chunked Mamba-1 selective scan against
+"""``ops/mamba1.py::mamba1_scan``: the chunked Mamba-1 selective scan against
 the recurrence itself, token by token, values and every gradient; rows
 that the chunk does and does not divide; the state across chunk
 boundaries; the skip; the meshes it refuses. Then the kernel pair of
@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops import ssm
+from ray_tpu.ops import mamba1
 from ray_tpu.ops.pallas import mamba1_scan as kernels
 from ray_tpu.util import tracing
 
@@ -52,7 +52,7 @@ def test_values_and_every_gradient_are_the_recurrences(t, chunk):
     def total(f):
         return lambda a: jnp.sum(f(**a) * weight)
 
-    chunked = lambda **a: ssm.mamba1_scan(**a, chunk=chunk)  # noqa: E731
+    chunked = lambda **a: mamba1.mamba1_scan(**a, chunk=chunk)  # noqa: E731
     got, want = chunked(**args), _per_token(**args)
     assert got.dtype == jnp.float32
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
@@ -71,8 +71,8 @@ def test_the_state_crosses_chunk_boundaries():
     # slow decays, so that the first row's write is still there at row 31
     args["A"] = args["A"] * 0.01
     moved = {**args, "x": args["x"].at[:, 0].add(1.0)}
-    delta = (ssm.mamba1_scan(**moved, chunk=8)
-             - ssm.mamba1_scan(**args, chunk=8))
+    delta = (mamba1.mamba1_scan(**moved, chunk=8)
+             - mamba1.mamba1_scan(**args, chunk=8))
     assert float(jnp.abs(delta[:, 31]).max()) > 1e-3
     np.testing.assert_allclose(
         delta, _per_token(**moved) - _per_token(**args), atol=2e-5)
@@ -80,18 +80,18 @@ def test_the_state_crosses_chunk_boundaries():
 
 def test_the_chunk_changes_nothing():
     args = _inputs(4, 40)
-    want = ssm.mamba1_scan(**args, chunk=40)
+    want = mamba1.mamba1_scan(**args, chunk=40)
     for chunk in (4, 8, 16, 64):
-        np.testing.assert_allclose(ssm.mamba1_scan(**args, chunk=chunk), want,
+        np.testing.assert_allclose(mamba1.mamba1_scan(**args, chunk=chunk), want,
                                    rtol=2e-5, atol=2e-5)
 
 
 def test_the_skip_is_d_times_x():
     args = _inputs(5, 16)
-    without = ssm.mamba1_scan(**{**args, "D": jnp.zeros_like(args["D"])},
+    without = mamba1.mamba1_scan(**{**args, "D": jnp.zeros_like(args["D"])},
                               chunk=8)
     np.testing.assert_allclose(
-        ssm.mamba1_scan(**args, chunk=8) - without, args["D"] * args["x"],
+        mamba1.mamba1_scan(**args, chunk=8) - without, args["D"] * args["x"],
         atol=1e-5)
 
 
@@ -100,7 +100,7 @@ def test_bfloat16_rows_are_cast_up_at_the_door():
     low = {k: (v.astype(jnp.bfloat16) if k in "xBC" else v)
            for k, v in args.items()}
     up = {k: v.astype(jnp.float32) for k, v in low.items()}
-    got = ssm.mamba1_scan(**low, chunk=8)
+    got = mamba1.mamba1_scan(**low, chunk=8)
     assert got.dtype == jnp.float32
     np.testing.assert_allclose(got, _per_token(**up), rtol=2e-5, atol=2e-5)
 
@@ -110,17 +110,17 @@ def test_the_path_is_named_and_sp_and_tp_are_refused(monkeypatch):
     said = {}
     monkeypatch.setattr(tracing, "note_trace", said.update)
     args = _inputs(7, 16)
-    ssm.mamba1_scan(**args, chunk=8)
+    mamba1.mamba1_scan(**args, chunk=8)
     assert said == {"ssm_path": "xla_chunked", "ssm_chunk": 8}
     devices = jax.devices()[:2]
-    assert ssm.mamba1_path((2, 16, 24), 4, 8, make_mesh(
+    assert mamba1.mamba1_path((2, 16, 24), 4, 8, make_mesh(
         {"dp": 2}, devices=devices)) == "xla_chunked"
     for axis in ("sp", "tp"):
         with pytest.raises(NotImplementedError, match=f"{axis}=2"):
-            ssm.mamba1_scan(**args, chunk=8,
+            mamba1.mamba1_scan(**args, chunk=8,
                             mesh=make_mesh({axis: 2}, devices=devices))
     with pytest.raises(ValueError, match="chunk"):
-        ssm.mamba1_path((2, 16, 24), 4, 0)
+        mamba1.mamba1_path((2, 16, 24), 4, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -140,7 +140,7 @@ def _on_kernels(**a):
 
 
 def _on_xla(**a):
-    return ssm._mamba1_xla_chunked(**a, chunk=4)
+    return mamba1._mamba1_xla_chunked(**a, chunk=4)
 
 
 @pytest.mark.parametrize("reference", [_on_xla, _per_token],
@@ -300,10 +300,10 @@ def test_which_programs_get_the_kernels(monkeypatch, backend, devices, shape,
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.setattr(jax, "device_count", lambda: devices)
     if isinstance(want, str):
-        assert ssm.mamba1_path(shape, states, 4, mesh) == want
+        assert mamba1.mamba1_path(shape, states, 4, mesh) == want
     else:
         with pytest.raises(want):
-            ssm.mamba1_path(shape, states, 4, mesh)
+            mamba1.mamba1_path(shape, states, 4, mesh)
 
 
 def test_the_scan_hands_a_tpu_program_to_the_kernels(monkeypatch):
@@ -317,6 +317,6 @@ def test_the_scan_hands_a_tpu_program_to_the_kernels(monkeypatch):
     monkeypatch.setattr(kernels, "mamba1_scan", functools.partial(
         kernels.mamba1_scan, interpret=True))
     args = _kernel_inputs(9, 70, b=1)
-    got = ssm.mamba1_scan(**args, chunk=4)
+    got = mamba1.mamba1_scan(**args, chunk=4)
     assert said == {"ssm_path": "pallas_chunked", "ssm_chunk": kernels.ROWS}
     np.testing.assert_allclose(got, _on_xla(**args), rtol=2e-5, atol=2e-5)

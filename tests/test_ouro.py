@@ -448,18 +448,19 @@ def _mlp_forwards(remat) -> int:
 
 def test_a_recomputed_block_runs_the_kept_matmuls_once(monkeypatch):
     """With ``remat`` the gradient holds the MLPs' forward matmuls once
-    in the layers that keep their three products (``_MLP_KEEPS``;
+    in the layers that keep their three products (``_mlp_keeps``;
     ``down``'s is read by the norm on the branch) and ``gate``'s once
-    more in the layers that keep two (``_block_keeps``: the first half).
+    more in the layers that keep two (the first half).
     With every layer keeping all three the count is that of the stack
     kept whole; under the policy without any of the names each block
     holds one more forward of all three."""
     n = OuroConfig.tiny().n_layer
     assert (_mlp_forwards(False), _mlp_forwards(True)) == (
         3 * n, 3 * n + n // 2)
-    monkeypatch.setattr(ouro, "_first_keeping_all", lambda cfg: 0)
+    monkeypatch.setattr(ouro, "_mlp_keeps", lambda cfg: (
+        "mlp_down", "mlp_up", "mlp_gate"))
     assert _mlp_forwards(True) == 3 * n
-    monkeypatch.setattr(ouro, "_block_keeps", lambda cfg, i: ())
+    monkeypatch.setattr(ouro, "_mlp_keeps", lambda cfg: ())
     assert _mlp_forwards(True) == 6 * n
 
 

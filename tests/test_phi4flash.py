@@ -19,7 +19,7 @@ from ray_tpu import train
 from ray_tpu.models import Phi4Flash, Phi4FlashConfig
 from ray_tpu.models import phi4flash as model_file
 from ray_tpu.models.phi4flash import phi4flash_loss_fn
-from ray_tpu.ops import attention, ssm
+from ray_tpu.ops import attention, mamba1
 from ray_tpu.util import tracing
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
@@ -276,15 +276,15 @@ def _cut_when_cross(name):
 # (module, attribute, what replaces it given the original)
 FAULTS = {
     "the_decay_from_the_previous_tokens_delta": (
-        ssm, "_mamba1_decay",
+        mamba1, "_mamba1_decay",
         lambda orig: lambda dt, A: orig(_shifted_rows(dt), A)),
     "the_input_term_without_delta": (
-        ssm, "_mamba1_write",
+        mamba1, "_mamba1_write",
         lambda orig: lambda dt, x, B: orig(jnp.ones_like(dt), x, B)),
     "a_chunks_state_handed_on_one_chunk_late": (
-        ssm, "_mamba1_walk", _one_chunk_late),
+        mamba1, "_mamba1_walk", _one_chunk_late),
     "the_skip_left_out": (
-        ssm, "mamba1_scan",
+        mamba1, "mamba1_scan",
         lambda orig: lambda x, dt, A, B, C, D, **kw: orig(
             x, dt, A, B, C, jnp.zeros_like(D), **kw)),
     "the_memory_of_layer_2_for_the_last_scans": (

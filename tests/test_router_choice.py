@@ -13,8 +13,8 @@ import pytest
 from conftest import live_kernel_calls, primitives
 
 from ray_tpu.ops import moe
-from ray_tpu.ops.attention import remat_policy
 from ray_tpu.ops.pallas import router_choice
+from ray_tpu.ops.remat import ROUTER_KEEPS, remat_policy
 
 T, D = 128, 32
 # (experts, top_k): OLMoE's and SmallThinker's, Nemotron's, Laguna's and
@@ -153,7 +153,7 @@ def test_a_recomputed_block_that_keeps_the_names_chooses_once(router, path):
     second time, in the softmax router too. On XLA's lines the softmax
     router's ``top_k`` runs again (its values reach no name), the
     sigmoid's does not."""
-    block = nn.remat(_Routed, policy=remat_policy(*moe.ROUTER_KEEPS))(
+    block = nn.remat(_Routed, policy=remat_policy(*ROUTER_KEEPS))(
         router, path)
     x = _inputs(64, 8)[0]
     params = block.init(jax.random.key(0), x)

@@ -17,8 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops import ssm
+from ray_tpu.ops import conv1d, gated_norm as norms, ssm
 from ray_tpu.ops.pallas import causal_conv, gated_norm, ssd_scan
+from ray_tpu.ops.remat import SSD_SCAN_OUT, SSD_SCAN_STATES
 
 B, H, P, G, N, CHUNK = 2, 4, 8, 2, 16, 16
 
@@ -127,13 +128,13 @@ def test_conv_is_four_shifted_multiply_adds_then_silu():
             if t - 3 + j >= 0:
                 acc = acc + np.asarray(w[j]) * np.asarray(x[:, t - 3 + j])
         want[:, t] = acc / (1 + np.exp(-acc))
-    np.testing.assert_allclose(ssm.causal_conv1d_silu(x, w, b), want,
+    np.testing.assert_allclose(conv1d.causal_conv1d_silu(x, w, b), want,
                                rtol=1e-5, atol=1e-6)
     # causal: the future does not reach back
     later = x.at[:, 5:].set(0.0)
     np.testing.assert_array_equal(
-        ssm.causal_conv1d_silu(later, w, b)[:, :5],
-        ssm.causal_conv1d_silu(x, w, b)[:, :5])
+        conv1d.causal_conv1d_silu(later, w, b)[:, :5],
+        conv1d.causal_conv1d_silu(x, w, b)[:, :5])
 
 
 def test_gated_norm_normalises_each_group():
@@ -145,9 +146,9 @@ def test_gated_norm_normalises_each_group():
     g = g.reshape(2, 5, 3, 4)
     g = g / np.sqrt((g * g).mean(-1, keepdims=True) + 1e-5)
     np.testing.assert_allclose(
-        ssm.gated_group_rms_norm(y, z, scale, 3, 1e-5),
+        norms.gated_group_rms_norm(y, z, scale, 3, 1e-5),
         g.reshape(2, 5, 12) * np.asarray(scale), rtol=1e-5, atol=1e-6)
-    grads = _grad(lambda *a: ssm.gated_group_rms_norm(
+    grads = _grad(lambda *a: norms.gated_group_rms_norm(
         *a, 3, 1e-5).sum(), (0, 1, 2))(y, z, scale)
     assert all(bool(jnp.isfinite(g).all()) for g in grads)
 
@@ -361,7 +362,7 @@ def test_one_groups_partial_db_dc_come_back_in_the_operands_dtype():
 
 
 @pytest.mark.parametrize("policy, forwards", [
-    ((ssm.SCAN_OUT, ssm.SCAN_STATES), 1), ((ssm.SCAN_OUT,), 2), ((), 2)],
+    ((SSD_SCAN_OUT, SSD_SCAN_STATES), 1), ((SSD_SCAN_OUT,), 2), ((), 2)],
     ids=["both_names", "the_output_alone", "no_name"])
 def test_a_policy_that_keeps_the_scans_two_names_runs_its_forward_once(
         policy, forwards):
@@ -518,7 +519,7 @@ def test_kernel_norm_is_the_xla_norm(monkeypatch, case, dtype):
     args = _norm_inputs(shape, dtype, seed=shape[1])
     got = _value_and_grads(_norm_kernel(groups), *args)
     want = _value_and_grads(
-        lambda *a: ssm.gated_group_rms_norm(*a, groups, 1e-5), *args)
+        lambda *a: norms.gated_group_rms_norm(*a, groups, 1e-5), *args)
     step = 1e-5 if dtype == jnp.float32 else 2 ** -7
     for name, g, w in zip("out dy dz dscale".split(), got, want):
         assert g.dtype == w.dtype and g.shape == w.shape, name
@@ -546,7 +547,7 @@ def test_norm_path_reads_the_backend_and_the_shapes(
         monkeypatch, backend, shape, groups, path):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.setattr(jax, "device_count", lambda: 1)
-    assert ssm.norm_path(shape, groups) == path
+    assert norms.norm_path(shape, groups) == path
 
 
 @pytest.mark.parametrize("axes, batch, path", [
@@ -566,7 +567,7 @@ def test_norm_path_reads_the_devices_the_program_spans(
     tables are one."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = None if axes is None else _mesh(**axes)
-    assert ssm.norm_path((batch, *NORM_CELL[1:]), 8, mesh) == path
+    assert norms.norm_path((batch, *NORM_CELL[1:]), 8, mesh) == path
     x, state = ((batch, *shape[1:]) for shape in CELL[:2])
     assert (ssm.scan_path(x, state, 128, mesh) == "pallas_chunked") == (
         path == "pallas")
@@ -638,7 +639,7 @@ def test_kernel_output_gate_is_the_xla_output_gate(monkeypatch, case, dtype,
     args = _gate_inputs(shape, heads, dtype, seed=shape[1])
     got = _value_and_grads(_gate_kernel(heads, gate_fn=gate_fn), *args)
     want = _value_and_grads(
-        lambda *a: ssm.sigmoid_gated_head_rms_norm(
+        lambda *a: norms.sigmoid_gated_head_rms_norm(
             *a, heads, 1e-5, gate_fn=gate_fn), *args)
     step = 1e-5 if dtype == jnp.float32 else 2 ** -7
     for name, g, w in zip("out do dgate dscale".split(), got, want):
@@ -658,7 +659,7 @@ def test_kernel_output_gate_takes_its_dtype_from_the_gate():
     gate, dout = gate.astype(jnp.bfloat16), dout.astype(jnp.bfloat16)
     got = _value_and_grads(_gate_kernel(2), o, gate, scale, dout)
     want = _value_and_grads(
-        lambda *a: ssm.sigmoid_gated_head_rms_norm(*a, 2, 1e-5),
+        lambda *a: norms.sigmoid_gated_head_rms_norm(*a, 2, 1e-5),
         o, gate, scale, dout)
     for name, g, w in zip("out do dgate dscale".split(), got, want):
         assert g.dtype == w.dtype and g.shape == w.shape, name
@@ -684,7 +685,7 @@ def test_norm_path_serves_32_heads_of_128(monkeypatch, backend, shape, heads,
     the answer for the trace."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.setattr(jax, "device_count", lambda: 1)
-    assert ssm.norm_path(shape, heads) == path
+    assert norms.norm_path(shape, heads) == path
 
 
 @pytest.mark.parametrize("axes, batch, path", [
@@ -700,16 +701,16 @@ def test_output_gate_notes_the_path_a_mesh_gives_it(monkeypatch, axes, batch,
     monkeypatch.setattr(tracing, "note_trace", seen.update)
     monkeypatch.setattr(gated_norm, "head_gate_norm",
                         lambda o, *a, **kw: ("kernels", kw["batch_axes"]))
-    monkeypatch.setattr(ssm, "_sigmoid_gated_head_rms_norm_xla",
+    monkeypatch.setattr(norms, "_sigmoid_gated_head_rms_norm_xla",
                         lambda *a: ("xla", None))
     o = jax.ShapeDtypeStruct((batch, 256, 4096), jnp.bfloat16)
-    ran, batch_axes = ssm.sigmoid_gated_head_rms_norm(
+    ran, batch_axes = norms.sigmoid_gated_head_rms_norm(
         o, o, None, 32, 1e-5, mesh=mesh)
     assert seen == {"kda_gate_path": path}
     assert (ran, batch_axes) == (("kernels", ("dp",)) if path == "pallas"
                                  else ("xla", None))
     seen.clear()    # Gated DeltaNet's gate: the same rule, its own note
-    assert ssm.sigmoid_gated_head_rms_norm(
+    assert norms.sigmoid_gated_head_rms_norm(
         o, o, None, 32, 1e-5, mesh=mesh, gate_fn="silu")[0] == ran
     assert seen == {"gdn_gate_path": path}
 
@@ -791,10 +792,10 @@ def test_kernel_conv_is_the_xla_conv(case, dtype, bias):
     blocks = {"rows": 64, "strip": 32, **blocks}
     args = _conv_inputs(shape, dtype, bias, seed=shape[1])
     got = _conv_value_and_grads(_conv_kernel(**blocks), *args)
-    want = _conv_value_and_grads(ssm._causal_conv1d_silu_xla, *args)
+    want = _conv_value_and_grads(conv1d._causal_conv1d_silu_xla, *args)
     f32 = jnp.float32
     exact = _conv_value_and_grads(
-        ssm._causal_conv1d_silu_xla,
+        conv1d._causal_conv1d_silu_xla,
         *(None if a is None else a.astype(f32) for a in args))
     assert len(got) == len(want) == (4 if bias else 3)
     for name, g, w, e in zip("y dx dw dbias".split(), got, want, exact):
@@ -872,7 +873,7 @@ def test_conv_path_reads_the_backend_and_the_shapes(
         monkeypatch, backend, shape, taps, path):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.setattr(jax, "device_count", lambda: 1)
-    assert ssm.conv_path(shape, taps) == path
+    assert conv1d.conv_path(shape, taps) == path
 
 
 @pytest.mark.parametrize("axes, batch, path", [
@@ -893,7 +894,7 @@ def test_conv_path_reads_the_devices_the_program_spans(
     else."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = None if axes is None else _mesh(**axes)
-    assert ssm.conv_path((batch, *CONV_CELL[1:]), 4, mesh) == path
+    assert conv1d.conv_path((batch, *CONV_CELL[1:]), 4, mesh) == path
 
 
 @pytest.mark.parametrize("backend, path", [("cpu", "xla"), ("tpu", "pallas")])
@@ -909,13 +910,13 @@ def test_conv_notes_its_path_and_takes_it(monkeypatch, backend, path):
     monkeypatch.setattr(
         causal_conv, "causal_conv", lambda x, *a, **kw: calls.append(kw) or x)
     x, w, b, _ = _conv_inputs((1, 32, 256), jnp.float32)
-    y = ssm.causal_conv1d_silu(x, w, b)
+    y = conv1d.causal_conv1d_silu(x, w, b)
     assert notes == {"conv_path": path, "conv_taps": 4, "conv_cols": 256}
     assert calls == ([{"mesh": None, "batch_axes": ()}] if path == "pallas"
                      else [])
     if path == "xla":
         np.testing.assert_array_equal(
-            y, ssm._causal_conv1d_silu_xla(x, w, b))
+            y, conv1d._causal_conv1d_silu_xla(x, w, b))
 
 
 def test_kernel_conv_over_a_batch_sharded_mesh_is_the_one_device_conv():
