@@ -52,7 +52,10 @@ product and choice (``ops/remat.py::ROUTER_KEEPS``), and at ``hc_mult``
 > 1 each sub-layer's residual maps with the 25 floats a token their
 backward kernel reads (``MAPS_KEEPS``), so the
 second pass runs ``pre`` and ``post`` from them and the maps' norm,
-product and forward kernel once a step.
+product and forward kernel once a step, and each sub-layer's last products
+(``_UNDER_MAPS``: under maps ``post``'s backward reads a sub-layer's
+output, so ``out_proj``'s, ``down``'s and the routed sum are kept, with
+the dense and shared MLPs' ``gate`` and ``up``).
 
 It is the benchmark's fourth language model
 (``joyai-llm-flash.b1-t8192`` runs the dense layer, four routed layers
@@ -84,6 +87,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.llama import RMSNorm, SwiGLU, rope_freqs, yarn_freqs
 from ray_tpu.models.nemotron_h import _Router   # gate: [d, E] and its bias
@@ -91,7 +95,8 @@ from ray_tpu.ops import hyper_connections as hc, remat
 from ray_tpu.ops.mla import UpProjections, latent_attention
 from ray_tpu.ops.moe import held_route_share, routed_ffn
 from ray_tpu.ops.pallas import program
-from ray_tpu.ops.remat import MAPS_KEEPS, ROUTER_KEEPS
+from ray_tpu.ops.remat import (
+    MAPS_KEEPS, MIXER_PROJ, MLP_DOWN, MLP_GATE, MLP_UP, MOE_OUT, ROUTER_KEEPS)
 from ray_tpu.util import tracing
 
 
@@ -356,7 +361,8 @@ class LatentAttention(nn.Module):
             c_q, c_kv, k_r, UpProjections(q_nope, q_rope, k_nope, v), angles,
             n_head=cfg.n_head, mesh=self.mesh, scale=cfg.mla_scale,
             rope_amplitude=cfg.rope_amplitude)
-        return _dense(cfg)(cfg.n_embd, name="out_proj")(o)
+        return checkpoint_name(
+            _dense(cfg)(cfg.n_embd, name="out_proj")(o), MIXER_PROJ)
 
 
 class _Experts(nn.Module):
@@ -391,6 +397,10 @@ class MoE(nn.Module):
             route_scale=cfg.route_scale, expert="swiglu",
             experts_held=cfg.experts_held)
         self.sow("moe", "load", load)
+        # the routed sum as the layer returns it, in front of the shared
+        # expert's: what a recomputed block under residual maps keeps of
+        # the held experts (``_keeps``)
+        y = checkpoint_name(y, MOE_OUT)
         return y + _swiglu(cfg, cfg.shared_width, "shared")(x)
 
 
@@ -463,13 +473,25 @@ class Block(nn.Module):
         return _around(cfg, "mlp", lambda u: mlp(mlp_norm(u)), x, self.mesh)
 
 
+# what a recomputed block keeps at ``hc_mult`` > 1 beside its router's:
+# each sub-layer's residual maps' (on the maps' XLA path nothing carries
+# them), each sub-layer's output as ``post`` reads it
+# (``post``'s backward reads it too, for the write-in map's gradient: under
+# maps a sub-layer's last product is read, where ``x + f(x)`` reads none):
+# ``out_proj``'s product, a dense or shared MLP's ``down`` and the routed
+# sum; and the dense and shared MLPs' ``gate`` and ``up``. The state
+# between the two sub-layers is not kept: with it (117 MB a block) the
+# second pass's passes over the streams moved between ``pre`` and ``post``
+# and the step did not (PERF.md section 6, PR 70)
+_UNDER_MAPS = (*MAPS_KEEPS, MIXER_PROJ, MLP_DOWN, MOE_OUT, MLP_GATE, MLP_UP)
+
+
 def _keeps(cfg: JoyAIConfig) -> tuple[str, ...]:
     """The names a recomputed block keeps beside its attention core's
     (``ops/remat.py`` has what each is): its router's, so that the
     second pass runs neither the float32 product nor the choice again,
-    and at ``hc_mult`` > 1 each sub-layer's residual maps' (on the maps'
-    XLA path nothing carries them)."""
-    return (*ROUTER_KEEPS, *(MAPS_KEEPS if cfg.hc_mult > 1 else ()))
+    and at ``hc_mult`` > 1 ``_UNDER_MAPS``."""
+    return (*ROUTER_KEEPS, *(_UNDER_MAPS if cfg.hc_mult > 1 else ()))
 
 
 class MTP(nn.Module):

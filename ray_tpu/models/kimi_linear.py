@@ -59,8 +59,14 @@ the block keeps its core's output and row statistics
 kernel runs once, and of a routed layer its router's float32 product,
 choice, chosen scores and counts (``ops/remat.py::ROUTER_KEEPS``, 17 MB a
 layer), so that the product at the highest precision, ``top_k``, the
-gather and the counts' scatter-add run once (the note
-``blocks_remat_keeps`` lists all nine names). **The output gate**
+gather and the counts' scatter-add run once; and the block's plain matmul
+products, 2.9 GB over the cell's five layers: a KDA mixer's ``W_q h``,
+``W_k h``, ``W_v h`` as the products leave them
+(``ops/remat.py::MIXER_IN``), the stream behind either mixer
+(``MIXER_STREAM``: the sum, so that the output projection has no reader
+left in the second pass and the MLP's norm reads one array), and the dense
+and shared MLPs' ``gate`` and ``up`` (the note ``blocks_remat_keeps``
+lists every name). **The output gate**
 (``ops/gated_norm.py::sigmoid_gated_head_rms_norm``, handed the mixer's mesh;
 the note ``kda_gate_path``) on its kernels (``pallas``: a TPU, heads of
 whole 128-lane tiles, one device or a mesh that shards the batch alone)
@@ -109,13 +115,19 @@ from ray_tpu.ops import conv1d, gated_norm, kda, remat
 from ray_tpu.ops.mla import UpProjections, latent_attention
 from ray_tpu.ops.moe import held_route_share
 from ray_tpu.ops.remat import (
-    KDA_OUT, KDA_SCAN_OUT, KDA_SCAN_STATES, ROUTER_KEEPS)
+    KDA_OUT, KDA_SCAN_OUT, KDA_SCAN_STATES, MIXER_IN, MIXER_STREAM, MLP_GATE,
+    MLP_UP, ROUTER_KEEPS)
 from ray_tpu.util import tracing
 
 
 # what a recomputed block keeps (the module docstring): a KDA mixer's
-# gated output and its recurrence's two, a routed layer's router's five
-_BLOCK_KEEPS = (KDA_OUT, KDA_SCAN_OUT, KDA_SCAN_STATES, *ROUTER_KEEPS)
+# gated output and its recurrence's two, a routed layer's router's five,
+# and the block's plain matmul products: a KDA mixer's three wide input
+# projections, the stream behind either mixer (so that its output
+# projection does not run again), and the dense and shared MLPs' ``gate``
+# and ``up``
+_BLOCK_KEEPS = (KDA_OUT, KDA_SCAN_OUT, KDA_SCAN_STATES, *ROUTER_KEEPS,
+                MIXER_IN, MIXER_STREAM, MLP_GATE, MLP_UP)
 
 
 @dataclass(frozen=True)
@@ -279,7 +291,10 @@ class KDAMixer(nn.Module):
         heads, kd, inner = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_inner
         dense, normal = _dense(cfg), nn.initializers.normal(0.02)
         with jax.named_scope("qkv"):
-            q, k, v = (dense(inner, name=n)(h) for n in "qkv")
+            # as the products leave them: ``_kda_core``'s convolutions
+            # read them, in both of its passes
+            q, k, v = (checkpoint_name(dense(inner, name=n)(h), MIXER_IN)
+                       for n in "qkv")
         with jax.named_scope("decay"):
             f_low = dense(cfg.kda_rank, name="f_a")(h)
             b_logit = dense(heads, name="b")(h)
@@ -347,7 +362,11 @@ class Block(nn.Module):
         mixer = (LatentAttention(cfg, self.mesh, name="attn")
                  if cfg.mixer(self.layer) == "M"
                  else KDAMixer(cfg, self.mesh, name="kda"))
-        x = x + mixer(_norm(cfg)(name="attn_norm")(x))
+        # the stream between the block's halves: what the MLP's norm reads
+        # (a recomputed block keeps the sum and not the mixer's output
+        # projection's product, which nothing reads but this add)
+        x = checkpoint_name(x + mixer(_norm(cfg)(name="attn_norm")(x)),
+                            MIXER_STREAM)
         mlp = (MoE(cfg, self.mesh, name="mlp")
                if self.layer >= cfg.dense_layers
                else _swiglu(cfg, cfg.dense_width, "mlp"))

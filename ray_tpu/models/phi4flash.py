@@ -48,7 +48,13 @@ are an attention core's output and row statistics
 a layer) and the MLP's ``gate_up`` product (``[T, 2 x 10,240]``, 168 MB
 a layer at 4,096 rows: the block's dearest matmul does not run twice;
 ``down``'s product is added to the stream as it is, so nothing in the
-backward pass reads it and it needs no name); the note
+backward pass reads it and it needs no name), the mixer's input
+projection's product (``ops/remat.py::MIXER_IN``; an attention layer's q,
+k, v as projected, ``ATTN_Q``, ``ATTN_K``, ``ATTN_V``: a third of the
+cores' operands as ``repeat`` writes them out), the stream behind the
+mixer (``MIXER_STREAM``: the mixer's output projection has no reader
+left) and the Mamba-1 scan's ``y`` and entering states (the kernels'
+forward rule's names: the forward kernel runs once a layer); the note
 ``blocks_remat_keeps`` lists them.
 
 It is the benchmark's seventh language model
@@ -79,11 +85,20 @@ from jax.ad_checkpoint import checkpoint_name
 from ray_tpu.models.nemotron_h import _conv_init, _dt_bias_init
 from ray_tpu.ops import conv1d, mamba1, remat
 from ray_tpu.ops.attention import differential_attention
-from ray_tpu.ops.remat import MLP_GATE_UP
+from ray_tpu.ops.remat import (
+    ATTN_K, ATTN_Q, ATTN_V, MAMBA1_SCAN_OUT, MAMBA1_SCAN_STATES, MIXER_IN,
+    MIXER_STREAM, MLP_GATE_UP)
 from ray_tpu.util import tracing
 
-# what a recomputed block keeps of its MLP (the module docstring)
-_MLP_KEEPS = (MLP_GATE_UP,)
+# what a recomputed block keeps (the module docstring): its MLP's
+# ``gate_up`` product, its mixer's input projection's product (an
+# attention layer's ``q``, ``k``, ``v``), the stream behind the mixer
+# (so that its output projection does not run again), and the Mamba-1
+# scan's forward kernel's two results. Not the differential combination's
+# result: kept, ``W_o``'s backward gave back what the second pass saved
+# (PERF.md section 6, PR 70)
+_BLOCK_KEEPS = (MLP_GATE_UP, MIXER_IN, MIXER_STREAM, ATTN_Q, ATTN_K, ATTN_V,
+                MAMBA1_SCAN_OUT, MAMBA1_SCAN_STATES)
 
 
 @dataclass(frozen=True)
@@ -266,7 +281,8 @@ class Mamba(nn.Module):
         cfg = self.config
         inner, n, r = cfg.mamba_inner, cfg.ssm_state, cfg.dt_rank
         f32 = jnp.float32
-        x, z = jnp.split(_dense(cfg)(2 * inner, name="in_proj")(h), 2, -1)
+        x, z = jnp.split(checkpoint_name(
+            _dense(cfg)(2 * inner, name="in_proj")(h), MIXER_IN), 2, -1)
         with jax.named_scope("conv"):
             x = _Conv(cfg, self.mesh, name="conv1d")(x)
         dbc = _dense(cfg)(r + 2 * n, name="x_proj")(x)
@@ -292,7 +308,8 @@ class GatedMemoryUnit(nn.Module):
     @nn.compact
     def __call__(self, h, memory):
         cfg = self.config
-        a = _dense(cfg)(cfg.mamba_inner, name="in_proj")(h)
+        a = checkpoint_name(
+            _dense(cfg)(cfg.mamba_inner, name="in_proj")(h), MIXER_IN)
         with jax.named_scope("gate"):
             gated = jax.nn.silu(a) * memory
         return _dense(cfg)(cfg.n_embd, name="out_proj")(gated)
@@ -337,11 +354,17 @@ class DiffAttention(nn.Module):
         kind = cfg.kind(self.layer)
         q_w, kv_w = cfg.n_head * hd, cfg.n_kv_head * hd
         h = h.astype(cfg.dtype)
+        # the products as a matmul wrote them (``repeat`` writes the core's
+        # operands out of them again in a recomputed block's second pass:
+        # kept as the core reads them they are three times the bytes)
         if kind == "X":
             (q,) = _Projection(cfg, (q_w,), name="q")(h)
+            q = checkpoint_name(q, ATTN_Q)
             k, v = kv
         else:
-            q, k, v = _Projection(cfg, (q_w, kv_w, kv_w), name="qkv")(h)
+            q, k, v = (checkpoint_name(z, n) for z, n in zip(
+                _Projection(cfg, (q_w, kv_w, kv_w), name="qkv")(h),
+                (ATTN_Q, ATTN_K, ATTN_V)))
             k, v = (z.reshape(b, t, cfg.n_kv_head, hd) for z in (k, v))
         lam_vec = {n: self.param(f"lambda_{n}",
                                  nn.initializers.normal(cfg.lambda_std),
@@ -398,7 +421,10 @@ class Block(nn.Module):
                 h, kv)
             if self.layer == cfg.kv_layer:
                 kv = made
-        x = x + y
+        # the stream between the block's halves: what ``ln_2`` reads (a
+        # recomputed block keeps the sum, not the mixer's last product,
+        # which nothing reads but this add)
+        x = checkpoint_name(x + y, MIXER_STREAM)
         return (x + MLP(cfg, name="mlp")(_norm(cfg)(name="ln_2")(x)),
                 memory, kv)
 
@@ -425,7 +451,7 @@ class Phi4Flash(nn.Module):
             ssm_state=cfg.ssm_state, ssm_dt_rank=cfg.dt_rank,
             yoco_memory_layer=cfg.memory_layer, yoco_kv_layer=cfg.kv_layer,
             blocks_remat=cfg.remat,
-            blocks_remat_keeps=remat.keeps_note(cfg.remat, _MLP_KEEPS))
+            blocks_remat_keeps=remat.keeps_note(cfg.remat, _BLOCK_KEEPS))
         wte = nn.Embed(cfg.vocab_size, cfg.n_embd, name="wte",
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                        embedding_init=nn.initializers.normal(0.02))
@@ -433,8 +459,9 @@ class Phi4Flash(nn.Module):
             x = self._constrain(wte(tokens))
         # a recomputed block keeps its attention core's output and row
         # statistics (42 MB a layer at 4,096 rows), as models/laguna.py,
-        # and its MLP's gate_up product (168 MB a layer)
-        block = remat.block(Block, cfg.remat, _MLP_KEEPS)
+        # and ``_BLOCK_KEEPS`` (its MLP's gate_up product, 168 MB a layer,
+        # and 0.1 GB a layer of its mixer's)
+        block = remat.block(Block, cfg.remat, _BLOCK_KEEPS)
         memory = kv = None
         with jax.named_scope("blocks"):
             for i in range(cfg.n_layer):

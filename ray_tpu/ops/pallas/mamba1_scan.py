@@ -9,7 +9,12 @@ writes ``y`` once; the only thing kept for the backward beside the inputs
 is the state *entering* each block (float32: ``[T / rows, N, C]``, 21 MB
 a layer at 4,096 rows of 5,120 channels in blocks of 64, where the XLA
 path's chunks of 4 keep 335 MB), from which the backward kernel recomputes
-a block's states, again into VMEM.
+a block's states, again into VMEM. The forward rule names its two results
+(``ops/remat.py::MAMBA1_SCAN_*``), as ``ssd_scan.py``'s does: a block
+recomputed under ``nn.remat`` whose policy lists them
+(``models/phi4flash.py``) finds ``y`` and the entering states kept and does
+not run the forward kernel a second time; outside such a policy a name is
+the identity.
 
 The decay ``exp(dt_t[c] A[n, c])`` differs by channel *and* by state, so
 there is no matmul in it: every operation is elementwise on the VPU (and
@@ -89,7 +94,10 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+
+from ray_tpu.ops.remat import MAMBA1_SCAN_OUT, MAMBA1_SCAN_STATES
 
 # Channels in a vreg: 8 sublanes of 128 lanes.
 _SUB, _LANES = 8, 128
@@ -416,6 +424,10 @@ def _mamba1_core(bm, cm, x, dt, rates, skip, static: _Static):
 
 def _mamba1_core_fwd(bm, cm, x, dt, rates, skip, static):
     y, entering = _mamba1_fwd(bm, cm, x, dt, rates, skip, **static._asdict())
+    # both named before they part into primal and residuals (the trap
+    # ``ops/remat.py::name_core_results`` records)
+    y, entering = checkpoint_name(y, MAMBA1_SCAN_OUT), checkpoint_name(
+        entering, MAMBA1_SCAN_STATES)
     return y, (bm, cm, x, dt, rates, skip, entering)
 
 

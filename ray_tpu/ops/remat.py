@@ -64,6 +64,11 @@ ROUTER_LSE = "moe_router_lse"          # the softmax router's logsumexp,
 #   which only the kernel's forward makes | its backward kernel | 4
 ROUTER_KEEPS = (ROUTER_LOGITS, ROUTER_EXPERTS, ROUTER_WEIGHTS,
                 ROUTER_COUNTS, ROUTER_LSE)
+# and the routed sum a layer returns (``models/joyai.py::MoE``, in front
+# of the shared expert's):
+MOE_OUT = "moe_routed_out"   # the held experts' part of every token's sum
+#   | under residual maps ``post``'s backward (the sum with the shared
+#   expert's is one add); under ``x + f(x)`` nobody's | 2 d
 
 # The Mamba-2 scan's kernels (``ops/pallas/ssd_scan.py``'s forward rule):
 SSD_SCAN_OUT = "ssd_scan_out"        # y | the gated norm's | 2 H P
@@ -74,6 +79,14 @@ SSD_SCAN_STATES = "ssd_scan_states"  # the state entering each chunk |
 # (``models/nemotron_h.py::Mamba2Mixer``):
 IN_PROJ_PARTS = ("mamba_z", "mamba_xbc", "mamba_dt")    # 2 (2 H P + 2 G N
 #   + H) | the norm's, the convolution's, softplus's
+
+# The Mamba-1 scan's kernels (``ops/pallas/mamba1_scan.py``'s forward
+# rule):
+MAMBA1_SCAN_OUT = "mamba1_scan_out"        # y float32 | the gate's, the
+#   memory's | 4 C
+MAMBA1_SCAN_STATES = "mamba1_scan_states"  # the state entering each row
+#   block | the backward kernel | 4 C N / 64 (21 MB a layer at 4,096 rows
+#   of 5,120 channels, state 16)
 
 # The gated delta rule's kernels (``ops/pallas/kda_scan.py``'s forward
 # rules: Kimi Delta Attention's and Gated DeltaNet's):
@@ -97,12 +110,25 @@ ATTN_Q = "attn_q"    # ``models/laguna.py::Attention``: q, k as the rotation
 ATTN_K = "attn_k"    #   left them (full layer) or the products did
 ATTN_V = "attn_v"    #   (sliding), and v | the core's backward | 2 H D,
 #                        2 x 2 G D
+#   ``models/phi4flash.py::DiffAttention``: the three products with their
+#   biases | ``repeat``, which writes the core's operands out of them
 ATTN_PROJ = "attn_out_proj"     # laguna: W_o's product | the stream's
 #                                 add | 2 d (67 MB at 16,384 rows)
 MIXER_PROJ = "mixer_out_proj"   # ``models/qwen3_next.py``: either mixer's
-#                                 output projection's product | 2 d
-MIXER_STREAM = "mixer_stream"   # ``models/granite.py``: the stream after
-#                                 the mixer | the MLP's norm | 2 d
+#   output projection's product | the stream's add; ``models/joyai.py``:
+#   the same, which under residual maps ``post``'s backward reads | 2 d
+MIXER_IN = "mixer_in_proj"      # a mixer's wide input projections'
+#   products as the matmuls left them. phi4flash: a Mamba mixer's ``[x |
+#   z]`` and the gated memory unit's ``W_in h`` | the convolution's, the
+#   gates' | 4 C, 2 C; kimi_linear: a KDA mixer's ``W_q h``, ``W_k h``,
+#   ``W_v h`` | the convolutions' | 3 x 2 H K (134 MB each a layer at
+#   16,384 rows)
+MIXER_STREAM = "mixer_stream"   # ``models/granite.py``,
+#   ``models/kimi_linear.py``, ``models/phi4flash.py``: the stream after
+#   the mixer | the MLP's norm | 2 d (kept, the mixer's output projection
+#   has no reader left in the second pass; against ``MIXER_PROJ`` it spares
+#   the forward pass a product written beside the sum and the second pass
+#   the add)
 KDA_OUT = "kda_gated_out"       # ``models/kimi_linear.py``: the output
 #                                 gate's result | W_o's | 2 H V
 GDN_OUT = "gdn_gated_out"       # qwen3_next: the same behind Gated

@@ -229,14 +229,16 @@ def test_recomputed_blocks_on_the_kernels_give_the_xla_paths_numbers(
 
 
 @pytest.mark.parametrize("hc_mult, keeps", [
-    (4, (*ROUTER_KEEPS, *MAPS_KEEPS, "attn_out", "attn_lse")),
+    (4, (*ROUTER_KEEPS, *MAPS_KEEPS, "mixer_out_proj", "mlp_down",
+         "moe_routed_out", "mlp_gate", "mlp_up", "attn_out", "attn_lse")),
     (1, (*ROUTER_KEEPS, "attn_out", "attn_lse"))],
     ids=["four_streams", "one_stream"])
 def test_what_a_recomputed_block_keeps_by_name(monkeypatch, hc_mult, keeps):
     """The policy's names at ``hc_mult`` 4 and at ``hc_mult`` 1: the
     routers' first (since PR 68 a recomputed block runs neither its
-    router's product nor the choice again), then the maps' where there
-    are streams, then the cores' two."""
+    router's product nor the choice again), then where there are streams
+    the maps' and each sub-layer's last products (``post``'s backward
+    reads a sub-layer's output), then the cores' two."""
     from ray_tpu.models import joyai
     from ray_tpu.ops import remat
     asked, policy = [], remat.remat_policy

@@ -467,7 +467,8 @@ def test_a_mixer_hands_its_mesh_to_the_one_output_gate(monkeypatch, axes):
 @pytest.mark.parametrize("remat, keeps", [
     (True, "kda_gated_out,kda_scan_out,kda_scan_states,moe_router_logits,"
      "moe_router_experts,moe_router_weights,moe_router_counts,"
-     "moe_router_lse,attn_out,attn_lse"),
+     "moe_router_lse,mixer_in_proj,mixer_stream,mlp_gate,mlp_up,attn_out,"
+     "attn_lse"),
     (False, "")],
     ids=["recomputed", "kept_whole"])
 def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
@@ -518,6 +519,43 @@ def test_a_recomputed_block_routes_once(monkeypatch, listed):
 
     assert routes(False) == (4, 4)
     assert routes(True) == ((4, 4) if listed else (8, 8))
+
+
+@pytest.mark.parametrize("listed", [True, False],
+                         ids=["pr_70s_list", "pr_66s_list"])
+def test_the_blocks_kept_products_change_no_number(monkeypatch, listed):
+    """Loss, report and every gradient leaf of the tiny stack (``KKKMK``,
+    a dense and four routed layers) with ``remat`` against without, under
+    one ``jit`` each: with the literal as it is (PR 70: a KDA mixer's
+    three input products, the stream behind either mixer, the dense
+    and shared MLPs' ``gate`` and ``up`` kept by name) and with those
+    names off it. A kept value is a buffer of what the second pass would
+    have made again."""
+    from ray_tpu.models import kimi_linear
+    if not listed:
+        monkeypatch.setattr(kimi_linear, "_BLOCK_KEEPS", tuple(
+            n for n in kimi_linear._BLOCK_KEEPS if n not in (
+                "mixer_in_proj", "mixer_stream", "mlp_gate", "mlp_up")))
+    got = {}
+    for remat in (False, True):
+        cfg = KimiLinearConfig.tiny(remat=remat, **F32)
+        model = KimiLinear(cfg)
+        params = _jittered(model.init_params(jax.random.key(7)), 7)
+        got[remat] = jax.jit(jax.value_and_grad(
+            kimi_linear_loss_fn(model, ce_chunk=32), has_aux=True))(
+                params, _batch(7, cfg))
+    ((want, want_report), want_grads), ((loss, report), grads) = (
+        got[False], got[True])
+    assert float(loss) == pytest.approx(float(want), rel=1e-6)
+    for key in want_report:
+        assert float(report[key]) == pytest.approx(
+            float(want_report[key]), rel=1e-6), key
+    want_leaves = dict(_leaves_with_names(want_grads))
+    assert len(want_leaves) == len(jax.tree_util.tree_leaves(grads)) > 100
+    for name, leaf in _leaves_with_names(grads):
+        scale = max(float(np.abs(want_leaves[name]).max()), 1e-3)
+        np.testing.assert_allclose(leaf, want_leaves[name],
+                                   atol=1e-5 * scale, err_msg=name)
 
 
 def _at_the_kernels_widths(remat):

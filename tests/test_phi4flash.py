@@ -455,7 +455,9 @@ def test_the_step_reports_the_keys_and_notes_what_the_layers_are(
 
 
 @pytest.mark.parametrize("remat, keeps", [
-    (True, "mlp_gate_up,attn_out,attn_lse"), (False, "")],
+    (True, "mlp_gate_up,mixer_in_proj,mixer_stream,attn_q,attn_k,attn_v,"
+     "mamba1_scan_out,mamba1_scan_states,attn_out,attn_lse"),
+    (False, "")],
     ids=["recomputed", "kept_whole"])
 def test_a_recomputed_block_says_what_its_policy_keeps(remat, keeps,
                                                        monkeypatch):
@@ -496,13 +498,14 @@ def _mlp_forwards(remat) -> int:
 def test_a_recomputed_block_runs_its_mlps_matmuls_once(monkeypatch):
     """With ``remat`` the gradient holds as many of the MLPs' forward
     matmuls as without it: the policy keeps ``gate_up``'s product
-    (``_MLP_KEEPS``), and nothing in the backward pass reads ``down``'s,
+    (``_BLOCK_KEEPS``), and nothing in the backward pass reads ``down``'s,
     which is added to the stream as it is. Under the policy without that
     name each block holds one more ``gate_up``, and still no second
     ``down``."""
     n = Phi4FlashConfig.tiny().n_layer
     assert (_mlp_forwards(False), _mlp_forwards(True)) == (2 * n, 2 * n)
-    monkeypatch.setattr(model_file, "_MLP_KEEPS", ())
+    monkeypatch.setattr(model_file, "_BLOCK_KEEPS", tuple(
+        n for n in model_file._BLOCK_KEEPS if n != "mlp_gate_up"))
     assert _mlp_forwards(True) == 3 * n
 
 
@@ -520,6 +523,77 @@ def test_a_recomputed_stack_gives_the_bits_of_the_one_kept_whole():
                 params, _batch(5, cfg))
     assert len(jax.tree_util.tree_leaves(got[False])) > 100
     assert same_bits(got[True], got[False])
+
+
+@pytest.fixture
+def on_the_scans_kernels(monkeypatch):
+    """The tiny stack at widths the Mamba-1 kernels tile (1,024 channels,
+    8 states; rows of 32 tokens, padded to a block of 64) with the scans
+    on ``ops/pallas/mamba1_scan.py``'s kernels, interpreted:
+    ``mamba1_path`` is told what a TPU would answer. -> remat -> (model,
+    loss function)."""
+    import functools
+
+    from ray_tpu.ops.pallas import mamba1_scan as kernels
+    monkeypatch.setattr(mamba1, "mamba1_path",
+                        lambda *a, **kw: "pallas_chunked")
+    monkeypatch.setattr(kernels, "mamba1_scan", functools.partial(
+        kernels.mamba1_scan, interpret=True))
+
+    def made(remat):
+        model = Phi4Flash(Phi4FlashConfig.tiny(
+            mamba_inner=1024, ssm_state=8, remat=remat, **F32))
+        return model, phi4flash_loss_fn(model, ce_chunk=16)
+    return made
+
+
+def test_recomputed_blocks_on_the_scans_kernels_give_the_kept_blocks_numbers(
+        on_the_scans_kernels):
+    """Loss, report and every gradient leaf with ``remat`` (PR 70: the
+    blocks keep the scan's ``y`` and entering states, which only the
+    kernels' forward rule names, beside their projections' products) against
+    without: the same kernels on the same operands."""
+    got = {}
+    for remat in (False, True):
+        model, loss_fn = on_the_scans_kernels(remat)
+        params = _jittered(model.init_params(jax.random.key(5)), 5)
+        got[remat] = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(
+            params, _batch(5, model.config, rows=1))
+    ((want, want_report), want_grads), ((loss, report), grads) = (
+        got[False], got[True])
+    assert float(loss) == pytest.approx(float(want), rel=1e-6)
+    assert float(report["mamba_out_rms"]) == pytest.approx(
+        float(want_report["mamba_out_rms"]), rel=1e-6)
+    want_leaves = dict(_leaves_with_names(want_grads))
+    assert len(want_leaves) == len(jax.tree_util.tree_leaves(grads)) > 100
+    for name, leaf in _leaves_with_names(grads):
+        scale = max(float(np.abs(want_leaves[name]).max()), 1e-3)
+        np.testing.assert_allclose(leaf, want_leaves[name],
+                                   atol=1e-5 * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("remat, listed, forwards", [
+    (True, True, 1), (True, False, 2), (False, True, 1)],
+    ids=["recomputed", "recomputed_without_the_scans_names", "kept_whole"])
+def test_the_scans_forward_kernel_runs_once_a_layer_under_remat(
+        on_the_scans_kernels, monkeypatch, remat, listed, forwards):
+    """In the gradient's jaxpr, with what nothing reads taken out as
+    lowering takes it out: a recomputed block runs the scan's forward
+    kernel once a Mamba layer (2 results: ``y`` and the entering states)
+    and its backward once (6); a policy without the kernels' two names
+    runs the forward twice, as before PR 70."""
+    from conftest import live_kernel_calls
+    if not listed:
+        monkeypatch.setattr(model_file, "_BLOCK_KEEPS", tuple(
+            n for n in model_file._BLOCK_KEEPS
+            if not n.startswith("mamba1_scan")))
+    model, loss_fn = on_the_scans_kernels(remat)
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    traced = jax.make_jaxpr(jax.value_and_grad(loss_fn, has_aux=True))(
+        params, _batch(0, model.config, rows=1))
+    layers = model.config.layer_kinds.count("M")
+    assert layers == 3
+    assert live_kernel_calls(traced) == [2] * forwards * layers + [6] * layers
 
 
 def test_every_kind_of_layer_has_its_own_scopes():

@@ -21,9 +21,9 @@ from ray_tpu.models import (
     granite, joyai, kimi_linear, laguna, ouro, phi4flash, qwen3_next)
 from ray_tpu.ops import remat
 from ray_tpu.ops.remat import (
-    ATTN_LSE, ATTN_OUT, KDA_SCAN_OUT, KDA_SCAN_STATES, MAPS_KEEPS,
-    ROUTER_COUNTS, ROUTER_EXPERTS, ROUTER_LSE, ROUTER_WEIGHTS, SSD_SCAN_OUT,
-    SSD_SCAN_STATES)
+    ATTN_LSE, ATTN_OUT, KDA_SCAN_OUT, KDA_SCAN_STATES, MAMBA1_SCAN_OUT,
+    MAMBA1_SCAN_STATES, MAPS_KEEPS, ROUTER_COUNTS, ROUTER_EXPERTS, ROUTER_LSE,
+    ROUTER_WEIGHTS, SSD_SCAN_OUT, SSD_SCAN_STATES)
 from ray_tpu.util import tracing
 
 ROUTERS = ("moe_router_logits,moe_router_experts,moe_router_weights,"
@@ -49,8 +49,8 @@ PRESETS = {
         M.KimiLinear, M.KimiLinearConfig.tiny,
         kimi_linear.kimi_linear_loss_fn,
         lambda cfg: kimi_linear._BLOCK_KEEPS,
-        f"kda_gated_out,kda_scan_out,kda_scan_states,{ROUTERS},attn_out,"
-        "attn_lse",
+        f"kda_gated_out,kda_scan_out,kda_scan_states,{ROUTERS},mixer_in_proj,"
+        "mixer_stream,mlp_gate,mlp_up,attn_out,attn_lse",
         {KDA_SCAN_OUT, KDA_SCAN_STATES} | NO_LSE),
     "laguna": (
         M.Laguna, M.LagunaConfig.tiny, laguna.laguna_loss_fn,
@@ -63,8 +63,10 @@ PRESETS = {
         "mlp_down,mlp_up,mlp_gate[1:],attn_out,attn_lse", set()),
     "phi4flash": (
         M.Phi4Flash, M.Phi4FlashConfig.tiny, phi4flash.phi4flash_loss_fn,
-        lambda cfg: phi4flash._MLP_KEEPS,
-        "mlp_gate_up,attn_out,attn_lse", set()),
+        lambda cfg: phi4flash._BLOCK_KEEPS,
+        "mlp_gate_up,mixer_in_proj,mixer_stream,attn_q,attn_k,attn_v,"
+        "mamba1_scan_out,mamba1_scan_states,attn_out,attn_lse",
+        {MAMBA1_SCAN_OUT, MAMBA1_SCAN_STATES}),
     "qwen3_next": (
         M.Qwen3Next, M.Qwen3NextConfig.tiny, qwen3_next.qwen3_next_loss_fn,
         lambda cfg: qwen3_next._BLOCK_KEEPS,
@@ -74,7 +76,8 @@ PRESETS = {
     "xing": (
         M.JoyAI, M.JoyAIConfig.tiny_xing, joyai.joyai_loss_fn, joyai._keeps,
         f"{ROUTERS},hc_maps_pre,hc_maps_post,hc_maps_res,hc_maps_m,"
-        "hc_maps_r,attn_out,attn_lse",
+        "hc_maps_r,mixer_out_proj,mlp_down,moe_routed_out,mlp_gate,mlp_up,"
+        "attn_out,attn_lse",
         set(MAPS_KEEPS) | NO_LSE),
     "joyai": (
         M.JoyAI, M.JoyAIConfig.tiny, joyai.joyai_loss_fn, joyai._keeps,
@@ -115,6 +118,57 @@ def test_every_listed_name_is_on_a_value_and_the_note_reads_as_it_did(
     table = [v for k, v in vars(remat).items() if k.isupper()]
     every = {n for v in table for n in ((v,) if isinstance(v, str) else v)}
     assert set(listed) <= every
+
+
+# what PR 70 listed, a model: (the module, its literal's attribute, the
+# names)
+LISTED_IN_PR_70 = {
+    "kimi_linear": (kimi_linear, "_BLOCK_KEEPS", (
+        "mixer_in_proj", "mixer_stream", "mlp_gate", "mlp_up")),
+    "phi4flash": (phi4flash, "_BLOCK_KEEPS", (
+        "mixer_in_proj", "mixer_stream", "attn_q", "attn_k", "attn_v")),
+    "xing": (joyai, "_UNDER_MAPS", (
+        "mixer_out_proj", "mlp_down", "moe_routed_out", "mlp_gate",
+        "mlp_up"))}
+
+
+@pytest.mark.parametrize("preset", list(LISTED_IN_PR_70))
+def test_a_newly_listed_name_is_kept_and_read(preset, monkeypatch):
+    """Kept *and read*: a policy keeps a name's own result, and a name
+    nothing in the backward pass reads (one behind an operation whose
+    backward reads that operation's own output, or on a product that is
+    only added to the stream) is pruned from the gradient's residuals
+    with the rest of what nobody uses. So with any one of the names PR
+    70 listed taken off the model's literal,
+    ``jax._src.ad_checkpoint.saved_residuals`` of the tiny model's loss
+    holds fewer arrays beside its arguments than with the literal as it
+    is."""
+    from jax._src.ad_checkpoint import saved_residuals
+    cls, config, loss_fn, literal, _, _ = PRESETS[preset]
+    module, attribute, names = LISTED_IN_PR_70[preset]
+    cfg = config(remat=True, dtype=jnp.float32)
+    model = cls(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    batch = {k: jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32)
+             for k in ("tokens", "targets")}
+
+    def kept() -> int:
+        def loss(params, batch):
+            out = loss_fn(model, ce_chunk=16)(params, batch)
+            return out[0] if isinstance(out, tuple) else out
+        # the arguments are held whoever reads them (a projection's bias
+        # is read by its recomputation alone)
+        return sum("from the argument" not in why
+                   for _, why in saved_residuals(loss, params, batch))
+
+    whole = getattr(module, attribute)
+    assert set(names) <= set(whole) <= set(literal(cfg))
+    with_all = kept()
+    for name in names:
+        monkeypatch.setattr(module, attribute,
+                            tuple(n for n in whole if n != name))
+        assert name not in literal(cfg)
+        assert kept() < with_all, name
 
 
 def _scan(kernels, out, states):
@@ -195,8 +249,21 @@ def _ssd():
     return _scan(ssd_scan, SSD_SCAN_OUT, SSD_SCAN_STATES)
 
 
+def _mamba1():
+    from ray_tpu.ops.pallas import mamba1_scan as kernels
+
+    def trace():
+        x = jnp.ones((1, 64, 1024), jnp.float32)
+        bc = jnp.ones((1, 64, 8), jnp.float32)
+        return jax.make_jaxpr(jax.grad(lambda x: kernels.mamba1_scan(
+            x, x, -jnp.ones((1024, 8)), bc, bc, jnp.ones((1024,)),
+            interpret=True).sum()))(x)
+    return trace, {MAMBA1_SCAN_OUT, MAMBA1_SCAN_STATES}
+
+
 RULES = {
-    "ssd_scan": _ssd, "kda_scan": lambda: _delta(False),
+    "ssd_scan": _ssd, "mamba1_scan": _mamba1,
+    "kda_scan": lambda: _delta(False),
     "gdn_scan": lambda: _delta(True), "hc_maps": _maps,
     "router_softmax": lambda: _router("softmax", {
         ROUTER_EXPERTS, ROUTER_WEIGHTS, ROUTER_COUNTS, ROUTER_LSE}),

@@ -50,7 +50,9 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert notes["attn_kind"] == "differential"
     assert notes["layer_pattern"] == "MSMSMFGX"
     assert notes["blocks_remat"] is True and notes["attn_window"] == 512
-    assert notes["blocks_remat_keeps"] == "mlp_gate_up,attn_out,attn_lse"
+    assert notes["blocks_remat_keeps"] == (
+        "mlp_gate_up,mixer_in_proj,mixer_stream,attn_q,attn_k,attn_v,"
+        "mamba1_scan_out,mamba1_scan_states,attn_out,attn_lse")
     assert notes["ssm_kind"] == "mamba1" and notes["ssm_tokens"] == 4096
     assert (notes["ssm_inner"], notes["ssm_state"], notes["ssm_dt_rank"],
             notes["ssm_chunk"]) == (5120, 16, 160, 64)   # the kernels' rows
@@ -91,11 +93,16 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
     assert sum("/attn/cross/" in line for line in calls) == 2
     assert all("bf16[1,4096,5120]" in line for line in calls)
     assert not any("/gmu/" in line for line in calls)
-    # three Mamba layers, the same count, each over [1, 4096, 5120]
+    # three Mamba layers, each over [1, 4096, 5120]: the scan's forward
+    # kernel in the block's forward pass alone (PR 70: the block keeps its
+    # two results by the forward rule's names; twice a layer before), the
+    # backward once
     assert all(re.search(r"/h_[024]/mamba/.*scan/", line) for line in scans)
-    assert sum("scan/jit(_mamba1_fwd)/" in line for line in scans) == 3 * 2
+    forward = [line for line in scans if "scan/jit(_mamba1_fwd)/" in line]
+    assert len(forward) == 3
+    assert not [line for line in forward if "rematted_computation" in line]
     assert sum("scan/jit(_mamba1_bwd)/" in line for line in scans) == 3
-    assert len(scans) == 3 * 3
+    assert len(scans) == 3 * 2
     # and their convolutions: the forward in the block's forward pass
     # and in its recomputation, the backward once
     assert all(re.search(r"/h_[024]/mamba/.*conv/", line) for line in convs)
@@ -113,16 +120,34 @@ def test_the_real_size_step_takes_the_kernels_it_should(real_size_step):
 
 @pytest.mark.slow
 def test_the_real_size_step_compiles_inside_the_chips_memory(real_size_step):
-    """Arguments + temporaries + unaliased outputs stay under the chip's
-    15.75 GB (12.87 GB at PR 48, 12.31 GB since the scans' kernels: PR
+    """Arguments + temporaries + unaliased outputs stay inside the chip's
+    16.909 GB (15.75 GiB) with the 13.0 the issue gave what the cell may
+    hold (12.87 GB at PR 48, 12.31 GB since the scans' kernels: PR
     49; the convolutions' kernels, PR 55, leave it unmoved; **12.06 GB
     with each block's ``gate_up`` product kept, 168 MB a layer, 1.34 GB
     in all: PR 62**, no more than without them (the step's peak,
     ``peak_memory_in_bytes``, reads 11.72 GB where it read 11.77: it
     stands where the kept products do not all lie; where, was not
-    read)."""
+    read). **PR 70's 13.05** (``peak_memory_in_bytes`` 11.72 -> 12.42; the
+    chip's allocator held 12.59 of it): 0.8 GB of arrays kept by name, the Mamba mixers' ``in_proj`` products
+    (84 MB a layer, the gated memory unit's 42), the stream behind every
+    mixer (21 MB), an attention layer's q, k, v as projected (42 MB), the
+    scans' ``y`` (float32, 84 MB) and entering states (21 MB). With the
+    cores' operands kept as ``repeat`` writes them out (126 MB a layer
+    where 42) it read 13.26, over what 13.0 held allows. No fusion is XLA's own
+    rematerialisation (``.remat`` in its name), and the scans' forward
+    kernel stands in the first pass alone."""
     cfg, _, lowered = real_size_step
-    m, total = program_bytes(lowered.compile())
+    compiled = lowered.compile()
+    m, total = program_bytes(compiled)
     assert m.argument_size_in_bytes == pytest.approx(
         cfg.num_params() * 10, rel=1e-3)    # f32 + bf16 + f32 a parameter
-    assert 4e9 < total <= 12.06e9 + 0.1e9
+    assert 12.5e9 < total <= 13.05e9 + 0.1e9    # 12.06 at PR 62
+    assert m.peak_memory_in_bytes <= 12.42e9 + 0.1e9
+    text = compiled.as_text()
+    assert not re.findall(r"^\s+%?[\w.\-]*\.remat\d* = ", text, re.M)
+    scans = re.findall(r'op_name="[^"]*jit\(_mamba1_fwd\)[^"]*"', text)
+    assert scans and not [s for s in scans if "rematted_computation" in s]
+    again = set(re.findall(
+        r"rematted_computation/h_\d/(\w+/\w+)/dot_general", text))
+    assert again <= {"mamba/x_proj", "mamba/dt"}, again

@@ -120,6 +120,36 @@ def _off(got: dict, want: dict) -> dict:
             for k in want if k in got}
 
 
+# -- a recomputed block's kept products --------------------------------------
+
+@pytest.mark.parametrize("routed", [False, True], ids=["dense", "routed"])
+def test_the_blocks_kept_products_change_no_number(routed):
+    """Loss, report and every gradient leaf of one block under four
+    streams with ``remat`` (PR 70: the block keeps, beside its routers'
+    and its maps', each sub-layer's output as ``post`` reads it,
+    ``out_proj``'s product, ``down``'s and the routed sum, and the dense
+    or shared MLP's ``gate`` and ``up``) against without, under one
+    ``jit`` each."""
+    got = {}
+    for remat in (False, True):
+        cfg = _small(remat=remat, mtp_depth=0,
+                     dense_layers=0 if routed else 1)
+        got[remat] = _program(cfg, _params(cfg), _batch(cfg))
+    (want, want_grads), (report, grads) = got[False], got[True]
+    assert set(report) == set(want)
+    for key in want:
+        assert float(report[key]) == pytest.approx(
+            float(want[key]), rel=1e-6, abs=1e-9), key
+    leaves = jax.tree_util.tree_leaves_with_path
+    want_leaves = {jax.tree_util.keystr(p): z for p, z in leaves(want_grads)}
+    assert len(want_leaves) == len(leaves(grads)) > 20
+    for path, leaf in leaves(grads):
+        name = jax.tree_util.keystr(path)
+        scale = max(float(np.abs(want_leaves[name]).max()), 1e-3)
+        np.testing.assert_allclose(leaf, want_leaves[name],
+                                   atol=1e-5 * scale, err_msg=name)
+
+
 # -- the model against the reference ----------------------------------------
 
 @pytest.mark.parametrize("overrides", [
@@ -372,8 +402,9 @@ def test_the_step_carries_the_residual_paths_scopes_notes_and_report(
         "blocks_remat_keeps": "moe_router_logits,moe_router_experts,"
                               "moe_router_weights,moe_router_counts,"
                               "moe_router_lse,hc_maps_pre,hc_maps_post,"
-                              "hc_maps_res,hc_maps_m,hc_maps_r,attn_out,"
-                              "attn_lse"}
+                              "hc_maps_res,hc_maps_m,hc_maps_r,"
+                              "mixer_out_proj,mlp_down,moe_routed_out,"
+                              "mlp_gate,mlp_up,attn_out,attn_lse"}
     assert notes["mla_scale"] == pytest.approx(cfg.mla_scale)
     assert cfg.mla_scale == pytest.approx(
         24 ** -0.5 * (0.1 * np.log(4.0) + 1.0) ** 2)
